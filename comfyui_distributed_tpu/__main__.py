@@ -38,10 +38,19 @@ def cmd_serve(args: argparse.Namespace) -> None:
 
     from .api.app import run_app
     from .cluster.controller import Controller
+    from .parallel.mesh import device_census
     from .utils.config import update_config
     from .utils.logging import log
     from .workers.detection import auto_populate_hosts
     from .workers.process_manager import delayed_auto_launch, get_worker_manager
+
+    # claim the devices NOW: this process owns every chip of the host for
+    # its lifetime (docs/deployment.md, "One process per chip"), and a
+    # backend that cannot start is a failed boot — not a server that finds
+    # out on its first request, and never a server on another platform
+    census = device_census()
+    log(f"devices: {len(census)} x {census[0]['platform']} "
+        f"({census[0]['kind']})")
 
     controller = Controller()
     if not controller.is_worker and not controller.load_config().get(
@@ -132,22 +141,10 @@ def cmd_convert(args: argparse.Namespace) -> None:
 
 
 def main(argv: list[str] | None = None) -> None:
-    import os
-
     from .parallel.bootstrap import ensure_virtual_devices
 
-    # CDT_VIRTUAL_DEVICES must land before the FIRST jax touch — which
-    # for the CLI is the JAX_PLATFORMS honor block right below
+    # CDT_VIRTUAL_DEVICES must land before the FIRST jax touch
     ensure_virtual_devices()
-
-    if os.environ.get("JAX_PLATFORMS"):
-        # the environment may pre-register an accelerator plugin and set
-        # jax_platforms programmatically, which overrides the env var —
-        # honor the operator's explicit request (e.g. CPU integration
-        # tests, or pinning "tpu" on a pod)
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
     p = argparse.ArgumentParser(prog="comfyui_distributed_tpu")
     sub = p.add_subparsers(dest="command", required=True)

@@ -4,6 +4,7 @@
 from __future__ import annotations
 
 import asyncio
+import json
 import socket
 from pathlib import Path
 
@@ -65,25 +66,33 @@ def tail_file(path: Path, max_bytes: int = 64 * 1024) -> str:
 def register(router, controller) -> None:
     from ..utils.deadline import deadline_call
 
-    _DEGRADED = [{"error": "device backend unresponsive"}]
+    def no_backend(detail: str) -> web.HTTPServiceUnavailable:
+        return web.HTTPServiceUnavailable(
+            text=json.dumps({"error": f"device backend unavailable: "
+                                      f"{detail}", "status": 503}),
+            content_type="application/json")
+
+    async def device_query(fn):
+        """``fn()`` off the event loop with a deadline (utils/deadline.py).
+        A census that raises or stalls is a 503: a host that cannot say
+        what devices it has is not serving, and no payload pretends
+        otherwise."""
+        try:
+            out = await deadline_call(fn, fallback=None)
+        except RuntimeError as e:       # the backend failed to initialise
+            raise no_backend(str(e)) from None
+        if out is None:
+            raise no_backend("no answer within the deadline")
+        return out
 
     async def system_info(request):
-        # controller.system_info() queries the device backend, which can
-        # hang INDEFINITELY when a network-attached accelerator service
-        # dies — deadline-guard it so the control plane stays responsive
-        # (utils/deadline.py; observed during the r04 chip outage)
-        info = await deadline_call(controller.system_info, fallback=None)
-        if info is None:
-            base = controller.system_info_no_devices()
-            base["devices"] = _DEGRADED
-            return web.json_response(base)
-        return web.json_response(info)
+        return web.json_response(
+            await device_query(controller.system_info))
 
     async def network_info(request):
         interfaces = _list_interfaces()
-        devices = await deadline_call(
-            lambda: controller.system_info()["devices"],
-            fallback=_DEGRADED)
+        devices = await device_query(
+            lambda: controller.system_info()["devices"])
         return web.json_response({
             "interfaces": interfaces,
             "recommended_ip": _recommend_ip(interfaces),
@@ -164,24 +173,15 @@ def register(router, controller) -> None:
 
     async def memory_stats(request):
         """Per-device HBM/host memory stats (None on backends that don't
-        report them, e.g. CPU). Deadline-guarded: per-device stats are
-        RPCs that hang forever when a tunneled backend dies."""
+        report them, e.g. CPU)."""
         def census():
             import jax
 
-            out = []
-            for d in jax.local_devices():
-                try:
-                    stats = d.memory_stats()
-                except Exception:
-                    stats = None
-                out.append({"id": d.id,
-                            "kind": getattr(d, "device_kind", "?"),
-                            "stats": stats})
-            return out
+            return [{"id": d.id, "kind": d.device_kind,
+                     "stats": d.memory_stats()}
+                    for d in jax.local_devices()]
 
-        devices = await deadline_call(census, fallback=_DEGRADED)
-        return web.json_response({"devices": devices})
+        return web.json_response({"devices": await device_query(census)})
 
     # --- telemetry (docs/telemetry.md) -------------------------------------
 

@@ -295,10 +295,14 @@ class Controller:
                        else self.stages.depths()),
         }
 
-    def system_info_no_devices(self) -> dict:
-        """Host facts that never touch the device backend — the degraded
-        payload when the accelerator service is unresponsive
-        (``utils/deadline.py``)."""
+    def system_info(self) -> dict:
+        """Parity: ``/distributed/system_info``
+        (``api/worker_routes.py:393-430``) with TPU topology instead of a
+        CUDA census."""
+        from .. import native
+        from ..parallel.mesh import device_census
+        from ..utils.compile_cache import active_cache_dir
+
         return {
             "machine_id": machine_id(),
             "platform": platform.system().lower(),
@@ -306,17 +310,10 @@ class Controller:
             "python": platform.python_version(),
             "is_docker": Path("/.dockerenv").exists(),
             "environment": detect_environment(),
+            "devices": device_census(),
+            "compile_cache_dir": active_cache_dir(),
+            "native_codec": native.is_native(),
         }
-
-    def system_info(self) -> dict:
-        """Parity: ``/distributed/system_info``
-        (``api/worker_routes.py:393-430``) with TPU topology instead of a
-        CUDA census."""
-        from ..parallel.mesh import device_census
-
-        info = self.system_info_no_devices()
-        info["devices"] = device_census()
-        return info
 
     def clear_memory(self) -> dict:
         """Parity: ``/distributed/clear_memory`` (``api/job_routes.py:160-203``)

@@ -21,7 +21,7 @@ Reference analogue: none — ComfyUI's torch kernels are pre-built, so the
 reference never needs to know its shape population. An XLA server does.
 
 Knobs: ``CDT_SHAPE_CATALOG`` (path; default
-``<CDT_COMPILE_CACHE_DIR>/shape_catalog.json``), ``CDT_SHAPE_OBSERVE=0``
+beside the XLA cache: ``<cache dir>/shape_catalog.json``), ``CDT_SHAPE_OBSERVE=0``
 disables runtime observation.
 """
 

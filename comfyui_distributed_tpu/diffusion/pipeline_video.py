@@ -23,8 +23,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.jax_compat import shard_map
 
 from ..models.vae import AutoencoderKL
 from ..models.video_dit import VideoDiT, pad_frames_4n1

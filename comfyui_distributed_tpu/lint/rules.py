@@ -558,7 +558,7 @@ class TracedPurityRule:
     title = "impure call inside a jit/shard_map-traced function"
 
     # matched on the LAST dotted component so every spelling works:
-    # jax.jit, jit, jax_compat.shard_map, jax.experimental...shard_map
+    # jax.jit, jit, jax.shard_map, jax.experimental...shard_map
     TRACE_ENTRY_TAILS = ("jit", "pjit", "shard_map")
 
     IMPURE_EXACT = {

@@ -37,7 +37,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from flax import linen as nn
 
 from ..ops.attention import full_attention, joint_ring_attention

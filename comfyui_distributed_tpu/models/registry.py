@@ -37,6 +37,14 @@ class ModelPreset:
     # high-noise and low-noise expert DiTs (t2v 0.875, i2v 0.9); None =
     # single-expert
     moe_boundary: "float | None" = None
+    # dtype the denoiser's float weights are HELD in on the device (None =
+    # as initialised/converted, float32). The UNets compute in bfloat16
+    # whatever they are held in, and XLA hoists the per-use casts out of
+    # the sampler loop: held in float32, SDXL's segment program is 9.57 GiB
+    # of arguments plus 4.85 GiB of temporaries (the bf16 copy) on a 16 GB
+    # chip; held in bfloat16 it is 4.79 + 0.56 (docs/weights.md). The tiny
+    # test presets stay float32: their parity tolerances are float32's.
+    param_dtype: "str | None" = None
 
     @property
     def kind(self) -> str:
@@ -194,11 +202,12 @@ def _wan_mmdit_preset():
 
 PRESETS: dict[str, ModelPreset] = {
     "sdxl": ModelPreset("sdxl", UNetConfig.sdxl(), VAEConfig.sdxl(),
-                        TextEncoderConfig(), clip="sdxl"),
+                        TextEncoderConfig(), clip="sdxl",
+                        param_dtype="bfloat16"),
     "sd15": ModelPreset("sd15", UNetConfig.sd15(),
                         VAEConfig(scaling_factor=0.18215),
                         TextEncoderConfig(output_dim=768, pooled_dim=768),
-                        clip="clip-l"),
+                        clip="clip-l", param_dtype="bfloat16"),
     "tiny": ModelPreset("tiny", UNetConfig.tiny(), VAEConfig.tiny(),
                         TextEncoderConfig.tiny(), sample_hw=(8, 8)),
     "flux": _flux_preset(),
@@ -310,6 +319,7 @@ class ModelBundle:
                 preset.unet, k1,
                 sample_shape=(*preset.sample_hw, preset.unet.in_channels),
                 context_len=preset.text.max_len, abstract=abstract_core,
+                param_dtype=preset.param_dtype,
             )
             self.pipeline = Txt2ImgPipeline(model, params, vae)
         if checkpoint_dir is not None:
@@ -353,6 +363,13 @@ class ModelBundle:
         self.text_encoder._cdt_encoder_id = _encoder_identity(
             self.preset.name, stack or "text", self._weights_source,
             seed=self._init_seed)
+
+    def text_tower(self) -> str:
+        """Which stack encodes prompts: the preset's real one once a
+        checkpoint built it (``sdxl`` = CLIP-L + bigG, ...), else the
+        random-weight stand-in ``TextEncoder``."""
+        return (self.preset.clip if self.clip_stack is not None
+                else "stand-in")
 
     def weights_identity(self) -> str:
         """Provenance of this bundle's CORE (denoiser) weights — the
@@ -574,6 +591,14 @@ class ModelBundle:
             self.build_clip_stack()
         self._weights_source = Path(path)
         convert_checkpoint(path, self)
+        if self.preset.param_dtype is not None:
+            # the converter fills float32 on the host; hold what the
+            # preset says (an orbax restore already lands in the dtype of
+            # the tree it restores over)
+            from .unet import _cast_float_params
+
+            self._set_core_params(_cast_float_params(
+                self._core_params(), self.preset.param_dtype))
         self._stamp_text_encoder()
 
     def load_safetensors_moe(self, high: Path, low: Path) -> None:
@@ -701,6 +726,23 @@ class ModelBundle:
         self.pipeline.vae.dec_params = dec
 
 
+def _note_weights(name: str, bundle: "ModelBundle") -> None:
+    """Say what was just put on the device: bytes, the dtype the
+    denoiser is held in, and which text tower will serve prompts."""
+    from ..cluster.residency import bundle_bytes
+    from ..telemetry import enabled as _tm_enabled
+    from ..telemetry import metrics as _tm
+
+    nbytes = bundle_bytes(bundle)
+    dtype = str(jax.tree_util.tree_leaves(bundle._core_params())[0].dtype)
+    log(f"model {name!r}: {nbytes / 2**30:.2f} GiB of weights, denoiser "
+        f"held in {dtype}, text tower {bundle.text_tower()}")
+    if _tm_enabled():
+        _tm.MODEL_WEIGHT_BYTES.labels(
+            model=name, dtype=dtype,
+            text_tower=bundle.text_tower()).set(float(nbytes))
+
+
 class ModelRegistry:
     def __init__(self, checkpoint_root: Optional[Path] = None,
                  hbm_budget_bytes: Optional[int] = None):
@@ -738,7 +780,8 @@ class ModelRegistry:
                 if preset is None:
                     raise ValidationError(f"unknown model {name!r}; have {self.available()}")
                 ckpt = self.checkpoint_root / name if self.checkpoint_root else None
-                self._cache[name] = ModelBundle(preset, ckpt)
+                self._cache[name] = bundle = ModelBundle(preset, ckpt)
+                _note_weights(name, bundle)
             bundle = self._cache[name]
             if self.residency is not None:
                 try:

@@ -23,7 +23,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from flax import linen as nn
 
 from .dit import DiTConfig, DoubleBlock, Modulation, SingleBlock, _modulate

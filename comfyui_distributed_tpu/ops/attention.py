@@ -24,21 +24,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 from ..utils import constants
 
 
 def _pvary(x, axis):
-    """Mark ``x`` axis-varying (jax>=0.9 renamed pvary → pcast). On
-    0.4.x neither exists — there is no varying-manual-axes type system
-    to satisfy (shard_map runs with check_rep off, utils/jax_compat), so
-    the mark is a no-op."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis)
-    return x
+    """Mark ``x`` axis-varying for shard_map's varying-manual-axes check."""
+    return jax.lax.pcast(x, axis, to="varying")
 
 
 def _flash_min_seq() -> int:
@@ -67,15 +60,12 @@ def _flash_enabled(q_len: Optional[int] = None,
     flag = constants.FLASH_ATTENTION.get()
     if flag is not None:
         return flag
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
-    if not on_tpu:
+    from .flash_attention import _layout_packed, _on_tpu
+
+    if not _on_tpu():
         return False
     if q_len is None:
         return True
-    from .flash_attention import _layout_packed
 
     if (num_heads is not None and head_dim is not None
             and _layout_packed(num_heads, head_dim, Nq=q_len, Nk=kv_len)):
@@ -203,11 +193,9 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
         _note_selection(geometry, choice)
         return choice
     forced = flag is True
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        on_tpu = False
-    if not on_tpu and not forced:
+    from .flash_attention import _on_tpu
+
+    if not forced and not _on_tpu():
         # off-accelerator serving always takes XLA (interpret-mode pallas
         # is a test vehicle, not a CPU fallback); not recorded — CPU
         # hosts would flood the selection log with xla lines

@@ -36,7 +36,7 @@ legality-ranked policy the shipped table was baked with — same
 geometry + same table ⇒ same choice, always.
 
 Knobs: ``CDT_ATTN_TABLE`` (local overlay path; default
-``<CDT_COMPILE_CACHE_DIR>/attn_tuning.json``), ``CDT_ATTN_TUNE=0``
+beside the XLA cache: ``<cache dir>/attn_tuning.json``), ``CDT_ATTN_TUNE=0``
 disables table lookups AND sweeps (env knobs and measured defaults
 rule, the pre-tuning-table behavior).
 """
@@ -372,7 +372,7 @@ def lookup(num_heads: int, head_dim: int, q_len: int, kv_len: int,
 # --- sweeping ----------------------------------------------------------------
 
 BLOCK_Q_CANDIDATES = (128, 256, 512)
-BLOCK_K_CANDIDATES = (128, 256, 512, 1024)
+BLOCK_K_CANDIDATES = (128, 256, 512)     # flash_attention._MAX_BLOCK_K
 
 # engagement floors measured r04 (docs/roofline.md finding 1a): below
 # them XLA's fused lowering wins and the sweep doesn't bother timing
@@ -528,13 +528,17 @@ class SweepEntry:
     outcome: str                  # swept | dry | cached | error
     seconds: float = 0.0
     detail: str = ""
+    # candidates the legality model offered and the compiler (or the run)
+    # refused, with its message: a refusal is a fault in the model
+    refused: list = dataclasses.field(default_factory=list)
 
     def to_dict(self) -> dict:
         return {"geometry": self.key.key_str(),
                 "choice": self.choice.to_dict() if self.choice else None,
                 "outcome": self.outcome,
                 "seconds": round(self.seconds, 3),
-                "detail": self.detail}
+                "detail": self.detail,
+                "refused": self.refused}
 
 
 def sweep_geometry(key: GeometryKey, mode: str = "auto",
@@ -544,7 +548,9 @@ def sweep_geometry(key: GeometryKey, mode: str = "auto",
     ``mode="timed"`` measures every candidate on the live backend (TPU);
     ``mode="dry"`` resolves the deterministic policy (CPU-safe, what the
     shipped table was baked with); ``mode="auto"`` picks timed on TPU,
-    dry elsewhere. Per-geometry failures are recorded, never raised."""
+    dry elsewhere. Per-geometry failures are recorded, never raised; a
+    candidate that fails is logged with the compiler's message and kept
+    in ``SweepEntry.refused``."""
     from .flash_attention import _on_tpu
 
     if mode == "auto":
@@ -556,22 +562,26 @@ def sweep_geometry(key: GeometryKey, mode: str = "auto",
             return SweepEntry(key, choice, "dry",
                               time.perf_counter() - t0)
         timings = []
+        refused = []
         for cand in candidates_for(key):
             try:
                 timings.append((_time_candidate(key, cand, runs), cand))
             except Exception as e:  # noqa: BLE001 — candidate isolation
-                debug_log(f"autotune: candidate {cand.tier} "
-                          f"{cand.block_q}/{cand.block_k} failed on "
-                          f"{key.key_str()}: {e}")
+                log(f"WARNING autotune: candidate {cand.tier} "
+                    f"{cand.block_q}/{cand.block_k} refused on "
+                    f"{key.key_str()}: {e}")
+                refused.append({**cand.to_dict(), "error": str(e)})
         if not timings:
             return SweepEntry(key, None, "error",
                               time.perf_counter() - t0,
-                              detail="every candidate failed")
+                              detail="every candidate failed",
+                              refused=refused)
         best_t, best = min(timings, key=lambda tc: tc[0])
         best = dataclasses.replace(
             best, reason=f"timed sweep: {best_t * 1e6:.0f} us/op over "
                          f"{len(timings)} candidates")
-        return SweepEntry(key, best, "swept", time.perf_counter() - t0)
+        return SweepEntry(key, best, "swept", time.perf_counter() - t0,
+                          refused=refused)
     except Exception as e:  # noqa: BLE001 — sweeps must never sink warmup
         return SweepEntry(key, None, "error", time.perf_counter() - t0,
                           detail=str(e))

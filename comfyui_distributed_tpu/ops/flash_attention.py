@@ -289,20 +289,23 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
+def _platform() -> str:
+    """Platform of the default backend. A backend that cannot initialise
+    raises here: a missing chip is an error at the call site, never a
+    quiet switch to interpret mode or to the XLA tier. The kernel
+    dispatcher (``ops/attention.py``) reads the platform through this one
+    function, which is also where an off-chip compile test steers it."""
+    return jax.devices()[0].platform
+
+
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    return _platform() == "tpu"
 
 
 def _in_manual_trace(x) -> bool:
     """True when tracing inside ``shard_map`` (the aval carries varying
     manual axes)."""
-    try:
-        return bool(getattr(jax.typeof(x), "vma", None))
-    except Exception:  # noqa: BLE001 — typeof unavailable on some inputs
-        return False
+    return bool(jax.typeof(x).vma)
 
 
 def _flash_emulated(q, k, v, block_q: int, block_k: int):
@@ -363,12 +366,8 @@ def _pad_and_prepare(q, k, v, block_q: int, block_k: int):
     vp = _pad_to(v, 1, block_k)
     precision = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
                  else jax.lax.Precision.DEFAULT)
-    try:
-        vma = getattr(jax.typeof(qp), "vma", None)
-    except Exception:  # noqa: BLE001 — typeof unavailable outside tracing
-        vma = None
-    out_sds = (jax.ShapeDtypeStruct(qp.shape, q.dtype, vma=vma)
-               if vma else jax.ShapeDtypeStruct(qp.shape, q.dtype))
+    out_sds = jax.ShapeDtypeStruct(qp.shape, q.dtype,
+                                   vma=jax.typeof(qp).vma)
     return qp, kp, vp, precision, out_sds
 
 
@@ -466,47 +465,74 @@ def _flash_mha_packed(q, k, v, num_heads: int, block_q: int, block_k: int,
 # block pair, and the autotune sweep walks the whole feasible set.
 _PACKED_MAX_HD = 2048
 
-# scoped-VMEM budget the working-set model checks against. The r05 WAN
-# probe anchors it: 1024 K-blocks at H·D=1536 died at 25.09 MB scoped
-# vs the chip's 16 MB, 512 K-blocks fit (docs/roofline.md).
+# scoped-VMEM limit of one kernel on the chip, and what the working-set
+# models below are checked against. Calibrated against the v5e compiler
+# itself (docs/kernels.md, "VMEM model"; tests/test_chip_compile.py asks
+# it again on every run): the compiler's own count has two parts. The
+# tiles and scratch the call declares, which the first two terms of each
+# model reproduce to the byte; and scratch for the values the kernel
+# BODY holds, which the declaration does not show and which the models
+# estimate from the body's largest live values.
 _VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 _MIN_BLOCK_Q = 64     # shrink floors: below these tiles the grid is all
 _MIN_BLOCK_K = 128    # overhead (one lane tile / 8 sublane tiles)
+# K tiles wider than this are not offered: at 1024 rows the compiler's
+# body scratch alone ran from 2 MB (H·D=640) to 19 MB (H·D=3072) — on no
+# probed width did a 1024-row K tile fit where a 512-row one did not.
+_MAX_BLOCK_K = 512
+
+
+def _logits_bytes(block_q: int, block_k: int) -> int:
+    """Per-head values of the shared accumulation step: f32 logits, f32
+    probabilities and their operand-dtype cast for the PV matmul, reused
+    from head to head."""
+    return block_q * block_k * (4 + 4 + 2)
 
 
 def _packed_vmem_bytes(hd: int, block_q: int, block_k: int,
                        itemsize: int) -> int:
-    """Working-set estimate of one packed-kernel grid step: double-
-    buffered q/k/v/out tiles in the operand dtype plus the f32 output
-    accumulator and the two lane-replicated m/l scratches."""
+    """Scoped VMEM of one packed-kernel grid step: double-buffered
+    q/k/v/out tiles in the operand dtype, the f32 output accumulator and
+    the two lane-replicated m/l scratches, plus the body's logits."""
     io = 2 * (2 * block_q * hd + 2 * block_k * hd) * itemsize
     scratch = block_q * hd * 4 + 2 * block_q * _LANES * 4
-    return io + scratch
+    return io + scratch + _logits_bytes(block_q, block_k)
 
 
 def _fused_vmem_bytes(c: int, hd: int, block_q: int, block_k: int,
                       itemsize: int) -> int:
-    """Working set of one fused-kernel grid step: double-buffered x
+    """Scoped VMEM of one fused-kernel grid step: double-buffered x
     row-tiles ([block, C]) and the out tile, the three resident [C, H·D]
-    projection weights (constant index map — fetched once, not double-
-    buffered), the projected-q scratch (operand dtype) and the f32
-    accumulator + m/l scratches."""
+    projection weights (constant index map — the compiler does keep one
+    buffer of each), the projected-q scratch (operand dtype) and the f32
+    accumulator + m/l scratches; plus what the body holds — the f32
+    results of the three in-kernel projections ([BQ, H·D] once per grid
+    row, two [BK, H·D] per step), the k/v casts back to the operand
+    dtype, and the logits. The body term is what the model used to leave
+    out: at C=1280 it is 5.8 MB at 256/256 blocks, and the compiler
+    refused that call (20.26 MB against the 16 MB limit) while the model
+    said 15.25."""
     io = 2 * (block_q * c + block_k * c + block_q * hd) * itemsize
     weights = 3 * c * hd * itemsize
     scratch = (block_q * hd * itemsize          # projected q
                + block_q * hd * 4               # f32 accumulator
                + 2 * block_q * _LANES * 4)      # m / l
-    return io + weights + scratch
+    body = (block_q * hd * 4                    # q projection, f32
+            + 2 * block_k * hd * 4              # k, v projections, f32
+            + 2 * block_k * hd * itemsize       # k, v casts
+            + _logits_bytes(block_q, block_k))
+    return io + weights + scratch + body
 
 
 def _shrink_blocks_for_vmem(bytes_fn, block_q: int, block_k: int
                             ) -> Optional[tuple[int, int]]:
     """Halve block_k (first — K tiles dominate the working set), then
-    block_q, until ``bytes_fn(bq, bk)`` fits ``_VMEM_BUDGET_BYTES``;
-    None when even the floor tiles blow the budget. Deterministic: the
-    same request always shrinks to the same blocks."""
+    block_q, until ``bytes_fn(bq, bk)`` fits ``_VMEM_BUDGET_BYTES`` with
+    a K tile no wider than ``_MAX_BLOCK_K``; None when even the floor
+    tiles blow the budget. Deterministic: the same request always
+    shrinks to the same blocks."""
     bq, bk = block_q, block_k
-    while bytes_fn(bq, bk) > _VMEM_BUDGET_BYTES:
+    while bk > _MAX_BLOCK_K or bytes_fn(bq, bk) > _VMEM_BUDGET_BYTES:
         if bk > _MIN_BLOCK_K:
             bk //= 2
         elif bq > _MIN_BLOCK_Q:
@@ -643,8 +669,9 @@ def flash_attention(
     """Exact bidirectional attention, [B,N,H,D] layout (matching
     ``ops.attention.full_attention``), computed by the pallas kernel.
 
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter
-    elsewhere (CPU tests run the same kernel code path).
+    ``interpret=None`` compiles the kernel, except where the platform is
+    positively ``cpu``: there the Pallas interpreter runs the same kernel
+    code (the CPU tests).
 
     ``block_q``/``block_k=None`` resolve to ``CDT_FLASH_BLOCK_Q``/
     ``CDT_FLASH_BLOCK_K`` (defaults 256/512, measured r04; the r05 WAN
@@ -664,7 +691,7 @@ def flash_attention(
     env var remains the global knob.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _platform() == "cpu"
     block_q, block_k = resolve_flash_blocks(block_q, block_k)
     B, Nq, H, D = q.shape
     _, Nk, _, _ = k.shape
@@ -779,13 +806,8 @@ def _flash_mha_fused(x, wq, wk, wv, num_heads: int, block_q: int,
     nqb = xq.shape[1] // block_q
     nkb = xkv.shape[1] // block_k
 
-    try:
-        vma = getattr(jax.typeof(xq), "vma", None)
-    except Exception:  # noqa: BLE001 — typeof unavailable outside tracing
-        vma = None
-    out_shape = (B, xq.shape[1], HD)
-    out_sds = (jax.ShapeDtypeStruct(out_shape, x.dtype, vma=vma)
-               if vma else jax.ShapeDtypeStruct(out_shape, x.dtype))
+    out_sds = jax.ShapeDtypeStruct((B, xq.shape[1], HD), x.dtype,
+                                   vma=jax.typeof(xq).vma)
 
     kernel = functools.partial(
         _flash_kernel_fused, kv_len=N, block_k=block_k, num_k_blocks=nkb,
@@ -838,7 +860,7 @@ def fused_qkv_attention(
     mode the requested blocks run regardless, keeping every geometry
     CPU-testable."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _platform() == "cpu"
     block_q, block_k = resolve_flash_blocks(block_q, block_k)
     B, N, C = x.shape
     HD = wq.shape[-1]

@@ -80,8 +80,8 @@ def ensure_virtual_devices(n: Optional[int] = None) -> Optional[int]:
             "imports jax.")
     os.environ["XLA_FLAGS"] = (
         f"{flags} --xla_force_host_platform_device_count={n}".strip())
-    # virtual devices exist only on the host platform; an accelerator
-    # plugin registering first would shadow them
+    # virtual devices exist only on the host platform; on a host with a
+    # chip the default backend would be the chip
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     log(f"virtual mesh: {n} host devices "
         f"(--xla_force_host_platform_device_count)")

@@ -17,7 +17,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import axis_size as _axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size as _axis_size
 
 from ..utils import constants
 
@@ -53,9 +54,7 @@ def mean_over(x: jax.Array, axis: str) -> jax.Array:
     from .overlap import overlap_enabled
 
     if overlap_enabled():
-        from ..utils.jax_compat import axis_size
-
-        return sum_over(x, axis) / axis_size(axis)
+        return sum_over(x, axis) / _axis_size(axis)
     return jax.lax.pmean(x, axis)
 
 

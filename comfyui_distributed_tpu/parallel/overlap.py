@@ -29,9 +29,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size as _axis_size
 
 from ..utils import constants
-from ..utils.jax_compat import axis_size as _axis_size
 
 
 def overlap_enabled() -> bool:

@@ -128,8 +128,31 @@ FAULTS_INJECTED = REGISTRY.counter(
 
 COMPILE_CACHE_ENABLED = REGISTRY.gauge(
     "cdt_compile_cache_enabled",
-    "1 when the persistent XLA compilation cache is active, 0 when "
-    "disabled or unavailable (the reason is logged at enable time).")
+    "1 once the persistent XLA compilation cache is on (the directory is "
+    "logged at enable time and reported by /distributed/system_info).")
+
+COMPILE_CACHE_REQUESTS = REGISTRY.counter(
+    "cdt_compile_cache_requests_total",
+    "Programs looked up in the persistent compilation cache, by outcome: "
+    "hit (loaded from disk) or miss (compiled, then written). Counted by "
+    "jax itself (jax.monitoring); programs it never writes — too quick "
+    "to compile, or carrying a host callback — show as neither.",
+    ("outcome",))
+
+XLA_COMPILE_SECONDS = REGISTRY.histogram(
+    "cdt_xla_compile_seconds",
+    "Wall-clock jax spent getting one executable, compiled or loaded "
+    "from the persistent cache (jax.monitoring backend_compile_duration). "
+    "Its count is the process's compile count; its sum is the compile "
+    "phase a warm cache shortens.",
+    buckets=COMPILE_BUCKETS)
+
+MODEL_WEIGHT_BYTES = REGISTRY.gauge(
+    "cdt_model_weight_bytes",
+    "Parameter bytes of a model bundle the registry built (denoiser, "
+    "both VAE halves, text tower), labelled with the dtype the denoiser "
+    "is held in and the text tower serving prompts.",
+    ("model", "dtype", "text_tower"))
 
 WARMUP_PROGRAMS = REGISTRY.counter(
     "cdt_warmup_programs_total",

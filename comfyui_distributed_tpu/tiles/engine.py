@@ -23,8 +23,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.jax_compat import shard_map
 
 from ..diffusion.guidance import cfg_denoiser
 from ..diffusion.pipeline import (GenerationSpec, Txt2ImgPipeline,
@@ -321,11 +321,8 @@ class TileUpscaler:
         env = TILES_PER_DEVICE.get()
         if env > 0:
             return env
-        try:
-            if jax.devices()[0].platform == "cpu":
-                return 1     # tests/tiny stacks: don't pad tiny jobs 8-wide
-        except RuntimeError:
-            return 1
+        if jax.devices()[0].platform == "cpu":
+            return 1     # tests/tiny stacks: don't pad tiny jobs 8-wide
         area = tile_w * tile_h
         if area <= 512 * 512:
             return 8
@@ -459,12 +456,11 @@ class TileUpscaler:
             Ranges wider than this host's chunk loop over sub-chunks, so
             a farm task sized by the MASTER's chunk still runs correctly
             on a worker whose own chunk differs (fewer local devices, a
-            different ``CDT_TILES_PER_DEVICE``, a CPU fallback host) —
-            chunk mismatch costs only padding, never correctness. All
-            sub-chunks are dispatched before any result is fetched: JAX
-            dispatch is async, so chunk i's device→host transfer
-            overlaps chunk i+1's compute (the fetch rides a slow link on
-            tunneled hosts)."""
+            different ``CDT_TILES_PER_DEVICE``) — chunk mismatch costs
+            only padding, never correctness. All sub-chunks are
+            dispatched before any result is fetched: JAX dispatch is
+            async, so chunk i's device→host transfer overlaps chunk
+            i+1's compute."""
             import numpy as np
 
             if start >= end:

@@ -19,8 +19,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.jax_compat import shard_map
 
 from ..diffusion.pipeline import Txt2ImgPipeline
 from ..ops.blend import composite_tiles, extract_tiles, feather_mask

@@ -802,11 +802,6 @@ COLLECTIVE_QUANT = knob_enum(
     doc="docs/parallelism.md")
 
 # --- compile cache / shape catalog / warmup (PR 4) --------------------------
-COMPILE_CACHE_DIR = knob_str(
-    "CDT_COMPILE_CACHE_DIR", None, "warmup",
-    "Persistent XLA compile cache directory (empty string = caching "
-    "off; unset = the shared default).", doc="docs/deployment.md",
-    keep_empty=True)
 SHAPE_CATALOG = knob_str(
     "CDT_SHAPE_CATALOG", None, "warmup",
     "Shape-catalog JSON path (default: next to the XLA cache).",
@@ -954,23 +949,10 @@ TEST_WATCHDOG_S = knob_float(
     "Per-test watchdog: dump all thread stacks (faulthandler) after this "
     "many seconds so a deadlock leaves evidence (0 = off).",
     doc="docs/lint.md")
-TEST_XLA_CACHE = knob_str(
-    "CDT_TEST_XLA_CACHE", "/tmp/cdt_xla_cache_tests", "testing",
-    "Persistent XLA compile cache for the test suite.")
 CHAOS_SEED = knob_int(
     "CDT_CHAOS_SEED", 42, "testing",
     "Fixed seed for the chaos suite so failures replay exactly.",
     doc="docs/resilience.md")
-BENCH_PREFLIGHT_TIMEOUT_S = knob_float(
-    "CDT_BENCH_PREFLIGHT_TIMEOUT_S", 120.0, "bench",
-    "Budget for bench.py's subprocess TPU preflight probe (seconds).")
-BENCH_BUDGET_S = knob_float(
-    "CDT_BENCH_BUDGET_S", 2400.0, "bench",
-    "Total wall-clock budget for bench.py's accelerator attempts "
-    "(seconds).")
-BENCH_ATTEMPT_TIMEOUT_S = knob_float(
-    "CDT_BENCH_ATTEMPT_TIMEOUT_S", 1800.0, "bench",
-    "Per-attempt subprocess timeout for bench.py (seconds).")
 PROBE_RUNS = knob_int(
     "CDT_PROBE_RUNS", None, "bench",
     "Override the timed-run count in scripts/mfu_probe.py.")

@@ -1,28 +1,28 @@
 """Deadline-guarded device-backend queries for the control plane.
 
-The r04 chip outage exposed a failure mode the reference never has
-(CUDA is local; this runtime may sit behind a network-attached device
-service): when the accelerator backend goes unreachable,
-``jax.devices()`` / per-device ``memory_stats()`` RPCs block
-**indefinitely**, and any aiohttp route that calls them synchronously
-freezes the whole event loop — including ``/distributed/health``, the
-exact endpoint peers use to decide this host is dead. Reference
-analogue for the *shape* of the guard: its worker probes use bounded
-HTTP timeouts everywhere (``utils/network.py``); the device backend
-deserves the same discipline.
+``jax.devices()`` and per-device ``memory_stats()`` are synchronous calls
+into the device runtime. The chip is local and owned by this process, so
+they normally answer at once — but the first one initialises the backend,
+and a runtime in trouble can block. An aiohttp route that makes such a
+call inline freezes the whole event loop, including
+``/distributed/health``, the exact endpoint peers use to decide this host
+is dead. Reference analogue for the *shape* of the guard: its worker
+probes use bounded HTTP timeouts everywhere (``utils/network.py``); the
+device backend gets the same discipline. The info routes turn a stall or
+a failure into a 503 (``api/info_routes.py``).
 
-Leak discipline: a stalled RPC can never be cancelled, so each timeout
-permanently occupies its thread for the outage's duration. Queries run
-on dedicated **daemon** threads (never the shared default executor —
-worker launch, tunnel setup, and media hashing live there) behind a
-2-permit semaphore: at most TWO threads can ever be stuck, further
-calls fall back immediately, and interpreter shutdown is never blocked.
-A cooldown gate additionally short-circuits attempts after a stall.
+Leak discipline: a stalled call can never be cancelled, so each timeout
+occupies its thread for as long as the stall lasts. Queries run on
+dedicated **daemon** threads (never the shared default executor — worker
+launch, the Cloudflare tunnel set-up, and media hashing live there)
+behind a 2-permit semaphore: at most TWO threads can ever be stuck,
+further calls fall back immediately, and interpreter shutdown is never
+blocked. A cooldown gate additionally short-circuits attempts after a
+stall.
 
 Exceptions are NOT conflated with stalls: a query that *fails fast*
-(e.g. a misconfigured backend raising at init) propagates to the
-caller — the app-level error middleware reports the real error — and
-does not close the gate.
+(e.g. a backend raising at init) propagates to the caller and does not
+close the gate.
 """
 
 from __future__ import annotations
@@ -62,7 +62,7 @@ async def deadline_call(fn: Callable[[], Any], timeout_s: float = 5.0,
     with a deadline.
 
     - timeout → log, close the gate for ``cooldown_s``, return
-      ``fallback`` (the thread stays parked until the RPC dies);
+      ``fallback`` (the thread stays parked until the call returns);
     - gate closed or both leak permits consumed → ``fallback``
       immediately;
     - ``fn`` raises → the exception PROPAGATES (fast failures carry
@@ -104,5 +104,5 @@ async def deadline_call(fn: Callable[[], Any], timeout_s: float = 5.0,
     except asyncio.TimeoutError:
         _note_stall(cooldown_s)
         log(f"device backend unresponsive (> {timeout_s:.0f}s) — "
-            f"degrading device queries for {cooldown_s:.0f}s")
+            f"refusing device queries for {cooldown_s:.0f}s")
         return fallback

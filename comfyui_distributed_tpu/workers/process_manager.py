@@ -61,6 +61,7 @@ class WorkerProcessManager:
 
     def launch_worker(self, worker_id: str) -> ManagedProcess:
         self.reap_dead()
+        _refuse_if_this_process_holds_chips(worker_id)
         if worker_id in self._managed:
             raise ProcessError(f"worker {worker_id!r} already running "
                                f"(pid {self._managed[worker_id].pid})")
@@ -134,6 +135,27 @@ class WorkerProcessManager:
     def cleanup_all(self) -> None:
         for wid in list(self._managed):
             self.stop_worker(wid)
+
+
+def _refuse_if_this_process_holds_chips(worker_id: str) -> None:
+    """One process per chip (docs/deployment.md). A TPU belongs to the
+    process that opened it, and the master opens every chip of its host
+    at boot: a second controller started here would fail to initialise
+    its backend, or hang trying. On one host the chips are mesh slots of
+    the one controller, not workers. (On the CPU backend of the test
+    suite any number of processes share the host, and local workers
+    launch as before.)"""
+    import jax
+
+    device = jax.devices()[0]
+    if device.platform != "cpu":
+        raise ProcessError(
+            f"cannot launch local worker {worker_id!r}: this process "
+            f"holds the host's {len(jax.devices())} {device.platform} "
+            f"device(s) ({device.device_kind}), and a chip belongs to one "
+            "process. On one host the master drives every chip through "
+            "its mesh (set mesh.shape); run workers on OTHER hosts and "
+            "configure them as type 'remote'.")
 
 
 _manager: Optional[WorkerProcessManager] = None

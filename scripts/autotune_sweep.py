@@ -65,10 +65,7 @@ def main() -> int:
     if not cli.dry_run:
         import jax
 
-        try:
-            platform = jax.devices()[0].platform
-        except RuntimeError:
-            platform = "none"
+        platform = jax.devices()[0].platform
         if platform != "tpu":
             # a timed sweep off-TPU would "measure" every pallas
             # candidate as a lowering failure and bake an all-xla table

@@ -130,7 +130,7 @@ env JAX_PLATFORMS=cpu CDT_CHAOS_SEED="${SEED}" CDT_LOCK_ORDER=1 \
 echo "[chaos] stage 7b: preempt load smoke (interactive p99 under a long job)"
 env JAX_PLATFORMS=cpu PYTHONPATH="$(pwd)" \
     CDT_CONFIG_PATH="$(mktemp -d)/config.json" \
-    CDT_COMPILE_CACHE_DIR="${CDT_COMPILE_CACHE_DIR:-/tmp/cdt_xla_cache_chaos}" \
+    JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-/tmp/cdt_xla_cache_chaos}" \
     python scripts/load_smoke.py --in-process --preempt --n 6 \
     --concurrency 4 --seed "${SEED}"
 
@@ -151,7 +151,7 @@ env JAX_PLATFORMS=cpu CDT_CHAOS_SEED="${SEED}" CDT_LOCK_ORDER=1 \
 echo "[chaos] stage 8b: stages load smoke (three pools, bounded backlogs)"
 env JAX_PLATFORMS=cpu PYTHONPATH="$(pwd)" \
     CDT_CONFIG_PATH="$(mktemp -d)/config.json" \
-    CDT_COMPILE_CACHE_DIR="${CDT_COMPILE_CACHE_DIR:-/tmp/cdt_xla_cache_chaos}" \
+    JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-/tmp/cdt_xla_cache_chaos}" \
     python scripts/load_smoke.py --in-process --stages --n 12 \
     --concurrency 8 --seed "${SEED}"
 
@@ -173,7 +173,7 @@ env JAX_PLATFORMS=cpu CDT_CHAOS_SEED="${SEED}" CDT_LOCK_ORDER=1 \
 echo "[chaos] stage 9b: fleet load smoke (cross-worker hit rate beats per-host)"
 env JAX_PLATFORMS=cpu PYTHONPATH="$(pwd)" \
     CDT_CONFIG_PATH="$(mktemp -d)/config.json" \
-    CDT_COMPILE_CACHE_DIR="${CDT_COMPILE_CACHE_DIR:-/tmp/cdt_xla_cache_chaos}" \
+    JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-/tmp/cdt_xla_cache_chaos}" \
     python scripts/load_smoke.py --fleet --fleet-n 4 \
     --concurrency 8 --seed "${SEED}"
 
@@ -191,13 +191,13 @@ env JAX_PLATFORMS=cpu PYTHONPATH="$(pwd)" \
 echo "[chaos] stage 10: loop-stall sanitizer (stage-split + fleet smokes armed)"
 env JAX_PLATFORMS=cpu PYTHONPATH="$(pwd)" \
     CDT_CONFIG_PATH="$(mktemp -d)/config.json" \
-    CDT_COMPILE_CACHE_DIR="${CDT_COMPILE_CACHE_DIR:-/tmp/cdt_xla_cache_chaos}" \
+    JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-/tmp/cdt_xla_cache_chaos}" \
     CDT_LOOP_STALL=1 CDT_LOOP_STALL_MS="${CDT_LOOP_STALL_MS:-250}" \
     python scripts/load_smoke.py --in-process --stages --n 12 \
     --concurrency 8 --seed "${SEED}"
 env JAX_PLATFORMS=cpu PYTHONPATH="$(pwd)" \
     CDT_CONFIG_PATH="$(mktemp -d)/config.json" \
-    CDT_COMPILE_CACHE_DIR="${CDT_COMPILE_CACHE_DIR:-/tmp/cdt_xla_cache_chaos}" \
+    JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-/tmp/cdt_xla_cache_chaos}" \
     CDT_LOOP_STALL=1 CDT_LOOP_STALL_MS="${CDT_LOOP_STALL_MS:-250}" \
     python scripts/load_smoke.py --fleet --fleet-n 4 \
     --concurrency 8 --seed "${SEED}"

@@ -10,8 +10,8 @@ delete the device array) and prints host RSS growth per variant:
     variant gc       — + gc.collect() every K transfers
     variant refresh  — + drop python refs immediately
 
-If RSS grows linearly under 'none' but not 'gc', the tunnel client frees
-its host mirror only at gc time → offload.py needs periodic collection.
+If RSS grows linearly under 'none' but not 'gc', the transfer client
+frees its host copy only at gc time → offload.py needs periodic collection.
 """
 
 from __future__ import annotations

@@ -8,7 +8,7 @@ image starts with ``cache_hit`` for the whole catalog — time-to-ready
 drops from full-compile cost to cache-load cost.
 
     # bake the shipped-workflow catalog for the tiny smoke models
-    CDT_COMPILE_CACHE_DIR=/image/xla python scripts/warmup_catalog.py \
+    JAX_COMPILATION_CACHE_DIR=/image/xla python scripts/warmup_catalog.py \
         --models tiny,flux-tiny
 
     # add explicit shapes beyond the workflow catalog

@@ -245,12 +245,12 @@ class TestRoutes:
                 assert net["recommended_ip"]
         run(body())
 
-    def test_device_routes_degrade_when_backend_hangs(self, tmp_config,
-                                                      monkeypatch):
-        """r04: a dead network-attached device backend makes
-        jax.devices()/memory_stats() block forever; the info routes must
-        answer a degraded payload within the deadline instead of
-        freezing the event loop (utils/deadline.py)."""
+    def test_device_routes_503_when_backend_hangs(self, tmp_config,
+                                                  monkeypatch):
+        """A device runtime that blocks jax.devices()/memory_stats() must
+        not freeze the event loop: the info routes answer 503 within the
+        deadline (utils/deadline.py), and no 200 payload stands in for a
+        census that was not taken."""
         import threading
         import time as _time
 
@@ -267,25 +267,43 @@ class TestRoutes:
                 lambda self: release.wait(30))     # simulated hang
             async with client:
                 t0 = _time.monotonic()
-                info = await (await client.get(
-                    "/distributed/system_info")).json()
+                resp = await client.get("/distributed/system_info")
                 assert _time.monotonic() - t0 < 10
-                assert info["devices"][0]["error"]
-                assert "machine_id" in info        # host facts survive
-                # gate now open: subsequent calls short-circuit fast
+                assert resp.status == 503
+                assert "device backend" in (await resp.json())["error"]
+                # gate now closed: subsequent calls short-circuit fast
                 t0 = _time.monotonic()
-                net = await (await client.get(
-                    "/distributed/network_info")).json()
+                resp = await client.get("/distributed/network_info")
                 assert _time.monotonic() - t0 < 2
-                assert net["devices"][0]["error"]
-                res = await (await client.get(
-                    "/distributed/memory_stats")).json()
-                assert res["devices"][0]["error"]
+                assert resp.status == 503
+                resp = await client.get("/distributed/memory_stats")
+                assert resp.status == 503
         try:
             run(body())
         finally:
             release.set()
             deadline.reset_gate()
+
+    def test_system_info_503_when_backend_fails_to_initialise(
+            self, tmp_config, monkeypatch):
+        """A backend that raises at init (no chip) is a 503 too, with
+        the runtime's own message — not a 200 and not a bare 500."""
+        from comfyui_distributed_tpu.utils import deadline
+
+        deadline.reset_gate()
+
+        def no_backend(self):
+            raise RuntimeError("Unable to initialize backend 'tpu'")
+
+        async def body():
+            controller, client = make_client()
+            monkeypatch.setattr(type(controller), "system_info", no_backend)
+            async with client:
+                resp = await client.get("/distributed/system_info")
+                assert resp.status == 503
+                assert "Unable to initialize" in (await resp.json())["error"]
+        run(body())
+        assert deadline.gate_open()            # a fast failure is no stall
 
     def test_deadline_call_semantics(self):
         """Unit contract of utils/deadline.deadline_call: fast failures

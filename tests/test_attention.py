@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from comfyui_distributed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 from comfyui_distributed_tpu.ops.attention import (
     full_attention,

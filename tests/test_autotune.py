@@ -268,13 +268,15 @@ class TestDispatcherPrecedence:
         (review finding: `>= (128, 256)` compared lexicographically)."""
         from comfyui_distributed_tpu.ops import flash_attention as fa
 
-        # H·D=1344 (H=21 illegal: 21·64=1344 % 128 != 0)... use a direct
-        # probe of the gate instead: feed the policy a geometry whose
-        # fused feasibility lands at a K floor and assert it avoids fused
-        key = geom(h=12, d=128, q=16384, kv=16384)   # WAN: fused (64,128)
-        assert fa._fused_feasible(1536, 12, 128) == (64, 128)
+        # feed the policy a geometry whose fused feasibility lands at a K
+        # floor and assert it avoids fused: SDXL's 32² level, where the
+        # three resident C=1280 weights leave room for 128/128 only
+        key = geom(h=20, d=64, q=1024, kv=1024)
+        assert fa._fused_feasible(1280, 20, 64) == (128, 128)
         choice = autotune.resolve_policy_choice(key)
         assert choice.tier != "fused"
+        # WAN's C=1536 weights leave room for no fused tile at all
+        assert fa._fused_feasible(1536, 12, 128) is None
 
     def test_prefer_flash_ignores_table_xla(self, on_tpu):
         """The memory-constrained caller's guarantee survives a

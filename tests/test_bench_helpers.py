@@ -1,6 +1,8 @@
 """Unit tests for bench.py's shared offload-bench helpers (r04: the
 leak budget and two-point extrapolation previously lived as diverging
-copies in the flux and wan14b benches) and the server compile cache."""
+copies in the flux and wan14b benches), the peaks table, and the rule
+that a benchmark without a chip is an error. The compile-cache cases
+live in tests/test_chip_smoke.py."""
 
 import importlib.util
 from pathlib import Path
@@ -118,7 +120,7 @@ class TestWorkloadsRunOnCpu:
     @pytest.mark.parametrize("workload", sorted(bench._WORKLOADS))
     def test_workload_emits_valid_result(self, workload, monkeypatch):
         monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        result = bench._workload_fn(workload)(2, 1, True)
+        result = bench._workload_fn(workload)(2, 1)
         assert result["metric"]
         assert result["value"] > 0
         assert result["unit"]
@@ -137,143 +139,28 @@ class TestWorkloadsRunOnCpu:
         assert choices == set(bench._WORKLOADS)
 
 
-class TestFailFast:
-    """BENCH_r05 rc=124 root cause: the watchdog re-ran a deterministic
-    backend-init crash for the whole 2400 s budget, then timed out with no
-    JSON line. Repeated identical failures are now terminal, and the CPU
-    fallback is capped at tiny scale."""
+class TestNoChipIsAnError:
+    """bench.py is one process on one backend: with no TPU it exits
+    non-zero and prints no result, unless the toy-shape CPU dry run was
+    asked for with JAX_PLATFORMS=cpu (what TestWorkloadsRunOnCpu does).
+    The seven rounds of ``*_cpu`` "results" came from a fallback."""
 
-    def test_identical_consecutive_failures_are_terminal(self):
-        assert not bench._is_terminal_failure([])
-        assert not bench._is_terminal_failure(["RuntimeError: init"])
-        assert not bench._is_terminal_failure(
-            ["RuntimeError: a", "RuntimeError: b"])   # flake, keep trying
-        assert bench._is_terminal_failure(
-            ["RuntimeError: init", "RuntimeError: init"])
-        assert bench._is_terminal_failure(
-            ["timeout", "RuntimeError: init", "RuntimeError: init"])
-        # empty tails (no stderr) never match — nothing to compare
-        assert not bench._is_terminal_failure(["", ""])
-        # watchdog timeouts carry a constant message by construction — a
-        # hung tunnel is transient flake, never terminal
-        assert not bench._is_terminal_failure(
-            ["attempt timed out after 300s", "attempt timed out after 300s"])
+    def test_exits_nonzero_when_platform_is_not_tpu(self, monkeypatch,
+                                                    capsys):
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr("sys.argv", ["bench.py", "--steps", "2"])
+        monkeypatch.setitem(bench._WORKLOADS, "txt2img",
+                            lambda *a: pytest.fail("workload ran"))
+        with pytest.raises(SystemExit) as exc:
+            bench.main()            # the suite's backend is the CPU
+        assert exc.value.code not in (0, None)
+        assert "no TPU" in str(exc.value.code)
+        assert capsys.readouterr().out == ""     # no result line
 
-    def test_cpu_fallback_is_tiny_capped(self):
-        assert bench._cap_cpu_fallback(30, None) == (4, 2)
-        assert bench._cap_cpu_fallback(30, 5) == (4, 2)
-        assert bench._cap_cpu_fallback(2, 1) == (2, 1)
-
-
-class TestCompileCache:
-    def test_enable_and_disable(self, tmp_path, monkeypatch):
-        from comfyui_distributed_tpu.utils.compile_cache import \
-            enable_compile_cache
-
-        d = enable_compile_cache(str(tmp_path / "xla"))
-        assert d == str(tmp_path / "xla")
-        import jax
-
-        assert jax.config.jax_compilation_cache_dir == d
-        monkeypatch.setenv("CDT_COMPILE_CACHE_DIR", "")
-        assert enable_compile_cache() is None
-
-    def test_unwritable_never_fatal(self, tmp_path):
-        from comfyui_distributed_tpu.utils.compile_cache import \
-            enable_compile_cache
-
-        ro = tmp_path / "ro"
-        ro.mkdir()
-        ro.chmod(0o500)
-        try:
-            # root bypasses the permission bit, so accept either outcome
-            # — the contract is only "never raises"
-            enable_compile_cache(str(ro / "sub" / "cache"))
-        finally:
-            ro.chmod(0o700)
-
-
-class TestPartialResultHandler:
-    """Satellite (ISSUE 4): an external overall-timeout (`timeout -k` →
-    SIGTERM, the BENCH_r05 rc=124 shape) must leave the evidence
-    accumulated so far in the results JSON, not an empty file."""
-
-    def test_sigterm_emits_partial_json_before_nonzero_exit(self, tmp_path):
-        import json
-        import signal
-        import subprocess
-        import sys
-        import time
-
-        out = tmp_path / "partial.json"
-        child_src = tmp_path / "child.py"
-        child_src.write_text(f"""
-import importlib.util, sys, time, types
-spec = importlib.util.spec_from_file_location("bench", {str(ROOT / "bench.py")!r})
-bench = importlib.util.module_from_spec(spec)
-spec.loader.exec_module(bench)
-cli = types.SimpleNamespace(out={str(out)!r})
-partial = {{"workload": "txt2img", "tpu_attempts": 2,
-            "tpu_errors": ["tunnel refused", "tunnel refused"],
-            "tpu_error": "tunnel refused"}}
-bench._install_partial_result_handler(cli, partial)
-print("ready", flush=True)
-time.sleep(60)
-""")
-        proc = subprocess.Popen([sys.executable, str(child_src)],
-                                stdout=subprocess.PIPE, text=True)
-        try:
-            assert proc.stdout.readline().strip() == "ready"
-            proc.send_signal(signal.SIGTERM)
-            rc = proc.wait(timeout=30)
-        finally:
-            if proc.poll() is None:
-                proc.kill()
-        assert rc == 128 + signal.SIGTERM          # nonzero, conventional
-        doc = json.loads(out.read_text())
-        assert doc["metric"] == "benchmark_partial"
-        assert doc["tpu_attempts"] == 2
-        assert doc["tpu_error"] == "tunnel refused"
-        assert doc["tpu_attempted"] is True
-        assert "signal" in doc["interrupted_by"]
-
-    def test_late_sigterm_does_not_clobber_final_result(self, tmp_path):
-        """Once a real result has been emitted, a late SIGTERM (e.g.
-        `timeout -k` firing during teardown just after success) must exit
-        without rewriting the good JSON as a zeroed partial."""
-        import json
-        import signal
-        import subprocess
-        import sys
-
-        out = tmp_path / "result.json"
-        child_src = tmp_path / "child.py"
-        child_src.write_text(f"""
-import importlib.util, sys, time, types
-spec = importlib.util.spec_from_file_location("bench", {str(ROOT / "bench.py")!r})
-bench = importlib.util.module_from_spec(spec)
-spec.loader.exec_module(bench)
-cli = types.SimpleNamespace(out={str(out)!r})
-partial = {{"workload": "txt2img", "tpu_attempts": 1, "tpu_errors": []}}
-bench._install_partial_result_handler(cli, partial)
-partial["_final_result_emitted"] = True
-bench._emit({{"metric": "img_per_s", "value": 3.5, "unit": "img/s"}}, cli.out)
-print("ready", flush=True)
-time.sleep(60)
-""")
-        proc = subprocess.Popen([sys.executable, str(child_src)],
-                                stdout=subprocess.PIPE, text=True)
-        try:
-            line = proc.stdout.readline().strip()  # _emit echoes the JSON
-            while line and line != "ready":
-                line = proc.stdout.readline().strip()
-            assert line == "ready"
-            proc.send_signal(signal.SIGTERM)
-            rc = proc.wait(timeout=30)
-        finally:
-            if proc.poll() is None:
-                proc.kill()
-        assert rc == 128 + signal.SIGTERM
-        doc = json.loads(out.read_text())
-        assert doc["metric"] == "img_per_s"        # not benchmark_partial
-        assert doc["value"] == 3.5
+    def test_unknown_device_kind_has_no_peak(self):
+        assert bench._peak_flops("TPU v5 lite") == 197e12
+        with pytest.raises(ValueError, match="no bf16 peak"):
+            bench._peak_flops("cpu")
+        # ... and MFU against it is an error, not a blank field
+        with pytest.raises(ValueError, match="no bf16 peak"):
+            bench._mfu_fields(1e12, 1.0, on_accel=True)

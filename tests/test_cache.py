@@ -639,25 +639,3 @@ def test_load_smoke_dup_rate_mix():
     none = load_smoke.build_workload(7, 40, dup_rate=0.0)
     prints0 = [_json.dumps(r["prompt"], sort_keys=True) for r in none]
     assert len(set(prints0)) == len(prints0)
-
-
-# --- bench preflight --------------------------------------------------------
-
-
-def test_bench_tpu_preflight_records_platform():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_preflight_test",
-        os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    pf = bench._tpu_preflight(120.0)
-    assert pf["attempted"] and pf["ok"]
-    assert pf["platform"] == "cpu"                  # this host's backend
-    assert pf["devices"] >= 1
-    assert pf["error"] is None
-    tiny = bench._tpu_preflight(0.001)
-    assert tiny["attempted"] and not tiny["ok"]
-    assert "preflight timeout" in (tiny["error"] or "")

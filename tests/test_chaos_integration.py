@@ -217,13 +217,11 @@ class TestRollingRestart:
         # session-persistent cache dir shared with tests/test_warmup.py:
         # whichever test runs first on a fresh machine pays the one cold
         # compile; every later pass is the cache-load path under test
-        import os as _os
-        warm_cache = _os.environ.get(
-            "CDT_TEST_XLA_CACHE", "/tmp/cdt_xla_cache_tests") + "_warmup"
+        warm_cache = cc.cache_dir_default() + "_tests_warmup"
         saved_dir = jax.config.jax_compilation_cache_dir
         saved_min = jax.config.jax_persistent_cache_min_compile_time_secs
-        saved_state = dict(cc._state)
-        monkeypatch.setenv("CDT_COMPILE_CACHE_DIR", warm_cache)
+        saved_active = cc._active
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", warm_cache)
         monkeypatch.setenv("CDT_SHAPE_CATALOG",
                            str(tmp_path / "fleet_catalog.json"))
         try:
@@ -321,4 +319,4 @@ class TestRollingRestart:
             jax.config.update("jax_compilation_cache_dir", saved_dir)
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", saved_min)
-            cc._state.update(saved_state)
+            cc._active = saved_active

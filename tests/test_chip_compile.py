@@ -1,0 +1,200 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed here and compiles for a v5e that is
+described, not attached (guide ``on-chip-measurement`` §2.3). Interpret
+mode cannot show what it shows: a kernel that passed every interpret-mode
+test was refused on the chip's terms for 4 MB more scoped VMEM than the
+repo's own model counted. These cases compile, at real widths:
+
+- every Pallas row of ``ops/attn_table_default.json`` alone, with its
+  operands as program arguments (the strictest setting the compiler has);
+- the classic ``bh`` call at FLUX's geometry, which no row selects but the
+  floors of ``select_kernel`` still reach;
+- SDXL's two self-attention sites inside the transformer block that calls
+  them, with the dispatcher choosing the tier as it does on the chip;
+- and, for every row, the largest blocks ``_fused_feasible`` /
+  ``_packed_feasible`` approve: a feasibility function that says yes where
+  the compiler says no is the bug this file exists to catch.
+
+Nothing runs, so nothing here is a result or a time. The persistent
+compilation cache is off around the compiles: an entry written for a
+described device cannot be read back without one, and only warns.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+# describing a topology loads libtpu, which one process at a time may do
+# unless told otherwise; no chip is opened here, and the suite's workers
+# compile side by side
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from comfyui_distributed_tpu.ops import attention as attn
+from comfyui_distributed_tpu.ops import autotune
+from comfyui_distributed_tpu.ops import flash_attention as fa
+
+TABLE = autotune.TuningTable(shipped=True, path="/nonexistent/none.json",
+                             autoload=True).entries()
+PALLAS_ROWS = {k.key_str(): (k, c) for k, c in TABLE.items()
+               if c.tier != "xla"}
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip of a 2x2 host."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile_kernel(chip, tier, H, D, nq, nk, bq, bk, batch=1):
+    """Compile one kernel call alone, operands as arguments; raises what
+    the chip's compiler raises."""
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+
+    if tier == "fused":
+        C = H * D
+        x, w = arg(batch, nq, C), arg(C, C)
+        lowered = fa._flash_mha_fused.lower(
+            x, w, w, w, num_heads=H, block_q=bq, block_k=bk,
+            interpret=False)
+    elif tier == "packed":
+        q, kv = arg(batch, nq, H * D), arg(batch, nk, H * D)
+        lowered = fa._flash_mha_packed.lower(
+            q, kv, kv, num_heads=H, block_q=bq, block_k=bk,
+            interpret=False)
+    else:
+        q, kv = arg(batch * H, nq, D), arg(batch * H, nk, D)
+        lowered = fa._flash_mha.lower(q, kv, kv, block_q=bq, block_k=bk,
+                                      interpret=False)
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# (id, tier, H, D, Nq, Nk, block_q, block_k): the table's Pallas rows at
+# their bucket lengths, WAN also at its real 14 040 tokens (not a block
+# multiple: the call pads), and the classic call the table never picks
+KERNEL_CASES = [
+    (ks, c.tier, k.num_heads, k.head_dim, k.q_bucket, k.kv_bucket,
+     c.block_q, c.block_k)
+    for ks, (k, c) in sorted(PALLAS_ROWS.items())
+] + [
+    ("wan_self_14040", "packed", 12, 128, 14040, 14040, 256, 512),
+    ("flux_bh_h24.d128.q8192", "bh", 24, 128, 8192, 8192, 256, 512),
+]
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES, ids=lambda c: c[0])
+def test_table_row_compiles_alone(chip, case):
+    _, tier, H, D, nq, nk, bq, bk = case
+    _compile_kernel(chip, tier, H, D, nq, nk, bq, bk,
+                    batch=2 if tier == "fused" else 1)
+
+
+def test_table_has_the_rows_the_main_paths_select():
+    """The cases above are read from the shipped table; an emptied table
+    must not pass by compiling nothing."""
+    zoo = {k.key_str() for k in autotune.model_zoo_geometries().values()}
+    assert zoo == {k.key_str() for k in TABLE}
+    assert len(PALLAS_ROWS) >= 5
+    assert {c.tier for _, c in PALLAS_ROWS.values()} == {"fused", "packed"}
+
+
+@pytest.mark.parametrize("level", [(640, 10, 4096, "fused"),
+                                   (1280, 20, 1024, "packed")],
+                         ids=lambda l: f"c{l[0]}.n{l[2]}")
+def test_sdxl_self_attention_inside_its_block(chip, level, monkeypatch):
+    """One SDXL transformer block (self-attention, cross-attention to 77
+    text tokens, GEGLU) at the width of the 64² and the 32² level, CFG
+    batch 2, with the dispatcher choosing as it does on the chip: the
+    Pallas call compiles where other ops of the same program produce its
+    operands, and the tier is the table's."""
+    from comfyui_distributed_tpu.models.layers import TransformerBlock
+
+    C, heads, n, tier = level
+    for var in ("CDT_FLASH_ATTENTION", "CDT_FLASH_LAYOUT", "CDT_ATTN_TUNE",
+                "CDT_FLASH_BLOCK_Q", "CDT_FLASH_BLOCK_K"):
+        monkeypatch.delenv(var, raising=False)
+    # the one place the kernels ask where they are (ops/flash_attention):
+    # steered here, in the test, since jax.devices() still says cpu
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    attn.reset_selections()
+
+    block = TransformerBlock(heads, C // heads)
+    x = jax.ShapeDtypeStruct((2, n, C), jnp.bfloat16, sharding=chip)
+    ctx = jax.ShapeDtypeStruct((2, 77, 2048), jnp.bfloat16, sharding=chip)
+    params = jax.eval_shape(
+        lambda: block.init(jax.random.key(0), jnp.zeros(x.shape, x.dtype),
+                           jnp.zeros(ctx.shape, ctx.dtype)))
+    params = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16, sharding=chip),
+        params)
+    compiled = jax.jit(block.apply).lower(params, x, ctx).compile()
+
+    assert "tpu_custom_call" in compiled.as_text()
+    selected = dict(item.split("=") for item in
+                    attn.selection_summary().split(","))
+    key = autotune.GeometryKey.from_shape(heads, C // heads, n, n).key_str()
+    assert selected[key].startswith(tier), selected
+    cross = autotune.GeometryKey.from_shape(heads, C // heads, n, 77)
+    assert selected[cross.key_str()] == "xla", selected
+
+
+def _approved_frontier(feasible):
+    """For each candidate block_q, the widest candidate block_k the
+    feasibility function approves unchanged."""
+    out = []
+    for bq in autotune.BLOCK_Q_CANDIDATES:
+        ok = [bk for bk in autotune.BLOCK_K_CANDIDATES
+              if feasible(bq, bk) == (bq, bk)]
+        if ok:
+            out.append((bq, max(ok)))
+    return out
+
+
+@pytest.mark.parametrize("row", sorted(PALLAS_ROWS), ids=str)
+def test_feasibility_never_approves_what_the_compiler_refuses(chip, row):
+    """The VMEM models against the compiler, on the rows the table ships:
+    whatever ``_fused_feasible`` / ``_packed_feasible`` approve for the
+    geometry — not only the pair the table chose — must compile. On the
+    parent commit the fused model approved 256/256 at C=1280 (15.25 MB by
+    its count) and the compiler wanted 20.26 MB of a 16 MB limit."""
+    key, _ = PALLAS_ROWS[row]
+    H, D, nq, nk = key.num_heads, key.head_dim, key.q_bucket, key.kv_bucket
+    tiers = {"packed": lambda bq, bk: fa._packed_feasible(H, D, bq, bk)}
+    if nq == nk:        # self-attention: the fused tier is a candidate too
+        tiers["fused"] = lambda bq, bk: fa._fused_feasible(H * D, H, D,
+                                                           bq, bk)
+    compiled = 0
+    for tier, feasible in tiers.items():
+        for bq, bk in _approved_frontier(feasible):
+            try:
+                _compile_kernel(chip, tier, H, D, nq, nk, bq, bk)
+            except Exception as e:  # noqa: BLE001 — the compiler's refusal
+                pytest.fail(f"{tier} {bq}/{bk} approved for {row} but "
+                            f"refused by the compiler: {str(e)[:400]}")
+            compiled += 1
+    assert compiled, f"nothing approved for {row}"

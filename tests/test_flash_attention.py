@@ -3,7 +3,7 @@ numerics checked against dense attention)."""
 
 import jax
 import jax.numpy as jnp
-from comfyui_distributed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 import numpy as np
 import pytest
 

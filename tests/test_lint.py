@@ -270,7 +270,7 @@ class TestJ001:
             import time
 
             import jax
-            from jax_compat import shard_map
+            from jax import shard_map
 
             @jax.jit
             def decorated(x):

@@ -29,7 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from comfyui_distributed_tpu.parallel import build_mesh
 from comfyui_distributed_tpu.parallel import overlap
-from comfyui_distributed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 MESH8 = {"x": 8}
 

@@ -35,8 +35,8 @@ def _stack(pos_embed="rope"):
 
 class TestFlatBlocks:
     """r04: streamed blocks are flattened to one contiguous buffer per
-    dtype (one device_put per block instead of ~20 — per-leaf RTT
-    dominated the tunneled stream). The layout must round-trip exactly."""
+    dtype (one device_put per block instead of ~20 — the fixed cost of
+    each put dominated the stream). The layout must round-trip exactly."""
 
     def test_roundtrip_uniform_dtype(self):
         from comfyui_distributed_tpu.diffusion.offload import (

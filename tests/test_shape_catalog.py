@@ -158,6 +158,6 @@ class TestRuntimeObservation:
 
     def test_default_path_lives_next_to_xla_cache(self, monkeypatch):
         monkeypatch.delenv("CDT_SHAPE_CATALOG", raising=False)
-        monkeypatch.setenv("CDT_COMPILE_CACHE_DIR", "/some/cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/cache")
         assert str(sc.default_catalog_path()) == \
             "/some/cache/shape_catalog.json"

@@ -9,7 +9,6 @@ cache + catalog) demonstrably skips recompilation — pass 2 after
 """
 
 import asyncio
-import os
 
 import jax
 import pytest
@@ -21,12 +20,12 @@ from comfyui_distributed_tpu.diffusion.warmup import (WarmupManager,
                                                       run_warmup)
 from comfyui_distributed_tpu.models.registry import ModelRegistry
 from comfyui_distributed_tpu.parallel import build_mesh
+from comfyui_distributed_tpu.utils.compile_cache import cache_dir_default
 
 # session-persistent (NOT per-test tmp): the cold compile happens once
 # per machine; re-runs exercise the cache-hit path at disk-read cost —
 # the same economics the subsystem exists to provide
-_WARM_CACHE = os.environ.get("CDT_TEST_XLA_CACHE",
-                             "/tmp/cdt_xla_cache_tests") + "_warmup"
+_WARM_CACHE = cache_dir_default() + "_tests_warmup"
 
 
 @pytest.fixture
@@ -37,12 +36,12 @@ def restore_cache_config():
 
     saved_dir = jax.config.jax_compilation_cache_dir
     saved_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    saved_state = dict(cc._state)
+    saved_active = cc._active
     yield
     jax.config.update("jax_compilation_cache_dir", saved_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       saved_min)
-    cc._state.update(saved_state)
+    cc._active = saved_active
 
 
 def _tiny_catalog(tmp_path):
@@ -206,7 +205,7 @@ class TestWarmupManager:
         from comfyui_distributed_tpu.telemetry import REGISTRY
 
         REGISTRY.reset()
-        monkeypatch.setenv("CDT_COMPILE_CACHE_DIR", _WARM_CACHE)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", _WARM_CACHE)
         mgr = WarmupManager(lambda: ModelRegistry(),
                             lambda: build_mesh({"dp": 1},
                                                jax.devices()[:1]),
@@ -228,7 +227,7 @@ class TestWarmupManager:
         and persists the table."""
         from comfyui_distributed_tpu.ops import autotune
 
-        monkeypatch.setenv("CDT_COMPILE_CACHE_DIR", _WARM_CACHE)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", _WARM_CACHE)
         autotune.reset_default_table()
         mgr = WarmupManager(lambda: ModelRegistry(),
                             lambda: build_mesh({"dp": 1},
@@ -263,7 +262,7 @@ class TestWarmupManager:
                                   restore_cache_config):
         from comfyui_distributed_tpu.ops import autotune
 
-        monkeypatch.setenv("CDT_COMPILE_CACHE_DIR", _WARM_CACHE)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", _WARM_CACHE)
         monkeypatch.setenv("CDT_ATTN_TUNE", "0")
         autotune.reset_default_table()
         mgr = WarmupManager(lambda: ModelRegistry(),

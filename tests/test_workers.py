@@ -160,6 +160,25 @@ class TestProcessManager:
             for p in procs:
                 p.kill()
 
+    def test_local_worker_refused_where_this_process_holds_the_chips(
+            self, tmp_config, monkeypatch):
+        """One process per chip: on a host whose TPU this process has
+        opened, a locally launched controller could never claim a device
+        — refused with the reason, before anything is spawned."""
+        import types
+
+        import jax
+
+        procs = []
+        mgr = self._manager_with_fake_launch(tmp_config, monkeypatch, procs)
+        chip = types.SimpleNamespace(platform="tpu",
+                                     device_kind="TPU v5 lite")
+        monkeypatch.setattr(jax, "devices", lambda *a: [chip] * 4)
+        with pytest.raises(ProcessError, match="a chip belongs to one "
+                                               "process"):
+            mgr.launch_worker("w1")
+        assert procs == [] and mgr.get_managed_workers() == {}
+
     def test_restore_and_reap(self, tmp_config, monkeypatch):
         """PID-only restore: alive PIDs restored, dead reaped (reference
         persistence.py:11-29)."""
