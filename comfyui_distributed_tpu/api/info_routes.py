@@ -10,6 +10,7 @@ from pathlib import Path
 
 from aiohttp import web
 
+from .. import telemetry
 from ..utils import constants
 from ..utils.exceptions import ValidationError
 
@@ -139,6 +140,9 @@ def register(router, controller) -> None:
             raise ValidationError("body must be a JSON object")
         if "out" in body and not isinstance(body["out"], str):
             raise ValidationError("'out' must be a string", field="out")
+        if not isinstance(body.get("python_tracer", False), bool):
+            raise ValidationError("'python_tracer' must be a boolean",
+                                  field="python_tracer")
         import os
         import time as _t
 
@@ -152,10 +156,18 @@ def register(router, controller) -> None:
             os.path.basename(str(body.get("out") or _t.strftime("%Y%m%d-%H%M%S"))),
             max_len=80, fallback="trace")
         out = os.path.join(root, name)
+        # the Python tracer is off unless asked for: it adds millions of
+        # host events a request (tens of MiB) that no reader of the trace
+        # uses; the host tracer stays on and keeps the TraceAnnotations
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = int(body.get("python_tracer", False))
         try:
-            jax.profiler.start_trace(out)
+            jax.profiler.start_trace(out, profiler_options=options)
         except RuntimeError as e:
             return web.json_response({"error": str(e)}, status=409)
+        # from here every synchronous span is mirrored into the trace as
+        # an annotation ``cdt.<name>``, on the device operations' clock
+        telemetry.set_annotator(jax.profiler.TraceAnnotation)
         profile_state["dir"] = out
         return web.json_response({"status": "tracing", "out": out})
 
@@ -165,6 +177,7 @@ def register(router, controller) -> None:
         if not profile_state["dir"]:
             return web.json_response({"error": "no trace running"}, status=409)
         out, profile_state["dir"] = profile_state["dir"], None
+        telemetry.set_annotator(None)
         try:
             jax.profiler.stop_trace()
         except RuntimeError as e:

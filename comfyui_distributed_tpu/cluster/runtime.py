@@ -353,9 +353,17 @@ class PromptQueue:
             try:
                 if telemetry.enabled():
                     for m in (job.group or [job]):
+                        waited = started - m.enqueued_at
                         _tm.QUEUE_WAIT_SECONDS.labels(
-                            priority=m.priority).observe(
-                                started - m.enqueued_at)
+                            priority=m.priority).observe(waited)
+                        # the same two timestamps as a span of the
+                        # request's tree; a prompt that came without a
+                        # trace id gets here the one its execution keeps
+                        m.trace_id = m.trace_id or telemetry.new_trace_id()
+                        telemetry.record_span(
+                            "prompt.queued", waited, trace_id=m.trace_id,
+                            parent_id=m.parent_span_id,
+                            prompt_id=m.prompt_id)
                 if self.preemption is not None:
                     # a strictly-higher class may already be waiting when
                     # a lower job starts (it was the best available)
@@ -422,8 +430,8 @@ class PromptQueue:
                                 parent_id=job.parent_span_id,
                                 prompt_id=job.prompt_id):
                 # run_in_executor does NOT propagate contextvars, so
-                # spans opened during graph execution (pipeline_call
-                # with its attn_kernels label, node-level spans)
+                # spans opened during graph execution (the node spans,
+                # pipeline_call and its launch/wait pair)
                 # would start orphan traces; copying the context in
                 # parents them under this execution span
                 ctx = contextvars.copy_context()

@@ -79,54 +79,53 @@ def cached_build(holder, key, builder, max_entries: int = 8):
     return fn
 
 
-class _AttnKernelSummary:
-    """Span-attr shim: ``telemetry.spans`` stringifies attrs when the
-    span CLOSES, so this resolves the kernel-selection summary after the
-    wrapped call's trace has run its dispatch."""
-
-    def __str__(self) -> str:
-        from ..ops.attention import selection_summary
-
-        return selection_summary() or "none"
-
-
 def bind_weights(jitted, weights, label: "str | None" = None,
-                 steps: "int | None" = None):
+                 steps: "int | None" = None, name: "str | None" = None):
     """Wrap a jitted function whose LEADING argument is the weight pytree:
     the returned callable supplies it automatically, while ``.jitted`` /
     ``.weights`` expose the raw jit object for AOT use
     (``bench.py``: ``fn.jitted.lower(fn.weights, *args)``). One shared
     definition — every pipeline factory returns this shape.
 
-    ``label`` opts the wrapper into telemetry: each call is timed to
-    completion (``block_until_ready`` — callers materialize the output
-    immediately anyway) and recorded as
+    Every call's LAUNCH is timed: the host time inside ``jitted(...)``
+    until JAX hands back the not-yet-ready result, as a ``program.launch``
+    span and ``cdt_pipeline_dispatch_seconds{pipeline}``. The series is
+    named by ``label``, else by ``name`` (a program that must stay
+    asynchronous gets a name and no label), else ``unnamed``.
+
+    ``label`` opts the wrapper into completion timing as well: each call
+    is waited for (``block_until_ready`` under a ``program.wait`` span —
+    callers materialize the output immediately anyway) and recorded as
     ``cdt_pipeline_compile_seconds{pipeline=label}`` on the first call
     (which pays trace + XLA compile) vs ``cdt_pipeline_execute_seconds``
     after; with ``steps`` the per-step quotient also lands in
-    ``cdt_sampler_step_seconds``. With telemetry disabled (or no label)
-    the call path is exactly the old one-liner."""
+    ``cdt_sampler_step_seconds``. With telemetry disabled the call path
+    is exactly the old one-liner."""
     from ..telemetry import enabled as _tm_enabled
 
     state = {"first": True}
+    series = label or name or "unnamed"
 
     def call(*args, **kw):
-        if label is None or not _tm_enabled():
+        if not _tm_enabled():
             return jitted(weights, *args, **kw)
         from ..telemetry import metrics as _tm
-        from ..telemetry.spans import span
+        from ..telemetry.spans import span, timed_span
 
+        launch = timed_span(
+            "program.launch",
+            _tm.PIPELINE_DISPATCH_SECONDS.labels(pipeline=series),
+            pipeline=series)
+        if label is None:
+            with launch:
+                return jitted(weights, *args, **kw)
         # step-time telemetry only: never feeds the program or keys
         t0 = time.perf_counter()  # cdtlint: disable=D001
-        # the attn_kernels attr records which kernel tier served each
-        # geometry this program traced (ops/attention.py dispatch), so
-        # the trace view answers "which kernel ran this step" without a
-        # profiler. Lazy: spans stringify attrs at close, AFTER the
-        # first call's trace has made its selections.
-        with span("pipeline_call", pipeline=label,
-                  attn_kernels=_AttnKernelSummary()):
-            out = jitted(weights, *args, **kw)
-            jax.block_until_ready(out)
+        with span("pipeline_call", pipeline=label):
+            with launch:
+                out = jitted(weights, *args, **kw)
+            with span("program.wait", pipeline=label):
+                jax.block_until_ready(out)
         dt = time.perf_counter() - t0  # cdtlint: disable=D001
         if state["first"]:
             state["first"] = False
@@ -697,7 +696,7 @@ class Txt2ImgPipeline:
 
         prep = bind_weights(jax.jit(shard_map(
             prep_body, mesh=mesh, in_specs=base_specs,
-            out_specs=carry_specs)), weights)
+            out_specs=carry_specs)), weights, name="txt2img_prep")
 
         def make_seg(length: int, with_token: bool):
             if with_token:
@@ -729,7 +728,8 @@ class Txt2ImgPipeline:
 
         fin = bind_weights(jax.jit(shard_map(
             fin_body, mesh=mesh, in_specs=(P(), carry_specs),
-            out_specs=P(axis, None, None, None))), weights)
+            out_specs=P(axis, None, None, None))), weights,
+            name="txt2img_fin")
 
         segs: "dict[tuple, Any]" = {}
 
@@ -844,26 +844,29 @@ class Txt2ImgPipeline:
             carry = bundle["prep"](*args)
             start = 0
 
+        from ..telemetry.spans import span
+
         done_here = 0
         while start < n:
-            if done_here > 0 and should_preempt is not None:
-                reason = should_preempt()
-                if reason:
-                    leaves = tuple(np.asarray(leaf)
-                                   for leaf in jax.device_get(carry))
-                    ckpt = LatentCheckpoint(
-                        sampler=spec.sampler, step=start, total_steps=n,
-                        carry=leaves, meta=identity)
-                    return {"checkpoint": ckpt, "reason": reason,
-                            "step": start}
-            length = min(seg_steps, n - start)
-            if progress_token is not None:
-                carry = bundle["seg"](length, True)(
-                    *args, jnp.int32(start), carry,
-                    jnp.asarray(progress_token, jnp.int32))
-            else:
-                carry = bundle["seg"](length)(*args, jnp.int32(start),
-                                              carry)
+            # the host work between two launches that is not the launch:
+            # the preempt check, the scalar uploads, the program lookup
+            with span("segment.boundary", step=start):
+                if done_here > 0 and should_preempt is not None:
+                    reason = should_preempt()
+                    if reason:
+                        leaves = tuple(np.asarray(leaf)
+                                       for leaf in jax.device_get(carry))
+                        ckpt = LatentCheckpoint(
+                            sampler=spec.sampler, step=start,
+                            total_steps=n, carry=leaves, meta=identity)
+                        return {"checkpoint": ckpt, "reason": reason,
+                                "step": start}
+                length = min(seg_steps, n - start)
+                seg = bundle["seg"](length, progress_token is not None)
+                operands = (jnp.int32(start), carry)
+                if progress_token is not None:
+                    operands += (jnp.asarray(progress_token, jnp.int32),)
+            carry = seg(*args, *operands)
             # materialize: the segment boundary IS the preemption point —
             # an unbounded dispatch pipeline would make it meaningless
             jax.block_until_ready(carry)

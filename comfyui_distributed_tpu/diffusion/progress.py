@@ -30,10 +30,14 @@ from __future__ import annotations
 
 import itertools
 import threading
+from collections import OrderedDict
 from typing import Callable, Optional
 
 import jax
 import numpy as np
+
+from ..telemetry import metrics as _tm
+from ..telemetry import spans as _spans
 
 # sink(token:int, shard:int, sigma:float, x0:np.ndarray). Registry keyed by
 # handle so removal is exact; empty = events dropped on the floor.
@@ -41,6 +45,11 @@ _LOCK = threading.Lock()
 _SINKS: "dict[int, Callable]" = {}
 _HANDLES = itertools.count(1)
 _TOKENS = itertools.count(1)
+# token -> (trace id, span id) of the code that asked for the token: the
+# callbacks run on runtime threads, which inherit no span context, and
+# their ``progress.sink`` spans belong in the request's tree
+_ORIGIN: "OrderedDict[int, tuple]" = OrderedDict()
+_ORIGIN_KEEP = 64
 
 
 def next_token() -> int:
@@ -48,7 +57,13 @@ def next_token() -> int:
     callback route: uniqueness across *all* trackers is what lets every
     sink receive every event and key only on its own jobs."""
     with _LOCK:
-        return next(_TOKENS)
+        token = next(_TOKENS)
+        trace_id = _spans.current_trace_id()
+        if trace_id is not None:
+            _ORIGIN[token] = (trace_id, _spans.current_span_id())
+            while len(_ORIGIN) > _ORIGIN_KEEP:
+                _ORIGIN.popitem(last=False)
+        return token
 
 
 def add_sink(fn: Callable) -> int:
@@ -85,11 +100,14 @@ def get_sink() -> Optional[Callable]:
 def _dispatch(token, shard, sigma, x0) -> None:
     with _LOCK:
         sinks = list(_SINKS.values())
-    for sink in sinks:
-        try:
-            sink(int(token), int(shard), float(sigma), np.asarray(x0))
-        except Exception:  # a broken UI consumer must never kill a job
-            pass
+        trace_id, parent_id = _ORIGIN.get(int(token), (None, None))
+    with _spans.timed_span("progress.sink", _tm.PROGRESS_CALLBACK_SECONDS,
+                           trace_id=trace_id, parent_id=parent_id):
+        for sink in sinks:
+            try:
+                sink(int(token), int(shard), float(sigma), np.asarray(x0))
+            except Exception:  # a broken UI consumer must never kill a job
+                pass
 
 
 # model calls the wrapped (guided) denoiser makes per sampler step; CFG is

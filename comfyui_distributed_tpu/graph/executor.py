@@ -11,6 +11,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable
 
+from ..telemetry.spans import span
 from ..utils.exceptions import ValidationError
 from .node import NODE_REGISTRY, get_node, is_link
 
@@ -163,7 +164,10 @@ class GraphExecutor:
                 raise InterruptedError(f"execution interrupted before {nid}")
             if nid in cache:
                 continue
-            cls = get_node(prompt[nid]["class_type"])
+            class_type = prompt[nid]["class_type"]
+            cls = get_node(class_type)
             kwargs = node_kwargs(prompt, nid, cache, self.context)
-            cache[nid] = tuple(cls().execute(**kwargs))
+            # class_type is a key of NODE_REGISTRY: a bounded label set
+            with span(f"node.{class_type}", node_id=nid):
+                cache[nid] = tuple(cls().execute(**kwargs))
         return cache

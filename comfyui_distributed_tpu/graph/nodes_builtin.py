@@ -1486,6 +1486,7 @@ class SaveImage(NodeDef):
 
     def execute(self, images, filename_prefix: str = "output",
                 output_dir: str = "", **_):
+        from ..telemetry.spans import span
         from ..utils.image import encode_png, to_uint8
 
         out_dir = Path(output_dir or "output")
@@ -1494,7 +1495,10 @@ class SaveImage(NodeDef):
         paths = []
         for i in range(arr.shape[0]):
             p = out_dir / f"{filename_prefix}_{i:05d}.png"
-            p.write_bytes(encode_png(arr[i]))
+            with span("image.encode_png"):
+                data = encode_png(arr[i])
+            with span("image.write", bytes=len(data)):
+                p.write_bytes(data)
             paths.append(str(p))
         log(f"saved {len(paths)} images to {out_dir}")
         return ()

@@ -83,8 +83,8 @@ def _flash_enabled(q_len: Optional[int] = None,
 # --- kernel-tier dispatch ----------------------------------------------------
 # selections made at trace time, remembered for observability: the log
 # line fires once per (geometry, choice), the counter feeds
-# cdt_attn_kernel_selected, and selection_summary() labels pipeline
-# spans so traces show which tier served each step without a profiler.
+# cdt_attn_kernel_selected (which chip_smoke.py checks), and
+# selection_summary() lists them for the kernel tests.
 
 import contextlib as _contextlib
 import contextvars as _contextvars
@@ -143,7 +143,7 @@ def _note_selection(geometry: str, choice) -> None:
 
 def selection_summary() -> str:
     """Compact 'geometry=tier' list of every kernel choice this process
-    has traced — attached to pipeline-call spans as ``attn_kernels``."""
+    has traced (the kernel tests read it)."""
     with _SELECTIONS_LOCK:
         return ",".join(f"{g}={d}" for g, d in sorted(_SELECTIONS.items()))
 

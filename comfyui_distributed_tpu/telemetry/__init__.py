@@ -27,7 +27,8 @@ from .registry import (BYTES_BUCKETS, COMPILE_BUCKETS, DURATION_BUCKETS,
                        Counter, Gauge, Histogram, MetricRegistry, REGISTRY,
                        enabled, set_enabled)
 from .spans import (STORE as SPAN_STORE, TRACE_HEADER, current_span_id,
-                    current_trace_id, parse_trace_header, span,
+                    current_trace_id, new_trace_id, parse_trace_header,
+                    record_span, set_annotator, span, timed_span,
                     trace_headers, use_trace)
 from . import metrics  # noqa: F401  — declares the standard families
 
@@ -40,6 +41,6 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricRegistry", "REGISTRY",
     "SPAN_STORE", "TRACE_HEADER", "counter", "current_span_id",
     "current_trace_id", "enabled", "gauge", "histogram", "metrics",
-    "parse_trace_header", "set_enabled", "span", "trace_headers",
-    "use_trace",
+    "new_trace_id", "parse_trace_header", "record_span", "set_annotator",
+    "set_enabled", "span", "timed_span", "trace_headers", "use_trace",
 ]

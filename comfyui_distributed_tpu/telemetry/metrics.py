@@ -41,6 +41,18 @@ PIPELINE_EXECUTE_SECONDS = REGISTRY.histogram(
     "the first), by pipeline.",
     ("pipeline",))
 
+PIPELINE_DISPATCH_SECONDS = REGISTRY.histogram(
+    "cdt_pipeline_dispatch_seconds",
+    "Host time inside the Python call of a bound program, until JAX "
+    "hands back the not-yet-ready result (a program's first call also "
+    "traces and compiles in there), by pipeline.",
+    ("pipeline",))
+
+PROGRESS_CALLBACK_SECONDS = REGISTRY.histogram(
+    "cdt_progress_callback_seconds",
+    "Host time inside one progress callback (a denoise call's x0 preview "
+    "handed to every registered sink).")
+
 # --- attention kernel dispatch / autotune (ops/attention.py, ops/autotune.py)
 
 ATTN_KERNEL_SELECTED = REGISTRY.counter(

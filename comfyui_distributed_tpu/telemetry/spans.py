@@ -18,10 +18,21 @@ both sides into one timeline.
 The orchestration layer's existing ``exec_…`` trace IDs are adopted
 verbatim (``span(..., trace_id=…)``), so log lines and span trees
 correlate on the same key.
+
+One clock with the profiler: while a profile session runs, the profile
+routes install an *annotator* (``set_annotator`` — a factory of context
+managers, ``jax.profiler.TraceAnnotation`` in practice; this module stays
+stdlib-only) and every span opened in synchronous code is mirrored as an
+annotation named ``cdt.<span name>``, so it lands in the host plane of the
+same ``.xplane.pb`` as the device's operations, on their timebase. An
+annotation belongs to a thread, so spans opened inside a running asyncio
+task (they interleave on the loop's thread) are not mirrored. With no
+session the cost is one ``is None`` test per span.
 """
 
 from __future__ import annotations
 
+import asyncio
 import contextvars
 import secrets
 import threading
@@ -115,6 +126,26 @@ class SpanStore:
 
 STORE = SpanStore()
 
+ANNOTATION_PREFIX = "cdt."
+
+# name -> context manager, or None (no profile session: nothing mirrored)
+_ANNOTATOR = None
+
+
+def set_annotator(factory) -> None:
+    """Install (or, with None, remove) the factory whose context managers
+    mirror synchronous spans into the profiler's trace."""
+    global _ANNOTATOR
+    _ANNOTATOR = factory
+
+
+def _in_asyncio_task() -> bool:
+    try:
+        asyncio.get_running_loop()
+    except RuntimeError:
+        return False
+    return True
+
 
 @contextmanager
 def span(name: str, trace_id: Optional[str] = None,
@@ -138,6 +169,10 @@ def span(name: str, trace_id: Optional[str] = None,
         parent_id = cur[1] or None
     span_id = secrets.token_hex(4)
     token = _CTX.set((trace_id, span_id))
+    annotation = None
+    if _ANNOTATOR is not None and not _in_asyncio_task():
+        annotation = _ANNOTATOR(ANNOTATION_PREFIX + name)
+        annotation.__enter__()
     start = time.time()
     t0 = time.perf_counter()
     error = None
@@ -148,20 +183,62 @@ def span(name: str, trace_id: Optional[str] = None,
         raise
     finally:
         duration = time.perf_counter() - t0
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         _CTX.reset(token)
-        rec = {
-            "name": name,
-            "trace_id": trace_id,
-            "span_id": span_id,
-            "parent_id": parent_id,
-            "start": start,
-            "duration_s": duration,
-            "attrs": {k: str(v) for k, v in attrs.items()},
-        }
-        if error is not None:
-            rec["error"] = error
-        STORE.record(rec)
-        _SPAN_SECONDS.labels(name=name).observe(duration)
+        _finish(name, trace_id, span_id, parent_id, start, duration, attrs,
+                error)
+
+
+def _finish(name, trace_id, span_id, parent_id, start, duration, attrs,
+            error=None) -> None:
+    rec = {
+        "name": name,
+        "trace_id": trace_id,
+        "span_id": span_id,
+        "parent_id": parent_id,
+        "start": start,
+        "duration_s": duration,
+        "attrs": {k: str(v) for k, v in attrs.items()},
+    }
+    if error is not None:
+        rec["error"] = error
+    STORE.record(rec)
+    _SPAN_SECONDS.labels(name=name).observe(duration)
+
+
+@contextmanager
+def timed_span(name: str, histogram, **attrs):
+    """A span whose duration is also observed into ``histogram`` (a
+    metric child with ``observe``): the callers that feed a family of
+    their own keep their clock reads in here."""
+    if not enabled():
+        yield None
+        return
+    t0 = time.perf_counter()
+    try:
+        with span(name, **attrs) as ctx:
+            yield ctx
+    finally:
+        histogram.observe(time.perf_counter() - t0)
+
+
+def record_span(name: str, duration_s: float,
+                trace_id: Optional[str] = None,
+                parent_id: Optional[str] = None, **attrs) -> None:
+    """Record a span that was not lived through as a ``with`` block: it
+    ended now and lasted ``duration_s`` (time in a queue, say, known only
+    once the wait is over). Same store, same histogram; never mirrored,
+    since an annotation cannot be opened in the past."""
+    if not enabled():
+        return
+    cur = _CTX.get()
+    if trace_id is None:
+        trace_id = cur[0] if cur else new_trace_id()
+    if parent_id is None and cur and cur[0] == trace_id:
+        parent_id = cur[1] or None
+    _finish(name, trace_id, secrets.token_hex(4), parent_id,
+            time.time() - duration_s, duration_s, attrs)
 
 
 @contextmanager
