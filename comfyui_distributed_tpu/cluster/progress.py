@@ -7,13 +7,21 @@ Consumes the ``jax.debug.callback`` events emitted by
 reference inherits from ComfyUI's executor hooks.
 
 Events are unordered (async host effects): ``sigma`` — strictly decreasing
-over the ladder — orders previews; the step *count* is simply the number of
-events seen from shard 0 (order-independent). Previews are kept per shard
-so a dp fan-out can show every participant's image forming.
+over the ladder — orders previews; the step *count* is the sum of the calls
+the events from shard 0 stand for (order-independent). Previews are kept
+per shard so a dp fan-out can show every participant's image forming.
+
+An event costs the chip a host round trip, so the tracker asks a compiled
+run for no more of them than its one consumer reads: it learns how long a
+denoiser call of a ``total_calls``-call run takes from the run before, and
+hands the next run a *stride* (``traced_token``) that spaces the events
+``EVENT_PERIOD_S`` apart. A first run, and any run of calls that long,
+reports every call.
 """
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 from collections import OrderedDict
@@ -23,6 +31,10 @@ import numpy as np
 
 from ..diffusion import progress as _events
 from ..utils.image import encode_png
+
+# the period at which the dashboard polls /distributed/progress and
+# /distributed/preview (web/main.js): events closer together are never seen
+EVENT_PERIOD_S = 0.75
 
 # Approximate linear latent→RGB maps for previews (rows = latent channels,
 # cols = RGB). These are the community-standard preview approximations for
@@ -55,12 +67,13 @@ def latent_to_rgb(latent: np.ndarray) -> np.ndarray:
 
 
 class _Job:
-    __slots__ = ("prompt_id", "total", "calls_seen", "previews",
+    __slots__ = ("prompt_id", "total", "stride", "calls_seen", "previews",
                  "preview_sigmas", "started", "updated", "done", "failed")
 
-    def __init__(self, prompt_id: str, total: int):
+    def __init__(self, prompt_id: str, total: int, stride: int = 1):
         self.prompt_id = prompt_id
         self.total = max(1, int(total))
+        self.stride = stride
         self.calls_seen = 0
         self.previews: dict[int, np.ndarray] = {}
         self.preview_sigmas: dict[int, float] = {}
@@ -78,6 +91,9 @@ class ProgressTracker:
         self._keep = keep
         self._jobs: "OrderedDict[int, _Job]" = OrderedDict()
         self._by_prompt: dict[str, int] = {}
+        # seconds one denoiser call took in the last finished run, by the
+        # run's total calls (what a run is known by when it starts)
+        self._call_seconds: dict[int, float] = {}
         self._lock = threading.Lock()
         # Events fan out to every registered sink; tokens are allocated
         # from the process-global counter (diffusion/progress.next_token)
@@ -98,6 +114,10 @@ class ProgressTracker:
         token = _events.next_token()
         with self._lock:
             job = _Job(prompt_id, total_calls)
+            call_s = self._call_seconds.get(job.total)
+            if call_s:
+                job.stride = max(1, min(job.total,
+                                        math.ceil(EVENT_PERIOD_S / call_s)))
             self._jobs[token] = job
             self._by_prompt[prompt_id] = token
             while len(self._jobs) > self._keep:
@@ -108,6 +128,16 @@ class ProgressTracker:
                 if self._by_prompt.get(old.prompt_id) == old_token:
                     self._by_prompt.pop(old.prompt_id, None)
         return token
+
+    def traced_token(self, token: int) -> np.ndarray:
+        """``[token, stride]`` — what a compiled run takes as its progress
+        token (``diffusion/progress.wrap_denoiser``): the stride spaces the
+        run's events ``EVENT_PERIOD_S`` apart at the call time the last
+        run of as many calls showed, 1 where none has."""
+        with self._lock:
+            job = self._jobs.get(token)
+            return np.array([token, 1 if job is None else job.stride],
+                            np.int32)
 
     def finish(self, prompt_id: str, failed: bool = False) -> None:
         """Mark a run finished. ``failed=True`` freezes progress where it
@@ -120,6 +150,9 @@ class ProgressTracker:
                 job.done = True
                 job.failed = failed
                 if not failed:
+                    if job.calls_seen:     # up to its last event, launch in
+                        self._call_seconds[job.total] = (
+                            (job.updated - job.started) / job.calls_seen)
                     job.calls_seen = job.total
                 job.updated = time.time()
 
@@ -134,14 +167,14 @@ class ProgressTracker:
     # --- event sink (jax.debug.callback, runtime threads) ---------------
 
     def _on_event(self, token: int, shard: int, sigma: float,
-                  x0: np.ndarray) -> None:
+                  x0: np.ndarray, calls: int = 1) -> None:
         with self._lock:
             job = self._jobs.get(token)
             if job is None or job.done:
                 return
             job.updated = time.time()
             if shard == 0:
-                job.calls_seen += 1
+                job.calls_seen += calls
             prev = job.preview_sigmas.get(shard)
             if prev is None or sigma <= prev:
                 job.preview_sigmas[shard] = sigma

@@ -6,15 +6,22 @@ substrate"). In a jit-compiled world the sampler scan is one XLA program,
 so progress must stream out *through* the compiled boundary:
 ``wrap_denoiser`` interposes on the (guided) denoiser and emits
 ``jax.debug.callback`` effects carrying ``(token, shard, sigma, x0)``.
-Callbacks are asynchronous host effects — the TPU does not stall on them —
-and the payload is one latent (`x0[:1]`, ~256 KB for SDXL), so the
-overhead is negligible against a UNet step.
+The payload is one latent (`x0[:1]`, ~256 KB for SDXL), but an event is
+NOT free: on the TPU a callback is a host send plus a receive the program
+waits on, so every event idles the chip for one host round trip (2.9 ms
+measured under ``serve``) and hands it any stall of the host — a frozen
+host freezes the chip at its next event, a chip with no event due runs
+through (PERF.md §6, PR 25). So the stream is rate-limited on the device:
+``token`` may carry a *stride*, and inside a sampler scan (which tells
+the wrapper its step through ``sampler_step``) only every stride-th step
+reports, each event standing for ``stride`` calls.
 
-``token`` is a *traced* int32 scalar, so one compiled program serves every
-job: the host allocates a fresh token per run and the callback routes on
-its runtime value. Callbacks are unordered; ``sigma`` (strictly decreasing
-over the ladder) is the ordering key the sink uses to keep the newest
-preview and a monotonic step count.
+``token`` is *traced* — an int32 scalar, or ``[token, stride]`` — so one
+compiled program serves every job and every stride: the host allocates a
+fresh token per run and the callback routes on its runtime value.
+Callbacks are unordered; ``sigma`` (strictly decreasing over the ladder)
+is the ordering key the sink uses to keep the newest preview and a
+monotonic step count.
 
 This module is deliberately free of cluster/HTTP imports: sinks are
 registered (``add_sink``) by ``cluster/progress.ProgressTracker``.
@@ -28,19 +35,23 @@ simply misses on tokens it didn't issue.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import itertools
 import threading
 from collections import OrderedDict
 from typing import Callable, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..telemetry import metrics as _tm
 from ..telemetry import spans as _spans
 
-# sink(token:int, shard:int, sigma:float, x0:np.ndarray). Registry keyed by
-# handle so removal is exact; empty = events dropped on the floor.
+# sink(token:int, shard:int, sigma:float, x0:np.ndarray, calls:int), calls
+# being the denoiser calls the event stands for. Registry keyed by handle so
+# removal is exact; empty = events dropped on the floor.
 _LOCK = threading.Lock()
 _SINKS: "dict[int, Callable]" = {}
 _HANDLES = itertools.count(1)
@@ -98,6 +109,9 @@ def get_sink() -> Optional[Callable]:
 
 
 def _dispatch(token, shard, sigma, x0) -> None:
+    token, calls = np.asarray(token), 1
+    if token.ndim:                 # [token, calls]: a strided event
+        token, calls = token[0], int(token[1])
     with _LOCK:
         sinks = list(_SINKS.values())
         trace_id, parent_id = _ORIGIN.get(int(token), (None, None))
@@ -105,7 +119,8 @@ def _dispatch(token, shard, sigma, x0) -> None:
                            trace_id=trace_id, parent_id=parent_id):
         for sink in sinks:
             try:
-                sink(int(token), int(shard), float(sigma), np.asarray(x0))
+                sink(int(token), int(shard), float(sigma), np.asarray(x0),
+                     calls)
             except Exception:  # a broken UI consumer must never kill a job
                 pass
 
@@ -129,16 +144,49 @@ def total_calls(sampler: str, steps: int) -> int:
     return steps
 
 
+# the (traced) global ladder index of the sampler step being traced, set by
+# the sampler scans; None outside one (python ladders, a bare denoiser)
+_STEP: contextvars.ContextVar = contextvars.ContextVar(
+    "cdt_progress_step", default=None)
+
+
+@contextlib.contextmanager
+def sampler_step(index):
+    """Entered by ``samplers.run_program``/``run_segment`` around one
+    ladder step: lets a wrapped denoiser called inside it (in a branch of
+    the step too) know which step it serves."""
+    reset = _STEP.set(index)
+    try:
+        yield
+    finally:
+        _STEP.reset(reset)
+
+
 def wrap_denoiser(denoise, token, shard_index):
-    """Interpose on a denoiser: after every model call, stream the current
-    x0 estimate (first batch element) to the host sink. ``token`` may be a
-    traced scalar; ``shard_index`` a traced ``axis_index`` under
-    ``shard_map`` (each shard reports itself — the sink keys previews by
-    shard and counts steps on shard 0 only)."""
+    """Interpose on a denoiser: after a model call, stream the current x0
+    estimate (first batch element) to the host sink. ``token`` may be
+    traced: an int32 scalar (every call reports itself), or ``[token,
+    stride]`` — then, inside a sampler scan, only the calls of every
+    stride-th step report, each for ``stride`` calls, and the chip meets
+    the host that much less often. ``shard_index`` may be a traced
+    ``axis_index`` under ``shard_map`` (each shard reports itself — the
+    sink keys previews by shard and counts steps on shard 0 only)."""
 
     def wrapped(x, sigma):
         x0 = denoise(x, sigma)
-        jax.debug.callback(_dispatch, token, shard_index, sigma, x0[:1])
+        tok = jnp.asarray(token, jnp.int32)
+        step = _STEP.get()
+        if tok.ndim == 0 or step is None:
+            jax.debug.callback(_dispatch, tok.reshape(-1)[0], shard_index,
+                               sigma, x0[:1])
+            return x0
+        stride = jnp.maximum(tok[1], 1)
+        jax.lax.cond(
+            (step + 1) % stride == 0,
+            lambda: jax.debug.callback(
+                _dispatch, jnp.stack([tok[0], stride]), shard_index, sigma,
+                x0[:1]),
+            lambda: None)
         return x0
 
     return wrapped

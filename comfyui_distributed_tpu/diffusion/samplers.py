@@ -38,6 +38,8 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from .progress import sampler_step
+
 Denoiser = Callable[[jax.Array, jax.Array], jax.Array]   # (x, sigma[]) -> x0_hat
 
 
@@ -61,6 +63,18 @@ class SamplerProgram:
     extract: Callable[[tuple], jax.Array]
 
 
+def _scan_body(prog: SamplerProgram):
+    """The scan body of both drivers: one step at its GLOBAL index, which
+    a progress-streaming denoiser reads (``progress.sampler_step``) to
+    report only every stride-th step."""
+
+    def body(carry, i):
+        with sampler_step(i):
+            return prog.step(carry, i), None
+
+    return body
+
+
 def run_segment(prog: SamplerProgram, carry: tuple, start,
                 length: int) -> tuple:
     """Advance ``length`` steps from global index ``start``.
@@ -72,14 +86,14 @@ def run_segment(prog: SamplerProgram, carry: tuple, start,
     if length <= 0:
         return carry
     xs = jnp.asarray(start, jnp.int32) + jnp.arange(length, dtype=jnp.int32)
-    carry, _ = jax.lax.scan(lambda c, i: (prog.step(c, i), None), carry, xs)
+    carry, _ = jax.lax.scan(_scan_body(prog), carry, xs)
     return carry
 
 
 def run_program(prog: SamplerProgram, x: jax.Array) -> jax.Array:
     """The monolithic run: init → scan the whole ladder → extract."""
     carry = prog.init(x)
-    carry, _ = jax.lax.scan(lambda c, i: (prog.step(c, i), None), carry,
+    carry, _ = jax.lax.scan(_scan_body(prog), carry,
                             jnp.arange(prog.n_steps, dtype=jnp.int32))
     return prog.extract(carry)
 

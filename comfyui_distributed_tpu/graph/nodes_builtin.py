@@ -471,8 +471,15 @@ class _ProgressScope:
     ``jax.debug.callback`` effects (block_until_ready alone does not
     flush them) before exit marks the run done — anything else marks it
     failed, freezing progress where it stopped instead of reporting
-    100%. ``on_step`` is the host-side reporter for the offloaded
-    (python-ladder) samplers — same tracker, no traced token."""
+    100%. ``traced`` is what a compiled run takes as ``progress_token``:
+    the token with the tracker's event stride. ``on_step`` is the
+    host-side reporter for the offloaded (python-ladder) samplers — same
+    tracker, no traced token."""
+
+    @property
+    def traced(self):
+        return (None if self.token is None
+                else self.tracker.traced_token(self.token))
 
     def on_step(self, sigma: float, x0) -> None:
         if self.token is not None:
@@ -1039,7 +1046,7 @@ class TPUTxt2Img(NodeDef):
             images = pipeline.generate(
                 mesh, spec, int(seed), positive["context"],
                 negative["context"], y, uy, hint=hint,
-                progress_token=ps.token,
+                progress_token=ps.traced,
             )
             ps.complete(images)
         return (images,)
@@ -1061,7 +1068,7 @@ class TPUTxt2Img(NodeDef):
                 negative["context"], y, uy,
                 segment_steps=token.segment_steps,
                 should_preempt=token.should_preempt, resume=token.resume,
-                progress_token=ps.token,
+                progress_token=ps.traced,
             )
             if "checkpoint" in result:
                 # scope exit freezes the progress bar where it stopped
@@ -1247,7 +1254,7 @@ class TPUFlowTxt2Img(NodeDef):
             # execution with quantized-resident/streamed blocks — how
             # FLUX-12B runs without a pod (docs/deployment.md §5).
             # Progress: fully-resident runs stream in-trace via
-            # ps.token; streamed runs report host-side via ps.on_step.
+            # ps.traced; streamed runs report host-side via ps.on_step.
             from ..diffusion.progress import total_calls
 
             with _pinned(model), \
@@ -1256,7 +1263,7 @@ class TPUFlowTxt2Img(NodeDef):
                                                spec.steps)) as ps:
                 images = model.pipeline.generate_offloaded(
                     spec, int(seed), ctx, pooled, on_step=ps.on_step,
-                    progress_token=ps.token,
+                    progress_token=ps.traced,
                     should_stop=_stop_cb(interrupt_event))
                 ps.complete(images)
         elif mode == "sp":
@@ -1284,7 +1291,7 @@ class TPUFlowTxt2Img(NodeDef):
                                                spec.steps)) as ps:
                 images = model.pipeline.generate(
                     mesh, spec, int(seed), ctx, pooled,
-                    progress_token=ps.token,
+                    progress_token=ps.traced,
                     uncond_context=uncond_ctx,
                     uncond_pooled=uncond_pooled)
                 ps.complete(images)
@@ -1365,11 +1372,11 @@ class TPUTxt2Video(NodeDef):
                 # full-size single-chip execution with quantized expert
                 # residency + dual-expert HBM swap — how WAN-14B runs
                 # without a pod (diffusion/offload.OffloadedWan).
-                # Progress: in-trace via ps.token when resident,
+                # Progress: in-trace via ps.traced when resident,
                 # host-side via ps.on_step when streaming.
                 videos = model.pipeline.generate_offloaded(
                     spec, int(seed), ctx, on_step=ps.on_step,
-                    progress_token=ps.token,
+                    progress_token=ps.traced,
                     should_stop=_stop_cb(interrupt_event))
             elif mode == "sp":
                 if "sp" not in mesh.shape:
@@ -1377,11 +1384,11 @@ class TPUTxt2Video(NodeDef):
                                       list(mesh.devices.flat))
                 videos = model.pipeline.generate_frames(
                     mesh, spec, int(seed), ctx, pooled,
-                    progress_token=ps.token)
+                    progress_token=ps.traced)
             else:
                 videos = model.pipeline.generate(mesh, spec, int(seed),
                                                  ctx, pooled,
-                                                 progress_token=ps.token)
+                                                 progress_token=ps.traced)
             ps.complete(videos)
         return (_flatten_video_batch(videos),)
 
@@ -1440,7 +1447,7 @@ class TPUImg2Video(NodeDef):
             if mode == "offload" or (mode == "dp" and offload_enabled()):
                 videos = model.pipeline.generate_offloaded_i2v(
                     spec, int(seed), image[:1], ctx, on_step=ps.on_step,
-                    progress_token=ps.token,
+                    progress_token=ps.traced,
                     should_stop=_stop_cb(interrupt_event))
             elif mode == "sp":
                 if "sp" not in mesh.shape:
@@ -1448,11 +1455,11 @@ class TPUImg2Video(NodeDef):
                                       list(mesh.devices.flat))
                 videos = model.pipeline.generate_i2v_frames(
                     mesh, spec, int(seed), image[:1], ctx, pooled,
-                    progress_token=ps.token)
+                    progress_token=ps.traced)
             else:
                 videos = model.pipeline.generate_i2v(
                     mesh, spec, int(seed), image[:1], ctx, pooled,
-                    progress_token=ps.token)
+                    progress_token=ps.traced)
             ps.complete(videos)
         return (_flatten_video_batch(videos),)
 

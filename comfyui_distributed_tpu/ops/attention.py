@@ -88,6 +88,7 @@ def _flash_enabled(q_len: Optional[int] = None,
 
 import contextlib as _contextlib
 import contextvars as _contextvars
+import dataclasses as _dataclasses
 import threading as _threading
 
 # tp shard degree of the program currently being traced: a tp-sharded
@@ -118,10 +119,24 @@ _SELECTIONS: "dict[str, str]" = {}
 _SELECTIONS_LOCK = _threading.Lock()
 
 
-def _note_selection(geometry: str, choice) -> None:
-    desc = choice.tier
-    if choice.block_q is not None:
-        desc += f":{choice.block_q}/{choice.block_k}"
+def _blocks_label(choice, kv_len: Optional[int] = None) -> str:
+    """'<block_q>/<block_k>' of a choice, '' for a tier without blocks;
+    a packed choice also says whether its K tile holds the whole sequence
+    (``k-resident``: one K step, the one-pass softmax form) or the
+    sequence streams through it (``k-streamed``)."""
+    if choice.block_q is None:
+        return ""
+    label = f"{choice.block_q}/{choice.block_k}"
+    if choice.tier == "packed" and kv_len is not None:
+        label += (":k-resident" if choice.block_k >= kv_len
+                  else ":k-streamed")
+    return label
+
+
+def _note_selection(geometry: str, choice,
+                    kv_len: Optional[int] = None) -> None:
+    blocks = _blocks_label(choice, kv_len)
+    desc = choice.tier + (f":{blocks}" if blocks else "")
     with _SELECTIONS_LOCK:
         if _SELECTIONS.get(geometry) == desc:
             return
@@ -136,9 +151,27 @@ def _note_selection(geometry: str, choice) -> None:
 
         if _tm_enabled():
             _tm.ATTN_KERNEL_SELECTED.labels(
-                tier=choice.tier, geometry=geometry).inc()
+                tier=choice.tier, geometry=geometry, blocks=blocks).inc()
     except Exception:  # noqa: BLE001 — observability must not sink dispatch
         pass
+
+
+def _with_packed_blocks(choice, q_len: int, kv_len: int, head_dim: int,
+                        dtype):
+    """A packed choice with the blocks its call will run: what the table
+    row or the env knobs requested, the rest derived from the shape
+    (``flash_attention._packed_blocks``) — resolved here so that the
+    selection log and counter show them, and handed to the call so that
+    it cannot resolve others."""
+    if choice.tier != "packed":
+        return choice
+    from .autotune import itemsize_of
+    from .flash_attention import _packed_blocks, _requested_blocks
+
+    bq, bk = _packed_blocks(
+        q_len, kv_len, head_dim, itemsize_of(dtype),
+        *_requested_blocks(choice.block_q, choice.block_k))
+    return _dataclasses.replace(choice, block_q=bq, block_k=bk)
 
 
 def selection_summary() -> str:
@@ -209,18 +242,14 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
                                   and tuned.tier == "xla"):
         choice = tuned
         if choice.tier == "fused" and not fusable:
-            from .autotune import itemsize_of
-            from .flash_attention import _packed_feasible
+            from .flash_attention import _packed_legal
 
-            feas = _packed_feasible(num_heads, head_dim,
-                                    choice.block_q, choice.block_k,
-                                    itemsize_of(dtype))
             choice = KernelChoice(
-                "packed" if feas else "bh",
-                *(feas or (choice.block_q, choice.block_k)),
-                source="table",
+                "packed" if _packed_legal(num_heads, head_dim) else "bh",
+                choice.block_q, choice.block_k, source="table",
                 reason="fused choice at a non-fusable site")
-        _note_selection(geometry, choice)
+        choice = _with_packed_blocks(choice, q_len, kv_len, head_dim, dtype)
+        _note_selection(geometry, choice, kv_len)
         return choice
 
     # env knobs + measured-floor defaults (the pre-table behavior)
@@ -243,7 +272,8 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
         choice = KernelChoice("packed", reason=why)
     else:
         choice = KernelChoice("bh", reason=why)
-    _note_selection(geometry, choice)
+    choice = _with_packed_blocks(choice, q_len, kv_len, head_dim, dtype)
+    _note_selection(geometry, choice, kv_len)
     return choice
 
 

@@ -18,8 +18,9 @@ Layout of the decision data:
   own sweep.
 - **KernelChoice** — (tier, block_q, block_k): tier is one of ``fused``
   (QKV projection folded into the flash grid), ``packed`` ([B, N, H·D]
-  native layout, VMEM-shrunk blocks where needed), ``bh`` (classic
-  [B·H, N, D] call), ``xla`` (the fused XLA lowering).
+  native layout walked in 128-lane head groups; a row without blocks
+  takes them from the shape — K resident where it fits), ``bh``
+  (classic [B·H, N, D] call), ``xla`` (the fused XLA lowering).
 - **TuningTable** — two layers: the resolved table for the known model
   zoo shipped in-repo (``ops/attn_table_default.json``, rebakeable with
   ``scripts/autotune_sweep.py``) plus a local overlay persisted next to
@@ -151,8 +152,8 @@ class KernelChoice:
     """A resolved kernel config: what ``full_attention`` should run."""
 
     tier: str
-    block_q: Optional[int] = None      # None: tier has no blocks (xla)
-    block_k: Optional[int] = None
+    block_q: Optional[int] = None      # None: tier has no blocks (xla),
+    block_k: Optional[int] = None      # or packed derives them (shape)
     source: str = "default"            # default | env | table | sweep
     reason: str = ""
 
@@ -195,20 +196,24 @@ def validate_entry(key: GeometryKey, choice: KernelChoice) -> list[str]:
         if choice.block_q is not None or choice.block_k is not None:
             errors.append("xla tier takes no block sizes")
         return errors
+    packed = choice.tier == "packed"       # its unset blocks stay unset
     try:
-        bq, bk = fa.resolve_flash_blocks(choice.block_q, choice.block_k)
+        bq, bk = (fa._requested_blocks if packed else
+                  fa.resolve_flash_blocks)(choice.block_q, choice.block_k)
     except ValueError as e:
         return [str(e)]
-    if choice.tier == "packed":
-        feas = fa._packed_feasible(H, D, bq, bk, itemsize)
-        if feas is None:
+    if packed:
+        if not fa._packed_legal(H, D):
             errors.append(
-                f"packed tier infeasible at H={H}, D={D} ({key.dtype})")
-        elif feas != (bq, bk):
-            errors.append(
-                f"blocks {bq}/{bk} exceed the VMEM model at H·D={H * D} "
-                f"({key.dtype}); largest feasible {feas[0]}/{feas[1]}")
-    elif choice.tier == "fused":
+                f"packed tier illegal at H={H}, D={D} ({key.dtype})")
+        else:
+            try:
+                fa._packed_blocks(key.q_bucket, key.kv_bucket, D, itemsize,
+                                  bq, bk)
+            except ValueError as e:
+                errors.append(str(e))
+        return errors
+    if choice.tier == "fused":
         feas = fa._fused_feasible(H * D, H, D, bq, bk, itemsize)
         if feas is None:
             errors.append(
@@ -372,7 +377,7 @@ def lookup(num_heads: int, head_dim: int, q_len: int, kv_len: int,
 # --- sweeping ----------------------------------------------------------------
 
 BLOCK_Q_CANDIDATES = (128, 256, 512)
-BLOCK_K_CANDIDATES = (128, 256, 512)     # flash_attention._MAX_BLOCK_K
+BLOCK_K_CANDIDATES = (128, 256, 512)     # fused: flash_attention._MAX_BLOCK_K
 
 # engagement floors measured r04 (docs/roofline.md finding 1a): below
 # them XLA's fused lowering wins and the sweep doesn't bother timing
@@ -405,11 +410,14 @@ def candidates_for(key: GeometryKey) -> list[KernelChoice]:
                                       itemsize) == (bq, bk):
                     out.append(KernelChoice("fused", bq, bk,
                                             source="sweep"))
-        for bq in BLOCK_Q_CANDIDATES:
-            for bk in BLOCK_K_CANDIDATES:
-                if fa._packed_feasible(H, D, bq, bk, itemsize) == (bq, bk):
-                    out.append(KernelChoice("packed", bq, bk,
-                                            source="sweep"))
+    if long_enough and fa._packed_legal(H, D):
+        # the shape's blocks first, then each q block against the K tile
+        # the shape gives it (K resident where it fits): short K chunks
+        # measured behind the parent kernel (PERF.md §6, PR 25) and are
+        # not offered
+        out.append(KernelChoice("packed", source="sweep"))
+        out.extend(KernelChoice("packed", bq, source="sweep")
+                   for bq in BLOCK_Q_CANDIDATES)
     if key.q_bucket >= _BH_MIN_Q or long_enough:
         for bq, bk in ((256, 512), (256, 1024), (512, 512)):
             out.append(KernelChoice("bh", bq, bk, source="sweep"))
@@ -422,9 +430,9 @@ def resolve_policy_choice(key: GeometryKey) -> KernelChoice:
     and the shipped-table bake use. Encodes the r04/r05 measurements as
     a ranking instead of a stopwatch: fused where it fits with real
     tiles (boundary cost beats the K/V-projection recompute only when
-    the working set isn't starved), else packed (VMEM-shrunk blocks
-    where the native ceiling is exceeded), else the classic bh call at
-    long-N, else xla. A timed sweep on hardware overrides all of this."""
+    the working set isn't starved), else packed (blocks left to the
+    shape), else the classic bh call at long-N, else xla. A timed sweep
+    on hardware overrides all of this."""
     from . import flash_attention as fa
 
     itemsize = itemsize_of(key.dtype)
@@ -445,14 +453,11 @@ def resolve_policy_choice(key: GeometryKey) -> KernelChoice:
                             reason="fused feasible with non-starved "
                                    "tiles: boundary cost > projection "
                                    "recompute")
-    packed = fa._packed_feasible(H, D, itemsize=itemsize)
-    if packed is not None:
-        why = ("native packed layout (r04 finding 1a)"
-               if H * D <= fa._PACKED_MAX_HD
-               else "VMEM-shrunk packed tiles past the native H·D "
-                    "ceiling (block-shrink legality path, ISSUE 8)")
-        return KernelChoice("packed", packed[0], packed[1],
-                            source="sweep", reason=why)
+    if fa._packed_legal(H, D):
+        return KernelChoice("packed", source="sweep",
+                            reason="native packed layout (r04 finding "
+                                   "1a), blocks from the shape: K resident "
+                                   "where it fits (PR 25)")
     return KernelChoice("bh", fa._DEFAULT_BLOCK_Q, fa._DEFAULT_BLOCK_K,
                         source="sweep",
                         reason="packed geometrically illegal")

@@ -17,17 +17,37 @@ The reference has no analogue (its compute hot loop is ComfyUI's
 ``common_ksampler``, SURVEY §3.3); this kernel sits *under* the parity
 surface as the execution engine's attention primitive.
 
-Kernel structure (standard TPU flash attention):
-grid = (batch·heads, Nq/block_q, Nk/block_k), K-blocks innermost so the
-running max ``m``, denominator ``l`` and output accumulator live in VMEM
-scratch across grid steps; the output block is written once on the final
-K step. Sequence lengths are padded to block multiples at trace time and
-masked with a static-length comparison — shapes stay static for XLA.
+Kernel structure. The classic (``bh``) and fused tiers are standard TPU
+flash attention: grid = (batch[·heads], Nq/block_q, Nk/block_k), K-blocks
+innermost so the running max ``m``, denominator ``l`` and output
+accumulator live in VMEM scratch across grid steps; the output block is
+written once on the final K step.
+
+The packed tier (``_flash_mha_packed``, the default wherever it is legal)
+puts heads on the grid instead. Operands stay in the model's natural
+``[B, N, H·D]`` layout and are tiled in 128-lane *groups* — two D=64 heads
+or one D=128 head: grid = (batch, H·D/128, Nq/block_q, Nk/block_k), tiles
+``(1, block_q, 128)`` and ``(1, block_k, 128)``. A K/V tile is one group
+wide, not H·D wide, so ``block_k`` is chosen from the shape up to the whole
+padded sequence (``_packed_blocks``): then K and V of a (batch, group) are
+fetched once and stay resident in VMEM while the q blocks walk past them,
+and no softmax state crosses a grid step. Inside a step K is read in
+slabs of at most ``_PACKED_SLAB`` rows (unrolled up to three, looped
+over beyond) with the online softmax carried in values. Only a sequence
+whose K/V do not fit (tens of thousands of tokens) streams ``block_k``
+chunks over the innermost grid axis, carrying ``m``/``l``/acc in scratch
+as the other tiers do.
+
+Sequence lengths are padded at trace time (K to a multiple of 128 when
+resident, q to a multiple of the chosen ``block_q``) and the padding tail
+is masked with a static-length comparison, in the packed tier only on
+the slab that holds it — shapes stay static for XLA.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -74,26 +94,34 @@ def _check_block(name: str, value: int, multiple: int) -> None:
             "pallas would fail during Mosaic lowering otherwise")
 
 
-def resolve_flash_blocks(block_q: Optional[int] = None,
-                         block_k: Optional[int] = None) -> tuple[int, int]:
-    """Resolve (block_q, block_k): explicit args win, then the
-    ``CDT_FLASH_BLOCK_Q``/``CDT_FLASH_BLOCK_K`` env knobs, then the
-    measured defaults (256/512, r04). Both sources are validated at
-    parse time — a non-positive or non-(8,128)-divisible value raises a
-    descriptive ``ValueError`` here instead of letting pallas fail deep
-    in lowering (tuning-table entries pass through the same check via
-    ``ops/autotune.py``)."""
+def _requested_blocks(block_q: Optional[int] = None,
+                      block_k: Optional[int] = None
+                      ) -> tuple[Optional[int], Optional[int]]:
+    """What the caller or the operator ASKED for: explicit args win, then
+    the ``CDT_FLASH_BLOCK_Q``/``CDT_FLASH_BLOCK_K`` env knobs; None where
+    neither spoke (each tier then applies its own default). Both sources
+    are validated here — a non-positive or non-(8,128)-divisible value
+    raises a descriptive ``ValueError`` instead of letting pallas fail
+    deep in lowering (tuning-table entries pass through the same check
+    via ``ops/autotune.py``)."""
     if block_q is None:
         block_q = _parse_block_env("CDT_FLASH_BLOCK_Q", _SUBLANES)
-        block_q = _DEFAULT_BLOCK_Q if block_q is None else block_q
     else:
         _check_block("block_q", block_q, _SUBLANES)
     if block_k is None:
         block_k = _parse_block_env("CDT_FLASH_BLOCK_K", _LANES)
-        block_k = _DEFAULT_BLOCK_K if block_k is None else block_k
     else:
         _check_block("block_k", block_k, _LANES)
     return block_q, block_k
+
+
+def resolve_flash_blocks(block_q: Optional[int] = None,
+                         block_k: Optional[int] = None) -> tuple[int, int]:
+    """``_requested_blocks`` with the classic/fused tiers' measured
+    defaults (256/512, r04) where nothing was requested."""
+    block_q, block_k = _requested_blocks(block_q, block_k)
+    return (_DEFAULT_BLOCK_Q if block_q is None else block_q,
+            _DEFAULT_BLOCK_K if block_k is None else block_k)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
@@ -150,11 +178,10 @@ def _accumulate_packed_heads(q, k, v, j, m_ref, l_ref, acc_ref, *,
                              kv_len: int, block_k: int, scale: float,
                              precision, num_heads: int, head_dim: int):
     """One K-block accumulation over statically-unrolled heads, operands
-    in the packed [block, H·D] layout. Head h's running max/denominator
-    live in lane h of the [BQ, 128] m/l scratches (hence ``num_heads ≤
-    128``). Shared by the packed and fused kernel tiers — the fused tier
-    differs only in where q/k/v come from (projected in-kernel), not in
-    the accumulation math."""
+    in the full-width [block, H·D] layout the fused tier projects them
+    into. Head h's running max/denominator live in lane h of the
+    [BQ, 128] m/l scratches (hence ``num_heads ≤ 128``). (The packed
+    tier used this loop too until its heads moved onto the grid.)"""
     col = jax.lax.broadcasted_iota(
         jnp.int32, (q.shape[0], block_k), 1) if kv_len % block_k else None
 
@@ -194,18 +221,147 @@ def _finalize_packed_heads(o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0, :, sl] = (acc_ref[:, sl] / l).astype(o_ref.dtype)
 
 
-def _flash_kernel_packed(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                         *, kv_len: int, block_k: int, num_k_blocks: int,
-                         scale: float, precision, num_heads: int,
-                         head_dim: int):
-    """Packed-heads variant: refs are [1, block, H·D] slices of the
-    model's NATURAL layout — the fused QKV projection emits [B, N, H·D]
-    and splitting heads along the minor axis is free, so no transpose
-    ever happens at the custom-call boundary (the boundary relayout, not
-    the kernel body, is what made the classic [B·H, N, D] call lose to
-    XLA fused attention at SDXL sequence lengths — `docs/roofline.md`
-    finding 1)."""
-    j = pl.program_id(2)
+def _stack_group_heads(q, head_dim: int):
+    """[BQ, W] q tile of one head group → [heads·BQ, W]: row block h
+    keeps head h's lanes and zeroes the others, so ONE 128-deep QKᵀ and
+    ONE 128-wide PV serve every head of the group — the MXU spends a
+    128-deep, 128-wide pass on a D=64 head either way, and no operand is
+    ever sliced at a 64-lane offset (measured on the v5e against 64-lane
+    slices: PERF.md §6, PR 25). A zeroed lane adds an exact 0 to the f32
+    logit. D ≥ 128 groups hold one head and pass through."""
+    block_q, width = q.shape
+    heads = width // head_dim
+    if heads == 1:
+        return q
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 1)
+    return jnp.concatenate(
+        [jnp.where((lane >= h * head_dim) & (lane < (h + 1) * head_dim),
+                   q, jnp.zeros_like(q)) for h in range(heads)], axis=0)
+
+
+def _unstack_group_heads(o, block_q: int, head_dim: int):
+    """[heads·BQ, W] → [BQ, W]: head h's lanes from row block h."""
+    width = o.shape[1]
+    out = o[:block_q]
+    if width == head_dim:
+        return out
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 1)
+    for h in range(1, width // head_dim):
+        out = jnp.where(lane >= h * head_dim,
+                        o[h * block_q:(h + 1) * block_q], out)
+    return out
+
+
+def _mask_k_tail(s, valid: int):
+    """NEG_INF on columns ≥ ``valid`` (a Python int) of one slab's
+    logits. Only the 128-column slabs that hold padding are touched: the
+    compare-and-select is paid where a tail is, not on every logit."""
+    if valid >= s.shape[1]:
+        return s
+    lo = max(valid, 0) // _LANES * _LANES
+    tail = s[:, lo:]
+    col = jax.lax.broadcasted_iota(jnp.int32, tail.shape, 1)
+    tail = jnp.where(col < valid - lo, tail, NEG_INF)
+    return tail if lo == 0 else jnp.concatenate([s[:, :lo], tail], axis=1)
+
+
+def _scale_folds_into_q(head_dim: int, dtype) -> bool:
+    """Whether 1/√D may multiply q instead of the logits: only where the
+    result is bit-identical (a power of two — D=64's 0.125 — commutes
+    with every rounding) or q is f32 (one f32 rounding ahead of the MXU's
+    own split). A bf16 q under D=128's 0.0884 would take a second
+    rounding, so those logits are scaled as before."""
+    return (math.frexp(head_dim ** -0.5)[0] == 0.5
+            or jnp.dtype(dtype).itemsize == 4)
+
+
+def _flash_kernel_packed(q_ref, k_ref, v_ref, o_ref, *scratch,
+                         kv_len: int, block_k: int, num_k_blocks: int,
+                         slab: int, head_dim: int, precision):
+    """One (batch, head group, q block, K chunk) step of the packed tier.
+    Refs are [1, block, W] tiles of the natural [B, N, H·D] layout, W the
+    group's lanes (``_packed_group``). The K chunk is walked in
+    ``slab``-row pieces with the online softmax carried in values; f32
+    logits, max, exp, sum and accumulator, MXU operands in the operand
+    dtype. With one K chunk (K/V resident, the usual case) nothing else
+    exists: no scratch, no state across grid steps, one normalisation at
+    the end. With several, m/l/acc pass from step to step through
+    scratch, and the tail mask is compiled only into the last."""
+    block_q = q_ref.shape[1]
+    scale = head_dim ** -0.5
+    q = q_ref[0]
+    fold = _scale_folds_into_q(head_dim, q.dtype)
+    if fold:
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    qs = _stack_group_heads(q, head_dim)
+    valid_last = kv_len - (num_k_blocks - 1) * block_k
+
+    def slab_step(state, start, rows: int, valid=None):
+        """One K slab into the running (m, l, acc); ``m is None`` on the
+        very first slab of a resident call, which needs no rescale."""
+        m, l, acc = state
+        k = k_ref[0, pl.ds(start, rows), :]
+        v = v_ref[0, pl.ds(start, rows), :]
+        s = jax.lax.dot_general(
+            qs, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+        if not fold:
+            s = s * scale
+        if valid is not None:
+            s = _mask_k_tail(s, valid)
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = m_cur if m is None else jnp.maximum(m, m_cur)
+        p = jnp.exp(s - m_new)
+        l_cur = jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+        if m is None:
+            return m_new, l_cur, pv
+        corr = jnp.exp(m - m_new)
+        return m_new, l * corr + l_cur, acc * corr + pv
+
+    def accumulate(state, masked: bool):
+        """The K chunk, slab by slab. The full slabs that hold no padding
+        come first: up to ``_PACKED_UNROLL_SLABS`` of them are unrolled
+        (SD3 has two and a tail: the form measured), more are looped
+        over, so neither the compiled body nor its VMEM grows with the
+        sequence. What is left — a shorter last slab, slabs with padding
+        — is unrolled, and only slabs with padding are masked."""
+        valid = valid_last if masked else block_k
+        clean = min(block_k, valid) // slab
+        if clean <= _PACKED_UNROLL_SLABS:
+            for i in range(clean):
+                state = slab_step(state, i * slab, slab)
+        else:
+            if state[0] is None:
+                rows = qs.shape[0]
+                state = (jnp.full((rows, 1), NEG_INF, jnp.float32),
+                         jnp.zeros((rows, 1), jnp.float32),
+                         jnp.zeros(qs.shape, jnp.float32))
+            state = jax.lax.fori_loop(
+                0, clean,
+                lambda i, st: slab_step(
+                    st, pl.multiple_of(i * slab, _LANES), slab),
+                state)
+        for start in range(clean * slab, block_k, slab):
+            rows = min(slab, block_k - start)
+            state = slab_step(state, start, rows,
+                              valid - start if start + rows > valid
+                              else None)
+        return state
+
+    def write_out(l, acc):
+        o_ref[0] = _unstack_group_heads(acc / l, block_q,
+                                        head_dim).astype(o_ref.dtype)
+
+    if num_k_blocks == 1:
+        _, l, acc = accumulate((None, None, None), masked=True)
+        write_out(l, acc)
+        return
+
+    m_ref, l_ref, acc_ref = scratch
+    j = pl.program_id(3)
 
     @pl.when(j == 0)
     def _init():
@@ -213,15 +369,32 @@ def _flash_kernel_packed(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    _accumulate_packed_heads(
-        q_ref[0], k_ref[0], v_ref[0], j, m_ref, l_ref, acc_ref,
-        kv_len=kv_len, block_k=block_k, scale=scale, precision=precision,
-        num_heads=num_heads, head_dim=head_dim)
+    def step(masked: bool):
+        m, l, acc = accumulate((m_ref[:, :1], l_ref[:, :1], acc_ref[:]),
+                               masked)
+        m_ref[:] = jnp.broadcast_to(m, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l, l_ref.shape)
+        acc_ref[:] = acc
+
+    if valid_last < block_k:
+        pl.when(j < num_k_blocks - 1)(lambda: step(False))
+        pl.when(j == num_k_blocks - 1)(lambda: step(True))
+    else:
+        step(False)
 
     @pl.when(j == num_k_blocks - 1)
     def _finalize():
-        _finalize_packed_heads(o_ref, m_ref, l_ref, acc_ref,
-                               num_heads=num_heads, head_dim=head_dim)
+        write_out(l_ref[:, :1], acc_ref[:])
+
+
+def _packed_scratch(block_q: int, head_dim: int) -> list:
+    """m / l (lane-replicated) and the f32 accumulator of a streamed
+    packed call, one row per (head of the group, q row)."""
+    width, heads = _packed_group(head_dim)
+    rows = heads * block_q
+    return [pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, width), jnp.float32)]
 
 
 def _flash_kernel_fused(xq_ref, xkv_ref, wq_ref, wk_ref, wv_ref, o_ref,
@@ -415,15 +588,19 @@ def _flash_mha(q, k, v, block_q: int, block_k: int, interpret: bool):
 def _flash_mha_packed(q, k, v, num_heads: int, block_q: int, block_k: int,
                       interpret: bool):
     """Packed-heads pallas call: operands stay [B, N, H·D] — the QKV
-    projection's own output layout — and the kernel splits heads along
-    the minor axis (free). Legality (``_packed_legal``): H·D % 128 == 0,
-    H ≤ 128, H·D ≤ ``_PACKED_MAX_HD``, and D % 64 == 0 (lane-aligned
-    head slices) — true for SDXL (640/1280) and WAN (1536); FLUX (3072)
-    exceeds the VMEM bound and stays on the classic [B·H, N, D] call."""
+    projection's own output layout, so no transpose ever happens at the
+    custom-call boundary (the boundary relayout, not the kernel body, is
+    what made the classic [B·H, N, D] call lose to XLA fused attention
+    at SDXL sequence lengths — `docs/roofline.md` finding 1) — and the
+    grid walks them in 128-lane head groups. ``block_k`` rows of K/V are
+    one grid step's tile: the whole padded sequence when it fits
+    (``_packed_blocks``), which makes the K/V index map constant along
+    the q axis, so a (batch, group)'s K and V are fetched once.
+    Legality: ``_packed_legal``."""
     B, Nq, HD = q.shape
     _, Nk, _ = k.shape
     D = HD // num_heads
-    scale = 1.0 / (D ** 0.5)
+    W, _ = _packed_group(D)
 
     qp, kp, vp, precision, out_sds = _pad_and_prepare(q, k, v, block_q,
                                                       block_k)
@@ -432,54 +609,74 @@ def _flash_mha_packed(q, k, v, num_heads: int, block_q: int, block_k: int,
 
     kernel = functools.partial(
         _flash_kernel_packed, kv_len=Nk, block_k=block_k, num_k_blocks=nkb,
-        scale=scale, precision=precision, num_heads=num_heads, head_dim=D)
+        slab=_packed_slab(block_k), head_dim=D, precision=precision)
 
-    q_spec = pl.BlockSpec((1, block_q, HD), lambda b, i, j: (b, i, 0),
+    q_spec = pl.BlockSpec((1, block_q, W), lambda b, g, i, j: (b, i, g),
                           memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, block_k, HD), lambda b, i, j: (b, j, 0),
+    kv_spec = pl.BlockSpec((1, block_k, W), lambda b, g, i, j: (b, j, g),
                            memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         kernel,
-        grid=(B, nqb, nkb),
+        grid=(B, HD // W, nqb, nkb),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
         out_shape=out_sds,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),   # per-head max
-            pltpu.VMEM((block_q, _LANES), jnp.float32),   # per-head sum
-            pltpu.VMEM((block_q, HD), jnp.float32),       # output acc
-        ],
+        scratch_shapes=_packed_scratch(block_q, D) if nkb > 1 else [],
+        # the algorithm's cost, not the body's: stacked D=64 heads issue
+        # 128-deep passes, and a looped slab has no static trip count to
+        # read off the jaxpr (utils/flops.py takes this number)
+        cost_estimate=pl.CostEstimate(
+            flops=4 * B * num_heads * Nq * Nk * D,
+            transcendentals=B * num_heads * Nq * Nk,
+            bytes_accessed=(2 * q.size + k.size + v.size)
+            * q.dtype.itemsize),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_PACKED_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(qp, kp, vp)
     return out[:, :Nq]
 
 
-# past this packed width the kernel needs shrunken q/k blocks to keep
-# its VMEM working set (double-buffered [block, H·D] K/V tiles + the
-# f32 accumulator) inside the ~16 MB budget. The DEFAULT auto-layout
-# stays classic past this width — the one shrink probed at r04 (FLUX's
-# H·D = 3072, 128/256 blocks) ran the offload ladder at 1.34 s/step vs
-# the classic [B·H, N, D] call's 1.21 s (`benchmarks/r04_tpu_flux.json`)
-# — but shrunken-packed is now *reachable* (explicit ``layout="packed"``
-# or a tuning-table entry, ``ops/autotune.py``): the r04 probe tried one
-# block pair, and the autotune sweep walks the whole feasible set.
-_PACKED_MAX_HD = 2048
-
-# scoped-VMEM limit of one kernel on the chip, and what the working-set
-# models below are checked against. Calibrated against the v5e compiler
-# itself (docs/kernels.md, "VMEM model"; tests/test_chip_compile.py asks
-# it again on every run): the compiler's own count has two parts. The
-# tiles and scratch the call declares, which the first two terms of each
-# model reproduce to the byte; and scratch for the values the kernel
-# BODY holds, which the declaration does not show and which the models
-# estimate from the body's largest live values.
+# scoped-VMEM limit of one fused or classic kernel on the chip (the
+# compiler's default), and what `_fused_vmem_bytes` is checked against.
+# Calibrated against the v5e compiler itself (docs/kernels.md, "VMEM
+# model"; tests/test_chip_compile.py asks it again on every run): the
+# compiler's own count has two parts. The tiles and scratch the call
+# declares, which the first terms of each model reproduce to the byte;
+# and scratch for the values the kernel BODY holds, which the declaration
+# does not show and which the models estimate from the body's largest
+# live values.
 _VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 _MIN_BLOCK_Q = 64     # shrink floors: below these tiles the grid is all
 _MIN_BLOCK_K = 128    # overhead (one lane tile / 8 sublane tiles)
-# K tiles wider than this are not offered: at 1024 rows the compiler's
-# body scratch alone ran from 2 MB (H·D=640) to 19 MB (H·D=3072) — on no
-# probed width did a 1024-row K tile fit where a 512-row one did not.
+# fused K tiles wider than this are not offered: the fused tier's tiles
+# are full-width ([block, C] and [block, H·D]), and at 1024 rows the
+# compiler's body scratch alone ran from 2 MB (H·D=640) to 19 MB
+# (H·D=3072) — on no probed width did a 1024-row K tile fit where a
+# 512-row one did not.
 _MAX_BLOCK_K = 512
+
+# The packed call asks the compiler for its own scoped limit
+# (`vmem_limit_bytes`; a v5e core has 128 MiB of VMEM) and plans its
+# blocks inside a budget below it: the margin is for what the model
+# cannot see of the compiler's body scratch.
+_PACKED_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+_PACKED_VMEM_BUDGET_BYTES = 48 * 1024 * 1024
+# longest K slab one in-body softmax step takes: measured on the v5e at
+# SD3's 4224 padded tokens, three slabs of 1408 beat one of 4224 by 11%
+# and eleven of 384 by 8% (PERF.md §6, PR 25)
+_PACKED_SLAB = 1536
+# a K chunk of up to this many slabs is unrolled in the body; a longer one
+# loops over its full slabs
+_PACKED_UNROLL_SLABS = 3
+# default q rows of one grid step, before rounding to the sequence
+_PACKED_BLOCK_Q = 512
+
+
+def _round_up(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
 
 
 def _logits_bytes(block_q: int, block_k: int) -> int:
@@ -489,14 +686,88 @@ def _logits_bytes(block_q: int, block_k: int) -> int:
     return block_q * block_k * (4 + 4 + 2)
 
 
-def _packed_vmem_bytes(hd: int, block_q: int, block_k: int,
-                       itemsize: int) -> int:
+def _packed_group(head_dim: int) -> tuple[int, int]:
+    """(lanes, heads) of one packed head group: the narrowest whole
+    number of heads that fills whole 128-lane tiles — two D=64 heads or
+    one D=128 head in 128 lanes."""
+    width = head_dim * _LANES // math.gcd(head_dim, _LANES)
+    return width, width // head_dim
+
+
+def _packed_slab(block_k: int) -> int:
+    """Rows of one in-body K slab: ``block_k`` cut into the fewest equal
+    lane-aligned pieces no longer than ``_PACKED_SLAB``."""
+    pieces = -(-block_k // _PACKED_SLAB)
+    return _round_up(-(-block_k // pieces), _LANES)
+
+
+def _packed_vmem_bytes(head_dim: int, block_q: int, block_k: int,
+                       itemsize: int, streamed: bool = False) -> int:
     """Scoped VMEM of one packed-kernel grid step: double-buffered
-    q/k/v/out tiles in the operand dtype, the f32 output accumulator and
-    the two lane-replicated m/l scratches, plus the body's logits."""
-    io = 2 * (2 * block_q * hd + 2 * block_k * hd) * itemsize
-    scratch = block_q * hd * 4 + 2 * block_q * _LANES * 4
-    return io + scratch + _logits_bytes(block_q, block_k)
+    q/k/v/out tiles of one head group in the operand dtype (the declared
+    part, which the compiler counts to the byte); what the body holds —
+    the group's stacked q, one K slab's logits, the f32 accumulator and
+    its PV addend; and, only when K streams over the grid, the m/l/acc
+    scratch. The logits term is calibrated against the v5e compiler
+    (docs/kernels.md): its stack for the unrolled slabs came to 9.5–10.5
+    B a slab logit with bf16 operands and 18–19 B with f32 (HIGHEST
+    splits the operands), and 4–5.5 B where the slabs are looped over;
+    6 × itemsize covers every probe."""
+    width, heads = _packed_group(head_dim)
+    rows = heads * block_q
+    io = 2 * (2 * block_q + 2 * block_k) * width * itemsize
+    body = (rows * width * itemsize
+            + rows * _packed_slab(block_k) * 6 * itemsize
+            + 2 * rows * width * 4)
+    scratch = (rows * width * 4 + 2 * rows * _LANES * 4) if streamed else 0
+    return io + body + scratch
+
+
+def _packed_blocks(q_len: int, kv_len: int, head_dim: int, itemsize: int = 2,
+                   block_q: Optional[int] = None,
+                   block_k: Optional[int] = None) -> tuple[int, int]:
+    """(block_q, block_k) of the packed call. Requested blocks (an
+    argument, `CDT_FLASH_BLOCK_Q/K`, a tuning-table row) keep their
+    meaning — rows of one q tile and of one K/V tile — and win; what is
+    not requested comes from the shape:
+
+    - ``block_k``: the whole sequence padded to 128 when the VMEM model
+      fits it (K/V resident, one K step), else the fewest equal
+      lane-aligned chunks that fit;
+    - ``block_q``: the sequence cut into the fewest pieces of at most
+      ``_PACKED_BLOCK_Q`` rows, rounded up to the operand's sublane tile
+      — SD3's 4173 tokens are 9 × 464 = 4176, not 17 × 256 = 4352.
+
+    Raises when requested blocks exceed the VMEM budget: a block-tuning
+    experiment must never measure other blocks than it asked for."""
+    sublanes = _SUBLANES * max(1, 4 // itemsize)
+    if block_q is None:
+        pieces = -(-q_len // _PACKED_BLOCK_Q)
+        block_q = _round_up(-(-q_len // pieces), sublanes)
+
+    def need(bk: int) -> int:
+        return _packed_vmem_bytes(head_dim, block_q, bk, itemsize,
+                                  streamed=bk < kv_len)
+
+    def fits(bk: int) -> bool:
+        return need(bk) <= _PACKED_VMEM_BUDGET_BYTES
+
+    if block_k is not None:
+        if not fits(block_k):
+            raise ValueError(
+                f"packed flash blocks {block_q}/{block_k} at D={head_dim} "
+                f"({itemsize}B operands) need {need(block_k) >> 20} MB of "
+                f"VMEM; the budget is {_PACKED_VMEM_BUDGET_BYTES >> 20} MB")
+        return block_q, block_k
+    padded = _round_up(kv_len, _LANES)
+    for chunks in range(1, padded // _LANES + 1):
+        block_k = _round_up(-(-padded // chunks), _LANES)
+        if fits(block_k):
+            return block_q, block_k
+    raise ValueError(
+        f"packed flash attention infeasible at D={head_dim}, "
+        f"block_q={block_q}: even a {_LANES}-row K tile exceeds the "
+        f"{_PACKED_VMEM_BUDGET_BYTES >> 20} MB VMEM budget")
 
 
 def _fused_vmem_bytes(c: int, hd: int, block_q: int, block_k: int,
@@ -526,11 +797,11 @@ def _fused_vmem_bytes(c: int, hd: int, block_q: int, block_k: int,
 
 def _shrink_blocks_for_vmem(bytes_fn, block_q: int, block_k: int
                             ) -> Optional[tuple[int, int]]:
-    """Halve block_k (first — K tiles dominate the working set), then
-    block_q, until ``bytes_fn(bq, bk)`` fits ``_VMEM_BUDGET_BYTES`` with
-    a K tile no wider than ``_MAX_BLOCK_K``; None when even the floor
-    tiles blow the budget. Deterministic: the same request always
-    shrinks to the same blocks."""
+    """Fused tier: halve block_k (first — K tiles dominate the working
+    set), then block_q, until ``bytes_fn(bq, bk)`` fits
+    ``_VMEM_BUDGET_BYTES`` with a K tile no wider than ``_MAX_BLOCK_K``;
+    None when even the floor tiles blow the budget. Deterministic: the
+    same request always shrinks to the same blocks."""
     bq, bk = block_q, block_k
     while bk > _MAX_BLOCK_K or bytes_fn(bq, bk) > _VMEM_BUDGET_BYTES:
         if bk > _MIN_BLOCK_K:
@@ -540,70 +811,6 @@ def _shrink_blocks_for_vmem(bytes_fn, block_q: int, block_k: int
         else:
             return None
     return bq, bk
-
-
-_shrink_logged: set = set()
-
-
-def _log_shrink(hd: int, block_q: int, block_k: int,
-                shrunk: Optional[tuple[int, int]], itemsize: int) -> None:
-    """Once per combination: a VMEM shrink of OPERATOR-requested blocks
-    is never silent — block-tuning experiments (`CDT_FLASH_BLOCK_Q/K`,
-    docs/roofline.md r05) must not measure different blocks than they
-    record. Candidate enumeration (the sweep) calls the feasibility
-    helpers directly and is exempt by construction."""
-    if not shrunk or shrunk == (block_q, block_k):
-        return
-    sig = (hd, block_q, block_k, itemsize)
-    if sig in _shrink_logged:
-        return
-    _shrink_logged.add(sig)
-    from ..utils.logging import log
-
-    log(f"flash packed: requested blocks {block_q}/{block_k} exceed the "
-        f"VMEM model at H·D={hd} ({itemsize}B operands); shrunk to "
-        f"{shrunk[0]}/{shrunk[1]}")
-
-
-def _packed_blocks(hd: int, block_q: int, block_k: int,
-                   itemsize: int = 2) -> tuple[int, int]:
-    """Block sizes for the packed call: the requested blocks, shrunk
-    (K first) until the VMEM working-set model fits — the legality path
-    that lets geometries past the native ``_PACKED_MAX_HD`` ceiling
-    (FLUX's H·D = 3072) run packed with shrunken [block, H·D] tiles
-    instead of falling back to the classic [B·H, N, D] call. Raises when
-    no feasible blocks exist (callers check ``_packed_feasible`` first).
-
-    A shrink is LOGGED (once per combination): block-tuning experiments
-    (`CDT_FLASH_BLOCK_Q/K`, docs/roofline.md r05) must never silently
-    measure different blocks than the operator requested."""
-    shrunk = _shrink_blocks_for_vmem(
-        functools.partial(_packed_vmem_bytes, hd, itemsize=itemsize),
-        block_q, block_k)
-    if shrunk is None:
-        raise ValueError(
-            f"packed flash attention infeasible at H·D={hd}: even "
-            f"{_MIN_BLOCK_Q}/{_MIN_BLOCK_K} blocks exceed the "
-            f"{_VMEM_BUDGET_BYTES >> 20} MB VMEM budget")
-    _log_shrink(hd, block_q, block_k, shrunk, itemsize)
-    return shrunk
-
-
-def _packed_feasible(H: int, D: int, block_q: int = _DEFAULT_BLOCK_Q,
-                     block_k: int = _DEFAULT_BLOCK_K,
-                     itemsize: int = 2) -> Optional[tuple[int, int]]:
-    """Shrink-aware packed legality: the geometric constraints of
-    ``_packed_legal`` minus its native width ceiling, plus a feasible
-    block pair under the VMEM model. Returns the (possibly shrunken)
-    blocks, or None. Used by explicit ``layout=\"packed\"`` requests and
-    tuning-table entries; the DEFAULT auto layout keeps the conservative
-    ``_packed_legal`` ceiling (shrunken-packed engages only where a
-    sweep or an operator asked for it)."""
-    if not ((H * D) % _LANES == 0 and H <= _LANES and D % 64 == 0):
-        return None
-    return _shrink_blocks_for_vmem(
-        functools.partial(_packed_vmem_bytes, H * D, itemsize=itemsize),
-        block_q, block_k)
 
 
 def _flash_min_seq_packed() -> int:
@@ -623,14 +830,12 @@ def _flash_min_kv_packed() -> int:
 
 
 def _packed_legal(H: int, D: int) -> bool:
-    """Pure geometric legality of the packed-heads layout. D % 64 keeps
-    the in-kernel head slices register-lane aligned and confines the
-    layout to the tested head-dim classes (64/128); e.g. H=128, D=16
-    would pass the packed-width checks but unroll a 128-way head loop
-    over 16-wide lane slices — a shape class never measured and likely
-    Mosaic-hostile."""
-    return ((H * D) % _LANES == 0 and H <= _LANES
-            and H * D <= _PACKED_MAX_HD and D % 64 == 0)
+    """Pure geometric legality of the packed-heads layout: whole heads
+    fill whole 128-lane groups. D % 64 confines the layout to the tested
+    head-dim classes (64/128); e.g. H=128, D=16 would pack eight heads a
+    group — a shape class never measured. No width ceiling: a tile is one
+    group wide whatever H·D is (FLUX's 3072 is twenty-four groups)."""
+    return (H * D) % _LANES == 0 and D % 64 == 0
 
 
 def _layout_packed(H: int, D: int,
@@ -638,7 +843,7 @@ def _layout_packed(H: int, D: int,
                    Nk: Optional[int] = None) -> bool:
     """Kernel I/O layout: ``packed`` (default where legal AND the
     measured engagement floors hold) keeps q/k/v in the model's natural
-    [B, N, H·D] layout and splits heads inside the kernel; ``bh`` is the
+    [B, N, H·D] layout and walks head groups on the grid; ``bh`` is the
     classic pre-transposed [B·H, N, D] call.
 
     ``CDT_FLASH_LAYOUT=bh`` restores the classic call everywhere;
@@ -674,56 +879,52 @@ def flash_attention(
     code (the CPU tests).
 
     ``block_q``/``block_k=None`` resolve to ``CDT_FLASH_BLOCK_Q``/
-    ``CDT_FLASH_BLOCK_K`` (defaults 256/512, measured r04; the r05 WAN
-    probes showed 512 is also the largest K block the 16 MB scoped VMEM
-    admits at H·D=1536 — docs/roofline.md). Both the env knobs and
-    explicit arguments are validated at parse time
-    (``resolve_flash_blocks``): non-positive or non-(8,128)-divisible
-    values raise a descriptive error instead of failing in lowering.
+    ``CDT_FLASH_BLOCK_K``, then per layout: the packed call derives what
+    is still unset from the shape (``_packed_blocks`` — K resident where
+    it fits), the classic call takes 256/512 (measured r04). Both the env
+    knobs and explicit arguments are validated at parse time
+    (``_requested_blocks``): non-positive or non-(8,128)-divisible values
+    raise a descriptive error instead of failing in lowering.
 
     ``layout`` forces the kernel I/O layout for this call: ``"packed"``
-    (where geometrically feasible — including widths past the native
-    ``_PACKED_MAX_HD`` ceiling via VMEM-model block shrinking; truly
-    infeasible geometries still fall back to the classic call) or
-    ``"bh"``; ``None`` auto-selects per ``_layout_packed`` (legality +
-    measured floors + ``CDT_FLASH_LAYOUT``). Used by the tuning table
+    (where geometrically legal; illegal geometries still fall back to
+    the classic call) or ``"bh"``; ``None`` auto-selects per
+    ``_layout_packed`` (legality + measured floors +
+    ``CDT_FLASH_LAYOUT``). Used by the tuning table
     (``ops/autotune.py``), layout-equivalence tests and power users; the
     env var remains the global knob.
     """
     if interpret is None:
         interpret = _platform() == "cpu"
-    block_q, block_k = resolve_flash_blocks(block_q, block_k)
+    block_q, block_k = _requested_blocks(block_q, block_k)
     B, Nq, H, D = q.shape
     _, Nk, _, _ = k.shape
-    itemsize = jnp.dtype(q.dtype).itemsize
-    packed_blocks: Optional[tuple[int, int]] = None
     if layout == "packed":
-        # explicit beats env + floors; shrink-aware so FLUX-width
-        # geometries run packed instead of silently degrading to classic
-        packed_blocks = _packed_feasible(H, D, block_q, block_k, itemsize)
-        _log_shrink(H * D, block_q, block_k, packed_blocks, itemsize)
+        packed = _packed_legal(H, D)               # explicit beats env + floors
     elif layout == "bh":
-        packed_blocks = None
+        packed = False
     elif layout is None:
-        if _layout_packed(H, D, Nq=Nq, Nk=Nk):
-            packed_blocks = _packed_blocks(H * D, block_q, block_k,
-                                           itemsize)
+        packed = _layout_packed(H, D, Nq=Nq, Nk=Nk)
     else:
         raise ValueError(
             f"layout must be 'packed', 'bh', or None, got {layout!r}")
-    # [B,N,H,D] → [B·H, N, D]
-    def to_bh(x, n):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, n, D)
-    if interpret and _in_manual_trace(q):
-        out = _flash_emulated(to_bh(q, Nq), to_bh(k, Nk), to_bh(v, Nk),
-                              block_q=block_q, block_k=block_k)
-    elif packed_blocks is not None:
-        bq, bk = packed_blocks
+    emulated = interpret and _in_manual_trace(q)
+    if packed and not emulated:
+        bq, bk = _packed_blocks(Nq, Nk, D, jnp.dtype(q.dtype).itemsize,
+                                block_q, block_k)
         out = _flash_mha_packed(
             q.reshape(B, Nq, H * D), k.reshape(B, Nk, H * D),
             v.reshape(B, Nk, H * D), num_heads=H,
             block_q=bq, block_k=bk, interpret=interpret)
         return out.reshape(B, Nq, H, D)
+    block_q, block_k = resolve_flash_blocks(block_q, block_k)
+
+    # [B,N,H,D] → [B·H, N, D]
+    def to_bh(x, n):
+        return x.transpose(0, 2, 1, 3).reshape(B * H, n, D)
+    if emulated:
+        out = _flash_emulated(to_bh(q, Nq), to_bh(k, Nk), to_bh(v, Nk),
+                              block_q=block_q, block_k=block_k)
     else:
         out = _flash_mha(to_bh(q, Nq), to_bh(k, Nk), to_bh(v, Nk),
                          block_q=block_q, block_k=block_k,
@@ -743,8 +944,8 @@ def _fused_feasible(C: int, H: int, D: int,
     axis) plus a feasible block pair under the fused VMEM model — the
     three resident [C, H·D] weights dominate it, so wide models (WAN
     1536, FLUX 3072) are fused-infeasible on chip and take the packed
-    (possibly block-shrunk) tier from the tuning table instead. Returns
-    the (possibly shrunken) blocks, or None."""
+    tier from the tuning table instead. Returns the (possibly shrunken)
+    blocks, or None."""
     HD = H * D
     if not (HD % _LANES == 0 and H <= _LANES and D % 64 == 0
             and C % _LANES == 0):

@@ -58,11 +58,13 @@ PROGRESS_CALLBACK_SECONDS = REGISTRY.histogram(
 ATTN_KERNEL_SELECTED = REGISTRY.counter(
     "cdt_attn_kernel_selected",
     "Attention kernel-tier selections at trace time, by tier "
-    "(fused/packed/bh/xla) and geometry (hH.dD.qN.kvN.dtype — bucketed, "
-    "so cardinality is bounded by the model zoo). Increments once per "
+    "(fused/packed/bh/xla), geometry (hH.dD.qN.kvN.dtype — bucketed, "
+    "so cardinality is bounded by the model zoo) and resolved blocks "
+    "('<block_q>/<block_k>', for packed also ':k-resident' or "
+    "':k-streamed'; '' where the tier has none). Increments once per "
     "traced program per geometry; the dispatch decision is observable "
     "without a profiler.",
-    ("tier", "geometry"))
+    ("tier", "geometry", "blocks"))
 
 AUTOTUNE_SWEEP_SECONDS = REGISTRY.histogram(
     "cdt_autotune_sweep_seconds",

@@ -62,6 +62,14 @@ def _jaxpr_flops(jaxpr) -> float:
         elif name == "pallas_call":
             # the kernel body runs once PER GRID STEP — counting it once
             # undercounts flash attention ~1000× (bq·bk block vs full N²)
+            # A kernel that states its algorithmic cost is taken at its
+            # word: its body may issue more than the algorithm needs
+            # (the packed flash kernel's stacked D=64 heads) or loop
+            # without a static trip count.
+            cost = eqn.params.get("cost_estimate")
+            if cost is not None:
+                total += cost.flops
+                continue
             gm = eqn.params.get("grid_mapping")
             grid = math.prod(gm.grid) if gm is not None and gm.grid else 1
             sub = eqn.params.get("jaxpr")

@@ -172,7 +172,7 @@ def exp_batch() -> None:
 def exp_attn() -> None:
     """Attention microbench: flash (auto layout) vs XLA, plus the fused
     QKV+attention tier at self-attention shapes where C == H·D. Shapes
-    cover SDXL, the FLUX H·D=3072 width (shrunk-packed since ISSUE 8) and
+    cover SDXL, the FLUX H·D=3072 width (packed: 24 head groups) and
     WAN's ~14k-token geometry."""
     import jax
     import jax.numpy as jnp

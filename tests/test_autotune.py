@@ -1,7 +1,8 @@
 """Attention autotuner (ops/autotune.py): table persistence + merge,
 shipped-table legality, deterministic sweeps, and dispatcher precedence
-(table > env knobs > measured defaults). Fast — no model builds, no
-pallas execution; tier-1."""
+(table > env knobs > measured defaults). Fast — no model builds;
+tier-1. The only pallas execution is the four tiny interpret-mode cases
+of ``TestPackedKernelSmoke`` at the end."""
 
 import json
 import types
@@ -118,16 +119,22 @@ class TestShippedTable:
             assert not errors, f"{key.key_str()}: {errors}"
 
     def test_flux_geometry_does_not_fall_back_to_classic(self):
-        """Acceptance: H·D=3072 gets shrunken packed tiles (or fused),
-        not the classic bh call."""
+        """Acceptance: H·D=3072 runs packed (or fused), not the classic
+        bh call."""
         t = TuningTable(shipped=True, path="/nonexistent/none.json")
         choice = t.get(GeometryKey.from_shape(24, 128, 4608, 4608))
         assert choice.tier in ("packed", "fused")
 
     def test_validate_entry_catches_vmem_blowout(self):
-        errors = autotune.validate_entry(
+        # a packed K tile is one head group wide: 1024 rows fit where the
+        # full-width tile's did not; a 4096-row q block against all of
+        # WAN's K does not
+        assert not autotune.validate_entry(
             geom(h=12, d=128, q=16384, kv=16384),
             KernelChoice("packed", 256, 1024))
+        errors = autotune.validate_entry(
+            geom(h=12, d=128, q=16384, kv=16384),
+            KernelChoice("packed", 4096, 16384))
         assert errors and "VMEM" in errors[0]
 
     def test_validate_entry_catches_bad_blocks(self):
@@ -148,11 +155,14 @@ class TestSweep:
         e = autotune.sweep_geometry(geom(q=512, kv=512), mode="dry")
         assert e.choice.tier == "xla"
 
-    def test_dry_policy_flux_width_gets_shrunk_packed(self):
+    def test_dry_policy_flux_width_gets_packed_with_the_shapes_blocks(self):
         e = autotune.sweep_geometry(
             geom(h=24, d=128, q=8192, kv=8192), mode="dry")
         assert e.choice.tier == "packed"
-        assert (e.choice.block_q, e.choice.block_k) == (256, 256)
+        # no blocks in the row: the call derives them from the lengths it
+        # meets, not from the bucket
+        assert (e.choice.block_q, e.choice.block_k) == (None, None)
+        assert "block_q" not in e.choice.to_dict()
 
     def test_candidates_deterministic_and_legal(self):
         k = geom(h=24, d=128, q=8192, kv=8192)
@@ -219,6 +229,13 @@ class TestDispatcherPrecedence:
         monkeypatch.delenv("CDT_FLASH_LAYOUT")
         choice = on_tpu.select_kernel(4096, 4096, 10, 64)
         assert choice.tier == "packed"             # r04 default
+        # ... with the blocks the call will run: K resident
+        assert (choice.block_q, choice.block_k) == (512, 4096)
+        monkeypatch.setenv("CDT_FLASH_BLOCK_K", "1024")
+        on_tpu.reset_selections()
+        choice = on_tpu.select_kernel(4096, 4096, 10, 64)
+        assert (choice.block_q, choice.block_k) == (512, 1024)
+        assert "packed:512/1024:k-streamed" in on_tpu.selection_summary()
 
     def test_explicit_flag_beats_table(self, on_tpu, monkeypatch):
         key = GeometryKey.from_shape(10, 64, 4096, 4096)
@@ -307,8 +324,8 @@ class TestDispatcherPrecedence:
         on_tpu.select_kernel(4096, 4096, 10, 64)   # dedup: one increment
         series = {tuple(sorted(lbl.items())): snap.get("value", 0)
                   for lbl, snap in tm.ATTN_KERNEL_SELECTED.series()}
-        lbl = tuple(sorted({"tier": "packed",
-                            "geometry": key.key_str()}.items()))
+        lbl = tuple(sorted({"tier": "packed", "geometry": key.key_str(),
+                            "blocks": "256/512:k-streamed"}.items()))
         assert series.get(lbl, 0) - before.get(lbl, 0) == 1
         assert key.key_str() in on_tpu.selection_summary()
 
@@ -387,3 +404,34 @@ class TestSweepCLI:
         entries = json.loads(out.read_text())["entries"]
         assert list(entries) == ["h12.d128.q16384.kv16384.bf16"]
         assert entries["h12.d128.q16384.kv16384.bf16"]["tier"] == "packed"
+
+
+class TestPackedKernelSmoke:
+    """The packed kernel's mathematics in the smoke tier (the full matrix
+    is ``tests/test_flash_attention.py``, marked slow): interpret mode,
+    tiny ragged shapes, both head-group kinds, K resident and streamed."""
+
+    @pytest.mark.parametrize("case", [
+        ("d64.resident", 2, 100, 77, 4, 64, None),
+        ("d64.streamed", 1, 100, 300, 2, 64, 128),
+        ("d128.resident", 1, 72, 200, 1, 128, None),
+        ("d128.streamed", 1, 72, 200, 3, 128, 128),
+    ], ids=lambda c: c[0])
+    def test_matches_xla_reference(self, case):
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from comfyui_distributed_tpu.ops import flash_attention as fa
+
+        _, B, Nq, Nk, H, D, bk = case
+        kq, kk, kv = jax.random.split(jax.random.key(3), 3)
+        q = jax.random.normal(kq, (B, Nq, H, D), jnp.float32)
+        k = jax.random.normal(kk, (B, Nk, H, D), jnp.float32)
+        v = jax.random.normal(kv, (B, Nk, H, D), jnp.float32)
+        out = fa.flash_attention(q, k, v, block_k=bk, interpret=True,
+                                 layout="packed")
+        np.testing.assert_allclose(
+            out, jax.nn.dot_product_attention(q, k, v),
+            atol=2e-5, rtol=2e-5)
+

@@ -12,9 +12,12 @@ repo's own model counted. These cases compile, at real widths:
   floors of ``select_kernel`` still reach;
 - SDXL's two self-attention sites inside the transformer block that calls
   them, with the dispatcher choosing the tier as it does on the chip;
-- and, for every row, the largest blocks ``_fused_feasible`` /
-  ``_packed_feasible`` approve: a feasibility function that says yes where
-  the compiler says no is the bug this file exists to catch.
+- SD3's joint attention (B=2, N=4173, H=24, D=64), which no row names:
+  the packed tier's default path, at the blocks its shape derives;
+- and, for every row and for SD3, the largest blocks ``_fused_feasible``
+  and the packed VMEM model (``_packed_blocks``) approve: a model that
+  says yes where the compiler says no is the bug this file exists to
+  catch.
 
 Nothing runs, so nothing here is a result or a time. The persistent
 compilation cache is off around the compiles: an entry written for a
@@ -94,24 +97,32 @@ def _compile_kernel(chip, tier, H, D, nq, nk, bq, bk, batch=1):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# (id, tier, H, D, Nq, Nk, block_q, block_k): the table's Pallas rows at
-# their bucket lengths, WAN also at its real 14 040 tokens (not a block
-# multiple: the call pads), and the classic call the table never picks
+# (id, tier, H, D, Nq, Nk, block_q, block_k, batch): the table's Pallas
+# rows at their bucket lengths (a packed row without blocks takes the
+# shape's, as the dispatcher resolves it), WAN also at its real 14 040
+# tokens (not a block multiple: the call pads) with the shape's blocks and
+# with requested 256/512 streaming K, SD3's joint attention at the CFG
+# batch, and the classic call the table never picks
 KERNEL_CASES = [
     (ks, c.tier, k.num_heads, k.head_dim, k.q_bucket, k.kv_bucket,
-     c.block_q, c.block_k)
+     c.block_q, c.block_k, 2 if c.tier == "fused" else 1)
     for ks, (k, c) in sorted(PALLAS_ROWS.items())
 ] + [
-    ("wan_self_14040", "packed", 12, 128, 14040, 14040, 256, 512),
-    ("flux_bh_h24.d128.q8192", "bh", 24, 128, 8192, 8192, 256, 512),
+    ("wan_self_14040", "packed", 12, 128, 14040, 14040, None, None, 1),
+    ("wan_self_14040_streamed", "packed", 12, 128, 14040, 14040, 256, 512, 1),
+    ("wan_self_32760", "packed", 12, 128, 32760, 32760, None, None, 1),
+    ("sd3_joint_4173", "packed", 24, 64, 4173, 4173, None, None, 2),
+    ("sd3_joint_4173_streamed", "packed", 24, 64, 4173, 4173, 256, 512, 2),
+    ("flux_bh_h24.d128.q8192", "bh", 24, 128, 8192, 8192, 256, 512, 1),
 ]
 
 
 @pytest.mark.parametrize("case", KERNEL_CASES, ids=lambda c: c[0])
 def test_table_row_compiles_alone(chip, case):
-    _, tier, H, D, nq, nk, bq, bk = case
-    _compile_kernel(chip, tier, H, D, nq, nk, bq, bk,
-                    batch=2 if tier == "fused" else 1)
+    _, tier, H, D, nq, nk, bq, bk, batch = case
+    if tier == "packed":
+        bq, bk = fa._packed_blocks(nq, nk, D, 2, bq, bk)
+    _compile_kernel(chip, tier, H, D, nq, nk, bq, bk, batch=batch)
 
 
 def test_table_has_the_rows_the_main_paths_select():
@@ -175,26 +186,53 @@ def _approved_frontier(feasible):
     return out
 
 
-@pytest.mark.parametrize("row", sorted(PALLAS_ROWS), ids=str)
+def _packed_frontier(nq, nk, D):
+    """The packed VMEM model's largest approvals at one geometry: the
+    tallest doubling q block it still holds the whole sequence against
+    (K resident) and the first past it, where K must stream — the two
+    working sets nearest the budget."""
+    resident = streamed = None
+    bq = 512
+    while bq <= 8192 and streamed is None:
+        try:
+            blocks = fa._packed_blocks(nq, nk, D, 2, bq, None)
+        except ValueError:
+            break
+        if blocks[1] >= nk:
+            resident = blocks
+        else:
+            streamed = blocks
+        bq *= 2
+    return [b for b in (resident, streamed) if b]
+
+
+PACKED_GEOMETRIES = {
+    **{ks: (k.num_heads, k.head_dim, k.q_bucket, k.kv_bucket)
+       for ks, (k, _) in PALLAS_ROWS.items()},
+    "sd3_joint_4173": (24, 64, 4173, 4173),
+    "wan_self_32760": (12, 128, 32760, 32760),
+}
+
+
+@pytest.mark.parametrize("row", sorted(PACKED_GEOMETRIES), ids=str)
 def test_feasibility_never_approves_what_the_compiler_refuses(chip, row):
-    """The VMEM models against the compiler, on the rows the table ships:
-    whatever ``_fused_feasible`` / ``_packed_feasible`` approve for the
-    geometry — not only the pair the table chose — must compile. On the
-    parent commit the fused model approved 256/256 at C=1280 (15.25 MB by
-    its count) and the compiler wanted 20.26 MB of a 16 MB limit."""
-    key, _ = PALLAS_ROWS[row]
-    H, D, nq, nk = key.num_heads, key.head_dim, key.q_bucket, key.kv_bucket
-    tiers = {"packed": lambda bq, bk: fa._packed_feasible(H, D, bq, bk)}
-    if nq == nk:        # self-attention: the fused tier is a candidate too
-        tiers["fused"] = lambda bq, bk: fa._fused_feasible(H * D, H, D,
-                                                           bq, bk)
-    compiled = 0
-    for tier, feasible in tiers.items():
-        for bq, bk in _approved_frontier(feasible):
-            try:
-                _compile_kernel(chip, tier, H, D, nq, nk, bq, bk)
-            except Exception as e:  # noqa: BLE001 — the compiler's refusal
-                pytest.fail(f"{tier} {bq}/{bk} approved for {row} but "
-                            f"refused by the compiler: {str(e)[:400]}")
-            compiled += 1
-    assert compiled, f"nothing approved for {row}"
+    """The VMEM models against the compiler, on the rows the table ships
+    and on SD3's default-path geometry: whatever ``_fused_feasible`` and
+    the packed model approve for the geometry — not only the pair the
+    table or the shape chose — must compile. On PR 21's parent the fused
+    model approved 256/256 at C=1280 (15.25 MB by its count) and the
+    compiler wanted 20.26 MB of a 16 MB limit."""
+    H, D, nq, nk = PACKED_GEOMETRIES[row]
+    approved = [("packed", bq, bk) for bq, bk in _packed_frontier(nq, nk, D)]
+    if nq == nk and row in PALLAS_ROWS:
+        # self-attention: the fused tier is a candidate too
+        approved += [("fused", bq, bk) for bq, bk in _approved_frontier(
+            lambda bq, bk: fa._fused_feasible(H * D, H, D, bq, bk))]
+    assert any(t == "packed" for t, _, _ in approved), \
+        f"nothing approved for {row}"
+    for tier, bq, bk in approved:
+        try:
+            _compile_kernel(chip, tier, H, D, nq, nk, bq, bk)
+        except Exception as e:  # noqa: BLE001 — the compiler's refusal
+            pytest.fail(f"{tier} {bq}/{bk} approved for {row} but "
+                        f"refused by the compiler: {str(e)[:400]}")

@@ -171,11 +171,9 @@ class TestLayoutVariants:
         (2, 300, 4, 64, 300),     # padded tails on both q and k
         (1, 1024, 10, 64, 77),    # SDXL cross-attention geometry
         (2, 513, 3, 128, 200),    # D=128, odd lengths
-        (1, 600, 24, 128, 500),   # FLUX geometry: H*D=3072 exceeds the
-                                  # native _PACKED_MAX_HD -> the ISSUE 8
-                                  # shrink path serves it with smaller
-                                  # [block, H*D] tiles (no classic
-                                  # fallback; see TestPackedShrink)
+        (1, 600, 24, 128, 500),   # FLUX geometry: H*D=3072 is 24 head
+                                  # groups on the grid, no width ceiling
+                                  # (see TestPackedBlocks)
     ])
     def test_packed_matches_bh(self, monkeypatch, shape):
         from comfyui_distributed_tpu.ops.flash_attention import flash_attention
@@ -233,10 +231,15 @@ class TestShapeGate:
                                          num_heads=10, head_dim=64)
 
     def test_packed_illegal_keeps_classic_gate(self, on_tpu):
-        # FLUX: H·D = 3072 > _PACKED_MAX_HD → classic call, 8192 gate
+        # H·D = 5·64 is not a whole number of 128-lane groups → classic
+        # call, 8192 gate
         assert not on_tpu._flash_enabled(q_len=4608, kv_len=4608,
-                                         num_heads=24, head_dim=128)
+                                         num_heads=5, head_dim=64)
         assert on_tpu._flash_enabled(q_len=9000, kv_len=9000,
+                                     num_heads=5, head_dim=64)
+        # FLUX's H·D = 3072 was past the old tile's width ceiling; a tile
+        # is one group wide now, so it is packed at its real length
+        assert on_tpu._flash_enabled(q_len=4608, kv_len=4608,
                                      num_heads=24, head_dim=128)
 
     def test_shape_free_call_keeps_classic_gate(self, on_tpu):
@@ -255,8 +258,8 @@ class TestShapeGate:
                                          num_heads=10, head_dim=64)
 
     def test_packed_layout_requires_lane_aligned_head_dim(self, monkeypatch):
-        # H=128, D=16 passes the packed-width checks but would unroll a
-        # 128-way head loop over 16-wide lane slices — excluded
+        # H=128, D=16 fills whole lane groups but with eight heads a
+        # group, a shape class never measured — excluded
         from comfyui_distributed_tpu.ops.flash_attention import _layout_packed
 
         monkeypatch.delenv("CDT_FLASH_LAYOUT", raising=False)
@@ -306,47 +309,104 @@ class TestShapeGate:
             flash_attention(q, k, v, block_k=200, interpret=True)
 
 
-class TestPackedShrink:
-    """The VMEM working-set model and the block-shrinking legality path
-    (ISSUE 8): geometries past the native packed ceiling get shrunken
-    [block, H·D] tiles instead of the classic [B·H, N, D] fallback."""
+def _packed_call_spy(monkeypatch):
+    """Record the (block_q, block_k) of every packed pallas call."""
+    from comfyui_distributed_tpu.ops import flash_attention as fa
 
-    def test_vmem_model_matches_r05_wan_probe(self):
-        """r05 measured: 1024 K-blocks at H·D=1536 blow the 16 MB scoped
-        VMEM (25.09 MB), 512 K-blocks fit (docs/roofline.md). The model
-        must reproduce that verdict."""
+    calls = []
+    orig = fa._flash_mha_packed
+
+    def spy(*args, **kw):
+        calls.append((kw.get("block_q"), kw.get("block_k")))
+        return orig(*args, **kw)
+
+    monkeypatch.setattr(fa, "_flash_mha_packed", spy)
+    return calls
+
+
+class TestPackedBlocks:
+    """The packed tier's blocks and VMEM model (PR 25): heads are a grid
+    axis, a tile is one 128-lane head group wide, and ``block_k`` comes
+    from the shape — the whole padded sequence where it fits."""
+
+    @pytest.mark.parametrize("case", [
+        # (Nq, Nk, D, itemsize, requested, expected)
+        (4173, 4173, 64, 2, (None, None), (464, 4224)),    # SD3: 9 x 464
+        (1024, 1024, 64, 2, (None, None), (512, 1024)),    # SDXL 32^2
+        (4096, 4096, 64, 2, (None, None), (512, 4096)),    # SDXL 64^2
+        (4608, 4608, 128, 2, (None, None), (512, 4608)),   # FLUX
+        (14040, 14040, 128, 2, (None, None), (512, 14080)),  # WAN 480p
+        (4173, 4173, 64, 4, (None, None), (464, 4224)),    # f32 operands
+        (4173, 4173, 64, 2, (256, None), (256, 4224)),     # q requested
+        (4173, 4173, 64, 2, (None, 512), (464, 512)),      # K requested
+        (4173, 4173, 64, 2, (256, 512), (256, 512)),       # both: as asked
+        (100, 77, 64, 2, (None, None), (112, 128)),        # one lane tile
+    ], ids=lambda c: f"q{c[0]}.kv{c[1]}.d{c[2]}.b{c[3]}.req{c[4]}")
+    def test_blocks_from_the_shape(self, case):
+        from comfyui_distributed_tpu.ops.flash_attention import _packed_blocks
+
+        nq, nk, d, itemsize, requested, expected = case
+        assert _packed_blocks(nq, nk, d, itemsize, *requested) == expected
+
+    def test_long_sequence_streams_in_the_longest_chunks_that_fit(self):
+        """K/V that do not fit beside the logits (a 32 k-token video at
+        f32) stream over the grid in equal lane-aligned chunks."""
         from comfyui_distributed_tpu.ops.flash_attention import (
-            _VMEM_BUDGET_BYTES, _packed_vmem_bytes)
+            _PACKED_VMEM_BUDGET_BYTES, _packed_blocks, _packed_vmem_bytes)
 
-        assert _packed_vmem_bytes(1536, 256, 1024, 2) > _VMEM_BUDGET_BYTES
-        assert _packed_vmem_bytes(1536, 256, 512, 2) <= _VMEM_BUDGET_BYTES
+        bq, bk = _packed_blocks(32760, 32760, 128, 4)
+        assert bk < 32760 and bk % 128 == 0
+        chunks = -(-32760 // bk)
+        assert chunks * bk - 32760 < 128 * chunks       # padding < a slab each
+        assert _packed_vmem_bytes(128, bq, bk, 4, True) \
+            <= _PACKED_VMEM_BUDGET_BYTES
+        # one chunk fewer would not have fit
+        longer = -(-32768 // (chunks - 1) // 128) * 128
+        assert _packed_vmem_bytes(128, bq, longer, 4, chunks > 2) \
+            > _PACKED_VMEM_BUDGET_BYTES
 
-    def test_flux_width_feasible_with_shrunk_blocks(self):
+    def test_vmem_model_grows_with_the_slab_not_the_width(self):
+        """What broke the r05 WAN probe (25.09 MB at H·D=1536, 256/1024
+        blocks) was the full-width tile. The model no longer takes H·D:
+        1024 K rows of one group are 0.5 MB, and the body term follows
+        the in-body slab, not block_k."""
         from comfyui_distributed_tpu.ops.flash_attention import (
-            _packed_feasible)
+            _PACKED_SLAB, _PACKED_VMEM_BUDGET_BYTES, _packed_slab,
+            _packed_vmem_bytes)
 
-        # default blocks blow VMEM at H·D=3072; the shrink path lands on
-        # a deterministic smaller pair instead of giving up
-        assert _packed_feasible(24, 128, 256, 512, 2) == (256, 256)
-        # f32 operands need a further shrink
-        assert _packed_feasible(24, 128, 256, 512, 4) == (128, 128)
-        # geometric illegality (lane-misaligned head dim) is still None
-        assert _packed_feasible(128, 16) is None
+        assert _packed_vmem_bytes(128, 256, 1024, 2, True) < 8 * 2 ** 20
+        assert _packed_slab(4224) == 1408 and _packed_slab(1024) == 1024
+        assert all(_packed_slab(n) <= _PACKED_SLAB
+                   for n in range(128, 40000, 128))
+        grow = (_packed_vmem_bytes(64, 464, 8448, 2)
+                - _packed_vmem_bytes(64, 464, 4224, 2))
+        assert grow == 2 * 2 * 4224 * 128 * 2           # K/V tiles only
+        assert _packed_vmem_bytes(64, 464, 4224, 2) \
+            < _PACKED_VMEM_BUDGET_BYTES
+
+    def test_requested_blocks_past_the_budget_raise(self):
+        from comfyui_distributed_tpu.ops.flash_attention import _packed_blocks
+
+        with pytest.raises(ValueError, match="VMEM"):
+            _packed_blocks(16384, 16384, 128, 2, 4096, 16384)
+
+    @pytest.mark.parametrize("H,D,legal", [
+        (24, 128, True), (24, 64, True), (10, 64, True), (2, 192, True),
+        (5, 64, False), (128, 16, False), (3, 80, False), (1, 192, False),
+    ])
+    def test_geometric_legality(self, H, D, legal):
+        from comfyui_distributed_tpu.ops.flash_attention import _packed_legal
+
+        assert _packed_legal(H, D) is legal
 
     def test_explicit_packed_at_flux_width_runs_packed(self, monkeypatch):
         """Acceptance: the FLUX geometry no longer falls back to the
         classic call — an explicit packed request at H·D=3072 computes
-        via the shrunk packed kernel and matches the dense reference."""
+        via the packed kernel, K resident, and matches the dense
+        reference."""
         from comfyui_distributed_tpu.ops import flash_attention as fa
 
-        calls = []
-        orig = fa._flash_mha_packed
-
-        def spy(*args, **kw):
-            calls.append((kw.get("block_q"), kw.get("block_k")))
-            return orig(*args, **kw)
-
-        monkeypatch.setattr(fa, "_flash_mha_packed", spy)
+        calls = _packed_call_spy(monkeypatch)
         q, k, v = rand_qkv(jax.random.key(20), B=1, Nq=600, Nk=500,
                            H=24, D=128)
         out = fa.flash_attention(q, k, v, interpret=True, layout="packed")
@@ -354,7 +414,86 @@ class TestPackedShrink:
                                    dense_reference(q, k, v),
                                    atol=5e-2, rtol=5e-2)
         assert calls, "packed kernel was not used at H·D=3072"
-        assert calls[0] == (128, 128)   # f32 shrink verdict
+        assert calls[0] == (304, 512)   # 2 x 304 q rows, K in one tile
+
+
+class TestPackedParity:
+    """The packed blocking against ``jax.nn.dot_product_attention`` in
+    f32: both group kinds (two D=64 heads, one D=128 head), K resident
+    and streamed, ragged lengths, Nq != Nk, both operand dtypes."""
+
+    CASES = [
+        # id, B, Nq, Nk, H, D, block_q, block_k
+        ("d64.even_groups.resident", 2, 333, 333, 4, 64, None, None),
+        ("d64.odd_groups.resident", 1, 333, 333, 6, 64, None, None),
+        ("d64.odd_groups.streamed", 1, 333, 333, 6, 64, 128, 128),
+        ("d64.cross.resident", 2, 300, 77, 2, 64, None, None),
+        ("d64.cross.streamed", 1, 200, 700, 2, 64, None, 256),
+        ("d64.resident.two_slabs", 1, 300, 1700, 2, 64, None, None),
+        ("d64.resident.long_tile", 1, 130, 300, 2, 64, 64, 512),
+        ("d128.resident", 1, 333, 500, 3, 128, None, None),
+        ("d128.streamed", 1, 333, 500, 3, 128, 64, 256),
+        ("d128.cross.streamed", 2, 77, 333, 2, 128, None, 128),
+        ("sd3.scaled", 2, 4096 // 16 + 77, 4096 // 16 + 77, 24, 64,
+         None, None),
+    ]
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", CASES, ids=lambda c: c[0])
+    def test_matches_xla_reference(self, monkeypatch, case, dtype):
+        from comfyui_distributed_tpu.ops import flash_attention as fa
+
+        _, B, Nq, Nk, H, D, bq, bk = case
+        calls = _packed_call_spy(monkeypatch)
+        q, k, v = rand_qkv(jax.random.key(7), B=B, Nq=Nq, Nk=Nk, H=H, D=D,
+                           dtype=jnp.dtype(dtype))
+        out = fa.flash_attention(q, k, v, block_q=bq, block_k=bk,
+                                 interpret=True, layout="packed")
+        ref = jax.nn.dot_product_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32))
+        assert out.dtype == q.dtype and out.shape == q.shape
+        tol = 2e-5 if dtype == "float32" else 2e-2
+        np.testing.assert_allclose(np.asarray(out, np.float32), ref,
+                                   atol=tol, rtol=tol)
+        (got_bq, got_bk), = calls
+        resident = got_bk >= Nk
+        assert resident == ("resident" in case[0] or "scaled" in case[0])
+        if bk is not None:
+            assert got_bk == bk
+
+    @pytest.mark.parametrize("block_k", [None, 1024])
+    def test_long_k_tile_loops_over_its_slabs(self, monkeypatch, block_k):
+        """A K tile of more than three clean slabs is walked by a loop,
+        not unrolled (WAN's 14 k tokens are ten slabs): same numbers,
+        resident and streamed, with padding that spans more than the
+        last slab when the requested tile is longer than what is left."""
+        from comfyui_distributed_tpu.ops import flash_attention as fa
+
+        monkeypatch.setattr(fa, "_PACKED_SLAB", 256)
+        q, k, v = rand_qkv(jax.random.key(11), Nq=200, Nk=1700, H=2, D=64)
+        assert 1792 // fa._packed_slab(1792) > fa._PACKED_UNROLL_SLABS
+        out = fa.flash_attention(q, k, v, block_k=block_k, interpret=True,
+                                 layout="packed")
+        np.testing.assert_allclose(out, dense_reference(q, k, v),
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_power_of_two_scale_folds_bit_identically(self):
+        """D=64: 1/sqrt(D) = 0.125 multiplies q instead of the logits and
+        the result does not change by a bit; D=128's 0.0884 stays on the
+        logits for bf16 operands (no second rounding of q)."""
+        from comfyui_distributed_tpu.ops import flash_attention as fa
+
+        assert fa._scale_folds_into_q(64, jnp.bfloat16)
+        assert not fa._scale_folds_into_q(128, jnp.bfloat16)
+        assert fa._scale_folds_into_q(128, jnp.float32)
+        q, k, v = rand_qkv(jax.random.key(9), Nq=128, Nk=256, H=2, D=64,
+                           dtype=jnp.bfloat16)
+        a = fa.flash_attention(q, k, v, interpret=True, layout="packed")
+        b = fa.flash_attention(q, k, v, interpret=True, layout="bh",
+                               block_q=128, block_k=256)
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
 
 
 def fused_reference(x, wq, wk, wv, num_heads):

@@ -2,6 +2,7 @@
 through scan, and sees conv FLOPs that XLA's TPU cost analysis drops."""
 
 import jax
+import pytest
 import jax.numpy as jnp
 import numpy as np
 
@@ -77,10 +78,14 @@ def test_unet_counts_dominant_flops():
     assert flops < 1e12
 
 
-def test_pallas_flash_counts_grid():
+@pytest.mark.parametrize("layout", ["bh", "packed"])
+def test_pallas_flash_counts_grid(layout):
     """The pallas kernel body runs once per grid step; the walker must
     multiply (missing this undercounts flash attention ~1000×). Flash
-    and dense attention carry identical algorithmic FLOPs."""
+    and dense attention carry identical algorithmic FLOPs: the classic
+    call by body × grid, the packed call — whose body stacks two D=64
+    heads into 128-deep passes and may loop over K slabs — by the cost
+    it states (``cost_estimate``)."""
     from comfyui_distributed_tpu.ops.flash_attention import flash_attention
 
     B, N, H, D = 1, 1024, 4, 64
@@ -89,6 +94,7 @@ def test_pallas_flash_counts_grid():
     dense = estimate_flops(
         lambda q, k, v: jax.nn.dot_product_attention(q, k, v), q, k, v)
     flash = estimate_flops(
-        lambda q, k, v: flash_attention(q, k, v, interpret=True), q, k, v)
+        lambda q, k, v: flash_attention(q, k, v, interpret=True,
+                                        layout=layout), q, k, v)
     assert dense == 2 * 2 * B * H * N * N * D
     assert flash == dense

@@ -129,7 +129,8 @@ class TestCallsPerStep:
         from comfyui_distributed_tpu.diffusion import sample, sigmas_karras
 
         seen = []
-        handle = events.add_sink(lambda tok, sh, sig, x0: seen.append(sig))
+        handle = events.add_sink(
+            lambda tok, sh, sig, x0, calls: seen.append(sig))
         try:
             steps = 5
             sigmas = sigmas_karras(steps, 0.03, 10.0)
@@ -141,6 +142,188 @@ class TestCallsPerStep:
             assert len(seen) == total_calls("heun", steps) == 2 * steps - 1
         finally:
             events.remove_sink(handle)
+
+
+class TestStridedEvents:
+    """An event costs the chip a host round trip, so a run may be asked
+    to report only every stride-th step: ``[token, stride]`` as the
+    traced token, each event standing for ``stride`` calls."""
+
+    @staticmethod
+    def _run(sampler, steps, token):
+        from comfyui_distributed_tpu.diffusion import sample, sigmas_karras
+
+        seen = []
+        handle = events.add_sink(
+            lambda tok, sh, sig, x0, calls: seen.append((tok, calls)))
+        try:
+            run = jax.jit(lambda x, tok: sample(
+                sampler, wrap_denoiser(lambda x, s: x * 0.5, tok,
+                                       jnp.int32(0)),
+                x, sigmas_karras(steps, 0.03, 10.0)))
+            out = run(jnp.ones((1, 4, 4, 1)), jnp.asarray(token, jnp.int32))
+            jax.block_until_ready(out)
+            jax.effects_barrier()
+        finally:
+            events.remove_sink(handle)
+        return out, seen
+
+    @pytest.mark.parametrize("sampler,steps,stride,events_seen", [
+        ("euler", 7, 1, 7),        # stride 1: every step, as a bare token
+        ("euler", 7, 3, 2),        # steps 3 and 6; step 7 is finish()'s
+        ("euler", 6, 6, 1),
+        ("euler", 5, 9, 0),        # a stride past the ladder: no event
+        ("heun", 6, 2, 6),         # both calls of steps 2, 4 (and 6: one)
+        ("euler", 4, 0, 4),        # a stride below 1 is 1
+    ])
+    def test_only_every_stride_th_step_reports(self, sampler, steps, stride,
+                                               events_seen):
+        _, seen = self._run(sampler, steps, [5, stride])
+        if sampler == "heun":      # the last step makes one call, not two
+            events_seen -= 1
+        assert len(seen) == events_seen
+        assert all(tok == 5 and calls == max(stride, 1)
+                   for tok, calls in seen)
+
+    def test_one_program_serves_every_stride(self):
+        """The stride is traced: changing it compiles nothing."""
+        from comfyui_distributed_tpu.diffusion import sample, sigmas_karras
+
+        seen = []
+        handle = events.add_sink(
+            lambda tok, sh, sig, x0, calls: seen.append(calls))
+        try:
+            run = jax.jit(lambda x, tok: sample(
+                "euler", wrap_denoiser(lambda x, s: x * 0.5, tok,
+                                       jnp.int32(0)),
+                x, sigmas_karras(6, 0.03, 10.0)))
+            x = jnp.ones((1, 4, 4, 1))
+            for stride in (1, 2, 3):
+                jax.block_until_ready(run(x, jnp.array([1, stride],
+                                                       jnp.int32)))
+            jax.effects_barrier()
+            assert run._cache_size() == 1
+            assert sorted(seen) == [1] * 6 + [2] * 3 + [3] * 2
+        finally:
+            events.remove_sink(handle)
+
+    def test_stride_leaves_the_samples_bit_identical(self):
+        bare, _ = self._run("euler", 6, 7)
+        strided, _ = self._run("euler", 6, [7, 4])
+        assert np.array_equal(np.asarray(bare), np.asarray(strided))
+
+    def test_outside_a_sampler_scan_every_call_reports(self):
+        """A python ladder calls the denoiser step by step: no scan tells
+        it the step, so a strided token reports every call, for one."""
+        seen = []
+        handle = events.add_sink(
+            lambda tok, sh, sig, x0, calls: seen.append((tok, calls)))
+        try:
+            den = jax.jit(wrap_denoiser(lambda x, s: x * 0.5,
+                                        jnp.array([9, 4], jnp.int32), 0))
+            for sigma in (3.0, 2.0, 1.0):
+                jax.block_until_ready(den(jnp.ones((1, 2, 2, 1)), sigma))
+            jax.effects_barrier()
+            assert seen == [(9, 1)] * 3
+        finally:
+            events.remove_sink(handle)
+
+    def test_segments_gate_on_the_global_step(self):
+        """A ladder cut into segments reports the same steps as the whole
+        ladder: the gate reads the global index, not the segment's."""
+        from comfyui_distributed_tpu.diffusion import sigmas_karras
+        from comfyui_distributed_tpu.diffusion.samplers import (
+            _euler_program, run_segment)
+
+        seen = []
+        handle = events.add_sink(
+            lambda tok, sh, sig, x0, calls: seen.append(round(sig, 5)))
+        try:
+            sigmas = sigmas_karras(6, 0.03, 10.0)
+            den = wrap_denoiser(lambda x, s: x * 0.5,
+                                jnp.array([3, 4], jnp.int32), 0)
+            prog = _euler_program(den, sigmas)
+            seg = jax.jit(lambda carry, start: run_segment(prog, carry,
+                                                           start, 3))
+            carry = prog.init(jnp.ones((1, 2, 2, 1)))
+            carry = seg(carry, 0)          # steps 1-3: none is the 4th
+            jax.block_until_ready(carry)
+            jax.effects_barrier()
+            assert seen == []
+            jax.block_until_ready(seg(carry, 3))   # steps 4-6: the 4th
+            jax.effects_barrier()
+            assert seen == [round(float(sigmas[3]), 5)]
+        finally:
+            events.remove_sink(handle)
+
+
+class TestTrackerStride:
+    """The tracker spaces a run's events ``EVENT_PERIOD_S`` apart at the
+    call time the last run of as many calls showed."""
+
+    @staticmethod
+    def _finished_run(tracker, prompt_id, total, call_s, calls=None):
+        token = tracker.start(prompt_id, total)
+        job = tracker._jobs[token]
+        calls = total if calls is None else calls
+        tracker._on_event(token, 0, 1.0, np.zeros((1, 2, 2, 4), np.float32),
+                          calls)
+        job.updated = job.started + call_s * calls
+        tracker.finish(prompt_id)
+        return token
+
+    def test_first_run_reports_every_call(self, tracker):
+        token = tracker.start("a", 28)
+        assert tracker.traced_token(token).tolist() == [token, 1]
+        assert tracker.traced_token(token).dtype == np.int32
+
+    @pytest.mark.parametrize("call_s,stride", [
+        (0.14, 6),      # SD3 on a v5e: 0.75 / 0.14 -> 6
+        (0.05, 15),
+        (0.75, 1),
+        (1.1, 1),       # WAN: a call outlasts the period, every call
+        (0.001, 28),    # never more than the run has calls
+    ])
+    def test_next_run_is_spaced_by_the_last(self, tracker, call_s, stride):
+        from comfyui_distributed_tpu.cluster.progress import EVENT_PERIOD_S
+
+        self._finished_run(tracker, "a", 28, call_s)
+        token = tracker.start("b", 28)
+        assert tracker.traced_token(token).tolist() == [token, stride]
+        assert stride == 1 or (stride - 1) * call_s < EVENT_PERIOD_S \
+            or stride == 28
+
+    def test_runs_of_other_lengths_keep_their_own_time(self, tracker):
+        self._finished_run(tracker, "a", 28, 0.14)
+        assert tracker.traced_token(tracker.start("b", 30)).tolist()[1] == 1
+        assert tracker.traced_token(tracker.start("c", 28)).tolist()[1] == 6
+
+    def test_a_compile_in_the_run_means_every_call_next(self, tracker):
+        """The first run of a program holds its compilation: its calls
+        look minutes long, so the second still reports every call."""
+        self._finished_run(tracker, "a", 28, 90.0 / 28)
+        assert tracker.traced_token(tracker.start("b", 28)).tolist()[1] == 1
+
+    def test_failed_or_silent_runs_teach_nothing(self, tracker):
+        token = tracker.start("a", 28)
+        tracker._on_event(token, 0, 1.0, np.zeros((1, 2, 2, 4), np.float32))
+        tracker.finish("a", failed=True)
+        tracker.start("b", 28)
+        tracker.finish("b")                 # no event at all
+        assert tracker.traced_token(tracker.start("c", 28)).tolist()[1] == 1
+
+    def test_strided_events_count_their_calls(self, tracker):
+        token = tracker.start("a", 28)
+        lat = np.zeros((1, 2, 2, 4), np.float32)
+        events._dispatch(np.array([token, 6], np.int32), 0, 5.0, lat)
+        events._dispatch(np.array([token, 6], np.int32), 0, 4.0, lat)
+        snap = tracker.snapshot("a")
+        assert snap["step"] == 12 and snap["total"] == 28
+        tracker.finish("a")
+        assert tracker.snapshot("a")["step"] == 28
+
+    def test_unknown_token_is_stride_one(self, tracker):
+        assert tracker.traced_token(12345).tolist() == [12345, 1]
 
 
 class TestTrackerCoexistence:
