@@ -274,6 +274,8 @@ def bundle_bytes(bundle, tp_shards: int = 1) -> int:
     from ..diffusion.offload import tree_bytes
 
     core = bundle._core_params()
+    if bundle.kind == "llm":          # no VAE, no text tower
+        return tree_bytes(core)
     low = getattr(bundle.pipeline, "dit_params_low", None)
     if tp_shards > 1:
         rules = _tp_rules_for(bundle)

@@ -1,0 +1,84 @@
+"""The prompt rewriter's two programs: ``llm_prefill`` and ``llm_decode``.
+
+Both are bound with ``bind_weights`` under a label, like every denoise
+program, so they are timed and spanned the same way
+(``cdt_pipeline_{compile,execute,dispatch}_seconds{pipeline}``,
+``program.launch`` / ``program.wait``). ``llm_decode`` is the whole decode
+loop in ONE program — ``samplers.token_program`` scanned by
+``run_segment`` — with no host round trip a token and no host callback,
+so both programs persist in the XLA cache.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..models import llm_hybrid
+from .pipeline import bind_weights, cached_build
+from .samplers import run_segment, token_program
+
+TAP_EVERY = 128     # llm_decode returns every 128th step's logits
+
+
+class LLMPipeline:
+    def __init__(self, config: llm_hybrid.LLMConfig, params):
+        self.config = config
+        self.params = params
+
+    def step(self, weights, state, token, pos):
+        """One decoded token: what ``llm_decode`` scans."""
+        return llm_hybrid.decode_step(self.config, weights, state, token,
+                                      pos)
+
+    def prefill_fn(self, prompt_tokens: int, new_tokens: int):
+        """``(ids [prompt_tokens]) -> (last logits [V], cache, held)``."""
+        cfg, max_len = self.config, prompt_tokens + new_tokens
+
+        def llm_prefill(weights, ids):
+            return llm_hybrid.prefill(cfg, weights, ids, max_len)
+
+        return bind_weights(jax.jit(llm_prefill), self.params,
+                            label="llm_prefill")
+
+    def decode_fn(self, prompt_tokens: int, new_tokens: int):
+        """``(logits, cache, key, temperature) -> (ids [new_tokens], tap
+        logits [new_tokens // TAP_EVERY, V], held slots per expert layer,
+        finite)``: ``new_tokens`` steps of the token program in one scan."""
+        cfg = self.config
+
+        def llm_decode(weights, logits, cache, key, temperature):
+            def forward(state, token, i):
+                return self.step(weights, state, token, prompt_tokens + i)
+
+            prog = token_program(forward, new_tokens, key, temperature,
+                                 TAP_EVERY, len(cfg.moe_layers))
+            carry = run_segment(prog, prog.init((logits, cache)), 0,
+                                new_tokens)
+            return prog.extract(carry), carry[3], carry[4], carry[5]
+
+        return bind_weights(jax.jit(llm_decode), self.params,
+                            label="llm_decode", steps=new_tokens)
+
+    def programs(self, prompt_tokens: int, new_tokens: int):
+        return cached_build(
+            self, ("llm", prompt_tokens, new_tokens),
+            lambda: (self.prefill_fn(prompt_tokens, new_tokens),
+                     self.decode_fn(prompt_tokens, new_tokens)))
+
+    def generate(self, ids, new_tokens: int, seed: int,
+                 temperature: float) -> dict:
+        """``ids`` (the prompt, host ints) → exactly ``new_tokens`` drawn
+        ids and what the programs say of themselves, fetched to the host
+        (the tap logits stay on the device)."""
+        prefill, decode = self.programs(len(ids), int(new_tokens))
+        logits, cache, held_prefill = prefill(jnp.asarray(ids, jnp.int32))
+        out, taps, held_decode, finite = decode(
+            logits, cache, jax.random.key(int(seed)),
+            jnp.asarray(temperature, jnp.float32))
+        # ``finite`` covers prefill's logits too: step 0 draws from them
+        return {"ids": np.asarray(out), "prefill_logits": logits,
+                "tap_logits": taps, "finite": bool(finite),
+                "held_prefill": np.asarray(held_prefill),
+                "held_decode": np.asarray(held_decode)}

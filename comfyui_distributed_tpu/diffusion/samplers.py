@@ -611,6 +611,57 @@ def extract_output(name: str, carry: tuple, **kwargs) -> jax.Array:
     return prog.extract(carry)
 
 
+# --- a decode loop is a sampler too ------------------------------------------
+
+
+def token_program(forward, n_steps: int, key: jax.Array, temperature,
+                  tap_every: int, n_counts: int) -> SamplerProgram:
+    """Autoregressive decoding as a :class:`SamplerProgram`, run by the
+    same :func:`run_segment` / :func:`run_program` drivers as the
+    denoisers: one step draws a token from the carried logits and runs
+    the model on it, ``forward(state, token, i) -> (logits [V] f32,
+    state, counts [n_counts] int32)``.
+
+    ``init((logits, state))`` takes what prefill left: the last prompt
+    position's logits and the model's state (any pytree: recurrent
+    states, convolution tails, a latent cache). The carry is ``(ids
+    [n_steps], logits, state, taps, counts, finite)``: the drawn ids
+    (``extract``), the logits the next draw will use, every
+    ``tap_every``-th step's logits as computed (``taps``
+    [n_steps // tap_every, V]: what a parity check compares), the sum of
+    ``forward``'s counts, and whether every logit drawn from was finite.
+    Step ``i`` draws with ``fold_in(key, i)`` of the GLOBAL index, so a
+    run cut into segments is the run uncut. ``temperature`` may be
+    traced; 0 is greedy. The carry is not the latent-shaped carry of the
+    denoisers' contract above: it is for one device, never sharded."""
+    tap_every = max(1, int(tap_every))
+
+    def init(x):
+        logits, state = x
+        return (jnp.zeros((n_steps,), jnp.int32), logits, state,
+                jnp.zeros((n_steps // tap_every, logits.shape[-1]),
+                          jnp.float32),
+                jnp.zeros((n_counts,), jnp.int32), jnp.asarray(True))
+
+    def step(carry, i):
+        ids, logits, state, taps, counts, finite = carry
+        finite = finite & jnp.isfinite(logits).all()
+        gumbel = jax.random.gumbel(jax.random.fold_in(key, i), logits.shape,
+                                   jnp.float32)
+        token = jnp.argmax(logits + temperature * gumbel).astype(jnp.int32)
+        ids = jax.lax.dynamic_update_index_in_dim(ids, token, i, 0)
+        logits, state, seen = forward(state, token, i)
+        if taps.shape[0]:
+            slot = jnp.minimum(i // tap_every, taps.shape[0] - 1)
+            row = jnp.where((i + 1) % tap_every == 0, logits,
+                            jax.lax.dynamic_index_in_dim(taps, slot, 0,
+                                                         False))
+            taps = jax.lax.dynamic_update_index_in_dim(taps, row, slot, 0)
+        return (ids, logits, state, taps, counts + seen, finite)
+
+    return SamplerProgram("token", n_steps, init, step, _extract_first)
+
+
 # --- the classic one-shot API (unchanged signatures) ------------------------
 
 

@@ -887,8 +887,118 @@ class CheckpointLoader(NodeDef):
         if model_registry is None:
             from ..models.registry import ModelRegistry
             model_registry = ModelRegistry()
+        from ..models.registry import PRESETS
+
+        preset = PRESETS.get(str(ckpt_name))
+        if preset is not None and preset.kind == "llm":
+            raise ValidationError(
+                f"{ckpt_name!r} is a language model: load it with "
+                "LLMLoader, not CheckpointLoader", field="ckpt_name")
         bundle = model_registry.get(ckpt_name)
         return (bundle, bundle.text_encoder, bundle.pipeline.vae)
+
+
+@register_node("LLMLoader")
+class LLMLoader(NodeDef):
+    """A language-model preset from the registry (``kind == "llm"``), held
+    resident beside the image models: ``LLM`` is its bundle."""
+
+    INPUTS = {"llm_name": "STRING"}
+    HIDDEN = {"model_registry": "*"}
+    RETURNS = ("LLM",)
+
+    def execute(self, llm_name: str, model_registry=None, **_):
+        from ..models.registry import PRESETS, ModelRegistry
+
+        preset = PRESETS.get(str(llm_name))
+        if preset is not None and preset.kind != "llm":
+            raise ValidationError(
+                f"{llm_name!r} is a {preset.kind} model: load it with "
+                "CheckpointLoader, not LLMLoader", field="llm_name")
+        if model_registry is None:
+            model_registry = ModelRegistry()
+        return (model_registry.get(llm_name),)
+
+
+# the rewriter's fixed instruction; cycled to fill the prompt to its length
+REWRITE_PREAMBLE = (
+    "you are a prompt engineer for an image model . think step by step "
+    "about the subject , the composition , the lighting , the lens and "
+    "the style the user most likely wants , then write one long detailed "
+    "caption that keeps every thing the user asked for and adds concrete "
+    "visual detail . do not add text , logos or watermarks . user prompt :")
+
+
+def rewrite_prompt_ids(text: str, prompt_tokens: int, vocab: int) -> list:
+    """Exactly ``prompt_tokens`` ids over ``[0, vocab)``: the fixed
+    preamble (cycled) and then the user's words, at most an eighth of the
+    prompt. A stand-in hash tokenizer, as ``models/text.py``'s: the
+    model's own tokenizer is not here."""
+    from ..models.text import _stable_hash_token
+
+    user = [_stable_hash_token(w, vocab)
+            for w in str(text).lower().split()][:max(1, prompt_tokens // 8)]
+    words = REWRITE_PREAMBLE.split()
+    lead = [_stable_hash_token(words[i % len(words)], vocab)
+            for i in range(prompt_tokens - len(user))]
+    return lead + user
+
+
+@register_node("TPUPromptRewrite")
+class TPUPromptRewrite(NodeDef):
+    """Rewrite a prompt with a language model ahead of ``CLIPTextEncode``:
+    ``prompt_tokens`` of instruction + user text in, exactly ``new_tokens``
+    sampled (no stop token), rendered as words. Two programs a call,
+    ``llm_prefill`` and ``llm_decode`` (``diffusion/pipeline_llm.py``);
+    the request fails on a non-finite logit or an id outside the
+    vocabulary slice the model holds."""
+
+    INPUTS = {"llm": "LLM", "text": "STRING", "seed": "INT"}
+    OPTIONAL = {"prompt_tokens": "INT", "new_tokens": "INT",
+                "temperature": "FLOAT"}
+    RETURNS = ("STRING",)
+
+    def execute(self, llm, text: str, seed: int, prompt_tokens: int = 512,
+                new_tokens: int = 1024, temperature: float = 0.7, **_):
+        from ..telemetry import enabled as _tm_enabled
+        from ..telemetry import metrics as _tm
+        from ..telemetry.spans import span
+
+        if getattr(llm, "kind", None) != "llm":
+            raise ValidationError("TPUPromptRewrite needs an LLM (wire "
+                                  "LLMLoader's output)", field="llm")
+        cfg = llm.pipeline.config
+        prompt_tokens, new_tokens = int(prompt_tokens), int(new_tokens)
+        if prompt_tokens < cfg.short_conv_kernel_size or new_tokens < 1:
+            raise ValidationError(
+                f"prompt_tokens {prompt_tokens} / new_tokens {new_tokens}: "
+                "too few", field="prompt_tokens")
+        with span("llm.tokenize", tokens=prompt_tokens):
+            ids = rewrite_prompt_ids(text, prompt_tokens, cfg.vocab_size)
+        with _pinned(llm):
+            out = llm.pipeline.generate(ids, new_tokens, int(seed),
+                                        float(temperature))
+        if _tm_enabled():
+            per_token = cfg.num_experts_per_tok * len(cfg.moe_layers)
+            for phase, tokens in (("prefill", prompt_tokens),
+                                  ("decode", new_tokens)):
+                held = int(out[f"held_{phase}"].sum())
+                _tm.LLM_TOKENS.labels(phase=phase).inc(tokens)
+                _tm.LLM_EXPERT_SLOTS.labels(where="held",
+                                            phase=phase).inc(held)
+                _tm.LLM_EXPERT_SLOTS.labels(where="absent", phase=phase).inc(
+                    tokens * per_token - held)
+        new_ids = out["ids"]
+        if not out["finite"]:
+            raise RuntimeError("the language model produced a non-finite "
+                               "logit")
+        if new_ids.min() < 0 or new_ids.max() >= cfg.vocab_size:
+            raise RuntimeError(
+                f"the language model drew an id outside its slice of "
+                f"{cfg.vocab_size} rows")
+        with span("llm.render", tokens=new_tokens):
+            words = " ".join(f"t{int(i)}" for i in new_ids)
+        return (words,)
 
 
 class _ShiftedModel:

@@ -45,9 +45,14 @@ class ModelPreset:
     # chip; held in bfloat16 it is 4.79 + 0.56 (docs/weights.md). The tiny
     # test presets stay float32: their parity tolerances are float32's.
     param_dtype: "str | None" = None
+    # LLMConfig of a language model (models/llm_hybrid.py): such a preset
+    # has no denoiser, VAE or text tower, and is loaded by LLMLoader
+    llm: "object | None" = None
 
     @property
     def kind(self) -> str:
+        if self.llm is not None:
+            return "llm"
         if self.video is not None:
             return "video"
         return "dit" if self.dit is not None else "unet"
@@ -200,6 +205,14 @@ def _wan_mmdit_preset():
         sample_hw=(60, 104), video=VideoDiTConfig.wan())
 
 
+def _llm_preset(name: str, tiny: bool = False):
+    from .llm_hybrid import LLMConfig
+
+    return ModelPreset(name, unet=None, vae=None, text=None,
+                       llm=LLMConfig.tiny() if tiny
+                       else LLMConfig.ling_flash_share())
+
+
 PRESETS: dict[str, ModelPreset] = {
     "sdxl": ModelPreset("sdxl", UNetConfig.sdxl(), VAEConfig.sdxl(),
                         TextEncoderConfig(), clip="sdxl",
@@ -223,6 +236,8 @@ PRESETS: dict[str, ModelPreset] = {
     "wan-2.2-t2v": _wan22_t2v_preset(),
     "wan-2.2-tiny": _wan22_tiny_preset(),
     "video-mmdit": _wan_mmdit_preset(),
+    "ling-3.0-flash-vl": _llm_preset("ling-3.0-flash-vl"),
+    "ling-tiny": _llm_preset("ling-tiny", tiny=True),
 }
 
 
@@ -726,6 +741,45 @@ class ModelBundle:
         self.pipeline.vae.dec_params = dec
 
 
+class LLMBundle:
+    """A loaded language model (``preset.kind == "llm"``): random weights
+    from the registry's seed, held on the device in the preset's dtype,
+    and the pipeline that binds its two programs. It shares the registry's
+    cache, lock and residency accounting with ``ModelBundle`` and has no
+    VAE or text tower of its own."""
+
+    kind = "llm"
+    text_encoder = None
+
+    def __init__(self, preset: ModelPreset,
+                 checkpoint_dir: Optional[Path] = None, seed: int = 0):
+        from ..diffusion.pipeline_llm import LLMPipeline
+        from .llm_hybrid import init_llm
+
+        if checkpoint_dir is not None and Path(checkpoint_dir).exists():
+            raise ValidationError(
+                f"preset {preset.name!r}: loading a language model's "
+                "checkpoint is not wired; it runs on seeded random weights")
+        self.preset = preset
+        self._init_seed = int(seed)
+        self.pipeline = LLMPipeline(
+            preset.llm, init_llm(preset.llm, jax.random.key(seed)))
+
+    def _core_params(self):
+        return self.pipeline.params
+
+    def text_tower(self) -> str:
+        return "none"
+
+    def weights_identity(self) -> str:
+        return f"{self.preset.name}/{_weights_tag(None, self._init_seed)}"
+
+    def release_device(self) -> None:
+        cache = getattr(self.pipeline, "_fn_cache", None)
+        if cache:
+            cache.clear()
+
+
 def _note_weights(name: str, bundle: "ModelBundle") -> None:
     """Say what was just put on the device: bytes, the dtype the
     denoiser is held in, and which text tower will serve prompts."""
@@ -780,7 +834,8 @@ class ModelRegistry:
                 if preset is None:
                     raise ValidationError(f"unknown model {name!r}; have {self.available()}")
                 ckpt = self.checkpoint_root / name if self.checkpoint_root else None
-                self._cache[name] = bundle = ModelBundle(preset, ckpt)
+                build = LLMBundle if preset.kind == "llm" else ModelBundle
+                self._cache[name] = bundle = build(preset, ckpt)
                 _note_weights(name, bundle)
             bundle = self._cache[name]
             if self.residency is not None:

@@ -53,6 +53,23 @@ PROGRESS_CALLBACK_SECONDS = REGISTRY.histogram(
     "Host time inside one progress callback (a denoise call's x0 preview "
     "handed to every registered sink).")
 
+# --- the prompt rewriter (graph/nodes_builtin.py: TPUPromptRewrite) ----------
+
+LLM_TOKENS = REGISTRY.counter(
+    "cdt_llm_tokens_total",
+    "Tokens a language model ran, by phase: prefill (prompt tokens of "
+    "llm_prefill) and decode (tokens drawn by llm_decode).",
+    ("phase",))
+
+LLM_EXPERT_SLOTS = REGISTRY.counter(
+    "cdt_llm_expert_slots_total",
+    "Routed expert slots (tokens x experts per token x expert layers), by "
+    "where the selected expert lives: held (on this chip, computed) or "
+    "absent (another chip of the expert group, left out), and by the "
+    "phase that routed them (prefill, decode). Counted inside the "
+    "programs and fed from their outputs.",
+    ("where", "phase"))
+
 # --- attention kernel dispatch / autotune (ops/attention.py, ops/autotune.py)
 
 ATTN_KERNEL_SELECTED = REGISTRY.counter(

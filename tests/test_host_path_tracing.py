@@ -224,6 +224,11 @@ def served(tmp_path_factory):
     patch.setenv("CDT_PROFILE_DIR", str(tmp / "profile"))
     patch.setenv("CDT_CACHE_DIR", str(tmp / "content_cache"))
     patch.setenv("CDT_PREEMPT_SEGMENT_STEPS", "2")
+    # every denoiser call reports: left to itself the tracker strides the
+    # second request's events by how long the first one's calls took, and
+    # a first request (compile included) under 3 s halves the counts below
+    from comfyui_distributed_tpu.cluster import progress as progress_mod
+    patch.setattr(progress_mod, "EVENT_PERIOD_S", 0.0)
     config_mod.invalidate_cache()
     was = telemetry.enabled()
     telemetry.set_enabled(True)
