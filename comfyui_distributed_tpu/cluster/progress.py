@@ -1,7 +1,9 @@
 """Host-side sampling-progress tracker: step counts + live latent previews.
 
 Consumes the ``jax.debug.callback`` events emitted by
-``diffusion/progress.wrap_denoiser`` and serves them to the control plane
+``diffusion/progress.wrap_denoiser`` and the host-side reports of the lanes
+that carry no callback (``report``: the served segment programs' outputs,
+the offloaded python ladders) and serves them to the control plane
 (``/distributed/progress/{prompt_id}``, ``/distributed/preview/{prompt_id}``)
 — the standalone equivalent of the per-step progress bar + live preview the
 reference inherits from ComfyUI's executor hooks.
@@ -156,13 +158,16 @@ class ProgressTracker:
                     job.calls_seen = job.total
                 job.updated = time.time()
 
-    def report(self, token: int, sigma: float, x0,
-               shard: int = 0) -> None:
-        """Host-side progress report — the offloaded samplers run their
-        ladder as a Python loop (``diffusion/offload.sample_euler_py``),
-        so they feed the SAME per-step progress/preview machinery the
-        compiled paths drive via ``jax.debug.callback``."""
-        self._on_event(token, shard, float(sigma), np.asarray(x0))
+    def report(self, token: int, sigma: float, x0, shard: int = 0,
+               calls: int = 1) -> None:
+        """Host-side progress report, feeding the SAME per-step
+        progress/preview machinery the callback paths drive: the
+        offloaded samplers run their ladder as a Python loop
+        (``diffusion/offload.sample_euler_py``, an event a step), and the
+        served lanes read each finished segment's last x0 from the
+        segment program's outputs (``diffusion/progress.deliver_segment``,
+        an event a segment and shard standing for ``calls`` calls)."""
+        self._on_event(token, shard, float(sigma), np.asarray(x0), calls)
 
     # --- event sink (jax.debug.callback, runtime threads) ---------------
 

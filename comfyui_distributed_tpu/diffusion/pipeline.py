@@ -634,9 +634,12 @@ class Txt2ImgPipeline:
 
         - ``prep(key, ctx, unc, y, uy) -> carry``: participant key
           fold-in + noise draw + the sampler's ``init``;
-        - ``seg(L)(key, ctx, unc, y, uy, start, carry) -> carry``: ``L``
-          denoise steps from traced global index ``start`` (one compiled
-          program per distinct length serves every offset);
+        - ``seg(L)(key, ctx, unc, y, uy, start, carry) -> (carry, sigma,
+          x0)``: ``L`` denoise steps from traced global index ``start``
+          (one compiled program per distinct length serves every
+          offset), with the last step's sigma and x0 estimate (one
+          latent a shard) as outputs for the progress stream — no host
+          callback, so the program persists in the compile cache;
         - ``fin(carry) -> images``: output-slot extract + VAE decode.
 
         The carry rides shard_map per the sampler contract
@@ -648,6 +651,7 @@ class Txt2ImgPipeline:
         every step applies the same closure at the same global index
         (tested: ``tests/test_checkpoint.py``,
         ``tests/test_preemption.py``)."""
+        from .progress import DenoiserTap
         from .samplers import carry_structure, extract_output, make_program
         from .samplers import run_segment as _run_segment
 
@@ -687,11 +691,14 @@ class Txt2ImgPipeline:
                 k, context, uncond,
                 y if has_y else None, uy if has_y else None,
                 spec, B, sigmas, progress=prog_pair, weights=weights)
-            return make_program(spec.sampler, denoise, sigmas,
-                                key=k_samp), x
+            # the tap costs a program nothing unless run_segment is asked
+            # to emit what it kept
+            tap = DenoiserTap(denoise)
+            return make_program(spec.sampler, tap, sigmas,
+                                key=k_samp), x, tap
 
         def prep_body(weights, key, context, uncond, y, uy):
-            prog, x = build_program(weights, key, context, uncond, y, uy)
+            prog, x, _ = build_program(weights, key, context, uncond, y, uy)
             return prog.init(x)
 
         prep = bind_weights(jax.jit(shard_map(
@@ -700,26 +707,36 @@ class Txt2ImgPipeline:
 
         def make_seg(length: int, with_token: bool):
             if with_token:
+                # the callback-carrying form: no lane of serve runs it
+                # (it is never persisted and launches in ~0.055 s); kept
+                # for the off-chip description in cdtbench/kinds/unet.py
                 def seg_body(weights, key, context, uncond, y, uy,
                              start, carry, token):
-                    prog, _ = build_program(weights, key, context,
-                                            uncond, y, uy, token=token)
+                    prog, _, _ = build_program(weights, key, context,
+                                               uncond, y, uy, token=token)
                     return _run_segment(prog, tuple(carry), start,
                                         length)
 
                 in_specs = base_specs + (P(), carry_specs, P())
+                out_specs = carry_specs
             else:
+                # the served form: no host effect; the last step's x0
+                # (first batch element of each shard) and its sigma
+                # leave as outputs beside the carry, which gains no leaf
                 def seg_body(weights, key, context, uncond, y, uy,
                              start, carry):
-                    prog, _ = build_program(weights, key, context,
-                                            uncond, y, uy)
-                    return _run_segment(prog, tuple(carry), start,
-                                        length)
+                    prog, _, tap = build_program(weights, key, context,
+                                                 uncond, y, uy)
+                    carry, (sigma, x0) = _run_segment(
+                        prog, tuple(carry), start, length, tap=tap)
+                    return carry, sigma, x0
 
                 in_specs = base_specs + (P(), carry_specs)
+                out_specs = (carry_specs, P(),
+                             P(axis, *(None,) * (len(x_shape) - 1)))
             return bind_weights(jax.jit(shard_map(
                 seg_body, mesh=mesh, in_specs=in_specs,
-                out_specs=carry_specs)), weights,
+                out_specs=out_specs)), weights,
                 label="txt2img_seg", steps=length)
 
         def fin_body(weights, carry):
@@ -787,7 +804,7 @@ class Txt2ImgPipeline:
         segment_steps: Optional[int] = None,
         should_preempt=None,
         resume=None,
-        progress_token: Optional[int] = None,
+        on_step=None,
     ) -> dict:
         """Run the solo generation in resumable K-step segments.
 
@@ -803,11 +820,18 @@ class Txt2ImgPipeline:
         the same inputs, interrupted or not.
 
         At least one segment always runs per invocation, so a
-        preempt-storm cannot live-lock a job into never advancing."""
+        preempt-storm cannot live-lock a job into never advancing.
+
+        ``on_step(sigma, x0, calls=, shard=)`` is the host-side progress
+        reporter (``_ProgressScope.on_step``): after every segment it is
+        handed, per dp shard, the x0 estimate of the segment's last step
+        and the model calls the segment made — read from the segment
+        program's outputs, never through a host callback."""
         import numpy as np
 
         from ..utils import constants as _c
         from .checkpoint import CheckpointRestoreError, LatentCheckpoint
+        from .progress import deliver_segment, segment_calls
 
         seg_steps = max(1, int(segment_steps
                                or _c.PREEMPT_SEGMENT_STEPS.get()))
@@ -862,14 +886,15 @@ class Txt2ImgPipeline:
                         return {"checkpoint": ckpt, "reason": reason,
                                 "step": start}
                 length = min(seg_steps, n - start)
-                seg = bundle["seg"](length, progress_token is not None)
-                operands = (jnp.int32(start), carry)
-                if progress_token is not None:
-                    operands += (jnp.asarray(progress_token, jnp.int32),)
-            carry = seg(*args, *operands)
+                seg = bundle["seg"](length)
+                at = jnp.int32(start)
+            carry, sigma, previews = seg(*args, at, carry)
             # materialize: the segment boundary IS the preemption point —
             # an unbounded dispatch pipeline would make it meaningless
             jax.block_until_ready(carry)
+            if on_step is not None:
+                deliver_segment(on_step, sigma, previews, segment_calls(
+                    spec.sampler, start, length, n))
             if resume_t0 is not None:
                 from .. import telemetry
                 if telemetry.enabled():

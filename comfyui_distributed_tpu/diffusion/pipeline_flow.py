@@ -2,7 +2,9 @@
 
 1. ``generate_fn`` — data-parallel seed fan-out over ``dp`` (the same
    contract as ``Txt2ImgPipeline``: BASELINE's "8 seed-varied images per
-   step-time").
+   step-time"). ``serve`` runs this mode as ``segment_fns``' prep →
+   segments → finish (``generate_segmented``): the same math with no host
+   callback, progress read from each segment's outputs.
 2. ``generate_sp_fn`` — ONE image's tokens sharded over ``sp`` with ring
    attention: the sampler's whole scan runs with every shard holding a row
    block of the latent — single-image latency scales with chip count,
@@ -41,6 +43,31 @@ class FlowSpec:
                                     # bakes guidance into `guidance`)
     sampler: str = "euler"
     per_device_batch: int = 1
+
+
+def _read_as(fn, params, *args) -> list:
+    """For each leaf of ``params`` (abstract shapes do), the ONE dtype
+    every use of it inside ``fn(params, *args)`` first converts it to —
+    a model computing in bf16 reads its float32-held weights so — or None
+    where some use takes the leaf as it is held. Converting such a leaf
+    ahead of the program is the program's own arithmetic, to the bit."""
+    closed = jax.make_jaxpr(fn)(params, *args)
+    leaves = closed.jaxpr.invars[:len(jax.tree.leaves(params))]
+    uses: dict = {id(v): set() for v in leaves}
+    for eqn in closed.jaxpr.eqns:
+        to = (jnp.dtype(eqn.params["new_dtype"])
+              if eqn.primitive.name == "convert_element_type" else None)
+        for v in eqn.invars:
+            if id(v) in uses:
+                uses[id(v)].add(to)
+    for v in closed.jaxpr.outvars:
+        if id(v) in uses:
+            uses[id(v)].add(None)
+    plan = []
+    for v in leaves:
+        (to,) = uses[id(v)] if len(uses[id(v)]) == 1 else (None,)
+        plan.append(None if to == v.aval.dtype else to)
+    return plan
 
 
 class FlowPipeline:
@@ -90,11 +117,13 @@ class FlowPipeline:
         return cfg_denoiser(make, context, uncond_context, cfg,
                             y=pooled, uncond_y=uncond_pooled)
 
-    def _sample_and_decode(self, key, context, pooled, spec: FlowSpec,
-                           batch: int, sigmas, lat_hw, sp_axis=None,
-                           decode: bool = True, weights=None,
-                           progress=None, uncond_context=None,
-                           uncond_pooled=None):
+    def _build_sampling(self, key, context, pooled, spec: FlowSpec,
+                        batch: int, lat_hw, sp_axis=None, weights=None,
+                        uncond_context=None, uncond_pooled=None):
+        """Everything before the sampler scan: the noise draw and the
+        (guided) denoiser closure. ONE definition for the monolithic
+        ``_sample_and_decode`` and the served segment programs
+        (``segment_fns``), so the two cannot drift apart."""
         lat_h, lat_w = lat_hw
         c = self.dit.config.in_channels
         x = jax.random.normal(key, (batch, lat_h, lat_w, c), jnp.float32)
@@ -104,6 +133,22 @@ class FlowPipeline:
                              weights=weights, cfg=spec.cfg,
                              uncond_context=bc(uncond_context),
                              uncond_pooled=bc(uncond_pooled))
+        return den, x
+
+    def _decode_latent(self, x0, weights=None):
+        images = self.vae.decode(
+            x0, params=None if weights is None else weights["vae_dec"])
+        return jnp.clip(images / 2.0 + 0.5, 0.0, 1.0)
+
+    def _sample_and_decode(self, key, context, pooled, spec: FlowSpec,
+                           batch: int, sigmas, lat_hw, sp_axis=None,
+                           decode: bool = True, weights=None,
+                           progress=None, uncond_context=None,
+                           uncond_pooled=None):
+        den, x = self._build_sampling(
+            key, context, pooled, spec, batch, lat_hw, sp_axis=sp_axis,
+            weights=weights, uncond_context=uncond_context,
+            uncond_pooled=uncond_pooled)
         if progress is not None:
             from .progress import wrap_denoiser
 
@@ -111,9 +156,7 @@ class FlowPipeline:
         x0 = sample(spec.sampler, den, x, sigmas, key=key)
         if not decode:
             return x0
-        images = self.vae.decode(
-            x0, params=None if weights is None else weights["vae_dec"])
-        return jnp.clip(images / 2.0 + 0.5, 0.0, 1.0)
+        return self._decode_latent(x0, weights)
 
     # --- mode 1: dp seed fan-out -------------------------------------------
 
@@ -213,6 +256,195 @@ class FlowPipeline:
         if progress_token is not None:
             args.append(jnp.asarray(progress_token, jnp.int32))
         return fn(*args)
+
+    # --- mode 1, as serve runs it: callback-free segments ------------------
+
+    def segment_fns(self, mesh: Mesh, spec: FlowSpec,
+                    axis: str = constants.AXIS_DATA):
+        """The ``dp`` generator as the triple ``Txt2ImgPipeline.
+        preemptible_fns`` cuts the UNet lane into, over the same shard
+        math as :meth:`generate_fn` (bit-identical to it: a scan cut into
+        segments is the scan, ``tests/test_segment_progress.py``):
+
+        - ``prep(key, ctx, pooled[, unc, unc_pooled]) -> carry``:
+          participant key fold-in + noise + the sampler's ``init``;
+        - ``seg(L)(key, ..., start, carry) -> (carry, sigma, x0)``: ``L``
+          steps from the traced global index ``start``, labelled
+          ``flow_dp``; the last step's sigma and x0 (one latent a shard)
+          are outputs for the progress stream;
+        - ``fin(carry) -> images``: output-slot extract + VAE decode;
+        - ``cast() -> leaves``: the DiT's weights as its forward pass
+          reads them, where that is not how they are held (``sd3-medium``
+          is held in float32 and computes in bfloat16). XLA hoists those
+          conversions out of a program's step loop, so the one program
+          paid them once a request and every segment program would pay
+          them again (12 ms each at SD3-medium's size); ``seg`` takes
+          ``cast``'s answer as its last argument instead, and the
+          request pays them once as before. None where nothing is
+          converted — and ``seg`` given ``()`` converts for itself, which
+          is what a run of ONE segment wants: nothing to share, and no
+          4 GiB to allocate beside two resident models.
+
+        None carries a host callback, so all persist in the compile
+        cache and launch in ~2 ms."""
+        from .pipeline import cached_build, mesh_cache_key
+        from .progress import DenoiserTap
+        from .samplers import (carry_structure, extract_output, make_program,
+                               run_segment)
+
+        def build():
+            sigmas = sigmas_flow(spec.steps, spec.shift)
+            ds = self.vae.config.downscale
+            lat_hw = (spec.height // ds, spec.width // ds)
+            B = spec.per_device_batch
+            x_shape = (B,) + lat_hw + (self.dit.config.in_channels,)
+            latent = P(axis, *(None,) * (len(x_shape) - 1))
+            carry_specs = tuple(
+                latent if tuple(leaf.shape) == x_shape else P()
+                for leaf in carry_structure(
+                    spec.sampler, jax.ShapeDtypeStruct(x_shape, jnp.float32)))
+            base_specs = (P(), P(), P(None, None, None), P(None, None))
+            if spec.cfg != 1.0:
+                base_specs += (P(None, None, None), P(None, None))
+            weights = self._weights()
+            like = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+            read_as = _read_as(
+                self.dit.apply,
+                jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                             weights["dit"]),
+                like(*x_shape), like(B),
+                like(B, 1, self.dit.config.context_dim),
+                like(B, self.dit.config.pooled_dim), like(B))
+            dit_def = jax.tree.structure(weights["dit"])
+
+            def flow_cast_body(weights):
+                return tuple(
+                    leaf.astype(to) for leaf, to in zip(
+                        jax.tree.leaves(weights["dit"]), read_as)
+                    if to is not None)
+
+            def with_cast(weights, cast):
+                """``weights`` with the converted leaves in their places:
+                the held ones they replace are then arguments no program
+                reads, which jit drops."""
+                if not cast:
+                    return weights
+                cast = iter(cast)
+                return {**weights, "dit": jax.tree.unflatten(dit_def, [
+                    leaf if to is None else next(cast) for leaf, to in zip(
+                        jax.tree.leaves(weights["dit"]), read_as)])}
+
+            cast = (bind_weights(jax.jit(shard_map(
+                flow_cast_body, mesh=mesh, in_specs=(P(),), out_specs=P())),
+                weights, name="flow_cast")
+                if any(to is not None for to in read_as) else None)
+
+            def program(weights, key, context, pooled, *uncond):
+                k = participant_key(key, axis)
+                den, x = self._build_sampling(
+                    k, context, pooled, spec, B, lat_hw, weights=weights,
+                    uncond_context=uncond[0] if uncond else None,
+                    uncond_pooled=uncond[1] if uncond else None)
+                tap = DenoiserTap(den)
+                return make_program(spec.sampler, tap, sigmas, key=k), x, tap
+
+            def flow_prep_body(weights, *args):
+                prog, x, _ = program(weights, *args)
+                return prog.init(x)
+
+            prep = bind_weights(jax.jit(shard_map(
+                flow_prep_body, mesh=mesh, in_specs=base_specs,
+                out_specs=carry_specs)), weights, name="flow_prep")
+
+            def make_seg(length: int):
+                def flow_seg_body(weights, *args):
+                    *args, start, carry, cast = args
+                    prog, _, tap = program(with_cast(weights, cast), *args)
+                    carry, (sigma, x0) = run_segment(
+                        prog, tuple(carry), start, length, tap=tap)
+                    return carry, sigma, x0
+
+                return bind_weights(jax.jit(shard_map(
+                    flow_seg_body, mesh=mesh,
+                    in_specs=base_specs + (P(), carry_specs, P()),
+                    out_specs=(carry_specs, P(), latent))), weights,
+                    label="flow_dp", steps=length)
+
+            def flow_fin_body(weights, carry):
+                return self._decode_latent(
+                    extract_output(spec.sampler, tuple(carry)), weights)
+
+            fin = bind_weights(jax.jit(shard_map(
+                flow_fin_body, mesh=mesh, in_specs=(P(), carry_specs),
+                out_specs=P(axis, None, None, None))), weights,
+                name="flow_fin")
+
+            segs: dict = {}
+
+            def seg(length: int):
+                if length not in segs:
+                    segs[length] = make_seg(length)
+                return segs[length]
+
+            return {"prep": prep, "seg": seg, "fin": fin, "cast": cast,
+                    "n_steps": spec.steps}
+
+        return cached_build(
+            self, ("segments", mesh_cache_key(mesh), spec, axis), build,
+            self._CACHE_MAX)
+
+    def generate_segmented(self, mesh: Mesh, spec: FlowSpec, seed: int,
+                           context: jax.Array, pooled: jax.Array,
+                           uncond_context: Optional[jax.Array] = None,
+                           uncond_pooled: Optional[jax.Array] = None,
+                           on_step=None, should_stop=None) -> jax.Array:
+        """:meth:`generate` as the serving lane runs it: prep → equal
+        segments of at most ``CDT_PREEMPT_SEGMENT_STEPS`` steps → finish,
+        bit-identical to the one program. After each segment
+        ``on_step(sigma, x0, calls=, shard=)`` (``_ProgressScope.
+        on_step``) is handed the segment's last x0 per dp shard, read
+        from the program's outputs; before each but the first
+        ``should_stop()`` is asked (``/distributed/interrupt``) and a
+        true answer raises ``InterruptedError``, as the offloaded ladder
+        does between steps."""
+        from ..telemetry.spans import span
+        from .progress import deliver_segment, segment_calls
+        from .samplers import equal_segment_steps
+
+        self._require_uncond(spec, uncond_context)
+        fns = self.segment_fns(mesh, spec)
+        n = fns["n_steps"]
+        args = (jax.random.key(seed), context, pooled)
+        if spec.cfg != 1.0:
+            if uncond_pooled is None:
+                uncond_pooled = jnp.zeros_like(pooled)
+            args += (uncond_context, uncond_pooled)
+        seg_steps = equal_segment_steps(
+            n, constants.PREEMPT_SEGMENT_STEPS.get())
+        carry = fns["prep"](*args)
+        # converted once for the segments to share; one segment alone
+        # converts inside its own program, as the one program did
+        cast = fns["cast"]() if fns["cast"] and n > seg_steps else ()
+        start = 0
+        while start < n:
+            with span("segment.boundary", step=start):
+                if start and should_stop is not None and should_stop():
+                    raise InterruptedError(
+                        f"sampling interrupted at step {start}/{n}")
+                length = min(seg_steps, n - start)
+                seg = fns["seg"](length)
+                at = jnp.int32(start)
+            carry, sigma, previews = seg(*args, at, carry, cast)
+            if on_step is not None:
+                deliver_segment(on_step, sigma, previews, segment_calls(
+                    spec.sampler, start, length, n))
+            start += length
+        # the last segment has been waited for: the converted weights go
+        # before the decode's scratch comes (both do not fit beside two
+        # resident models, PERF.md §6)
+        for leaf in cast:
+            leaf.delete()
+        return fns["fin"](carry)
 
     @staticmethod
     def _require_uncond(spec: FlowSpec, uncond_context) -> None:

@@ -23,6 +23,21 @@ Callbacks are unordered; ``sigma`` (strictly decreasing over the ladder)
 is the ordering key the sink uses to keep the newest preview and a
 monotonic step count.
 
+Which lanes stream how (PR 27). A program with a host callback is never
+written to JAX's persistent compile cache and its Python launch costs
+~0.055 s, so the two SERVED lanes carry none: ``TPUTxt2Img``'s serving
+lane (``Txt2ImgPipeline.generate_preemptible``) and ``TPUFlowTxt2Img``'s
+``dp`` mode (``FlowPipeline.generate_segmented``) run callback-free
+segment programs whose denoiser is wrapped in a :class:`DenoiserTap`: the
+x0 of a segment's last step leaves the program as an ordinary output and
+the host hands it to the same sinks when the segment is over
+(:func:`deliver_segment`), one event a segment and chip standing for the
+segment's calls. Every other compiled program — the monolithic
+``generate_fn`` builders (ControlNet graphs, ``CDT_PREEMPT=0``), img2img,
+near, video, microbatch, the resident offload ladders — still streams
+through ``wrap_denoiser``'s callback, stride and all.
+``cdt_progress_events_total{source}`` says which path fed the stream.
+
 This module is deliberately free of cluster/HTTP imports: sinks are
 registered (``add_sink``) by ``cluster/progress.ProgressTracker``.
 
@@ -46,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry import enabled as _tm_enabled
 from ..telemetry import metrics as _tm
 from ..telemetry import spans as _spans
 
@@ -115,6 +131,8 @@ def _dispatch(token, shard, sigma, x0) -> None:
     with _LOCK:
         sinks = list(_SINKS.values())
         trace_id, parent_id = _ORIGIN.get(int(token), (None, None))
+    if _tm_enabled():
+        _tm.PROGRESS_EVENTS.labels(source="callback").inc()
     with _spans.timed_span("progress.sink", _tm.PROGRESS_CALLBACK_SECONDS,
                            trace_id=trace_id, parent_id=parent_id):
         for sink in sinks:
@@ -142,6 +160,16 @@ def total_calls(sampler: str, steps: int) -> int:
     if sampler in _SECOND_ORDER:
         return max(1, 2 * steps - 1)
     return steps
+
+
+def segment_calls(sampler: str, start: int, length: int, steps: int) -> int:
+    """Model calls of ladder steps ``[start, start + length)`` of a
+    ``steps``-step run: what one segment's event stands for. Summed over
+    the segments of a run it is ``total_calls``."""
+    calls = calls_per_step(sampler) * length
+    if sampler in _SECOND_ORDER and start + length >= steps:
+        calls -= 1                 # the final step's single-call fallback
+    return calls
 
 
 # the (traced) global ladder index of the sampler step being traced, set by
@@ -190,3 +218,49 @@ def wrap_denoiser(denoise, token, shard_index):
         return x0
 
     return wrapped
+
+
+# --- the served lanes: progress as a program output, no callback -------------
+
+
+class DenoiserTap:
+    """Interpose on a denoiser WITHOUT a host effect: remember the first
+    call a sampler step makes — ``(sigma, x0[:1])``, traced at the step's
+    own level (every sampler's first call is; a second-order corrector
+    inside a ``cond`` is not, and is never kept) — for the scan that runs
+    the step to emit as its per-step output
+    (``samplers.run_segment(..., tap=)``). The program's last row is the
+    preview the host reads when the segment is over."""
+
+    def __init__(self, denoise):
+        self._denoise = denoise
+        self._seen = None
+
+    def __call__(self, x, sigma):
+        x0 = self._denoise(x, sigma)
+        if self._seen is None:
+            self._seen = (jnp.asarray(sigma, jnp.float32), x0[:1])
+        return x0
+
+    def take(self):
+        """The call kept since the last ``take`` (None if none)."""
+        seen, self._seen = self._seen, None
+        return seen
+
+
+def deliver_segment(on_step, sigma, previews, calls: int) -> None:
+    """Hand a finished segment's tap to the host-side reporter
+    ``on_step(sigma, x0, calls=, shard=)`` (``_ProgressScope.on_step`` →
+    ``ProgressTracker.report``): ``previews`` is the program's output, one
+    latent a dp shard ([n_dp, ...]), fetched here — the program is over,
+    so this waits on a copy and on no chip. Timed like a callback:
+    ``progress.sink`` span, ``cdt_progress_callback_seconds``."""
+    with _spans.timed_span("progress.sink", _tm.PROGRESS_CALLBACK_SECONDS,
+                           source="segment"):
+        sigma, previews = float(sigma), np.asarray(previews)
+        if _tm_enabled():
+            _tm.PROGRESS_EVENTS.labels(source="segment").inc(
+                previews.shape[0])
+        for shard in range(previews.shape[0]):
+            on_step(sigma, previews[shard:shard + 1], calls=calls,
+                    shard=shard)

@@ -63,31 +63,51 @@ class SamplerProgram:
     extract: Callable[[tuple], jax.Array]
 
 
-def _scan_body(prog: SamplerProgram):
+def _scan_body(prog: SamplerProgram, tap=None):
     """The scan body of both drivers: one step at its GLOBAL index, which
     a progress-streaming denoiser reads (``progress.sampler_step``) to
-    report only every stride-th step."""
+    report only every stride-th step. With a ``tap`` (a
+    ``progress.DenoiserTap`` the program's denoiser was wrapped in) the
+    step's per-step output is what the tap kept of it, ``(sigma,
+    x0[:1])``; without one there is no output and nothing is added."""
 
     def body(carry, i):
         with sampler_step(i):
-            return prog.step(carry, i), None
+            if tap is None:
+                return prog.step(carry, i), None
+            tap.take()
+            return prog.step(carry, i), tap.take()
 
     return body
 
 
-def run_segment(prog: SamplerProgram, carry: tuple, start,
-                length: int) -> tuple:
+def run_segment(prog: SamplerProgram, carry: tuple, start, length: int,
+                tap=None):
     """Advance ``length`` steps from global index ``start``.
 
     ``start`` may be traced (one compiled segment program serves every
     offset of that length); ``length`` is static. The xs are
     ``start + arange(length)`` so the step closure sees the same global
-    indices the monolithic scan would."""
+    indices the monolithic scan would. With a ``tap`` the answer is
+    ``(carry, (sigma, x0))`` of the segment's last step (so ``length`` is
+    1 or more) — progress as an ordinary output, the carry's bits
+    untouched."""
     if length <= 0:
         return carry
     xs = jnp.asarray(start, jnp.int32) + jnp.arange(length, dtype=jnp.int32)
-    carry, _ = jax.lax.scan(_scan_body(prog), carry, xs)
-    return carry
+    carry, seen = jax.lax.scan(_scan_body(prog, tap), carry, xs)
+    if tap is None:
+        return carry
+    return carry, jax.tree.map(lambda rows: rows[-1], seen)
+
+
+def equal_segment_steps(n_steps: int, at_most: int) -> int:
+    """The length to cut an ``n_steps`` ladder at so that it runs in the
+    fewest segments of ``at_most`` steps or fewer, all equal but for a
+    shorter last one: 28 at most 8 at a time is 7+7+7+7 — ONE compiled
+    segment program where 8+8+8+4 would be two."""
+    n_segments = max(1, -(-n_steps // max(1, at_most)))
+    return -(-n_steps // n_segments)
 
 
 def run_program(prog: SamplerProgram, x: jax.Array) -> jax.Array:

@@ -72,16 +72,55 @@ def _abstract(shape, dtype="float32"):
     return jax.ShapeDtypeStruct(tuple(shape), dtype)
 
 
+def _lower_segments(fns: dict, args: tuple, lengths) -> None:
+    """Compile a served prep → segments → finish triple
+    (``Txt2ImgPipeline.preemptible_fns``, ``FlowPipeline.segment_fns``).
+    What one program hands the next (the carry, the flow lane's
+    converted weights) is described with the sharding it is left in: a
+    committed argument is part of what jit lowers, and the executable
+    warmed here must be the one a request looks up."""
+    import jax
+    import jax.numpy as jnp
+
+    def outputs(fn, *operands):
+        """Compile ``fn``; its outputs, abstract, as it places them."""
+        compiled = fn.jitted.lower(fn.weights, *operands).compile()
+        return jax.tree.map(
+            lambda leaf, sharding: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=sharding),
+            jax.eval_shape(fn.jitted, fn.weights, *operands),
+            compiled.output_shardings)
+
+    carry = outputs(fns["prep"], *args)
+    tail = (carry,)
+    if "cast" in fns:      # the flow lane: shared by two segments or more
+        shared = fns["cast"] is not None and len(lengths) > 1
+        tail += (outputs(fns["cast"]) if shared else (),)
+    for length in sorted(set(lengths)):
+        outputs(fns["seg"](length), *args, _abstract((), jnp.int32), *tail)
+    outputs(fns["fin"], carry)
+
+
+def _cut(n_steps: int, length: int) -> list:
+    """The segment lengths an ``n_steps`` ladder runs in, ``length`` at a
+    time."""
+    return [min(length, n_steps - start)
+            for start in range(0, n_steps, length)]
+
+
 def lower_program(bundle, key: ProgramKey, mesh) -> None:
     """Trace + XLA-compile ONE catalog program ahead of time. Shapes come
     from the preset's config (context length / dims) and the key's
     geometry; nothing executes and no batch-sized buffer is allocated.
 
-    The ``progress=True`` variant is compiled — that IS the serving
-    program: every sampler node runs with a live ProgressTracker
-    (``_ProgressScope`` always yields a token on the server path), and
-    the progress token changes the traced HLO, so warming the
-    token-less variant would leave the first real request cold."""
+    What is compiled IS what serves the key. ``txt2img`` (with
+    preemption on, the default) and ``flow_dp`` are served as
+    callback-free prep → segments → finish triples, which persist in
+    the compile cache; the rest as one program in its ``progress=True``
+    variant, since every sampler node runs with a live ProgressTracker
+    and the progress token changes the traced HLO (those carry a host
+    callback, are never persisted, and are warm only for the life of
+    the process)."""
     import jax
     import jax.numpy as jnp
 
@@ -100,22 +139,32 @@ def lower_program(bundle, key: ProgramKey, mesh) -> None:
         spec = GenerationSpec(height=key.height, width=key.width,
                               steps=key.steps,
                               per_device_batch=key.batch)
-        fn = bundle.pipeline.generate_fn(mesh, spec, progress=True)
         text = bundle.preset.text
         ctx = _abstract((1, text.max_len, text.output_dim))
         adm = bundle.pipeline.unet.config.adm_in_channels
         y = _abstract((1, max(adm, 1)))
+        if constants.PREEMPT.get():
+            fns = bundle.pipeline.preemptible_fns(mesh, spec)
+            _lower_segments(fns, (prng, ctx, ctx, y, y), _cut(
+                fns["n_steps"], constants.PREEMPT_SEGMENT_STEPS.get()))
+            return
+        fn = bundle.pipeline.generate_fn(mesh, spec, progress=True)
         args = (prng, ctx, ctx, y, y, token)
     elif key.pipeline == "flow_dp":
         from .pipeline_flow import FlowSpec
 
         spec = FlowSpec(height=key.height, width=key.width,
                         steps=key.steps, per_device_batch=key.batch)
-        fn = bundle.pipeline.generate_fn(mesh, spec, progress=True)
+        from .samplers import equal_segment_steps
+
         cfg = bundle.pipeline.dit.config
         ctx = _abstract((1, bundle.preset.text.max_len, cfg.context_dim))
         pooled = _abstract((1, cfg.pooled_dim))
-        args = (prng, ctx, pooled, token)
+        fns = bundle.pipeline.segment_fns(mesh, spec)
+        _lower_segments(fns, (prng, ctx, pooled), _cut(
+            spec.steps, equal_segment_steps(
+                spec.steps, constants.PREEMPT_SEGMENT_STEPS.get())))
+        return
     elif key.pipeline == "video_dp":
         from .pipeline_video import VideoSpec
 

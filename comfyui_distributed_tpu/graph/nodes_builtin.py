@@ -467,23 +467,30 @@ def _stop_cb(interrupt_event):
 
 class _ProgressScope:
     """Progress lifecycle shared by the sampler nodes: allocates a token
-    on entry; ``complete(out)`` blocks on the result AND drains pending
-    ``jax.debug.callback`` effects (block_until_ready alone does not
-    flush them) before exit marks the run done — anything else marks it
-    failed, freezing progress where it stopped instead of reporting
-    100%. ``traced`` is what a compiled run takes as ``progress_token``:
-    the token with the tracker's event stride. ``on_step`` is the
-    host-side reporter for the offloaded (python-ladder) samplers — same
-    tracker, no traced token."""
+    on entry; ``complete(out)`` blocks on the result before exit marks
+    the run done — anything else marks it failed, freezing progress where
+    it stopped instead of reporting 100%.
+
+    Two ways feed the tracker. The SERVED lanes (``TPUTxt2Img``'s
+    preemptible lane, ``TPUFlowTxt2Img`` in ``dp`` mode) and the
+    offloaded python ladders report host-side through ``on_step``: their
+    programs carry no host callback, and a segment's last x0 is read from
+    its outputs (``diffusion/progress.deliver_segment``). Every other
+    compiled run takes ``traced`` as its ``progress_token`` — the token
+    with the tracker's event stride — and streams through
+    ``jax.debug.callback``; ``complete`` then also drains the pending
+    callbacks (``block_until_ready`` alone does not flush them)."""
 
     @property
     def traced(self):
         return (None if self.token is None
                 else self.tracker.traced_token(self.token))
 
-    def on_step(self, sigma: float, x0) -> None:
+    def on_step(self, sigma: float, x0, calls: int = 1,
+                shard: int = 0) -> None:
         if self.token is not None:
-            self.tracker.report(self.token, sigma, x0)
+            self.tracker.report(self.token, sigma, x0, shard=shard,
+                                calls=calls)
 
     def __init__(self, tracker, prompt_id: str, total_calls: int):
         self.tracker, self.prompt_id = tracker, prompt_id
@@ -491,10 +498,13 @@ class _ProgressScope:
                       if tracker is not None and prompt_id else None)
         self._ok = False
 
-    def complete(self, out) -> None:
+    def complete(self, out, callbacks: bool = True) -> None:
+        """``callbacks=False`` where the run's programs carry none: there
+        is nothing for an ``effects_barrier`` to drain."""
         if self.token is not None:
             jax.block_until_ready(out)
-            jax.effects_barrier()
+            if callbacks:
+                jax.effects_barrier()
         self._ok = True
 
     def __enter__(self):
@@ -1139,11 +1149,12 @@ class TPUTxt2Img(NodeDef):
         if preemption is not None and hint is None:
             # serving lane (cluster/preemption.py): resumable K-step
             # segments, preempt checks at segment boundaries, optional
-            # checkpoint restore. Bit-identical to the monolithic path,
-            # and per-step preview streaming rides the segment programs
-            # exactly like the monolithic token variant. (ControlNet
-            # graphs keep the monolithic path: per-request hints are
-            # not threaded through the segment programs.)
+            # checkpoint restore. Bit-identical to the monolithic path;
+            # the segment programs carry no host callback and the
+            # progress stream is fed from their outputs, a preview a
+            # segment. (ControlNet graphs keep the monolithic path:
+            # per-request hints are not threaded through the segment
+            # programs.)
             with _pinned(model):
                 return (self._execute_preemptible(
                     pipeline, mesh, spec, int(seed), positive, negative,
@@ -1178,7 +1189,7 @@ class TPUTxt2Img(NodeDef):
                 negative["context"], y, uy,
                 segment_steps=token.segment_steps,
                 should_preempt=token.should_preempt, resume=token.resume,
-                progress_token=ps.traced,
+                on_step=ps.on_step,
             )
             if "checkpoint" in result:
                 # scope exit freezes the progress bar where it stopped
@@ -1187,7 +1198,7 @@ class TPUTxt2Img(NodeDef):
                 raise PreemptedError(result["checkpoint"],
                                      result["reason"])
             images = result["images"]
-            ps.complete(images)
+            ps.complete(images, callbacks=False)
         return images
 
 
@@ -1399,12 +1410,14 @@ class TPUFlowTxt2Img(NodeDef):
                     _ProgressScope(progress_tracker, prompt_id,
                                    total_calls(spec.sampler,
                                                spec.steps)) as ps:
-                images = model.pipeline.generate(
+                # the serving lane: callback-free segment programs, the
+                # stream fed from their outputs (diffusion/progress.py)
+                images = model.pipeline.generate_segmented(
                     mesh, spec, int(seed), ctx, pooled,
-                    progress_token=ps.traced,
                     uncond_context=uncond_ctx,
-                    uncond_pooled=uncond_pooled)
-                ps.complete(images)
+                    uncond_pooled=uncond_pooled, on_step=ps.on_step,
+                    should_stop=_stop_cb(interrupt_event))
+                ps.complete(images, callbacks=False)
         return (images,)
 
 

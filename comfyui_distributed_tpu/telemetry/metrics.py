@@ -50,8 +50,18 @@ PIPELINE_DISPATCH_SECONDS = REGISTRY.histogram(
 
 PROGRESS_CALLBACK_SECONDS = REGISTRY.histogram(
     "cdt_progress_callback_seconds",
-    "Host time inside one progress callback (a denoise call's x0 preview "
-    "handed to every registered sink).")
+    "Host time of one delivery to the progress sinks: one progress "
+    "callback (a denoise call's x0 preview, on a runtime thread), or, on "
+    "the served lanes, the fetch of a finished segment's previews and "
+    "their hand-over, one observation a segment.")
+
+PROGRESS_EVENTS = REGISTRY.counter(
+    "cdt_progress_events_total",
+    "Progress events handed to the sinks (one per reporting chip), by "
+    "what fed the stream: callback (jax.debug.callback inside a compiled "
+    "program) or segment (outputs of a callback-free segment program, "
+    "read by the host at the segment's end).",
+    ("source",))
 
 # --- the prompt rewriter (graph/nodes_builtin.py: TPUPromptRewrite) ----------
 

@@ -174,7 +174,8 @@ class TestEcosystemNodes:
         seen = {}
 
         class FakePipe:
-            def generate(self, mesh, spec, seed, ctx, pooled, **kw):
+            def generate_segmented(self, mesh, spec, seed, ctx, pooled,
+                                   **kw):
                 seen["shift"] = spec.shift
                 return jnp.zeros((1, 4, 4, 3))
 

@@ -224,11 +224,6 @@ def served(tmp_path_factory):
     patch.setenv("CDT_PROFILE_DIR", str(tmp / "profile"))
     patch.setenv("CDT_CACHE_DIR", str(tmp / "content_cache"))
     patch.setenv("CDT_PREEMPT_SEGMENT_STEPS", "2")
-    # every denoiser call reports: left to itself the tracker strides the
-    # second request's events by how long the first one's calls took, and
-    # a first request (compile included) under 3 s halves the counts below
-    from comfyui_distributed_tpu.cluster import progress as progress_mod
-    patch.setattr(progress_mod, "EVENT_PERIOD_S", 0.0)
     config_mod.invalidate_cache()
     was = telemetry.enabled()
     telemetry.set_enabled(True)
@@ -316,8 +311,11 @@ class TestServedRequestTree:
         assert [(n["attrs"]["step"], p)
                 for n, p in parents["segment.boundary"]] == [
                     ("0", sampler), ("2", sampler)]
-        # the callbacks run on runtime threads and still join the tree
-        assert [p for _, p in parents["progress.sink"]] == [sampler] * 4
+        # the served lane carries no callback: one host-side delivery a
+        # segment, on the executor's thread, straight under the node
+        assert [(n["attrs"]["source"], p)
+                for n, p in parents["progress.sink"]] == [
+                    ("segment", sampler)] * 2
         assert "attn_kernels" not in parents["pipeline_call"][0][0]["attrs"]
 
     def test_png_and_write_sit_under_save_image(self, served):
@@ -350,11 +348,17 @@ class TestServedRequestTree:
         waits = _series(served["after"], "cdt_queue_wait_seconds")
         assert sum(s["count"] for s in waits) == 2
         (sink,) = _series(served["after"], "cdt_progress_callback_seconds")
-        assert sink["count"] == 8           # 4 denoise calls a request
+        assert sink["count"] == 4           # 2 segments a request
+        assert sink["sum"] > 0              # progress_ms is never null
         span_counts = _counts(served["after"], "cdt_span_seconds", "name")
         assert span_counts["prompt.queued"] == 2
         assert span_counts["node.CLIPTextEncode"] == 4
-        assert span_counts["progress.sink"] == 8
+        assert span_counts["progress.sink"] == 4
+
+    def test_the_stream_is_fed_by_segments_not_callbacks(self, served):
+        fed = {s["labels"]["source"]: s["value"] for s in _series(
+            served["after"], "cdt_progress_events_total")}
+        assert fed == {"segment": 4}        # one a segment and chip
 
 
 class TestProfileSession:
@@ -377,8 +381,10 @@ class TestProfileSession:
             "cdt.node.CLIPTextEncode", "cdt.node.DistributedSeed",
             "cdt.node.TPUTxt2Img", "cdt.node.DistributedCollector",
             "cdt.node.SaveImage"]
+        # the delivery after each segment, on the launching thread
+        assert main.count("cdt.progress.sink") == 2
         everywhere = [n for line in mirrored for n in line]
-        assert everywhere.count("cdt.progress.sink") == 4
+        assert everywhere.count("cdt.progress.sink") == 2
         # the loop's own spans are not mirrored
         assert not {"cdt.orchestrate", "cdt.prompt.execute",
                     "cdt.prompt.queued"} & set(everywhere)

@@ -460,15 +460,18 @@ def test_progress_routes(tmp_config):
 
 
 @pytest.mark.slow  # builds a real model stack
-def test_flow_pipeline_progress(tracker, tmp_config):
-    """FLUX-path progress: the flow pipeline streams steps too, and its
-    compiled-fn cache keys progress separately."""
+def test_flow_pipeline_progress(tracker, tmp_config, monkeypatch):
+    """FLUX-path progress as serve runs it: the segmented flow lane feeds
+    the tracker host-side from each segment's outputs — no callback, no
+    token — and the callback form of ``generate`` still streams (the
+    node-level cases live in tests/test_segment_progress.py)."""
     from comfyui_distributed_tpu.diffusion.pipeline_flow import (FlowPipeline,
                                                                  FlowSpec)
     from comfyui_distributed_tpu.models.dit import DiTConfig, init_dit
     from comfyui_distributed_tpu.models.vae import AutoencoderKL, VAEConfig
     from comfyui_distributed_tpu.parallel import build_mesh
 
+    monkeypatch.setenv("CDT_PREEMPT_SEGMENT_STEPS", "2")
     cfg = DiTConfig.tiny()
     model, params = init_dit(cfg, jax.random.key(0), sample_hw=(8, 8),
                              context_len=6)
@@ -481,17 +484,21 @@ def test_flow_pipeline_progress(tracker, tmp_config):
     spec = FlowSpec(height=16, width=16, steps=3)
 
     token = tracker.start("flow1", total_calls(spec.sampler, spec.steps))
-    out = pipe.generate(mesh, spec, 0, ctx, pooled, progress_token=token)
-    jax.block_until_ready(out)
-    jax.effects_barrier()
+    out = pipe.generate_segmented(
+        mesh, spec, 0, ctx, pooled,
+        on_step=lambda sigma, x0, calls, shard: tracker.report(
+            token, sigma, x0, shard=shard, calls=calls))
+    jax.block_until_ready(out)          # no effects_barrier: no effects
     snap = tracker.snapshot("flow1")
     assert snap["step"] == 3, snap
     assert snap["shards_reporting"] == 2
-    # cache: same (mesh, spec) with progress off is a separate entry that
-    # still runs
-    out2 = pipe.generate(mesh, spec, 0, ctx, pooled)
-    assert np.asarray(out2).shape == np.asarray(out).shape
-    assert len(pipe._fn_cache) == 2
+    # the callback form keeps its own cache entries and still streams
+    token = tracker.start("flow2", total_calls(spec.sampler, spec.steps))
+    out2 = pipe.generate(mesh, spec, 0, ctx, pooled, progress_token=token)
+    jax.block_until_ready(out2)
+    jax.effects_barrier()
+    assert tracker.snapshot("flow2")["step"] == 3
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
 
 
 @pytest.mark.slow  # builds a real video model stack
