@@ -452,12 +452,13 @@ class StageManager:
         from ..frontdoor.microbatch import _observe_group_shape
 
         _observe_group_shape(lead, len(grp))
-        for p, lat in zip(grp, lats):
-            # the handoff carries the LAZY device array: materialization
-            # happens on the decode worker, overlapped with this pool's
-            # next program (T3-style; docs/stages.md)
-            self.decode.put(_DecodeWork(ticket, p, lat,
-                                        sampler_batch=len(grp)))
+        # the handoff carries the LAZY device arrays: materialization
+        # happens on the decode worker, overlapped with this pool's
+        # next program (T3-style; docs/stages.md). One put for the group:
+        # its latents share a decode program however the threads are
+        # scheduled
+        self.decode.put(*[_DecodeWork(ticket, p, lat, sampler_batch=len(grp))
+                          for p, lat in zip(grp, lats)])
 
     def _solo_member(self, ticket: _GroupTicket, p,
                      batch_size: int = 1) -> None:

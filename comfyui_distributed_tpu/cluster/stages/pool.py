@@ -87,18 +87,24 @@ class StagePool:
 
     # --- producer -----------------------------------------------------------
 
-    def put(self, item) -> None:
+    def put(self, *items) -> None:
+        """Enqueue ``items`` in one step: a worker sees all of them or
+        none. What a producer finishes together (a denoised group's
+        latents) it hands over together: between two separate puts the
+        woken worker can run for a thread switch interval (5 ms, the
+        decode window's length) and take the first item alone."""
         with self._cond:
-            if self.batch_key is None:
-                self._fifo.append(item)
-            else:
+            for item in items:
+                if self.batch_key is None:
+                    self._fifo.append(item)
+                    continue
                 key = self.batch_key(item)
                 bucket = self._buckets.get(key)
                 if bucket is None:
                     bucket = self._buckets[key] = [self._clock(), deque()]
                 bucket[1].append(item)
             self._ensure_threads_locked()
-            self._cond.notify()
+            self._cond.notify(len(items))
         self._export()
 
     def depth(self) -> int:

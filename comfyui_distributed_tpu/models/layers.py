@@ -3,8 +3,9 @@
 Design notes (TPU):
 - compute in ``bfloat16`` (param storage ``float32``): MXU native dtype;
 - GroupNorm in float32 for numerical stability, cast back after;
-- attention uses ``jax.nn.dot_product_attention`` so XLA picks the fused
-  flash-style lowering;
+- attention runs the kernel ``ops/attention.select_kernel`` picks for the
+  site: a Pallas flash tier on the TPU where one wins, else
+  ``jax.nn.dot_product_attention`` (XLA's fused lowering);
 - all shapes static; no python control flow depends on values.
 """
 
@@ -118,10 +119,11 @@ class Attention(nn.Module):
 
     Self-attention sites (no context) are fusable: projection feeds
     attention with nothing in between, so when the kernel dispatcher
-    (``ops/attention.select_kernel`` — tuning table > env > defaults)
-    picks the fused tier, the QKV matmuls fold into the flash grid
-    (``ops/flash_attention.fused_qkv_attention``) and q/k/v never
-    materialize in HBM. Either branch owns the identical param tree."""
+    (``ops/attention.select_kernel`` — a tuning-table row, else the one
+    policy; asked once a site) picks the fused tier, the QKV matmuls fold
+    into the flash grid (``ops/flash_attention.fused_qkv_attention``) and
+    q/k/v never materialize in HBM. Either branch owns the identical
+    param tree."""
 
     num_heads: int
     head_dim: int
@@ -133,29 +135,16 @@ class Attention(nn.Module):
         inner = self.num_heads * self.head_dim
         B, N, C = x.shape
         M = ctx.shape[1]
-        from ..ops.attention import select_kernel
+        from ..ops.attention import full_attention, select_kernel
 
-        choice = select_kernel(int(N), int(M), self.num_heads,
-                               self.head_dim, dtype=self.dtype,
-                               fusable=context is None)
-        use_fused = choice.tier == "fused" and context is None
-        if use_fused:
-            # the table/policy validated fused feasibility assuming
-            # C == H·D (true for every zoo config); this site's REAL
-            # channel width may differ — re-check with it so an
-            # infeasible width degrades to the dense path instead of
-            # raising mid-forward
-            from ..ops.autotune import itemsize_of
-            from ..ops.flash_attention import (_DEFAULT_BLOCK_K,
-                                               _DEFAULT_BLOCK_Q,
-                                               _fused_feasible)
-
-            use_fused = _fused_feasible(
-                int(C), self.num_heads, self.head_dim,
-                choice.block_q or _DEFAULT_BLOCK_Q,
-                choice.block_k or _DEFAULT_BLOCK_K,
-                itemsize_of(self.dtype)) is not None
-        if use_fused:
+        # the site's REAL channel width goes in: a table row validated
+        # fused feasibility assuming C == H·D (true for every zoo
+        # config), and a width that is not feasible gets the dense
+        # branch's kernel instead of raising mid-forward
+        choice = select_kernel(
+            int(N), int(M), self.num_heads, self.head_dim, dtype=self.dtype,
+            fusable_width=int(C) if context is None else None)
+        if choice.tier == "fused":
             from ..ops.flash_attention import fused_qkv_attention
 
             wq = _ProjKernel(inner, name="to_q")(C)
@@ -173,9 +162,7 @@ class Attention(nn.Module):
             q = q.reshape(B, N, self.num_heads, self.head_dim)
             k = k.reshape(B, M, self.num_heads, self.head_dim)
             v = v.reshape(B, M, self.num_heads, self.head_dim)
-            from ..ops.attention import full_attention
-
-            out = full_attention(q, k, v)
+            out = full_attention(q, k, v, choice=choice)
         out = out.reshape(B, N, inner)
         return nn.Dense(x.shape[-1], dtype=self.dtype, name="to_out")(out)
 

@@ -34,52 +34,6 @@ def _pvary(x, axis):
     return jax.lax.pcast(x, axis, to="varying")
 
 
-def _flash_min_seq() -> int:
-    """Below this q length the classic pre-transposed ([B·H,N,D]) flash
-    call LOSES to XLA's fused attention on TPU — measured r04
-    (`scripts/mfu_probe.py forward`, SDXL 1024²: flash-bh 0.1763 s/fwd
-    vs XLA 0.1677, trace shows the boundary relayout, not the kernel
-    body, as the cost): at N ≤ a few K the O(N²) score matrix fits HBM
-    comfortably and XLA fuses softmax into the matmuls. Reached when
-    the packed-heads layout is not legal AND when a packed-legal shape
-    fails the packed floors (the short-K / short-q fall-through below);
-    flash-bh's win is memory at long N (ring/SP sequences, video token
-    counts)."""
-    return constants.FLASH_MIN_SEQ.get()
-
-
-def _flash_enabled(q_len: Optional[int] = None,
-                   kv_len: Optional[int] = None,
-                   num_heads: Optional[int] = None,
-                   head_dim: Optional[int] = None) -> bool:
-    """Pallas flash attention: env-forceable; default = TPU AND the
-    shape is one where flash beats XLA's fused lowering — for the
-    packed-heads layout that is q ≥ 1024 with non-tiny K; for the
-    classic transposed layout q ≥ 8192 (both measured r04, overridable
-    via ``CDT_FLASH_MIN_SEQ[_PACKED]`` / ``CDT_FLASH_MIN_KV_PACKED``)."""
-    flag = constants.FLASH_ATTENTION.get()
-    if flag is not None:
-        return flag
-    from .flash_attention import _layout_packed, _on_tpu
-
-    if not _on_tpu():
-        return False
-    if q_len is None:
-        return True
-
-    if (num_heads is not None and head_dim is not None
-            and _layout_packed(num_heads, head_dim, Nq=q_len, Nk=kv_len)):
-        # _layout_packed is env + legality + the packed seq/KV floors —
-        # the same predicate flash_attention uses for its layout choice,
-        # so gate and kernel can't drift.
-        return True
-    # Packed illegal, or a packed-legal shape failed its floors (e.g.
-    # tiny cross-attn K): the classic bh gate — at very long q the
-    # memory win of the streamed softmax still applies, and
-    # ``flash_attention`` makes the matching layout choice.
-    return q_len >= _flash_min_seq()
-
-
 # --- kernel-tier dispatch ----------------------------------------------------
 # selections made at trace time, remembered for observability: the log
 # line fires once per (geometry, choice), the counter feeds
@@ -159,18 +113,18 @@ def _note_selection(geometry: str, choice,
 def _with_packed_blocks(choice, q_len: int, kv_len: int, head_dim: int,
                         dtype):
     """A packed choice with the blocks its call will run: what the table
-    row or the env knobs requested, the rest derived from the shape
+    row requested, the rest derived from the shape
     (``flash_attention._packed_blocks``) — resolved here so that the
     selection log and counter show them, and handed to the call so that
     it cannot resolve others."""
     if choice.tier != "packed":
         return choice
     from .autotune import itemsize_of
-    from .flash_attention import _packed_blocks, _requested_blocks
+    from .flash_attention import _check_blocks, _packed_blocks
 
-    bq, bk = _packed_blocks(
-        q_len, kv_len, head_dim, itemsize_of(dtype),
-        *_requested_blocks(choice.block_q, choice.block_k))
+    _check_blocks(choice.block_q, choice.block_k)
+    bq, bk = _packed_blocks(q_len, kv_len, head_dim, itemsize_of(dtype),
+                            choice.block_q, choice.block_k)
     return _dataclasses.replace(choice, block_q=bq, block_k=bk)
 
 
@@ -186,32 +140,88 @@ def reset_selections() -> None:
         _SELECTIONS.clear()
 
 
+# Engagement floors of the pallas tiers, measured r04 on the v5e
+# (`scripts/mfu_probe.py`, docs/roofline.md finding 1a). The packed
+# layout beats XLA's fused attention from SDXL's self-attention lengths
+# up (q >= 1024) but not with a tiny K: at SDXL cross-attention (K = 77
+# text tokens in one mostly-padding tile) it measured behind XLA (1.20 vs
+# 1.04 ms/64-op chain). The classic pre-transposed ([B·H,N,D]) call LOSES
+# to XLA at SDXL lengths (flash-bh 0.1763 s/fwd vs XLA 0.1677 at 1024²;
+# the trace shows the boundary relayout, not the kernel body, as the
+# cost): at N <= a few K the O(N²) score matrix fits HBM comfortably and
+# XLA fuses softmax into the matmuls, so its win is memory at long N
+# (ring/SP sequences, video token counts).
+PACKED_MIN_Q = 1024
+PACKED_MIN_KV = 256
+BH_MIN_Q = 8192
+
+
+def policy_choice(q_len: int, kv_len: int, num_heads: int, head_dim: int,
+                  flash_only: bool = False):
+    """The one rule for a geometry no table row decides — what
+    ``select_kernel`` falls back to and what a dry bake of the tuning
+    table writes (``autotune.sweep_geometry``): packed where the layout
+    is legal and both of its floors hold; else the classic ``bh`` call
+    from ``BH_MIN_Q`` up, where the streamed softmax's memory win still
+    applies (packed-illegal widths, or a long q over a tiny K); else XLA.
+    ``flash_only`` takes the XLA outcome away (a caller that was
+    promised flash): ``bh`` at any length. Never ``fused``: that tier is
+    a table row's to give. Blocks are left to the shape
+    (``_with_packed_blocks``) or the classic 256/512."""
+    from .autotune import KernelChoice
+    from .flash_attention import _packed_legal
+
+    if (_packed_legal(num_heads, head_dim) and q_len >= PACKED_MIN_Q
+            and kv_len >= PACKED_MIN_KV):
+        return KernelChoice(
+            "packed", reason="native packed layout (r04 finding 1a), blocks "
+                             "from the shape: K resident where it fits "
+                             "(PR 25)")
+    if flash_only or q_len >= BH_MIN_Q:
+        return KernelChoice(
+            "bh", reason="packed illegal or below its floors: classic "
+                         "call for the streamed softmax's memory win "
+                         "(r04 gate)")
+    return KernelChoice(
+        "xla", reason="below packed floors (r04: XLA fused lowering wins "
+                      "short sequences)")
+
+
 def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
-                  dtype="bfloat16", fusable: bool = False,
+                  dtype="bfloat16", fusable_width: Optional[int] = None,
                   prefer_flash: bool = False):
-    """Resolve the kernel tier + block config for one attention geometry.
+    """Resolve the kernel tier + block config for one attention site. The
+    ONLY code that chooses; nothing downstream decides again. In order:
 
-    Precedence: explicit ``CDT_FLASH_ATTENTION`` > tuning table
-    (``ops/autotune.py`` — the per-geometry swept winner) > env knobs
-    (``CDT_FLASH_LAYOUT``/``CDT_FLASH_BLOCK_Q/K``) > measured-floor
-    defaults (the r04/r05 gates in ``_flash_enabled``). Deterministic:
-    same geometry + same table ⇒ same choice with no env set.
+    1. ``CDT_FLASH_ATTENTION=0`` → ``xla`` (the operator's way out);
+    2. not on a TPU and not forced (``=1``) → ``xla``;
+    3. the tuning table's row for the geometry (``ops/autotune.py``),
+       else the one policy (:func:`policy_choice`);
+    4. a ``fused`` answer where the site cannot run that tier becomes
+       ``packed`` (where legal, else ``bh``) with the row's blocks;
+    5. an ``xla`` answer under ``prefer_flash`` or ``=1`` becomes the
+       policy's flash answer.
 
-    ``fusable=True`` marks a projection→attention site with nothing in
-    between (SDXL UNet self-attention) where the fused QKV tier is
-    executable; elsewhere a table entry saying ``fused`` downgrades to
-    the packed tier with the same blocks — same layout family, q/k/v
-    just arrive pre-projected. ``prefer_flash`` (memory-constrained
-    callers, see ``full_attention``) keeps its guarantee ahead of the
-    table: a table entry saying ``xla`` is ignored there, because the
-    sweep optimized for time while the caller needs the streamed
-    softmax to fit HBM at all.
+    Packed blocks are resolved here, once, from the row and the shape, so
+    the log, the counter and the call agree. Deterministic: same site +
+    same table ⇒ same choice.
+
+    ``fusable_width`` is the channel width C of a projection→attention
+    site with nothing in between (SDXL UNet self-attention), where the
+    fused QKV tier is executable if C passes the tier's VMEM model;
+    ``None`` where q/k/v arrive projected — same layout family as
+    packed. ``prefer_flash`` (memory-constrained callers, see
+    ``full_attention``) outranks a table ``xla`` row: the sweep optimized
+    for time while the caller needs the streamed softmax to fit HBM at
+    all.
 
     Mesh-aware: inside a :func:`tp_shard_scope` the head count is
     divided by the tp degree BEFORE key derivation — the per-shard
     geometry (H/tp heads) is what actually executes, and a full-H table
     entry can carry blocks that are illegal (or slow) at H/tp."""
-    from .autotune import KernelChoice, GeometryKey, lookup
+    from .autotune import GeometryKey, KernelChoice, itemsize_of, lookup
+    from .flash_attention import (_fused_feasible, _on_tpu, _packed_legal,
+                                  resolve_flash_blocks)
 
     # ONE definition of the per-shard rule (GeometryKey.shard): sweeps,
     # table keys and this dispatch must never disagree about it
@@ -226,83 +236,64 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
         _note_selection(geometry, choice)
         return choice
     forced = flag is True
-    from .flash_attention import _on_tpu
-
     if not forced and not _on_tpu():
         # off-accelerator serving always takes XLA (interpret-mode pallas
         # is a test vehicle, not a CPU fallback); not recorded — CPU
         # hosts would flood the selection log with xla lines
         return KernelChoice("xla", reason="not on TPU")
 
-    tuned = lookup(num_heads, head_dim, q_len, kv_len, dtype)
-    # a table "xla" entry yields to BOTH explicit force (=1 promised
-    # flash) and prefer_flash (the sweep optimized for time; the caller
-    # needs the streamed softmax to fit HBM at all)
-    if tuned is not None and not ((forced or prefer_flash)
-                                  and tuned.tier == "xla"):
-        choice = tuned
-        if choice.tier == "fused" and not fusable:
-            from .flash_attention import _packed_legal
-
+    choice = (lookup(num_heads, head_dim, q_len, kv_len, dtype)
+              or policy_choice(q_len, kv_len, num_heads, head_dim))
+    if choice.tier == "fused":
+        blocks = None
+        if fusable_width is not None:
+            blocks = _fused_feasible(
+                fusable_width, num_heads, head_dim,
+                *resolve_flash_blocks(choice.block_q, choice.block_k),
+                itemsize_of(dtype))
+        if blocks is not None:
+            choice = _dataclasses.replace(choice, block_q=blocks[0],
+                                          block_k=blocks[1])
+        else:
             choice = KernelChoice(
                 "packed" if _packed_legal(num_heads, head_dim) else "bh",
-                choice.block_q, choice.block_k, source="table",
-                reason="fused choice at a non-fusable site")
-        choice = _with_packed_blocks(choice, q_len, kv_len, head_dim, dtype)
-        _note_selection(geometry, choice, kv_len)
-        return choice
-
-    # env knobs + measured-floor defaults (the pre-table behavior)
-    from .flash_attention import _layout_packed
-
-    if forced or prefer_flash:
-        use_flash = True
-        why = ("CDT_FLASH_ATTENTION=1" if forced
-               else "prefer_flash (memory-constrained caller)")
-    else:
-        use_flash = _flash_enabled(q_len=q_len, kv_len=kv_len,
-                                   num_heads=num_heads, head_dim=head_dim)
-        why = "measured r04 shape gates"
-    if not use_flash:
-        choice = KernelChoice("xla", reason=why)
-    elif _layout_packed(num_heads, head_dim, Nq=q_len, Nk=kv_len):
-        # the same legality + measured-floors + CDT_FLASH_LAYOUT
-        # predicate flash_attention's auto layout used, so forced-flash
-        # keeps its historical layout choices
-        choice = KernelChoice("packed", reason=why)
-    else:
-        choice = KernelChoice("bh", reason=why)
+                choice.block_q, choice.block_k, source=choice.source,
+                reason="fused choice at a site that cannot fuse")
+    elif choice.tier == "xla" and (forced or prefer_flash):
+        choice = _dataclasses.replace(
+            policy_choice(q_len, kv_len, num_heads, head_dim,
+                          flash_only=True),
+            reason="CDT_FLASH_ATTENTION=1" if forced
+            else "prefer_flash (memory-constrained caller)")
     choice = _with_packed_blocks(choice, q_len, kv_len, head_dim, dtype)
     _note_selection(geometry, choice, kv_len)
     return choice
 
 
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                   prefer_flash: bool = False) -> jax.Array:
-    """Dense [B,N,H,D] attention dispatched per geometry: the tuning
-    table's swept winner where one exists (``select_kernel`` — table >
-    env knobs > measured defaults), the r04 shape gates otherwise, XLA
-    off-TPU.
+                   prefer_flash: bool = False, choice=None) -> jax.Array:
+    """Dense [B,N,H,D] attention by the kernel ``select_kernel`` picks
+    for the geometry (a tuning-table row, else the one policy; XLA
+    off-TPU). A caller that has already asked (``models/layers.py``)
+    hands its ``choice`` in and is not asked about again.
 
-    ``prefer_flash=True`` skips the shape gates AND table ``xla``
-    entries (still TPU-only, still overridable by an explicit
+    ``prefer_flash=True`` outranks the policy's floors AND table ``xla``
+    rows (still TPU-only, still overridable by an explicit
     ``CDT_FLASH_ATTENTION``): set by memory-constrained callers — the
     fp8-resident offload executor's block programs OOM'd at compile with
     XLA attention (measured r04: 16.89 GB needed vs 15.75 HBM at FLUX's
     4608 tokens × 24 heads with 12 GB of weights resident) while flash's
     streamed softmax fits."""
-    B, Nq, H, D = q.shape
-    choice = select_kernel(int(Nq), int(k.shape[1]), int(H), int(D),
-                           dtype=q.dtype, prefer_flash=prefer_flash)
+    if choice is None:
+        B, Nq, H, D = q.shape
+        choice = select_kernel(int(Nq), int(k.shape[1]), int(H), int(D),
+                               dtype=q.dtype, prefer_flash=prefer_flash)
     if choice.tier == "xla":
         return jax.nn.dot_product_attention(q, k, v)
     from .flash_attention import flash_attention
 
-    # a "fused" table entry reaching this pre-projected site runs the
-    # same packed layout family (select_kernel already downgraded it)
-    layout = "packed" if choice.tier == "packed" else "bh"
     return flash_attention(q, k, v, block_q=choice.block_q,
-                           block_k=choice.block_k, layout=layout)
+                           block_k=choice.block_k, layout=choice.tier)
 
 
 def _flash_block(q, k, v, m, l, acc, scale):

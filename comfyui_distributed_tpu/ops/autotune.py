@@ -2,13 +2,12 @@
 
 ``docs/roofline.md`` ended r05 with every workload pinned to a measured
 attention config — but the measurements lived in a human's shell
-history as ``CDT_FLASH_BLOCK_Q/K`` experiments. This module makes them
-an artifact: the first time a (heads, head_dim, N, dtype) geometry is
-met, a sweep walks the legal kernel tiers and block sizes, and the
-winner persists to a tuning table consulted by ``ops/attention.py``'s
-dispatcher ahead of the env knobs — so every new model generation lands
-on its best kernel config without code edits, and a fleet shares one
-table the way it shares one XLA cache.
+history. This module makes them an artifact: the first time a (heads,
+head_dim, N, dtype) geometry is met, a sweep walks the legal kernel
+tiers and block sizes, and the winner persists to a tuning table that
+``ops/attention.select_kernel`` consults ahead of its one policy — so
+every new model generation lands on its best kernel config without code
+edits, and a fleet shares one table the way it shares one XLA cache.
 
 Layout of the decision data:
 
@@ -32,14 +31,13 @@ Sweeps run OFF the request path: ``diffusion/warmup.py`` tunes every
 catalog geometry during the worker's AOT pass (the worker reports
 ``warming`` until its geometries are tuned), and the CLI pre-bakes
 fleet images. On hardware the sweep times real candidates; off
-hardware (``mode="dry"``) it resolves the same deterministic
-legality-ranked policy the shipped table was baked with — same
-geometry + same table ⇒ same choice, always.
+hardware (``mode="dry"``) it writes what the dispatcher's one policy
+(``ops/attention.policy_choice``) answers — same geometry + same table
+⇒ same choice, always.
 
 Knobs: ``CDT_ATTN_TABLE`` (local overlay path; default
 beside the XLA cache: ``<cache dir>/attn_tuning.json``), ``CDT_ATTN_TUNE=0``
-disables table lookups AND sweeps (env knobs and measured defaults
-rule, the pre-tuning-table behavior).
+disables table lookups AND sweeps (the policy alone rules).
 """
 
 from __future__ import annotations
@@ -81,8 +79,8 @@ def dtype_name(dtype) -> str:
 
 def itemsize_of(dtype) -> int:
     """Operand byte width for the VMEM working-set model. One
-    definition — the dispatcher, the validator and the policy all key
-    legality on it, and a drift between them would approve blocks the
+    definition — the dispatcher and the validator both key legality
+    on it, and a drift between them would approve blocks the
     kernel can't fit."""
     return 4 if dtype_name(dtype) == "f32" else 2
 
@@ -154,7 +152,7 @@ class KernelChoice:
     tier: str
     block_q: Optional[int] = None      # None: tier has no blocks (xla),
     block_k: Optional[int] = None      # or packed derives them (shape)
-    source: str = "default"            # default | env | table | sweep
+    source: str = "default"            # default (policy)|env|table|sweep
     reason: str = ""
 
     def __post_init__(self):
@@ -196,13 +194,15 @@ def validate_entry(key: GeometryKey, choice: KernelChoice) -> list[str]:
         if choice.block_q is not None or choice.block_k is not None:
             errors.append("xla tier takes no block sizes")
         return errors
-    packed = choice.tier == "packed"       # its unset blocks stay unset
+    bq, bk = choice.block_q, choice.block_k
     try:
-        bq, bk = (fa._requested_blocks if packed else
-                  fa.resolve_flash_blocks)(choice.block_q, choice.block_k)
+        if choice.tier == "packed":        # its unset blocks stay unset
+            fa._check_blocks(bq, bk)
+        else:
+            bq, bk = fa.resolve_flash_blocks(bq, bk)
     except ValueError as e:
         return [str(e)]
-    if packed:
+    if choice.tier == "packed":
         if not fa._packed_legal(H, D):
             errors.append(
                 f"packed tier illegal at H={H}, D={D} ({key.dtype})")
@@ -379,26 +379,21 @@ def lookup(num_heads: int, head_dim: int, q_len: int, kv_len: int,
 BLOCK_Q_CANDIDATES = (128, 256, 512)
 BLOCK_K_CANDIDATES = (128, 256, 512)     # fused: flash_attention._MAX_BLOCK_K
 
-# engagement floors measured r04 (docs/roofline.md finding 1a): below
-# them XLA's fused lowering wins and the sweep doesn't bother timing
-# pallas tiers — they'd be legal but pointless
-_PACKED_MIN_Q = 1024
-_PACKED_MIN_KV = 256
-_BH_MIN_Q = 8192
-
-
 def candidates_for(key: GeometryKey) -> list[KernelChoice]:
     """Deterministic candidate list for one geometry: every legal
     (tier, block_q, block_k) worth timing, xla always last (the
     baseline). Order is fixed so timed ties and dry-mode policy picks
     are reproducible."""
     from . import flash_attention as fa
+    from .attention import BH_MIN_Q, PACKED_MIN_KV, PACKED_MIN_Q
 
     itemsize = itemsize_of(key.dtype)
     H, D = key.num_heads, key.head_dim
     out: list[KernelChoice] = []
-    long_enough = (key.q_bucket >= _PACKED_MIN_Q
-                   and key.kv_bucket >= _PACKED_MIN_KV)
+    # below the policy's floors XLA's fused lowering wins and the sweep
+    # doesn't bother timing pallas tiers — they'd be legal but pointless
+    long_enough = (key.q_bucket >= PACKED_MIN_Q
+                   and key.kv_bucket >= PACKED_MIN_KV)
     # fused is self-attention only (q and k/v project from the SAME x);
     # cross geometries never get fused candidates — no fusable site can
     # present them, and timing one would race an Nq×Nq problem against
@@ -418,49 +413,11 @@ def candidates_for(key: GeometryKey) -> list[KernelChoice]:
         out.append(KernelChoice("packed", source="sweep"))
         out.extend(KernelChoice("packed", bq, source="sweep")
                    for bq in BLOCK_Q_CANDIDATES)
-    if key.q_bucket >= _BH_MIN_Q or long_enough:
+    if key.q_bucket >= BH_MIN_Q or long_enough:
         for bq, bk in ((256, 512), (256, 1024), (512, 512)):
             out.append(KernelChoice("bh", bq, bk, source="sweep"))
     out.append(KernelChoice("xla", source="sweep"))
     return out
-
-
-def resolve_policy_choice(key: GeometryKey) -> KernelChoice:
-    """Deterministic no-timing resolution — what ``mode=\"dry\"`` sweeps
-    and the shipped-table bake use. Encodes the r04/r05 measurements as
-    a ranking instead of a stopwatch: fused where it fits with real
-    tiles (boundary cost beats the K/V-projection recompute only when
-    the working set isn't starved), else packed (blocks left to the
-    shape), else the classic bh call at long-N, else xla. A timed sweep
-    on hardware overrides all of this."""
-    from . import flash_attention as fa
-
-    itemsize = itemsize_of(key.dtype)
-    H, D = key.num_heads, key.head_dim
-    if key.q_bucket < _PACKED_MIN_Q or key.kv_bucket < _PACKED_MIN_KV:
-        if key.q_bucket >= _BH_MIN_Q:
-            return KernelChoice("bh", fa._DEFAULT_BLOCK_Q,
-                                fa._DEFAULT_BLOCK_K, source="sweep",
-                                reason="long q, short kv: streamed "
-                                       "softmax memory win (r04 gate)")
-        return KernelChoice("xla", source="sweep",
-                            reason="below packed floors (r04: XLA fused "
-                                   "lowering wins short sequences)")
-    fused = (fa._fused_feasible(H * D, H, D, itemsize=itemsize)
-             if key.q_bucket == key.kv_bucket else None)  # self-attn only
-    if fused is not None and fused[0] >= 128 and fused[1] >= 256:
-        return KernelChoice("fused", fused[0], fused[1], source="sweep",
-                            reason="fused feasible with non-starved "
-                                   "tiles: boundary cost > projection "
-                                   "recompute")
-    if fa._packed_legal(H, D):
-        return KernelChoice("packed", source="sweep",
-                            reason="native packed layout (r04 finding "
-                                   "1a), blocks from the shape: K resident "
-                                   "where it fits (PR 25)")
-    return KernelChoice("bh", fa._DEFAULT_BLOCK_Q, fa._DEFAULT_BLOCK_K,
-                        source="sweep",
-                        reason="packed geometrically illegal")
 
 
 def _time_candidate(key: GeometryKey, choice: KernelChoice,
@@ -551,11 +508,12 @@ def sweep_geometry(key: GeometryKey, mode: str = "auto",
     """Resolve the best kernel config for one geometry.
 
     ``mode="timed"`` measures every candidate on the live backend (TPU);
-    ``mode="dry"`` resolves the deterministic policy (CPU-safe, what the
-    shipped table was baked with); ``mode="auto"`` picks timed on TPU,
-    dry elsewhere. Per-geometry failures are recorded, never raised; a
+    ``mode="dry"`` writes what the dispatcher's policy answers at the
+    bucket's lengths (CPU-safe); ``mode="auto"`` picks timed on TPU, dry
+    elsewhere. Per-geometry failures are recorded, never raised; a
     candidate that fails is logged with the compiler's message and kept
     in ``SweepEntry.refused``."""
+    from .attention import policy_choice
     from .flash_attention import _on_tpu
 
     if mode == "auto":
@@ -563,7 +521,8 @@ def sweep_geometry(key: GeometryKey, mode: str = "auto",
     t0 = time.perf_counter()
     try:
         if mode == "dry":
-            choice = resolve_policy_choice(key)
+            choice = policy_choice(key.q_bucket, key.kv_bucket,
+                                   key.num_heads, key.head_dim)
             return SweepEntry(key, choice, "dry",
                               time.perf_counter() - t0)
         timings = []

@@ -55,8 +55,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils import constants as _constants
-
 # lane width: scratch vectors m/l are stored lane-replicated (BQ, 128)
 _LANES = 128
 _SUBLANES = 8        # f32 sublane tile height — block_q granularity
@@ -64,25 +62,6 @@ NEG_INF = -1e30      # large-but-finite: -inf breaks max on fully-masked rows
 
 _DEFAULT_BLOCK_Q = 256   # measured r04 at SDXL shapes (docs/roofline.md)
 _DEFAULT_BLOCK_K = 512
-
-
-def _parse_block_env(name: str, multiple: int) -> Optional[int]:
-    """Parse one ``CDT_FLASH_BLOCK_*`` knob, rejecting values pallas
-    would only reject deep in Mosaic lowering (or worse, mis-tile): the
-    block size must be a positive multiple of the hardware tile for its
-    axis (``block_q``: 8 sublanes, ``block_k``: 128 lanes). Unset/empty
-    returns None (caller applies the default)."""
-    raw = _constants.knob(name).raw()
-    if raw is None or raw.strip() == "":
-        return None
-    try:
-        value = int(raw)
-    except (TypeError, ValueError):
-        raise ValueError(
-            f"{name}={raw!r} is not an integer: flash block sizes must be "
-            f"positive multiples of {multiple}") from None
-    _check_block(name, value, multiple)
-    return value
 
 
 def _check_block(name: str, value: int, multiple: int) -> None:
@@ -94,32 +73,22 @@ def _check_block(name: str, value: int, multiple: int) -> None:
             "pallas would fail during Mosaic lowering otherwise")
 
 
-def _requested_blocks(block_q: Optional[int] = None,
-                      block_k: Optional[int] = None
-                      ) -> tuple[Optional[int], Optional[int]]:
-    """What the caller or the operator ASKED for: explicit args win, then
-    the ``CDT_FLASH_BLOCK_Q``/``CDT_FLASH_BLOCK_K`` env knobs; None where
-    neither spoke (each tier then applies its own default). Both sources
-    are validated here — a non-positive or non-(8,128)-divisible value
-    raises a descriptive ``ValueError`` instead of letting pallas fail
-    deep in lowering (tuning-table entries pass through the same check
-    via ``ops/autotune.py``)."""
-    if block_q is None:
-        block_q = _parse_block_env("CDT_FLASH_BLOCK_Q", _SUBLANES)
-    else:
+def _check_blocks(block_q: Optional[int], block_k: Optional[int]) -> None:
+    """Validate requested blocks (an argument, a tuning-table row; None
+    = not requested, the tier applies its own): a non-positive or
+    non-(8,128)-divisible value raises a descriptive ``ValueError``
+    instead of letting pallas fail deep in lowering."""
+    if block_q is not None:
         _check_block("block_q", block_q, _SUBLANES)
-    if block_k is None:
-        block_k = _parse_block_env("CDT_FLASH_BLOCK_K", _LANES)
-    else:
+    if block_k is not None:
         _check_block("block_k", block_k, _LANES)
-    return block_q, block_k
 
 
 def resolve_flash_blocks(block_q: Optional[int] = None,
                          block_k: Optional[int] = None) -> tuple[int, int]:
-    """``_requested_blocks`` with the classic/fused tiers' measured
+    """Checked blocks of the classic and fused tiers: the measured
     defaults (256/512, r04) where nothing was requested."""
-    block_q, block_k = _requested_blocks(block_q, block_k)
+    _check_blocks(block_q, block_k)
     return (_DEFAULT_BLOCK_Q if block_q is None else block_q,
             _DEFAULT_BLOCK_K if block_k is None else block_k)
 
@@ -727,9 +696,9 @@ def _packed_blocks(q_len: int, kv_len: int, head_dim: int, itemsize: int = 2,
                    block_q: Optional[int] = None,
                    block_k: Optional[int] = None) -> tuple[int, int]:
     """(block_q, block_k) of the packed call. Requested blocks (an
-    argument, `CDT_FLASH_BLOCK_Q/K`, a tuning-table row) keep their
-    meaning — rows of one q tile and of one K/V tile — and win; what is
-    not requested comes from the shape:
+    argument, a tuning-table row) keep their meaning — rows of one q
+    tile and of one K/V tile — and win; what is not requested comes from
+    the shape:
 
     - ``block_k``: the whole sequence padded to 128 when the VMEM model
       fits it (K/V resident, one K step), else the fewest equal
@@ -813,22 +782,6 @@ def _shrink_blocks_for_vmem(bytes_fn, block_q: int, block_k: int
     return bq, bk
 
 
-def _flash_min_seq_packed() -> int:
-    """Engagement floor for the packed-heads layout: measured r04 it
-    beats XLA already at SDXL self-attention lengths (docs/roofline.md
-    finding 1a) but not below ~1024 tokens."""
-    return _constants.FLASH_MIN_SEQ_PACKED.get()
-
-
-def _flash_min_kv_packed() -> int:
-    """Short-K floor for the packed kernel: at SDXL cross-attention
-    (K = 77 text tokens padded to one 512 block) the kernel wastes most
-    of its K tile and measures behind XLA (1.20 vs 1.04 ms/64-op chain,
-    r04) — those sites stay on XLA's fused lowering / the classic bh
-    call."""
-    return _constants.FLASH_MIN_KV_PACKED.get()
-
-
 def _packed_legal(H: int, D: int) -> bool:
     """Pure geometric legality of the packed-heads layout: whole heads
     fill whole 128-lane groups. D % 64 confines the layout to the tested
@@ -836,33 +789,6 @@ def _packed_legal(H: int, D: int) -> bool:
     group — a shape class never measured. No width ceiling: a tile is one
     group wide whatever H·D is (FLUX's 3072 is twenty-four groups)."""
     return (H * D) % _LANES == 0 and D % 64 == 0
-
-
-def _layout_packed(H: int, D: int,
-                   Nq: Optional[int] = None,
-                   Nk: Optional[int] = None) -> bool:
-    """Kernel I/O layout: ``packed`` (default where legal AND the
-    measured engagement floors hold) keeps q/k/v in the model's natural
-    [B, N, H·D] layout and walks head groups on the grid; ``bh`` is the
-    classic pre-transposed [B·H, N, D] call.
-
-    ``CDT_FLASH_LAYOUT=bh`` restores the classic call everywhere;
-    ``CDT_FLASH_LAYOUT=packed`` is the default (packed where legal and
-    the floors hold — both env states behave identically, preserving
-    the historical meaning of an exported ``packed``). An explicit
-    per-call layout override is ``flash_attention(..., layout=...)``.
-    Without ``Nq``/``Nk`` (the shape-gate site, which applies its own
-    thresholds) only legality and the env override are checked."""
-    env = _constants.FLASH_LAYOUT.get()
-    if env == "bh":
-        return False
-    if not _packed_legal(H, D):
-        return False
-    # The packed call must also clear its measured floors, so a
-    # user-raised CDT_FLASH_MIN_SEQ_PACKED/KV floor is never bypassed by
-    # the shape gate's classic fall-through (r04 review finding).
-    return ((Nq is None or Nq >= _flash_min_seq_packed())
-            and (Nk is None or Nk >= _flash_min_kv_packed()))
 
 
 def flash_attention(
@@ -878,38 +804,33 @@ def flash_attention(
     positively ``cpu``: there the Pallas interpreter runs the same kernel
     code (the CPU tests).
 
-    ``block_q``/``block_k=None`` resolve to ``CDT_FLASH_BLOCK_Q``/
-    ``CDT_FLASH_BLOCK_K``, then per layout: the packed call derives what
-    is still unset from the shape (``_packed_blocks`` — K resident where
-    it fits), the classic call takes 256/512 (measured r04). Both the env
-    knobs and explicit arguments are validated at parse time
-    (``_requested_blocks``): non-positive or non-(8,128)-divisible values
-    raise a descriptive error instead of failing in lowering.
+    ``block_q``/``block_k`` are checked (``_check_blocks``:
+    non-positive or non-(8,128)-divisible values raise a descriptive
+    error instead of failing in lowering); where ``None``, the packed
+    call derives them from the shape (``_packed_blocks`` — K resident
+    where it fits) and the classic call takes 256/512 (measured r04).
 
-    ``layout`` forces the kernel I/O layout for this call: ``"packed"``
-    (where geometrically legal; illegal geometries still fall back to
-    the classic call) or ``"bh"``; ``None`` auto-selects per
-    ``_layout_packed`` (legality + measured floors +
-    ``CDT_FLASH_LAYOUT``). Used by the tuning table
-    (``ops/autotune.py``), layout-equivalence tests and power users; the
-    env var remains the global knob.
+    ``layout`` is the kernel I/O layout: ``"bh"`` the classic
+    pre-transposed call; ``"packed"`` or ``None`` the packed call where
+    the geometry is legal for it (``_packed_legal``), else the classic
+    one. Nothing is decided here beyond legality: whether a site runs
+    flash at all, and in which layout, is ``ops/attention.select_kernel``'s
+    choice, which arrives as these arguments.
     """
     if interpret is None:
         interpret = _platform() == "cpu"
-    block_q, block_k = _requested_blocks(block_q, block_k)
     B, Nq, H, D = q.shape
     _, Nk, _, _ = k.shape
-    if layout == "packed":
-        packed = _packed_legal(H, D)               # explicit beats env + floors
+    if layout in (None, "packed"):
+        packed = _packed_legal(H, D)
     elif layout == "bh":
         packed = False
-    elif layout is None:
-        packed = _layout_packed(H, D, Nq=Nq, Nk=Nk)
     else:
         raise ValueError(
             f"layout must be 'packed', 'bh', or None, got {layout!r}")
     emulated = interpret and _in_manual_trace(q)
     if packed and not emulated:
+        _check_blocks(block_q, block_k)
         bq, bk = _packed_blocks(Nq, Nk, D, jnp.dtype(q.dtype).itemsize,
                                 block_q, block_k)
         out = _flash_mha_packed(
@@ -1055,8 +976,8 @@ def fused_qkv_attention(
     Serves projection→attention sites with nothing in between (SDXL
     UNet self-attention); sites that qk-norm/RoPE between projection and
     attention (FLUX, WAN) cannot fuse and take the packed tier instead.
-    ``interpret=None`` auto-selects like ``flash_attention``; blocks
-    resolve via the same validated env knobs. On hardware, infeasible
+    ``interpret=None`` auto-selects like ``flash_attention``; blocks are
+    checked the same way and default to 256/512. On hardware, infeasible
     geometries (the VMEM model — weights resident) raise; in interpret
     mode the requested blocks run regardless, keeping every geometry
     CPU-testable."""

@@ -14,9 +14,8 @@ Design (docs/lint.md, rule K001):
   never via raw ``os.environ`` — cdtlint rule K001 machine-checks this.
 - Parsing is once-per-value (cached against the raw string, so a
   monkeypatched env var re-parses) with validation: garbage raises a
-  descriptive :class:`KnobError` at the first read (the
-  ``resolve_flash_blocks`` precedent from PR 5) instead of letting a typo'd
-  knob silently fall back or crash something deep. The few hot-loop gate
+  descriptive :class:`KnobError` at the first read instead of letting a
+  typo'd knob silently fall back or crash something deep. The few hot-loop gate
   knobs whose warn-and-default behavior is a tested contract opt out via
   ``on_garbage="default"``.
 - Import-time module constants (``HEARTBEAT_INTERVAL`` et al.) are kept for
@@ -88,12 +87,6 @@ class Knob:
         self._cached_value = None
 
     # -- reads ---------------------------------------------------------
-
-    def raw(self) -> Optional[str]:
-        """The raw env string (None when unset). Escape hatch for sites
-        with bespoke parsing/validation (``resolve_flash_blocks``) —
-        still counts as a registry read for lint rule K001."""
-        return os.environ.get(self.name)
 
     def is_set(self) -> bool:
         return os.environ.get(self.name) is not None
@@ -827,35 +820,11 @@ WARMUP_MODELS = knob_str(
 # --- attention kernels / autotuner (PR 5, docs/kernels.md) ------------------
 FLASH_ATTENTION = knob_optbool(
     "CDT_FLASH_ATTENTION", "kernels",
-    "Force the flash path on (1) or off (0); unset = table/heuristics.",
+    "Force the flash path on (1) or off (0); unset = table row, else the "
+    "one policy.",
     doc="docs/kernels.md", on_garbage="default")
-FLASH_LAYOUT = knob_enum(
-    "CDT_FLASH_LAYOUT", "", ("", "bh", "packed"), "kernels",
-    "Force the flash kernel layout ('bh' classic per-head, 'packed' "
-    "head-packed).", doc="docs/kernels.md", keep_empty=True,
-    on_garbage="default")
-FLASH_BLOCK_Q = knob_int(
-    "CDT_FLASH_BLOCK_Q", None, "kernels",
-    "Flash q-axis block size (positive multiple of 8; validated by "
-    "resolve_flash_blocks).", doc="docs/kernels.md")
-FLASH_BLOCK_K = knob_int(
-    "CDT_FLASH_BLOCK_K", None, "kernels",
-    "Flash k-axis block size (positive multiple of 128).",
-    doc="docs/kernels.md")
-# Hot-loop gate knobs: warn-and-default on garbage is a TESTED contract
+# Hot-loop gate knob: warn-and-default on garbage is a TESTED contract
 # (an env typo must not crash the attention dispatch mid-job).
-FLASH_MIN_SEQ = knob_int(
-    "CDT_FLASH_MIN_SEQ", 8192, "kernels",
-    "Min q-length before the classic flash tier engages.",
-    doc="docs/kernels.md", on_garbage="default")
-FLASH_MIN_SEQ_PACKED = knob_int(
-    "CDT_FLASH_MIN_SEQ_PACKED", 1024, "kernels",
-    "Min q-length before the packed tier engages.",
-    doc="docs/kernels.md", on_garbage="default")
-FLASH_MIN_KV_PACKED = knob_int(
-    "CDT_FLASH_MIN_KV_PACKED", 256, "kernels",
-    "Min kv-length before the packed tier engages.",
-    doc="docs/kernels.md", on_garbage="default")
 RING_BLOCK = knob_int(
     "CDT_RING_BLOCK", 1024, "kernels",
     "Ring-attention block size for the sp axis.",

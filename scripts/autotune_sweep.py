@@ -10,8 +10,10 @@
 
 Default geometry set is the known model zoo
 (``autotune.model_zoo_geometries``: SDXL self/cross, FLUX joint, WAN
-self/cross). ``--dry-run`` resolves the deterministic legality-ranked
-policy and works anywhere (interpret-mode legality only — no timing);
+self/cross). ``--dry-run`` writes what the dispatcher's one policy
+(``ops/attention.policy_choice``) answers and works anywhere (no timing;
+it never answers ``fused``, so ``--dry-run --bake`` would overwrite the
+shipped table's one hand-kept row);
 without it the sweep times every candidate on the live backend and
 belongs on the TPU host. Every resolved entry is validated
 (``autotune.validate_entry``) before writing; exit 1 on any error so a

@@ -146,7 +146,7 @@ def exp_forward(flash: str | None = None) -> None:
                if flops else None}
         print(json.dumps(rec), flush=True)
         results.append(rec)
-        # new trace next loop: clear the jit cache so _flash_enabled
+        # new trace next loop: clear the jit cache so select_kernel
         # re-evaluates
         fwd._clear_cache()
 

@@ -146,8 +146,7 @@ def test_sdxl_self_attention_inside_its_block(chip, level, monkeypatch):
     from comfyui_distributed_tpu.models.layers import TransformerBlock
 
     C, heads, n, tier = level
-    for var in ("CDT_FLASH_ATTENTION", "CDT_FLASH_LAYOUT", "CDT_ATTN_TUNE",
-                "CDT_FLASH_BLOCK_Q", "CDT_FLASH_BLOCK_K"):
+    for var in ("CDT_FLASH_ATTENTION", "CDT_ATTN_TUNE"):
         monkeypatch.delenv(var, raising=False)
     # the one place the kernels ask where they are (ops/flash_attention):
     # steered here, in the test, since jax.devices() still says cpu

@@ -28,58 +28,62 @@ def rand_qkv(key, B=1, Nq=128, Nk=128, H=2, D=64, dtype=jnp.float32):
     return q, k, v
 
 
+@pytest.mark.parametrize("layout", ["bh", "packed"])
 class TestNumerics:
-    def test_block_aligned(self):
+    """Both kernel layouts against the dense reference (``packed`` at a
+    packed-illegal geometry is the classic call)."""
+
+    def test_block_aligned(self, layout):
         q, k, v = rand_qkv(jax.random.key(0), Nq=256, Nk=256)
-        out = flash_attention(q, k, v, interpret=True)
+        out = flash_attention(q, k, v, interpret=True, layout=layout)
         ref = dense_reference(q, k, v)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    def test_ragged_lengths_masked(self):
+    def test_ragged_lengths_masked(self, layout):
         """Nq/Nk not multiples of the block sizes → padding is masked out."""
         q, k, v = rand_qkv(jax.random.key(1), Nq=100, Nk=77)
-        out = flash_attention(q, k, v, interpret=True)
+        out = flash_attention(q, k, v, interpret=True, layout=layout)
         ref = dense_reference(q, k, v)
         assert out.shape == (1, 100, 2, 64)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    def test_multi_kv_blocks_accumulate(self):
+    def test_multi_kv_blocks_accumulate(self, layout):
         """Nk spanning several K blocks exercises the streaming-softmax
         carry (running max / denominator / accumulator rescale)."""
         q, k, v = rand_qkv(jax.random.key(2), Nq=128, Nk=512)
-        out = flash_attention(q, k, v, block_k=128, interpret=True)
+        out = flash_attention(q, k, v, block_k=128, interpret=True, layout=layout)
         ref = dense_reference(q, k, v)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    def test_bf16_inputs(self):
+    def test_bf16_inputs(self, layout):
         q, k, v = rand_qkv(jax.random.key(3), Nq=128, Nk=256,
                            dtype=jnp.bfloat16)
-        out = flash_attention(q, k, v, interpret=True)
+        out = flash_attention(q, k, v, interpret=True, layout=layout)
         ref = dense_reference(q, k, v)
         assert out.dtype == jnp.bfloat16
         np.testing.assert_allclose(out.astype(np.float32), ref,
                                    atol=2e-2, rtol=2e-2)
 
-    def test_extreme_logits_stable(self):
+    def test_extreme_logits_stable(self, layout):
         """Large-magnitude logits must not overflow exp (running-max
         subtraction)."""
         q, k, v = rand_qkv(jax.random.key(4), Nq=128, Nk=256)
         q = q * 30.0
-        out = flash_attention(q, k, v, interpret=True)
+        out = flash_attention(q, k, v, interpret=True, layout=layout)
         ref = dense_reference(q, k, v)
         assert np.isfinite(np.asarray(out)).all()
         np.testing.assert_allclose(out, ref, atol=2e-4, rtol=2e-4)
 
-    def test_batch_and_heads(self):
+    def test_batch_and_heads(self, layout):
         q, k, v = rand_qkv(jax.random.key(5), B=2, Nq=64, Nk=64, H=4, D=32)
-        out = flash_attention(q, k, v, interpret=True)
+        out = flash_attention(q, k, v, interpret=True, layout=layout)
         ref = dense_reference(q, k, v)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    def test_cross_attention_shape(self):
+    def test_cross_attention_shape(self, layout):
         """Cross attention: 77-token text context vs image queries."""
         q, k, v = rand_qkv(jax.random.key(6), Nq=256, Nk=77)
-        out = flash_attention(q, k, v, interpret=True)
+        out = flash_attention(q, k, v, interpret=True, layout=layout)
         ref = dense_reference(q, k, v)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
@@ -109,33 +113,32 @@ class TestShardMap:
 
 class TestDispatch:
     def test_full_attention_env_toggle(self, monkeypatch):
+        """CDT_FLASH_ATTENTION is the one environment variable that takes
+        part: =0 is XLA whatever the geometry, =1 reaches flash on this
+        CPU host (the interpreter), unset is XLA off-TPU."""
         from comfyui_distributed_tpu.ops import attention as attn
 
         monkeypatch.setenv("CDT_FLASH_ATTENTION", "0")
-        assert not attn._flash_enabled()
+        assert attn.select_kernel(1 << 20, 1 << 20, 10, 64).tier == "xla"
         monkeypatch.setenv("CDT_FLASH_ATTENTION", "1")
-        assert attn._flash_enabled()
+        assert attn.select_kernel(64, 64, 2, 64).tier == "bh"
+        assert attn.select_kernel(4096, 4096, 16, 64).tier == "packed"
+        monkeypatch.delenv("CDT_FLASH_ATTENTION")
+        assert attn.select_kernel(4096, 4096, 16, 64).tier == "xla"
 
-    def test_seq_length_gate(self, monkeypatch):
-        """r04: with no explicit env the flash default is gated on q
-        length — below CDT_FLASH_MIN_SEQ the XLA fused lowering wins on
-        TPU (measured: scripts/mfu_probe.py, SDXL 1024² flash 0.1763
-        s/fwd vs XLA 0.1677), so short sequences must resolve to False
-        even on TPU. Off-TPU (this CPU host) both resolve False; the
-        explicit flags override everything."""
+    def test_seq_length_gate(self):
+        """r04: the classic call is gated on q length — below 8192 the
+        XLA fused lowering wins on TPU (measured: scripts/mfu_probe.py,
+        SDXL 1024² flash 0.1763 s/fwd vs XLA 0.1677), so a packed-illegal
+        short sequence resolves to XLA; a caller promised flash gets the
+        classic call at any length."""
         from comfyui_distributed_tpu.ops import attention as attn
 
-        monkeypatch.delenv("CDT_FLASH_ATTENTION", raising=False)
-        assert attn._flash_min_seq() == 8192
-        monkeypatch.setenv("CDT_FLASH_MIN_SEQ", "4096")
-        assert attn._flash_min_seq() == 4096
-        # short q: gated off regardless of platform
-        assert not attn._flash_enabled(q_len=4095)
-        # explicit force wins over the gate
-        monkeypatch.setenv("CDT_FLASH_ATTENTION", "1")
-        assert attn._flash_enabled(q_len=64)
-        monkeypatch.setenv("CDT_FLASH_ATTENTION", "0")
-        assert not attn._flash_enabled(q_len=1 << 20)
+        assert attn.BH_MIN_Q == 8192
+        assert attn.policy_choice(8191, 8191, 5, 64).tier == "xla"
+        assert attn.policy_choice(8192, 8192, 5, 64).tier == "bh"
+        assert attn.policy_choice(64, 64, 5, 64, flash_only=True).tier \
+            == "bh"
 
     def test_prefer_flash_safe_off_tpu(self, monkeypatch):
         """prefer_flash skips the seq-length gate but NOT the platform
@@ -175,14 +178,13 @@ class TestLayoutVariants:
                                   # groups on the grid, no width ceiling
                                   # (see TestPackedBlocks)
     ])
-    def test_packed_matches_bh(self, monkeypatch, shape):
+    def test_packed_matches_bh(self, shape):
         from comfyui_distributed_tpu.ops.flash_attention import flash_attention
 
         b, nq, h, d, nk = shape
         q = jax.random.normal(jax.random.key(0), (b, nq, h, d))
         k = jax.random.normal(jax.random.key(1), (b, nk, h, d))
         v = jax.random.normal(jax.random.key(2), (b, nk, h, d))
-        monkeypatch.delenv("CDT_FLASH_LAYOUT", raising=False)
         a = flash_attention(q, k, v, interpret=True, layout="packed")
         b_ = flash_attention(q, k, v, interpret=True, layout="bh")
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
@@ -193,120 +195,106 @@ class TestLayoutVariants:
 
 class TestShapeGate:
     """r04 final gate: on TPU (simulated here by patching jax.devices)
-    the default picks flash per shape — packed-legal layouts engage at
+    the one policy picks per shape — packed-legal layouts engage at
     q ≥ 1024 with K ≥ 256 (measured crossover, docs/roofline.md finding
-    1a), packed-illegal layouts keep the classic 8192 gate."""
+    1a), packed-illegal layouts keep the classic 8192 gate. Geometries
+    with no table row, so the policy alone answers."""
 
     @pytest.fixture()
-    def on_tpu(self, monkeypatch):
+    def tier(self, monkeypatch):
         import types
 
         from comfyui_distributed_tpu.ops import attention as attn
 
         monkeypatch.delenv("CDT_FLASH_ATTENTION", raising=False)
-        monkeypatch.delenv("CDT_FLASH_MIN_SEQ", raising=False)
-        monkeypatch.delenv("CDT_FLASH_MIN_SEQ_PACKED", raising=False)
-        monkeypatch.delenv("CDT_FLASH_MIN_KV_PACKED", raising=False)
-        monkeypatch.delenv("CDT_FLASH_LAYOUT", raising=False)
-        monkeypatch.delenv("CDT_FLASH_BLOCK_Q", raising=False)
-        monkeypatch.delenv("CDT_FLASH_BLOCK_K", raising=False)
+        monkeypatch.setenv("CDT_ATTN_TUNE", "0")
         fake = types.SimpleNamespace(platform="tpu")
         monkeypatch.setattr(attn.jax, "devices", lambda *a: [fake])
-        return attn
 
-    def test_packed_legal_engages_at_sdxl_lengths(self, on_tpu):
+        def tier(q_len, kv_len, num_heads, head_dim):
+            return attn.select_kernel(q_len, kv_len, num_heads,
+                                      head_dim).tier
+        return tier
+
+    def test_packed_legal_engages_at_sdxl_lengths(self, tier):
         # SDXL self-attention: 4096 tokens, 10 heads × 64
-        assert on_tpu._flash_enabled(q_len=4096, kv_len=4096,
-                                     num_heads=10, head_dim=64)
+        assert tier(4096, 4096, 10, 64) == "packed"
         # the 32² block: 1024 tokens — exactly at the packed floor
-        assert on_tpu._flash_enabled(q_len=1024, kv_len=1024,
-                                     num_heads=20, head_dim=64)
-        assert not on_tpu._flash_enabled(q_len=512, kv_len=512,
-                                         num_heads=20, head_dim=64)
+        assert tier(1024, 1024, 20, 64) == "packed"
+        assert tier(512, 512, 20, 64) == "xla"
 
-    def test_short_kv_cross_attention_stays_on_xla(self, on_tpu):
+    def test_short_kv_cross_attention_stays_on_xla(self, tier):
         # SDXL cross-attention: K = 77 text tokens → one mostly-padding
         # K block, measured behind XLA
-        assert not on_tpu._flash_enabled(q_len=4096, kv_len=77,
-                                         num_heads=10, head_dim=64)
+        assert tier(4096, 77, 10, 64) == "xla"
 
-    def test_packed_illegal_keeps_classic_gate(self, on_tpu):
+    def test_packed_illegal_keeps_classic_gate(self, tier):
         # H·D = 5·64 is not a whole number of 128-lane groups → classic
         # call, 8192 gate
-        assert not on_tpu._flash_enabled(q_len=4608, kv_len=4608,
-                                         num_heads=5, head_dim=64)
-        assert on_tpu._flash_enabled(q_len=9000, kv_len=9000,
-                                     num_heads=5, head_dim=64)
+        assert tier(4608, 4608, 5, 64) == "xla"
+        assert tier(9000, 9000, 5, 64) == "bh"
         # FLUX's H·D = 3072 was past the old tile's width ceiling; a tile
         # is one group wide now, so it is packed at its real length
-        assert on_tpu._flash_enabled(q_len=4608, kv_len=4608,
-                                     num_heads=24, head_dim=128)
+        assert tier(4608, 4608, 24, 128) == "packed"
 
-    def test_shape_free_call_keeps_classic_gate(self, on_tpu):
-        # callers that pass only q_len (no head geometry) get the
-        # classic 8192 threshold
-        assert not on_tpu._flash_enabled(q_len=4096)
-        assert on_tpu._flash_enabled(q_len=8192)
+    def test_floors_hold_exact_lengths_not_buckets(self, tier):
+        # 1000 tokens share the 1024 bucket of the table's keys; the
+        # policy reads the length itself
+        assert tier(1000, 1000, 16, 64) == "xla"
+        assert tier(1024, 255, 16, 64) == "xla"
+        assert tier(1024, 256, 16, 64) == "packed"
 
-    def test_short_kv_long_q_falls_through_to_classic_gate(self, on_tpu):
+    def test_short_kv_long_q_falls_through_to_classic_gate(self, tier):
         # packed-legal geometry whose KV floor fails must still reach
         # the classic bh gate at very long q (streamed-softmax memory
         # win), not silently drop flash entirely (r04 advisor finding)
-        assert on_tpu._flash_enabled(q_len=16384, kv_len=77,
-                                     num_heads=10, head_dim=64)
-        assert not on_tpu._flash_enabled(q_len=4096, kv_len=77,
-                                         num_heads=10, head_dim=64)
+        assert tier(16384, 77, 10, 64) == "bh"
+        assert tier(4096, 77, 10, 64) == "xla"
 
     def test_packed_layout_requires_lane_aligned_head_dim(self, monkeypatch):
         # H=128, D=16 fills whole lane groups but with eight heads a
-        # group, a shape class never measured — excluded
-        from comfyui_distributed_tpu.ops.flash_attention import _layout_packed
+        # group, a shape class never measured — excluded: asked for
+        # packed (or nothing), such a call runs the classic kernel
+        from comfyui_distributed_tpu.ops import flash_attention as fa
 
-        monkeypatch.delenv("CDT_FLASH_LAYOUT", raising=False)
-        assert not _layout_packed(128, 16)
-        assert _layout_packed(10, 64)
-        assert _layout_packed(16, 128)
+        assert not fa._packed_legal(128, 16)
+        calls = _packed_call_spy(monkeypatch)
+        q, k, v = rand_qkv(jax.random.key(13), Nq=64, Nk=64, H=128, D=16)
+        for layout in ("packed", None):
+            out = flash_attention(q, k, v, interpret=True, layout=layout)
+            np.testing.assert_allclose(out, dense_reference(q, k, v),
+                                       atol=2e-5, rtol=2e-5)
+        assert not calls
+        q, k, v = rand_qkv(jax.random.key(14), Nq=64, Nk=64, H=2, D=64)
+        flash_attention(q, k, v, interpret=True)
+        assert calls == [(64, 128)]
 
-    def test_malformed_gate_env_falls_back(self, on_tpu, monkeypatch):
-        # an env typo must degrade to the default, not crash the gate
-        monkeypatch.setenv("CDT_FLASH_MIN_SEQ_PACKED", "banana")
-        assert on_tpu._flash_enabled(q_len=4096, kv_len=4096,
-                                     num_heads=10, head_dim=64)
+    def test_block_arguments_validated(self):
+        """Non-positive or non-(8,128)-divisible blocks raise a
+        descriptive error at the call instead of letting pallas fail
+        deep in Mosaic lowering (ISSUE 8 satellite) — in either layout,
+        and in a tuning-table row."""
+        from comfyui_distributed_tpu.ops import autotune
 
-    def test_block_env_knobs_reach_kernel(self, monkeypatch):
-        """CDT_FLASH_BLOCK_Q/K (r05 tuning knobs) change the kernel's
-        block geometry without changing its math."""
         q, k, v = rand_qkv(jax.random.key(12), Nq=256, Nk=512)
-        ref = dense_reference(q, k, v)
-        monkeypatch.setenv("CDT_FLASH_BLOCK_Q", "128")
-        monkeypatch.setenv("CDT_FLASH_BLOCK_K", "128")
-        out = flash_attention(q, k, v, interpret=True)
-        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-
-    def test_block_env_knobs_validated_at_parse(self, monkeypatch):
-        """Non-positive or non-(8,128)-divisible block knobs raise a
-        descriptive error at first use instead of letting pallas fail
-        deep in Mosaic lowering (ISSUE 8 satellite; the old behavior
-        silently fell back, hiding operator typos)."""
-        q, k, v = rand_qkv(jax.random.key(12), Nq=256, Nk=512)
-        monkeypatch.setenv("CDT_FLASH_BLOCK_Q", "0")
-        with pytest.raises(ValueError, match="CDT_FLASH_BLOCK_Q"):
-            flash_attention(q, k, v, interpret=True)
-        monkeypatch.setenv("CDT_FLASH_BLOCK_Q", "100")   # not 8-divisible
-        with pytest.raises(ValueError, match="multiple of 8"):
-            flash_attention(q, k, v, interpret=True)
-        monkeypatch.setenv("CDT_FLASH_BLOCK_Q", "256")
-        monkeypatch.setenv("CDT_FLASH_BLOCK_K", "-64")
-        with pytest.raises(ValueError, match="multiple of 128"):
-            flash_attention(q, k, v, interpret=True)
-        monkeypatch.setenv("CDT_FLASH_BLOCK_K", "banana")
-        with pytest.raises(ValueError, match="not an integer"):
-            flash_attention(q, k, v, interpret=True)
-        # explicit arguments go through the same validation
-        monkeypatch.delenv("CDT_FLASH_BLOCK_Q")
-        monkeypatch.delenv("CDT_FLASH_BLOCK_K")
-        with pytest.raises(ValueError, match="multiple of 128"):
-            flash_attention(q, k, v, block_k=200, interpret=True)
+        for layout in ("packed", "bh"):
+            with pytest.raises(ValueError, match="block_q=0"):
+                flash_attention(q, k, v, block_q=0, interpret=True,
+                                layout=layout)
+            with pytest.raises(ValueError, match="multiple of 8"):
+                flash_attention(q, k, v, block_q=100, interpret=True,
+                                layout=layout)
+            with pytest.raises(ValueError, match="multiple of 128"):
+                flash_attention(q, k, v, block_q=256, block_k=-64,
+                                interpret=True, layout=layout)
+            with pytest.raises(ValueError, match="multiple of 128"):
+                flash_attention(q, k, v, block_k=200, interpret=True,
+                                layout=layout)
+        key = autotune.GeometryKey.from_shape(2, 64, 256, 512)
+        for tier in ("packed", "bh", "fused"):
+            errors = autotune.validate_entry(
+                key, autotune.KernelChoice(tier, 256, 200))
+            assert errors and "multiple of 128" in errors[0]
 
 
 def _packed_call_spy(monkeypatch):
@@ -385,10 +373,22 @@ class TestPackedBlocks:
             < _PACKED_VMEM_BUDGET_BYTES
 
     def test_requested_blocks_past_the_budget_raise(self):
+        """In the function, in the call, and in a table row's dispatch."""
+        from comfyui_distributed_tpu.ops import attention as attn
+        from comfyui_distributed_tpu.ops import autotune
         from comfyui_distributed_tpu.ops.flash_attention import _packed_blocks
 
         with pytest.raises(ValueError, match="VMEM"):
             _packed_blocks(16384, 16384, 128, 2, 4096, 16384)
+        choice = autotune.KernelChoice("packed", 4096, 16384)
+        with pytest.raises(ValueError, match="VMEM"):
+            attn._with_packed_blocks(choice, 16384, 16384, 128, "bf16")
+        q, k, v = (jax.ShapeDtypeStruct((1, 16384, 1, 128), jnp.bfloat16),) * 3
+        with pytest.raises(ValueError, match="VMEM"):
+            jax.eval_shape(
+                lambda q, k, v: flash_attention(q, k, v, block_q=4096,
+                                                block_k=16384,
+                                                interpret=True), q, k, v)
 
     @pytest.mark.parametrize("H,D,legal", [
         (24, 128, True), (24, 64, True), (10, 64, True), (2, 192, True),

@@ -483,8 +483,8 @@ class TestKnobRegistry:
             constants.OFFLOAD_LADDER.get()
 
     def test_fallback_knobs_warn_and_default(self, monkeypatch):
-        monkeypatch.setenv("CDT_FLASH_MIN_SEQ_PACKED", "banana")
-        assert constants.FLASH_MIN_SEQ_PACKED.get() == 1024
+        monkeypatch.setenv("CDT_RING_BLOCK", "banana")
+        assert constants.RING_BLOCK.get() == 1024
 
     def test_optbool_tristate(self, monkeypatch):
         monkeypatch.delenv("CDT_OFFLOAD", raising=False)

@@ -182,6 +182,26 @@ class TestStagePool:
         assert batches == [2]
         pool.stop()
 
+    def test_items_put_together_are_taken_together(self):
+        """One ``put`` of several items is atomic: even with no window at
+        all the worker cannot take the first without the second (two
+        puts could be split by thread scheduling, which is what made a
+        denoised group of 2 decode as 1 + 1 under load)."""
+        batches, ev = [], threading.Event()
+
+        def runner(items):
+            batches.append([it.key for it in items])
+            if sum(len(b) for b in batches) >= 3:
+                ev.set()
+
+        pool = StagePool("decode", 1, runner,
+                         batch_key=lambda it: it.bucket_key(),
+                         max_batch=8, window_s=0.0)
+        pool.put(_Item("a"), _Item("a"), _Item("b"))
+        assert ev.wait(5.0)
+        assert sorted(batches) == [["a", "a"], ["b"]]
+        pool.stop()
+
     def test_stop_returns_leftover_items(self):
         started = threading.Event()
 
