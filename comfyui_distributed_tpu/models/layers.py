@@ -117,13 +117,19 @@ class _ProjKernel(nn.Module):
 class Attention(nn.Module):
     """Multi-head attention over [B, N, C] with optional cross context.
 
-    Self-attention sites (no context) are fusable: projection feeds
-    attention with nothing in between, so when the kernel dispatcher
-    (``ops/attention.select_kernel`` — a tuning-table row, else the one
-    policy; asked once a site) picks the fused tier, the QKV matmuls fold
-    into the flash grid (``ops/flash_attention.fused_qkv_attention``) and
-    q/k/v never materialize in HBM. Either branch owns the identical
-    param tree."""
+    The kernel dispatcher (``ops/attention.select_kernel`` — a
+    tuning-table row, else the one policy) is asked once a site. Every
+    shipped row and the policy lead to the dense arm: plain ``nn.Dense``
+    projections, then ``full_attention`` with the choice (the packed
+    Pallas kernel at SDXL's 64² and 32² self-attention, XLA at its
+    cross-attention). Self-attention sites (no context) are also
+    fusable — projection feeds attention with nothing in between — and
+    a local table row can give them the fused tier, which folds the QKV
+    matmuls into the flash grid
+    (``ops/flash_attention.fused_qkv_attention``); no shipped row does:
+    that kernel re-projects K and V for every q block and measured 3.1×
+    the dense arm's time at SDXL's 64² site (PERF.md §6, PR 29). Either
+    arm owns the identical param tree."""
 
     num_heads: int
     head_dim: int

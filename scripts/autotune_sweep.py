@@ -12,8 +12,8 @@ Default geometry set is the known model zoo
 (``autotune.model_zoo_geometries``: SDXL self/cross, FLUX joint, WAN
 self/cross). ``--dry-run`` writes what the dispatcher's one policy
 (``ops/attention.policy_choice``) answers and works anywhere (no timing;
-it never answers ``fused``, so ``--dry-run --bake`` would overwrite the
-shipped table's one hand-kept row);
+``--dry-run --bake`` rewrites the shipped table to the byte: it holds no
+hand-kept row);
 without it the sweep times every candidate on the live backend and
 belongs on the TPU host. Every resolved entry is validated
 (``autotune.validate_entry``) before writing; exit 1 on any error so a

@@ -323,7 +323,7 @@ class TestDispatcherPrecedence:
 # policy line that a PR means to change.
 PINNED_SELECTIONS = [
     ("solo30.self64", 10, 64, 4096, 4096, "bf16", True, False, 1,
-     ("fused", 256, 512)),
+     ("packed", 512, 4096)),
     ("solo30.self32", 20, 64, 1024, 1024, "bf16", True, False, 1,
      ("packed", 512, 1024)),
     ("solo30.cross64", 10, 64, 4096, 77, "bf16", False, False, 1,
@@ -341,9 +341,9 @@ PINNED_SELECTIONS = [
     ("solo30.self32.tp2", 20, 64, 1024, 1024, "bf16", True, False, 2,
      ("packed", 512, 1024)),
     ("zoo.sdxl_self64.fusable", 10, 64, 4096, 4096, "bf16", True, False, 1,
-     ("fused", 256, 512)),
+     ("packed", 512, 4096)),
     ("zoo.sdxl_self64.projected", 10, 64, 4096, 4096, "bf16", False, False, 1,
-     ("packed", 256, 512)),
+     ("packed", 512, 4096)),
     ("zoo.sdxl_self32.fusable", 20, 64, 1024, 1024, "bf16", True, False, 1,
      ("packed", 512, 1024)),
     ("zoo.sdxl_self32.projected", 20, 64, 1024, 1024, "bf16", False, False, 1,
@@ -434,10 +434,10 @@ def test_attention_site_asks_once(monkeypatch, cross):
     assert calls[0][1]["fusable_width"] == (None if cross else 128)
 
 
-# the shipped rows the policy does not answer the same: kept by hand.
-# h10.d64.q4096.kv4096 → fused is what the benchmark's `solo30` runs
-# (`_flash_mha_fused` ×10) and what ROADMAP S2 will measure against packed.
-HAND_KEPT_ROWS = {"h10.d64.q4096.kv4096.bf16": "packed"}
+# the shipped rows the policy does not answer the same, with the policy's
+# tier: kept by hand. None today; the mechanism stays until the autotuner
+# is timed on a chip and may bring one (ROADMAP D14).
+HAND_KEPT_ROWS = {}
 
 
 def test_dry_rebake_reproduces_the_shipped_table():
@@ -537,7 +537,9 @@ class TestSweepCLI:
 class TestPackedKernelSmoke:
     """The packed kernel's mathematics in the smoke tier (the full matrix
     is ``tests/test_flash_attention.py``, marked slow): interpret mode,
-    tiny ragged shapes, both head-group kinds, K resident and streamed."""
+    tiny ragged shapes, both head-group kinds, K resident and streamed,
+    and SDXL's 64² site as it runs (an odd count of D=64 head groups
+    behind plain projections)."""
 
     @pytest.mark.parametrize("case", [
         ("d64.resident", 2, 100, 77, 4, 64, None),
@@ -563,3 +565,28 @@ class TestPackedKernelSmoke:
             out, jax.nn.dot_product_attention(q, k, v),
             atol=2e-5, rtol=2e-5)
 
+    def test_sdxl_self64_site_through_attention_module(self, monkeypatch):
+        """H=10, D=64 (five 128-lane groups) through ``layers.Attention``
+        with the kernels forced on, at the shortest ragged length past
+        the packed floor: the site projects with ``nn.Dense`` and runs
+        the packed call, against XLA attention on the same parameters."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from comfyui_distributed_tpu.models.layers import Attention
+        from comfyui_distributed_tpu.ops import attention as attn
+
+        monkeypatch.setenv("CDT_FLASH_ATTENTION", "1")
+        attn.reset_selections()
+        module = Attention(num_heads=10, head_dim=64, dtype=jnp.float32)
+        x = jax.random.normal(jax.random.key(5), (1, 1030, 640), jnp.float32)
+        params = module.init(jax.random.key(6), x)
+        assert set(params["params"]) == {"to_q", "to_k", "to_v", "to_out"}
+        out = module.apply(params, x)
+        assert "h10.d64.q2048.kv2048.f32=packed:344/1152:k-resident" \
+            in attn.selection_summary()
+
+        monkeypatch.setenv("CDT_FLASH_ATTENTION", "0")
+        np.testing.assert_allclose(out, module.apply(params, x),
+                                   atol=2e-5, rtol=2e-5)

@@ -8,6 +8,8 @@ repo's own model counted. These cases compile, at real widths:
 
 - every Pallas row of ``ops/attn_table_default.json`` alone, with its
   operands as program arguments (the strictest setting the compiler has);
+- the fused tier at SDXL's 64² geometry, which no row selects: compiled
+  while its code lives (ROADMAP D12);
 - the classic ``bh`` call at FLUX's geometry, which no row selects but the
   floors of ``select_kernel`` still reach;
 - SDXL's two self-attention sites inside the transformer block that calls
@@ -102,10 +104,11 @@ def _compile_kernel(chip, tier, H, D, nq, nk, bq, bk, batch=1):
 # shape's, as the dispatcher resolves it), WAN also at its real 14 040
 # tokens (not a block multiple: the call pads) with the shape's blocks and
 # with requested 256/512 streaming K, SD3's joint attention at the CFG
-# batch, and the classic call the table never picks
+# batch, the classic call the table never picks, and SDXL's 64² site at the
+# CFG batch by the fused tier (no row's) and by the packed one (as it runs)
 KERNEL_CASES = [
     (ks, c.tier, k.num_heads, k.head_dim, k.q_bucket, k.kv_bucket,
-     c.block_q, c.block_k, 2 if c.tier == "fused" else 1)
+     c.block_q, c.block_k, 1)
     for ks, (k, c) in sorted(PALLAS_ROWS.items())
 ] + [
     ("wan_self_14040", "packed", 12, 128, 14040, 14040, None, None, 1),
@@ -114,6 +117,8 @@ KERNEL_CASES = [
     ("sd3_joint_4173", "packed", 24, 64, 4173, 4173, None, None, 2),
     ("sd3_joint_4173_streamed", "packed", 24, 64, 4173, 4173, 256, 512, 2),
     ("flux_bh_h24.d128.q8192", "bh", 24, 128, 8192, 8192, 256, 512, 1),
+    ("sdxl_self64_fused", "fused", 10, 64, 4096, 4096, 256, 512, 2),
+    ("sdxl_self64_cfg", "packed", 10, 64, 4096, 4096, None, None, 2),
 ]
 
 
@@ -131,11 +136,10 @@ def test_table_has_the_rows_the_main_paths_select():
     zoo = {k.key_str() for k in autotune.model_zoo_geometries().values()}
     assert zoo == {k.key_str() for k in TABLE}
     assert len(PALLAS_ROWS) >= 5
-    assert {c.tier for _, c in PALLAS_ROWS.values()} == {"fused", "packed"}
+    assert {c.tier for _, c in PALLAS_ROWS.values()} == {"packed"}
 
 
-@pytest.mark.parametrize("level", [(640, 10, 4096, "fused"),
-                                   (1280, 20, 1024, "packed")],
+@pytest.mark.parametrize("level", [(640, 10, 4096), (1280, 20, 1024)],
                          ids=lambda l: f"c{l[0]}.n{l[2]}")
 def test_sdxl_self_attention_inside_its_block(chip, level, monkeypatch):
     """One SDXL transformer block (self-attention, cross-attention to 77
@@ -145,7 +149,7 @@ def test_sdxl_self_attention_inside_its_block(chip, level, monkeypatch):
     operands, and the tier is the table's."""
     from comfyui_distributed_tpu.models.layers import TransformerBlock
 
-    C, heads, n, tier = level
+    C, heads, n = level
     for var in ("CDT_FLASH_ATTENTION", "CDT_ATTN_TUNE"):
         monkeypatch.delenv(var, raising=False)
     # the one place the kernels ask where they are (ops/flash_attention):
@@ -168,7 +172,7 @@ def test_sdxl_self_attention_inside_its_block(chip, level, monkeypatch):
     selected = dict(item.split("=") for item in
                     attn.selection_summary().split(","))
     key = autotune.GeometryKey.from_shape(heads, C // heads, n, n).key_str()
-    assert selected[key].startswith(tier), selected
+    assert selected[key].startswith("packed"), selected
     cross = autotune.GeometryKey.from_shape(heads, C // heads, n, 77)
     assert selected[cross.key_str()] == "xla", selected
 
