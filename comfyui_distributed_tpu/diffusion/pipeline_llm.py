@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import llm_hybrid
+from ..models.llm_model import cache_bytes
 from .pipeline import bind_weights, cached_build
 from .samplers import run_segment, token_program
 
@@ -23,21 +23,26 @@ TAP_EVERY = 128     # llm_decode returns every 128th step's logits
 
 
 class LLMPipeline:
-    def __init__(self, config: llm_hybrid.LLMConfig, params):
+    """Any language model that gives ``models/llm_model.LLMModel``'s
+    functions: ``config.model`` is that value, ``config`` its sizes."""
+
+    def __init__(self, config, params):
         self.config = config
+        self.model = config.model
         self.params = params
 
     def step(self, weights, state, token, pos):
         """One decoded token: what ``llm_decode`` scans."""
-        return llm_hybrid.decode_step(self.config, weights, state, token,
+        return self.model.decode_step(self.config, weights, state, token,
                                       pos)
 
     def prefill_fn(self, prompt_tokens: int, new_tokens: int):
         """``(ids [prompt_tokens]) -> (last logits [V], cache, held)``."""
         cfg, max_len = self.config, prompt_tokens + new_tokens
+        prefill = self.model.prefill
 
         def llm_prefill(weights, ids):
-            return llm_hybrid.prefill(cfg, weights, ids, max_len)
+            return prefill(cfg, weights, ids, max_len)
 
         return bind_weights(jax.jit(llm_prefill), self.params,
                             label="llm_prefill")
@@ -46,14 +51,14 @@ class LLMPipeline:
         """``(logits, cache, key, temperature) -> (ids [new_tokens], tap
         logits [new_tokens // TAP_EVERY, V], held slots per expert layer,
         finite)``: ``new_tokens`` steps of the token program in one scan."""
-        cfg = self.config
+        n_counts = len(self.config.moe_layers)
 
         def llm_decode(weights, logits, cache, key, temperature):
             def forward(state, token, i):
                 return self.step(weights, state, token, prompt_tokens + i)
 
             prog = token_program(forward, new_tokens, key, temperature,
-                                 TAP_EVERY, len(cfg.moe_layers))
+                                 TAP_EVERY, n_counts)
             carry = run_segment(prog, prog.init((logits, cache)), 0,
                                 new_tokens)
             return prog.extract(carry), carry[3], carry[4], carry[5]
@@ -79,6 +84,9 @@ class LLMPipeline:
             jnp.asarray(temperature, jnp.float32))
         # ``finite`` covers prefill's logits too: step 0 draws from them
         return {"ids": np.asarray(out), "prefill_logits": logits,
+                # shapes only (eval_shape): nothing is allocated or run
+                "cache_bytes": cache_bytes(self.model, self.config,
+                                           len(ids) + int(new_tokens)),
                 "tap_logits": taps, "finite": bool(finite),
                 "held_prefill": np.asarray(held_prefill),
                 "held_decode": np.asarray(held_decode)}

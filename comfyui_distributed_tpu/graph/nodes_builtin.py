@@ -957,11 +957,9 @@ def rewrite_prompt_ids(text: str, prompt_tokens: int, vocab: int) -> list:
 @register_node("TPUPromptRewrite")
 class TPUPromptRewrite(NodeDef):
     """Rewrite a prompt with a language model ahead of ``CLIPTextEncode``:
-    ``prompt_tokens`` of instruction + user text in, exactly ``new_tokens``
-    sampled (no stop token), rendered as words. Two programs a call,
-    ``llm_prefill`` and ``llm_decode`` (``diffusion/pipeline_llm.py``);
-    the request fails on a non-finite logit or an id outside the
-    vocabulary slice the model holds."""
+    ``prompt_tokens`` in, exactly ``new_tokens`` sampled (no stop token),
+    as words. Two programs a call (``diffusion/pipeline_llm.py``); fails
+    on a non-finite logit or an id outside the model's vocabulary slice."""
 
     INPUTS = {"llm": "LLM", "text": "STRING", "seed": "INT"}
     OPTIONAL = {"prompt_tokens": "INT", "new_tokens": "INT",
@@ -979,7 +977,7 @@ class TPUPromptRewrite(NodeDef):
                                   "LLMLoader's output)", field="llm")
         cfg = llm.pipeline.config
         prompt_tokens, new_tokens = int(prompt_tokens), int(new_tokens)
-        if prompt_tokens < cfg.short_conv_kernel_size or new_tokens < 1:
+        if prompt_tokens < cfg.min_prompt_tokens or new_tokens < 1:
             raise ValidationError(
                 f"prompt_tokens {prompt_tokens} / new_tokens {new_tokens}: "
                 "too few", field="prompt_tokens")
@@ -989,15 +987,17 @@ class TPUPromptRewrite(NodeDef):
             out = llm.pipeline.generate(ids, new_tokens, int(seed),
                                         float(temperature))
         if _tm_enabled():
-            per_token = cfg.num_experts_per_tok * len(cfg.moe_layers)
+            for kind, size in out["cache_bytes"].items():
+                _tm.LLM_CACHE_BYTES.labels(layers=kind).set(float(size))
             for phase, tokens in (("prefill", prompt_tokens),
                                   ("decode", new_tokens)):
                 held = int(out[f"held_{phase}"].sum())
                 _tm.LLM_TOKENS.labels(phase=phase).inc(tokens)
-                _tm.LLM_EXPERT_SLOTS.labels(where="held",
-                                            phase=phase).inc(held)
+                _tm.LLM_EXPERT_SLOTS.labels(where="held", phase=phase).inc(held)
                 _tm.LLM_EXPERT_SLOTS.labels(where="absent", phase=phase).inc(
-                    tokens * per_token - held)
+                    tokens * cfg.routed_slots_per_token - held)
+                _tm.LLM_STREAM_MIX.labels(phase=phase).inc(
+                    tokens * cfg.stream_mixes_per_token)
         new_ids = out["ids"]
         if not out["finite"]:
             raise RuntimeError("the language model produced a non-finite "

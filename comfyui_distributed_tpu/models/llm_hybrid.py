@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import delta_rule, expert_share, latent_attention as mla_ops
+from .llm_model import LLMModel
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,6 +86,10 @@ class LLMConfig:
             dtype="float32")
         return cls(**{**base, **kw})
 
+    @property
+    def model(self) -> LLMModel:
+        return MODEL
+
     def is_mla(self, i: int) -> bool:
         return (i + 1) % self.layer_group_size == 0
 
@@ -113,6 +118,16 @@ class LLMConfig:
     @property
     def kda_width(self) -> int:
         return self.num_attention_heads * self.head_dim
+
+    @property
+    def routed_slots_per_token(self) -> int:
+        return self.num_experts_per_tok * len(self.moe_layers)
+
+    stream_mixes_per_token = 0        # one residual stream, nothing mixed
+
+    @property
+    def min_prompt_tokens(self) -> int:
+        return self.short_conv_kernel_size
 
 
 # --- weights ---------------------------------------------------------------
@@ -193,16 +208,16 @@ def _draw(key, spec):
     shape, dtype, (how, *args) = spec
     if how == "linspace":
         return jnp.linspace(args[0], args[1], shape[0], dtype=dtype)
-    if how == "const":
-        return jnp.full(shape, args[0], dtype)
+    if how == "const":      # a scalar, or one value for each of the last axis
+        return jnp.broadcast_to(jnp.asarray(args[0], dtype), shape)
     std = args[0] if args[0] is not None else 1.0 / math.sqrt(shape[-2])
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
-def init_llm(cfg: LLMConfig, key, abstract: bool = False):
-    """Random weights from ``key``, built on the device leaf by leaf
-    (``abstract``: a ShapeDtypeStruct tree, for off-chip compiles)."""
-    specs = _shapes(cfg)
+def init_tree(specs, key, abstract: bool = False):
+    """A weight tree from its ``(shape, dtype name, init)`` leaves: random
+    from ``key``, built on the device leaf by leaf (``abstract``: a
+    ShapeDtypeStruct tree, for off-chip compiles)."""
     leaves, treedef = jax.tree_util.tree_flatten(specs, is_leaf=_is_leaf)
     if abstract:
         return jax.tree_util.tree_unflatten(treedef, [
@@ -213,9 +228,17 @@ def init_llm(cfg: LLMConfig, key, abstract: bool = False):
         treedef, [draw(k, s) for k, s in zip(keys, leaves)])
 
 
-def param_count(cfg: LLMConfig) -> int:
-    leaves = jax.tree_util.tree_leaves(_shapes(cfg), is_leaf=_is_leaf)
+def count_params(specs) -> int:
+    leaves = jax.tree_util.tree_leaves(specs, is_leaf=_is_leaf)
     return sum(math.prod(s[0]) for s in leaves)
+
+
+def init_llm(cfg: LLMConfig, key, abstract: bool = False):
+    return init_tree(_shapes(cfg), key, abstract)
+
+
+def param_count(cfg: LLMConfig) -> int:
+    return count_params(_shapes(cfg))
 
 
 # --- pieces shared by prefill and decode -----------------------------------
@@ -288,6 +311,13 @@ def _mla_scale(cfg: LLMConfig) -> float:
     return 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
 
 
+_ACT = expert_share.silu_gate        # every FFN here is SwiGLU
+
+
+def _swiglu(x, ffn, dtype):
+    return expert_share.gated_mlp(x, ffn["w_gu"], ffn["w_down"], dtype, _ACT)
+
+
 def _count_held(cfg: LLMConfig, idx):
     return expert_share.held_slots(idx, cfg.first_expert,
                                    cfg.num_experts).sum().astype(jnp.int32)
@@ -321,6 +351,11 @@ def empty_cache(cfg: LLMConfig, max_len: int) -> dict:
               for _ in cfg.mla_layers],
         "kr": [jnp.zeros((max_len, cfg.qk_rope_head_dim), dtype)
                for _ in cfg.mla_layers]}
+
+
+def cache_kinds(cfg: LLMConfig, cache: dict) -> dict:
+    return {"recurrent": [cache["S"], cache["conv"]],
+            "full": [cache["c"], cache["kr"]]}
 
 
 def prefill(cfg: LLMConfig, params, ids, max_len: int,
@@ -377,13 +412,11 @@ def prefill(cfg: LLMConfig, params, ids, max_len: int,
             idx, w = expert_share.route(x, m["w_router"], m["router_bias"],
                                         cfg.routing)
             h = h + expert_share.held_part_dense(
-                x, idx, w, m["e_gu"], m["e_down"], cfg.first_expert, dtype) \
-                + expert_share.swiglu(x, m["shared"]["w_gu"],
-                                      m["shared"]["w_down"], dtype)
+                x, idx, w, m["e_gu"], m["e_down"], cfg.first_expert, dtype,
+                _ACT) + _swiglu(x, m["shared"], dtype)
             held.append(_count_held(cfg, idx))
         else:
-            h = h + expert_share.swiglu(x, layer["ffn"]["w_gu"],
-                                        layer["ffn"]["w_down"], dtype)
+            h = h + _swiglu(x, layer["ffn"], dtype)
     logits = logits_of(cfg, params, h if all_logits else h[-1])
     return logits, cache, _stack_counts(held)
 
@@ -440,11 +473,11 @@ def decode_step(cfg: LLMConfig, params, cache: dict, token, pos):
                                         m["router_bias"], cfg.routing)
             h = h + expert_share.held_part_token(
                 x, idx[0], w[0], m["e_gu"], m["e_down"], cfg.first_expert,
-                dtype) \
-                + expert_share.swiglu(x[None], m["shared"]["w_gu"],
-                                      m["shared"]["w_down"], dtype)[0]
+                dtype, _ACT) + _swiglu(x[None], m["shared"], dtype)[0]
             held.append(_count_held(cfg, idx))
         else:
-            h = h + expert_share.swiglu(x[None], layer["ffn"]["w_gu"],
-                                        layer["ffn"]["w_down"], dtype)[0]
+            h = h + _swiglu(x[None], layer["ffn"], dtype)[0]
     return logits_of(cfg, params, h), cache, _stack_counts(held)
+
+
+MODEL = LLMModel(init_llm, prefill, decode_step, empty_cache, cache_kinds)

@@ -45,8 +45,9 @@ class ModelPreset:
     # chip; held in bfloat16 it is 4.79 + 0.56 (docs/weights.md). The tiny
     # test presets stay float32: their parity tolerances are float32's.
     param_dtype: "str | None" = None
-    # LLMConfig of a language model (models/llm_hybrid.py): such a preset
-    # has no denoiser, VAE or text tower, and is loaded by LLMLoader
+    # config of a language model (models/llm_hybrid.py, llm_motif.py; its
+    # ``.model`` gives the functions): such a preset has no denoiser, VAE
+    # or text tower, and is loaded by LLMLoader
     llm: "object | None" = None
 
     @property
@@ -205,12 +206,18 @@ def _wan_mmdit_preset():
         sample_hw=(60, 104), video=VideoDiTConfig.wan())
 
 
-def _llm_preset(name: str, tiny: bool = False):
-    from .llm_hybrid import LLMConfig
+def _llm_preset(name: str, family: str, tiny: bool = False):
+    """A language model of ``family``: at its published widths (this
+    chip's share), or its tiny float32 form for the CPU."""
+    if family == "motif":
+        from .llm_motif import MotifConfig
 
-    return ModelPreset(name, unet=None, vae=None, text=None,
-                       llm=LLMConfig.tiny() if tiny
-                       else LLMConfig.ling_flash_share())
+        share = MotifConfig.tiny if tiny else MotifConfig.motif_share
+    else:
+        from .llm_hybrid import LLMConfig
+
+        share = LLMConfig.tiny if tiny else LLMConfig.ling_flash_share
+    return ModelPreset(name, unet=None, vae=None, text=None, llm=share())
 
 
 PRESETS: dict[str, ModelPreset] = {
@@ -236,8 +243,10 @@ PRESETS: dict[str, ModelPreset] = {
     "wan-2.2-t2v": _wan22_t2v_preset(),
     "wan-2.2-tiny": _wan22_tiny_preset(),
     "video-mmdit": _wan_mmdit_preset(),
-    "ling-3.0-flash-vl": _llm_preset("ling-3.0-flash-vl"),
-    "ling-tiny": _llm_preset("ling-tiny", tiny=True),
+    "ling-3.0-flash-vl": _llm_preset("ling-3.0-flash-vl", "hybrid"),
+    "ling-tiny": _llm_preset("ling-tiny", "hybrid", tiny=True),
+    "motif-3-beta": _llm_preset("motif-3-beta", "motif"),
+    "motif-tiny": _llm_preset("motif-tiny", "motif", tiny=True),
 }
 
 
@@ -743,8 +752,9 @@ class ModelBundle:
 
 class LLMBundle:
     """A loaded language model (``preset.kind == "llm"``): random weights
-    from the registry's seed, held on the device in the preset's dtype,
-    and the pipeline that binds its two programs. It shares the registry's
+    from the registry's seed (drawn by the model the preset's config
+    names), held on the device in the preset's dtype, and the pipeline
+    that binds its two programs. It shares the registry's
     cache, lock and residency accounting with ``ModelBundle`` and has no
     VAE or text tower of its own."""
 
@@ -754,7 +764,6 @@ class LLMBundle:
     def __init__(self, preset: ModelPreset,
                  checkpoint_dir: Optional[Path] = None, seed: int = 0):
         from ..diffusion.pipeline_llm import LLMPipeline
-        from .llm_hybrid import init_llm
 
         if checkpoint_dir is not None and Path(checkpoint_dir).exists():
             raise ValidationError(
@@ -763,7 +772,8 @@ class LLMBundle:
         self.preset = preset
         self._init_seed = int(seed)
         self.pipeline = LLMPipeline(
-            preset.llm, init_llm(preset.llm, jax.random.key(seed)))
+            preset.llm, preset.llm.model.init(preset.llm,
+                                              jax.random.key(seed)))
 
     def _core_params(self):
         return self.pipeline.params

@@ -3,14 +3,17 @@
 Expert parallelism divides a layer's experts over chips: every chip routes
 every token over ALL the experts (the router keeps its published width and
 its experts per token) and computes the part of the result that ITS
-experts give, ``Σ_{e ∈ held ∩ selected} w_e · SwiGLU_e(x)``. The exchange
+experts give, ``Σ_{e ∈ held ∩ selected} w_e · MLP_e(x)``. The exchange
 that would add the other chips' parts is not here, and nothing stands in
 for it: on one chip the partial result is what goes on
 (``tests/test_llm_hybrid.py`` ties the shares to the uncut layer).
 
-Routing (sigmoid scores, group-limited top-k): the selection runs on
-``score + bias``, the combine weights on the bare scores of the selected
-experts, normalised and scaled. Scores are float32.
+Routing (sigmoid scores, top-k, group-limited where the model groups its
+experts): the selection runs on ``score + bias``, the combine weights on
+the bare scores of the selected experts, normalised and scaled. Scores are
+float32. The experts are gated MLPs ``(act(x W_g, x W_u)) W_down``; the
+model passes its activation (``silu_gate`` here; a model may bring its own,
+with per-expert parameters).
 
 Two forms of the experts' part: :func:`held_part_dense` applies every held
 expert to every token and masks (prefill: a few hundred tokens keep the
@@ -30,7 +33,7 @@ import jax.numpy as jnp
 class Routing:
     experts: int          # the router's width: all experts of the layer
     per_token: int
-    groups: int
+    groups: int           # 1: ungrouped, the best ``per_token`` of all
     groups_kept: int
     scaling: float
     group_top: int = 2    # a group's score is the sum of its best two
@@ -38,11 +41,14 @@ class Routing:
 
 def route(x, w_router, bias, r: Routing):
     """``x`` [T,D] → ``(idx [T,k] int32, weights [T,k] f32)`` over all
-    ``r.experts``. ``bias`` moves the selection only."""
+    ``r.experts``. ``bias`` (None: the router has none) moves the
+    selection only."""
     s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                                w_router.astype(jnp.float32),
                                precision=jax.lax.Precision.HIGHEST))
-    sel = s + bias.astype(jnp.float32)
+    sel = s if bias is None else s + bias.astype(jnp.float32)
+    if r.groups == 1:
+        return _combine(s, jax.lax.top_k(sel, r.per_token)[1], r)
     T = x.shape[0]
     per_group = r.experts // r.groups
     grouped = sel.reshape(T, r.groups, per_group)
@@ -52,19 +58,33 @@ def route(x, w_router, bias, r: Routing):
         jnp.arange(T)[:, None], kept].set(True)
     masked = jnp.where(jnp.repeat(group_ok, per_group, axis=1), sel,
                        -jnp.inf)
-    idx = jax.lax.top_k(masked, r.per_token)[1]
+    return _combine(s, jax.lax.top_k(masked, r.per_token)[1], r)
+
+
+def _combine(s, idx, r: Routing):
     w = jnp.take_along_axis(s, idx, axis=1)
     w = w / w.sum(-1, keepdims=True) * r.scaling
     return idx.astype(jnp.int32), w
 
 
-def swiglu(x, w_gu, w_down, dtype):
-    """``(silu(x W_g) ⊙ x W_u) W_down`` with ``W_gu = [W_g | W_u]``."""
+def silu_gate(g, u, _=None):
+    """SwiGLU's gate: ``silu(g) ⊙ u``. An activation is ``act(g, u,
+    params) -> [..., F]`` in float32; ``params`` are its own (None here)."""
+    return jax.nn.silu(g) * u
+
+
+def gated_mlp(x, w_gu, w_down, dtype, act, act_params=None):
+    """``act(x W_g, x W_u) W_down`` with ``W_gu = [W_g | W_u]``."""
     gu = jnp.dot(x.astype(dtype), w_gu.astype(dtype),
                  preferred_element_type=jnp.float32)
     g, u = jnp.split(gu, 2, axis=-1)
-    return jnp.dot((jax.nn.silu(g) * u).astype(dtype), w_down.astype(dtype),
+    return jnp.dot(act(g, u, act_params).astype(dtype), w_down.astype(dtype),
                    preferred_element_type=jnp.float32)
+
+
+def swiglu(x, w_gu, w_down, dtype):
+    """``(silu(x W_g) ⊙ x W_u) W_down``."""
+    return gated_mlp(x, w_gu, w_down, dtype, silu_gate)
 
 
 def held_slots(idx, first: int, held: int):
@@ -72,36 +92,62 @@ def held_slots(idx, first: int, held: int):
     return (idx >= first) & (idx < first + held)
 
 
-def held_part_dense(x, idx, w, e_gu, e_down, first: int, dtype):
-    """``x`` [T,D]; ``e_gu`` [E_held,D,2F]; ``e_down`` [E_held,F,D].
-    Every held expert on every token, combined with the routing weight
-    (zero where the token did not select it). Answers [T,D] f32."""
+def held_part_dense(x, idx, w, e_gu, e_down, first: int, dtype,
+                    act=silu_gate, act_params=None,
+                    expert_chunk: int | None = None):
+    """``x`` [T,D]; ``e_gu`` [E_held,D,2F]; ``e_down`` [E_held,F,D];
+    ``act_params`` None or [E_held,...]. Every held expert on every
+    token, combined with the routing weight (zero where the token did not
+    select it). ``expert_chunk``: that many experts at a time, so that the
+    float32 ``[E_held,T,2F]`` is never whole. Answers [T,D] f32."""
     held = e_gu.shape[0]
     local = idx - first                                          # [T,k]
     combine = jnp.where(
         held_slots(idx, first, held)[..., None]
         & (local[..., None] == jnp.arange(held)), w[..., None], 0.0
     ).sum(1)                                                     # [T,E_held]
-    gu = jnp.einsum("td,edf->etf", x.astype(dtype), e_gu.astype(dtype),
-                    preferred_element_type=jnp.float32)
-    g, u = jnp.split(gu, 2, axis=-1)
-    y = jnp.einsum("etf,efd->etd", (jax.nn.silu(g) * u).astype(dtype),
-                   e_down.astype(dtype), preferred_element_type=jnp.float32)
-    return jnp.einsum("etd,te->td", y, combine)
+
+    def part(e_gu, e_down, params, combine):
+        gu = jnp.einsum("td,edf->etf", x.astype(dtype), e_gu.astype(dtype),
+                        preferred_element_type=jnp.float32)
+        g, u = jnp.split(gu, 2, axis=-1)
+        if params is not None:
+            params = params[:, None]                 # over the tokens
+        y = jnp.einsum("etf,efd->etd", act(g, u, params).astype(dtype),
+                       e_down.astype(dtype),
+                       preferred_element_type=jnp.float32)
+        return jnp.einsum("etd,te->td", y, combine)
+
+    if expert_chunk is None or expert_chunk >= held:
+        return part(e_gu, e_down, act_params, combine)
+    chunks = jax.tree_util.tree_map(
+        lambda a: a.reshape(held // expert_chunk, expert_chunk,
+                            *a.shape[1:]),
+        (e_gu, e_down, act_params, combine.T))
+
+    def body(acc, chunk):
+        e_gu, e_down, params, combine_t = chunk
+        return acc + part(e_gu, e_down, params, combine_t.T), None
+
+    return jax.lax.scan(body, jnp.zeros(x.shape, jnp.float32), chunks)[0]
 
 
-def held_part_token(x, idx, w, e_gu, e_down, first: int, dtype):
+def held_part_token(x, idx, w, e_gu, e_down, first: int, dtype,
+                    act=silu_gate, act_params=None):
     """One token: ``x`` [D], ``idx``/``w`` [k]. A loop over the held
     experts this token selected, and over nothing else: an absent slot
     costs no read of any weight. Answers [D] f32."""
     held = held_slots(idx, first, e_gu.shape[0])
     order = jnp.argsort(~held, stable=True)        # held slots first
 
+    def one(a, e):
+        return jax.lax.dynamic_index_in_dim(a, e, 0, False)
+
     def body(j, acc):
         slot = order[j]
         e = idx[slot] - first
-        y = swiglu(x[None], jax.lax.dynamic_index_in_dim(e_gu, e, 0, False),
-                   jax.lax.dynamic_index_in_dim(e_down, e, 0, False), dtype)
+        y = gated_mlp(x[None], one(e_gu, e), one(e_down, e), dtype, act,
+                      None if act_params is None else one(act_params, e))
         return acc + w[slot] * y[0]
 
     return jax.lax.fori_loop(0, held.sum(), body,

@@ -80,6 +80,21 @@ LLM_EXPERT_SLOTS = REGISTRY.counter(
     "programs and fed from their outputs.",
     ("where", "phase"))
 
+LLM_CACHE_BYTES = REGISTRY.gauge(
+    "cdt_llm_cache_bytes",
+    "Bytes of one rewrite request's decode cache, by the kind of layer "
+    "that holds them: window (a ring of sliding_window rows a layer), "
+    "full (prompt + new rows a layer), recurrent (linear-attention states "
+    "and convolution tails). Set when a request's cache is made.",
+    ("layers",))
+
+LLM_STREAM_MIX = REGISTRY.counter(
+    "cdt_llm_stream_mix_total",
+    "Residual-stream mixes a language model ran (one Sinkhorn-normalised "
+    "n x n mix a sublayer a token: 2 x layers x tokens; 0 for a model "
+    "with one residual stream), by phase (prefill, decode).",
+    ("phase",))
+
 # --- attention kernel dispatch / autotune (ops/attention.py, ops/autotune.py)
 
 ATTN_KERNEL_SELECTED = REGISTRY.counter(
