@@ -26,6 +26,7 @@ from ..models.layers import timestep_embedding
 from ..models.unet import UNet2D, UNetConfig
 from ..models.vae import AutoencoderKL
 from ..parallel.rng import participant_key
+from ..parallel.sharding import mesh_cache_key, replicate
 from ..utils import constants
 from .guidance import cfg_denoiser, eps_denoiser
 from .samplers import sample
@@ -45,17 +46,6 @@ class GenerationSpec:
     guidance_scale: float = 5.0
     per_device_batch: int = 1
     denoise: float = 1.0           # <1.0: img2img partial ladder (tile engine)
-
-
-def mesh_cache_key(mesh: Mesh) -> tuple:
-    """Value key for a mesh: axis names + shape + device ids.
-
-    ``id(mesh)`` is wrong here — ids are recycled after GC, so a
-    long-lived controller could be handed a stale compiled fn for a
-    *different* mesh with a coincident id. Shared by every pipeline's
-    compile cache."""
-    return (tuple(mesh.axis_names), tuple(mesh.shape.values()),
-            tuple(d.id for d in mesh.devices.flat))
 
 
 def cached_build(holder, key, builder, max_entries: int = 8):
@@ -80,12 +70,22 @@ def cached_build(holder, key, builder, max_entries: int = 8):
 
 
 def bind_weights(jitted, weights, label: "str | None" = None,
-                 steps: "int | None" = None, name: "str | None" = None):
+                 steps: "int | None" = None, name: "str | None" = None,
+                 mesh: "Mesh | None" = None):
     """Wrap a jitted function whose LEADING argument is the weight pytree:
     the returned callable supplies it automatically, while ``.jitted`` /
     ``.weights`` expose the raw jit object for AOT use
     (``bench.py``: ``fn.jitted.lower(fn.weights, *args)``). One shared
     definition — every pipeline factory returns this shape.
+
+    ``mesh``: the mesh of a program that reads ``weights`` replicated
+    (``shard_map`` with the weights under ``P()``). The tree is placed on
+    it HERE, once, by ``parallel/sharding.replicate`` — which hands every
+    program of one mesh the same placed leaves, and on a one-device mesh
+    the caller's own — so that no call reshards it: leaves that live on
+    one chip, handed to a four-chip program as they are, are copied to
+    the other three at EVERY call and dropped after it. ``.weights`` is
+    the placed tree, and it lives as long as the wrapper does.
 
     Every call's LAUNCH is timed: the host time inside ``jitted(...)``
     until JAX hands back the not-yet-ready result, as a ``program.launch``
@@ -103,6 +103,8 @@ def bind_weights(jitted, weights, label: "str | None" = None,
     is exactly the old one-liner."""
     from ..telemetry import enabled as _tm_enabled
 
+    if mesh is not None:
+        weights = replicate(mesh, weights)
     state = {"first": True}
     series = label or name or "unnamed"
 
@@ -455,7 +457,7 @@ class Txt2ImgPipeline:
         weights = self._weights()
 
         return bind_weights(jitted, weights, label="txt2img",
-                            steps=len(sigmas) - 1)
+                            steps=len(sigmas) - 1, mesh=mesh)
 
     def img2img_fn(self, mesh: Mesh, spec: GenerationSpec,
                    axis: str = constants.AXIS_DATA,
@@ -529,7 +531,7 @@ class Txt2ImgPipeline:
         weights = self._weights(img2img=True)
 
         return bind_weights(jitted, weights, label="img2img",
-                            steps=len(sigmas) - 1)
+                            steps=len(sigmas) - 1, mesh=mesh)
 
     def img2img(
         self,
@@ -651,16 +653,17 @@ class Txt2ImgPipeline:
         every step applies the same closure at the same global index
         (tested: ``tests/test_checkpoint.py``,
         ``tests/test_preemption.py``)."""
+        return cached_build(
+            self, ("segments", mesh_cache_key(mesh), spec, axis),
+            lambda: self._build_preemptible(mesh, spec, axis),
+            self._CACHE_MAX)
+
+    def _build_preemptible(self, mesh: Mesh, spec: GenerationSpec,
+                           axis: str) -> dict:
+        """What :meth:`preemptible_fns` caches, one per (mesh, spec)."""
         from .progress import DenoiserTap
         from .samplers import carry_structure, extract_output, make_program
         from .samplers import run_segment as _run_segment
-
-        key_cache = (mesh_cache_key(mesh), spec, axis)
-        if not hasattr(self, "_preempt_cache"):
-            self._preempt_cache: "dict[tuple, Any]" = {}
-        bundle = self._preempt_cache.get(key_cache)
-        if bundle is not None:
-            return bundle
 
         has_y = self.unet.config.adm_in_channels > 0
         sigmas = make_sigma_ladder(spec, self.schedule)
@@ -703,7 +706,8 @@ class Txt2ImgPipeline:
 
         prep = bind_weights(jax.jit(shard_map(
             prep_body, mesh=mesh, in_specs=base_specs,
-            out_specs=carry_specs)), weights, name="txt2img_prep")
+            out_specs=carry_specs)), weights, name="txt2img_prep",
+            mesh=mesh)
 
         def make_seg(length: int, with_token: bool):
             if with_token:
@@ -737,7 +741,7 @@ class Txt2ImgPipeline:
             return bind_weights(jax.jit(shard_map(
                 seg_body, mesh=mesh, in_specs=in_specs,
                 out_specs=out_specs)), weights,
-                label="txt2img_seg", steps=length)
+                label="txt2img_seg", steps=length, mesh=mesh)
 
         def fin_body(weights, carry):
             x0 = extract_output(spec.sampler, tuple(carry))
@@ -746,7 +750,7 @@ class Txt2ImgPipeline:
         fin = bind_weights(jax.jit(shard_map(
             fin_body, mesh=mesh, in_specs=(P(), carry_specs),
             out_specs=P(axis, None, None, None))), weights,
-            name="txt2img_fin")
+            name="txt2img_fin", mesh=mesh)
 
         segs: "dict[tuple, Any]" = {}
 
@@ -762,12 +766,8 @@ class Txt2ImgPipeline:
             (n_dp * B,) + tuple(leaf.shape[1:])
             if tuple(leaf.shape) == x_shape else tuple(leaf.shape)
             for leaf in carry_struct)
-        bundle = {"prep": prep, "seg": seg, "fin": fin, "n_steps": n,
-                  "carry_shapes": global_shapes}
-        if len(self._preempt_cache) >= self._CACHE_MAX:
-            self._preempt_cache.pop(next(iter(self._preempt_cache)))
-        self._preempt_cache[key_cache] = bundle
-        return bundle
+        return {"prep": prep, "seg": seg, "fin": fin, "n_steps": n,
+                "carry_shapes": global_shapes}
 
     def checkpoint_identity(self, mesh: Mesh, spec: GenerationSpec,
                             seed: int,
@@ -940,7 +940,7 @@ class Txt2ImgPipeline:
                       out_specs=P(axis, None, None, None))
         return bind_weights(jax.jit(f), self._weights(),
                             label="txt2img_near",
-                            steps=len(sigmas) - 1)
+                            steps=len(sigmas) - 1, mesh=mesh)
 
     def generate_near(
         self,
@@ -1027,7 +1027,8 @@ class Txt2ImgPipeline:
             out_specs=P(axis, None, None, None),
         )
         return bind_weights(jax.jit(f), self._weights(),
-                            label="txt2img_mb", steps=len(sigmas) - 1)
+                            label="txt2img_mb", steps=len(sigmas) - 1,
+                            mesh=mesh)
 
     def microbatch_tp_fn(self, mesh: Mesh, spec: GenerationSpec,
                          n_requests: int,
@@ -1250,7 +1251,8 @@ class Txt2ImgPipeline:
             out_specs=P(axis, None, None, None),
         )
         return bind_weights(jax.jit(f), {"unet": self.unet_params},
-                            label="txt2img_lat", steps=len(sigmas) - 1)
+                            label="txt2img_lat", steps=len(sigmas) - 1,
+                            mesh=mesh)
 
     def latent_microbatch_tp_fn(self, mesh: Mesh, spec: GenerationSpec,
                                 n_requests: int,
@@ -1354,7 +1356,7 @@ class Txt2ImgPipeline:
             out_specs=P(axis, None, None, None),
         )
         return bind_weights(jax.jit(f), {"vae_dec": self.vae.dec_params},
-                            label="vae_decode_batch")
+                            label="vae_decode_batch", mesh=mesh)
 
     def decode_latents(self, mesh: Mesh, latents: "list",
                        per_device_batch: "int | None" = None) -> "list":

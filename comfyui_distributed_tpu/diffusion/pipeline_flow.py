@@ -202,7 +202,7 @@ class FlowPipeline:
         weights = self._weights()
 
         return bind_weights(jitted, weights, label="flow_dp",
-                            steps=spec.steps)
+                            steps=spec.steps, mesh=mesh)
 
     _CACHE_MAX = 8
 
@@ -336,7 +336,7 @@ class FlowPipeline:
 
             cast = (bind_weights(jax.jit(shard_map(
                 flow_cast_body, mesh=mesh, in_specs=(P(),), out_specs=P())),
-                weights, name="flow_cast")
+                weights, name="flow_cast", mesh=mesh)
                 if any(to is not None for to in read_as) else None)
 
             def program(weights, key, context, pooled, *uncond):
@@ -354,7 +354,8 @@ class FlowPipeline:
 
             prep = bind_weights(jax.jit(shard_map(
                 flow_prep_body, mesh=mesh, in_specs=base_specs,
-                out_specs=carry_specs)), weights, name="flow_prep")
+                out_specs=carry_specs)), weights, name="flow_prep",
+                mesh=mesh)
 
             def make_seg(length: int):
                 def flow_seg_body(weights, *args):
@@ -368,7 +369,7 @@ class FlowPipeline:
                     flow_seg_body, mesh=mesh,
                     in_specs=base_specs + (P(), carry_specs, P()),
                     out_specs=(carry_specs, P(), latent))), weights,
-                    label="flow_dp", steps=length)
+                    label="flow_dp", steps=length, mesh=mesh)
 
             def flow_fin_body(weights, carry):
                 return self._decode_latent(
@@ -377,7 +378,7 @@ class FlowPipeline:
             fin = bind_weights(jax.jit(shard_map(
                 flow_fin_body, mesh=mesh, in_specs=(P(), carry_specs),
                 out_specs=P(axis, None, None, None))), weights,
-                name="flow_fin")
+                name="flow_fin", mesh=mesh)
 
             segs: dict = {}
 
@@ -649,7 +650,7 @@ class FlowPipeline:
         weights = self._weights()
 
         return bind_weights(jitted, weights, label="flow_sp",
-                            steps=spec.steps)
+                            steps=spec.steps, mesh=mesh)
 
     def generate_sp(self, mesh: Mesh, spec: FlowSpec, seed: int,
                     context: jax.Array, pooled: jax.Array,

@@ -224,7 +224,7 @@ class VideoPipeline:
         weights = self._weights()
 
         return bind_weights(jitted, weights, label="video_dp",
-                            steps=spec.steps)
+                            steps=spec.steps, mesh=mesh)
 
     _CACHE_MAX = 4
 
@@ -549,7 +549,7 @@ class VideoPipeline:
         weights = self._weights()
 
         return bind_weights(jitted, weights, label="video_i2v",
-                            steps=spec.steps)
+                            steps=spec.steps, mesh=mesh)
 
     def generate_i2v(self, mesh: Mesh, spec: VideoSpec, seed: int,
                      image: jax.Array, context: jax.Array,
@@ -628,7 +628,7 @@ class VideoPipeline:
         weights = self._weights()
 
         return bind_weights(jitted, weights, label="video_i2v_sp",
-                            steps=spec.steps)
+                            steps=spec.steps, mesh=mesh)
 
     def generate_frames_fn(self, mesh: Mesh, spec: VideoSpec,
                            axis: str = constants.AXIS_SEQUENCE,
@@ -680,4 +680,4 @@ class VideoPipeline:
         weights = self._weights()
 
         return bind_weights(jitted, weights, label="video_sp",
-                            steps=spec.steps)
+                            steps=spec.steps, mesh=mesh)

@@ -706,14 +706,19 @@ class ModelBundle:
         reused (residency-planner eviction, ``cluster/residency.py``):
         offload executors' stacked/resident blocks are freed explicitly
         (``diffusion/offload.release_store``), and every pipeline compile
-        cache is cleared so no jitted closure keeps device arrays alive.
+        cache is cleared so no jitted closure keeps device arrays alive —
+        the weights' placed copies on a mesh's other chips among them:
+        they live only as long as a program bound to them
+        (``parallel/sharding.replicate``).
         Host-side params (numpy/orbax trees) survive — re-acquiring the
         bundle re-uploads, it does not re-convert."""
         from ..diffusion.offload import release_store
 
-        for cache_name in ("_fn_cache", "_i2i_cache", "_control_clones"):
-            cache = getattr(self.pipeline, cache_name, None)
-            if not isinstance(cache, dict):
+        # every program cache a pipeline keeps is a dict attribute named
+        # *_cache (or _control_clones): found by that, not by a list
+        for name, cache in vars(self.pipeline).items():
+            if not (isinstance(cache, dict)
+                    and name.endswith(("_cache", "_clones"))):
                 continue
             for v in cache.values():
                 if hasattr(v, "stacked") and hasattr(v, "resident"):

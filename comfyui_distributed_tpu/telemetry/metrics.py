@@ -63,6 +63,22 @@ PROGRESS_EVENTS = REGISTRY.counter(
     "read by the host at the segment's end).",
     ("source",))
 
+WEIGHT_PLACEMENT = REGISTRY.counter(
+    "cdt_weight_placement_total",
+    "Weight trees bound to a mesh program (parallel/sharding.replicate, "
+    "one count a tree), by outcome: placed (some leaf was copied to the "
+    "mesh's devices, under a weights.place span), reused (every leaf to "
+    "place was already held for a live program of that mesh) or identity "
+    "(every leaf already had the mesh's replicated sharding: a one-chip "
+    "host counts nothing else).",
+    ("outcome",))
+
+WEIGHT_PLACEMENT_BYTES = REGISTRY.counter(
+    "cdt_weight_placement_bytes_total",
+    "Bytes of weight leaves copied by placements, summed over the devices "
+    "that received a copy. Grows when a program is built, never when one "
+    "is called.")
+
 # --- the prompt rewriter (graph/nodes_builtin.py: TPUPromptRewrite) ----------
 
 LLM_TOKENS = REGISTRY.counter(

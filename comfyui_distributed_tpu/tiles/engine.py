@@ -240,7 +240,7 @@ class TileUpscaler:
         jitted = jax.jit(run)
         weights = self.pipeline._weights(img2img=True)
 
-        return bind_weights(jitted, weights)
+        return bind_weights(jitted, weights, mesh=mesh)
 
     def upscale(
         self,
@@ -418,7 +418,8 @@ class TileUpscaler:
                       P(None, None, None), P(None, None), P(None, None)),
             out_specs=P(axis, None, None, None),
         ))
-        sharded = bind_weights(jitted, self.pipeline._weights(img2img=True))
+        sharded = bind_weights(jitted, self.pipeline._weights(img2img=True),
+                               mesh=mesh)
         key = jax.random.key(seed)
 
         def run_one(start: int, end: int):

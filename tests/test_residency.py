@@ -183,6 +183,43 @@ class TestRegistryIntegration:
         assert leaf.deleted
         assert bundle.pipeline._fn_cache == {}
 
+    def test_release_device_drops_served_programs_and_placed_copies(self):
+        """An evicted bundle keeps no bound program and no copy of its
+        weights on a mesh's other chips; acquired again it places once
+        more (PR 31)."""
+        import gc
+        import weakref
+
+        import jax
+
+        from comfyui_distributed_tpu.diffusion.pipeline import GenerationSpec
+        from comfyui_distributed_tpu.parallel import build_mesh
+        from comfyui_distributed_tpu.telemetry import metrics as tm
+
+        placed = tm.WEIGHT_PLACEMENT.labels(outcome="placed")
+        bundle = ModelRegistry().get("tiny")
+        pipe = bundle.pipeline
+        mesh = build_mesh({"dp": 4}, devices=jax.devices()[:4])
+        spec = GenerationSpec(height=16, width=16, steps=2)
+        start = placed.value
+        fns = pipe.preemptible_fns(mesh, spec)
+        fns["seg"](2)
+        # a cache release_device did not clear before; shares the VAE's leaves
+        pipe._dec_cache = {"decode": pipe.decode_fn(mesh, 1)}
+        assert placed.value == start + 1
+        watched = [weakref.ref(leaf)
+                   for leaf in jax.tree.leaves(fns["prep"].weights)]
+        assert all(len(ref().devices()) == 4 for ref in watched)
+        del fns
+        bundle.release_device()
+        gc.collect()
+        assert not any(cache for name, cache in vars(pipe).items()
+                       if name.endswith(("_cache", "_clones")))
+        assert not hasattr(pipe, "_preempt_cache")
+        assert all(ref() is None for ref in watched)
+        pipe.preemptible_fns(mesh, spec)
+        assert placed.value == start + 2
+
 
 class TestLoRAHotPatch:
     def test_request_pins_base_and_patches_a_clone(self):
