@@ -71,7 +71,8 @@ def cached_build(holder, key, builder, max_entries: int = 8):
 
 def bind_weights(jitted, weights, label: "str | None" = None,
                  steps: "int | None" = None, name: "str | None" = None,
-                 mesh: "Mesh | None" = None):
+                 mesh: "Mesh | None" = None,
+                 span_attrs: "dict | None" = None):
     """Wrap a jitted function whose LEADING argument is the weight pytree:
     the returned callable supplies it automatically, while ``.jitted`` /
     ``.weights`` expose the raw jit object for AOT use
@@ -99,8 +100,9 @@ def bind_weights(jitted, weights, label: "str | None" = None,
     ``cdt_pipeline_compile_seconds{pipeline=label}`` on the first call
     (which pays trace + XLA compile) vs ``cdt_pipeline_execute_seconds``
     after; with ``steps`` the per-step quotient also lands in
-    ``cdt_sampler_step_seconds``. With telemetry disabled the call path
-    is exactly the old one-liner."""
+    ``cdt_sampler_step_seconds``. ``span_attrs`` are further attributes of
+    the ``pipeline_call`` span (a chunked prefill's ``chunk``). With
+    telemetry disabled the call path is exactly the old one-liner."""
     from ..telemetry import enabled as _tm_enabled
 
     if mesh is not None:
@@ -123,7 +125,7 @@ def bind_weights(jitted, weights, label: "str | None" = None,
                 return jitted(weights, *args, **kw)
         # step-time telemetry only: never feeds the program or keys
         t0 = time.perf_counter()  # cdtlint: disable=D001
-        with span("pipeline_call", pipeline=label):
+        with span("pipeline_call", pipeline=label, **(span_attrs or {})):
             with launch:
                 out = jitted(weights, *args, **kw)
             with span("program.wait", pipeline=label):

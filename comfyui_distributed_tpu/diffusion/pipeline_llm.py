@@ -15,7 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.llm_model import cache_bytes
+from ..models.llm_model import cache_bytes, chunked_prefill
+from ..ops.expert_share import prefill_form
 from .pipeline import bind_weights, cached_build
 from .samplers import run_segment, token_program
 
@@ -36,9 +37,32 @@ class LLMPipeline:
         return self.model.decode_step(self.config, weights, state, token,
                                       pos)
 
+    def prefill_plan(self, prompt_tokens: int) -> tuple[int, int, str]:
+        """``(chunk, chunks, form)``: how ``llm_prefill`` walks a prompt of
+        this length (whole: one chunk) and the form its expert layers
+        take for the rows a call of them sees."""
+        cfg = self.config
+        if self.model.prefill_chunk is None:
+            return prompt_tokens, 1, prefill_form(prompt_tokens, cfg.routing)
+        chunk = min(cfg.prefill_chunk_tokens, prompt_tokens)
+        return chunk, -(-prompt_tokens // chunk), prefill_form(
+            chunk, cfg.routing, cfg.expert_tile)
+
     def prefill_fn(self, prompt_tokens: int, new_tokens: int):
-        """``(ids [prompt_tokens]) -> (last logits [V], cache, held)``."""
+        """``(ids [prompt_tokens]) -> (last logits [V], cache, held)``; a
+        model that gives ``prefill_chunk`` is walked through the cache in
+        chunks inside this one program, and also answers the rows its
+        expert layers multiplied."""
         cfg, max_len = self.config, prompt_tokens + new_tokens
+        if self.model.prefill_chunk is not None:
+            # the continuation, scanned: (..., cache, held, rows multiplied)
+            def llm_prefill(weights, ids):
+                return chunked_prefill(self.model, cfg, weights, ids,
+                                       max_len)
+
+            return bind_weights(
+                jax.jit(llm_prefill), self.params, label="llm_prefill",
+                span_attrs={"chunk": self.prefill_plan(prompt_tokens)[0]})
         prefill = self.model.prefill
 
         def llm_prefill(weights, ids):
@@ -47,10 +71,13 @@ class LLMPipeline:
         return bind_weights(jax.jit(llm_prefill), self.params,
                             label="llm_prefill")
 
-    def decode_fn(self, prompt_tokens: int, new_tokens: int):
+    def decode_fn(self, prompt_tokens: int, new_tokens: int,
+                  tap_every: int = TAP_EVERY):
         """``(logits, cache, key, temperature) -> (ids [new_tokens], tap
-        logits [new_tokens // TAP_EVERY, V], held slots per expert layer,
-        finite)``: ``new_tokens`` steps of the token program in one scan."""
+        logits [new_tokens // tap_every, V], held slots per expert layer,
+        finite)``: ``new_tokens`` steps of the token program in one scan.
+        ``tap_every`` is the served program's unless a parity tool builds
+        a decode of its own to compare more rows."""
         n_counts = len(self.config.moe_layers)
 
         def llm_decode(weights, logits, cache, key, temperature):
@@ -58,7 +85,7 @@ class LLMPipeline:
                 return self.step(weights, state, token, prompt_tokens + i)
 
             prog = token_program(forward, new_tokens, key, temperature,
-                                 TAP_EVERY, n_counts)
+                                 tap_every, n_counts)
             carry = run_segment(prog, prog.init((logits, cache)), 0,
                                 new_tokens)
             return prog.extract(carry), carry[3], carry[4], carry[5]
@@ -78,7 +105,13 @@ class LLMPipeline:
         ids and what the programs say of themselves, fetched to the host
         (the tap logits stay on the device)."""
         prefill, decode = self.programs(len(ids), int(new_tokens))
-        logits, cache, held_prefill = prefill(jnp.asarray(ids, jnp.int32))
+        _, chunks, form = self.prefill_plan(len(ids))
+        logits, cache, held_prefill, *rows = prefill(
+            jnp.asarray(ids, jnp.int32))
+        # a chunked prefill counts the rows its experts multiplied; the
+        # whole-prompt form multiplies every held expert by every token
+        rows = int(np.asarray(rows[0]).sum()) if rows else (
+            len(ids) * self.config.num_experts * len(self.config.moe_layers))
         out, taps, held_decode, finite = decode(
             logits, cache, jax.random.key(int(seed)),
             jnp.asarray(temperature, jnp.float32))
@@ -89,4 +122,6 @@ class LLMPipeline:
                                            len(ids) + int(new_tokens)),
                 "tap_logits": taps, "finite": bool(finite),
                 "held_prefill": np.asarray(held_prefill),
-                "held_decode": np.asarray(held_decode)}
+                "held_decode": np.asarray(held_decode),
+                "prefill_chunks": chunks, "prefill_form": form,
+                "rows_prefill": rows}

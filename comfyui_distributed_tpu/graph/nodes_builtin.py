@@ -23,6 +23,7 @@ AUDIO = {"waveform": [B,C,S], "sample_rate": int}; CONDITIONING =
 
 from __future__ import annotations
 
+import functools
 import json
 from pathlib import Path
 from typing import Any, Optional
@@ -947,11 +948,19 @@ def rewrite_prompt_ids(text: str, prompt_tokens: int, vocab: int) -> list:
     from ..models.text import _stable_hash_token
 
     user = [_stable_hash_token(w, vocab)
-            for w in str(text).lower().split()][:max(1, prompt_tokens // 8)]
-    words = REWRITE_PREAMBLE.split()
-    lead = [_stable_hash_token(words[i % len(words)], vocab)
-            for i in range(prompt_tokens - len(user))]
-    return lead + user
+            for w in str(text).lower().split()[:max(1, prompt_tokens // 8)]]
+    lead = _preamble_ids(vocab)
+    n = prompt_tokens - len(user)
+    return (lead * (n // len(lead) + 1))[:n] + user
+
+
+@functools.lru_cache(maxsize=8)
+def _preamble_ids(vocab: int) -> list:
+    """The preamble's words hashed once a vocabulary: a 32 k-token prompt
+    cycles these ids, it does not hash 32 k words a request."""
+    from ..models.text import _stable_hash_token
+
+    return [_stable_hash_token(w, vocab) for w in REWRITE_PREAMBLE.split()]
 
 
 @register_node("TPUPromptRewrite")
@@ -989,6 +998,12 @@ class TPUPromptRewrite(NodeDef):
         if _tm_enabled():
             for kind, size in out["cache_bytes"].items():
                 _tm.LLM_CACHE_BYTES.labels(layers=kind).set(float(size))
+            _tm.LLM_CACHE_POSITIONS.set(float(prompt_tokens + new_tokens))
+            _tm.LLM_PREFILL_CHUNKS.inc(out["prefill_chunks"])
+            _tm.LLM_EXPERT_ROWS.labels(form=out["prefill_form"]).inc(
+                out["rows_prefill"])
+            _tm.LLM_EXPERT_ROWS.labels(form="token").inc(
+                int(out["held_decode"].sum()))
             for phase, tokens in (("prefill", prompt_tokens),
                                   ("decode", new_tokens)):
                 held = int(out[f"held_{phase}"].sum())

@@ -411,9 +411,11 @@ def prefill(cfg: LLMConfig, params, ids, max_len: int,
             m = layer["moe"]
             idx, w = expert_share.route(x, m["w_router"], m["router_bias"],
                                         cfg.routing)
-            h = h + expert_share.held_part_dense(
+            # the form the rows call for: dense-masked at a few hundred
+            y, _ = expert_share.held_part(
                 x, idx, w, m["e_gu"], m["e_down"], cfg.first_expert, dtype,
-                _ACT) + _swiglu(x, m["shared"], dtype)
+                cfg.routing, _ACT)
+            h = h + y + _swiglu(x, m["shared"], dtype)
             held.append(_count_held(cfg, idx))
         else:
             h = h + _swiglu(x, layer["ffn"], dtype)

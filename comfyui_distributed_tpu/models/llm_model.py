@@ -19,6 +19,12 @@ class LLMModel(NamedTuple):
     #                         (logits [V], cache, held [expert layers])
     empty_cache: Callable   # (cfg, max_len) -> the decode carry's state
     cache_kinds: Callable   # (cfg, cache) -> {kind of layer: its leaves}
+    # the prefill's continuation, for a model whose prompt is walked in
+    # chunks through the cache (None: ``prefill`` takes it whole):
+    # (cfg, weights, cache, ids [C], start, n_valid, all_logits=False) ->
+    # (logits, cache, held [expert layers], rows [expert layers]); the
+    # chunk's length is ``cfg.prefill_chunk_tokens``
+    prefill_chunk: "Callable | None" = None
 
 
 def cache_bytes(model: LLMModel, cfg, max_len: int) -> dict:
@@ -30,3 +36,36 @@ def cache_bytes(model: LLMModel, cfg, max_len: int) -> dict:
     return {kind: sum(a.size * a.dtype.itemsize
                       for a in jax.tree_util.tree_leaves(leaves))
             for kind, leaves in model.cache_kinds(cfg, cache).items()}
+
+
+def chunked_prefill(model: LLMModel, cfg, weights, ids, max_len: int,
+                    all_logits: bool = False, chunk: "int | None" = None,
+                    **kw):
+    """A prompt ``ids`` [T] through ``model.prefill_chunk``, ``chunk``
+    tokens (``cfg.prefill_chunk_tokens``) at a time: ONE scan whose carry
+    is the cache, so nothing the size of the prompt exists but the cache
+    and the ids. The last chunk is padded (the cache has rows for it).
+    Answers ``(logits, cache, held, rows)``: the last position's
+    logits [V] (every position's [T,V] with ``all_logits``), held slots
+    and rows multiplied per expert layer summed over the chunks."""
+    import jax
+    import jax.numpy as jnp
+
+    T = ids.shape[0]
+    chunk = min(chunk or cfg.prefill_chunk_tokens, T)
+    n = -(-T // chunk)
+    cache = model.empty_cache(cfg, max(max_len, n * chunk))
+    padded = jnp.pad(ids, (0, n * chunk - T)).reshape(n, chunk)
+
+    def body(cache, xs):
+        i, chunk_ids = xs
+        start = i * chunk
+        logits, cache, held, rows = model.prefill_chunk(
+            cfg, weights, cache, chunk_ids, start,
+            jnp.minimum(chunk, T - start), all_logits, **kw)
+        return cache, (logits, held, rows)
+
+    cache, (logits, held, rows) = jax.lax.scan(
+        body, cache, (jnp.arange(n), padded))
+    logits = logits.reshape(n * chunk, -1)[:T] if all_logits else logits[-1]
+    return logits, cache, held.sum(0), rows.sum(0)

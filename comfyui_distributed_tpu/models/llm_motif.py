@@ -379,9 +379,9 @@ def prefill(cfg: MotifConfig, params, ids, max_len: int,
             def experts(x):
                 idx, w = expert_share.route(x, m["w_router"], None,
                                             cfg.routing)
-                y = expert_share.held_part_dense(
+                y, _ = expert_share.held_part(
                     x, idx, w, m["e_gu"], m["e_down"], cfg.first_expert,
-                    dtype, poly_norm_gate(cfg), m["e_poly"],
+                    dtype, cfg.routing, poly_norm_gate(cfg), m["e_poly"],
                     expert_chunk=math.gcd(cfg.num_experts, 8))
                 return y + _mlp(cfg, x, m["shared"], dtype), idx
 

@@ -45,7 +45,8 @@ class ModelPreset:
     # chip; held in bfloat16 it is 4.79 + 0.56 (docs/weights.md). The tiny
     # test presets stay float32: their parity tolerances are float32's.
     param_dtype: "str | None" = None
-    # config of a language model (models/llm_hybrid.py, llm_motif.py; its
+    # config of a language model (models/llm_hybrid.py, llm_motif.py,
+    # llm_kimi.py; its
     # ``.model`` gives the functions): such a preset has no denoiser, VAE
     # or text tower, and is loaded by LLMLoader
     llm: "object | None" = None
@@ -213,6 +214,10 @@ def _llm_preset(name: str, family: str, tiny: bool = False):
         from .llm_motif import MotifConfig
 
         share = MotifConfig.tiny if tiny else MotifConfig.motif_share
+    elif family == "kimi":
+        from .llm_kimi import KimiConfig
+
+        share = KimiConfig.tiny if tiny else KimiConfig.kimi_share
     else:
         from .llm_hybrid import LLMConfig
 
@@ -247,6 +252,8 @@ PRESETS: dict[str, ModelPreset] = {
     "ling-tiny": _llm_preset("ling-tiny", "hybrid", tiny=True),
     "motif-3-beta": _llm_preset("motif-3-beta", "motif"),
     "motif-tiny": _llm_preset("motif-tiny", "motif", tiny=True),
+    "kimi-k2.6": _llm_preset("kimi-k2.6", "kimi"),
+    "kimi-tiny": _llm_preset("kimi-tiny", "kimi", tiny=True),
 }
 
 

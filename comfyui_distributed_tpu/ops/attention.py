@@ -110,6 +110,22 @@ def _note_selection(geometry: str, choice,
         pass
 
 
+def note_latent_causal(num_heads: int, qk_dim: int, q_len: int, kv_len: int,
+                       dtype, block_q: int, block_k: int) -> None:
+    """The blocked causal latent-attention kernel reports itself as the
+    tiers do — the server log's ``attention:`` line and
+    ``cdt_attn_kernel_selected`` — though nothing here chooses it: a
+    chunked prefill over a latent cache has one kernel on a TPU
+    (``latent_attention.mla_chunk_attention``)."""
+    from .autotune import GeometryKey, KernelChoice
+
+    _note_selection(
+        GeometryKey.from_shape(num_heads, qk_dim, q_len, kv_len,
+                               dtype).key_str(),
+        KernelChoice("latent_causal", block_q, block_k,
+                     reason="chunked prefill over a latent cache"))
+
+
 def _with_packed_blocks(choice, q_len: int, kv_len: int, head_dim: int,
                         dtype):
     """A packed choice with the blocks its call will run: what the table

@@ -56,6 +56,10 @@ from ..utils.logging import debug_log, log
 
 TABLE_VERSION = 1
 TIERS = ("fused", "packed", "bh", "xla")
+# a kernel that is no tier of the bidirectional dispatch (no table row, no
+# policy arm chooses it) but reports itself the same way: the blocked causal
+# latent-attention kernel of a chunked prefill (ops/flash_latent.py)
+REPORTED_TIERS = TIERS + ("latent_causal",)
 
 # the in-repo resolved table for the known model zoo
 _SHIPPED_PATH = Path(__file__).resolve().parent / "attn_table_default.json"
@@ -156,9 +160,9 @@ class KernelChoice:
     reason: str = ""
 
     def __post_init__(self):
-        if self.tier not in TIERS:
+        if self.tier not in REPORTED_TIERS:
             raise ValueError(f"unknown kernel tier {self.tier!r}; "
-                             f"have {TIERS}")
+                             f"have {REPORTED_TIERS}")
 
     def to_dict(self) -> dict:
         d = {"tier": self.tier}

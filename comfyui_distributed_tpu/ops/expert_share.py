@@ -15,10 +15,18 @@ float32. The experts are gated MLPs ``(act(x W_g, x W_u)) W_down``; the
 model passes its activation (``silu_gate`` here; a model may bring its own,
 with per-expert parameters).
 
-Two forms of the experts' part: :func:`held_part_dense` applies every held
-expert to every token and masks (prefill: a few hundred tokens keep the
-matrix units busy), :func:`held_part_token` reads only the held experts
-one token selected (decode: the bytes are the cost).
+Three forms of the experts' part. :func:`held_part_dense` applies every
+held expert to every token and masks (a prefill of a few hundred tokens: one
+large product keeps the matrix units busy, and a held expert expects so few
+rows that gathering them would multiply mostly padding).
+:func:`held_part_grouped` gathers the rows routed to each held expert in
+expert order and multiplies them in tiles of :data:`GROUP_TILE` rows, a
+tile against one expert (a prefill of thousands of tokens: the dense form
+would multiply ``experts / per_token`` times the routed rows).
+:func:`held_part_token` reads only the held experts one token selected
+(decode: the bytes are the cost). A prefill takes the form
+:func:`prefill_form` names from the rows it sees — one rule, no flag —
+through :func:`held_part`.
 """
 
 from __future__ import annotations
@@ -130,6 +138,80 @@ def held_part_dense(x, idx, w, e_gu, e_down, first: int, dtype,
         return acc + part(e_gu, e_down, params, combine_t.T), None
 
     return jax.lax.scan(body, jnp.zeros(x.shape, jnp.float32), chunks)[0]
+
+
+GROUP_TILE = 128      # rows of one grouped product: the MXU's height
+
+
+def prefill_form(rows: int, r: Routing, tile: int = GROUP_TILE) -> str:
+    """``grouped`` where a held expert expects (``rows · per_token /
+    experts``, routing even) at least half a tile of rows, ``dense``
+    below that: there most of every tile would be padding, and each of
+    them still reads its expert's weights."""
+    return "grouped" if 2 * rows * r.per_token >= tile * r.experts \
+        else "dense"
+
+
+def held_part(x, idx, w, e_gu, e_down, first: int, dtype, r: Routing,
+              act=silu_gate, act_params=None, expert_chunk: int | None = None,
+              valid=None, tile: int = GROUP_TILE):
+    """A prefill's held part in the form :func:`prefill_form` names for
+    its ``x.shape[0]`` rows. Answers ``(y [T,D] f32, rows multiplied)``:
+    the second is what the form costs (``E_held · T`` dense, the tiles'
+    rows grouped), beside the held slots it was needed for."""
+    if prefill_form(x.shape[0], r, tile) == "grouped":
+        return held_part_grouped(x, idx, w, e_gu, e_down, first, dtype, act,
+                                 act_params, valid, tile)
+    if valid is not None:
+        w = jnp.where(valid[:, None], w, 0.0)
+    y = held_part_dense(x, idx, w, e_gu, e_down, first, dtype, act,
+                        act_params, expert_chunk)
+    return y, jnp.asarray(e_gu.shape[0] * x.shape[0], jnp.int32)
+
+
+def held_part_grouped(x, idx, w, e_gu, e_down, first: int, dtype,
+                      act=silu_gate, act_params=None, valid=None,
+                      tile: int = GROUP_TILE):
+    """What :func:`held_part_dense` computes, by group: the routed slots
+    that fell on held experts (of rows ``valid`` says are real; None: all)
+    are put in expert order, each expert's rows are cut into tiles of
+    ``tile`` rows (its last one padded, an expert with no rows has none)
+    and a loop walks exactly the tiles there are: gather the tile's rows
+    of ``x``, one gated MLP against that expert's weights, scatter-add
+    times the routing weight. Shapes are static (a tile), the count of
+    tiles is the data's: no slot is ever dropped, whatever the skew.
+    Answers ``([T,D] f32, rows multiplied = tiles · tile)``."""
+    T, k = idx.shape
+    E = e_gu.shape[0]
+    held = held_slots(idx, first, E)
+    if valid is not None:
+        held &= valid[:, None]
+    local = jnp.where(held, idx - first, E).reshape(-1)      # E: not ours
+    token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+    weight = w.reshape(-1)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    counts = (local[:, None] == jnp.arange(E)).sum(0).astype(jnp.int32)
+    tiles = (counts + tile - 1) // tile
+    tile_end, row_end = jnp.cumsum(tiles), jnp.cumsum(counts)
+
+    def one(a, e):
+        return jax.lax.dynamic_index_in_dim(a, e, 0, False)
+
+    def body(t, out):
+        e = (t >= tile_end).sum().astype(jnp.int32)     # the tile's expert
+        at = (t - (tile_end[e] - tiles[e])) * tile + jnp.arange(tile)
+        real = at < counts[e]
+        slot = order[jnp.clip(row_end[e] - counts[e] + at, 0, T * k - 1)]
+        rows = token[slot]
+        y = gated_mlp(x[rows], one(e_gu, e), one(e_down, e), dtype, act,
+                      None if act_params is None else one(act_params, e))
+        return out.at[rows].add(
+            jnp.where(real, weight[slot], 0.0)[:, None] * y)
+
+    n_tiles = tile_end[-1]
+    out = jax.lax.fori_loop(0, n_tiles, body,
+                            jnp.zeros(x.shape, jnp.float32))
+    return out, n_tiles * tile
 
 
 def held_part_token(x, idx, w, e_gu, e_down, first: int, dtype,

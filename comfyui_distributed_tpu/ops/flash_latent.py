@@ -1,0 +1,147 @@
+"""Pallas causal attention over decompressed latent keys and values.
+
+The prefill side of multi-head latent attention (``ops/latent_attention.py``):
+a chunk of queries at positions ``start .. start+C−1`` against the rows
+``j ≤`` each query's position of a decompressed workspace. What the
+bidirectional kernels of ``flash_attention.py`` do not have:
+
+- a **causal mask** whose diagonal moves with ``start`` (a scalar the
+  kernel prefetches: one compiled kernel serves every chunk of a scan).
+  K blocks wholly above a q block's last row are neither fetched (their
+  block index is clamped to the last needed one, so the pipeline re-uses
+  the tile it holds) nor computed; only a block the diagonal crosses is
+  masked;
+- **unequal widths**: a query and key are ``nope + rope`` wide (128 + 64),
+  a value ``v`` wide (128). The rope key is ONE ``[S, rope]`` array shared
+  by every head — a head's logits are ``q_nope·k_nope + q_rope·k_rope``,
+  two products into one float32 tile, so no per-head copy of it exists;
+- keys and values read from ONE workspace ``[S, H·(nope+v)]`` (a head's
+  ``[k_nope | v]`` side by side, as the decompression ``c W_b`` leaves
+  them): with ``nope == v`` the K tile of head ``h`` is column block ``2h``
+  and the V tile ``2h+1`` of the same array.
+
+grid = (heads, C/block_q, S/block_k), K innermost; running max, sum and
+the float32 accumulator live in VMEM scratch across K steps. Queries come
+pre-multiplied by the softmax scale.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import _LANES, NEG_INF
+
+_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def _last_block(start, i, block_q: int, block_k: int, num_k_blocks: int):
+    """The last K block a q block's rows can see."""
+    return jnp.minimum((start + (i + 1) * block_q - 1) // block_k,
+                       num_k_blocks - 1)
+
+
+def _latent_causal_kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
+                          o_ref, m_ref, l_ref, acc_ref, *, block_q: int,
+                          block_k: int, num_k_blocks: int, precision):
+    i, j = pl.program_id(1), pl.program_id(2)
+    first_row = start_ref[0] + i * block_q
+    last = _last_block(start_ref[0], i, block_q, block_k, num_k_blocks)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def step(masked: bool):
+        nt = (((1,), (1,)), ((), ()))
+        s = jax.lax.dot_general(qn_ref[...], kn_ref[...], nt,
+                                preferred_element_type=jnp.float32,
+                                precision=precision)
+        s += jax.lax.dot_general(qr_ref[0], kr_ref[...], nt,
+                                 preferred_element_type=jnp.float32,
+                                 precision=precision)
+        if masked:
+            row = first_row + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            col = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                         1)
+            s = jnp.where(col <= row, s, NEG_INF)
+        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        v = v_ref[...]
+        pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32,
+                                 precision=precision)
+        acc_ref[:] = acc_ref[:] * corr + pv
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    # the diagonal crosses a block whose last column lies past the first row
+    crosses = (j + 1) * block_k - 1 > first_row
+    pl.when((j <= last) & crosses)(lambda: step(True))
+    pl.when((j <= last) & jnp.logical_not(crosses))(lambda: step(False))
+
+    @pl.when(j == num_k_blocks - 1)
+    def _finalize():
+        o_ref[...] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("num_heads", "block_q",
+                                             "block_k", "interpret"))
+def latent_causal_mha(q_nope, q_rope, kv, k_rope, start, num_heads: int,
+                      block_q: int, block_k: int, interpret: bool):
+    """``q_nope`` [C, H·nope], ``q_rope`` [H, C, rope] (roped), both times
+    the softmax scale; ``kv`` [S, H·(nope+v)] with ``nope == v``;
+    ``k_rope`` [S, rope]; ``start`` the first query's position (traced).
+    ``C % block_q == 0`` and ``S % block_k == 0``. Answers [C, H·v]."""
+    C, S = q_nope.shape[0], kv.shape[0]
+    H = num_heads
+    nope, rope = q_nope.shape[1] // H, q_rope.shape[-1]
+    nq, nk = C // block_q, S // block_k
+    precision = (jax.lax.Precision.HIGHEST if q_nope.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    kernel = functools.partial(_latent_causal_kernel, block_q=block_q,
+                               block_k=block_k, num_k_blocks=nk,
+                               precision=precision)
+
+    def k_block(i, j, start_ref):
+        return jnp.minimum(j, _last_block(start_ref[0], i, block_q, block_k,
+                                          nk))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(H, nq, nk),
+        in_specs=[
+            pl.BlockSpec((block_q, nope), lambda h, i, j, s: (i, h)),
+            pl.BlockSpec((1, block_q, rope), lambda h, i, j, s: (h, i, 0)),
+            pl.BlockSpec((block_k, nope),
+                         lambda h, i, j, s: (k_block(i, j, s), 2 * h)),
+            pl.BlockSpec((block_k, rope),
+                         lambda h, i, j, s: (k_block(i, j, s), 0)),
+            pl.BlockSpec((block_k, nope),
+                         lambda h, i, j, s: (k_block(i, j, s), 2 * h + 1)),
+        ],
+        out_specs=pl.BlockSpec((block_q, nope), lambda h, i, j, s: (i, h)),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, _LANES), jnp.float32),   # running max
+            pltpu.VMEM((block_q, _LANES), jnp.float32),   # running sum
+            pltpu.VMEM((block_q, nope), jnp.float32),     # output acc
+        ])
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((C, H * nope), q_nope.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(jnp.reshape(start, (1,)).astype(jnp.int32), q_nope, q_rope, kv, k_rope,
+      kv)

@@ -23,15 +23,49 @@ difference once (``tests/test_llm_motif.py`` holds the two equal).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
-def rope_interleaved(x, positions, theta: float):
+def yarn_frequencies(d: int, theta: float, factor: float,
+                     original_len: int, beta_fast: float = 32.0,
+                     beta_slow: float = 1.0):
+    """YaRN's frequency table for ``d`` rotary dimensions, float32 [d/2]:
+    ``f_i = theta^(−2i/d)`` blended per frequency with ``f_i / factor``.
+    ``corr(n) = d · ln(L₀ / (2π n)) / (2 ln theta)`` is the dimension that
+    turns ``n`` times over the original length ``L₀``; dimensions below
+    ``⌊corr(beta_fast)⌋`` keep their frequency, those above
+    ``⌈corr(beta_slow)⌉`` are divided by ``factor``, a linear ramp between.
+    Cos and sin are not scaled here (the softmax scale carries
+    ``mscale²``: :func:`yarn_mscale`)."""
+    def corr(n):
+        return d * math.log(original_len / (2 * math.pi * n)) \
+            / (2 * math.log(theta))
+
+    low = min(max(math.floor(corr(beta_fast)), 0), d - 1)
+    high = min(max(math.ceil(corr(beta_slow)), 0), d - 1)
+    i = np.arange(d // 2, dtype=np.float64)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    freq = theta ** (-2.0 * i / d)
+    return (freq * ((1.0 - ramp) + ramp / factor)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """``0.1 · mscale · ln(factor) + 1``: the softmax scale is multiplied
+    by its square where ``mscale_all_dim`` is set."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_interleaved(x, positions, theta: float, freqs=None):
     """Rotate pairs ``(x[2i], x[2i+1])`` of the last axis by ``pos ·
-    theta^(−2i/d)``. ``x`` [T,...,d], ``positions`` [T]."""
+    theta^(−2i/d)``, or by ``pos · freqs[i]`` where a table is given
+    (:func:`yarn_frequencies`). ``x`` [T,...,d], ``positions`` [T]."""
     d = x.shape[-1]
-    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d) \
+        if freqs is None else jnp.asarray(freqs, jnp.float32)
     ang = positions.astype(jnp.float32)[:, None] * freq           # [T,d/2]
     ang = ang.reshape(ang.shape[0], *([1] * (x.ndim - 2)), d // 2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
@@ -155,3 +189,120 @@ def gdla_absorbed_step(q_nope, q_rope, c_cache, kr_cache, valid, w_uk, w_uv,
     diff = ctx[:, :J - 1] - lam[..., None] * ctx[:, J - 1:]
     return jnp.einsum("gjc,cgv->gjv", diff.astype(dtype), w_uv.astype(dtype),
                       preferred_element_type=jnp.float32)
+
+
+# --- prefill through the cache: blocked causal attention --------------------
+
+# large-but-finite, as the flash kernels': −inf breaks max on a masked tile
+_NEG = -1e30
+
+
+def causal_blocked_lax(q_nope, q_rope, kv, k_rope, start, nope: int, dtype,
+                       block_q: int, block_k: int):
+    """The blocked causal schedule in ``jax.lax``: q blocks walked by
+    ``lax.map``, each over the K blocks its rows can see (a loop whose
+    count is the block's own), online softmax in float32. ``q_nope``
+    [C,H,nope], ``q_rope`` [C,H,r] (roped), both already times the
+    scale; ``kv`` [S,H,nope+v] decompressed rows; ``k_rope`` [S,r];
+    ``C % block_q == 0``, ``S % block_k == 0``. The emulated path of
+    ``flash_latent.latent_causal_mha`` (same blocks, same mask, same
+    accumulation) and its rival on the chip. Answers [C,H,v]."""
+    C, H, _ = q_nope.shape
+    S, v = kv.shape[0], kv.shape[-1] - nope
+    q_nope, q_rope = q_nope.astype(dtype), q_rope.astype(dtype)
+
+    def rows(a, at, n):
+        return jax.lax.dynamic_slice_in_dim(a, at, n, 0)
+
+    def one_block(i):
+        qn, qr = rows(q_nope, i * block_q, block_q), \
+            rows(q_rope, i * block_q, block_q)
+        row = start + i * block_q + jnp.arange(block_q)
+        last = jnp.minimum((start + (i + 1) * block_q - 1) // block_k,
+                           S // block_k - 1)
+
+        def step(j, carry):
+            m, l, acc = carry
+            kvb, krb = rows(kv, j * block_k, block_k), \
+                rows(k_rope, j * block_k, block_k)
+            s = (jnp.einsum("thd,shd->hts", qn, kvb[..., :nope],
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("thr,sr->hts", qr, krb.astype(dtype),
+                              preferred_element_type=jnp.float32))
+            col = j * block_k + jnp.arange(block_k)
+            s = jnp.where(col[None, None, :] <= row[None, :, None], s, _NEG)
+            m_new = jnp.maximum(m, s.max(-1))
+            p = jnp.exp(s - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            acc = acc * corr[..., None] + jnp.einsum(
+                "hts,shv->htv", p.astype(dtype), kvb[..., nope:],
+                preferred_element_type=jnp.float32)
+            return m_new, l * corr + p.sum(-1), acc
+
+        init = (jnp.full((H, block_q), _NEG, jnp.float32),
+                jnp.zeros((H, block_q), jnp.float32),
+                jnp.zeros((H, block_q, v), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, last + 1, step, init)
+        return jnp.swapaxes(acc / l[..., None], 0, 1)        # [bq,H,v]
+
+    out = jax.lax.map(one_block, jnp.arange(C // block_q))
+    return out.reshape(C, H, v)
+
+
+def mla_chunk_attention(q_nope, q_rope, c_cache, kr_cache, start, w_b,
+                        scale: float, dtype, block_q: int, block_k: int,
+                        kernel: str | None = None):
+    """A chunk of ``C`` queries at positions ``start .. start+C−1``
+    against a latent cache that already holds the chunk's own rows:
+    position ``t`` sees cache rows ``j ≤ t``. ``q_nope`` [C,H,nope],
+    ``q_rope`` [C,H,r] (roped), ``c_cache`` [S,rank], ``kr_cache`` [S,r].
+
+    The rows ``< start+C`` are decompressed (``c W_b``: a head's ``[k_nope
+    | v]``) into a workspace, ``C`` rows at a time and only as many as the
+    chunk can see — what continuing from a cache costs over a whole-prompt
+    pass — and the chunk runs blocked causal attention over it: nothing
+    ``C × S`` exists. ``kernel``: ``pallas`` (``ops/flash_latent.py``: the
+    default on a TPU where ``nope == v``), ``interpret`` (the same kernel
+    in the Pallas interpreter) or ``lax`` (:func:`causal_blocked_lax`: the
+    default elsewhere). Answers [C,H,v] in ``dtype``."""
+    from . import flash_latent
+    from .flash_attention import _platform
+
+    C, H, nope = q_nope.shape
+    S, rank = c_cache.shape
+    v = w_b.shape[1] // H - nope
+    if kernel is None:
+        kernel = "pallas" if _platform() == "tpu" and nope == v else "lax"
+    bq = math.gcd(C, block_q)
+    step = math.lcm(C, block_k)
+    S_pad = -(-S // step) * step
+    c_pad = jnp.pad(c_cache, ((0, S_pad - S), (0, 0)))
+    kr_pad = jnp.pad(kr_cache, ((0, S_pad - S), (0, 0))).astype(dtype)
+
+    def fill(j, kv):
+        rows = jax.lax.dynamic_slice_in_dim(c_pad, j * C, C, 0)
+        return jax.lax.dynamic_update_slice_in_dim(
+            kv, jnp.dot(rows.astype(dtype), w_b.astype(dtype),
+                        preferred_element_type=jnp.float32).astype(dtype),
+            j * C, 0)
+
+    n_fill = jnp.minimum((start + 2 * C - 1) // C, S_pad // C)
+    kv = jax.lax.fori_loop(0, n_fill, fill,
+                           jnp.zeros((S_pad, w_b.shape[1]), dtype))
+    q_nope = (q_nope * scale).astype(dtype)
+    q_rope = (q_rope * scale).astype(dtype)
+    if kernel == "lax":
+        return causal_blocked_lax(q_nope, q_rope, kv.reshape(S_pad, H, -1),
+                                  kr_pad, start, nope, dtype, bq,
+                                  block_k).astype(dtype)
+    if kernel == "pallas":
+        from .attention import note_latent_causal
+
+        note_latent_causal(H, nope + q_rope.shape[-1], C, S_pad, dtype, bq,
+                           block_k)
+    with jax.named_scope("mla_causal"):
+        o = flash_latent.latent_causal_mha(
+            q_nope.reshape(C, H * nope), jnp.swapaxes(q_rope, 0, 1), kv,
+            kr_pad, start, num_heads=H, block_q=bq, block_k=block_k,
+            interpret=kernel == "interpret")
+    return o.reshape(C, H, v)

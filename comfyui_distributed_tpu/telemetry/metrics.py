@@ -104,6 +104,25 @@ LLM_CACHE_BYTES = REGISTRY.gauge(
     "and convolution tails). Set when a request's cache is made.",
     ("layers",))
 
+LLM_PREFILL_CHUNKS = REGISTRY.counter(
+    "cdt_llm_prefill_chunks_total",
+    "Chunks llm_prefill walked through the cache (a model whose prompt is "
+    "taken whole counts one a request).")
+
+LLM_EXPERT_ROWS = REGISTRY.counter(
+    "cdt_llm_expert_rows_total",
+    "Rows the held experts' matrix products multiplied, by the form that "
+    "ran: dense (every held expert x every token), grouped (the routed "
+    "rows in expert order, in tiles: tiles x tile rows) or token (decode: "
+    "one row a held slot). Over cdt_llm_expert_slots_total{where=held} it "
+    "is what a form costs for the routed work it was needed for.",
+    ("form",))
+
+LLM_CACHE_POSITIONS = REGISTRY.gauge(
+    "cdt_llm_cache_positions",
+    "Positions (rows a full layer) one rewrite request's cache holds: "
+    "prompt + new tokens. Set when a request's cache is made.")
+
 LLM_STREAM_MIX = REGISTRY.counter(
     "cdt_llm_stream_mix_total",
     "Residual-stream mixes a language model ran (one Sinkhorn-normalised "
@@ -116,7 +135,8 @@ LLM_STREAM_MIX = REGISTRY.counter(
 ATTN_KERNEL_SELECTED = REGISTRY.counter(
     "cdt_attn_kernel_selected",
     "Attention kernel-tier selections at trace time, by tier "
-    "(fused/packed/bh/xla), geometry (hH.dD.qN.kvN.dtype — bucketed, "
+    "(fused/packed/bh/xla; latent_causal: a chunked latent prefill's "
+    "own kernel), geometry (hH.dD.qN.kvN.dtype — bucketed, "
     "so cardinality is bounded by the model zoo) and resolved blocks "
     "('<block_q>/<block_k>', for packed also ':k-resident' or "
     "':k-streamed'; '' where the tier has none). Increments once per "

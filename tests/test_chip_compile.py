@@ -239,3 +239,26 @@ def test_feasibility_never_approves_what_the_compiler_refuses(chip, row):
         except Exception as e:  # noqa: BLE001 — the compiler's refusal
             pytest.fail(f"{tier} {bq}/{bk} approved for {row} but "
                         f"refused by the compiler: {str(e)[:400]}")
+
+
+def test_the_latent_causal_kernel_compiles_at_the_served_geometry(chip):
+    """``ops/flash_latent.py`` as the long-brief rewriter's prefill calls
+    it: 64 heads of 128 + the shared 64-wide rope key, 128-wide values, a
+    4096-token chunk over a 32 k workspace, 1024-row tiles, the chunk's
+    start a traced scalar (one compiled kernel serves every chunk)."""
+    from comfyui_distributed_tpu.models.llm_kimi import KimiConfig
+    from comfyui_distributed_tpu.ops import flash_latent
+
+    cfg = KimiConfig.kimi_share()
+    H, C, S = cfg.num_attention_heads, cfg.prefill_chunk_tokens, 36864
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+
+    lowered = flash_latent.latent_causal_mha.lower(
+        arg(C, H * cfg.qk_nope_head_dim), arg(H, C, cfg.qk_rope_head_dim),
+        arg(S, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        arg(S, cfg.qk_rope_head_dim),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=chip), num_heads=H,
+        block_q=cfg.attn_block_q, block_k=cfg.attn_block_k, interpret=False)
+    assert "tpu_custom_call" in lowered.compile().as_text()

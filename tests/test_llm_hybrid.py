@@ -562,3 +562,60 @@ def test_the_roofline_share_reads_device_time_and_decode_slots():
                         {**ctx, "opened": bare, "closed": bare}) is None
     assert readers.read("llm_decode_ms_per_token",
                         {**ctx, "opened": bare, "closed": bare}) is None
+
+
+def test_the_shared_modules_leave_this_models_programs_as_they_were():
+    """What PR 32 added to the shared code does not reach this model: its
+    prefill takes the prompt whole, the one rule picks the dense-masked
+    experts at its cell's 512 prompt rows (16 held of 512: 8 rows an
+    expert), and ``rope_interleaved`` without a table is the θ formula
+    bit for bit."""
+    from comfyui_distributed_tpu.diffusion.pipeline_llm import LLMPipeline
+    from comfyui_distributed_tpu.ops import expert_share, latent_attention
+
+    share = L.LLMConfig.ling_flash_share()
+    assert share.model.prefill_chunk is None
+    assert (share.num_experts, share.router_experts) == (16, 512)
+    assert expert_share.prefill_form(512, share.routing) == "dense"
+    assert LLMPipeline(share, None).prefill_plan(512) == (512, 1, "dense")
+    whole = LLMPipeline(CFG, None).prefill_fn(16, 8).jitted
+    out = jax.eval_shape(whole, L.init_llm(CFG, None, abstract=True),
+                         jax.ShapeDtypeStruct((16,), jnp.int32))
+    assert len(out) == 3          # no rows-multiplied output: not the scan
+    x = jax.random.normal(jax.random.key(2), (5, 3, 64))
+    pos = jnp.asarray([0, 7, 511, 1535, 40000])
+    theta = share.rope_theta
+    freq = theta ** (-jnp.arange(0, 64, 2, dtype=jnp.float32) / 64)
+    ang = (pos.astype(jnp.float32)[:, None] * freq).reshape(5, 1, 32)
+    a, b = x[..., 0::2], x[..., 1::2]
+    want = jnp.stack([a * jnp.cos(ang) - b * jnp.sin(ang),
+                      a * jnp.sin(ang) + b * jnp.cos(ang)],
+                     axis=-1).reshape(x.shape)
+    got = latent_attention.rope_interleaved(x, pos, theta)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_rewrite_prompt_ids_cycles_the_preamble_hashed_once():
+    """Same ids out as hashing every word of every request: the preamble's
+    words are hashed once a vocabulary and cycled."""
+    from comfyui_distributed_tpu.graph import nodes_builtin
+    from comfyui_distributed_tpu.models.text import _stable_hash_token
+
+    words = nodes_builtin.REWRITE_PREAMBLE.split()
+    for text, n, vocab in (("a red fox jumps", 512, 19648),
+                           ("x " * 9000, 32768, 20480),
+                           ("one", 5, 64), ("", 40, 64)):
+        user = [_stable_hash_token(w, vocab)
+                for w in text.lower().split()][:max(1, n // 8)]
+        want = [_stable_hash_token(words[i % len(words)], vocab)
+                for i in range(n - len(user))] + user
+        assert nodes_builtin.rewrite_prompt_ids(text, n, vocab) == want
+    calls = []
+    real = nodes_builtin._preamble_ids
+    nodes_builtin._preamble_ids = lambda vocab: calls.append(vocab) or real(
+        vocab)
+    try:
+        nodes_builtin.rewrite_prompt_ids("a b c", 32768, 20480)
+    finally:
+        nodes_builtin._preamble_ids = real
+    assert calls == [20480]

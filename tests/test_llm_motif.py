@@ -642,3 +642,41 @@ def test_the_parity_tools_lower_arms_change_what_the_program_computes(
     assert close(parity_motif.load_reference().forward(
         CFG, params, jnp.arange(16))[0], R.forward(
         CFG, params, jnp.arange(16))[0], 1e-6)
+
+
+def test_the_shared_modules_leave_this_models_programs_as_they_were():
+    """What PR 32 added to the shared code does not reach this model: its
+    prefill takes the prompt whole, the one rule picks the dense-masked
+    experts at its cell's 1024 prompt rows (48 held of 384: 21 rows an
+    expert, under half a tile), and ``rope_interleaved`` without a table
+    is the θ formula bit for bit."""
+    share = M.MotifConfig.motif_share()
+    assert share.model.prefill_chunk is None
+    assert (share.num_experts, share.router_experts) == (48, 384)
+    assert expert_share.prefill_form(1024, share.routing) == "dense"
+    assert pipeline_llm.LLMPipeline(share, None).prefill_plan(1024) \
+        == (1024, 1, "dense")
+    # the dense arm of the rule is held_part_dense itself, chunks and all
+    x = jax.random.normal(jax.random.key(3), (6, CFG.hidden_size))
+    m = M.init_motif(CFG, jax.random.key(4))["layers"][2]["moe"]
+    idx, w = expert_share.route(x, m["w_router"], None, CFG.routing)
+    act = M.poly_norm_gate(CFG)
+    direct = expert_share.held_part_dense(
+        x, idx, w, m["e_gu"], m["e_down"], 0, jnp.float32, act, m["e_poly"],
+        expert_chunk=4)
+    ruled, rows = expert_share.held_part(
+        x, idx, w, m["e_gu"], m["e_down"], 0, jnp.float32, CFG.routing, act,
+        m["e_poly"], expert_chunk=4)
+    assert np.array_equal(np.asarray(direct), np.asarray(ruled))
+    assert int(rows) == 6 * CFG.num_experts
+    x = jax.random.normal(jax.random.key(2), (4, 64))
+    pos = jnp.asarray([0, 127, 1024, 2047])
+    freq = share.rope_theta ** (-jnp.arange(0, 64, 2,
+                                            dtype=jnp.float32) / 64)
+    ang = pos.astype(jnp.float32)[:, None] * freq
+    a, b = x[..., 0::2], x[..., 1::2]
+    want = jnp.stack([a * jnp.cos(ang) - b * jnp.sin(ang),
+                      a * jnp.sin(ang) + b * jnp.cos(ang)],
+                     axis=-1).reshape(x.shape)
+    got = latent_attention.rope_interleaved(x, pos, share.rope_theta)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
