@@ -23,8 +23,11 @@ AUDIO = {"waveform": [B,C,S], "sample_rate": int}; CONDITIONING =
 
 from __future__ import annotations
 
+import concurrent.futures
+import contextvars
 import functools
 import json
+import os
 from pathlib import Path
 from typing import Any, Optional
 
@@ -1621,8 +1624,21 @@ class VAEDecode(NodeDef):
         return (jnp.clip(out / 2.0 + 0.5, 0.0, 1.0),)
 
 
+# a saved batch's images, one worker each (SaveImage). The executor starts
+# a thread only when a task finds none idle and its threads never retire,
+# so the cap is also what one large batch leaves behind for good
+_SAVE_POOL = concurrent.futures.ThreadPoolExecutor(
+    max_workers=min(os.cpu_count() or 1, 8), thread_name_prefix="cdt-save")
+
+
 @register_node("SaveImage")
 class SaveImage(NodeDef):
+    """Writes a batch as ``<prefix>_<index>.png``. The batch is brought
+    to the host once; the images share nothing after that, so each of
+    several is quantised, encoded and written by a worker of
+    ``_SAVE_POOL``, side by side; one image takes the same steps on the
+    calling thread."""
+
     INPUTS = {"images": "IMAGE"}
     OPTIONAL = {"filename_prefix": "STRING"}
     HIDDEN = {"output_dir": "STRING"}
@@ -1631,20 +1647,41 @@ class SaveImage(NodeDef):
 
     def execute(self, images, filename_prefix: str = "output",
                 output_dir: str = "", **_):
+        from ..telemetry import enabled as _tm_enabled
+        from ..telemetry import metrics as _tm
         from ..telemetry.spans import span
         from ..utils.image import encode_png, to_uint8
 
         out_dir = Path(output_dir or "output")
         out_dir.mkdir(parents=True, exist_ok=True)
-        arr = to_uint8(images)
-        paths = []
-        for i in range(arr.shape[0]):
+        batch = np.asarray(images)
+        if batch.ndim != 4:
+            # one [H,W,C] image gains its axis, anything else is refused
+            batch = to_uint8(batch)
+        pooled = len(batch) > 1
+
+        def save(i: int) -> str:
             p = out_dir / f"{filename_prefix}_{i:05d}.png"
+            arr = to_uint8(batch[i])
             with span("image.encode_png"):
-                data = encode_png(arr[i])
+                data = encode_png(arr[0])
             with span("image.write", bytes=len(data)):
                 p.write_bytes(data)
-            paths.append(str(p))
+            return str(p)
+
+        if pooled:
+            # each task under a copy of this thread's context: its spans
+            # are children of node.SaveImage. Every task is waited for,
+            # then the lowest failing index raises, as the loop's would
+            tasks = [_SAVE_POOL.submit(contextvars.copy_context().run, save, i)
+                     for i in range(len(batch))]
+            concurrent.futures.wait(tasks)
+            paths = [t.result() for t in tasks]
+        else:
+            paths = [save(i) for i in range(len(batch))]
+        if _tm_enabled():
+            _tm.IMAGE_SAVE_IMAGES.labels(
+                mode="pooled" if pooled else "inline").inc(len(paths))
         log(f"saved {len(paths)} images to {out_dir}")
         return ()
 

@@ -79,6 +79,15 @@ WEIGHT_PLACEMENT_BYTES = REGISTRY.counter(
     "that received a copy. Grows when a program is built, never when one "
     "is called.")
 
+# --- saved images (graph/nodes_builtin.py: SaveImage) -------------------------
+
+IMAGE_SAVE_IMAGES = REGISTRY.counter(
+    "cdt_image_save_images_total",
+    "Images SaveImage wrote, by how their quantise, encode and write "
+    "ran: pooled (a batch of several, one worker thread an image, "
+    "side by side) or inline (a batch of one, on the calling thread).",
+    ("mode",))
+
 # --- the prompt rewriter (graph/nodes_builtin.py: TPUPromptRewrite) ----------
 
 LLM_TOKENS = REGISTRY.counter(
