@@ -1,0 +1,7 @@
+"""The layer is in ``resnet_xla_mxu_pct.json``."""
+
+from cdtbench import device_layers
+
+
+def read(ctx):
+    return device_layers.read_xla_mxu(ctx, "resnet_xla_mxu_pct")

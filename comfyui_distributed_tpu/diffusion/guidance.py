@@ -14,6 +14,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.device_scopes import device_scope
 from .schedules import NoiseSchedule
 from .samplers import Denoiser
 
@@ -30,11 +31,14 @@ def eps_denoiser(
     """eps-pred VP model → x0 denoiser: D(x,σ) = x − σ·eps(x·c_in, t(σ))."""
 
     def denoise(x: jax.Array, sigma: jax.Array) -> jax.Array:
-        c_in = 1.0 / jnp.sqrt(sigma ** 2 + 1.0)
-        t = schedule.timestep_for_sigma(sigma)
-        t_b = jnp.broadcast_to(t, (x.shape[0],))
-        eps = model_fn(x * c_in, t_b, context, y)
-        return x - sigma * eps
+        with device_scope("sampler"):
+            c_in = 1.0 / jnp.sqrt(sigma ** 2 + 1.0)
+            t = schedule.timestep_for_sigma(sigma)
+            t_b = jnp.broadcast_to(t, (x.shape[0],))
+            x_in = x * c_in
+        eps = model_fn(x_in, t_b, context, y)
+        with device_scope("sampler"):
+            return x - sigma * eps
 
     return denoise
 
@@ -47,9 +51,11 @@ def flow_denoiser(
     """Rectified-flow velocity model → x0 denoiser: D(x,σ) = x − σ·v(x, σ)."""
 
     def denoise(x: jax.Array, sigma: jax.Array) -> jax.Array:
-        t_b = jnp.broadcast_to(sigma, (x.shape[0],))
+        with device_scope("sampler"):
+            t_b = jnp.broadcast_to(sigma, (x.shape[0],))
         v = model_fn(x, t_b, context, y)
-        return x - sigma * v
+        with device_scope("sampler"):
+            return x - sigma * v
 
     return denoise
 
@@ -67,16 +73,19 @@ def cfg_denoiser(
     ``make_denoiser(context, y)`` builds the underlying denoiser; both
     conditionings are stacked along batch so one forward serves both.
     """
-    ctx2 = jnp.concatenate([context, uncond_context], axis=0)
-    y2 = None
-    if y is not None:
-        y2 = jnp.concatenate([y, uncond_y if uncond_y is not None else jnp.zeros_like(y)], axis=0)
+    with device_scope("sampler"):
+        ctx2 = jnp.concatenate([context, uncond_context], axis=0)
+        y2 = None
+        if y is not None:
+            y2 = jnp.concatenate([y, uncond_y if uncond_y is not None else jnp.zeros_like(y)], axis=0)
     inner = make_denoiser(ctx2, y2)
 
     def denoise(x: jax.Array, sigma: jax.Array) -> jax.Array:
-        x2 = jnp.concatenate([x, x], axis=0)
+        with device_scope("sampler"):
+            x2 = jnp.concatenate([x, x], axis=0)
         out = inner(x2, sigma)
-        cond, uncond = jnp.split(out, 2, axis=0)
-        return uncond + guidance_scale * (cond - uncond)
+        with device_scope("sampler"):
+            cond, uncond = jnp.split(out, 2, axis=0)
+            return uncond + guidance_scale * (cond - uncond)
 
     return denoise

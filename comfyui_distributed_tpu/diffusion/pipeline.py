@@ -27,6 +27,7 @@ from ..models.unet import UNet2D, UNetConfig
 from ..models.vae import AutoencoderKL
 from ..parallel.rng import participant_key
 from ..parallel.sharding import mesh_cache_key, replicate
+from ..telemetry.device_scopes import device_scope
 from ..utils import constants
 from .guidance import cfg_denoiser, eps_denoiser
 from .samplers import sample
@@ -199,8 +200,11 @@ def inpaint_denoiser(base, src: jax.Array, noise: jax.Array,
     only hides the drift for fully-unmasked pixels."""
 
     def denoise(xx, sigma):
-        xx = xx * mask + (src + noise * sigma) * (1.0 - mask)
-        return base(xx, sigma) * mask + src * (1.0 - mask)
+        with device_scope("sampler"):
+            xx = xx * mask + (src + noise * sigma) * (1.0 - mask)
+        x0 = base(xx, sigma)
+        with device_scope("sampler"):
+            return x0 * mask + src * (1.0 - mask)
 
     return denoise
 
@@ -311,35 +315,33 @@ class Txt2ImgPipeline:
         segment programs (``preemptible_fns``) — the key split, noise
         draw, and guidance wiring must be byte-for-byte the same math on
         both paths or checkpoint/resume loses bit-identity."""
-        k_noise, k_samp = jax.random.split(key)
-        if init_latent is None:
-            lat_h = spec.height // self.vae.config.downscale
-            lat_w = spec.width // self.vae.config.downscale
-            noise = jax.random.normal(
-                k_noise, (batch, lat_h, lat_w, self.latent_channels),
-                jnp.float32,
-            )
-            x = noise * sigmas[0]
-        else:
-            noise = jax.random.normal(k_noise, init_latent.shape, jnp.float32)
-            x = init_latent + noise * sigmas[0]
+        bc = lambda a: (None if a is None
+                        else jnp.broadcast_to(a, (batch,) + a.shape[1:]))
+        with device_scope("sampler"):
+            k_noise, k_samp = jax.random.split(key)
+            if init_latent is None:
+                lat_h = spec.height // self.vae.config.downscale
+                lat_w = spec.width // self.vae.config.downscale
+                noise = jax.random.normal(
+                    k_noise, (batch, lat_h, lat_w, self.latent_channels),
+                    jnp.float32,
+                )
+                x = noise * sigmas[0]
+            else:
+                noise = jax.random.normal(k_noise, init_latent.shape,
+                                          jnp.float32)
+                x = init_latent + noise * sigmas[0]
+            context, y = bc(context), bc(y)
+            if spec.guidance_scale != 1.0:
+                uncond_context, uncond_y = bc(uncond_context), bc(uncond_y)
 
         if spec.guidance_scale != 1.0:
             denoise = cfg_denoiser(
                 lambda ctx, yy: self._denoiser(ctx, yy, hint=hint,
                                                weights=weights),
-                jnp.broadcast_to(context, (batch,) + context.shape[1:]),
-                jnp.broadcast_to(uncond_context, (batch,) + uncond_context.shape[1:]),
-                spec.guidance_scale,
-                None if y is None else jnp.broadcast_to(y, (batch,) + y.shape[1:]),
-                None if uncond_y is None else jnp.broadcast_to(uncond_y, (batch,) + uncond_y.shape[1:]),
-            )
+                context, uncond_context, spec.guidance_scale, y, uncond_y)
         else:
-            denoise = self._denoiser(
-                jnp.broadcast_to(context, (batch,) + context.shape[1:]),
-                None if y is None else jnp.broadcast_to(y, (batch,) + y.shape[1:]),
-                hint=hint, weights=weights,
-            )
+            denoise = self._denoiser(context, y, hint=hint, weights=weights)
         if inpaint_mask is not None and init_latent is not None:
             denoise = inpaint_denoiser(denoise, init_latent, noise,
                                        inpaint_mask)
@@ -399,8 +401,9 @@ class Txt2ImgPipeline:
         fused path, the preemptible ``fin`` program, and the decode
         pool's batched program (``decode_fn``) so the image math cannot
         drift between the serving tiers."""
-        images = self.vae.decode(x0, params=vae_params)
-        return jnp.clip(images / 2.0 + 0.5, 0.0, 1.0)
+        images = self.vae.decode(x0, params=vae_params)   # cdt.vae_decode
+        with device_scope("vae_decode"):
+            return jnp.clip(images / 2.0 + 0.5, 0.0, 1.0)
 
     def generate_fn(self, mesh: Mesh, spec: GenerationSpec,
                     axis: str = constants.AXIS_DATA,

@@ -25,6 +25,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..models.dit import DiT, DiTConfig
 from ..models.vae import AutoencoderKL
 from ..parallel.rng import participant_key
+from ..telemetry.device_scopes import device_scope
 from ..utils import constants
 from .pipeline import bind_weights
 from .samplers import sample
@@ -94,11 +95,13 @@ class FlowPipeline:
 
         def make(ctx, pl):
             def denoise(x, sigma):
-                t = jnp.broadcast_to(sigma, (x.shape[0],))
-                g = jnp.full((x.shape[0],), guidance)
+                with device_scope("sampler"):
+                    t = jnp.broadcast_to(sigma, (x.shape[0],))
+                    g = jnp.full((x.shape[0],), guidance)
                 v = self.dit.apply(dit_params, x, t, ctx, pl, g,
                                    sp_axis=sp_axis)
-                return x - sigma * v
+                with device_scope("sampler"):
+                    return x - sigma * v
             return denoise
 
         if cfg == 1.0:
@@ -126,19 +129,24 @@ class FlowPipeline:
         (``segment_fns``), so the two cannot drift apart."""
         lat_h, lat_w = lat_hw
         c = self.dit.config.in_channels
-        x = jax.random.normal(key, (batch, lat_h, lat_w, c), jnp.float32)
         bc = lambda a: (None if a is None
                         else jnp.broadcast_to(a, (batch,) + a.shape[1:]))
-        den = self._denoiser(bc(context), bc(pooled), spec.guidance, sp_axis,
+        with device_scope("sampler"):
+            x = jax.random.normal(key, (batch, lat_h, lat_w, c), jnp.float32)
+            context, pooled = bc(context), bc(pooled)
+            uncond_context = bc(uncond_context)
+            uncond_pooled = bc(uncond_pooled)
+        den = self._denoiser(context, pooled, spec.guidance, sp_axis,
                              weights=weights, cfg=spec.cfg,
-                             uncond_context=bc(uncond_context),
-                             uncond_pooled=bc(uncond_pooled))
+                             uncond_context=uncond_context,
+                             uncond_pooled=uncond_pooled)
         return den, x
 
     def _decode_latent(self, x0, weights=None):
-        images = self.vae.decode(
+        images = self.vae.decode(                          # cdt.vae_decode
             x0, params=None if weights is None else weights["vae_dec"])
-        return jnp.clip(images / 2.0 + 0.5, 0.0, 1.0)
+        with device_scope("vae_decode"):
+            return jnp.clip(images / 2.0 + 0.5, 0.0, 1.0)
 
     def _sample_and_decode(self, key, context, pooled, spec: FlowSpec,
                            batch: int, sigmas, lat_hw, sp_axis=None,
@@ -318,6 +326,8 @@ class FlowPipeline:
             dit_def = jax.tree.structure(weights["dit"])
 
             def flow_cast_body(weights):
+                # the weights' conversion, once a request: reads as
+                # (unnamed) by design — no layer's work
                 return tuple(
                     leaf.astype(to) for leaf, to in zip(
                         jax.tree.leaves(weights["dit"]), read_as)

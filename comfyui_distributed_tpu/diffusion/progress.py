@@ -64,6 +64,7 @@ import numpy as np
 from ..telemetry import enabled as _tm_enabled
 from ..telemetry import metrics as _tm
 from ..telemetry import spans as _spans
+from ..telemetry.device_scopes import device_scope
 
 # sink(token:int, shard:int, sigma:float, x0:np.ndarray, calls:int), calls
 # being the denoiser calls the event stands for. Registry keyed by handle so
@@ -239,7 +240,8 @@ class DenoiserTap:
     def __call__(self, x, sigma):
         x0 = self._denoise(x, sigma)
         if self._seen is None:
-            self._seen = (jnp.asarray(sigma, jnp.float32), x0[:1])
+            with device_scope("sampler"):
+                self._seen = (jnp.asarray(sigma, jnp.float32), x0[:1])
         return x0
 
     def take(self):

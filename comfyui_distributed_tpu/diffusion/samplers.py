@@ -38,6 +38,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.device_scopes import device_scope
 from .progress import sampler_step
 
 Denoiser = Callable[[jax.Array, jax.Array], jax.Array]   # (x, sigma[]) -> x0_hat
@@ -94,11 +95,14 @@ def run_segment(prog: SamplerProgram, carry: tuple, start, length: int,
     untouched."""
     if length <= 0:
         return carry
-    xs = jnp.asarray(start, jnp.int32) + jnp.arange(length, dtype=jnp.int32)
+    with device_scope("sampler"):
+        xs = jnp.asarray(start, jnp.int32) + jnp.arange(length,
+                                                        dtype=jnp.int32)
     carry, seen = jax.lax.scan(_scan_body(prog, tap), carry, xs)
     if tap is None:
         return carry
-    return carry, jax.tree.map(lambda rows: rows[-1], seen)
+    with device_scope("sampler"):
+        return carry, jax.tree.map(lambda rows: rows[-1], seen)
 
 
 def equal_segment_steps(n_steps: int, at_most: int) -> int:
@@ -157,10 +161,12 @@ def _euler_program(denoise, sigmas, key=None) -> SamplerProgram:
 
     def step(carry, i):
         (x,) = carry
-        sigma, sigma_next = sigmas[i], sigmas[i + 1]
+        with device_scope("sampler"):
+            sigma, sigma_next = sigmas[i], sigmas[i + 1]
         denoised = denoise(x, sigma)
-        d = _to_d(x, sigma, denoised)
-        return (x + d * (sigma_next - sigma),)
+        with device_scope("sampler"):
+            d = _to_d(x, sigma, denoised)
+            return (x + d * (sigma_next - sigma),)
 
     return SamplerProgram("euler", sigmas.shape[0] - 1,
                           lambda x: (x,), step, _extract_first)
@@ -170,14 +176,17 @@ def _euler_ancestral_program(denoise, sigmas, key,
                              eta: float = 1.0) -> SamplerProgram:
     def step(carry, i):
         (x,) = carry
-        sigma, sigma_next = sigmas[i], sigmas[i + 1]
+        with device_scope("sampler"):
+            sigma, sigma_next = sigmas[i], sigmas[i + 1]
         denoised = denoise(x, sigma)
-        sigma_down, sigma_up = _ancestral_sigmas(sigma, sigma_next, eta)
-        d = _to_d(x, sigma, denoised)
-        x = x + d * (sigma_down - sigma)
-        noise = jax.random.normal(jax.random.fold_in(key, i), x.shape, x.dtype)
-        # last step has sigma_next == 0 → sigma_up == 0 → no noise added
-        return (x + noise * sigma_up,)
+        with device_scope("sampler"):
+            sigma_down, sigma_up = _ancestral_sigmas(sigma, sigma_next, eta)
+            d = _to_d(x, sigma, denoised)
+            x = x + d * (sigma_down - sigma)
+            noise = jax.random.normal(jax.random.fold_in(key, i), x.shape,
+                                      x.dtype)
+            # last step has sigma_next == 0 → sigma_up == 0 → no noise added
+            return (x + noise * sigma_up,)
 
     return SamplerProgram("euler_ancestral", sigmas.shape[0] - 1,
                           lambda x: (x,), step, _extract_first)
@@ -188,16 +197,21 @@ def _heun_program(denoise, sigmas, key=None) -> SamplerProgram:
 
     def step(carry, i):
         (x,) = carry
-        sigma, sigma_next = sigmas[i], sigmas[i + 1]
+        with device_scope("sampler"):
+            sigma, sigma_next = sigmas[i], sigmas[i + 1]
         denoised = denoise(x, sigma)
-        d = _to_d(x, sigma, denoised)
-        dt = sigma_next - sigma
-        x_euler = x + d * dt
+        with device_scope("sampler"):
+            d = _to_d(x, sigma, denoised)
+            dt = sigma_next - sigma
+            x_euler = x + d * dt
 
+        # the branch calls the model: the cond stays outside the scope, the
+        # branch's own arithmetic opens it
         def heun_correct(_):
             denoised2 = denoise(x_euler, sigma_next)
-            d2 = _to_d(x_euler, sigma_next, denoised2)
-            return x + (d + d2) / 2 * dt
+            with device_scope("sampler"):
+                d2 = _to_d(x_euler, sigma_next, denoised2)
+                return x + (d + d2) / 2 * dt
 
         # at the final step sigma_next==0: plain euler (no second eval at σ=0)
         x = jax.lax.cond(sigma_next > 0, heun_correct, lambda _: x_euler, None)
@@ -216,25 +230,27 @@ def _dpmpp_2m_program(denoise, sigmas, key=None) -> SamplerProgram:
 
     def step(carry, i):
         x, old_denoised, have_old = carry
-        sigma, sigma_next = sigmas[i], sigmas[i + 1]
+        with device_scope("sampler"):
+            sigma, sigma_next = sigmas[i], sigmas[i + 1]
         denoised = denoise(x, sigma)
+        with device_scope("sampler"):
 
-        def first_order(_):
-            # exact Euler in exponential-integrator form
-            return x * (sigma_next / sigma) + denoised * (1 - sigma_next / sigma)
+            def first_order(_):
+                # exact Euler in exponential-integrator form
+                return x * (sigma_next / sigma) + denoised * (1 - sigma_next / sigma)
 
-        def second_order(_):
-            h = t_of(sigma_next) - t_of(sigma)
-            h_last = t_of(sigma) - t_of(sigmas[i - 1])
-            r = h_last / jnp.maximum(h, 1e-10)
-            denoised_d = (1 + 1 / (2 * r)) * denoised - (1 / (2 * r)) * old_denoised
-            return x * (sigma_next / sigma) + denoised_d * (1 - sigma_next / sigma)
+            def second_order(_):
+                h = t_of(sigma_next) - t_of(sigma)
+                h_last = t_of(sigma) - t_of(sigmas[i - 1])
+                r = h_last / jnp.maximum(h, 1e-10)
+                denoised_d = (1 + 1 / (2 * r)) * denoised - (1 / (2 * r)) * old_denoised
+                return x * (sigma_next / sigma) + denoised_d * (1 - sigma_next / sigma)
 
-        use_second = jnp.logical_and(have_old, sigma_next > 0)
-        x_new = jax.lax.cond(use_second, second_order, first_order, None)
-        # sigma_next == 0: x -> denoised exactly
-        x_new = jnp.where(sigma_next > 0, x_new, denoised)
-        return (x_new, denoised, jnp.array(True))
+            use_second = jnp.logical_and(have_old, sigma_next > 0)
+            x_new = jax.lax.cond(use_second, second_order, first_order, None)
+            # sigma_next == 0: x -> denoised exactly
+            x_new = jnp.where(sigma_next > 0, x_new, denoised)
+            return (x_new, denoised, jnp.array(True))
 
     return SamplerProgram(
         "dpmpp_2m", sigmas.shape[0] - 1,
@@ -249,18 +265,20 @@ def _ddim_program(denoise, sigmas, key=None,
 
     def step(carry, i):
         (x,) = carry
-        sigma, sigma_next = sigmas[i], sigmas[i + 1]
+        with device_scope("sampler"):
+            sigma, sigma_next = sigmas[i], sigmas[i + 1]
         denoised = denoise(x, sigma)
-        if eta and key is not None:
-            sigma_down, sigma_up = _ancestral_sigmas(sigma, sigma_next, eta)
-        else:
-            sigma_down, sigma_up = sigma_next, jnp.zeros(())
-        x = denoised + (x - denoised) * (sigma_down / jnp.maximum(sigma, 1e-10))
-        if eta and key is not None:
-            noise = jax.random.normal(jax.random.fold_in(key, i),
-                                      x.shape, x.dtype)
-            x = x + noise * sigma_up
-        return (x,)
+        with device_scope("sampler"):
+            if eta and key is not None:
+                sigma_down, sigma_up = _ancestral_sigmas(sigma, sigma_next, eta)
+            else:
+                sigma_down, sigma_up = sigma_next, jnp.zeros(())
+            x = denoised + (x - denoised) * (sigma_down / jnp.maximum(sigma, 1e-10))
+            if eta and key is not None:
+                noise = jax.random.normal(jax.random.fold_in(key, i),
+                                          x.shape, x.dtype)
+                x = x + noise * sigma_up
+            return (x,)
 
     return SamplerProgram("ddim", sigmas.shape[0] - 1,
                           lambda x: (x,), step, _extract_first)
@@ -272,11 +290,13 @@ def _lcm_program(denoise, sigmas, key) -> SamplerProgram:
 
     def step(carry, i):
         (x,) = carry
-        denoised = denoise(x, sigmas[i])
-        sigma_next = sigmas[i + 1]
-        noise = jax.random.normal(jax.random.fold_in(key, i),
-                                  x.shape, x.dtype)
-        return (denoised + jnp.where(sigma_next > 0, sigma_next, 0.0) * noise,)
+        with device_scope("sampler"):
+            sigma, sigma_next = sigmas[i], sigmas[i + 1]
+        denoised = denoise(x, sigma)
+        with device_scope("sampler"):
+            noise = jax.random.normal(jax.random.fold_in(key, i),
+                                      x.shape, x.dtype)
+            return (denoised + jnp.where(sigma_next > 0, sigma_next, 0.0) * noise,)
 
     return SamplerProgram("lcm", sigmas.shape[0] - 1,
                           lambda x: (x,), step, _extract_first)
@@ -297,35 +317,40 @@ def _dpmpp_sde_program(denoise, sigmas, key, eta: float = 1.0,
 
     def step(carry, i):
         (x,) = carry
-        sigma, sigma_next = sigmas[i], sigmas[i + 1]
+        with device_scope("sampler"):
+            sigma, sigma_next = sigmas[i], sigmas[i + 1]
         denoised = denoise(x, sigma)
 
         def last(_):
             return denoised
 
         def stage(_):
-            t, t_next = t_of(sigma), t_of(sigma_next)
-            h = t_next - t
-            s = t + h * r
-            fac = 1.0 / (2.0 * r)
-            # midpoint stage with its own ancestral split
-            sd1, su1 = _ancestral_sigmas(sigma_of(t), sigma_of(s), eta)
-            s_down = t_of(sd1)
-            x2 = (sigma_of(s_down) / sigma_of(t)) * x \
-                - jnp.expm1(t - s_down) * denoised
-            noise1 = jax.random.normal(jax.random.fold_in(key, 2 * i),
-                                       x.shape, x.dtype)
-            x2 = x2 + noise1 * su1 * s_noise
-            denoised2 = denoise(x2, sigma_of(s))
-            # full step
-            sd2, su2 = _ancestral_sigmas(sigma_of(t), sigma_of(t_next), eta)
-            t_down = t_of(sd2)
-            denoised_d = (1 - fac) * denoised + fac * denoised2
-            x_new = (sigma_of(t_down) / sigma_of(t)) * x \
-                - jnp.expm1(t - t_down) * denoised_d
-            noise2 = jax.random.normal(jax.random.fold_in(key, 2 * i + 1),
-                                       x.shape, x.dtype)
-            return x_new + noise2 * su2 * s_noise
+            with device_scope("sampler"):
+                t, t_next = t_of(sigma), t_of(sigma_next)
+                h = t_next - t
+                s = t + h * r
+                fac = 1.0 / (2.0 * r)
+                # midpoint stage with its own ancestral split
+                sd1, su1 = _ancestral_sigmas(sigma_of(t), sigma_of(s), eta)
+                s_down = t_of(sd1)
+                x2 = (sigma_of(s_down) / sigma_of(t)) * x \
+                    - jnp.expm1(t - s_down) * denoised
+                noise1 = jax.random.normal(jax.random.fold_in(key, 2 * i),
+                                           x.shape, x.dtype)
+                x2 = x2 + noise1 * su1 * s_noise
+                sigma_s = sigma_of(s)
+            denoised2 = denoise(x2, sigma_s)
+            with device_scope("sampler"):
+                # full step
+                sd2, su2 = _ancestral_sigmas(sigma_of(t), sigma_of(t_next),
+                                             eta)
+                t_down = t_of(sd2)
+                denoised_d = (1 - fac) * denoised + fac * denoised2
+                x_new = (sigma_of(t_down) / sigma_of(t)) * x \
+                    - jnp.expm1(t - t_down) * denoised_d
+                noise2 = jax.random.normal(
+                    jax.random.fold_in(key, 2 * i + 1), x.shape, x.dtype)
+                return x_new + noise2 * su2 * s_noise
 
         return (jax.lax.cond(sigma_next > 0, stage, last, None),)
 
@@ -343,30 +368,32 @@ def _dpmpp_2m_sde_program(denoise, sigmas, key, eta: float = 1.0,
 
     def step(carry, i):
         x, old_denoised, h_last, have_old = carry
-        sigma, sigma_next = sigmas[i], sigmas[i + 1]
+        with device_scope("sampler"):
+            sigma, sigma_next = sigmas[i], sigmas[i + 1]
         denoised = denoise(x, sigma)
+        with device_scope("sampler"):
 
-        def last(_):
-            return denoised, jnp.zeros(())
+            def last(_):
+                return denoised, jnp.zeros(())
 
-        def stage(_):
-            h = t_of(sigma_next) - t_of(sigma)
-            eta_h = eta * h
-            x_new = (sigma_next / jnp.maximum(sigma, 1e-10)) \
-                * jnp.exp(-eta_h) * x \
-                - jnp.expm1(-h - eta_h) * denoised
-            r = h_last / jnp.maximum(h, 1e-10)
-            second = -jnp.expm1(-h - eta_h) * (0.5 / jnp.maximum(r, 1e-10)) \
-                * (denoised - old_denoised)
-            x_new = x_new + jnp.where(have_old, second, 0.0)
-            noise = jax.random.normal(jax.random.fold_in(key, i),
-                                      x.shape, x.dtype)
-            x_new = x_new + noise * sigma_next * s_noise \
-                * jnp.sqrt(jnp.maximum(-jnp.expm1(-2.0 * eta_h), 0.0))
-            return x_new, h
+            def stage(_):
+                h = t_of(sigma_next) - t_of(sigma)
+                eta_h = eta * h
+                x_new = (sigma_next / jnp.maximum(sigma, 1e-10)) \
+                    * jnp.exp(-eta_h) * x \
+                    - jnp.expm1(-h - eta_h) * denoised
+                r = h_last / jnp.maximum(h, 1e-10)
+                second = -jnp.expm1(-h - eta_h) * (0.5 / jnp.maximum(r, 1e-10)) \
+                    * (denoised - old_denoised)
+                x_new = x_new + jnp.where(have_old, second, 0.0)
+                noise = jax.random.normal(jax.random.fold_in(key, i),
+                                          x.shape, x.dtype)
+                x_new = x_new + noise * sigma_next * s_noise \
+                    * jnp.sqrt(jnp.maximum(-jnp.expm1(-2.0 * eta_h), 0.0))
+                return x_new, h
 
-        x_new, h = jax.lax.cond(sigma_next > 0, stage, last, None)
-        return (x_new, denoised, h, jnp.array(True))
+            x_new, h = jax.lax.cond(sigma_next > 0, stage, last, None)
+            return (x_new, denoised, h, jnp.array(True))
 
     return SamplerProgram(
         "dpmpp_2m_sde", sigmas.shape[0] - 1,
@@ -392,24 +419,26 @@ def _res_2m_program(denoise, sigmas, key=None,
 
     def step(carry, i):
         x, old_denoised, h_prev, have_old = carry
-        sigma, sigma_next = sigmas[i], sigmas[i + 1]
+        with device_scope("sampler"):
+            sigma, sigma_next = sigmas[i], sigmas[i + 1]
         denoised = denoise(x, sigma)
-        if eta:
-            sigma_down, sigma_up = _ancestral_sigmas(sigma, sigma_next, eta)
-        else:
-            sigma_down, sigma_up = sigma_next, jnp.zeros(())
-        h = _t_of(sigma_down) - _t_of(sigma)
-        i0 = _i0(h)
-        slope = (denoised - old_denoised) / jnp.maximum(h_prev, 1e-10)
-        x_new = jnp.exp(-h) * x + i0 * denoised \
-            + jnp.where(have_old, (h - i0), 0.0) * slope
-        if eta:
-            noise = jax.random.normal(jax.random.fold_in(key, i),
-                                      x.shape, x.dtype)
-            x_new = x_new + noise * sigma_up
-        x_new = jnp.where(sigma_next > 0, x_new, denoised)
-        h_real = _t_of(sigma_next) - _t_of(sigma)
-        return (x_new, denoised, h_real, jnp.array(True))
+        with device_scope("sampler"):
+            if eta:
+                sigma_down, sigma_up = _ancestral_sigmas(sigma, sigma_next, eta)
+            else:
+                sigma_down, sigma_up = sigma_next, jnp.zeros(())
+            h = _t_of(sigma_down) - _t_of(sigma)
+            i0 = _i0(h)
+            slope = (denoised - old_denoised) / jnp.maximum(h_prev, 1e-10)
+            x_new = jnp.exp(-h) * x + i0 * denoised \
+                + jnp.where(have_old, (h - i0), 0.0) * slope
+            if eta:
+                noise = jax.random.normal(jax.random.fold_in(key, i),
+                                          x.shape, x.dtype)
+                x_new = x_new + noise * sigma_up
+            x_new = jnp.where(sigma_next > 0, x_new, denoised)
+            h_real = _t_of(sigma_next) - _t_of(sigma)
+            return (x_new, denoised, h_real, jnp.array(True))
 
     return SamplerProgram(
         "res_2m", sigmas.shape[0] - 1,
@@ -430,31 +459,39 @@ def _res_2s_program(denoise, sigmas, key=None, eta: float = 0.0,
 
     def step(carry, i):
         (x,) = carry
-        sigma, sigma_next = sigmas[i], sigmas[i + 1]
+        with device_scope("sampler"):
+            sigma, sigma_next = sigmas[i], sigmas[i + 1]
         denoised = denoise(x, sigma)
-        if eta:
-            sigma_down, sigma_up = _ancestral_sigmas(sigma, sigma_next, eta)
-        else:
-            sigma_down, sigma_up = sigma_next, jnp.zeros(())
+        with device_scope("sampler"):
+            if eta:
+                sigma_down, sigma_up = _ancestral_sigmas(sigma, sigma_next,
+                                                         eta)
+            else:
+                sigma_down, sigma_up = sigma_next, jnp.zeros(())
 
         def last(_):
             return denoised
 
         def stage(_):
-            h = _t_of(sigma_down) - _t_of(sigma)
-            ch = c2 * h
-            x_s = jnp.exp(-ch) * x + _i0(ch) * denoised
-            denoised_s = denoise(x_s, sigma * jnp.exp(-ch))
-            i0 = _i0(h)
-            psi = (h - i0) / jnp.maximum(ch, 1e-10)
-            return jnp.exp(-h) * x + (i0 - psi) * denoised \
-                + psi * denoised_s
+            with device_scope("sampler"):
+                h = _t_of(sigma_down) - _t_of(sigma)
+                ch = c2 * h
+                x_s = jnp.exp(-ch) * x + _i0(ch) * denoised
+                sigma_s = sigma * jnp.exp(-ch)
+            denoised_s = denoise(x_s, sigma_s)
+            with device_scope("sampler"):
+                i0 = _i0(h)
+                psi = (h - i0) / jnp.maximum(ch, 1e-10)
+                return jnp.exp(-h) * x + (i0 - psi) * denoised \
+                    + psi * denoised_s
 
         x_new = jax.lax.cond(sigma_next > 0, stage, last, None)
         if eta:
-            noise = jax.random.normal(jax.random.fold_in(key, i),
-                                      x.shape, x.dtype)
-            x_new = x_new + jnp.where(sigma_next > 0, noise * sigma_up, 0.0)
+            with device_scope("sampler"):
+                noise = jax.random.normal(jax.random.fold_in(key, i),
+                                          x.shape, x.dtype)
+                x_new = x_new + jnp.where(sigma_next > 0, noise * sigma_up,
+                                          0.0)
         return (x_new,)
 
     return SamplerProgram("res_2s", sigmas.shape[0] - 1,
@@ -475,37 +512,39 @@ def _dpmpp_3m_sde_program(denoise, sigmas, key, eta: float = 1.0,
 
     def step(carry, i):
         x, d1, d2, h1, h2, count = carry
-        sigma, sigma_next = sigmas[i], sigmas[i + 1]
+        with device_scope("sampler"):
+            sigma, sigma_next = sigmas[i], sigmas[i + 1]
         denoised = denoise(x, sigma)
+        with device_scope("sampler"):
 
-        def last(_):
-            return denoised, jnp.zeros(())
+            def last(_):
+                return denoised, jnp.zeros(())
 
-        def stage(_):
-            h = _t_of(sigma_next) - _t_of(sigma)
-            h_eta = h * (eta + 1.0)
-            x_new = jnp.exp(-h_eta) * x + _i0(h_eta) * denoised
-            phi2 = jnp.expm1(-h_eta) / h_eta + 1.0
-            phi3 = phi2 / h_eta - 0.5
-            r0 = h1 / h
-            r1 = h2 / h
-            d1_0 = (denoised - d1) / jnp.maximum(r0, 1e-10)
-            d1_1 = (d1 - d2) / jnp.maximum(r1, 1e-10)
-            dd1 = d1_0 + (d1_0 - d1_1) * r0 / jnp.maximum(r0 + r1, 1e-10)
-            dd2 = (d1_0 - d1_1) / jnp.maximum(r0 + r1, 1e-10)
-            third = x_new + phi2 * dd1 - phi3 * dd2
-            second = x_new + phi2 * d1_0
-            x_new = jnp.where(count >= 2, third,
-                              jnp.where(count == 1, second, x_new))
-            if eta:
-                noise = jax.random.normal(jax.random.fold_in(key, i),
-                                          x.shape, x.dtype)
-                x_new = x_new + noise * sigma_next * s_noise * jnp.sqrt(
-                    jnp.maximum(-jnp.expm1(-2.0 * h * eta), 0.0))
-            return x_new, h
+            def stage(_):
+                h = _t_of(sigma_next) - _t_of(sigma)
+                h_eta = h * (eta + 1.0)
+                x_new = jnp.exp(-h_eta) * x + _i0(h_eta) * denoised
+                phi2 = jnp.expm1(-h_eta) / h_eta + 1.0
+                phi3 = phi2 / h_eta - 0.5
+                r0 = h1 / h
+                r1 = h2 / h
+                d1_0 = (denoised - d1) / jnp.maximum(r0, 1e-10)
+                d1_1 = (d1 - d2) / jnp.maximum(r1, 1e-10)
+                dd1 = d1_0 + (d1_0 - d1_1) * r0 / jnp.maximum(r0 + r1, 1e-10)
+                dd2 = (d1_0 - d1_1) / jnp.maximum(r0 + r1, 1e-10)
+                third = x_new + phi2 * dd1 - phi3 * dd2
+                second = x_new + phi2 * d1_0
+                x_new = jnp.where(count >= 2, third,
+                                  jnp.where(count == 1, second, x_new))
+                if eta:
+                    noise = jax.random.normal(jax.random.fold_in(key, i),
+                                              x.shape, x.dtype)
+                    x_new = x_new + noise * sigma_next * s_noise * jnp.sqrt(
+                        jnp.maximum(-jnp.expm1(-2.0 * h * eta), 0.0))
+                return x_new, h
 
-        x_new, h = jax.lax.cond(sigma_next > 0, stage, last, None)
-        return (x_new, denoised, d1, h, h1, count + 1)
+            x_new, h = jax.lax.cond(sigma_next > 0, stage, last, None)
+            return (x_new, denoised, d1, h, h1, count + 1)
 
     return SamplerProgram(
         "dpmpp_3m_sde", sigmas.shape[0] - 1,
@@ -561,17 +600,19 @@ def _uni_pc_program(denoise, sigmas, key=None) -> SamplerProgram:
         # x_pred: predicted state at σ_i (uncorrected); x_prev: corrected
         # state at σ_{i−1}; d_prev/d_prev2: D at σ_{i−1}/σ_{i−2}
         x_prev, x_pred, d_prev, d_prev2, h_prev, h_prev2, count = carry
-        sigma, sigma_next = sigmas[i], sigmas[i + 1]
+        with device_scope("sampler"):
+            sigma, sigma_next = sigmas[i], sigmas[i + 1]
         d_cur = denoise(x_pred, sigma)
-        # corrector for the transition that produced x_pred
-        x_cur = jnp.where(
-            count >= 1,
-            correct(x_prev, d_prev2, d_prev, d_cur, h_prev, h_prev2, count),
-            x_pred)
-        h = _t_of(sigma_next) - _t_of(sigma)
-        x_next = predict(x_cur, d_cur, d_prev, h, h_prev, count)
-        x_next = jnp.where(sigma_next > 0, x_next, d_cur)
-        return (x_cur, x_next, d_cur, d_prev, h, h_prev, count + 1)
+        with device_scope("sampler"):
+            # corrector for the transition that produced x_pred
+            x_cur = jnp.where(
+                count >= 1,
+                correct(x_prev, d_prev2, d_prev, d_cur, h_prev, h_prev2, count),
+                x_pred)
+            h = _t_of(sigma_next) - _t_of(sigma)
+            x_next = predict(x_cur, d_cur, d_prev, h, h_prev, count)
+            x_next = jnp.where(sigma_next > 0, x_next, d_cur)
+            return (x_cur, x_next, d_cur, d_prev, h, h_prev, count + 1)
 
     return SamplerProgram(
         "uni_pc", sigmas.shape[0] - 1,
@@ -665,19 +706,23 @@ def token_program(forward, n_steps: int, key: jax.Array, temperature,
 
     def step(carry, i):
         ids, logits, state, taps, counts, finite = carry
-        finite = finite & jnp.isfinite(logits).all()
-        gumbel = jax.random.gumbel(jax.random.fold_in(key, i), logits.shape,
-                                   jnp.float32)
-        token = jnp.argmax(logits + temperature * gumbel).astype(jnp.int32)
-        ids = jax.lax.dynamic_update_index_in_dim(ids, token, i, 0)
+        with device_scope("llm_sample"):
+            finite = finite & jnp.isfinite(logits).all()
+            gumbel = jax.random.gumbel(jax.random.fold_in(key, i),
+                                       logits.shape, jnp.float32)
+            token = jnp.argmax(logits + temperature * gumbel).astype(
+                jnp.int32)
+            ids = jax.lax.dynamic_update_index_in_dim(ids, token, i, 0)
         logits, state, seen = forward(state, token, i)
-        if taps.shape[0]:
-            slot = jnp.minimum(i // tap_every, taps.shape[0] - 1)
-            row = jnp.where((i + 1) % tap_every == 0, logits,
-                            jax.lax.dynamic_index_in_dim(taps, slot, 0,
-                                                         False))
-            taps = jax.lax.dynamic_update_index_in_dim(taps, row, slot, 0)
-        return (ids, logits, state, taps, counts + seen, finite)
+        with device_scope("llm_sample"):
+            if taps.shape[0]:
+                slot = jnp.minimum(i // tap_every, taps.shape[0] - 1)
+                row = jnp.where((i + 1) % tap_every == 0, logits,
+                                jax.lax.dynamic_index_in_dim(taps, slot, 0,
+                                                             False))
+                taps = jax.lax.dynamic_update_index_in_dim(taps, row, slot,
+                                                           0)
+            return (ids, logits, state, taps, counts + seen, finite)
 
     return SamplerProgram("token", n_steps, init, step, _extract_first)
 

@@ -124,7 +124,7 @@ def lower_program(bundle, key: ProgramKey, mesh) -> None:
     import jax
     import jax.numpy as jnp
 
-    prng = jax.random.key(0)
+    prng = jax.eval_shape(jax.random.key, 0)      # a key's shape: nothing runs
     token = _abstract((), jnp.int32)
     if key.mesh:
         # mesh-tier program: the key names its own strategy mesh (sp /
@@ -207,9 +207,9 @@ def lower_program(bundle, key: ProgramKey, mesh) -> None:
         # here would not be the one serving loads
         from jax.sharding import NamedSharding, PartitionSpec
 
+        base = jax.random.key(0)
         keys = jax.device_put(
-            jax.vmap(lambda i: jax.random.fold_in(prng, i))(
-                jnp.arange(B)),
+            jax.vmap(lambda i: jax.random.fold_in(base, i))(jnp.arange(B)),
             NamedSharding(mesh, PartitionSpec("dp")))
         ctx = _abstract((1, bundle.preset.text.max_len, cfg.context_dim))
         pooled = _abstract((1, cfg.pooled_dim))

@@ -42,6 +42,7 @@ from flax import linen as nn
 
 from ..ops.attention import full_attention, joint_ring_attention
 from ..utils import constants
+from ..telemetry.device_scopes import device_scope
 from .layers import timestep_embedding
 
 
@@ -217,8 +218,9 @@ class MLPEmbedder(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        h = nn.Dense(self.hidden, dtype=self.dtype, name="in_layer")(x)
-        return nn.Dense(self.hidden, dtype=self.dtype, name="out_layer")(nn.silu(h))
+        with device_scope("norm_mod"):
+            h = nn.Dense(self.hidden, dtype=self.dtype, name="in_layer")(x)
+            return nn.Dense(self.hidden, dtype=self.dtype, name="out_layer")(nn.silu(h))
 
 
 class Modulation(nn.Module):
@@ -230,13 +232,21 @@ class Modulation(nn.Module):
 
     @nn.compact
     def __call__(self, vec: jax.Array) -> tuple[jax.Array, ...]:
-        out = nn.Dense(self.hidden * 3 * self.n_outputs, dtype=self.dtype,
-                       kernel_init=nn.initializers.zeros, name="mod")(nn.silu(vec))
-        return tuple(jnp.split(out[:, None, :], 3 * self.n_outputs, axis=-1))
+        with device_scope("norm_mod"):
+            out = nn.Dense(self.hidden * 3 * self.n_outputs, dtype=self.dtype,
+                           kernel_init=nn.initializers.zeros, name="mod")(nn.silu(vec))
+            return tuple(jnp.split(out[:, None, :], 3 * self.n_outputs, axis=-1))
 
 
 def _modulate(x, shift, scale):
     return x * (1 + scale) + shift
+
+
+def _norm_modulate(x, shift, scale, dt):
+    """A block's scale-free LayerNorm and its adaLN modulation."""
+    with device_scope("norm_mod"):
+        return _modulate(nn.LayerNorm(use_scale=False, use_bias=False,
+                                      dtype=dt)(x), shift, scale)
 
 
 class _QKV(nn.Module):
@@ -248,21 +258,22 @@ class _QKV(nn.Module):
     @nn.compact
     def __call__(self, x):
         B, N, _ = x.shape
-        qkv = nn.Dense(self.hidden * 3, dtype=self.dtype, name="qkv")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        hd = self.hidden // self.heads
-        shape = (B, N, self.heads, hd)
-        if not self.qk_norm:
-            # SD3-medium: raw q/k (its checkpoints carry no norm scales)
-            return q.reshape(shape), k.reshape(shape), v.reshape(shape)
-        # qk-norm (learned-scale RMS over head_dim) as in FLUX's QKNorm /
-        # SD3.5's ln_q/ln_k — the scales land from checkpoints'
-        # {query,key}_norm.scale / ln_{q,k}.weight entries
-        qs = self.param("q_scale", nn.initializers.ones, (hd,), jnp.float32)
-        ks = self.param("k_scale", nn.initializers.ones, (hd,), jnp.float32)
-        q = _rms(q.reshape(shape)) * qs.astype(self.dtype)
-        k = _rms(k.reshape(shape)) * ks.astype(self.dtype)
-        return q, k, v.reshape(shape)
+        with device_scope("attn_proj"):
+            qkv = nn.Dense(self.hidden * 3, dtype=self.dtype, name="qkv")(x)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            hd = self.hidden // self.heads
+            shape = (B, N, self.heads, hd)
+            if not self.qk_norm:
+                # SD3-medium: raw q/k (its checkpoints carry no norm scales)
+                return q.reshape(shape), k.reshape(shape), v.reshape(shape)
+            # qk-norm (learned-scale RMS over head_dim) as in FLUX's QKNorm /
+            # SD3.5's ln_q/ln_k — the scales land from checkpoints'
+            # {query,key}_norm.scale / ln_{q,k}.weight entries
+            qs = self.param("q_scale", nn.initializers.ones, (hd,), jnp.float32)
+            ks = self.param("k_scale", nn.initializers.ones, (hd,), jnp.float32)
+            q = _rms(q.reshape(shape)) * qs.astype(self.dtype)
+            k = _rms(k.reshape(shape)) * ks.astype(self.dtype)
+            return q, k, v.reshape(shape)
 
 
 def _rms(x, eps=1e-6):
@@ -285,43 +296,41 @@ class DoubleBlock(nn.Module):
         t_sh1, t_sc1, t_g1, t_sh2, t_sc2, t_g2 = Modulation(2, cfg.hidden, dt,
                                                             name="txt_mod")(vec)
 
-        img_n = _modulate(nn.LayerNorm(use_scale=False, use_bias=False,
-                                       dtype=dt)(img), i_sh1, i_sc1)
-        txt_n = _modulate(nn.LayerNorm(use_scale=False, use_bias=False,
-                                       dtype=dt)(txt), t_sh1, t_sc1)
+        img_n = _norm_modulate(img, i_sh1, i_sc1, dt)
+        txt_n = _norm_modulate(txt, t_sh1, t_sc1, dt)
         iq, ik, iv = _QKV(cfg.hidden, cfg.heads, dt, cfg.qk_norm, name="img_qkv")(img_n)
         tq, tk, tv = _QKV(cfg.hidden, cfg.heads, dt, cfg.qk_norm, name="txt_qkv")(txt_n)
-        if pe_img is not None:
-            iq, ik = apply_rope(iq, pe_img), apply_rope(ik, pe_img)
-            tq, tk = apply_rope(tq, pe_txt), apply_rope(tk, pe_txt)
-
-        if sp_axis is None:
+        with device_scope("attn_proj"):
+            if pe_img is not None:
+                iq, ik = apply_rope(iq, pe_img), apply_rope(ik, pe_img)
+                tq, tk = apply_rope(tq, pe_txt), apply_rope(tk, pe_txt)
             q = jnp.concatenate([tq, iq], axis=1)
-            k = jnp.concatenate([tk, ik], axis=1)
-            v = jnp.concatenate([tv, iv], axis=1)
-            out = full_attention(q, k, v,
+            if sp_axis is None:
+                k = jnp.concatenate([tk, ik], axis=1)
+                v = jnp.concatenate([tv, iv], axis=1)
+        if sp_axis is None:
+            out = full_attention(q, k, v,                  # cdt.attn_core
                                  prefer_flash=cfg.attn_backend == "flash")
         else:
-            q = jnp.concatenate([tq, iq], axis=1)
             out = joint_ring_attention(q, tk, tv, ik, iv, sp_axis)
         T = txt.shape[1]
-        t_out, i_out = out[:, :T], out[:, T:]
-        B = img.shape[0]
-        i_out = i_out.reshape(B, -1, cfg.hidden)
-        t_out = t_out.reshape(B, T, cfg.hidden)
-        img = img + i_g1 * nn.Dense(cfg.hidden, dtype=dt, name="img_proj")(i_out)
-        txt = txt + t_g1 * nn.Dense(cfg.hidden, dtype=dt, name="txt_proj")(t_out)
+        with device_scope("attn_proj"):
+            t_out, i_out = out[:, :T], out[:, T:]
+            B = img.shape[0]
+            i_out = i_out.reshape(B, -1, cfg.hidden)
+            t_out = t_out.reshape(B, T, cfg.hidden)
+            img = img + i_g1 * nn.Dense(cfg.hidden, dtype=dt, name="img_proj")(i_out)
+            txt = txt + t_g1 * nn.Dense(cfg.hidden, dtype=dt, name="txt_proj")(t_out)
 
-        img_m = _modulate(nn.LayerNorm(use_scale=False, use_bias=False,
-                                       dtype=dt)(img), i_sh2, i_sc2)
-        txt_m = _modulate(nn.LayerNorm(use_scale=False, use_bias=False,
-                                       dtype=dt)(txt), t_sh2, t_sc2)
-        img_h = nn.Dense(cfg.hidden * 4, dtype=dt, name="img_mlp_up")(img_m)
-        img = img + i_g2 * nn.Dense(cfg.hidden, dtype=dt,
-                                    name="img_mlp_down")(nn.gelu(img_h))
-        txt_h = nn.Dense(cfg.hidden * 4, dtype=dt, name="txt_mlp_up")(txt_m)
-        txt = txt + t_g2 * nn.Dense(cfg.hidden, dtype=dt,
-                                    name="txt_mlp_down")(nn.gelu(txt_h))
+        img_m = _norm_modulate(img, i_sh2, i_sc2, dt)
+        txt_m = _norm_modulate(txt, t_sh2, t_sc2, dt)
+        with device_scope("ffn"):
+            img_h = nn.Dense(cfg.hidden * 4, dtype=dt, name="img_mlp_up")(img_m)
+            img = img + i_g2 * nn.Dense(cfg.hidden, dtype=dt,
+                                        name="img_mlp_down")(nn.gelu(img_h))
+            txt_h = nn.Dense(cfg.hidden * 4, dtype=dt, name="txt_mlp_up")(txt_m)
+            txt = txt + t_g2 * nn.Dense(cfg.hidden, dtype=dt,
+                                        name="txt_mlp_down")(nn.gelu(txt_h))
         return img, txt
 
 
@@ -336,24 +345,28 @@ class SingleBlock(nn.Module):
         cfg = self.config
         dt = cfg.jnp_dtype
         sh, sc, g = Modulation(1, cfg.hidden, dt, name="mod")(vec)
-        xn = _modulate(nn.LayerNorm(use_scale=False, use_bias=False, dtype=dt)(x),
-                       sh, sc)
+        xn = _norm_modulate(x, sh, sc, dt)
         q, k, v = _QKV(cfg.hidden, cfg.heads, dt, cfg.qk_norm, name="qkv")(xn)
         if pe_full is not None:
-            q, k = apply_rope(q, pe_full), apply_rope(k, pe_full)
+            with device_scope("attn_proj"):
+                q, k = apply_rope(q, pe_full), apply_rope(k, pe_full)
         if sp_axis is None:
-            out = full_attention(q, k, v,
+            out = full_attention(q, k, v,                  # cdt.attn_core
                                  prefer_flash=cfg.attn_backend == "flash")
         else:
             # txt tokens lead the sequence on every shard
-            tk, ik = k[:, :txt_len], k[:, txt_len:]
-            tv, iv = v[:, :txt_len], v[:, txt_len:]
+            with device_scope("attn_proj"):
+                tk, ik = k[:, :txt_len], k[:, txt_len:]
+                tv, iv = v[:, :txt_len], v[:, txt_len:]
             out = joint_ring_attention(q, tk, tv, ik, iv, sp_axis)
         B, N, _, _ = out.shape
-        out = out.reshape(B, N, cfg.hidden)
-        mlp_in = nn.Dense(cfg.hidden * 4, dtype=dt, name="mlp_up")(xn)
-        fused = jnp.concatenate([out, nn.gelu(mlp_in)], axis=-1)
-        return x + g * nn.Dense(cfg.hidden, dtype=dt, name="out")(fused)
+        # one product takes the attention's output and the MLP's: four
+        # fifths of its rows are the MLP's, so it counts as the FFN
+        with device_scope("ffn"):
+            out = out.reshape(B, N, cfg.hidden)
+            mlp_in = nn.Dense(cfg.hidden * 4, dtype=dt, name="mlp_up")(xn)
+            fused = jnp.concatenate([out, nn.gelu(mlp_in)], axis=-1)
+            return x + g * nn.Dense(cfg.hidden, dtype=dt, name="out")(fused)
 
 
 class DiT(nn.Module):
@@ -370,73 +383,82 @@ class DiT(nn.Module):
         B, H, W, C = x.shape
         p = cfg.patch_size
 
-        tokens = patchify(x.astype(dt), p)
-        img = nn.Dense(cfg.hidden, dtype=dt, name="img_in")(tokens)
-        pe_img = pe_txt = pe_full = None
-        if cfg.pos_embed == "rope":
-            # per-head rotary positions (FLUX layout); in sp mode the row
-            # ids are offset by this shard's global row-block start so a
-            # sharded run rotates identically to the unsharded one
-            if sp_axis is None:
-                ids_img = image_ids(H // p, W // p)
+        with device_scope("norm_mod"):
+            tokens = patchify(x.astype(dt), p)
+            img = nn.Dense(cfg.hidden, dtype=dt, name="img_in")(tokens)
+            pe_img = pe_txt = pe_full = None
+            if cfg.pos_embed == "rope":
+                # per-head rotary positions (FLUX layout); in sp mode the row
+                # ids are offset by this shard's global row-block start so a
+                # sharded run rotates identically to the unsharded one
+                if sp_axis is None:
+                    ids_img = image_ids(H // p, W // p)
+                else:
+                    idx = jax.lax.axis_index(sp_axis)
+                    ids_img = image_ids(H // p, W // p,
+                                        row_offset=idx * (H // p))
+                ids_txt = jnp.zeros((context.shape[1], 3), jnp.int32)
+                pe_img = rope_freqs(ids_img, cfg.axes_dim, cfg.rope_theta)
+                pe_txt = rope_freqs(ids_txt, cfg.axes_dim, cfg.rope_theta)
+                pe_full = (jnp.concatenate([pe_txt[0], pe_img[0]], axis=0),
+                           jnp.concatenate([pe_txt[1], pe_img[1]], axis=0))
+            elif cfg.pos_embed == "learned":
+                # SD3: trained (max × max) table, CENTER-cropped to the patch
+                # grid; in sp mode each shard crops its own row block of the
+                # global grid so the sharded run adds identical positions
+                m = cfg.pos_embed_max_size
+                table = self.param("pos_emb", nn.initializers.normal(0.01),
+                                   (m * m, cfg.hidden)).reshape(m, m, cfg.hidden)
+                hp, wp = H // p, W // p
+                n_sh = 1 if sp_axis is None else _axis_size(sp_axis)
+                gh = hp * n_sh                       # global patch rows
+                if gh > m or wp > m:
+                    raise ValueError(
+                        f"sample grid {gh}×{wp} exceeds the learned position "
+                        f"table ({m}×{m}) — SD3-family models cannot sample "
+                        "beyond pos_embed_max_size patches per side")
+                top, left = (m - gh) // 2, (m - wp) // 2
+                rows = table[:, left:left + wp]
+                if sp_axis is None:
+                    pos = rows[top:top + hp]
+                else:
+                    idx = jax.lax.axis_index(sp_axis)
+                    pos = jax.lax.dynamic_slice_in_dim(
+                        rows, top + idx * hp, hp, axis=0)
+                img = img + pos.reshape(hp * wp, cfg.hidden)[None].astype(dt)
+            elif sp_axis is None:
+                pos = sincos_2d(H // p, W // p, cfg.hidden)
+                img = img + pos[None].astype(dt)
             else:
+                # x is this shard's row block of the global image: build the
+                # global position table and slice this shard's rows
+                n_sh = _axis_size(sp_axis)
                 idx = jax.lax.axis_index(sp_axis)
-                ids_img = image_ids(H // p, W // p,
-                                    row_offset=idx * (H // p))
-            ids_txt = jnp.zeros((context.shape[1], 3), jnp.int32)
-            pe_img = rope_freqs(ids_img, cfg.axes_dim, cfg.rope_theta)
-            pe_txt = rope_freqs(ids_txt, cfg.axes_dim, cfg.rope_theta)
-            pe_full = (jnp.concatenate([pe_txt[0], pe_img[0]], axis=0),
-                       jnp.concatenate([pe_txt[1], pe_img[1]], axis=0))
-        elif cfg.pos_embed == "learned":
-            # SD3: trained (max × max) table, CENTER-cropped to the patch
-            # grid; in sp mode each shard crops its own row block of the
-            # global grid so the sharded run adds identical positions
-            m = cfg.pos_embed_max_size
-            table = self.param("pos_emb", nn.initializers.normal(0.01),
-                               (m * m, cfg.hidden)).reshape(m, m, cfg.hidden)
-            hp, wp = H // p, W // p
-            n_sh = 1 if sp_axis is None else _axis_size(sp_axis)
-            gh = hp * n_sh                       # global patch rows
-            if gh > m or wp > m:
-                raise ValueError(
-                    f"sample grid {gh}×{wp} exceeds the learned position "
-                    f"table ({m}×{m}) — SD3-family models cannot sample "
-                    "beyond pos_embed_max_size patches per side")
-            top, left = (m - gh) // 2, (m - wp) // 2
-            rows = table[:, left:left + wp]
-            if sp_axis is None:
-                pos = rows[top:top + hp]
-            else:
-                idx = jax.lax.axis_index(sp_axis)
-                pos = jax.lax.dynamic_slice_in_dim(
-                    rows, top + idx * hp, hp, axis=0)
-            img = img + pos.reshape(hp * wp, cfg.hidden)[None].astype(dt)
-        elif sp_axis is None:
-            pos = sincos_2d(H // p, W // p, cfg.hidden)
-            img = img + pos[None].astype(dt)
-        else:
-            # x is this shard's row block of the global image: build the
-            # global position table and slice this shard's rows
-            n_sh = _axis_size(sp_axis)
-            idx = jax.lax.axis_index(sp_axis)
-            pos_full = sincos_2d((H * n_sh) // p, W // p, cfg.hidden)
-            per = pos_full.shape[0] // n_sh
-            pos = jax.lax.dynamic_slice_in_dim(pos_full, idx * per, per, axis=0)
-            img = img + pos[None].astype(dt)
+                pos_full = sincos_2d((H * n_sh) // p, W // p, cfg.hidden)
+                per = pos_full.shape[0] // n_sh
+                pos = jax.lax.dynamic_slice_in_dim(pos_full, idx * per, per, axis=0)
+                img = img + pos[None].astype(dt)
 
-        txt = nn.Dense(cfg.hidden, dtype=dt, name="txt_in")(context.astype(dt))
+            txt = nn.Dense(cfg.hidden, dtype=dt, name="txt_in")(context.astype(dt))
 
         # FLUX conditioning vector: summed MLPEmbedder outputs (time_in /
         # vector_in / guidance_in) — the exact functional form of the
         # published checkpoints, so weights port without surgery
-        vec = MLPEmbedder(cfg.hidden, dt, name="time_in")(
-            timestep_embedding(t * 1000.0, 256).astype(dt))
-        vec = vec + MLPEmbedder(cfg.hidden, dt, name="vector_in")(pooled.astype(dt))
+        # (MLPEmbedder opens cdt.norm_mod itself; the sums go with it)
+        with device_scope("norm_mod"):
+            t_emb = timestep_embedding(t * 1000.0, 256).astype(dt)
+            pooled = pooled.astype(dt)
+        vec = MLPEmbedder(cfg.hidden, dt, name="time_in")(t_emb)
+        pooled_vec = MLPEmbedder(cfg.hidden, dt, name="vector_in")(pooled)
+        with device_scope("norm_mod"):
+            vec = vec + pooled_vec
         if cfg.guidance_embed:
-            gvec = guidance if guidance is not None else jnp.full((B,), 3.5)
-            vec = vec + MLPEmbedder(cfg.hidden, dt, name="guidance_in")(
-                timestep_embedding(gvec * 1000.0, 256).astype(dt))
+            with device_scope("norm_mod"):
+                gvec = guidance if guidance is not None else jnp.full((B,), 3.5)
+                g_emb = timestep_embedding(gvec * 1000.0, 256).astype(dt)
+            g_vec = MLPEmbedder(cfg.hidden, dt, name="guidance_in")(g_emb)
+            with device_scope("norm_mod"):
+                vec = vec + g_vec
 
         DBlock = (nn.remat(DoubleBlock, static_argnums=(4,))
                   if cfg.remat else DoubleBlock)
@@ -445,22 +467,21 @@ class DiT(nn.Module):
         for i in range(cfg.depth_double):
             img, txt = DBlock(cfg, name=f"double_{i}")(
                 img, txt, vec, sp_axis, pe_img, pe_txt)
-        xcat = jnp.concatenate([txt, img], axis=1)
+        with device_scope("attn_proj"):
+            xcat = jnp.concatenate([txt, img], axis=1)
         T = txt.shape[1]
         for i in range(cfg.depth_single):
             xcat = SBlock(cfg, name=f"single_{i}")(xcat, vec, T, sp_axis,
                                                    pe_full)
-        img = xcat[:, T:]
-
         sh, sc, _ = Modulation(1, cfg.hidden, dt, name="final_mod")(vec)
-        img = _modulate(nn.LayerNorm(use_scale=False, use_bias=False, dtype=dt)(img),
-                        sh, sc)
-        out = nn.Dense(p * p * C, dtype=jnp.float32,
-                       kernel_init=nn.initializers.zeros, name="img_out")(
-            img.astype(jnp.float32))
-        # in sp mode (H, W) is the local row block — output stays local,
-        # so the sampler update is shard-local too
-        return unpatchify(out, (H, W), p, C)
+        img = _norm_modulate(xcat[:, T:], sh, sc, dt)
+        with device_scope("norm_mod"):
+            out = nn.Dense(p * p * C, dtype=jnp.float32,
+                           kernel_init=nn.initializers.zeros, name="img_out")(
+                img.astype(jnp.float32))
+            # in sp mode (H, W) is the local row block — output stays local,
+            # so the sampler update is shard-local too
+            return unpatchify(out, (H, W), p, C)
 
 
 def init_dit(config: DiTConfig, rng: jax.Array,

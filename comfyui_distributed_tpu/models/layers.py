@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..telemetry.device_scopes import device_scope
+
 
 def jit_apply(owner, module, attr: str = "_apply", **jit_kwargs):
     """Lazily-jitted ``module.apply`` cached on ``owner`` under ``attr``.
@@ -86,17 +88,18 @@ class ResBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, emb: jax.Array) -> jax.Array:
-        h = GroupNorm32()(x)
-        h = nn.silu(h)
-        h = nn.Conv(self.out_channels, (3, 3), padding=1, dtype=self.dtype, name="conv1")(h)
-        emb_out = nn.Dense(self.out_channels, dtype=self.dtype, name="time_proj")(nn.silu(emb))
-        h = h + emb_out[:, None, None, :]
-        h = GroupNorm32()(h)
-        h = nn.silu(h)
-        h = nn.Conv(self.out_channels, (3, 3), padding=1, dtype=self.dtype, name="conv2")(h)
-        if x.shape[-1] != self.out_channels:
-            x = nn.Conv(self.out_channels, (1, 1), dtype=self.dtype, name="skip")(x)
-        return x + h
+        with device_scope("resnet"):
+            h = GroupNorm32()(x)
+            h = nn.silu(h)
+            h = nn.Conv(self.out_channels, (3, 3), padding=1, dtype=self.dtype, name="conv1")(h)
+            emb_out = nn.Dense(self.out_channels, dtype=self.dtype, name="time_proj")(nn.silu(emb))
+            h = h + emb_out[:, None, None, :]
+            h = GroupNorm32()(h)
+            h = nn.silu(h)
+            h = nn.Conv(self.out_channels, (3, 3), padding=1, dtype=self.dtype, name="conv2")(h)
+            if x.shape[-1] != self.out_channels:
+                x = nn.Conv(self.out_channels, (1, 1), dtype=self.dtype, name="skip")(x)
+            return x + h
 
 
 class _ProjKernel(nn.Module):
@@ -153,24 +156,28 @@ class Attention(nn.Module):
         if choice.tier == "fused":
             from ..ops.flash_attention import fused_qkv_attention
 
-            wq = _ProjKernel(inner, name="to_q")(C)
-            wk = _ProjKernel(inner, name="to_k")(C)
-            wv = _ProjKernel(inner, name="to_v")(C)
-            out = fused_qkv_attention(
-                x.astype(self.dtype), wq.astype(self.dtype),
-                wk.astype(self.dtype), wv.astype(self.dtype),
-                self.num_heads, block_q=choice.block_q,
-                block_k=choice.block_k)
+            # the projections run inside the kernel: all of it is the core
+            with device_scope("attn_core"):
+                wq = _ProjKernel(inner, name="to_q")(C)
+                wk = _ProjKernel(inner, name="to_k")(C)
+                wv = _ProjKernel(inner, name="to_v")(C)
+                out = fused_qkv_attention(
+                    x.astype(self.dtype), wq.astype(self.dtype),
+                    wk.astype(self.dtype), wv.astype(self.dtype),
+                    self.num_heads, block_q=choice.block_q,
+                    block_k=choice.block_k)
         else:
-            q = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="to_q")(x)
-            k = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="to_k")(ctx)
-            v = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="to_v")(ctx)
-            q = q.reshape(B, N, self.num_heads, self.head_dim)
-            k = k.reshape(B, M, self.num_heads, self.head_dim)
-            v = v.reshape(B, M, self.num_heads, self.head_dim)
-            out = full_attention(q, k, v, choice=choice)
-        out = out.reshape(B, N, inner)
-        return nn.Dense(x.shape[-1], dtype=self.dtype, name="to_out")(out)
+            with device_scope("attn_proj"):
+                q = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="to_q")(x)
+                k = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="to_k")(ctx)
+                v = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="to_v")(ctx)
+                q = q.reshape(B, N, self.num_heads, self.head_dim)
+                k = k.reshape(B, M, self.num_heads, self.head_dim)
+                v = v.reshape(B, M, self.num_heads, self.head_dim)
+            out = full_attention(q, k, v, choice=choice)    # cdt.attn_core
+        with device_scope("attn_proj"):
+            out = out.reshape(B, N, inner)
+            return nn.Dense(x.shape[-1], dtype=self.dtype, name="to_out")(out)
 
 
 class GEGLU(nn.Module):
@@ -180,11 +187,12 @@ class GEGLU(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         dim = x.shape[-1]
-        h = nn.Dense(dim * self.mult * 2, dtype=self.dtype, name="proj_in")(x)
-        h, gate = jnp.split(h, 2, axis=-1)
-        # LDM's GEGLU uses exact (erf) gelu; flax defaults to tanh approx
-        h = h * nn.gelu(gate, approximate=False)
-        return nn.Dense(dim, dtype=self.dtype, name="proj_out")(h)
+        with device_scope("ffn"):
+            h = nn.Dense(dim * self.mult * 2, dtype=self.dtype, name="proj_in")(x)
+            h, gate = jnp.split(h, 2, axis=-1)
+            # LDM's GEGLU uses exact (erf) gelu; flax defaults to tanh approx
+            h = h * nn.gelu(gate, approximate=False)
+            return nn.Dense(dim, dtype=self.dtype, name="proj_out")(h)
 
 
 class TransformerBlock(nn.Module):
@@ -196,14 +204,26 @@ class TransformerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, context: Optional[jax.Array]) -> jax.Array:
-        x = x + Attention(self.num_heads, self.head_dim, self.dtype, name="attn1")(
-            nn.LayerNorm(dtype=self.dtype)(x)
-        )
-        x = x + Attention(self.num_heads, self.head_dim, self.dtype, name="attn2")(
-            nn.LayerNorm(dtype=self.dtype)(x), context
-        )
-        x = x + GEGLU(dtype=self.dtype, name="ff")(nn.LayerNorm(dtype=self.dtype)(x))
-        return x
+        # each residual add goes with the product it follows (the compiler
+        # fuses it into that product's epilogue)
+        attn1 = Attention(self.num_heads, self.head_dim, self.dtype, name="attn1")
+        attn2 = Attention(self.num_heads, self.head_dim, self.dtype, name="attn2")
+        ff = GEGLU(dtype=self.dtype, name="ff")
+        with device_scope("norm_mod"):
+            h = nn.LayerNorm(dtype=self.dtype)(x)
+        h = attn1(h)
+        with device_scope("attn_proj"):
+            x = x + h
+        with device_scope("norm_mod"):
+            h = nn.LayerNorm(dtype=self.dtype)(x)
+        h = attn2(h, context)
+        with device_scope("attn_proj"):
+            x = x + h
+        with device_scope("norm_mod"):
+            h = nn.LayerNorm(dtype=self.dtype)(x)
+        h = ff(h)
+        with device_scope("ffn"):
+            return x + h
 
 
 class SpatialTransformer(nn.Module):
@@ -217,14 +237,17 @@ class SpatialTransformer(nn.Module):
     def __call__(self, x: jax.Array, context: Optional[jax.Array]) -> jax.Array:
         B, H, W, C = x.shape
         head_dim = C // self.num_heads
-        h = GroupNorm32()(x)
-        h = nn.Dense(C, dtype=self.dtype, name="proj_in")(h.reshape(B, H * W, C))
+        with device_scope("norm_mod"):
+            h = GroupNorm32()(x)
+        with device_scope("attn_proj"):
+            h = nn.Dense(C, dtype=self.dtype, name="proj_in")(h.reshape(B, H * W, C))
         for i in range(self.depth):
             h = TransformerBlock(self.num_heads, head_dim, self.dtype, name=f"block_{i}")(
                 h, context
             )
-        h = nn.Dense(C, dtype=self.dtype, name="proj_out")(h)
-        return x + h.reshape(B, H, W, C)
+        with device_scope("attn_proj"):
+            h = nn.Dense(C, dtype=self.dtype, name="proj_out")(h)
+            return x + h.reshape(B, H, W, C)
 
 
 class Downsample(nn.Module):
@@ -233,7 +256,8 @@ class Downsample(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        return nn.Conv(self.out_channels, (3, 3), strides=2, padding=1, dtype=self.dtype)(x)
+        with device_scope("resnet"):
+            return nn.Conv(self.out_channels, (3, 3), strides=2, padding=1, dtype=self.dtype)(x)
 
 
 class Upsample(nn.Module):
@@ -243,5 +267,6 @@ class Upsample(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         B, H, W, C = x.shape
-        x = jax.image.resize(x, (B, H * 2, W * 2, C), method="nearest")
-        return nn.Conv(self.out_channels, (3, 3), padding=1, dtype=self.dtype)(x)
+        with device_scope("resnet"):
+            x = jax.image.resize(x, (B, H * 2, W * 2, C), method="nearest")
+            return nn.Conv(self.out_channels, (3, 3), padding=1, dtype=self.dtype)(x)

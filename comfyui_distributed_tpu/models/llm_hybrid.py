@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import delta_rule, expert_share, latent_attention as mla_ops
+from ..telemetry.device_scopes import device_scope
 from .llm_model import LLMModel
 
 
@@ -319,21 +320,35 @@ def _swiglu(x, ffn, dtype):
 
 
 def _count_held(cfg: LLMConfig, idx):
-    return expert_share.held_slots(idx, cfg.first_expert,
-                                   cfg.num_experts).sum().astype(jnp.int32)
+    with device_scope("llm_router"):
+        return expert_share.held_slots(
+            idx, cfg.first_expert, cfg.num_experts).sum().astype(jnp.int32)
 
 
 def _stack_counts(held):
-    return jnp.stack(held) if held else jnp.zeros((0,), jnp.int32)
+    with device_scope("llm_router"):
+        return jnp.stack(held) if held else jnp.zeros((0,), jnp.int32)
+
+
+def _pre_norm(h, weight, eps: float):
+    """A sublayer's RMS norm of the residual stream."""
+    with device_scope("llm_norm"):
+        return rms_norm(h, weight, eps)
+
+
+def _embed(params, ids):
+    with device_scope("llm_head"):
+        return params["embed"][ids].astype(jnp.float32)
 
 
 def logits_of(cfg: LLMConfig, params, h):
     """Final norm and the (sliced) head; float32."""
     dtype = jnp.dtype(cfg.dtype)
-    x = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-    return jnp.einsum("...d,vd->...v", x.astype(dtype),
-                      params["head"].astype(dtype),
-                      preferred_element_type=jnp.float32)
+    with device_scope("llm_head"):
+        x = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+        return jnp.einsum("...d,vd->...v", x.astype(dtype),
+                          params["head"].astype(dtype),
+                          preferred_element_type=jnp.float32)
 
 
 # --- prefill ---------------------------------------------------------------
@@ -367,46 +382,50 @@ def prefill(cfg: LLMConfig, params, ids, max_len: int,
     dtype = jnp.dtype(cfg.dtype)
     T = ids.shape[0]
     K = cfg.short_conv_kernel_size
-    positions = jnp.arange(T)
     chunk = math.gcd(T, 64)
-    cache = empty_cache(cfg, max_len)
+    with device_scope("llm_attn"):
+        positions = jnp.arange(T)
+        cache = empty_cache(cfg, max_len)
     held = []
-    h = params["embed"][ids].astype(jnp.float32)
+    h = _embed(params, ids)
     kda_at = mla_at = 0
     for i, layer in enumerate(params["layers"]):
-        x = rms_norm(h, layer["norm1"], cfg.rms_norm_eps)
+        x = _pre_norm(h, layer["norm1"], cfg.rms_norm_eps)
         if cfg.is_mla(i):
             p = layer["mla"]
-            q_nope, q_rope, c, kr, gate = _split_mla_in(
-                cfg, p, _dot(x, p["w_in"], dtype), positions)
-            o = mla_ops.mla_naive(q_nope, q_rope, c, kr, p["w_b"],
-                                  _mla_scale(cfg), dtype)
-            h = h + _mla_out(p, o, gate, dtype)
-            cache["c"][mla_at] = cache["c"][mla_at].at[:T].set(
-                c.astype(dtype))
-            cache["kr"][mla_at] = cache["kr"][mla_at].at[:T].set(
-                kr.astype(dtype))
+            with device_scope("llm_attn"):
+                q_nope, q_rope, c, kr, gate = _split_mla_in(
+                    cfg, p, _dot(x, p["w_in"], dtype), positions)
+                o = mla_ops.mla_naive(q_nope, q_rope, c, kr, p["w_b"],
+                                      _mla_scale(cfg), dtype)
+                h = h + _mla_out(p, o, gate, dtype)
+                cache["c"][mla_at] = cache["c"][mla_at].at[:T].set(
+                    c.astype(dtype))
+                cache["kr"][mla_at] = cache["kr"][mla_at].at[:T].set(
+                    kr.astype(dtype))
             mla_at += 1
         else:
             p = layer["kda"]
-            qkv, g_raw, beta_raw, gate = _split_kda_in(
-                cfg, _dot(x, p["w_in"], dtype))
-            # the cache keeps the convolution's inputs as the served dtype
-            # holds them, so prefill convolves what decode will see
-            qkv = qkv.astype(dtype)
-            padded = jnp.concatenate(
-                [jnp.zeros((K - 1, 3, cfg.kda_width), dtype), qkv]
-            ).astype(jnp.float32)
-            conv = sum(padded[j:j + T] * p["conv"][:, j] for j in range(K))
-            q, k, v, g, beta = _kda_gates(cfg, p, conv, g_raw, beta_raw)
-            o, S = delta_rule.kda_chunked(
-                q, k, v, g, beta, cache["S"][kda_at],
-                1.0 / math.sqrt(cfg.head_dim), chunk)
-            h = h + _kda_out(cfg, p, o, gate, dtype)
-            cache["S"][kda_at] = S
-            cache["conv"][kda_at] = qkv[T - (K - 1):]
+            with device_scope("llm_attn"):
+                qkv, g_raw, beta_raw, gate = _split_kda_in(
+                    cfg, _dot(x, p["w_in"], dtype))
+                # the cache keeps the convolution's inputs as the served
+                # dtype holds them, so prefill convolves what decode will see
+                qkv = qkv.astype(dtype)
+                padded = jnp.concatenate(
+                    [jnp.zeros((K - 1, 3, cfg.kda_width), dtype), qkv]
+                ).astype(jnp.float32)
+                conv = sum(padded[j:j + T] * p["conv"][:, j]
+                           for j in range(K))
+                q, k, v, g, beta = _kda_gates(cfg, p, conv, g_raw, beta_raw)
+                o, S = delta_rule.kda_chunked(
+                    q, k, v, g, beta, cache["S"][kda_at],
+                    1.0 / math.sqrt(cfg.head_dim), chunk)
+                h = h + _kda_out(cfg, p, o, gate, dtype)
+                cache["S"][kda_at] = S
+                cache["conv"][kda_at] = qkv[T - (K - 1):]
             kda_at += 1
-        x = rms_norm(h, layer["norm2"], cfg.rms_norm_eps)
+        x = _pre_norm(h, layer["norm2"], cfg.rms_norm_eps)
         if cfg.is_moe(i):
             m = layer["moe"]
             idx, w = expert_share.route(x, m["w_router"], m["router_bias"],
@@ -415,10 +434,12 @@ def prefill(cfg: LLMConfig, params, ids, max_len: int,
             y, _ = expert_share.held_part(
                 x, idx, w, m["e_gu"], m["e_down"], cfg.first_expert, dtype,
                 cfg.routing, _ACT)
-            h = h + y + _swiglu(x, m["shared"], dtype)
+            with device_scope("llm_shared_ffn"):
+                h = h + y + _swiglu(x, m["shared"], dtype)
             held.append(_count_held(cfg, idx))
         else:
-            h = h + _swiglu(x, layer["ffn"], dtype)
+            with device_scope("llm_shared_ffn"):
+                h = h + _swiglu(x, layer["ffn"], dtype)
     logits = logits_of(cfg, params, h if all_logits else h[-1])
     return logits, cache, _stack_counts(held)
 
@@ -431,54 +452,61 @@ def decode_step(cfg: LLMConfig, params, cache: dict, token, pos):
     cache. Answers ``(logits [V] f32, cache, held [expert layers])``."""
     dtype = jnp.dtype(cfg.dtype)
     K = cfg.short_conv_kernel_size
-    positions = jnp.reshape(pos, (1,))
+    with device_scope("llm_attn"):
+        positions = jnp.reshape(pos, (1,))
     cache = {k: list(v) for k, v in cache.items()}
     held = []
-    h = params["embed"][token].astype(jnp.float32)
+    h = _embed(params, token)
     kda_at = mla_at = 0
     for i, layer in enumerate(params["layers"]):
-        x = rms_norm(h, layer["norm1"], cfg.rms_norm_eps)
+        x = _pre_norm(h, layer["norm1"], cfg.rms_norm_eps)
         if cfg.is_mla(i):
             p = layer["mla"]
-            q_nope, q_rope, c, kr, gate = _split_mla_in(
-                cfg, p, _dot(x[None], p["w_in"], dtype), positions)
-            c_cache = jax.lax.dynamic_update_slice(
-                cache["c"][mla_at], c.astype(dtype), (pos, 0))
-            kr_cache = jax.lax.dynamic_update_slice(
-                cache["kr"][mla_at], kr.astype(dtype), (pos, 0))
-            o = mla_ops.mla_absorbed_step(
-                q_nope[0], q_rope[0], c_cache, kr_cache, pos, p["w_b"],
-                _mla_scale(cfg), dtype)
-            h = h + _mla_out(p, o, gate[0], dtype)
+            with device_scope("llm_attn"):
+                q_nope, q_rope, c, kr, gate = _split_mla_in(
+                    cfg, p, _dot(x[None], p["w_in"], dtype), positions)
+                c_cache = jax.lax.dynamic_update_slice(
+                    cache["c"][mla_at], c.astype(dtype), (pos, 0))
+                kr_cache = jax.lax.dynamic_update_slice(
+                    cache["kr"][mla_at], kr.astype(dtype), (pos, 0))
+                o = mla_ops.mla_absorbed_step(
+                    q_nope[0], q_rope[0], c_cache, kr_cache, pos, p["w_b"],
+                    _mla_scale(cfg), dtype)
+                h = h + _mla_out(p, o, gate[0], dtype)
             cache["c"][mla_at], cache["kr"][mla_at] = c_cache, kr_cache
             mla_at += 1
         else:
             p = layer["kda"]
-            qkv, g_raw, beta_raw, gate = _split_kda_in(
-                cfg, _dot(x, p["w_in"], dtype))
-            window = jnp.concatenate(
-                [cache["conv"][kda_at], qkv.astype(dtype)[None]])  # [K,3,kw]
-            conv = (window.astype(jnp.float32)
-                    * jnp.swapaxes(p["conv"], 0, 1)).sum(0)
-            q, k, v, g, beta = _kda_gates(cfg, p, conv, g_raw, beta_raw)
-            S, o = delta_rule.kda_step(
-                cache["S"][kda_at], q, k, v, g, beta,
-                1.0 / math.sqrt(cfg.head_dim))
-            h = h + _kda_out(cfg, p, o, gate, dtype)
-            cache["S"][kda_at] = S
-            cache["conv"][kda_at] = window[1:]
+            with device_scope("llm_attn"):
+                qkv, g_raw, beta_raw, gate = _split_kda_in(
+                    cfg, _dot(x, p["w_in"], dtype))
+                window = jnp.concatenate(
+                    [cache["conv"][kda_at],
+                     qkv.astype(dtype)[None]])                  # [K,3,kw]
+                conv = (window.astype(jnp.float32)
+                        * jnp.swapaxes(p["conv"], 0, 1)).sum(0)
+                q, k, v, g, beta = _kda_gates(cfg, p, conv, g_raw, beta_raw)
+                S, o = delta_rule.kda_step(
+                    cache["S"][kda_at], q, k, v, g, beta,
+                    1.0 / math.sqrt(cfg.head_dim))
+                h = h + _kda_out(cfg, p, o, gate, dtype)
+                cache["S"][kda_at] = S
+                cache["conv"][kda_at] = window[1:]
             kda_at += 1
-        x = rms_norm(h, layer["norm2"], cfg.rms_norm_eps)
+        x = _pre_norm(h, layer["norm2"], cfg.rms_norm_eps)
         if cfg.is_moe(i):
             m = layer["moe"]
             idx, w = expert_share.route(x[None], m["w_router"],
                                         m["router_bias"], cfg.routing)
-            h = h + expert_share.held_part_token(
+            y = expert_share.held_part_token(
                 x, idx[0], w[0], m["e_gu"], m["e_down"], cfg.first_expert,
-                dtype, _ACT) + _swiglu(x[None], m["shared"], dtype)[0]
+                dtype, _ACT)
+            with device_scope("llm_shared_ffn"):
+                h = h + y + _swiglu(x[None], m["shared"], dtype)[0]
             held.append(_count_held(cfg, idx))
         else:
-            h = h + _swiglu(x[None], layer["ffn"], dtype)[0]
+            with device_scope("llm_shared_ffn"):
+                h = h + _swiglu(x[None], layer["ffn"], dtype)[0]
     return logits_of(cfg, params, h), cache, _stack_counts(held)
 
 

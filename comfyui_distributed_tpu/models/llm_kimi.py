@@ -42,9 +42,10 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import expert_share, latent_attention as mla_ops
-from .llm_hybrid import (_ACT, _const, _count_held, _dot, _normal,
-                         _stack_counts, _swiglu, count_params, init_tree,
-                         logits_of, rms_norm)
+from ..telemetry.device_scopes import device_scope
+from .llm_hybrid import (_ACT, _const, _count_held, _dot, _embed, _normal,
+                         _pre_norm, _stack_counts, _swiglu, count_params,
+                         init_tree, logits_of, rms_norm)
 from .llm_model import LLMModel, chunked_prefill
 
 
@@ -258,26 +259,30 @@ def prefill_chunk(cfg: KimiConfig, params, cache: dict, ids, start, n_valid,
     form multiplied for them."""
     dtype = jnp.dtype(cfg.dtype)
     C = ids.shape[0]
-    positions = start + jnp.arange(C)
-    valid = jnp.arange(C) < n_valid
+    with device_scope("llm_attn"):
+        positions = start + jnp.arange(C)
+    with device_scope("llm_router"):
+        valid = jnp.arange(C) < n_valid
     cache = {k: list(v) for k, v in cache.items()}
     held, rows = [], []
-    h = params["embed"][ids].astype(jnp.float32)
+    h = _embed(params, ids)
     for i, layer in enumerate(params["layers"]):
         p = layer["attn"]
-        x = rms_norm(h, layer["norm1"], cfg.rms_norm_eps)
-        q_nope, q_rope, c, kr = _split_in(cfg, p, _dot(x, p["w_a"], dtype),
-                                          positions)
-        cache["c"][i] = jax.lax.dynamic_update_slice(
-            cache["c"][i], c.astype(dtype), (start, 0))
-        cache["kr"][i] = jax.lax.dynamic_update_slice(
-            cache["kr"][i], kr.astype(dtype), (start, 0))
-        o = mla_ops.mla_chunk_attention(
+        x = _pre_norm(h, layer["norm1"], cfg.rms_norm_eps)
+        with device_scope("llm_attn"):
+            q_nope, q_rope, c, kr = _split_in(
+                cfg, p, _dot(x, p["w_a"], dtype), positions)
+            cache["c"][i] = jax.lax.dynamic_update_slice(
+                cache["c"][i], c.astype(dtype), (start, 0))
+            cache["kr"][i] = jax.lax.dynamic_update_slice(
+                cache["kr"][i], kr.astype(dtype), (start, 0))
+        o = mla_ops.mla_chunk_attention(                   # cdt.llm_attn
             q_nope, q_rope, cache["c"][i], cache["kr"][i], start, p["w_b"],
             cfg.softmax_scale, dtype, cfg.attn_block_q, cfg.attn_block_k,
             kernel)
-        h = h + _attn_out(p, o, dtype)
-        x = rms_norm(h, layer["norm2"], cfg.rms_norm_eps)
+        with device_scope("llm_attn"):
+            h = h + _attn_out(p, o, dtype)
+        x = _pre_norm(h, layer["norm2"], cfg.rms_norm_eps)
         if cfg.is_moe(i):
             m = layer["moe"]
             idx, w = expert_share.route(x, m["w_router"], m["router_bias"],
@@ -285,12 +290,19 @@ def prefill_chunk(cfg: KimiConfig, params, cache: dict, ids, start, n_valid,
             y, n_rows = expert_share.held_part(
                 x, idx, w, m["e_gu"], m["e_down"], cfg.first_expert, dtype,
                 cfg.routing, _ACT, valid=valid, tile=cfg.expert_tile)
-            h = h + y + _swiglu(x, m["shared"], dtype)
-            held.append(_count_held(cfg, jnp.where(valid[:, None], idx, -1)))
-            rows.append(n_rows.astype(jnp.int32))
+            with device_scope("llm_shared_ffn"):
+                h = h + y + _swiglu(x, m["shared"], dtype)
+            with device_scope("llm_router"):
+                real = jnp.where(valid[:, None], idx, -1)
+                n_rows = n_rows.astype(jnp.int32)
+            held.append(_count_held(cfg, real))
+            rows.append(n_rows)
         else:
-            h = h + _swiglu(x, layer["ffn"], dtype)
-    logits = logits_of(cfg, params, h if all_logits else h[n_valid - 1])
+            with device_scope("llm_shared_ffn"):
+                h = h + _swiglu(x, layer["ffn"], dtype)
+    with device_scope("llm_head"):
+        last = h if all_logits else h[n_valid - 1]
+    logits = logits_of(cfg, params, last)
     return logits, cache, _stack_counts(held), _stack_counts(rows)
 
 
@@ -310,34 +322,39 @@ def decode_step(cfg: KimiConfig, params, cache: dict, token, pos):
     """One token ``token`` (scalar id) at position ``pos`` through the
     cache; answers as ``llm_hybrid.decode_step``."""
     dtype = jnp.dtype(cfg.dtype)
-    positions = jnp.reshape(pos, (1,))
+    with device_scope("llm_attn"):
+        positions = jnp.reshape(pos, (1,))
     cache = {k: list(v) for k, v in cache.items()}
     held = []
-    h = params["embed"][token].astype(jnp.float32)
+    h = _embed(params, token)
     for i, layer in enumerate(params["layers"]):
         p = layer["attn"]
-        x = rms_norm(h, layer["norm1"], cfg.rms_norm_eps)
-        q_nope, q_rope, c, kr = _split_in(
-            cfg, p, _dot(x[None], p["w_a"], dtype), positions)
-        cache["c"][i] = jax.lax.dynamic_update_slice(
-            cache["c"][i], c.astype(dtype), (pos, 0))
-        cache["kr"][i] = jax.lax.dynamic_update_slice(
-            cache["kr"][i], kr.astype(dtype), (pos, 0))
-        o = mla_ops.mla_absorbed_step(
-            q_nope[0], q_rope[0], cache["c"][i], cache["kr"][i], pos,
-            p["w_b"], cfg.softmax_scale, dtype)
-        h = h + _attn_out(p, o, dtype)
-        x = rms_norm(h, layer["norm2"], cfg.rms_norm_eps)
+        x = _pre_norm(h, layer["norm1"], cfg.rms_norm_eps)
+        with device_scope("llm_attn"):
+            q_nope, q_rope, c, kr = _split_in(
+                cfg, p, _dot(x[None], p["w_a"], dtype), positions)
+            cache["c"][i] = jax.lax.dynamic_update_slice(
+                cache["c"][i], c.astype(dtype), (pos, 0))
+            cache["kr"][i] = jax.lax.dynamic_update_slice(
+                cache["kr"][i], kr.astype(dtype), (pos, 0))
+            o = mla_ops.mla_absorbed_step(
+                q_nope[0], q_rope[0], cache["c"][i], cache["kr"][i], pos,
+                p["w_b"], cfg.softmax_scale, dtype)
+            h = h + _attn_out(p, o, dtype)
+        x = _pre_norm(h, layer["norm2"], cfg.rms_norm_eps)
         if cfg.is_moe(i):
             m = layer["moe"]
             idx, w = expert_share.route(x[None], m["w_router"],
                                         m["router_bias"], cfg.routing)
-            h = h + expert_share.held_part_token(
+            y = expert_share.held_part_token(
                 x, idx[0], w[0], m["e_gu"], m["e_down"], cfg.first_expert,
-                dtype, _ACT) + _swiglu(x[None], m["shared"], dtype)[0]
+                dtype, _ACT)
+            with device_scope("llm_shared_ffn"):
+                h = h + y + _swiglu(x[None], m["shared"], dtype)[0]
             held.append(_count_held(cfg, idx))
         else:
-            h = h + _swiglu(x[None], layer["ffn"], dtype)[0]
+            with device_scope("llm_shared_ffn"):
+                h = h + _swiglu(x[None], layer["ffn"], dtype)[0]
     return logits_of(cfg, params, h), cache, _stack_counts(held)
 
 

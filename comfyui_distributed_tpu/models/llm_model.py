@@ -51,21 +51,30 @@ def chunked_prefill(model: LLMModel, cfg, weights, ids, max_len: int,
     import jax
     import jax.numpy as jnp
 
+    from ..telemetry.device_scopes import device_scope
+
     T = ids.shape[0]
     chunk = min(chunk or cfg.prefill_chunk_tokens, T)
     n = -(-T // chunk)
-    cache = model.empty_cache(cfg, max(max_len, n * chunk))
-    padded = jnp.pad(ids, (0, n * chunk - T)).reshape(n, chunk)
+    # the scan's own bookkeeping (the empty cache, the padded ids, what is
+    # kept of the chunks' answers) counts with the token carry
+    with device_scope("llm_sample"):
+        cache = model.empty_cache(cfg, max(max_len, n * chunk))
+        padded = jnp.pad(ids, (0, n * chunk - T)).reshape(n, chunk)
+        chunks = jnp.arange(n)
 
     def body(cache, xs):
         i, chunk_ids = xs
-        start = i * chunk
+        with device_scope("llm_sample"):
+            start = i * chunk
+            n_valid = jnp.minimum(chunk, T - start)
         logits, cache, held, rows = model.prefill_chunk(
-            cfg, weights, cache, chunk_ids, start,
-            jnp.minimum(chunk, T - start), all_logits, **kw)
+            cfg, weights, cache, chunk_ids, start, n_valid, all_logits,
+            **kw)
         return cache, (logits, held, rows)
 
-    cache, (logits, held, rows) = jax.lax.scan(
-        body, cache, (jnp.arange(n), padded))
-    logits = logits.reshape(n * chunk, -1)[:T] if all_logits else logits[-1]
-    return logits, cache, held.sum(0), rows.sum(0)
+    cache, (logits, held, rows) = jax.lax.scan(body, cache, (chunks, padded))
+    with device_scope("llm_sample"):
+        logits = (logits.reshape(n * chunk, -1)[:T] if all_logits
+                  else logits[-1])
+        return logits, cache, held.sum(0), rows.sum(0)

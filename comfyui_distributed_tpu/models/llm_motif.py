@@ -43,8 +43,10 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import expert_share, latent_attention as mla_ops
-from .llm_hybrid import (_const, _count_held, _dot, _normal, _stack_counts,
-                         count_params, init_tree, logits_of, rms_norm)
+from ..telemetry.device_scopes import device_scope, device_scoped
+from .llm_hybrid import (_const, _count_held, _dot, _embed, _normal,
+                         _pre_norm, _stack_counts, count_params, init_tree,
+                         logits_of, rms_norm)
 from .llm_model import LLMModel
 
 
@@ -230,12 +232,12 @@ def hc_coefficients(cfg: MotifConfig, p, X):
     """``X`` [...,n,D] → ``(H_pre [...,n], H_post [...,n], H_res
     [...,n,n])``, float32."""
     n = cfg.mhc_expansion_rate
-    flat = X.reshape(*X.shape[:-2], n * X.shape[-1])
-    u = jnp.dot(rms_norm(flat, p["gamma"], cfg.rms_norm_eps),
-                p["phi"].astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST)
-    a, b = p["alpha"], p["bias"]
-    with jax.named_scope("mhc_mix"):
+    with device_scope("llm_mix"):
+        flat = X.reshape(*X.shape[:-2], n * X.shape[-1])
+        u = jnp.dot(rms_norm(flat, p["gamma"], cfg.rms_norm_eps),
+                    p["phi"].astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
+        a, b = p["alpha"], p["bias"]
         pre = jax.nn.sigmoid(a[0] * u[..., :n] + b[:n])
         post = 2.0 * jax.nn.sigmoid(a[1] * u[..., n:2 * n] + b[n:2 * n])
         res = sinkhorn(jnp.exp(
@@ -246,13 +248,17 @@ def hc_coefficients(cfg: MotifConfig, p, X):
 
 def hyper_connect(cfg: MotifConfig, p, X, sublayer):
     """One sublayer under the mixed residual. ``sublayer(x [...,D]) ->
-    (y [...,D], extra)``; answers ``(X', extra)``."""
+    (y [...,D], extra)``; answers ``(X', extra)``. The sublayer opens its
+    own device scopes; what is traced here is the mixer's and the norm's."""
     pre, post, res = hc_coefficients(cfg, p, X)
-    x = rms_norm((pre[..., None] * X).sum(-2), p["norm"], cfg.rms_norm_eps)
+    with device_scope("llm_mix"):
+        merged = (pre[..., None] * X).sum(-2)
+    x = _pre_norm(merged, p["norm"], cfg.rms_norm_eps)
     y, extra = sublayer(x)
-    mixed = (res[..., None] * X[..., None, :, :]).sum(-2)
-    out = mixed + post[..., None] * y[..., None, :]
-    return jnp.clip(out, -cfg.hidden_clamp, cfg.hidden_clamp), extra
+    with device_scope("llm_mix"):
+        mixed = (res[..., None] * X[..., None, :, :]).sum(-2)
+        out = mixed + post[..., None] * y[..., None, :]
+        return jnp.clip(out, -cfg.hidden_clamp, cfg.hidden_clamp), extra
 
 
 def poly_norm_gate(cfg: MotifConfig):
@@ -347,16 +353,19 @@ def prefill(cfg: MotifConfig, params, ids, max_len: int,
     ``llm_hybrid.prefill``: ``(logits, cache, held)``."""
     dtype = jnp.dtype(cfg.dtype)
     T, W = ids.shape[0], cfg.sliding_window
-    positions = jnp.arange(T)
-    cache = empty_cache(cfg, max_len)
+    with device_scope("llm_attn"):
+        positions = jnp.arange(T)
+        cache = empty_cache(cfg, max_len)
     held = []
-    e = params["embed"][ids].astype(jnp.float32)
-    X = jnp.broadcast_to(e[:, None], (T, cfg.mhc_expansion_rate,
-                                      cfg.hidden_size))
+    e = _embed(params, ids)
+    with device_scope("llm_mix"):
+        X = jnp.broadcast_to(e[:, None], (T, cfg.mhc_expansion_rate,
+                                          cfg.hidden_size))
     for i, layer in enumerate(params["layers"]):
         p = layer["attn"]
         full = cfg.is_full(i)
 
+        @device_scoped("llm_attn")
         def attention(x):
             q_nope, q_rope, c, kr, lam, gate = _split_in(
                 cfg, p, _dot(x, p["w_in"], dtype), positions)
@@ -366,13 +375,16 @@ def prefill(cfg: MotifConfig, params, ids, max_len: int,
             return _attn_out(p, o, gate, dtype), (c, kr)
 
         X, (c, kr) = hyper_connect(cfg, layer["attn_hc"], X, attention)
-        if full:
-            rows = slots = positions
-        else:                  # the ring: position t lives in slot t % W
-            rows = jnp.arange(max(0, T - W), T)
-            slots = rows % W
-        cache["c"][i] = cache["c"][i].at[slots].set(c[rows].astype(dtype))
-        cache["kr"][i] = cache["kr"][i].at[slots].set(kr[rows].astype(dtype))
+        with device_scope("llm_attn"):
+            if full:
+                rows = slots = positions
+            else:              # the ring: position t lives in slot t % W
+                rows = jnp.arange(max(0, T - W), T)
+                slots = rows % W
+            cache["c"][i] = cache["c"][i].at[slots].set(
+                c[rows].astype(dtype))
+            cache["kr"][i] = cache["kr"][i].at[slots].set(
+                kr[rows].astype(dtype))
         if cfg.is_moe(i):
             m = layer["moe"]
 
@@ -383,16 +395,19 @@ def prefill(cfg: MotifConfig, params, ids, max_len: int,
                     x, idx, w, m["e_gu"], m["e_down"], cfg.first_expert,
                     dtype, cfg.routing, poly_norm_gate(cfg), m["e_poly"],
                     expert_chunk=math.gcd(cfg.num_experts, 8))
-                return y + _mlp(cfg, x, m["shared"], dtype), idx
+                with device_scope("llm_shared_ffn"):
+                    return y + _mlp(cfg, x, m["shared"], dtype), idx
 
             X, idx = hyper_connect(cfg, layer["ffn_hc"], X, experts)
             held.append(_count_held(cfg, idx))
         else:
             X, _ = hyper_connect(
-                cfg, layer["ffn_hc"], X,
-                lambda x: (_mlp(cfg, x, layer["ffn"], dtype), None))
-    h = X.sum(-2)
-    logits = logits_of(cfg, params, h if all_logits else h[-1])
+                cfg, layer["ffn_hc"], X, device_scoped("llm_shared_ffn")(
+                    lambda x: (_mlp(cfg, x, layer["ffn"], dtype), None)))
+    with device_scope("llm_mix"):
+        h = X.sum(-2)
+        h = h if all_logits else h[-1]
+    logits = logits_of(cfg, params, h)
     return logits, cache, _stack_counts(held)
 
 
@@ -404,15 +419,19 @@ def decode_step(cfg: MotifConfig, params, cache: dict, token, pos):
     cache; answers as ``llm_hybrid.decode_step``."""
     dtype = jnp.dtype(cfg.dtype)
     W = cfg.sliding_window
-    positions = jnp.reshape(pos, (1,))
+    with device_scope("llm_attn"):
+        positions = jnp.reshape(pos, (1,))
     cache = {k: list(v) for k, v in cache.items()}
     held = []
-    e = params["embed"][token].astype(jnp.float32)
-    X = jnp.broadcast_to(e, (cfg.mhc_expansion_rate, cfg.hidden_size))
+    e = _embed(params, token)
+    with device_scope("llm_mix"):
+        X = jnp.broadcast_to(e, (cfg.mhc_expansion_rate, cfg.hidden_size))
     for i, layer in enumerate(params["layers"]):
         p = layer["attn"]
-        slot = pos if cfg.is_full(i) else pos % W
+        with device_scope("llm_attn"):
+            slot = pos if cfg.is_full(i) else pos % W
 
+        @device_scoped("llm_attn")
         def attention(x):
             q_nope, q_rope, c, kr, lam, gate = _split_in(
                 cfg, p, _dot(x[None], p["w_in"], dtype), positions)
@@ -440,15 +459,19 @@ def decode_step(cfg: MotifConfig, params, cache: dict, token, pos):
                     x, idx[0], w[0], m["e_gu"], m["e_down"],
                     cfg.first_expert, dtype, poly_norm_gate(cfg),
                     m["e_poly"])
-                return y + _mlp(cfg, x[None], m["shared"], dtype)[0], idx
+                with device_scope("llm_shared_ffn"):
+                    return y + _mlp(cfg, x[None], m["shared"], dtype)[0], idx
 
             X, idx = hyper_connect(cfg, layer["ffn_hc"], X, experts)
             held.append(_count_held(cfg, idx))
         else:
             X, _ = hyper_connect(
-                cfg, layer["ffn_hc"], X,
-                lambda x: (_mlp(cfg, x[None], layer["ffn"], dtype)[0], None))
-    return logits_of(cfg, params, X.sum(-2)), cache, _stack_counts(held)
+                cfg, layer["ffn_hc"], X, device_scoped("llm_shared_ffn")(
+                    lambda x: (_mlp(cfg, x[None], layer["ffn"], dtype)[0],
+                               None)))
+    with device_scope("llm_mix"):
+        h = X.sum(-2)
+    return logits_of(cfg, params, h), cache, _stack_counts(held)
 
 
 MODEL = LLMModel(init_motif, prefill, decode_step, empty_cache, cache_kinds)

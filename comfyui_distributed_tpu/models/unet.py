@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..telemetry.device_scopes import device_scope
 from .layers import (
     GroupNorm32,
     ResBlock,
@@ -118,23 +119,24 @@ class UNet2D(nn.Module):
         dt = cfg.jnp_dtype
         time_dim = cfg.model_channels * 4
 
-        emb = timestep_embedding(t, cfg.model_channels)
-        emb = nn.Dense(time_dim, dtype=dt, name="time_1")(emb.astype(dt))
-        emb = nn.Dense(time_dim, dtype=dt, name="time_2")(nn.silu(emb))
-        if cfg.adm_in_channels:
-            assert y is not None, "config.adm_in_channels set but y not given"
-            yemb = nn.Dense(time_dim, dtype=dt, name="label_1")(y.astype(dt))
-            yemb = nn.Dense(time_dim, dtype=dt, name="label_2")(nn.silu(yemb))
-            emb = emb + yemb
-
-        x = x.astype(dt)
-        if context is not None:
-            context = context.astype(dt)
+        with device_scope("norm_mod"):
+            emb = timestep_embedding(t, cfg.model_channels)
+            emb = nn.Dense(time_dim, dtype=dt, name="time_1")(emb.astype(dt))
+            emb = nn.Dense(time_dim, dtype=dt, name="time_2")(nn.silu(emb))
+            if cfg.adm_in_channels:
+                assert y is not None, "config.adm_in_channels set but y not given"
+                yemb = nn.Dense(time_dim, dtype=dt, name="label_1")(y.astype(dt))
+                yemb = nn.Dense(time_dim, dtype=dt, name="label_2")(nn.silu(yemb))
+                emb = emb + yemb
+            if context is not None:
+                context = context.astype(dt)
 
         Res = nn.remat(ResBlock) if cfg.remat else ResBlock
         Attn = nn.remat(SpatialTransformer) if cfg.remat else SpatialTransformer
 
-        h = nn.Conv(cfg.model_channels, (3, 3), padding=1, dtype=dt, name="conv_in")(x)
+        with device_scope("resnet"):
+            x = x.astype(dt)
+            h = nn.Conv(cfg.model_channels, (3, 3), padding=1, dtype=dt, name="conv_in")(x)
         skips = [h]
 
         # --- down path ---
@@ -168,14 +170,17 @@ class UNet2D(nn.Module):
             assert len(down_res) == len(skips), (
                 f"control carries {len(down_res)} skip residuals, "
                 f"UNet has {len(skips)}")
-            h = h + mid_res.astype(h.dtype)
-            skips = [s + r.astype(s.dtype) for s, r in zip(skips, down_res)]
+            with device_scope("resnet"):
+                h = h + mid_res.astype(h.dtype)
+                skips = [s + r.astype(s.dtype)
+                         for s, r in zip(skips, down_res)]
 
         # --- up path ---
         for level in reversed(range(len(cfg.channel_mult))):
             ch = cfg.model_channels * cfg.channel_mult[level]
             for i in range(cfg.num_res_blocks + 1):
-                h = jnp.concatenate([h, skips.pop()], axis=-1)
+                with device_scope("resnet"):
+                    h = jnp.concatenate([h, skips.pop()], axis=-1)
                 h = Res(ch, dt, name=f"up_{level}_res_{i}")(h, emb)
                 if cfg.transformer_depth[level]:
                     h = Attn(
@@ -187,11 +192,12 @@ class UNet2D(nn.Module):
             if level > 0:
                 h = Upsample(ch, dt, name=f"up_{level}_us")(h)
 
-        h = GroupNorm32(name="norm_out")(h)
-        h = nn.silu(h)
-        h = nn.Conv(
-            cfg.out_channels, (3, 3), padding=1, dtype=jnp.float32, name="conv_out"
-        )(h.astype(jnp.float32))
+        with device_scope("resnet"):
+            h = GroupNorm32(name="norm_out")(h)
+            h = nn.silu(h)
+            h = nn.Conv(
+                cfg.out_channels, (3, 3), padding=1, dtype=jnp.float32, name="conv_out"
+            )(h.astype(jnp.float32))
         return h
 
 

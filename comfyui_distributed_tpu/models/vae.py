@@ -199,8 +199,11 @@ class AutoencoderKL:
         return (mean - self.config.shift_factor) * self.config.scaling_factor
 
     def decode(self, latents: jax.Array, params=None) -> jax.Array:
+        from ..telemetry.device_scopes import device_scope
         from .layers import jit_apply
 
-        return jit_apply(self, self.decoder, "_dec_fn")(
-            self.dec_params if params is None else params,
-            latents / self.config.scaling_factor + self.config.shift_factor)
+        with device_scope("vae_decode"):
+            return jit_apply(self, self.decoder, "_dec_fn")(
+                self.dec_params if params is None else params,
+                latents / self.config.scaling_factor
+                + self.config.shift_factor)

@@ -26,7 +26,13 @@ import jax
 import jax.numpy as jnp
 from jax.lax import axis_size as _axis_size
 
+from ..telemetry.device_scopes import device_scope, device_scoped
 from ..utils import constants
+
+# the attention core's device scope round a whole dispatcher: the kernel
+# call with its pads, transposes and casts is cdt.attn_core wherever it is
+# called from, self and cross alike
+_attn_core = device_scoped("attn_core")
 
 
 def _pvary(x, axis):
@@ -286,6 +292,7 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
     return choice
 
 
+@_attn_core
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    prefer_flash: bool = False, choice=None) -> jax.Array:
     """Dense [B,N,H,D] attention by the kernel ``select_kernel`` picks
@@ -391,6 +398,7 @@ def _ring_rotate(axis: str, n_shards: int, *payloads):
     return tuple(jax.lax.ppermute(p, axis, perm) for p in payloads)
 
 
+@_attn_core
 def ring_attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     axis: str = constants.AXIS_SEQUENCE,
@@ -453,6 +461,7 @@ def ring_attention(
     return out.astype(q.dtype)
 
 
+@_attn_core
 def joint_ring_attention(
     q: jax.Array,
     txt_k: jax.Array, txt_v: jax.Array,
@@ -540,6 +549,8 @@ def ulysses_attention(
     def to_seq(x):
         return jax.lax.all_to_all(x, axis, split_axis=1, concat_axis=2, tiled=True)
 
-    qh, kh, vh = to_heads(q), to_heads(k), to_heads(v)
+    with device_scope("attn_core"):
+        qh, kh, vh = to_heads(q), to_heads(k), to_heads(v)
     out = full_attention(qh, kh, vh)
-    return to_seq(out)
+    with device_scope("attn_core"):
+        return to_seq(out)

@@ -36,6 +36,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.device_scopes import device_scope, device_scoped
+
 
 @dataclasses.dataclass(frozen=True)
 class Routing:
@@ -47,6 +49,7 @@ class Routing:
     group_top: int = 2    # a group's score is the sum of its best two
 
 
+@device_scoped("llm_router")
 def route(x, w_router, bias, r: Routing):
     """``x`` [T,D] → ``(idx [T,k] int32, weights [T,k] f32)`` over all
     ``r.experts``. ``bias`` (None: the router has none) moves the
@@ -100,6 +103,7 @@ def held_slots(idx, first: int, held: int):
     return (idx >= first) & (idx < first + held)
 
 
+@device_scoped("llm_experts")
 def held_part_dense(x, idx, w, e_gu, e_down, first: int, dtype,
                     act=silu_gate, act_params=None,
                     expert_chunk: int | None = None):
@@ -163,12 +167,14 @@ def held_part(x, idx, w, e_gu, e_down, first: int, dtype, r: Routing,
         return held_part_grouped(x, idx, w, e_gu, e_down, first, dtype, act,
                                  act_params, valid, tile)
     if valid is not None:
-        w = jnp.where(valid[:, None], w, 0.0)
+        with device_scope("llm_experts"):
+            w = jnp.where(valid[:, None], w, 0.0)
     y = held_part_dense(x, idx, w, e_gu, e_down, first, dtype, act,
                         act_params, expert_chunk)
     return y, jnp.asarray(e_gu.shape[0] * x.shape[0], jnp.int32)
 
 
+@device_scoped("llm_experts")
 def held_part_grouped(x, idx, w, e_gu, e_down, first: int, dtype,
                       act=silu_gate, act_params=None, valid=None,
                       tile: int = GROUP_TILE):
@@ -214,6 +220,7 @@ def held_part_grouped(x, idx, w, e_gu, e_down, first: int, dtype,
     return out, n_tiles * tile
 
 
+@device_scoped("llm_experts")
 def held_part_token(x, idx, w, e_gu, e_down, first: int, dtype,
                     act=silu_gate, act_params=None):
     """One token: ``x`` [D], ``idx``/``w`` [k]. A loop over the held

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry.device_scopes import device_scoped
+
 
 def yarn_frequencies(d: int, theta: float, factor: float,
                      original_len: int, beta_fast: float = 32.0,
@@ -249,6 +251,7 @@ def causal_blocked_lax(q_nope, q_rope, kv, k_rope, start, nope: int, dtype,
     return out.reshape(C, H, v)
 
 
+@device_scoped("llm_attn")
 def mla_chunk_attention(q_nope, q_rope, c_cache, kr_cache, start, w_b,
                         scale: float, dtype, block_q: int, block_k: int,
                         kernel: str | None = None):
@@ -300,9 +303,8 @@ def mla_chunk_attention(q_nope, q_rope, c_cache, kr_cache, start, w_b,
 
         note_latent_causal(H, nope + q_rope.shape[-1], C, S_pad, dtype, bq,
                            block_k)
-    with jax.named_scope("mla_causal"):
-        o = flash_latent.latent_causal_mha(
-            q_nope.reshape(C, H * nope), jnp.swapaxes(q_rope, 0, 1), kv,
-            kr_pad, start, num_heads=H, block_q=bq, block_k=block_k,
-            interpret=kernel == "interpret")
+    o = flash_latent.latent_causal_mha(
+        q_nope.reshape(C, H * nope), jnp.swapaxes(q_rope, 0, 1), kv,
+        kr_pad, start, num_heads=H, block_q=bq, block_k=block_k,
+        interpret=kernel == "interpret")
     return o.reshape(C, H, v)

@@ -16,6 +16,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.device_scopes import device_scope
+
 
 def seed_to_key(seed: int) -> jax.Array:
     return jax.random.key(jnp.uint32(seed))
@@ -28,7 +30,8 @@ def participant_key(base_key: jax.Array, axis: str) -> jax.Array:
     ``N`` — preserving the reference's deterministic master-first ordering
     (``nodes/collector.py:252-295``) without special-casing the master.
     """
-    return jax.random.fold_in(base_key, jax.lax.axis_index(axis))
+    with device_scope("sampler"):
+        return jax.random.fold_in(base_key, jax.lax.axis_index(axis))
 
 
 def participant_keys(base_key: jax.Array, n: int) -> jax.Array:

@@ -8,6 +8,11 @@ Everything that compiles goes through :func:`enable_compile_cache`: the
 server at boot, ``bench.py``, ``scripts/mfu_probe.py``, the warmup pass
 (``diffusion/warmup.py``) and the test suite.
 
+A cached executable keeps the metadata it was compiled with, so the
+key includes it (``jax_compilation_cache_include_metadata_in_key``, with
+the one line an operation is traced at and none of its callers): what a
+profile names an operation is then what the running code names it.
+
 The directory is placed from outside with JAX's own variable,
 ``JAX_COMPILATION_CACHE_DIR``. Where it is set, JAX reads it itself and
 this module sets no directory in code. Where it is not, the directory is
@@ -84,6 +89,22 @@ def enable_compile_cache(path: Optional[str] = None,
         jax.config.update("jax_compilation_cache_dir", d)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_secs))
+    # An executable read from the cache carries the names it was COMPILED
+    # with. JAX's default key strips debug info, so a program whose
+    # operations did not change is served with the op_name and source
+    # lines of whichever checkout compiled it first, and a profile shows
+    # stale (or no) cdt.<layer> scopes (telemetry/device_scopes.py; seen on
+    # the chip in PR 34: fin_body read from PR 33's cache had none). With
+    # the names in the key a profile's names are the running code's; the
+    # price is that a program is compiled again after an edit that moves
+    # the lines it is traced through, once a checkout.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    # ... and only the line an operation is traced AT, not the ten frames
+    # of callers JAX adds by default: the warm-up pass and a request reach
+    # one program by different paths and must find one cache entry. (Not
+    # jax_include_full_tracebacks_in_locations=False: that path of JAX
+    # 0.9 drops most of the names themselves; seen on the chip, PR 34.)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     _active = d
     log(f"compile cache: persisting XLA programs under {d}"
         + (f" (from {JAX_CACHE_ENV})" if placed_outside else ""))

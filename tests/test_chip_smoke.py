@@ -155,6 +155,25 @@ class TestCompileCachePlacement:
         # the git-ignored place: what is cached is never committed
         assert ".cache/" in (ROOT / ".gitignore").read_text().split()
 
+    def test_the_names_a_program_was_traced_with_are_part_of_its_key(
+            self, cache_config, monkeypatch, tmp_path):
+        """A cached executable keeps the op names it was compiled with:
+        with JAX's default key a program whose operations did not change
+        would show another checkout's (or no) ``cdt.<layer>`` scopes in a
+        profile (seen on the chip, PR 34). The names, not the callers'
+        frames: the warm-up pass and a request reach one program by
+        different paths and must find one entry
+        (``test_warmup.test_warm_restart_skips_recompilation``)."""
+        from comfyui_distributed_tpu.utils import compile_cache as cc
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "x"))
+        cc.enable_compile_cache()
+        assert dict(cache_config)[
+            "jax_compilation_cache_include_metadata_in_key"] is True
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        assert dict(cache_config)["jax_traceback_in_locations_limit"] == 1
+        assert jax.config.jax_traceback_in_locations_limit == 1
+
     def test_unwritable_is_an_error_not_silence(self, cache_config,
                                                 monkeypatch, tmp_path):
         from comfyui_distributed_tpu.utils import compile_cache as cc

@@ -608,7 +608,13 @@ def test_the_cell_assembles_with_the_briefs_sizes_and_the_units_step():
                      "kimi_attn_core_mxu_pct", "kimi_attn_core_pct",
                      "kimi_decode_hbm_pct", "kimi_held_slot_pct",
                      "denoise_ms_per_step", "peak_hbm_gib"}
-    assert not {n for n in names if n.startswith(("llm_", "motif_"))}
+    # Ling's own readers (keyed by kind) stay out; the four keyed by device
+    # scope (PR 34) serve every rewriter
+    scoped = {"llm_attn_pct", "llm_experts_pct", "llm_ffn_pct",
+              "llm_head_sample_pct"}
+    assert scoped <= names
+    assert not {n for n in names - scoped
+                if n.startswith(("llm_", "motif_"))}
     for other in ("motif-3-beta.reprompt1k-sdxl8",
                   "ling-3.0-flash-vl.reprompt1k", "sdxl-base.solo30"):
         import cdtbench.workload as workload

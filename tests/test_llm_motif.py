@@ -529,7 +529,12 @@ def test_the_cell_assembles_with_the_rewriters_sizes_and_the_units_step():
     assert names >= {"motif_decode_ms_per_token", "motif_prefill_ms",
                      "motif_decode_hbm_pct", "motif_held_slot_pct",
                      "motif_window_cache_pct", "denoise_ms_per_step"}
-    assert not {n for n in names if n.startswith("llm_")}
+    # Ling's own readers (keyed by kind) stay out; the four keyed by device
+    # scope (PR 34) serve every rewriter
+    scoped = {"llm_attn_pct", "llm_experts_pct", "llm_ffn_pct",
+              "llm_head_sample_pct"}
+    assert scoped <= names
+    assert not {n for n in names - scoped if n.startswith("llm_")}
 
 
 def test_decode_bytes_count_the_leaves_the_model_holds():
