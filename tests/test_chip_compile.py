@@ -13,7 +13,9 @@ repo's own model counted. These cases compile, at real widths:
 - the classic ``bh`` call at FLUX's geometry, which no row selects but the
   floors of ``select_kernel`` still reach;
 - SDXL's two self-attention sites inside the transformer block that calls
-  them, with the dispatcher choosing the tier as it does on the chip;
+  them, with the dispatcher choosing the tier as it does on the chip —
+  and, in the same text, where the compiler put the GEGLU's exact gelu:
+  in a product's epilogue, not on ``proj_out``'s operand path (PR 35);
 - SD3's joint attention (B=2, N=4173, H=24, D=64), which no row names:
   the packed tier's default path, at the blocks its shape derives;
 - and, for every row and for SD3, the largest blocks ``_fused_feasible``
@@ -27,6 +29,7 @@ described device cannot be read back without one, and only warns.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 # describing a topology loads libtpu, which one process at a time may do
@@ -139,6 +142,44 @@ def test_table_has_the_rows_the_main_paths_select():
     assert {c.tier for _, c in PALLAS_ROWS.values()} == {"packed"}
 
 
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%?[\w.\-]+) \(.*\) -> .* \{$")
+_FF_ERFC = re.compile(r'op_name="[^"]*/ff/[^"]*erfc')
+
+
+def _computations(text):
+    """``{name: [instruction lines]}`` of a compiled module's text."""
+    out, name = {}, None
+    for line in map(str.strip, text.splitlines()):
+        head = _COMPUTATION.match(line)
+        if name is None and head:
+            name = head.group(1)
+            out[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def _assert_gelu_is_a_products_epilogue(text):
+    """The exact gelu is 64 vector instructions an element. Every one of
+    the feed-forward's must sit in a computation that holds a
+    ``convolution`` (the epilogue of that product's own output fusion,
+    beside the matrix unit), none in a loop fusion that prepares another
+    product's operand, where the matrix unit waits for it."""
+    holders = {name: body for name, body in _computations(text).items()
+               if any(_FF_ERFC.search(line) for line in body)}
+    assert holders, "no ff/…erfc instruction: the reader is looking wrong"
+    for name, body in holders.items():
+        assert any(" convolution(" in line for line in body), (
+            f"{name} computes the feed-forward's erfc and holds no "
+            "convolution")
+    products = re.findall(
+        r' convolution\(.*op_name="[^"]*/ff/cdt\.ffn/(\w+)/', text)
+    assert sorted(products) == ["gate", "proj_out", "value"], products
+    assert not re.findall(r"= \S+ copy\(.*proj_in", text)
+
+
 @pytest.mark.parametrize("level", [(640, 10, 4096), (1280, 20, 1024)],
                          ids=lambda l: f"c{l[0]}.n{l[2]}")
 def test_sdxl_self_attention_inside_its_block(chip, level, monkeypatch):
@@ -168,7 +209,9 @@ def test_sdxl_self_attention_inside_its_block(chip, level, monkeypatch):
         params)
     compiled = jax.jit(block.apply).lower(params, x, ctx).compile()
 
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    _assert_gelu_is_a_products_epilogue(text)
     selected = dict(item.split("=") for item in
                     attn.selection_summary().split(","))
     key = autotune.GeometryKey.from_shape(heads, C // heads, n, n).key_str()
