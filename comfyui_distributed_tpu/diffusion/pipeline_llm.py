@@ -37,22 +37,28 @@ class LLMPipeline:
         return self.model.decode_step(self.config, weights, state, token,
                                       pos)
 
-    def prefill_plan(self, prompt_tokens: int) -> tuple[int, int, str]:
+    def prefill_plan(self, prompt_tokens: int) -> tuple:
         """``(chunk, chunks, form)``: how ``llm_prefill`` walks a prompt of
         this length (whole: one chunk) and the form its expert layers
-        take for the rows a call of them sees."""
-        cfg = self.config
-        if self.model.prefill_chunk is None:
-            return prompt_tokens, 1, prefill_form(prompt_tokens, cfg.routing)
-        chunk = min(cfg.prefill_chunk_tokens, prompt_tokens)
-        return chunk, -(-prompt_tokens // chunk), prefill_form(
-            chunk, cfg.routing, cfg.expert_tile)
+        take for the rows a call of them sees — None for a model that has
+        no expert layer (its config then has no ``routing`` to read)."""
+        cfg, whole = self.config, self.model.prefill_chunk is None
+        chunk = prompt_tokens if whole \
+            else min(cfg.prefill_chunk_tokens, prompt_tokens)
+        if not cfg.moe_layers:
+            form = None
+        elif whole:
+            form = prefill_form(chunk, cfg.routing)
+        else:
+            form = prefill_form(chunk, cfg.routing, cfg.expert_tile)
+        return chunk, -(-prompt_tokens // chunk), form
 
     def prefill_fn(self, prompt_tokens: int, new_tokens: int):
         """``(ids [prompt_tokens]) -> (last logits [V], cache, held)``; a
         model that gives ``prefill_chunk`` is walked through the cache in
         chunks inside this one program, and also answers the rows its
-        expert layers multiplied."""
+        expert layers multiplied (``held`` and the rows have one entry an
+        expert layer: none for a model without one)."""
         cfg, max_len = self.config, prompt_tokens + new_tokens
         if self.model.prefill_chunk is not None:
             # the continuation, scanned: (..., cache, held, rows multiplied)
@@ -74,8 +80,9 @@ class LLMPipeline:
     def decode_fn(self, prompt_tokens: int, new_tokens: int,
                   tap_every: int = TAP_EVERY):
         """``(logits, cache, key, temperature) -> (ids [new_tokens], tap
-        logits [new_tokens // tap_every, V], held slots per expert layer,
-        finite)``: ``new_tokens`` steps of the token program in one scan.
+        logits [new_tokens // tap_every, V], held slots per expert layer
+        (an empty vector where the model has none), finite)``:
+        ``new_tokens`` steps of the token program in one scan.
         ``tap_every`` is the served program's unless a parity tool builds
         a decode of its own to compare more rows."""
         n_counts = len(self.config.moe_layers)
@@ -108,8 +115,9 @@ class LLMPipeline:
         _, chunks, form = self.prefill_plan(len(ids))
         logits, cache, held_prefill, *rows = prefill(
             jnp.asarray(ids, jnp.int32))
-        # a chunked prefill counts the rows its experts multiplied; the
-        # whole-prompt form multiplies every held expert by every token
+        # a chunked prefill counts the rows its experts multiplied (none
+        # without an expert layer); the whole-prompt form multiplies every
+        # held expert by every token
         rows = int(np.asarray(rows[0]).sum()) if rows else (
             len(ids) * self.config.num_experts * len(self.config.moe_layers))
         out, taps, held_decode, finite = decode(

@@ -1003,19 +1003,27 @@ class TPUPromptRewrite(NodeDef):
                 _tm.LLM_CACHE_BYTES.labels(layers=kind).set(float(size))
             _tm.LLM_CACHE_POSITIONS.set(float(prompt_tokens + new_tokens))
             _tm.LLM_PREFILL_CHUNKS.inc(out["prefill_chunks"])
-            _tm.LLM_EXPERT_ROWS.labels(form=out["prefill_form"]).inc(
-                out["rows_prefill"])
-            _tm.LLM_EXPERT_ROWS.labels(form="token").inc(
-                int(out["held_decode"].sum()))
-            for phase, tokens in (("prefill", prompt_tokens),
-                                  ("decode", new_tokens)):
-                held = int(out[f"held_{phase}"].sum())
+            phases = (("prefill", prompt_tokens), ("decode", new_tokens))
+            for phase, tokens in phases:
                 _tm.LLM_TOKENS.labels(phase=phase).inc(tokens)
-                _tm.LLM_EXPERT_SLOTS.labels(where="held", phase=phase).inc(held)
-                _tm.LLM_EXPERT_SLOTS.labels(where="absent", phase=phase).inc(
-                    tokens * cfg.routed_slots_per_token - held)
                 _tm.LLM_STREAM_MIX.labels(phase=phase).inc(
                     tokens * cfg.stream_mixes_per_token)
+                # a model counts what it has: state-space layers, experts
+                scanned = tokens * getattr(cfg, "scan_layers_per_token", 0)
+                if scanned:
+                    _tm.LLM_SCAN_TOKENS.labels(phase=phase).inc(scanned)
+            if cfg.moe_layers:
+                _tm.LLM_EXPERT_ROWS.labels(form=out["prefill_form"]).inc(
+                    out["rows_prefill"])
+                _tm.LLM_EXPERT_ROWS.labels(form="token").inc(
+                    int(out["held_decode"].sum()))
+                for phase, tokens in phases:
+                    held = int(out[f"held_{phase}"].sum())
+                    _tm.LLM_EXPERT_SLOTS.labels(where="held",
+                                                phase=phase).inc(held)
+                    _tm.LLM_EXPERT_SLOTS.labels(
+                        where="absent", phase=phase).inc(
+                            tokens * cfg.routed_slots_per_token - held)
         new_ids = out["ids"]
         if not out["finite"]:
             raise RuntimeError("the language model produced a non-finite "

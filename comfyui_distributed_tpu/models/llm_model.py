@@ -17,6 +17,9 @@ class LLMModel(NamedTuple):
     #                         (last logits [V], cache, held [expert layers])
     decode_step: Callable   # (cfg, weights, cache, token, pos) ->
     #                         (logits [V], cache, held [expert layers])
+    # ``held`` has one count an expert layer — ``cfg.moe_layers`` — and is
+    # an empty int32 vector for a model that has none (its config then
+    # needs no ``routing``, ``num_experts`` or ``expert_tile``)
     empty_cache: Callable   # (cfg, max_len) -> the decode carry's state
     cache_kinds: Callable   # (cfg, cache) -> {kind of layer: its leaves}
     # the prefill's continuation, for a model whose prompt is walked in
@@ -44,9 +47,14 @@ def chunked_prefill(model: LLMModel, cfg, weights, ids, max_len: int,
     """A prompt ``ids`` [T] through ``model.prefill_chunk``, ``chunk``
     tokens (``cfg.prefill_chunk_tokens``) at a time: ONE scan whose carry
     is the cache, so nothing the size of the prompt exists but the cache
-    and the ids. The last chunk is padded (the cache has rows for it).
-    Answers ``(logits, cache, held, rows)``: the last position's
-    logits [V] (every position's [T,V] with ``all_logits``), held slots
+    and the ids. The last chunk is padded (the cache has rows for it):
+    ``prefill_chunk`` is told how many of its rows are the prompt's
+    (``n_valid``) and owes the carry this — rows of a full-length cache
+    past ``n_valid`` may hold anything (nothing reads them before a decode
+    step rewrites them), but a RECURRENT leaf (a state, a convolution's
+    tail) must come back as token ``n_valid − 1`` left it, for a padded row
+    that advanced it would be part of every token after. Answers
+    ``(logits, cache, held, rows)``: the last position's logits [V] (every position's [T,V] with ``all_logits``), held slots
     and rows multiplied per expert layer summed over the chunks."""
     import jax
     import jax.numpy as jnp

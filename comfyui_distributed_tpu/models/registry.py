@@ -46,7 +46,7 @@ class ModelPreset:
     # test presets stay float32: their parity tolerances are float32's.
     param_dtype: "str | None" = None
     # config of a language model (models/llm_hybrid.py, llm_motif.py,
-    # llm_kimi.py; its
+    # llm_kimi.py, llm_jamba.py; its
     # ``.model`` gives the functions): such a preset has no denoiser, VAE
     # or text tower, and is loaded by LLMLoader
     llm: "object | None" = None
@@ -209,8 +209,13 @@ def _wan_mmdit_preset():
 
 def _llm_preset(name: str, family: str, tiny: bool = False):
     """A language model of ``family``: at its published widths (this
-    chip's share), or its tiny float32 form for the CPU."""
-    if family == "motif":
+    chip's share, or the whole model where it fits), or its tiny float32
+    form for the CPU."""
+    if family == "jamba":
+        from .llm_jamba import JambaConfig
+
+        share = JambaConfig.tiny if tiny else JambaConfig.jamba2_3b
+    elif family == "motif":
         from .llm_motif import MotifConfig
 
         share = MotifConfig.tiny if tiny else MotifConfig.motif_share
@@ -254,6 +259,8 @@ PRESETS: dict[str, ModelPreset] = {
     "motif-tiny": _llm_preset("motif-tiny", "motif", tiny=True),
     "kimi-k2.6": _llm_preset("kimi-k2.6", "kimi"),
     "kimi-tiny": _llm_preset("kimi-tiny", "kimi", tiny=True),
+    "ai21-jamba2-3b": _llm_preset("ai21-jamba2-3b", "jamba"),
+    "jamba-tiny": _llm_preset("jamba-tiny", "jamba", tiny=True),
 }
 
 

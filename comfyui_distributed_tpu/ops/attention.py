@@ -554,3 +554,20 @@ def ulysses_attention(
     out = full_attention(qh, kh, vh)
     with device_scope("attn_core"):
         return to_seq(out)
+
+
+def note_shared_kv_causal(num_heads: int, head_dim: int, q_len: int,
+                          kv_len: int, dtype, block_q: int,
+                          block_k: int) -> None:
+    """As :func:`note_latent_causal`, for the blocked causal kernel over ONE
+    shared key/value head (``shared_kv_attention.causal_chunk``): a tier of
+    its own in the ``attention:`` line and ``cdt_attn_kernel_selected``.
+    (At the end of the file: the image programs' compile-cache keys hold
+    the lines their attention sites are traced at, above.)"""
+    from .autotune import GeometryKey, KernelChoice
+
+    _note_selection(
+        GeometryKey.from_shape(num_heads, head_dim, q_len, kv_len,
+                               dtype).key_str(),
+        KernelChoice("shared_kv_causal", block_q, block_k,
+                     reason="chunked prefill over one shared K/V head"))

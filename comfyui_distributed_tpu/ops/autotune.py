@@ -56,10 +56,11 @@ from ..utils.logging import debug_log, log
 
 TABLE_VERSION = 1
 TIERS = ("fused", "packed", "bh", "xla")
-# a kernel that is no tier of the bidirectional dispatch (no table row, no
-# policy arm chooses it) but reports itself the same way: the blocked causal
-# latent-attention kernel of a chunked prefill (ops/flash_latent.py)
-REPORTED_TIERS = TIERS + ("latent_causal",)
+# kernels that are no tier of the bidirectional dispatch (no table row, no
+# policy arm chooses them) but report themselves the same way: the blocked
+# causal kernels of a chunked prefill (ops/flash_latent.py), over a latent
+# cache and over one shared key/value head
+REPORTED_TIERS = TIERS + ("latent_causal", "shared_kv_causal")
 
 # the in-repo resolved table for the known model zoo
 _SHIPPED_PATH = Path(__file__).resolve().parent / "attn_table_default.json"

@@ -1,9 +1,15 @@
-"""Pallas causal attention over decompressed latent keys and values.
+"""Pallas causal attention for a prefill walked in chunks through a cache.
 
-The prefill side of multi-head latent attention (``ops/latent_attention.py``):
-a chunk of queries at positions ``start .. start+C−1`` against the rows
-``j ≤`` each query's position of a decompressed workspace. What the
-bidirectional kernels of ``flash_attention.py`` do not have:
+Two kernels on one schedule. ``latent_causal_mha`` is the prefill side of
+multi-head latent attention (``ops/latent_attention.py``): a chunk of
+queries at positions ``start .. start+C−1`` against the rows ``j ≤`` each
+query's position of a decompressed workspace. ``shared_kv_causal_mha`` (at
+the end of the file) is the same for many query heads over ONE key/value
+head (``ops/shared_kv_attention.py``): the schedule's pieces —
+``_last_block``, ``_on_visible_blocks``, ``_mask_above_diagonal``,
+``_accumulate`` — are shared, the logits and the K/V tiles are each
+kernel's own. What the bidirectional kernels of ``flash_attention.py`` do
+not have:
 
 - a **causal mask** whose diagonal moves with ``start`` (a scalar the
   kernel prefetches: one compiled kernel serves every chunk of a scan).
@@ -11,8 +17,8 @@ bidirectional kernels of ``flash_attention.py`` do not have:
   block index is clamped to the last needed one, so the pipeline re-uses
   the tile it holds) nor computed; only a block the diagonal crosses is
   masked;
-- **unequal widths**: a query and key are ``nope + rope`` wide (128 + 64),
-  a value ``v`` wide (128). The rope key is ONE ``[S, rope]`` array shared
+- (``latent_causal_mha``) **unequal widths**: a query and key are ``nope
+  + rope`` wide (128 + 64), a value ``v`` wide (128). The rope key is ONE ``[S, rope]`` array shared
   by every head — a head's logits are ``q_nope·k_nope + q_rope·k_rope``,
   two products into one float32 tile, so no per-head copy of it exists;
 - keys and values read from ONE workspace ``[S, H·(nope+v)]`` (a head's
@@ -45,6 +51,59 @@ def _last_block(start, i, block_q: int, block_k: int, num_k_blocks: int):
                        num_k_blocks - 1)
 
 
+def _mask_above_diagonal(s, first_row, first_col):
+    """Logits ``s`` of rows ``first_row ..`` against columns ``first_col
+    ..`` with every column past its row's position at −inf."""
+    row = first_row + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = first_col + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(col <= row, s, NEG_INF)
+
+
+def _accumulate(s, v, m_ref, l_ref, acc_ref, precision):
+    """One K block's logits ``s`` and values ``v`` into the running max,
+    sum and float32 accumulator."""
+    m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32,
+                             precision=precision)
+    acc_ref[:] = acc_ref[:] * corr + pv
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _on_visible_blocks(step, j, last, first_row, block_k: int):
+    """Run ``step(masked)`` for K block ``j`` if the q block sees it:
+    masked only where the diagonal crosses it (its last column lies past
+    the q block's first row), skipped past ``last``."""
+    crosses = (j + 1) * block_k - 1 > first_row
+    pl.when((j <= last) & crosses)(lambda: step(True))
+    pl.when((j <= last) & jnp.logical_not(crosses))(lambda: step(False))
+
+
+def _init_running(j, m_ref, l_ref, acc_ref):
+    @pl.when(j == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _running_scratch(block_q: int, width: int):
+    return [pltpu.VMEM((block_q, _LANES), jnp.float32),   # running max
+            pltpu.VMEM((block_q, _LANES), jnp.float32),   # running sum
+            pltpu.VMEM((block_q, width), jnp.float32)]    # output acc
+
+
+def _precision_of(dtype):
+    return (jax.lax.Precision.HIGHEST if dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+
+
 def _latent_causal_kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
                           o_ref, m_ref, l_ref, acc_ref, *, block_q: int,
                           block_k: int, num_k_blocks: int, precision):
@@ -52,11 +111,7 @@ def _latent_causal_kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
     first_row = start_ref[0] + i * block_q
     last = _last_block(start_ref[0], i, block_q, block_k, num_k_blocks)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    _init_running(j, m_ref, l_ref, acc_ref)
 
     def step(masked: bool):
         nt = (((1,), (1,)), ((), ()))
@@ -67,28 +122,10 @@ def _latent_causal_kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
                                  preferred_element_type=jnp.float32,
                                  precision=precision)
         if masked:
-            row = first_row + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            col = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape,
-                                                         1)
-            s = jnp.where(col <= row, s, NEG_INF)
-        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[...]
-        pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32,
-                                 precision=precision)
-        acc_ref[:] = acc_ref[:] * corr + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+            s = _mask_above_diagonal(s, first_row, j * block_k)
+        _accumulate(s, v_ref[...], m_ref, l_ref, acc_ref, precision)
 
-    # the diagonal crosses a block whose last column lies past the first row
-    crosses = (j + 1) * block_k - 1 > first_row
-    pl.when((j <= last) & crosses)(lambda: step(True))
-    pl.when((j <= last) & jnp.logical_not(crosses))(lambda: step(False))
+    _on_visible_blocks(step, j, last, first_row, block_k)
 
     @pl.when(j == num_k_blocks - 1)
     def _finalize():
@@ -107,8 +144,7 @@ def latent_causal_mha(q_nope, q_rope, kv, k_rope, start, num_heads: int,
     H = num_heads
     nope, rope = q_nope.shape[1] // H, q_rope.shape[-1]
     nq, nk = C // block_q, S // block_k
-    precision = (jax.lax.Precision.HIGHEST if q_nope.dtype == jnp.float32
-                 else jax.lax.Precision.DEFAULT)
+    precision = _precision_of(q_nope.dtype)
     kernel = functools.partial(_latent_causal_kernel, block_q=block_q,
                                block_k=block_k, num_k_blocks=nk,
                                precision=precision)
@@ -131,11 +167,7 @@ def latent_causal_mha(q_nope, q_rope, kv, k_rope, start, num_heads: int,
                          lambda h, i, j, s: (k_block(i, j, s), 2 * h + 1)),
         ],
         out_specs=pl.BlockSpec((block_q, nope), lambda h, i, j, s: (i, h)),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((block_q, _LANES), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, nope), jnp.float32),     # output acc
-        ])
+        scratch_shapes=_running_scratch(block_q, nope))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, H * nope), q_nope.dtype),
@@ -145,3 +177,71 @@ def latent_causal_mha(q_nope, q_rope, kv, k_rope, start, num_heads: int,
         interpret=interpret,
     )(jnp.reshape(start, (1,)).astype(jnp.int32), q_nope, q_rope, kv, k_rope,
       kv)
+
+
+# --- one key/value head shared by every query head --------------------------
+
+
+def _shared_kv_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                      acc_ref, *, block_q: int, block_k: int,
+                      num_k_blocks: int, precision):
+    i, j = pl.program_id(1), pl.program_id(2)
+    first_row = start_ref[0] + i * block_q
+    last = _last_block(start_ref[0], i, block_q, block_k, num_k_blocks)
+    _init_running(j, m_ref, l_ref, acc_ref)
+
+    def step(masked: bool):
+        s = jax.lax.dot_general(q_ref[...], k_ref[...],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                                precision=precision)
+        if masked:
+            s = _mask_above_diagonal(s, first_row, j * block_k)
+        _accumulate(s, v_ref[...], m_ref, l_ref, acc_ref, precision)
+
+    _on_visible_blocks(step, j, last, first_row, block_k)
+
+    @pl.when(j == num_k_blocks - 1)
+    def _finalize():
+        o_ref[...] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("num_heads", "block_q",
+                                             "block_k", "interpret"))
+def shared_kv_causal_mha(q, k, v, start, num_heads: int, block_q: int,
+                         block_k: int, interpret: bool):
+    """Causal attention of ``num_heads`` query heads over ONE key/value
+    head (multi-query attention, no positional encoding): the schedule
+    above — the prefetched ``start``, the clamped last block, the masked
+    diagonal step — with K and V tiles that ignore the head index, so no
+    per-head copy of the cache exists. ``q`` [C, H·d] times the softmax
+    scale, ``k``, ``v`` [S, d] (the cache itself), ``start`` the first
+    query's position (traced). ``C % block_q == 0``, ``S % block_k == 0``.
+    Answers [C, H·d]."""
+    C, S = q.shape[0], k.shape[0]
+    d = k.shape[1]
+    nq, nk = C // block_q, S // block_k
+    kernel = functools.partial(_shared_kv_kernel, block_q=block_q,
+                               block_k=block_k, num_k_blocks=nk,
+                               precision=_precision_of(q.dtype))
+
+    def kv_block(h, i, j, start_ref):
+        return (jnp.minimum(j, _last_block(start_ref[0], i, block_q,
+                                           block_k, nk)), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(num_heads, nq, nk),
+        in_specs=[pl.BlockSpec((block_q, d), lambda h, i, j, s: (i, h)),
+                  pl.BlockSpec((block_k, d), kv_block),
+                  pl.BlockSpec((block_k, d), kv_block)],
+        out_specs=pl.BlockSpec((block_q, d), lambda h, i, j, s: (i, h)),
+        scratch_shapes=_running_scratch(block_q, d))
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((C, num_heads * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(jnp.reshape(start, (1,)).astype(jnp.int32), q, k, v)

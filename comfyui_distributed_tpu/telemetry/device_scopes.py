@@ -14,7 +14,8 @@ A scope is trace-time metadata: nothing runs at a call and no numerics,
 shape or fusion input changes. A compiled program keeps the names it was
 compiled with, so ``utils/compile_cache.py`` puts them into the persistent
 cache's key: a trace shows the running code's scopes, never those of the
-checkout that filled the cache. One set of layers for every model, at most 16;
+checkout that filled the cache. One set of layers for every model, at most 16
+(and 16 there are: a seventeenth replaces one);
 a name that is not registered is refused when the program is traced.
 Scopes do not nest: the reader takes the innermost ``cdt.*`` component,
 so an operation belongs under exactly one (``tests/test_device_scopes.py``
@@ -53,6 +54,9 @@ DEVICE_LAYERS: tuple[tuple[str, str], ...] = (
     ("llm_attn", "a language model's attention: projections, short "
                  "convolutions, rope, cache write and read, the KDA / MLA / "
                  "GDLA core, the output projection and its residual add"),
+    ("llm_ssm", "a state-space mixer whole: in_proj, the causal depthwise "
+                "convolution, x_proj / dt_proj and their three norms, the "
+                "selective scan, the gate, out_proj and its residual add"),
     ("llm_router", "router logits, selection, weights, the slot counters"),
     ("llm_experts", "the held routed experts, all three forms (dense-masked, "
                     "grouped, per token) and their combine"),

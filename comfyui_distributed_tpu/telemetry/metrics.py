@@ -102,15 +102,17 @@ LLM_EXPERT_SLOTS = REGISTRY.counter(
     "where the selected expert lives: held (on this chip, computed) or "
     "absent (another chip of the expert group, left out), and by the "
     "phase that routed them (prefill, decode). Counted inside the "
-    "programs and fed from their outputs.",
+    "programs and fed from their outputs; a model with no expert layer "
+    "moves neither.",
     ("where", "phase"))
 
 LLM_CACHE_BYTES = REGISTRY.gauge(
     "cdt_llm_cache_bytes",
     "Bytes of one rewrite request's decode cache, by the kind of layer "
     "that holds them: window (a ring of sliding_window rows a layer), "
-    "full (prompt + new rows a layer), recurrent (linear-attention states "
-    "and convolution tails). Set when a request's cache is made.",
+    "full (prompt + new rows a layer), recurrent (linear-attention or "
+    "state-space states and convolution tails). Set when a request's "
+    "cache is made.",
     ("layers",))
 
 LLM_PREFILL_CHUNKS = REGISTRY.counter(
@@ -139,13 +141,22 @@ LLM_STREAM_MIX = REGISTRY.counter(
     "with one residual stream), by phase (prefill, decode).",
     ("phase",))
 
+LLM_SCAN_TOKENS = REGISTRY.counter(
+    "cdt_llm_scan_tokens_total",
+    "Token steps a language model's selective scan walked (tokens x "
+    "state-space layers: each is d_inner x d_state state updates), by "
+    "phase (prefill: the chunked kernel; decode: one step a token). A "
+    "model with no state-space layer never moves it.",
+    ("phase",))
+
 # --- attention kernel dispatch / autotune (ops/attention.py, ops/autotune.py)
 
 ATTN_KERNEL_SELECTED = REGISTRY.counter(
     "cdt_attn_kernel_selected",
     "Attention kernel-tier selections at trace time, by tier "
-    "(fused/packed/bh/xla; latent_causal: a chunked latent prefill's "
-    "own kernel), geometry (hH.dD.qN.kvN.dtype — bucketed, "
+    "(fused/packed/bh/xla; latent_causal, shared_kv_causal: a chunked "
+    "prefill's own kernel over a latent cache / over one shared "
+    "key/value head), geometry (hH.dD.qN.kvN.dtype — bucketed, "
     "so cardinality is bounded by the model zoo) and resolved blocks "
     "('<block_q>/<block_k>', for packed also ':k-resident' or "
     "':k-streamed'; '' where the tier has none). Increments once per "

@@ -305,3 +305,82 @@ def test_the_latent_causal_kernel_compiles_at_the_served_geometry(chip):
         jax.ShapeDtypeStruct((), jnp.int32, sharding=chip), num_heads=H,
         block_q=cfg.attn_block_q, block_k=cfg.attn_block_k, interpret=False)
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_the_selective_scan_kernel_compiles_at_the_served_geometry(chip):
+    """``ops/selective_scan.py`` as the state-space rewriter's prefill calls
+    it: a 4096-token chunk of 5120 channels and 16 states, the blocks the
+    module ships."""
+    from comfyui_distributed_tpu.ops import selective_scan as ss
+
+    T, d, N = 4096, 5120, 16
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+
+    lowered = ss.selective_scan.lower(
+        arg(d, N), arg(T, d), arg(T, d), arg(T, d), arg(T, N), arg(T, N),
+        arg(d, N), arg(d), block_t=ss.BLOCK_T, block_d=ss.BLOCK_D,
+        unroll=ss.UNROLL)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_the_shared_kv_causal_kernel_compiles_at_the_served_geometry(chip):
+    """``flash_latent.shared_kv_causal_mha`` as the same prefill calls it: 20
+    query heads of 128 over one key/value head, a 4096-token chunk over the
+    64 k cache padded to the K block, 1024-row tiles, a traced start."""
+    from comfyui_distributed_tpu.models.llm_jamba import JambaConfig
+    from comfyui_distributed_tpu.ops import flash_latent
+
+    cfg = JambaConfig.jamba2_3b()
+    H, d, C, S = cfg.num_attention_heads, cfg.head_dim, 4096, 66560
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+
+    lowered = flash_latent.shared_kv_causal_mha.lower(
+        arg(C, H * d), arg(S, d), arg(S, d),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=chip), num_heads=H,
+        block_q=cfg.attn_block_q, block_k=cfg.attn_block_k, interpret=False)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_the_state_space_rewriters_programs_fit_beside_sdxl(chip,
+                                                            monkeypatch):
+    """Both language programs of ``ai21-jamba2-3b.brief64k-sdxl8`` at the
+    cell's sizes (65 536 + 128 tokens, the WHOLE model): they compile for
+    the chip, their arguments + temporaries leave room for SDXL's segment
+    program (4.79 + 0.56 GiB, docs/weights.md) in 15.75 GiB, and the
+    program holds one Pallas call site a run of Mamba layers and one an
+    attention layer — five — not one a layer (28+), so a warm set-up
+    re-lowers five kernels (PERF.md §2)."""
+    from comfyui_distributed_tpu.diffusion.pipeline_llm import LLMPipeline
+    from comfyui_distributed_tpu.models.llm_jamba import JambaConfig
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    cfg = JambaConfig.jamba2_3b()
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+            tree)
+
+    weights = place(cfg.model.init(cfg, None, abstract=True))
+    prefill, decode = LLMPipeline(cfg, weights).programs(65536, 128)
+    ids = jax.ShapeDtypeStruct((65536,), jnp.int32, sharding=chip)
+    logits, cache, *_ = jax.eval_shape(prefill.jitted, weights, ids)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    gib, sdxl = 2.0 ** 30, 4.79 + 0.56
+    compiled = prefill.jitted.lower(weights, ids).compile()
+    assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") \
+        == len(cfg.mamba_runs) + len(cfg.attention_layers) == 5
+    mem = compiled.memory_analysis()
+    prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
+    assert 5.6 < prefill_gib < 7.0 and prefill_gib + sdxl < 15.75 - 2.0
+    compiled = decode.jitted.lower(
+        weights, place(logits), place(cache), place(key),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=chip)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()     # decode is XLA
+    mem = compiled.memory_analysis()
+    decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
+    assert 5.6 < decode_gib < 6.5 and decode_gib + sdxl < 15.75 - 2.0

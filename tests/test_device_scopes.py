@@ -212,7 +212,8 @@ def _llm_decode(module, config, tokens):
 
 LLMS = {"ling-tiny": ("llm_hybrid", "LLMConfig", 24),
         "motif-tiny": ("llm_motif", "MotifConfig", 24),
-        "kimi-tiny": ("llm_kimi", "KimiConfig", 37)}     # three chunks of 16
+        "kimi-tiny": ("llm_kimi", "KimiConfig", 37),     # three chunks of 16
+        "jamba-tiny": ("llm_jamba", "JambaConfig", 37)}
 
 PROGRAMS = {"txt2img_seg": _txt2img_seg, "flow_seg": _flow_seg, "fin": _fin}
 for _name, _how in LLMS.items():
@@ -239,6 +240,11 @@ EXPECTED = {
                               "llm_shared_ffn", "llm_head"},
     "llm_decode:kimi-tiny": {"llm_attn", "llm_router", "llm_experts",
                              "llm_shared_ffn", "llm_head"},
+    # no expert layer: no router, no experts; the mixers are llm_ssm
+    "llm_prefill:jamba-tiny": {"llm_ssm", "llm_attn", "llm_shared_ffn",
+                               "llm_head"},
+    "llm_decode:jamba-tiny": {"llm_ssm", "llm_attn", "llm_shared_ffn",
+                              "llm_head"},
 }
 
 

@@ -1,0 +1,143 @@
+"""Mamba-1's selective scan (``ops/selective_scan.py``): one token, a chunk
+as ``lax.scan`` and a chunk as the Pallas kernel (in the interpreter here)
+are one function, from a non-zero state, across block boundaries and past
+a padded chunk's last real row — held to a recurrence written out by hand
+in numpy float64."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from comfyui_distributed_tpu.ops import selective_scan as ss
+
+TOL = 2e-5
+
+
+def case(T, d, N, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 8)
+    return dict(
+        h0=jax.random.normal(ks[0], (d, N)),
+        u=jax.random.normal(ks[1], (T, d)),
+        dt=jax.nn.softplus(jax.random.normal(ks[2], (T, d)) - 2.0),
+        z=jax.random.normal(ks[3], (T, d)),
+        B=jax.random.normal(ks[4], (T, N)),
+        C=jax.random.normal(ks[5], (T, N)),
+        A=-jnp.exp(jax.random.normal(ks[6], (d, N))),
+        D=jax.random.normal(ks[7], (d,)))
+
+
+def by_hand(h0, u, dt, z, B, C, A, D):
+    """The recurrence, a token and a channel at a time, in float64."""
+    f = {k: np.asarray(v, np.float64) for k, v in dict(
+        h0=h0, u=u, dt=dt, z=z, B=B, C=C, A=A, D=D).items()}
+    h, ys = f["h0"].copy(), []
+    for t in range(f["u"].shape[0]):
+        h = np.exp(f["dt"][t][:, None] * f["A"]) * h \
+            + (f["dt"][t] * f["u"][t])[:, None] * f["B"][t][None, :]
+        y = h @ f["C"][t] + f["D"] * f["u"][t]
+        ys.append(y * f["z"][t] / (1.0 + np.exp(-f["z"][t])))
+    return np.stack(ys), h
+
+
+def close(a, b, tol=TOL):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.abs(a - b).max()) <= tol * max(1.0, float(np.abs(b).max()))
+
+
+def order(c):
+    return (c["h0"], c["u"], c["dt"], c["z"], c["B"], c["C"], c["A"], c["D"])
+
+
+def test_one_token_is_the_recurrence_written_out():
+    c = case(1, 24, 4)
+    y, h = ss.scan_step(c["h0"], c["u"][0], c["dt"][0], c["z"][0], c["B"][0],
+                        c["C"][0], c["A"], c["D"])
+    want_y, want_h = by_hand(**c)
+    assert close(y, want_y[0]) and close(h, want_h)
+    assert y.dtype == h.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("kernel", ["lax", "interpret"])
+def test_a_chunk_from_a_nonzero_state_is_the_token_loop(kernel):
+    c = case(24, 64, 16, seed=1)
+    y, h = ss.scan_chunk(*order(c), kernel=kernel)
+    want_y, want_h = by_hand(**c)
+    assert close(y, want_y) and close(h, want_h)
+    h_step, ys = c["h0"], []
+    for t in range(24):
+        y_t, h_step = ss.scan_step(h_step, c["u"][t], c["dt"][t], c["z"][t],
+                                   c["B"][t], c["C"][t], c["A"], c["D"])
+        ys.append(y_t)
+    assert close(y, jnp.stack(ys)) and close(h, h_step)
+
+
+@pytest.mark.parametrize("block_t,block_d,unroll", [(8, 32, 8), (16, 64, 8),
+                                                    (8, 64, 4), (32, 16, 8)])
+def test_the_kernels_blocks_do_not_change_the_answer(block_t, block_d,
+                                                     unroll):
+    """Time blocks hand the state on through VMEM scratch, channel groups
+    keep their own slice of it: any blocking is the unblocked scan."""
+    c = case(32, 64, 8, seed=2)
+    want_y, want_h = ss.scan_chunk(*order(c), kernel="lax")
+    y, h = ss.selective_scan(*order(c), block_t=block_t, block_d=block_d,
+                             unroll=unroll, interpret=True)
+    assert close(y, want_y) and close(h, want_h)
+
+
+@pytest.mark.parametrize("kernel", ["lax", "interpret"])
+@pytest.mark.parametrize("n_valid", [1, 11, 16])
+def test_padded_rows_leave_the_state_where_the_last_real_token_left_it(
+        kernel, n_valid):
+    c = case(16, 32, 4, seed=3)
+    y, h = ss.scan_chunk(*order(c), n_valid=jnp.int32(n_valid), kernel=kernel)
+    cut = {k: (v[:n_valid] if k in ("u", "dt", "z", "B", "C") else v)
+           for k, v in c.items()}
+    want_y, want_h = by_hand(**cut)
+    assert close(h, want_h) and close(y[:n_valid], want_y)
+
+
+def test_two_chunks_are_one_chunk():
+    c = case(32, 32, 4, seed=4)
+    whole_y, whole_h = ss.scan_chunk(*order(c), kernel="lax")
+    first = {k: (v[:16] if k in ("u", "dt", "z", "B", "C") else v)
+             for k, v in c.items()}
+    y1, h1 = ss.scan_chunk(*order(first), kernel="interpret")
+    second = {k: (v[16:] if k in ("u", "dt", "z", "B", "C") else v)
+              for k, v in c.items()}
+    second["h0"] = h1
+    y2, h2 = ss.scan_chunk(*order(second), kernel="interpret")
+    assert close(jnp.concatenate([y1, y2]), whole_y) and close(h2, whole_h)
+
+
+def test_a_bfloat16_state_is_not_the_float32_state():
+    """What the parity tool's ``state_bf16`` arm must be able to show: the
+    state handed on in bfloat16 drifts from the float32 one by more than
+    the float32 forms differ among themselves."""
+    c = case(64, 32, 4, seed=5)
+    _, want = ss.scan_chunk(*order(c), kernel="lax")
+    h = c["h0"]
+    for lo in range(0, 64, 8):
+        part = {k: (v[lo:lo + 8] if k in ("u", "dt", "z", "B", "C") else v)
+                for k, v in c.items()}
+        part["h0"] = h.astype(jnp.bfloat16).astype(jnp.float32)
+        _, h = ss.scan_chunk(*order(part), kernel="lax")
+    assert not close(h, want, tol=1e-4)
+
+
+def test_the_platform_picks_the_form_and_a_name_overrides_it(monkeypatch):
+    from comfyui_distributed_tpu.ops import flash_attention
+
+    seen = []
+    monkeypatch.setattr(ss, "selective_scan",
+                        lambda *a, **kw: seen.append(kw) or (a[1], a[0]))
+    c = case(16, 32, 4)
+    ss.scan_chunk(*order(c))
+    assert not seen                                   # the CPU: lax
+    monkeypatch.setattr(flash_attention, "_platform", lambda: "tpu")
+    ss.scan_chunk(*order(c))
+    assert seen == [dict(block_t=16, block_d=32, unroll=8, interpret=False)]
+    big = case(512, 1024, 4)
+    ss.scan_chunk(*order(big))
+    assert seen[-1] == dict(block_t=ss.BLOCK_T, block_d=ss.BLOCK_D,
+                            unroll=ss.UNROLL, interpret=False)
