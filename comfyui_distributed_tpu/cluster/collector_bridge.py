@@ -24,6 +24,7 @@ from typing import Any, Optional, Sequence
 import aiohttp
 import numpy as np
 
+from ..telemetry import enabled as _tm_enabled, metrics as _tm
 from ..utils import constants
 from ..utils.async_helpers import run_in_loop
 from ..utils.audio_payload import decode_audio, encode_audio
@@ -261,18 +262,28 @@ class CollectorBridge:
                         delegate_only: bool):
         """Master first, then workers in enabled order, batch_idx order
         within each worker (``nodes/collector.py:252-295``). A delegate-only
-        master contributes nothing (``:329-333``)."""
+        master contributes nothing (``:329-333``).
+
+        When no worker contributed an image there is nothing to
+        concatenate with, and ``local_images`` is handed through as the
+        object it came in: a device array stays on its chips, sharded as
+        the program left it, for the consumer to fetch from. Only a
+        worker's images (host arrays) bring the master's batch to the
+        host. ``cdt_collector_batches_total{path}`` counts which ran."""
+        remote = [imgs[idx][None]
+                  for imgs in (per_worker.get(w, {}) for w in expected)
+                  for idx in sorted(imgs)]
+        if _tm_enabled():
+            _tm.COLLECTOR_BATCHES.labels(
+                path="gathered" if remote else "local").inc()
+        if not remote:
+            return local_images
         batches: list[np.ndarray] = []
         if local_images is not None and not delegate_only:
             local = np.asarray(local_images, dtype=np.float32)
             if local.size:
                 batches.append(local)
-        for w in expected:
-            imgs = per_worker.get(w, {})
-            for idx in sorted(imgs):
-                batches.append(imgs[idx][None])
-        if not batches:
-            return local_images
+        batches.extend(remote)
         hw = batches[0].shape[1:3]
         kept = [b for b in batches if b.shape[1:3] == hw]
         if len(kept) != len(batches):

@@ -1641,11 +1641,13 @@ _SAVE_POOL = concurrent.futures.ThreadPoolExecutor(
 
 @register_node("SaveImage")
 class SaveImage(NodeDef):
-    """Writes a batch as ``<prefix>_<index>.png``. The batch is brought
-    to the host once; the images share nothing after that, so each of
-    several is quantised, encoded and written by a worker of
-    ``_SAVE_POOL``, side by side; one image takes the same steps on the
-    calling thread."""
+    """Writes a batch as ``<prefix>_<index>.png``. The images share
+    nothing, so each of several is fetched, quantised, encoded and written
+    by a worker of ``_SAVE_POOL``, side by side; one image takes the same
+    steps on the calling thread. The batch as a whole never comes to the
+    host: of a device array a worker copies the one addressable shard
+    that holds its image (a fan-out's four images leave their four chips
+    at once), and no device program is launched for it."""
 
     INPUTS = {"images": "IMAGE"}
     OPTIONAL = {"filename_prefix": "STRING"}
@@ -1662,15 +1664,39 @@ class SaveImage(NodeDef):
 
         out_dir = Path(output_dir or "output")
         out_dir.mkdir(parents=True, exist_ok=True)
-        batch = np.asarray(images)
-        if batch.ndim != 4:
+        if not hasattr(images, "shape"):
+            images = np.asarray(images)
+        if len(images.shape) != 4:
             # one [H,W,C] image gains its axis, anything else is refused
-            batch = to_uint8(batch)
-        pooled = len(batch) > 1
+            images = to_uint8(images)
+        n = images.shape[0]
+        # image index -> (the addressable shard that holds it whole, the
+        # shard's first row); None: host rows, indexed where they are
+        holders = None
+        if isinstance(images, jax.Array):
+            holders = {}
+            for shard in images.addressable_shards:
+                if shard.data.shape[1:] == images.shape[1:]:
+                    first, stop, _ = shard.index[0].indices(n)
+                    for i in range(first, stop):
+                        holders.setdefault(i, (shard, first))
+            if len(holders) < n:
+                # an image split over chips has no shard to copy: one gather
+                images, holders = np.asarray(images), None
+        pooled = n > 1
+
+        def fetch(i: int) -> np.ndarray:
+            if holders is None:
+                return images[i]
+            shard, first = holders[i]
+            # the shard's buffer is copied and indexed HERE: shard.data[j]
+            # would compile and run a slice program for every image
+            with span("image.fetch", bytes=images.nbytes // n):
+                return np.asarray(shard.data)[i - first]
 
         def save(i: int) -> str:
             p = out_dir / f"{filename_prefix}_{i:05d}.png"
-            arr = to_uint8(batch[i])
+            arr = to_uint8(fetch(i))
             with span("image.encode_png"):
                 data = encode_png(arr[0])
             with span("image.write", bytes=len(data)):
@@ -1682,11 +1708,11 @@ class SaveImage(NodeDef):
             # are children of node.SaveImage. Every task is waited for,
             # then the lowest failing index raises, as the loop's would
             tasks = [_SAVE_POOL.submit(contextvars.copy_context().run, save, i)
-                     for i in range(len(batch))]
+                     for i in range(n)]
             concurrent.futures.wait(tasks)
             paths = [t.result() for t in tasks]
         else:
-            paths = [save(i) for i in range(len(batch))]
+            paths = [save(i) for i in range(n)]
         if _tm_enabled():
             _tm.IMAGE_SAVE_IMAGES.labels(
                 mode="pooled" if pooled else "inline").inc(len(paths))

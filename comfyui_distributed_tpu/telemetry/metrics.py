@@ -79,11 +79,21 @@ WEIGHT_PLACEMENT_BYTES = REGISTRY.counter(
     "that received a copy. Grows when a program is built, never when one "
     "is called.")
 
+# --- collected batches (cluster/collector_bridge.py: _combine_images) ---------
+
+COLLECTOR_BATCHES = REGISTRY.counter(
+    "cdt_collector_batches_total",
+    "Collects on a master, by what became of its own batch: local (no "
+    "worker contributed an image: handed through as the object it came "
+    "in, a device array still on its chips) or gathered (brought to the "
+    "host and concatenated master-first with the workers' images).",
+    ("path",))
+
 # --- saved images (graph/nodes_builtin.py: SaveImage) -------------------------
 
 IMAGE_SAVE_IMAGES = REGISTRY.counter(
     "cdt_image_save_images_total",
-    "Images SaveImage wrote, by how their quantise, encode and write "
+    "Images SaveImage wrote, by how their fetch, quantise, encode and write "
     "ran: pooled (a batch of several, one worker thread an image, "
     "side by side) or inline (a batch of one, on the calling thread).",
     ("mode",))

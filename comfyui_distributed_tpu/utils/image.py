@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import base64
 import io
+import struct
+import zlib
 
 import numpy as np
 
@@ -35,15 +37,35 @@ def from_uint8(arr: np.ndarray) -> np.ndarray:
     return arr.astype(np.float32) / 255.0
 
 
+_PNG_SIGNATURE = b"\x89PNG\r\n\x1a\n"
+# channels -> PNG colour type at bit depth 8: grey, grey + alpha, RGB, RGBA
+_PNG_COLOUR_TYPE = {1: 0, 2: 4, 3: 2, 4: 6}
+
+
+def _png_chunk(kind: bytes, body: bytes) -> bytes:
+    return (struct.pack(">I", len(body)) + kind + body
+            + struct.pack(">I", zlib.crc32(body, zlib.crc32(kind))))
+
+
 def encode_png(image: np.ndarray, compress_level: int = 0) -> bytes:
     """One [H,W,C] image → PNG bytes (compress_level 0 for speed, matching
-    ``nodes/collector.py:156``)."""
-    from PIL import Image
-
-    img = Image.fromarray(to_uint8(image)[0])
-    buf = io.BytesIO()
-    img.save(buf, format="PNG", compress_level=compress_level)
-    return buf.getvalue()
+    ``nodes/collector.py:156``). The file is framed here: the pixels as
+    filter-0 rows in ONE ``IDAT``, deflated by zlib at ``compress_level``
+    (0 stores them), so they are copied once and never read by a filter
+    heuristic; any PNG reader decodes the input bit for bit."""
+    arr = to_uint8(image)[0]
+    height, width, channels = arr.shape
+    if channels not in _PNG_COLOUR_TYPE:
+        raise ValidationError(
+            f"a PNG holds 1 to 4 channels, got {channels} (shape {arr.shape})")
+    rows = np.zeros((height, 1 + width * channels), np.uint8)   # filter byte 0
+    rows[:, 1:] = arr.reshape(height, width * channels)
+    header = struct.pack(">IIBBBBB", width, height, 8,
+                         _PNG_COLOUR_TYPE[channels], 0, 0, 0)
+    return b"".join((
+        _PNG_SIGNATURE, _png_chunk(b"IHDR", header),
+        _png_chunk(b"IDAT", zlib.compress(rows, compress_level)),
+        _png_chunk(b"IEND", b"")))
 
 
 def decode_png(data: bytes) -> np.ndarray:

@@ -1,8 +1,11 @@
 """Image/audio codec tests (parity: reference utils/image.py +
 utils/audio_payload.py validation behavior)."""
 
+import io
+
 import numpy as np
 import pytest
+from PIL import Image
 
 from comfyui_distributed_tpu.utils import audio_payload, image
 from comfyui_distributed_tpu.utils.exceptions import ValidationError
@@ -15,6 +18,88 @@ def test_png_roundtrip_exact_uint8():
     assert decoded.shape == (8, 6, 3)
     # PNG is lossless over the uint8 quantization
     np.testing.assert_array_equal(image.to_uint8(decoded), image.to_uint8(img))
+
+
+def _pixels(height, width, channels, dtype):
+    rng = np.random.default_rng(height * 31 + width * 7 + channels)
+    if dtype == "uint8":
+        return rng.integers(0, 256, (height, width, channels), dtype=np.uint8)
+    # past both ends of [0,1]: the quantiser clips before the framing
+    return rng.random((height, width, channels), dtype=np.float32) * 1.3 - 0.15
+
+
+class TestFramedPng:
+    """``encode_png`` frames the file itself (ISSUE 38): signature, IHDR,
+    one IDAT of filter-0 rows, IEND. Held to PIL on the reading side."""
+
+    @pytest.mark.parametrize("dtype", ["float32", "uint8"])
+    @pytest.mark.parametrize("size", [(5, 7), (6, 8), (1, 1), (33, 2)])
+    @pytest.mark.parametrize("channels,mode", [(1, "L"), (2, "LA"),
+                                               (3, "RGB"), (4, "RGBA")])
+    def test_pil_reads_back_the_input(self, channels, mode, size, dtype):
+        x = _pixels(*size, channels, dtype)
+        want = image.to_uint8(x)[0]
+        data = image.encode_png(x)
+        Image.open(io.BytesIO(data)).verify()       # every chunk's CRC
+        opened = Image.open(io.BytesIO(data))
+        assert (opened.mode, opened.size) == (mode, size[::-1])
+        got = np.asarray(opened).reshape(want.shape)
+        np.testing.assert_array_equal(got, want)
+        # and the pixels PIL's own level-0 file of the same array holds
+        # (the encoder this one replaced)
+        buf = io.BytesIO()
+        Image.fromarray(want[..., 0] if channels == 1 else want).save(
+            buf, format="PNG", compress_level=0)
+        np.testing.assert_array_equal(
+            got, np.asarray(Image.open(buf)).reshape(want.shape))
+
+    @pytest.mark.parametrize("level", [1, 6, 9])
+    @pytest.mark.parametrize("channels", [1, 3, 4])
+    def test_a_level_is_zlibs_argument(self, channels, level):
+        x = np.broadcast_to(np.arange(64, dtype=np.uint8)[None, :, None],
+                            (48, 64, channels))
+        stored, deflated = image.encode_png(x), image.encode_png(x, level)
+        assert len(deflated) < len(stored)
+        for data in (stored, deflated):
+            Image.open(io.BytesIO(data)).verify()
+            np.testing.assert_array_equal(
+                np.asarray(Image.open(io.BytesIO(data))).reshape(x.shape), x)
+
+    def test_the_file_is_four_chunks_and_its_rows(self):
+        x = _pixels(6, 8, 3, "uint8")
+        data = image.encode_png(x)
+        assert data[:8] == b"\x89PNG\r\n\x1a\n"
+        kinds, at = [], 8
+        while at < len(data):
+            size = int.from_bytes(data[at:at + 4], "big")
+            kinds.append(data[at + 4:at + 8])
+            at += 12 + size
+        assert kinds == [b"IHDR", b"IDAT", b"IEND"] and at == len(data)
+        # stored, not deflated: the file is the pixels plus a fixed frame
+        assert len(data) - x.size < 100
+
+    @pytest.mark.parametrize("shape", [(4, 4, 5), (4, 4, 0), (2, 2, 4, 4, 3),
+                                       (4,), ()])
+    def test_what_is_no_image_is_refused(self, shape):
+        with pytest.raises(ValidationError):
+            image.encode_png(np.zeros(shape, np.float32))
+
+    def test_a_batch_gives_its_first_image(self):
+        x = _pixels(4, 4, 3, "uint8")
+        assert image.encode_png(np.stack([x, 255 - x])) == image.encode_png(x)
+
+    def test_the_encode_side_does_not_import_pil(self):
+        import ast
+        import inspect
+        tree = ast.parse(inspect.getsource(image))
+        importers = {fn.name for fn in ast.walk(tree)
+                     if isinstance(fn, ast.FunctionDef)
+                     for node in ast.walk(fn)
+                     if isinstance(node, (ast.Import, ast.ImportFrom))
+                     and "PIL" in ast.dump(node)}
+        assert importers == {"decode_png"}
+        assert not any(isinstance(node, (ast.Import, ast.ImportFrom))
+                       and "PIL" in ast.dump(node) for node in tree.body)
 
 
 def test_b64_roundtrip_and_invalid():

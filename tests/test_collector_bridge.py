@@ -20,6 +20,42 @@ def img(value, hw=(4, 4)):
     return np.full((hw[0], hw[1], 3), value, np.float32)
 
 
+def local_batch(how):
+    """The master's own batch as a node may hand it to the collector."""
+    if how == "none":
+        return None
+    x = np.stack([img(v) for v in (0.1, 0.35, 0.6, 0.85)])
+    if how == "numpy":
+        return x
+    if how == "numpy_uint8":
+        return (x * 255).astype(np.uint8)
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    if how == "one_device":
+        return jax.device_put(x, jax.devices()[0])
+    mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))     # a fan-out's output
+    return jax.device_put(x, NamedSharding(mesh, P("dp", None, None, None)))
+
+
+def combine_as_before(local_images, per_worker, expected, delegate_only):
+    """``_combine_images`` as it was before ISSUE 38: the reference for
+    every collect a worker contributed to."""
+    batches = []
+    if local_images is not None and not delegate_only:
+        local = np.asarray(local_images, dtype=np.float32)
+        if local.size:
+            batches.append(local)
+    for w in expected:
+        imgs = per_worker.get(w, {})
+        for idx in sorted(imgs):
+            batches.append(imgs[idx][None])
+    if not batches:
+        return local_images
+    hw = batches[0].shape[1:3]
+    return np.concatenate([b for b in batches if b.shape[1:3] == hw], axis=0)
+
+
 class TestCombineImages:
     def test_master_first_then_worker_order(self):
         per_worker = {
@@ -48,6 +84,79 @@ class TestCombineImages:
         local = img(0.5)[None]
         out = CollectorBridge._combine_images(local, {}, (), False)
         np.testing.assert_array_equal(out, local)
+
+    @pytest.mark.parametrize("expected,per_worker", [
+        ((), {}), (("w1", "w2"), {"w1": {}, "w2": {}}), (("w1",), {})])
+    @pytest.mark.parametrize("how", ["numpy", "numpy_uint8", "one_device",
+                                     "dp_rows", "none"])
+    def test_nothing_to_concatenate_with_hands_the_batch_through(
+            self, how, expected, per_worker):
+        """ISSUE 38: with no worker's image the master's batch is returned
+        as the OBJECT it came in — a device array stays on its chips,
+        sharded as the program left it; nothing is copied or converted."""
+        local = local_batch(how)
+        out = CollectorBridge._combine_images(local, per_worker, expected,
+                                              delegate_only=False)
+        assert out is local
+        if how == "dp_rows":
+            assert len({s.device for s in out.addressable_shards}) == 4
+
+    @pytest.mark.parametrize("how", ["numpy", "one_device", "dp_rows"])
+    def test_a_delegated_master_with_no_results_keeps_its_placeholder(
+            self, how):
+        local = local_batch(how)
+        assert CollectorBridge._combine_images(
+            local, {"w1": {}}, ("w1",), delegate_only=True) is local
+
+    @pytest.mark.parametrize("how", ["numpy", "numpy_uint8", "one_device",
+                                     "dp_rows"])
+    @pytest.mark.parametrize("case", ["worker", "delegate_only",
+                                      "mismatched", "two_workers"])
+    def test_with_a_workers_images_the_result_is_the_gathers(self, how, case):
+        """A worker contributed: the path before ISSUE 38 (kept below as
+        the reference), whatever the master's batch is made of."""
+        local = local_batch(how)
+        per_worker, expected, delegate_only = {
+            "worker": ({"w1": {0: img(0.3)}}, ("w1",), False),
+            "delegate_only": ({"w1": {0: img(0.3)}}, ("w1",), True),
+            "mismatched": ({"w1": {0: img(0.5, hw=(8, 8)), 1: img(0.6)}},
+                           ("w1",), False),
+            "two_workers": ({"w2": {0: img(0.8)},
+                             "w1": {1: img(0.4), 0: img(0.2)}, "w3": {}},
+                            ("w1", "w2", "w3"), False),
+        }[case]
+        out = CollectorBridge._combine_images(local, per_worker, expected,
+                                              delegate_only)
+        want = combine_as_before(local, per_worker, expected, delegate_only)
+        assert isinstance(out, np.ndarray) and out.dtype == want.dtype
+        np.testing.assert_array_equal(out, want)
+
+    def test_counts_which_path_ran(self):
+        from comfyui_distributed_tpu import telemetry
+
+        def counted() -> dict:
+            series = telemetry.REGISTRY.snapshot().get(
+                "cdt_collector_batches_total", {}).get("series", [])
+            by_path = {s["labels"]["path"]: s["value"] for s in series}
+            return {k: by_path.get(k, 0) for k in ("local", "gathered")}
+
+        was = telemetry.enabled()
+        telemetry.set_enabled(True)
+        try:
+            before = counted()
+            CollectorBridge._combine_images(img(0.1)[None], {}, (), False)
+            CollectorBridge._combine_images(
+                local_batch("dp_rows"), {"w1": {}}, ("w1",), False)
+            CollectorBridge._combine_images(
+                img(0.1)[None], {"w1": {0: img(0.3)}}, ("w1",), False)
+            after = counted()
+            telemetry.set_enabled(False)
+            CollectorBridge._combine_images(img(0.1)[None], {}, (), False)
+            assert counted() == after
+        finally:
+            telemetry.set_enabled(was)
+        assert {k: after[k] - before[k] for k in after} == {
+            "local": 2, "gathered": 1}
 
 
 class TestCombineAudio:

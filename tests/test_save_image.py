@@ -1,13 +1,16 @@
-"""A batch's images are saved side by side (ISSUE 33).
+"""A batch's images are saved side by side (ISSUE 33), each from the
+chip that holds it (ISSUE 38).
 
-``SaveImage`` brings a batch to the host once and gives every image of
-several to a worker of its module's pool: quantise, encode, write.
-Held here to the loop it replaces (kept below as the reference): the same
-file names in the same order with the same bytes, whatever the batch is
-made of; one image never leaves the calling thread; a failing image
-surfaces as the loop's would, after the others have finished; the spans
-of every image hang under the caller's span; two calls at once share the
-pool without waiting on each other for ever.
+``SaveImage`` gives every image of several to a worker of its module's
+pool: fetch, quantise, encode, write. Of a device array a worker copies
+the one addressable shard that holds its image; the batch as a whole never
+comes to the host and no device program runs for it. Held here to the
+loop it replaces (kept below as the reference): the same file names in
+the same order with the same bytes, whatever the batch is made of; one
+image never leaves the calling thread; a failing image surfaces as the
+loop's would, after the others have finished; the spans of every image
+hang under the caller's span; two calls at once share the pool without
+waiting on each other for ever.
 """
 
 import sys
@@ -59,7 +62,15 @@ def placed(x: np.ndarray, how: str):
     if how == "one_device":
         return jax.device_put(x, jax.devices()[0])
     mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))     # a fan-out's output
-    return jax.device_put(x, NamedSharding(mesh, P("dp", None, None, None)))
+    spec = {"dp_rows": P("dp", None, None, None),
+            "replicated": P(),
+            # every image split over the chips: no shard holds one whole
+            "split_height": P(None, "dp", None, None)}[how]
+    return jax.device_put(x, NamedSharding(mesh, spec))
+
+
+def on_device(how: str) -> bool:
+    return how != "numpy" and how != "single_hwc"
 
 
 @pytest.fixture
@@ -84,7 +95,8 @@ class TestBytesEqualTheSerialLoop:
     @pytest.mark.parametrize("dtype", ["float32", "uint8"])
     @pytest.mark.parametrize("how,n", [
         ("numpy", 4), ("dp_rows", 4), ("dp_rows", 8), ("one_device", 4),
-        ("numpy", 3)])
+        ("numpy", 3), ("replicated", 4), ("replicated", 3),
+        ("split_height", 4)])
     def test_names_order_and_bytes(self, tmp_path, counted, how, n, dtype):
         x = pixels(n, dtype)
         images = placed(x, how)
@@ -135,7 +147,9 @@ class TestOneImageStaysOnTheCallingThread:
         assert (after["inline"] - before["inline"],
                 after["pooled"] - before["pooled"]) == (1, 0)
         names = sorted(s["name"] for s in telemetry.SPAN_STORE.spans("exec_one"))
-        assert names == ["image.encode_png", "image.write", "node.SaveImage"]
+        assert names == ["image.encode_png"] + (
+            ["image.fetch"] if on_device(how) else []) + [
+            "image.write", "node.SaveImage"]
 
 
 class TestAFailingImage:
@@ -214,15 +228,124 @@ class TestSpansOfEveryImage:
         by_name: dict = {}
         for s in spans:
             by_name.setdefault(s["name"], []).append(s)
+        phases = ["image.encode_png", "image.write"] + (
+            ["image.fetch"] if on_device(how) else [])
         assert {k: len(v) for k, v in by_name.items()} == {
-            "node.SaveImage": 1, "image.encode_png": 4, "image.write": 4}
-        for name in ("image.encode_png", "image.write"):
+            "node.SaveImage": 1, **{name: 4 for name in phases}}
+        for name in phases:
             assert {s["parent_id"] for s in by_name[name]} == {node_id}, name
         assert all(int(s["attrs"]["bytes"]) > 0 for s in by_name["image.write"])
         # mirrored for the profiler on the workers' own threads
         mirrored = [(n, t) for n, t in events if n.startswith("cdt.image.")]
-        assert len(mirrored) == 8
+        assert len(mirrored) == 4 * len(phases)
         assert threading.get_ident() not in {t for _, t in mirrored}
+
+
+COMPILES = []          # every backend compile of this process, once listened for
+
+
+@pytest.fixture(scope="module")
+def compiles():
+    import jax.monitoring
+
+    def on_duration(event: str, seconds: float, **_):
+        if event.endswith("backend_compile_duration"):
+            COMPILES.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return COMPILES
+
+
+def host_copies(monkeypatch, on_copy=lambda: None) -> list:
+    """The jax arrays ``SaveImage``'s module brings to the host from now
+    on, in order: every such copy there goes through ``np.asarray``."""
+    import jax
+
+    real, asked = np.asarray, []
+
+    def seen(a, *args, **kwargs):
+        if isinstance(a, jax.Array):
+            asked.append(a)
+            on_copy()
+        return real(a, *args, **kwargs)
+
+    monkeypatch.setattr(nodes_builtin.np, "asarray", seen)
+    return asked
+
+
+class TestEachWorkerFetchesItsOwnShard:
+    """ISSUE 38: a device batch leaves its chips shard by shard, each on
+    the worker that saves the image; nothing gathers the whole."""
+
+    @pytest.mark.parametrize("dtype", ["float32", "uint8"])
+    @pytest.mark.parametrize("how,n,shards_read", [
+        ("dp_rows", 4, 4), ("dp_rows", 8, 4), ("replicated", 4, 1),
+        ("one_device", 4, 1)])
+    def test_no_whole_batch_copy_and_no_device_program(
+            self, tmp_path, counted, monkeypatch, compiles, how, n,
+            shards_read, dtype):
+        x = pixels(n, dtype)[:, :H - 1, :W - 3]   # a shape no other test has
+        images = placed(x, how)
+        whole = [s.data for s in images.addressable_shards
+                 if s.data.shape == images.shape]
+        (tmp_path / "ref").mkdir()
+        want = serial_loop(x, tmp_path / "ref", "img")
+        asked = host_copies(monkeypatch)
+        compiled_before = len(compiles)
+        with telemetry.span("node.SaveImage", trace_id="exec_fetch") as (
+                _, node_id):
+            SaveImage().execute(images, filename_prefix="img",
+                                output_dir=str(tmp_path / "out"))
+        ran = compiles[compiled_before:]
+        monkeypatch.undo()
+        # indexing a device array eagerly would have compiled a slice
+        assert ran == []
+        # only shards were copied: the batch itself never (a one-device or
+        # replicated batch IS one shard: its buffer, not a gather)
+        assert all(any(a is s.data for s in images.addressable_shards)
+                   for a in asked)
+        assert not any(a is images for a in asked)
+        assert len({id(a) for a in asked}) == shards_read
+        assert len(asked) == n
+        if not whole:
+            assert {a.shape[0] for a in asked} == {n // 4}
+        fetches = [s for s in telemetry.SPAN_STORE.spans("exec_fetch")
+                   if s["name"] == "image.fetch"]
+        assert len(fetches) == n
+        assert {s["parent_id"] for s in fetches} == {node_id}
+        assert {int(s["attrs"]["bytes"]) for s in fetches} == {x[0].nbytes}
+        got = sorted((tmp_path / "out").iterdir())
+        assert [p.read_bytes() for p in got] == [p.read_bytes() for p in want]
+
+    def test_the_fetches_of_a_fan_out_run_on_four_threads(
+            self, tmp_path, counted, monkeypatch):
+        if nodes_builtin._SAVE_POOL._max_workers < 4:
+            pytest.skip("the pool of this host has fewer than four workers")
+        # four workers inside their fetch at once: each waits for the others
+        inside = threading.Barrier(4, timeout=30)
+        threads = []
+
+        def arrived():
+            threads.append(threading.get_ident())
+            inside.wait()
+
+        host_copies(monkeypatch, on_copy=arrived)
+        SaveImage().execute(placed(pixels(4, "float32"), "dp_rows"),
+                            output_dir=str(tmp_path))
+        monkeypatch.undo()
+        assert len(set(threads)) == 4
+        assert threading.get_ident() not in threads
+
+    def test_an_image_split_over_chips_is_gathered_once(
+            self, tmp_path, counted, monkeypatch):
+        asked = host_copies(monkeypatch)
+        images = placed(pixels(4, "float32"), "split_height")
+        with telemetry.span("node.SaveImage", trace_id="exec_split"):
+            SaveImage().execute(images, output_dir=str(tmp_path))
+        monkeypatch.undo()
+        assert len(asked) == 1 and asked[0] is images
+        assert "image.fetch" not in {
+            s["name"] for s in telemetry.SPAN_STORE.spans("exec_split")}
 
 
 class TestTwoCallsAtOnce:
