@@ -1012,6 +1012,11 @@ class TPUPromptRewrite(NodeDef):
                 scanned = tokens * getattr(cfg, "scan_layers_per_token", 0)
                 if scanned:
                     _tm.LLM_SCAN_TOKENS.labels(phase=phase).inc(scanned)
+            pairs = getattr(cfg, "attended_keys", None)
+            if pairs is not None:     # window and full layers mixed
+                for (kind, phase), n in pairs(prompt_tokens,
+                                              new_tokens).items():
+                    _tm.LLM_ATTN_KEYS.labels(layers=kind, phase=phase).inc(n)
             if cfg.moe_layers:
                 _tm.LLM_EXPERT_ROWS.labels(form=out["prefill_form"]).inc(
                     out["rows_prefill"])

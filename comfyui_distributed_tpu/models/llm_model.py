@@ -26,7 +26,11 @@ class LLMModel(NamedTuple):
     # chunks through the cache (None: ``prefill`` takes it whole):
     # (cfg, weights, cache, ids [C], start, n_valid, all_logits=False) ->
     # (logits, cache, held [expert layers], rows [expert layers]); the
-    # chunk's length is ``cfg.prefill_chunk_tokens``
+    # chunk's length is ``cfg.prefill_chunk_tokens`` (a model whose cache
+    # holds a RING ties it to the ring's length), ``start`` a multiple of
+    # it, and the cache may hold three kinds of leaf: full-length rows,
+    # rings and recurrent states (what a padded chunk owes each:
+    # ``chunked_prefill``)
     prefill_chunk: "Callable | None" = None
 
 
@@ -53,7 +57,10 @@ def chunked_prefill(model: LLMModel, cfg, weights, ids, max_len: int,
     past ``n_valid`` may hold anything (nothing reads them before a decode
     step rewrites them), but a RECURRENT leaf (a state, a convolution's
     tail) must come back as token ``n_valid − 1`` left it, for a padded row
-    that advanced it would be part of every token after. Answers
+    that advanced it would be part of every token after. The rows of a
+    RING (slot ``position % length``) are a recurrent leaf in this sense: a
+    padded row would overwrite the slot of a row the next token still sees,
+    so a padded chunk writes only its ``n_valid`` rows there. Answers
     ``(logits, cache, held, rows)``: the last position's logits [V] (every position's [T,V] with ``all_logits``), held slots
     and rows multiplied per expert layer summed over the chunks."""
     import jax

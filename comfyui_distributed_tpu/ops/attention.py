@@ -571,3 +571,20 @@ def note_shared_kv_causal(num_heads: int, head_dim: int, q_len: int,
                                dtype).key_str(),
         KernelChoice("shared_kv_causal", block_q, block_k,
                      reason="chunked prefill over one shared K/V head"))
+
+
+def note_gqa(banded: bool, num_heads: int, head_dim: int, q_len: int,
+             kv_len: int, dtype, block_q: int, block_k: int) -> None:
+    """As :func:`note_latent_causal`, for the blocked causal kernel over
+    grouped key/value heads (``gqa_attention.causal_chunk``): two tiers of
+    their own in the ``attention:`` line and ``cdt_attn_kernel_selected``,
+    ``gqa_window`` (a band: the last ``window`` keys) and ``gqa_causal``
+    (every key below the query)."""
+    from .autotune import GeometryKey, KernelChoice
+
+    _note_selection(
+        GeometryKey.from_shape(num_heads, head_dim, q_len, kv_len,
+                               dtype).key_str(),
+        KernelChoice("gqa_window" if banded else "gqa_causal", block_q,
+                     block_k, reason="chunked prefill over grouped K/V "
+                     "heads" + (", banded" if banded else "")))

@@ -59,8 +59,10 @@ TIERS = ("fused", "packed", "bh", "xla")
 # kernels that are no tier of the bidirectional dispatch (no table row, no
 # policy arm chooses them) but report themselves the same way: the blocked
 # causal kernels of a chunked prefill (ops/flash_latent.py), over a latent
-# cache and over one shared key/value head
-REPORTED_TIERS = TIERS + ("latent_causal", "shared_kv_causal")
+# cache, over one shared key/value head, and over grouped key/value heads
+# (whole, or the band of a window layer)
+REPORTED_TIERS = TIERS + ("latent_causal", "shared_kv_causal", "gqa_causal",
+                          "gqa_window")
 
 # the in-repo resolved table for the known model zoo
 _SHIPPED_PATH = Path(__file__).resolve().parent / "attn_table_default.json"

@@ -151,7 +151,14 @@ def prefill_form(rows: int, r: Routing, tile: int = GROUP_TILE) -> str:
     """``grouped`` where a held expert expects (``rows · per_token /
     experts``, routing even) at least half a tile of rows, ``dense``
     below that: there most of every tile would be padding, and each of
-    them still reads its expert's weights."""
+    them still reads its expert's weights. EXACTLY at half a tile (4096
+    rows × top 4 ÷ 256 experts = 64: the fifth rewriter's chunk) even
+    routing would make the grouped form multiply 2.0 rows a routed row;
+    served, it multiplied 1.18 (PERF.md §6, PR 39: a brief repeats its
+    tokens, so the experts that are chosen at all get thousands of rows a
+    chunk and only each one's last tile is padded), where the dense form
+    multiplies ``held experts × rows`` whatever is routed: 64 a routed
+    row there."""
     return "grouped" if 2 * rows * r.per_token >= tile * r.experts \
         else "dense"
 

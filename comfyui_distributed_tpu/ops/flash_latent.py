@@ -1,15 +1,17 @@
 """Pallas causal attention for a prefill walked in chunks through a cache.
 
-Two kernels on one schedule. ``latent_causal_mha`` is the prefill side of
+Three kernels on one schedule. ``latent_causal_mha`` is the prefill side of
 multi-head latent attention (``ops/latent_attention.py``): a chunk of
 queries at positions ``start .. start+C−1`` against the rows ``j ≤`` each
-query's position of a decompressed workspace. ``shared_kv_causal_mha`` (at
-the end of the file) is the same for many query heads over ONE key/value
-head (``ops/shared_kv_attention.py``): the schedule's pieces —
+query's position of a decompressed workspace. ``shared_kv_causal_mha`` is
+the same for many query heads over ONE key/value head
+(``ops/shared_kv_attention.py``). The third (at the end of the file, jitted
+as ``gqa_causal_mha`` and ``gqa_window_mha``: ``ops/gqa_attention.py``)
+serves GROUPS of query heads over a key/value head each and may see a BAND
+only, the last ``window`` keys of each query. The schedule's pieces —
 ``_last_block``, ``_on_visible_blocks``, ``_mask_above_diagonal``,
 ``_accumulate`` — are shared, the logits and the K/V tiles are each
-kernel's own. What the bidirectional kernels of ``flash_attention.py`` do
-not have:
+kernel's own. What the bidirectional kernels of ``flash_attention.py`` lack:
 
 - a **causal mask** whose diagonal moves with ``start`` (a scalar the
   kernel prefetches: one compiled kernel serves every chunk of a scan).
@@ -18,16 +20,14 @@ not have:
   the tile it holds) nor computed; only a block the diagonal crosses is
   masked;
 - (``latent_causal_mha``) **unequal widths**: a query and key are ``nope
-  + rope`` wide (128 + 64), a value ``v`` wide (128). The rope key is ONE ``[S, rope]`` array shared
-  by every head — a head's logits are ``q_nope·k_nope + q_rope·k_rope``,
-  two products into one float32 tile, so no per-head copy of it exists;
-- keys and values read from ONE workspace ``[S, H·(nope+v)]`` (a head's
-  ``[k_nope | v]`` side by side, as the decompression ``c W_b`` leaves
-  them): with ``nope == v`` the K tile of head ``h`` is column block ``2h``
-  and the V tile ``2h+1`` of the same array.
+  + rope`` wide (128 + 64), a value ``v`` wide (128), the rope key ONE
+  ``[S, rope]`` array shared by every head (two products into one float32
+  tile); keys and values read from ONE workspace ``[S, H·(nope+v)]``: with
+  ``nope == v`` the K tile of head ``h`` is column block ``2h`` and the V
+  tile ``2h+1`` of the same array.
 
-grid = (heads, C/block_q, S/block_k), K innermost; running max, sum and
-the float32 accumulator live in VMEM scratch across K steps. Queries come
+grid = (heads, C/block_q, S/block_k), K innermost; running max, sum and the
+float32 accumulator live in VMEM scratch across K steps. Queries come
 pre-multiplied by the softmax scale.
 """
 
@@ -76,13 +76,21 @@ def _accumulate(s, v, m_ref, l_ref, acc_ref, precision):
     l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
 
-def _on_visible_blocks(step, j, last, first_row, block_k: int):
+def _on_visible_blocks(step, j, last, first_row, block_k: int, first=None,
+                       edge=None):
     """Run ``step(masked)`` for K block ``j`` if the q block sees it:
     masked only where the diagonal crosses it (its last column lies past
-    the q block's first row), skipped past ``last``."""
+    the q block's first row), skipped past ``last``. Under a band, also
+    skipped below ``first`` and masked where the band's lower edge crosses
+    it (its first column lies below ``edge``, the first column the q
+    block's LAST row sees)."""
     crosses = (j + 1) * block_k - 1 > first_row
-    pl.when((j <= last) & crosses)(lambda: step(True))
-    pl.when((j <= last) & jnp.logical_not(crosses))(lambda: step(False))
+    seen = j <= last
+    if first is not None:
+        crosses |= j * block_k < edge
+        seen &= j >= first
+    pl.when(seen & crosses)(lambda: step(True))
+    pl.when(seen & jnp.logical_not(crosses))(lambda: step(False))
 
 
 def _init_running(j, m_ref, l_ref, acc_ref):
@@ -245,3 +253,121 @@ def shared_kv_causal_mha(q, k, v, start, num_heads: int, block_q: int,
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(jnp.reshape(start, (1,)).astype(jnp.int32), q, k, v)
+
+
+# --- groups of query heads over a key/value head each, whole or a band -----
+
+
+def _first_column(row, window: "int | None", lowest):
+    """The first column the query at position ``row`` sees: the band's
+    lower edge (``window`` keys, its own included) or the lowest valid
+    column, whichever is higher."""
+    return lowest if window is None \
+        else jnp.maximum(row - window + 1, lowest)
+
+
+def _mask_outside_band(s, first_row, first_col, window: "int | None",
+                       lowest):
+    """As :func:`_mask_above_diagonal`, with every column below its row's
+    first visible one (:func:`_first_column`) at −inf too."""
+    row = first_row + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = first_col + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    seen = (col <= row) & (col >= _first_column(row, window, lowest))
+    return jnp.where(seen, s, NEG_INF)
+
+
+def _gqa_kernel(bounds_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                acc_ref, *, block_q: int, block_k: int, num_k_blocks: int,
+                window: "int | None", precision):
+    i, j = pl.program_id(1), pl.program_id(2)
+    start, lowest = bounds_ref[0], bounds_ref[1]
+    first_row = start + i * block_q
+    last = _last_block(start, i, block_q, block_k, num_k_blocks)
+    first = _first_column(first_row, window, lowest) // block_k
+    edge = _first_column(first_row + block_q - 1, window, lowest)
+    _init_running(j, m_ref, l_ref, acc_ref)
+
+    def step(masked: bool):
+        s = jax.lax.dot_general(q_ref[...], k_ref[0],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                                precision=precision)
+        if masked:
+            s = _mask_outside_band(s, first_row, j * block_k, window, lowest)
+        _accumulate(s, v_ref[0], m_ref, l_ref, acc_ref, precision)
+
+    _on_visible_blocks(step, j, last, first_row, block_k, first, edge)
+
+    @pl.when(j == num_k_blocks - 1)
+    def _finalize():
+        o_ref[...] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+def _gqa_mha(q, k, v, start, lowest, num_heads: int, window: "int | None",
+             block_q: int, block_k: int, interpret: bool):
+    """Causal attention of ``num_heads`` query heads over ``G`` key/value
+    heads, query head ``h`` reading head ``h // (num_heads / G)``: the
+    schedule above with K and V tiles indexed by the GROUP (no per-head
+    copy of the cache exists) and, with ``window``, a band — a query sees
+    the ``window`` keys up to its own, K blocks wholly below the band are
+    neither fetched nor computed, the block its lower edge crosses is
+    masked as the diagonal's is. ``q`` [C, H·d] times the softmax scale;
+    ``k``, ``v`` [G, S, d]; ``start`` the first query's position and
+    ``lowest`` the first valid key row (both traced: rows below ``lowest``
+    hold nothing yet). ``C % block_q == 0``, ``S % block_k == 0``.
+    Answers [C, H·d]."""
+    C, (G, S, d) = q.shape[0], k.shape
+    per_group = num_heads // G
+    nq, nk = C // block_q, S // block_k
+    kernel = functools.partial(_gqa_kernel, block_q=block_q,
+                               block_k=block_k, num_k_blocks=nk,
+                               window=window,
+                               precision=_precision_of(q.dtype))
+
+    def kv_block(h, i, j, bounds_ref):
+        start, lowest = bounds_ref[0], bounds_ref[1]
+        first = _first_column(start + i * block_q, window, lowest) // block_k
+        last = _last_block(start, i, block_q, block_k, nk)
+        return (h // per_group, jnp.clip(j, first, last), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(num_heads, nq, nk),
+        in_specs=[pl.BlockSpec((block_q, d), lambda h, i, j, b: (i, h)),
+                  pl.BlockSpec((1, block_k, d), kv_block),
+                  pl.BlockSpec((1, block_k, d), kv_block)],
+        out_specs=pl.BlockSpec((block_q, d), lambda h, i, j, b: (i, h)),
+        scratch_shapes=_running_scratch(block_q, d))
+    bounds = jnp.stack([jnp.asarray(start, jnp.int32),
+                        jnp.asarray(lowest, jnp.int32)])
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((C, num_heads * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(bounds, q, k, v)
+
+
+# one body under two names, so that a device trace tells the full layers'
+# kernel from the window layers'
+
+
+@functools.partial(jax.jit, static_argnames=("num_heads", "block_q",
+                                             "block_k", "interpret"))
+def gqa_causal_mha(q, k, v, start, num_heads: int, block_q: int,
+                   block_k: int, interpret: bool):
+    """:func:`_gqa_mha` over every key ``≤`` the query's position."""
+    return _gqa_mha(q, k, v, start, 0, num_heads, None, block_q, block_k,
+                    interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("num_heads", "window",
+                                             "block_q", "block_k",
+                                             "interpret"))
+def gqa_window_mha(q, k, v, start, lowest, num_heads: int, window: int,
+                   block_q: int, block_k: int, interpret: bool):
+    """:func:`_gqa_mha` over the last ``window`` keys of each query."""
+    return _gqa_mha(q, k, v, start, lowest, num_heads, window, block_q,
+                    block_k, interpret)

@@ -159,14 +159,25 @@ LLM_SCAN_TOKENS = REGISTRY.counter(
     "model with no state-space layer never moves it.",
     ("phase",))
 
+LLM_ATTN_KEYS = REGISTRY.counter(
+    "cdt_llm_attn_keys_total",
+    "(query, key) pairs ONE head attended in a language model's attention, "
+    "summed over the layers of a kind: full (every key below the query: "
+    "T(T+1)/2 a layer a prefill) or window (at most sliding_window keys a "
+    "query: ~T x W), by phase (prefill, decode). From the config's sizes "
+    "and the request's token counts; a model that does not mix the two "
+    "kinds never moves it.",
+    ("layers", "phase"))
+
 # --- attention kernel dispatch / autotune (ops/attention.py, ops/autotune.py)
 
 ATTN_KERNEL_SELECTED = REGISTRY.counter(
     "cdt_attn_kernel_selected",
     "Attention kernel-tier selections at trace time, by tier "
-    "(fused/packed/bh/xla; latent_causal, shared_kv_causal: a chunked "
-    "prefill's own kernel over a latent cache / over one shared "
-    "key/value head), geometry (hH.dD.qN.kvN.dtype — bucketed, "
+    "(fused/packed/bh/xla; latent_causal, shared_kv_causal, gqa_causal, "
+    "gqa_window: a chunked prefill's own kernel over a latent cache / one "
+    "shared key/value head / grouped key/value heads, whole or a window's "
+    "band), geometry (hH.dD.qN.kvN.dtype — bucketed, "
     "so cardinality is bounded by the model zoo) and resolved blocks "
     "('<block_q>/<block_k>', for packed also ':k-resident' or "
     "':k-streamed'; '' where the tier has none). Increments once per "

@@ -384,3 +384,80 @@ def test_the_state_space_rewriters_programs_fit_beside_sdxl(chip,
     mem = compiled.memory_analysis()
     decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
     assert 5.6 < decode_gib < 6.5 and decode_gib + sdxl < 15.75 - 2.0
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+def test_the_grouped_query_kernel_compiles_at_the_served_geometry(chip,
+                                                                  window):
+    """``flash_latent``'s third kernel under both of its names as the
+    window/full rewriter's prefill calls it: 48 query heads of 128 over 8
+    key/value heads, a 4096-token chunk — over the 128 k buffer rounded to
+    the K block (``gqa_causal_mha``) and over ``[ring ; chunk]`` under a
+    band of 4096 (``gqa_window_mha``) — 1024-row tiles, traced bounds."""
+    from comfyui_distributed_tpu.models.llm_trinity import TrinityConfig
+    from comfyui_distributed_tpu.ops import flash_latent
+
+    cfg = TrinityConfig.trinity_share()
+    H, G, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    C, S = 4096, 132096 if window is None else 8192
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+
+    blocks = dict(num_heads=H, block_q=cfg.attn_block_q,
+                  block_k=cfg.attn_block_k, interpret=False)
+    if window is None:
+        lowered = flash_latent.gqa_causal_mha.lower(
+            arg(C, H * d), arg(G, S, d), arg(G, S, d), scalar, **blocks)
+    else:
+        lowered = flash_latent.gqa_window_mha.lower(
+            arg(C, H * d), arg(G, S, d), arg(G, S, d), scalar, scalar,
+            window=window, **blocks)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_the_window_and_full_rewriters_programs_fit_beside_sdxl(chip,
+                                                                monkeypatch):
+    """Both language programs of ``trinity-large-preview.brief128k-sdxl8``
+    at the cell's sizes (131 072 + 128 tokens, the published widths, 16
+    experts held): they compile for the chip, their arguments + temporaries
+    leave room for SDXL's segment program (4.79 + 0.56 GiB,
+    docs/weights.md) in 15.75 GiB, ``llm_prefill`` holds one Pallas call
+    site a layer — five — and updates the full layer's buffer in place."""
+    from comfyui_distributed_tpu.diffusion.pipeline_llm import LLMPipeline
+    from comfyui_distributed_tpu.models.llm_trinity import TrinityConfig
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    cfg = TrinityConfig.trinity_share()
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+            tree)
+
+    weights = place(cfg.model.init(cfg, None, abstract=True))
+    prefill, decode = LLMPipeline(cfg, weights).programs(131072, 128)
+    ids = jax.ShapeDtypeStruct((131072,), jnp.int32, sharding=chip)
+    logits, cache, *_ = jax.eval_shape(prefill.jitted, weights, ids)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    gib, sdxl = 2.0 ** 30, 4.79 + 0.56
+    compiled = prefill.jitted.lower(weights, ids).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") \
+        == cfg.num_hidden_layers == 5
+    # the 0.25 GiB K and V buffers are written where they lie: no copy of
+    # one inside the scan of chunks
+    assert not [line for line in text.splitlines()
+                if "bf16[8,132096,128]" in line.split("=")[0]
+                and " copy(" in line]
+    mem = compiled.memory_analysis()
+    prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
+    assert 4.9 < prefill_gib < 6.2 and prefill_gib + sdxl < 15.75 - 2.0
+    compiled = decode.jitted.lower(
+        weights, place(logits), place(cache), place(key),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=chip)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()     # decode is XLA
+    mem = compiled.memory_analysis()
+    decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
+    assert 5.2 < decode_gib < 6.8 and decode_gib + sdxl < 15.75 - 2.0

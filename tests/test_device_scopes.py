@@ -213,7 +213,8 @@ def _llm_decode(module, config, tokens):
 LLMS = {"ling-tiny": ("llm_hybrid", "LLMConfig", 24),
         "motif-tiny": ("llm_motif", "MotifConfig", 24),
         "kimi-tiny": ("llm_kimi", "KimiConfig", 37),     # three chunks of 16
-        "jamba-tiny": ("llm_jamba", "JambaConfig", 37)}
+        "jamba-tiny": ("llm_jamba", "JambaConfig", 37),
+        "trinity-tiny": ("llm_trinity", "TrinityConfig", 21)}  # 2 chunks + 5
 
 PROGRAMS = {"txt2img_seg": _txt2img_seg, "flow_seg": _flow_seg, "fin": _fin}
 for _name, _how in LLMS.items():
@@ -245,6 +246,11 @@ EXPECTED = {
                                "llm_head"},
     "llm_decode:jamba-tiny": {"llm_ssm", "llm_attn", "llm_shared_ffn",
                               "llm_head"},
+    # four norms a block: the two after the sublayers are llm_norm's too
+    "llm_prefill:trinity-tiny": {"llm_attn", "llm_router", "llm_experts",
+                                 "llm_shared_ffn", "llm_head"},
+    "llm_decode:trinity-tiny": {"llm_attn", "llm_router", "llm_experts",
+                                "llm_shared_ffn", "llm_head"},
 }
 
 

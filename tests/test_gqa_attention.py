@@ -1,0 +1,138 @@
+"""``ops/gqa_attention.py`` and the third kernel of ``ops/flash_latent.py``
+(groups of query heads over a key/value head each, whole or a band), in the
+Pallas interpreter and as the ``lax`` statement, against a naive float64
+masked softmax: over starts, band edges inside, at and across blocks,
+``window=None``, a ring that is still empty, 6 heads a group."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from comfyui_distributed_tpu.ops import flash_latent, gqa_attention
+
+
+def case(key, C, S, H, G, d):
+    kq, kk, kv = jax.random.split(key, 3)
+    return (jax.random.normal(kq, (C, H, d)),
+            jax.random.normal(kk, (G, S, d)),
+            jax.random.normal(kv, (G, S, d)))
+
+
+def naive(q, k, v, start, scale, window=None, lowest=0):
+    C, H, d = q.shape
+    G, S, _ = k.shape
+    heads = np.repeat(np.arange(G), H // G)       # head h reads h // (H/G)
+    s = np.einsum("chd,hsd->chs", np.asarray(q, np.float64),
+                  np.asarray(k, np.float64)[heads]) * scale
+    row = (start + np.arange(C))[:, None]
+    col = np.arange(S)[None, :]
+    seen = (col <= row) & (col >= lowest)
+    if window is not None:
+        seen &= row - col < window
+    s = np.where(seen[:, None, :], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum("chs,hsd->chd", p / p.sum(-1, keepdims=True),
+                     np.asarray(v, np.float64)[heads])
+
+
+def close(a, b, tol=1e-5):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.abs(a - b).max()) <= tol * max(1.0, float(np.abs(b).max()))
+
+
+@pytest.mark.parametrize("kernel", ["lax", "interpret"])
+@pytest.mark.parametrize("start", [0, 16, 40])
+def test_the_full_kernel_is_naive_attention_with_six_heads_a_group(kernel,
+                                                                   start):
+    """12 heads of 16 over 2 key/value heads, a chunk of 16 at three starts
+    over 56 rows (padded to the K block inside): the group's tile, the
+    moving diagonal, the clamped last block."""
+    q, k, v = case(jax.random.key(1), 16, 56, 12, 2, 16)
+    got = gqa_attention.causal_chunk(q, k, v, jnp.int32(start), 0.25,
+                                     jnp.float32, 8, 8, kernel=kernel)
+    assert got.shape == (16, 12, 16)
+    assert close(got, naive(q, k, v, start, 0.25))
+
+
+# (window, block_q, block_k): the band's lower edge inside a block, at a
+# block's first column, across several blocks, and a band of ONE key
+BANDS = [(5, 8, 8), (8, 8, 8), (16, 8, 8), (19, 4, 8), (12, 8, 4), (1, 8, 8)]
+
+
+@pytest.mark.parametrize("kernel", ["lax", "interpret"])
+@pytest.mark.parametrize("window,block_q,block_k", BANDS)
+@pytest.mark.parametrize("start", [0, 8, 24])
+def test_the_band_is_the_last_window_keys(kernel, window, block_q, block_k,
+                                          start):
+    q, k, v = case(jax.random.key(2), 16, 40, 6, 1, 8)
+    got = gqa_attention.causal_chunk(
+        q, k, v, jnp.int32(start), 1.0, jnp.float32, block_q, block_k,
+        window=window, kernel=kernel)
+    assert close(got, naive(q, k, v, start, 1.0, window))
+
+
+@pytest.mark.parametrize("kernel", ["lax", "interpret"])
+@pytest.mark.parametrize("lowest", [0, 3, 8, 16])
+def test_rows_below_the_lowest_valid_one_hold_nothing(kernel, lowest):
+    """``[ring ; chunk]`` with the ring still (partly) empty: queries at
+    rows 16.. of 32, a window of 16, the rows below ``lowest`` poisoned
+    (a masked logit is replaced, never multiplied)."""
+    q, k, v = case(jax.random.key(3), 16, 32, 4, 2, 8)
+    bad_k, bad_v = k.at[:, :lowest].set(jnp.nan), v.at[:, :lowest].set(1e30)
+    got = gqa_attention.causal_chunk(
+        q, bad_k, bad_v, jnp.int32(16), 1.0, jnp.float32, 8, 8, window=16,
+        lowest=jnp.int32(lowest), kernel=kernel)
+    assert np.isfinite(np.asarray(got)).all()
+    assert close(got, naive(q, k, v, 16, 1.0, 16, lowest))
+
+
+def test_blocks_outside_the_band_are_never_read():
+    """A band of 8 over 48 rows from row 32: blocks 0–2 lie wholly below
+    every query's band and blocks past the diagonal above it."""
+    q, k, v = case(jax.random.key(4), 8, 48, 4, 2, 8)
+    bad_k = k.at[:, :24].set(jnp.nan).at[:, 40:].set(jnp.nan)
+    bad_v = v.at[:, :24].set(jnp.nan).at[:, 40:].set(jnp.nan)
+    got = gqa_attention.causal_chunk(
+        q, bad_k, bad_v, jnp.int32(32), 1.0, jnp.float32, 8, 8, window=8,
+        kernel="interpret")
+    assert np.isfinite(np.asarray(got)).all()
+    assert close(got, naive(q, k, v, 32, 1.0, 8))
+
+
+def test_the_two_names_are_one_body():
+    q, k, v = case(jax.random.key(5), 8, 16, 4, 2, 8)
+    flat = q.reshape(8, 32)
+    whole = flash_latent.gqa_causal_mha(flat, k, v, jnp.int32(8),
+                                        num_heads=4, block_q=8, block_k=8,
+                                        interpret=True)
+    wide = flash_latent.gqa_window_mha(flat, k, v, jnp.int32(8), jnp.int32(0),
+                                       num_heads=4, window=64, block_q=8,
+                                       block_k=8, interpret=True)
+    assert np.array_equal(np.asarray(whole), np.asarray(wide))
+    assert flash_latent.gqa_causal_mha.__name__ == "gqa_causal_mha"
+    assert flash_latent.gqa_window_mha.__name__ == "gqa_window_mha"
+
+
+def test_the_decode_step_reads_the_rows_it_is_told_are_valid():
+    q, k, v = case(jax.random.key(6), 1, 24, 6, 2, 8)
+    valid = jnp.arange(24) <= 17
+    got = gqa_attention.step(q[0], k, v, valid, 0.3, jnp.float32)
+    assert close(got, naive(q, k, v, 17, 0.3)[0])
+    ring = (jnp.arange(24) <= 17) & (jnp.arange(24) > 9)
+    got = gqa_attention.step(q[0], k, v, ring, 0.3, jnp.float32)
+    assert close(got, naive(q, k, v, 17, 0.3, window=8)[0])
+
+
+def test_both_kernels_report_a_tier_of_their_own():
+    from comfyui_distributed_tpu.ops import attention, autotune
+
+    for tier in ("gqa_window", "gqa_causal"):
+        assert tier in autotune.REPORTED_TIERS and tier not in autotune.TIERS
+    attention.reset_selections()
+    attention.note_gqa(True, 48, 128, 4096, 8192, jnp.bfloat16, 1024, 1024)
+    attention.note_gqa(False, 48, 128, 4096, 132096, jnp.bfloat16, 1024, 1024)
+    summary = attention.selection_summary()
+    assert "gqa_window:1024/1024" in summary
+    assert "gqa_causal:1024/1024" in summary
+    attention.reset_selections()

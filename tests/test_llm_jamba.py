@@ -519,10 +519,11 @@ def test_the_cell_assembles_with_the_briefs_sizes_and_the_units_step():
     assert {m["name"] for m in ours} == JAMBA_METRICS
     assert all(m["workloads"] == [CELL] and m["moves"] == "request_p50_s"
                for m in ours)
-    # appended as one block (later PRs append after it), the cell last
+    # appended as one block and the cell after the six before it (later
+    # PRs append after both)
     first = bench["per_layer"].index(ours[0])
     assert bench["per_layer"][first:first + 9] == ours
-    assert bench["workloads"][-1]["name"] == CELL
+    assert bench["workloads"][6]["name"] == CELL
     for other in ("kimi-k2.6.brief32k-sdxl8", "sdxl-base.solo30"):
         import cdtbench.workload as workload
 
