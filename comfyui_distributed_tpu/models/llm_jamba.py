@@ -69,10 +69,11 @@ class JambaConfig:
     vocab_size: int = 65536
     dtype: str = "bfloat16"
     # the schedule of the chunked prefill, fixed here by measurement
-    # (PERF.md §6, PR 37); sizes of the program, not options of a request
+    # (PERF.md §6, PR 37; the tile the smallest sum over the 16 chunks of
+    # a 64k prefill, PR 40); sizes of the program, not options of a request
     prefill_chunk_tokens: int = 4096
-    attn_block_q: int = 1024
-    attn_block_k: int = 1024
+    attn_block_q: int = 2048
+    attn_block_k: int = 2048
 
     @classmethod
     def jamba2_3b(cls) -> "JambaConfig":

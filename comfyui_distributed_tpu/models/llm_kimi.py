@@ -82,9 +82,12 @@ class KimiConfig:
     vocab_size: int = 20480
     dtype: str = "bfloat16"
     # the schedule of the chunked prefill, fixed here by measurement
-    # (PERF.md §6, PR 32); sizes of the program, not options of a request
+    # (PERF.md §6, PR 32; the tile the smallest sum over the 8 chunks of a
+    # 32k prefill, PR 40: this kernel's two logit products want a taller q
+    # tile and NOT a longer K tile); sizes of the program, not options of
+    # a request
     prefill_chunk_tokens: int = 4096
-    attn_block_q: int = 1024
+    attn_block_q: int = 2048
     attn_block_k: int = 1024
     expert_tile: int = expert_share.GROUP_TILE
 

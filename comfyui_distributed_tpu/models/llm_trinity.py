@@ -87,11 +87,16 @@ class TrinityConfig:
     vocab_size: int = 25024
     dtype: str = "bfloat16"
     # the schedule of the chunked prefill: the chunk IS the window (the
-    # ring's contract, below); blocks as the neighbours' (PERF.md §6,
-    # PR 39); sizes of the program, not options of a request
+    # ring's contract, below); a tile a KERNEL, each the smallest sum over
+    # the 32 chunks of a 128k prefill (scripts/causal_tile_sweep.py;
+    # PERF.md §6, PR 40) — the full layer walks the whole buffer and wants
+    # large tiles, the band sees 8192 rows and computes more masked pairs
+    # under them; sizes of the program, not options of a request
     prefill_chunk_tokens: int = 4096
-    attn_block_q: int = 1024
-    attn_block_k: int = 1024
+    attn_full_block_q: int = 2048
+    attn_full_block_k: int = 2048
+    attn_window_block_q: int = 1024
+    attn_window_block_k: int = 1024
     expert_tile: int = expert_share.GROUP_TILE
 
     @classmethod
@@ -107,14 +112,18 @@ class TrinityConfig:
         """The CPU tests' size, float32: every mechanism, small widths, 3
         query heads a key/value head, a window (and so a chunk) the tests
         outrun many times, a router wider than the experts held, a chunk
-        that sits exactly at the grouped form's edge as the served one."""
+        that sits exactly at the grouped form's edge as the served one, a
+        full-layer tile (two q blocks under a K block LONGER than the
+        chunk) and a band tile (one q block over K blocks of half a chunk)
+        that differ as the served ones do."""
         base = dict(
             hidden_size=32, intermediate_size=48, num_attention_heads=6,
             num_key_value_heads=2, head_dim=8, sliding_window=8,
             max_position_embeddings=96, router_experts=16, num_experts=4,
             num_experts_per_tok=2, moe_intermediate_size=16, vocab_size=64,
-            dtype="float32", prefill_chunk_tokens=8, attn_block_q=4,
-            attn_block_k=4, expert_tile=2)
+            dtype="float32", prefill_chunk_tokens=8, attn_full_block_q=4,
+            attn_full_block_k=16, attn_window_block_q=8,
+            attn_window_block_k=4, expert_tile=2)
         return cls(**{**base, **kw})
 
     def __post_init__(self):
@@ -305,10 +314,10 @@ def _rows(a, dtype):
 
 def empty_cache(cfg: TrinityConfig, max_len: int) -> dict:
     """Per layer a K and a V: a ring of ``sliding_window`` rows for a window
-    layer, for a full layer ``max_len`` rows rounded up to the attention's
-    K block (the blocked kernel then reads the buffer as it is)."""
+    layer, for a full layer ``max_len`` rows rounded up to the FULL layer's
+    K block (its blocked kernel then reads the buffer as it is)."""
     dtype = jnp.dtype(cfg.dtype)
-    G, d, bk = cfg.num_key_value_heads, cfg.head_dim, cfg.attn_block_k
+    G, d, bk = cfg.num_key_value_heads, cfg.head_dim, cfg.attn_full_block_k
     rows = [-(-max_len // bk) * bk if cfg.is_full(i) else cfg.sliding_window
             for i in range(cfg.num_hidden_layers)]
     return {"k": [jnp.zeros((G, r, d), dtype) for r in rows],
@@ -388,14 +397,14 @@ def prefill_chunk(cfg: TrinityConfig, params, cache: dict, ids, start,
                         for n, a in (("k", k), ("v", v)))
                 cache["k"][i], cache["v"][i] = k, v
                 o = gqa_attention.causal_chunk(
-                    q, k, v, start, scale, dtype, cfg.attn_block_q,
-                    cfg.attn_block_k, kernel=kernel)
+                    q, k, v, start, scale, dtype, cfg.attn_full_block_q,
+                    cfg.attn_full_block_k, kernel=kernel)
             else:
                 o = gqa_attention.causal_chunk(
                     q, jnp.concatenate([cache["k"][i], k], axis=1),
                     jnp.concatenate([cache["v"][i], v], axis=1), W, scale,
-                    dtype, cfg.attn_block_q, cfg.attn_block_k, window=W,
-                    lowest=lowest, kernel=kernel)
+                    dtype, cfg.attn_window_block_q, cfg.attn_window_block_k,
+                    window=W, lowest=lowest, kernel=kernel)
                 # the chunk's rows ARE the new ring, where they are the
                 # prompt's: slot = position − start
                 for n, a in (("k", k), ("v", v)):

@@ -28,7 +28,13 @@ kernel's own. What the bidirectional kernels of ``flash_attention.py`` lack:
 
 grid = (heads, C/block_q, S/block_k), K innermost; running max, sum and the
 float32 accumulator live in VMEM scratch across K steps. Queries come
-pre-multiplied by the softmax scale.
+pre-multiplied by the softmax scale. ``S`` is the WHOLE buffer: a block a q
+block does not see is skipped but still a grid step (~0.3 µs), and every
+visible step rewrites the running tiles whole, so a kernel over a long
+prefix wants large tiles and a band of a few K blocks small ones — each
+caller hands the kernel IT runs a ``(block_q, block_k)`` of its own, a
+field of its model's config fixed by ``scripts/causal_tile_sweep.py``
+(docs/kernels.md).
 """
 
 from __future__ import annotations

@@ -287,8 +287,9 @@ def test_feasibility_never_approves_what_the_compiler_refuses(chip, row):
 def test_the_latent_causal_kernel_compiles_at_the_served_geometry(chip):
     """``ops/flash_latent.py`` as the long-brief rewriter's prefill calls
     it: 64 heads of 128 + the shared 64-wide rope key, 128-wide values, a
-    4096-token chunk over a 32 k workspace, 1024-row tiles, the chunk's
-    start a traced scalar (one compiled kernel serves every chunk)."""
+    4096-token chunk over a 32 k workspace, the tile the config ships, the
+    chunk's start a traced scalar (one compiled kernel serves every
+    chunk)."""
     from comfyui_distributed_tpu.models.llm_kimi import KimiConfig
     from comfyui_distributed_tpu.ops import flash_latent
 
@@ -328,12 +329,14 @@ def test_the_selective_scan_kernel_compiles_at_the_served_geometry(chip):
 def test_the_shared_kv_causal_kernel_compiles_at_the_served_geometry(chip):
     """``flash_latent.shared_kv_causal_mha`` as the same prefill calls it: 20
     query heads of 128 over one key/value head, a 4096-token chunk over the
-    64 k cache padded to the K block, 1024-row tiles, a traced start."""
+    64 k cache padded to the K block, the tile the config ships, a traced
+    start."""
     from comfyui_distributed_tpu.models.llm_jamba import JambaConfig
     from comfyui_distributed_tpu.ops import flash_latent
 
     cfg = JambaConfig.jamba2_3b()
-    H, d, C, S = cfg.num_attention_heads, cfg.head_dim, 4096, 66560
+    H, d, C = cfg.num_attention_heads, cfg.head_dim, 4096
+    S = -(-(65536 + 128) // cfg.attn_block_k) * cfg.attn_block_k
 
     def arg(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
@@ -393,20 +396,28 @@ def test_the_grouped_query_kernel_compiles_at_the_served_geometry(chip,
     window/full rewriter's prefill calls it: 48 query heads of 128 over 8
     key/value heads, a 4096-token chunk — over the 128 k buffer rounded to
     the K block (``gqa_causal_mha``) and over ``[ring ; chunk]`` under a
-    band of 4096 (``gqa_window_mha``) — 1024-row tiles, traced bounds."""
-    from comfyui_distributed_tpu.models.llm_trinity import TrinityConfig
+    band of 4096 (``gqa_window_mha``) — each at the tile IT ships with
+    (the full layer's is the larger: where a tile outgrows VMEM, this is
+    where it fails first), traced bounds."""
+    from comfyui_distributed_tpu.models import llm_trinity
     from comfyui_distributed_tpu.ops import flash_latent
 
-    cfg = TrinityConfig.trinity_share()
+    cfg = llm_trinity.TrinityConfig.trinity_share()
     H, G, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    C, S = 4096, 132096 if window is None else 8192
+    C = cfg.prefill_chunk_tokens
+    buffer = jax.eval_shape(lambda: llm_trinity.empty_cache(
+        cfg, 131072 + 128))["k"][cfg.layer_types.index(llm_trinity.FULL)]
+    S = buffer.shape[1] if window is None else 2 * window
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
 
     def arg(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
 
-    blocks = dict(num_heads=H, block_q=cfg.attn_block_q,
-                  block_k=cfg.attn_block_k, interpret=False)
+    bq, bk = (cfg.attn_full_block_q, cfg.attn_full_block_k) \
+        if window is None \
+        else (cfg.attn_window_block_q, cfg.attn_window_block_k)
+    assert S % bk == 0 and C % bq == 0
+    blocks = dict(num_heads=H, block_q=bq, block_k=bk, interpret=False)
     if window is None:
         lowered = flash_latent.gqa_causal_mha.lower(
             arg(C, H * d), arg(G, S, d), arg(G, S, d), scalar, **blocks)
@@ -448,9 +459,11 @@ def test_the_window_and_full_rewriters_programs_fit_beside_sdxl(chip,
         == cfg.num_hidden_layers == 5
     # the 0.25 GiB K and V buffers are written where they lie: no copy of
     # one inside the scan of chunks
+    full = cache["k"][cfg.layer_types.index("full_attention")]
+    buffer = "bf16[{},{},{}]".format(*full.shape)
+    assert full.shape[1] % cfg.attn_full_block_k == 0
     assert not [line for line in text.splitlines()
-                if "bf16[8,132096,128]" in line.split("=")[0]
-                and " copy(" in line]
+                if buffer in line.split("=")[0] and " copy(" in line]
     mem = compiled.memory_analysis()
     prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
     assert 4.9 < prefill_gib < 6.2 and prefill_gib + sdxl < 15.75 - 2.0

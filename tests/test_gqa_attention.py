@@ -2,7 +2,8 @@
 (groups of query heads over a key/value head each, whole or a band), in the
 Pallas interpreter and as the ``lax`` statement, against a naive float64
 masked softmax: over starts, band edges inside, at and across blocks,
-``window=None``, a ring that is still empty, 6 heads a group."""
+``window=None``, a ring that is still empty, 6 heads a group, and tiles
+whose sides differ (each kernel is served with a pair of its own)."""
 
 import jax
 import jax.numpy as jnp
@@ -41,23 +42,35 @@ def close(a, b, tol=1e-5):
     return float(np.abs(a - b).max()) <= tol * max(1.0, float(np.abs(b).max()))
 
 
-@pytest.mark.parametrize("kernel", ["lax", "interpret"])
+# (block_q, block_k) of the blocked kernel: square; a K block over two and
+# four q blocks (at start 16 the diagonal runs INSIDE a K block they share);
+# a q block over four K blocks; a K block LONGER than the chunk
+TILES = [(8, 8), (8, 16), (4, 16), (16, 4), (8, 32)]
+KERNEL_TILES = [("lax", 8, 8)] + [("interpret", bq, bk) for bq, bk in TILES]
+
+
+@pytest.mark.parametrize("kernel,block_q,block_k", KERNEL_TILES)
 @pytest.mark.parametrize("start", [0, 16, 40])
-def test_the_full_kernel_is_naive_attention_with_six_heads_a_group(kernel,
-                                                                   start):
+def test_the_full_kernel_is_naive_attention_with_six_heads_a_group(
+        kernel, block_q, block_k, start):
     """12 heads of 16 over 2 key/value heads, a chunk of 16 at three starts
     over 56 rows (padded to the K block inside): the group's tile, the
     moving diagonal, the clamped last block."""
     q, k, v = case(jax.random.key(1), 16, 56, 12, 2, 16)
     got = gqa_attention.causal_chunk(q, k, v, jnp.int32(start), 0.25,
-                                     jnp.float32, 8, 8, kernel=kernel)
+                                     jnp.float32, block_q, block_k,
+                                     kernel=kernel)
     assert got.shape == (16, 12, 16)
     assert close(got, naive(q, k, v, start, 0.25))
 
 
 # (window, block_q, block_k): the band's lower edge inside a block, at a
-# block's first column, across several blocks, and a band of ONE key
-BANDS = [(5, 8, 8), (8, 8, 8), (16, 8, 8), (19, 4, 8), (12, 8, 4), (1, 8, 8)]
+# block's first column, across several blocks, a band of ONE key, and
+# under tiles far from square: the band's edge and the diagonal in ONE K
+# block of several q blocks, a q block over many K blocks, a K block
+# longer than the chunk
+BANDS = [(5, 8, 8), (8, 8, 8), (16, 8, 8), (19, 4, 8), (12, 8, 4), (1, 8, 8),
+         (8, 4, 16), (16, 16, 4), (12, 8, 32), (5, 2, 16)]
 
 
 @pytest.mark.parametrize("kernel", ["lax", "interpret"])
@@ -72,17 +85,21 @@ def test_the_band_is_the_last_window_keys(kernel, window, block_q, block_k,
     assert close(got, naive(q, k, v, start, 1.0, window))
 
 
-@pytest.mark.parametrize("kernel", ["lax", "interpret"])
+@pytest.mark.parametrize("kernel,block_q,block_k",
+                         KERNEL_TILES[:2] + [("interpret", 4, 16),
+                                             ("interpret", 16, 32)])
 @pytest.mark.parametrize("lowest", [0, 3, 8, 16])
-def test_rows_below_the_lowest_valid_one_hold_nothing(kernel, lowest):
+def test_rows_below_the_lowest_valid_one_hold_nothing(kernel, block_q,
+                                                      block_k, lowest):
     """``[ring ; chunk]`` with the ring still (partly) empty: queries at
     rows 16.. of 32, a window of 16, the rows below ``lowest`` poisoned
-    (a masked logit is replaced, never multiplied)."""
+    (a masked logit is replaced, never multiplied) — under the larger
+    tiles ``lowest`` falls inside the one K block every q block reads."""
     q, k, v = case(jax.random.key(3), 16, 32, 4, 2, 8)
     bad_k, bad_v = k.at[:, :lowest].set(jnp.nan), v.at[:, :lowest].set(1e30)
     got = gqa_attention.causal_chunk(
-        q, bad_k, bad_v, jnp.int32(16), 1.0, jnp.float32, 8, 8, window=16,
-        lowest=jnp.int32(lowest), kernel=kernel)
+        q, bad_k, bad_v, jnp.int32(16), 1.0, jnp.float32, block_q, block_k,
+        window=16, lowest=jnp.int32(lowest), kernel=kernel)
     assert np.isfinite(np.asarray(got)).all()
     assert close(got, naive(q, k, v, 16, 1.0, 16, lowest))
 
@@ -98,6 +115,30 @@ def test_blocks_outside_the_band_are_never_read():
         kernel="interpret")
     assert np.isfinite(np.asarray(got)).all()
     assert close(got, naive(q, k, v, 32, 1.0, 8))
+
+
+def test_a_model_serves_each_kernel_with_a_pair_of_its_own():
+    """The full layer's pair and the band's differ in ONE model, at the
+    served size and at the tests'; ``empty_cache`` rounds the full buffer
+    to the FULL layer's K block and leaves the rings at the window."""
+    from comfyui_distributed_tpu.models import llm_trinity as M
+
+    for cfg, max_len in ((M.TrinityConfig.trinity_share(), 131072 + 128),
+                         (M.TrinityConfig.tiny(), 21 + 6)):
+        full = (cfg.attn_full_block_q, cfg.attn_full_block_k)
+        band = (cfg.attn_window_block_q, cfg.attn_window_block_k)
+        assert full != band
+        assert cfg.prefill_chunk_tokens % full[0] == 0
+        assert cfg.prefill_chunk_tokens % band[0] == 0
+        assert 2 * cfg.sliding_window % band[1] == 0  # [ring ; chunk] whole
+        cache = jax.eval_shape(lambda: M.empty_cache(cfg, max_len))
+        for i, (k, v) in enumerate(zip(cache["k"], cache["v"])):
+            rows = -(-max_len // full[1]) * full[1] if cfg.is_full(i) \
+                else cfg.sliding_window
+            assert k.shape == v.shape == (cfg.num_key_value_heads, rows,
+                                          cfg.head_dim)
+    tiny = M.TrinityConfig.tiny()
+    assert tiny.attn_full_block_k > tiny.prefill_chunk_tokens
 
 
 def test_the_two_names_are_one_body():
@@ -131,8 +172,8 @@ def test_both_kernels_report_a_tier_of_their_own():
         assert tier in autotune.REPORTED_TIERS and tier not in autotune.TIERS
     attention.reset_selections()
     attention.note_gqa(True, 48, 128, 4096, 8192, jnp.bfloat16, 1024, 1024)
-    attention.note_gqa(False, 48, 128, 4096, 132096, jnp.bfloat16, 1024, 1024)
+    attention.note_gqa(False, 48, 128, 4096, 133120, jnp.bfloat16, 2048, 2048)
     summary = attention.selection_summary()
     assert "gqa_window:1024/1024" in summary
-    assert "gqa_causal:1024/1024" in summary
+    assert "gqa_causal:2048/2048" in summary
     attention.reset_selections()
