@@ -40,7 +40,8 @@ import jax.numpy as jnp
 from jax.lax import axis_size as _axis_size
 from flax import linen as nn
 
-from ..ops.attention import full_attention, joint_ring_attention
+from ..ops.attention import (Columns, as_heads, full_attention,
+                             joint_attention, joint_ring_attention)
 from ..utils import constants
 from ..telemetry.device_scopes import device_scope
 from .layers import timestep_embedding
@@ -256,24 +257,29 @@ class _QKV(nn.Module):
     qk_norm: bool = True
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, cut: bool = True):
+        """q, k, v ``[B, N, heads, hd]``. ``cut=False`` (a joint block's
+        segment, for ``ops.attention.joint_attention``) leaves what nothing
+        below touches — v, and q and k without qk-norm — as ``Columns`` of
+        the product's own output: the kernel reads them there."""
         B, N, _ = x.shape
         with device_scope("attn_proj"):
             qkv = nn.Dense(self.hidden * 3, dtype=self.dtype, name="qkv")(x)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
             hd = self.hidden // self.heads
-            shape = (B, N, self.heads, hd)
+            q, k, v = (Columns(qkv, g, 3) for g in range(3))
+            if cut:
+                q, k, v = (as_heads(c, self.heads) for c in (q, k, v))
             if not self.qk_norm:
                 # SD3-medium: raw q/k (its checkpoints carry no norm scales)
-                return q.reshape(shape), k.reshape(shape), v.reshape(shape)
+                return q, k, v
             # qk-norm (learned-scale RMS over head_dim) as in FLUX's QKNorm /
             # SD3.5's ln_q/ln_k — the scales land from checkpoints'
             # {query,key}_norm.scale / ln_{q,k}.weight entries
             qs = self.param("q_scale", nn.initializers.ones, (hd,), jnp.float32)
             ks = self.param("k_scale", nn.initializers.ones, (hd,), jnp.float32)
-            q = _rms(q.reshape(shape)) * qs.astype(self.dtype)
-            k = _rms(k.reshape(shape)) * ks.astype(self.dtype)
-            return q, k, v.reshape(shape)
+            q = _rms(as_heads(q, self.heads)) * qs.astype(self.dtype)
+            k = _rms(as_heads(k, self.heads)) * ks.astype(self.dtype)
+            return q, k, v
 
 
 def _rms(x, eps=1e-6):
@@ -298,27 +304,34 @@ class DoubleBlock(nn.Module):
 
         img_n = _norm_modulate(img, i_sh1, i_sc1, dt)
         txt_n = _norm_modulate(txt, t_sh1, t_sc1, dt)
-        iq, ik, iv = _QKV(cfg.hidden, cfg.heads, dt, cfg.qk_norm, name="img_qkv")(img_n)
-        tq, tk, tv = _QKV(cfg.hidden, cfg.heads, dt, cfg.qk_norm, name="txt_qkv")(txt_n)
-        with device_scope("attn_proj"):
-            if pe_img is not None:
-                iq, ik = apply_rope(iq, pe_img), apply_rope(ik, pe_img)
-                tq, tk = apply_rope(tq, pe_txt), apply_rope(tk, pe_txt)
-            q = jnp.concatenate([tq, iq], axis=1)
-            if sp_axis is None:
-                k = jnp.concatenate([tk, ik], axis=1)
-                v = jnp.concatenate([tv, iv], axis=1)
+        # one chip: the segments go to the attention as they lie (q, k, v
+        # inside the products' outputs where nothing touches them); the ring
+        # takes arrays
+        cut = sp_axis is not None
+        iq, ik, iv = _QKV(cfg.hidden, cfg.heads, dt, cfg.qk_norm,
+                          name="img_qkv")(img_n, cut)
+        tq, tk, tv = _QKV(cfg.hidden, cfg.heads, dt, cfg.qk_norm,
+                          name="txt_qkv")(txt_n, cut)
+        if pe_img is not None:
+            with device_scope("attn_proj"):
+                iq, ik = (apply_rope(as_heads(x, cfg.heads), pe_img)
+                          for x in (iq, ik))
+                tq, tk = (apply_rope(as_heads(x, cfg.heads), pe_txt)
+                          for x in (tq, tk))
         if sp_axis is None:
-            out = full_attention(q, k, v,                  # cdt.attn_core
-                                 prefer_flash=cfg.attn_backend == "flash")
+            t_out, i_out = joint_attention(                # cdt.attn_core
+                (tq, tk, tv), (iq, ik, iv), cfg.heads,
+                prefer_flash=cfg.attn_backend == "flash")
         else:
+            with device_scope("attn_proj"):
+                q = jnp.concatenate([tq, iq], axis=1)
             out = joint_ring_attention(q, tk, tv, ik, iv, sp_axis)
-        T = txt.shape[1]
+            T = txt.shape[1]
+            with device_scope("attn_proj"):
+                B = img.shape[0]
+                i_out = out[:, T:].reshape(B, -1, cfg.hidden)
+                t_out = out[:, :T].reshape(B, T, cfg.hidden)
         with device_scope("attn_proj"):
-            t_out, i_out = out[:, :T], out[:, T:]
-            B = img.shape[0]
-            i_out = i_out.reshape(B, -1, cfg.hidden)
-            t_out = t_out.reshape(B, T, cfg.hidden)
             img = img + i_g1 * nn.Dense(cfg.hidden, dtype=dt, name="img_proj")(i_out)
             txt = txt + t_g1 * nn.Dense(cfg.hidden, dtype=dt, name="txt_proj")(t_out)
 

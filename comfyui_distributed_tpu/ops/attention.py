@@ -94,8 +94,8 @@ def _blocks_label(choice, kv_len: Optional[int] = None) -> str:
 
 
 def _note_selection(geometry: str, choice,
-                    kv_len: Optional[int] = None) -> None:
-    blocks = _blocks_label(choice, kv_len)
+                    kv_len: Optional[int] = None, blocks=None) -> None:
+    blocks = blocks or _blocks_label(choice, kv_len)
     desc = choice.tier + (f":{blocks}" if blocks else "")
     with _SELECTIONS_LOCK:
         if _SELECTIONS.get(geometry) == desc:
@@ -211,7 +211,7 @@ def policy_choice(q_len: int, kv_len: int, num_heads: int, head_dim: int,
 
 def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
                   dtype="bfloat16", fusable_width: Optional[int] = None,
-                  prefer_flash: bool = False):
+                  prefer_flash: bool = False, segments=None):
     """Resolve the kernel tier + block config for one attention site. The
     ONLY code that chooses; nothing downstream decides again. In order:
 
@@ -225,8 +225,8 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
        policy's flash answer.
 
     Packed blocks are resolved here, once, from the row and the shape, so
-    the log, the counter and the call agree. Deterministic: same site +
-    same table ⇒ same choice.
+    the log, the counter and the call agree (``segments``: a joint site's
+    text and image rows, for its label). Same site + table ⇒ same choice.
 
     ``fusable_width`` is the channel width C of a projection→attention
     site with nothing in between (SDXL UNet self-attention), where the
@@ -234,8 +234,7 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
     ``None`` where q/k/v arrive projected — same layout family as
     packed. ``prefer_flash`` (memory-constrained callers, see
     ``full_attention``) outranks a table ``xla`` row: the sweep optimized
-    for time while the caller needs the streamed softmax to fit HBM at
-    all.
+    for time while the caller needs the streamed softmax to fit HBM.
 
     Mesh-aware: inside a :func:`tp_shard_scope` the head count is
     divided by the tp degree BEFORE key derivation — the per-shard
@@ -288,7 +287,8 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
             reason="CDT_FLASH_ATTENTION=1" if forced
             else "prefer_flash (memory-constrained caller)")
     choice = _with_packed_blocks(choice, q_len, kv_len, head_dim, dtype)
-    _note_selection(geometry, choice, kv_len)
+    _note_selection(geometry, choice, kv_len,
+                    _joint_blocks_label(choice, segments, head_dim, dtype))
     return choice
 
 
@@ -588,3 +588,114 @@ def note_gqa(banded: bool, num_heads: int, head_dim: int, q_len: int,
         KernelChoice("gqa_window" if banded else "gqa_causal", block_q,
                      block_k, reason="chunked prefill over grouped K/V "
                      "heads" + (", banded" if banded else "")))
+
+
+# --- two row segments: MMDiT joint attention ---------------------------------
+# (At the end of the file, as the notes above: the image programs'
+# compile-cache keys hold the lines their attention sites are traced at.)
+
+from typing import NamedTuple as _NamedTuple
+
+
+class Columns(_NamedTuple):
+    """Columns ``[index·w, (index+1)·w)`` of ``array`` ``[B, N, count·w]``,
+    not cut out: q, k or v inside a ``qkv`` product's output, which the
+    two-segment kernel reads in place (``ops/flash_joint.py``)."""
+
+    array: jax.Array
+    index: int = 0
+    count: int = 1
+
+    @property
+    def width(self) -> int:
+        return self.array.shape[-1] // self.count
+
+    def cut(self) -> jax.Array:
+        """The columns as an array of their own ``[B, N, w]``."""
+        if self.count == 1:
+            return self.array
+        return jax.lax.slice_in_dim(self.array, self.index * self.width,
+                                    (self.index + 1) * self.width, axis=2)
+
+
+def as_heads(x, num_heads: int) -> jax.Array:
+    """``[B, N, H, D]`` of a :class:`Columns` (cut out) or of an array that
+    is in that layout already."""
+    if not isinstance(x, Columns):
+        return x
+    B, N, _ = x.array.shape
+    return x.cut().reshape(B, N, num_heads, -1)
+
+
+def _as_columns(x) -> Columns:
+    if isinstance(x, Columns):
+        return x
+    B, N, H, D = x.shape
+    return Columns(x.reshape(B, N, H * D))
+
+
+def _joint_plan(choice, segments, head_dim: int, dtype):
+    """Tiles of the two-segment packed call where a joint site takes it:
+    the choice is ``packed`` with K/V resident over the joint rows, and the
+    geometry is one ``flash_joint.joint_plan`` serves. Else None."""
+    if segments is None or choice.tier != "packed":
+        return None
+    from .autotune import itemsize_of
+    from .flash_joint import joint_plan
+
+    txt_len, img_len = segments
+    if choice.block_k < txt_len + img_len:
+        return None
+    return joint_plan(txt_len, img_len, head_dim, itemsize_of(dtype))
+
+
+def _joint_blocks_label(choice, segments, head_dim: int, dtype):
+    """'<image q rows>+<text q rows>/<image K rows>+<text K tile>:k-resident'
+    where a joint site's call has tiles a segment, None where it is the
+    one-segment call's label that holds."""
+    plan = _joint_plan(choice, segments, head_dim, dtype)
+    return plan and plan.label(segments[1])
+
+
+def joint_attention(txt, img, num_heads: int, prefer_flash: bool = False,
+                    choice=None) -> tuple[jax.Array, jax.Array]:
+    """Attention of an MMDiT joint block: text rows and image rows, each
+    query over every key of both. ``txt`` / ``img`` are the segment's
+    ``(q, k, v)``, each ``[B, N, H, D]`` or — what no norm or rope touched —
+    :class:`Columns` of the ``qkv`` product's output. Returns the text rows'
+    and the image rows' answers, ``[B, T, H·D]`` and ``[B, N, H·D]``: what
+    the two output projections read.
+
+    ``select_kernel`` is asked once, with the JOINT lengths (a caller that
+    has asked hands its ``choice`` in). On ``packed`` with K/V resident and
+    lane-aligned image rows the two-segment kernel reads every operand
+    where it lies (``ops/flash_joint.py``). Everywhere else — the ``xla``
+    tier, a streamed K, ragged image rows, the Pallas interpreter inside
+    ``shard_map`` — the segments are concatenated for
+    :func:`full_attention` and its answer is split."""
+    txt, img = ([_as_columns(x) for x in seg] for seg in (txt, img))
+    T, N = txt[0].array.shape[1], img[0].array.shape[1]
+    D = img[0].width // num_heads
+    dtype = img[0].array.dtype
+    if choice is None:
+        choice = select_kernel(T + N, T + N, num_heads, D, dtype=dtype,
+                               prefer_flash=prefer_flash, segments=(T, N))
+    plan = _joint_plan(choice, (T, N), D, dtype)
+    if plan is not None:
+        from .flash_attention import _in_manual_trace, _platform
+        from .flash_joint import flash_joint_attention
+
+        interpret = _platform() == "cpu"
+        if not (interpret and _in_manual_trace(img[0].array)):
+            with device_scope("attn_core"):
+                return flash_joint_attention(txt, img, num_heads, plan,
+                                             interpret)
+    with device_scope("attn_proj"):
+        q, k, v = (jnp.concatenate([as_heads(t, num_heads),
+                                    as_heads(i, num_heads)], axis=1)
+                   for t, i in zip(txt, img))
+    out = full_attention(q, k, v, choice=choice)
+    with device_scope("attn_proj"):
+        B = out.shape[0]
+        return (out[:, :T].reshape(B, T, num_heads * D),
+                out[:, T:].reshape(B, N, num_heads * D))

@@ -474,3 +474,73 @@ def test_the_window_and_full_rewriters_programs_fit_beside_sdxl(chip,
     mem = compiled.memory_analysis()
     decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
     assert 5.2 < decode_gib < 6.8 and decode_gib + sdxl < 15.75 - 2.0
+
+
+# (id, config, text rows, batch, label): a joint block as its model's cell
+# or card runs it, 1024² (4096 image rows)
+JOINT_BLOCKS = [
+    ("sd3_medium", "sd3_medium", 77, 2, "packed:512+80/4096+128:k-resident"),
+    ("flux", "flux", 512, 1, "packed:512+512/4096+512:k-resident"),
+]
+
+
+@pytest.mark.parametrize("case", JOINT_BLOCKS, ids=lambda c: c[0])
+def test_a_joint_block_hands_the_kernel_its_products_outputs(chip, case,
+                                                             monkeypatch):
+    """One MMDiT joint block at published widths with the dispatcher
+    choosing as it does on the chip (PR 41): ONE Pallas call site, reported
+    with tiles a segment, and no operation anywhere in the compiled block
+    that holds the joint rows — no concatenated q, k or v (4173 rows), no
+    padded copy (4176, 4224), no joint answer to cut. SD3's kernel reads
+    the image ``qkv`` product's own output three times (and the text
+    product's zero-padded tile three times); where a model ropes and norms
+    q and k (FLUX) it still reads v there."""
+    from comfyui_distributed_tpu.models import dit
+
+    _, preset, T, B, label = case
+    for var in ("CDT_FLASH_ATTENTION", "CDT_ATTN_TUNE"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    attn.reset_selections()
+    cfg = getattr(dit.DiTConfig, preset)()
+    N, hd = 4096, cfg.head_dim
+    block = dit.DoubleBlock(cfg)
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    args = [arg(B, N, cfg.hidden), arg(B, T, cfg.hidden), arg(B, cfg.hidden)]
+    if cfg.pos_embed == "rope":
+        args += [(arg(n, hd // 2, dtype=jnp.float32),) * 2 for n in (N, T)]
+
+    def forward(params, img, txt, vec, *pe):
+        return block.apply(params, img, txt, vec, None, *pe)
+
+    zeros = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                   args)
+    params = jax.eval_shape(
+        lambda: block.init(jax.random.key(0), *zeros[:3], None, *zeros[3:]))
+    params = jax.tree_util.tree_map(
+        lambda p: arg(*p.shape), params)
+    text = jax.jit(forward).lower(params, *args).compile().as_text()
+
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, len(calls)
+    assert "_flash_mha_packed_joint" in calls[0]
+    joint_rows = re.findall(
+        rf"\[{B},(?:{T + N}|{-(-(T + N) // 16) * 16}|"
+        rf"{-(-(T + N) // 128) * 128}),\d+", text)
+    assert not joint_rows, joint_rows[:3]
+    # text q, image q, text k, image k, text v, image v
+    operands = re.sub(r"/\*[^*]*\*/", "", re.search(
+        r"custom-call\(([^)]*)\)", calls[0]).group(1)).split(", ")
+    image_v = next(line for line in text.splitlines()
+                   if line.strip().startswith(operands[5] + " = "))
+    assert re.search(r'op_name="[^"]*img_qkv/[^"]*dot_general', image_v), \
+        image_v[:300]
+    if not (cfg.qk_norm or cfg.pos_embed == "rope"):
+        assert operands[1] == operands[3] == operands[5], operands
+        assert operands[0] == operands[2] == operands[4], operands
+    key = autotune.GeometryKey.from_shape(cfg.heads, hd, T + N, T + N)
+    assert attn.selection_summary() == f"{key.key_str()}={label}"

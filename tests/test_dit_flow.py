@@ -317,3 +317,65 @@ class TestRope:
         single = np.asarray(pipe.generate_sp(build_mesh({"sp": 1}), spec,
                                              seed=7, context=ctx, pooled=pooled))
         np.testing.assert_allclose(sp_out, single, rtol=2e-4, atol=2e-4)
+
+
+# --- joint blocks through the two-segment attention entry (PR 41) -----------
+
+
+def _awake(params, seed=7):
+    """Every zero-initialised leaf (``img_out``, the adaLN modulations)
+    made non-zero: a DiT whose output SEES its blocks."""
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.key(seed), len(leaves))
+    return jax.tree_util.tree_unflatten(tree, [
+        0.05 * jax.random.normal(k, l.shape, l.dtype)
+        if not np.asarray(l).any() else l for k, l in zip(keys, leaves)])
+
+
+@pytest.mark.parametrize("name", ["sd3_tiny", "tiny_rope_qknorm"])
+def test_joint_blocks_on_the_packed_tier_match_the_xla_arm(name, monkeypatch):
+    """The forward through ``ops.attention.joint_attention`` with the
+    dispatcher on the ``packed`` tier (forced, as on a TPU; the Pallas
+    interpreter here) against the ``xla`` arm, with weights that let the
+    output see the blocks: SD3's class hands the kernel bare ``qkv``
+    products, the rope + qk-norm class q and k as buffers of their own."""
+    import dataclasses
+
+    from comfyui_distributed_tpu.ops import attention as attn
+    from comfyui_distributed_tpu.utils.flops import estimate_flops
+
+    if name == "sd3_tiny":
+        cfg = dataclasses.replace(DiTConfig.sd3_tiny(), hidden=128, heads=2,
+                                  pos_embed_max_size=32)
+    else:
+        cfg = DiTConfig.tiny(pos_embed="rope", hidden=128, heads=2,
+                             depth_single=0)
+    assert cfg.head_dim == 64
+    monkeypatch.delenv("CDT_FLASH_ATTENTION", raising=False)
+    model, params = init_dit(cfg, jax.random.key(0), sample_hw=(64, 64),
+                             context_len=13)
+    params = _awake(params)
+    keys = jax.random.split(jax.random.key(1), 3)
+    args = (jax.random.normal(keys[0], (2, 64, 64, cfg.in_channels)),
+            jnp.array([0.3, 0.8]),
+            jax.random.normal(keys[1], (2, 13, cfg.context_dim)),
+            jax.random.normal(keys[2], (2, cfg.pooled_dim)))
+
+    attn.reset_selections()
+    xla_out = model.apply(params, *args)
+    xla_flops = estimate_flops(model.apply, params, *args)
+    assert attn.selection_summary() == ""
+    assert float(jnp.std(xla_out)) > 1e-3      # the blocks are seen
+
+    monkeypatch.setenv("CDT_FLASH_ATTENTION", "1")
+    packed_out = model.apply(params, *args)
+    # 13 text rows + 32 × 32 image rows, bf16: one 16-row text q tile
+    assert attn.selection_summary() == (
+        "h2.d64.q2048.kv2048.bf16=packed:512+16/1024+128:k-resident")
+    scale = float(jnp.abs(xla_out).max())
+    np.testing.assert_allclose(np.asarray(packed_out), np.asarray(xla_out),
+                               atol=2e-2 * scale, rtol=2e-2)
+    assert estimate_flops(model.apply, params, *args) == xla_flops
+    # one step's operations as PR 40's tree counted them
+    assert xla_flops == {"sd3_tiny": 3852642304.0,
+                         "tiny_rope_qknorm": 3852642304.0}[name]

@@ -702,3 +702,204 @@ class TestFusedModelSite:
         params = module.init(jax.random.key(20), x, ctx)
         out = module.apply(params, x, ctx)   # downgrades, must not crash
         assert out.shape == (1, N, C)
+
+
+# --- the two-segment packed call (MMDiT joint attention, PR 41) -------------
+
+
+def _jaxpr_equations(jaxpr) -> int:
+    """Equations of a jaxpr and of every jaxpr its equations hold (a jit's,
+    the Pallas kernel body's, a loop's)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _jaxpr_equations(sub)
+    return n
+
+
+# (id, B, text rows, image rows, H, D, q and k as buffers of their own)
+JOINT_CASES = [
+    ("sd3_text77_d64", 2, 77, 1024, 4, 64, False),
+    ("flux_text512_d128", 1, 512, 1024, 2, 128, True),
+    ("text_shorter_than_a_sublane_tile", 1, 5, 512, 2, 64, False),
+    ("two_q_blocks_of_384", 1, 77, 768, 2, 64, False),
+]
+
+
+class TestJointSegments:
+    """``ops.attention.joint_attention`` on the ``packed`` tier (a choice
+    handed in; the Pallas interpreter) against float32 softmax attention
+    over the concatenated rows: both outputs, operands as column groups of
+    the ``qkv`` products or as buffers of their own."""
+
+    @staticmethod
+    def _segments(case, dtype, seed=0, scale_txt_v=1.0):
+        from comfyui_distributed_tpu.ops.attention import Columns
+
+        _, B, T, N, H, D, own_qk = case
+        HD = H * D
+        keys = jax.random.split(jax.random.key(seed), 6)
+        txt_qkv = jax.random.normal(keys[0], (B, T, 3 * HD), dtype)
+        img_qkv = jax.random.normal(keys[1], (B, N, 3 * HD), dtype)
+        if scale_txt_v != 1.0:
+            txt_qkv = txt_qkv.at[..., 2 * HD:].multiply(scale_txt_v)
+        txt = [Columns(txt_qkv, g, 3) for g in range(3)]
+        img = [Columns(img_qkv, g, 3) for g in range(3)]
+        if own_qk:        # qk-norm / rope models: [B, N, H, D] buffers
+            own = [jax.random.normal(k, (B, n, H, D), dtype)
+                   for k, n in zip(keys[2:], (T, T, N, N))]
+            txt[:2], img[:2] = own[:2], own[2:]
+        return txt, img
+
+    @staticmethod
+    def _reference(txt, img, H):
+        from comfyui_distributed_tpu.ops.attention import as_heads
+
+        q, k, v = (jnp.concatenate([as_heads(t, H), as_heads(i, H)], axis=1)
+                   for t, i in zip(txt, img))
+        out = dense_reference(q, k, v)
+        return out.reshape(*out.shape[:2], -1)
+
+    @staticmethod
+    def _packed_choice(case):
+        from comfyui_distributed_tpu.ops.autotune import KernelChoice
+
+        _, _, T, N, _, _, _ = case
+        return KernelChoice("packed", 512, -(-(T + N) // 128) * 128)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", JOINT_CASES, ids=lambda c: c[0])
+    def test_two_segment_call_matches_softmax_over_the_joint_rows(
+            self, case, dtype, monkeypatch):
+        from comfyui_distributed_tpu.ops import attention as attn
+        from comfyui_distributed_tpu.ops import flash_joint as fj
+
+        _, B, T, N, H, D, _ = case
+        calls = []
+        real = fj.flash_joint_attention
+        monkeypatch.setattr(
+            fj, "flash_joint_attention",
+            lambda *a, **kw: calls.append(a[3]) or real(*a, **kw))
+        txt, img = self._segments(case, jnp.dtype(dtype))
+        t_out, i_out = attn.joint_attention(
+            txt, img, H, choice=self._packed_choice(case))
+        assert len(calls) == 1, "the two-segment kernel did not run"
+        assert calls[0].txt_rows % 128 == 0 and N % calls[0].img_block_q == 0
+        assert t_out.shape == (B, T, H * D) and i_out.shape == (B, N, H * D)
+        assert t_out.dtype == i_out.dtype == jnp.dtype(dtype)
+        ref = self._reference(txt, img, H)
+        tol = 2e-5 if dtype == "float32" else 2e-2
+        np.testing.assert_allclose(t_out.astype(np.float32), ref[:, :T],
+                                   atol=tol, rtol=tol)
+        np.testing.assert_allclose(i_out.astype(np.float32), ref[:, T:],
+                                   atol=tol, rtol=tol)
+
+    def test_the_text_tile_is_padded_with_zeros(self, monkeypatch):
+        """The mask sets a padding column's probability to exactly 0, and
+        0 × NaN is NaN in the value product: the text tile's padding rows
+        must be zeros, not whatever lies past the text. With large text
+        values the answer is right; with the padding poisoned every row
+        of both outputs is lost."""
+        from comfyui_distributed_tpu.ops import attention as attn
+        from comfyui_distributed_tpu.ops import flash_joint as fj
+
+        case = ("poison", 1, 77, 256, 2, 64, False)
+        txt, img = self._segments(case, jnp.float32, seed=3,
+                                  scale_txt_v=1e4)
+        choice = self._packed_choice(case)
+        t_out, i_out = attn.joint_attention(txt, img, 2, choice=choice)
+        ref = self._reference(txt, img, 2)
+        np.testing.assert_allclose(t_out, ref[:, :77], atol=5e-2, rtol=2e-5)
+        np.testing.assert_allclose(i_out, ref[:, 77:], atol=5e-2, rtol=2e-5)
+
+        def poisoned(x, rows):
+            return jnp.pad(x, ((0, 0), (0, rows - x.shape[1]), (0, 0)),
+                           constant_values=jnp.nan)
+
+        monkeypatch.setattr(fj, "_pad_rows", poisoned)
+        fj._flash_mha_packed_joint.clear_cache()
+        try:
+            t_bad, i_bad = attn.joint_attention(txt, img, 2, choice=choice)
+        finally:
+            fj._flash_mha_packed_joint.clear_cache()
+        assert np.isnan(np.asarray(t_bad)).all()
+        assert np.isnan(np.asarray(i_bad)).all()
+
+    @pytest.mark.parametrize("why,case,block_k", [
+        ("ragged_image_rows", ("r", 1, 77, 200, 2, 64, False), None),
+        ("k_streamed", ("s", 1, 77, 512, 2, 64, False), 256),
+        ("xla_tier", ("x", 1, 77, 512, 2, 64, False), None),
+    ])
+    def test_other_geometries_concatenate_as_before(self, why, case,
+                                                    block_k, monkeypatch):
+        """Where the two-segment call cannot be taken the segments are
+        concatenated for the one-segment dispatcher, and the answer is the
+        same attention."""
+        from comfyui_distributed_tpu.ops import attention as attn
+        from comfyui_distributed_tpu.ops import flash_joint as fj
+        from comfyui_distributed_tpu.ops.autotune import KernelChoice
+
+        monkeypatch.setattr(
+            fj, "flash_joint_attention",
+            lambda *a, **kw: pytest.fail("two-segment call taken"))
+        _, B, T, N, H, D, _ = case
+        txt, img = self._segments(case, jnp.float32, seed=5)
+        choice = (KernelChoice("xla") if why == "xla_tier"
+                  else KernelChoice("packed", 128, block_k or 640))
+        t_out, i_out = attn.joint_attention(txt, img, H, choice=choice)
+        ref = self._reference(txt, img, H)
+        np.testing.assert_allclose(t_out, ref[:, :T], atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(i_out, ref[:, T:], atol=2e-5, rtol=2e-5)
+
+    def test_label_and_stated_cost(self, monkeypatch):
+        """``select_kernel`` told the segments reports the two-segment
+        tiles under the JOINT geometry's key, and the call states the
+        one-segment call's algorithmic cost over the joint rows."""
+        from comfyui_distributed_tpu.ops import attention as attn
+        from comfyui_distributed_tpu.utils.flops import estimate_flops
+
+        monkeypatch.setenv("CDT_FLASH_ATTENTION", "1")
+        attn.reset_selections()
+        choice = attn.select_kernel(4173, 4173, 24, 64, segments=(77, 4096))
+        assert choice.tier == "packed" and choice.block_k == 4224
+        assert attn.selection_summary() == (
+            "h24.d64.q8192.kv8192.bf16="
+            "packed:512+80/4096+128:k-resident")
+        # a one-segment site of the same geometry keeps its label
+        attn.reset_selections()
+        attn.select_kernel(4173, 4173, 24, 64)
+        assert attn.selection_summary().endswith(
+            "=packed:464/4224:k-resident")
+
+        case = ("cost", 2, 77, 1024, 4, 64, False)
+        txt, img = self._segments(case, jnp.bfloat16)
+        flops = estimate_flops(
+            lambda: attn.joint_attention(
+                txt, img, 4, choice=self._packed_choice(case)))
+        assert flops == 4 * 2 * 4 * (77 + 1024) ** 2 * 64
+
+    @pytest.mark.parametrize("shape,equations", [
+        ((2, 4173, 24, 64, "bfloat16"), 95),     # SD3's joint rows, merged
+        ((2, 4096, 10, 64, "bfloat16"), 76),     # SDXL's 64² self-attention
+        ((1, 1024, 4, 128, "float32"), 16),
+    ], ids=lambda x: str(x))
+    def test_one_segment_call_traces_as_before(self, shape, equations):
+        """PR 41 added a kernel beside ``_flash_mha_packed``; the
+        one-segment call itself — SDXL's, WAN's, a single block's — must
+        trace to the program it traced to (counts recorded at PR 40's
+        tree)."""
+        from comfyui_distributed_tpu.ops import flash_attention as fa
+
+        B, N, H, D, dtype = shape
+        q = jax.ShapeDtypeStruct((B, N, H * D), jnp.dtype(dtype))
+        bq, bk = fa._packed_blocks(N, N, D, jnp.dtype(dtype).itemsize)
+        jaxpr = jax.make_jaxpr(
+            lambda q, k, v: fa._flash_mha_packed(
+                q, k, v, num_heads=H, block_q=bq, block_k=bk,
+                interpret=False))(q, q, q)
+        assert _jaxpr_equations(jaxpr.jaxpr) == equations
