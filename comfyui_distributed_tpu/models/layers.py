@@ -102,37 +102,14 @@ class ResBlock(nn.Module):
             return x + h
 
 
-class _ProjKernel(nn.Module):
-    """Bare [in, out] projection weight under a Dense-compatible param
-    path (``<name>/kernel``, lecun-normal init) — the fused attention
-    tier consumes the raw matrix instead of applying the layer, so the
-    activations never round-trip HBM, while checkpoints keep loading
-    into the exact tree ``nn.Dense(use_bias=False)`` would own."""
-
-    features: int
-
-    @nn.compact
-    def __call__(self, in_features: int) -> jax.Array:
-        return self.param("kernel", nn.initializers.lecun_normal(),
-                          (in_features, self.features))
-
-
 class Attention(nn.Module):
     """Multi-head attention over [B, N, C] with optional cross context.
 
     The kernel dispatcher (``ops/attention.select_kernel`` — a
-    tuning-table row, else the one policy) is asked once a site. Every
-    shipped row and the policy lead to the dense arm: plain ``nn.Dense``
-    projections, then ``full_attention`` with the choice (the packed
-    Pallas kernel at SDXL's 64² and 32² self-attention, XLA at its
-    cross-attention). Self-attention sites (no context) are also
-    fusable — projection feeds attention with nothing in between — and
-    a local table row can give them the fused tier, which folds the QKV
-    matmuls into the flash grid
-    (``ops/flash_attention.fused_qkv_attention``); no shipped row does:
-    that kernel re-projects K and V for every q block and measured 3.1×
-    the dense arm's time at SDXL's 64² site (PERF.md §6, PR 29). Either
-    arm owns the identical param tree."""
+    tuning-table row, else the one policy) is asked once a site; then
+    plain ``nn.Dense`` projections and ``full_attention`` with the choice
+    (the packed Pallas kernel at SDXL's 64² and 32² self-attention, XLA
+    at its cross-attention)."""
 
     num_heads: int
     head_dim: int
@@ -146,44 +123,26 @@ class Attention(nn.Module):
         M = ctx.shape[1]
         from ..ops.attention import full_attention, select_kernel
 
-        # the site's REAL channel width goes in: a table row validated
-        # fused feasibility assuming C == H·D (true for every zoo
-        # config), and a width that is not feasible gets the dense
-        # branch's kernel instead of raising mid-forward
-        choice = select_kernel(
-            int(N), int(M), self.num_heads, self.head_dim, dtype=self.dtype,
-            fusable_width=int(C) if context is None else None)
-        if choice.tier == "fused":
-            from ..ops.flash_attention import fused_qkv_attention
-
-            # the projections run inside the kernel: all of it is the core
-            with device_scope("attn_core"):
-                wq = _ProjKernel(inner, name="to_q")(C)
-                wk = _ProjKernel(inner, name="to_k")(C)
-                wv = _ProjKernel(inner, name="to_v")(C)
-                out = fused_qkv_attention(
-                    x.astype(self.dtype), wq.astype(self.dtype),
-                    wk.astype(self.dtype), wv.astype(self.dtype),
-                    self.num_heads, block_q=choice.block_q,
-                    block_k=choice.block_k)
-        else:
-            with device_scope("attn_proj"):
-                q = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="to_q")(x)
-                k = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="to_k")(ctx)
-                v = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="to_v")(ctx)
-                q = q.reshape(B, N, self.num_heads, self.head_dim)
-                k = k.reshape(B, M, self.num_heads, self.head_dim)
-                v = v.reshape(B, M, self.num_heads, self.head_dim)
-            out = full_attention(q, k, v, choice=choice)    # cdt.attn_core
+        choice = select_kernel(int(N), int(M), self.num_heads,
+                               self.head_dim, dtype=self.dtype)
+        with device_scope("attn_proj"):
+            q = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="to_q")(x)
+            k = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="to_k")(ctx)
+            v = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="to_v")(ctx)
+            q = q.reshape(B, N, self.num_heads, self.head_dim)
+            k = k.reshape(B, M, self.num_heads, self.head_dim)
+            v = v.reshape(B, M, self.num_heads, self.head_dim)
+        out = full_attention(q, k, v, choice=choice)    # cdt.attn_core
         with device_scope("attn_proj"):
             out = out.reshape(B, N, inner)
-            return nn.Dense(x.shape[-1], dtype=self.dtype, name="to_out")(out)
+            return nn.Dense(C, dtype=self.dtype, name="to_out")(out)
 
 
 class _ProjParams(nn.Module):
     """``kernel`` [in, out] and ``bias`` [out] under the param paths, and
-    from the initialisers, of ``nn.Dense(features)`` — as ``_ProjKernel``
-    for a layer whose caller applies the weight in parts."""
+    from the initialisers, of ``nn.Dense(features)``, for a layer whose
+    caller applies the weight in parts: checkpoints keep loading into the
+    exact tree ``nn.Dense`` would own."""
 
     features: int
 

@@ -14,7 +14,8 @@ W_x``; ``δ``, ``B``, ``C`` each RMS-normed with a weight of its own; ``Δ =
 softplus(δ W_dt + b_dt)``; ``A = −exp(A_log)``; the recurrence and the gate
 are ``ops/selective_scan.py``'s; out ``= y W_out``. **Attention**:
 ``num_attention_heads`` query heads over ``num_key_value_heads`` = 1 key
-and value head (``ops/shared_kv_attention.py``), scale ``d^−½``, no bias.
+and value head (``ops/gqa_attention.py``: one group), scale ``d^−½``, no
+bias.
 
 The cache is what a token leaves behind: a state ``[d_inner, d_state]``
 float32 and the convolution's last ``d_conv − 1`` inputs a Mamba layer
@@ -44,7 +45,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..ops import selective_scan, shared_kv_attention
+from ..ops import gqa_attention, selective_scan
 from ..telemetry.device_scopes import device_scope
 from .llm_hybrid import (_const, _dot, _embed, _normal, _pre_norm, _swiglu,
                          count_params, init_tree, rms_norm)
@@ -99,8 +100,8 @@ class JambaConfig:
 
     def __post_init__(self):
         if self.num_key_value_heads != 1:
-            raise ValueError("one shared key/value head is what "
-                             "ops/shared_kv_attention.py computes")
+            raise ValueError("one shared key/value head is what the "
+                             "cache holds a row of")
 
     @property
     def d_inner(self) -> int:
@@ -313,9 +314,9 @@ def _attention_chunk(cfg: JambaConfig, layer, h, k_cache, v_cache, start,
                                                (start, 0))
         v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(dtype),
                                                (start, 0))
-        o = shared_kv_attention.causal_chunk(
-            q, k_cache, v_cache, start, cfg.head_dim ** -0.5, dtype,
-            cfg.attn_block_q, cfg.attn_block_k, kernel)
+        o = gqa_attention.causal_chunk(
+            q, k_cache[None], v_cache[None], start, cfg.head_dim ** -0.5,
+            dtype, cfg.attn_block_q, cfg.attn_block_k, kernel=kernel)
         h = h + _dot(o.reshape(o.shape[0], -1), layer["attn"]["w_o"], dtype)
     return _ffn(cfg, layer, h), k_cache, v_cache
 
@@ -376,8 +377,9 @@ def _attention_token(cfg: JambaConfig, layer, h, k_cache, v_cache, pos):
             k_cache, k[None].astype(dtype), (pos, 0))
         v_cache = jax.lax.dynamic_update_slice(
             v_cache, v[None].astype(dtype), (pos, 0))
-        o = shared_kv_attention.step(q, k_cache, v_cache, pos,
-                                     cfg.head_dim ** -0.5, dtype)
+        o = gqa_attention.step(q, k_cache[None], v_cache[None],
+                               jnp.arange(k_cache.shape[0]) <= pos,
+                               cfg.head_dim ** -0.5, dtype)
         h = h + _dot(o.reshape(-1), layer["attn"]["w_o"], dtype)
     return _ffn(cfg, layer, h), k_cache, v_cache
 

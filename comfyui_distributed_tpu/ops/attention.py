@@ -116,20 +116,32 @@ def _note_selection(geometry: str, choice,
         pass
 
 
-def note_latent_causal(num_heads: int, qk_dim: int, q_len: int, kv_len: int,
-                       dtype, block_q: int, block_k: int) -> None:
-    """The blocked causal latent-attention kernel reports itself as the
-    tiers do — the server log's ``attention:`` line and
-    ``cdt_attn_kernel_selected`` — though nothing here chooses it: a
-    chunked prefill over a latent cache has one kernel on a TPU
-    (``latent_attention.mla_chunk_attention``)."""
+# what each blocked causal prefill kernel of ops/flash_latent.py says of
+# itself in the ``attention:`` line: tier → reason
+CAUSAL_TIER_REASONS = {
+    "latent_causal": "chunked prefill over a latent cache",
+    "shared_kv_causal": "chunked prefill over one shared K/V head",
+    "gqa_causal": "chunked prefill over grouped K/V heads",
+    "gqa_window": "chunked prefill over grouped K/V heads, banded",
+}
+
+
+def note_causal(tier: str, num_heads: int, head_dim: int, q_len: int,
+                kv_len: int, dtype, block_q: int, block_k: int) -> None:
+    """A blocked causal prefill kernel reports itself as the tiers do —
+    the server log's ``attention:`` line and ``cdt_attn_kernel_selected``
+    — though nothing here chooses it: a chunked prefill through a cache
+    has one kernel on a TPU, and its caller
+    (``latent_attention.mla_chunk_attention``,
+    ``gqa_attention.causal_chunk``) names the tier it ran
+    (:data:`CAUSAL_TIER_REASONS`) with the blocks it was served."""
     from .autotune import GeometryKey, KernelChoice
 
     _note_selection(
-        GeometryKey.from_shape(num_heads, qk_dim, q_len, kv_len,
+        GeometryKey.from_shape(num_heads, head_dim, q_len, kv_len,
                                dtype).key_str(),
-        KernelChoice("latent_causal", block_q, block_k,
-                     reason="chunked prefill over a latent cache"))
+        KernelChoice(tier, block_q, block_k,
+                     reason=CAUSAL_TIER_REASONS[tier]))
 
 
 def _with_packed_blocks(choice, q_len: int, kv_len: int, head_dim: int,
@@ -187,8 +199,7 @@ def policy_choice(q_len: int, kv_len: int, num_heads: int, head_dim: int,
     from ``BH_MIN_Q`` up, where the streamed softmax's memory win still
     applies (packed-illegal widths, or a long q over a tiny K); else XLA.
     ``flash_only`` takes the XLA outcome away (a caller that was
-    promised flash): ``bh`` at any length. Never ``fused``: that tier is
-    a table row's to give. Blocks are left to the shape
+    promised flash): ``bh`` at any length. Blocks are left to the shape
     (``_with_packed_blocks``) or the classic 256/512."""
     from .autotune import KernelChoice
     from .flash_attention import _packed_legal
@@ -210,8 +221,8 @@ def policy_choice(q_len: int, kv_len: int, num_heads: int, head_dim: int,
 
 
 def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
-                  dtype="bfloat16", fusable_width: Optional[int] = None,
-                  prefer_flash: bool = False, segments=None):
+                  dtype="bfloat16", prefer_flash: bool = False,
+                  segments=None):
     """Resolve the kernel tier + block config for one attention site. The
     ONLY code that chooses; nothing downstream decides again. In order:
 
@@ -219,30 +230,23 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
     2. not on a TPU and not forced (``=1``) → ``xla``;
     3. the tuning table's row for the geometry (``ops/autotune.py``),
        else the one policy (:func:`policy_choice`);
-    4. a ``fused`` answer where the site cannot run that tier becomes
-       ``packed`` (where legal, else ``bh``) with the row's blocks;
-    5. an ``xla`` answer under ``prefer_flash`` or ``=1`` becomes the
+    4. an ``xla`` answer under ``prefer_flash`` or ``=1`` becomes the
        policy's flash answer.
 
     Packed blocks are resolved here, once, from the row and the shape, so
     the log, the counter and the call agree (``segments``: a joint site's
     text and image rows, for its label). Same site + table ⇒ same choice.
 
-    ``fusable_width`` is the channel width C of a projection→attention
-    site with nothing in between (SDXL UNet self-attention), where the
-    fused QKV tier is executable if C passes the tier's VMEM model;
-    ``None`` where q/k/v arrive projected — same layout family as
-    packed. ``prefer_flash`` (memory-constrained callers, see
-    ``full_attention``) outranks a table ``xla`` row: the sweep optimized
-    for time while the caller needs the streamed softmax to fit HBM.
+    ``prefer_flash`` (memory-constrained callers, see ``full_attention``)
+    outranks a table ``xla`` row: the sweep optimized for time while the
+    caller needs the streamed softmax to fit HBM.
 
     Mesh-aware: inside a :func:`tp_shard_scope` the head count is
     divided by the tp degree BEFORE key derivation — the per-shard
     geometry (H/tp heads) is what actually executes, and a full-H table
     entry can carry blocks that are illegal (or slow) at H/tp."""
-    from .autotune import GeometryKey, KernelChoice, itemsize_of, lookup
-    from .flash_attention import (_fused_feasible, _on_tpu, _packed_legal,
-                                  resolve_flash_blocks)
+    from .autotune import GeometryKey, KernelChoice, lookup
+    from .flash_attention import _on_tpu
 
     # ONE definition of the per-shard rule (GeometryKey.shard): sweeps,
     # table keys and this dispatch must never disagree about it
@@ -265,22 +269,7 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
 
     choice = (lookup(num_heads, head_dim, q_len, kv_len, dtype)
               or policy_choice(q_len, kv_len, num_heads, head_dim))
-    if choice.tier == "fused":
-        blocks = None
-        if fusable_width is not None:
-            blocks = _fused_feasible(
-                fusable_width, num_heads, head_dim,
-                *resolve_flash_blocks(choice.block_q, choice.block_k),
-                itemsize_of(dtype))
-        if blocks is not None:
-            choice = _dataclasses.replace(choice, block_q=blocks[0],
-                                          block_k=blocks[1])
-        else:
-            choice = KernelChoice(
-                "packed" if _packed_legal(num_heads, head_dim) else "bh",
-                choice.block_q, choice.block_k, source=choice.source,
-                reason="fused choice at a site that cannot fuse")
-    elif choice.tier == "xla" and (forced or prefer_flash):
+    if choice.tier == "xla" and (forced or prefer_flash):
         choice = _dataclasses.replace(
             policy_choice(q_len, kv_len, num_heads, head_dim,
                           flash_only=True),
@@ -556,43 +545,7 @@ def ulysses_attention(
         return to_seq(out)
 
 
-def note_shared_kv_causal(num_heads: int, head_dim: int, q_len: int,
-                          kv_len: int, dtype, block_q: int,
-                          block_k: int) -> None:
-    """As :func:`note_latent_causal`, for the blocked causal kernel over ONE
-    shared key/value head (``shared_kv_attention.causal_chunk``): a tier of
-    its own in the ``attention:`` line and ``cdt_attn_kernel_selected``.
-    (At the end of the file: the image programs' compile-cache keys hold
-    the lines their attention sites are traced at, above.)"""
-    from .autotune import GeometryKey, KernelChoice
-
-    _note_selection(
-        GeometryKey.from_shape(num_heads, head_dim, q_len, kv_len,
-                               dtype).key_str(),
-        KernelChoice("shared_kv_causal", block_q, block_k,
-                     reason="chunked prefill over one shared K/V head"))
-
-
-def note_gqa(banded: bool, num_heads: int, head_dim: int, q_len: int,
-             kv_len: int, dtype, block_q: int, block_k: int) -> None:
-    """As :func:`note_latent_causal`, for the blocked causal kernel over
-    grouped key/value heads (``gqa_attention.causal_chunk``): two tiers of
-    their own in the ``attention:`` line and ``cdt_attn_kernel_selected``,
-    ``gqa_window`` (a band: the last ``window`` keys) and ``gqa_causal``
-    (every key below the query)."""
-    from .autotune import GeometryKey, KernelChoice
-
-    _note_selection(
-        GeometryKey.from_shape(num_heads, head_dim, q_len, kv_len,
-                               dtype).key_str(),
-        KernelChoice("gqa_window" if banded else "gqa_causal", block_q,
-                     block_k, reason="chunked prefill over grouped K/V "
-                     "heads" + (", banded" if banded else "")))
-
-
 # --- two row segments: MMDiT joint attention ---------------------------------
-# (At the end of the file, as the notes above: the image programs'
-# compile-cache keys hold the lines their attention sites are traced at.)
 
 from typing import NamedTuple as _NamedTuple
 

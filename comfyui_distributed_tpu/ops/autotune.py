@@ -15,11 +15,10 @@ Layout of the decision data:
   sequence lengths bucket to the next power of two so one entry serves
   a resolution family instead of every ±8-token variant compiling its
   own sweep.
-- **KernelChoice** — (tier, block_q, block_k): tier is one of ``fused``
-  (QKV projection folded into the flash grid), ``packed`` ([B, N, H·D]
-  native layout walked in 128-lane head groups; a row without blocks
-  takes them from the shape — K resident where it fits), ``bh``
-  (classic [B·H, N, D] call), ``xla`` (the fused XLA lowering).
+- **KernelChoice** — (tier, block_q, block_k): tier is one of ``packed``
+  ([B, N, H·D] native layout walked in 128-lane head groups; a row
+  without blocks takes them from the shape — K resident where it fits),
+  ``bh`` (classic [B·H, N, D] call), ``xla`` (the fused XLA lowering).
 - **TuningTable** — two layers: the resolved table for the known model
   zoo shipped in-repo (``ops/attn_table_default.json``, rebakeable with
   ``scripts/autotune_sweep.py``) plus a local overlay persisted next to
@@ -55,7 +54,7 @@ from ..utils.jsonio import atomic_write_json, read_json
 from ..utils.logging import debug_log, log
 
 TABLE_VERSION = 1
-TIERS = ("fused", "packed", "bh", "xla")
+TIERS = ("packed", "bh", "xla")
 # kernels that are no tier of the bidirectional dispatch (no table row, no
 # policy arm chooses them) but report themselves the same way: the blocked
 # causal kernels of a chunked prefill (ops/flash_latent.py), over a latent
@@ -219,17 +218,6 @@ def validate_entry(key: GeometryKey, choice: KernelChoice) -> list[str]:
                                   bq, bk)
             except ValueError as e:
                 errors.append(str(e))
-        return errors
-    if choice.tier == "fused":
-        feas = fa._fused_feasible(H * D, H, D, bq, bk, itemsize)
-        if feas is None:
-            errors.append(
-                f"fused tier infeasible at C=H·D={H * D} ({key.dtype})")
-        elif feas != (bq, bk):
-            errors.append(
-                f"fused blocks {bq}/{bk} exceed the VMEM model at "
-                f"C=H·D={H * D} ({key.dtype}); largest feasible "
-                f"{feas[0]}/{feas[1]}")
     return errors
 
 
@@ -384,7 +372,6 @@ def lookup(num_heads: int, head_dim: int, q_len: int, kv_len: int,
 # --- sweeping ----------------------------------------------------------------
 
 BLOCK_Q_CANDIDATES = (128, 256, 512)
-BLOCK_K_CANDIDATES = (128, 256, 512)     # fused: flash_attention._MAX_BLOCK_K
 
 def candidates_for(key: GeometryKey) -> list[KernelChoice]:
     """Deterministic candidate list for one geometry: every legal
@@ -394,24 +381,12 @@ def candidates_for(key: GeometryKey) -> list[KernelChoice]:
     from . import flash_attention as fa
     from .attention import BH_MIN_Q, PACKED_MIN_KV, PACKED_MIN_Q
 
-    itemsize = itemsize_of(key.dtype)
     H, D = key.num_heads, key.head_dim
     out: list[KernelChoice] = []
     # below the policy's floors XLA's fused lowering wins and the sweep
     # doesn't bother timing pallas tiers — they'd be legal but pointless
     long_enough = (key.q_bucket >= PACKED_MIN_Q
                    and key.kv_bucket >= PACKED_MIN_KV)
-    # fused is self-attention only (q and k/v project from the SAME x);
-    # cross geometries never get fused candidates — no fusable site can
-    # present them, and timing one would race an Nq×Nq problem against
-    # the other tiers' Nq×Nk
-    if long_enough and key.q_bucket == key.kv_bucket:
-        for bq in BLOCK_Q_CANDIDATES:
-            for bk in BLOCK_K_CANDIDATES:
-                if fa._fused_feasible(H * D, H, D, bq, bk,
-                                      itemsize) == (bq, bk):
-                    out.append(KernelChoice("fused", bq, bk,
-                                            source="sweep"))
     if long_enough and fa._packed_legal(H, D):
         # the shape's blocks first, then each q block against the K tile
         # the shape gives it (K resident where it fits): short K chunks
@@ -442,32 +417,19 @@ def _time_candidate(key: GeometryKey, choice: KernelChoice,
     B, Nq, Nk = 1, key.q_bucket, key.kv_bucket
     scan_len = 8
 
-    if choice.tier == "fused":
-        C = H * D
-        x = jax.random.normal(jax.random.key(0), (B, Nq, C), dt)
-        ws = [jax.random.normal(jax.random.key(i), (C, C), dt) / (C ** 0.5)
-              for i in (1, 2, 3)]
+    q = jax.random.normal(jax.random.key(0), (B, Nq, H, D), dt)
+    k = jax.random.normal(jax.random.key(1), (B, Nk, H, D), dt)
+    v = jax.random.normal(jax.random.key(2), (B, Nk, H, D), dt)
 
+    if choice.tier == "xla":
         def op(carry):
-            o = fa.fused_qkv_attention(carry, *ws, H,
-                                       block_q=choice.block_q,
-                                       block_k=choice.block_k,
-                                       interpret=False)
-            return o.reshape(B, Nq, C)
+            return jax.nn.dot_product_attention(carry, k, v)
     else:
-        q = jax.random.normal(jax.random.key(0), (B, Nq, H, D), dt)
-        k = jax.random.normal(jax.random.key(1), (B, Nk, H, D), dt)
-        v = jax.random.normal(jax.random.key(2), (B, Nk, H, D), dt)
-
-        if choice.tier == "xla":
-            def op(carry):
-                return jax.nn.dot_product_attention(carry, k, v)
-        else:
-            def op(carry):
-                return fa.flash_attention(
-                    carry, k, v, block_q=choice.block_q,
-                    block_k=choice.block_k, interpret=False,
-                    layout="packed" if choice.tier == "packed" else "bh")
+        def op(carry):
+            return fa.flash_attention(
+                carry, k, v, block_q=choice.block_q,
+                block_k=choice.block_k, interpret=False,
+                layout="packed" if choice.tier == "packed" else "bh")
 
     @jax.jit
     def run(seed, first):
@@ -478,14 +440,13 @@ def _time_candidate(key: GeometryKey, choice: KernelChoice,
         final, _ = jax.lax.scan(body, first, None, length=scan_len)
         return jnp.sum(final.astype(jnp.float32))
 
-    first = x if choice.tier == "fused" else q
     import statistics
 
-    float(run(jnp.float32(0.0), first))            # compile + warm
+    float(run(jnp.float32(0.0), q))                # compile + warm
     times = []
     for i in range(runs):
         t0 = time.perf_counter()
-        float(run(jnp.float32(i + 1.0), first))
+        float(run(jnp.float32(i + 1.0), q))
         times.append(time.perf_counter() - t0)
     return statistics.median(times) / scan_len
 

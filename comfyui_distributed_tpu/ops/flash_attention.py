@@ -17,8 +17,8 @@ The reference has no analogue (its compute hot loop is ComfyUI's
 ``common_ksampler``, SURVEY §3.3); this kernel sits *under* the parity
 surface as the execution engine's attention primitive.
 
-Kernel structure. The classic (``bh``) and fused tiers are standard TPU
-flash attention: grid = (batch[·heads], Nq/block_q, Nk/block_k), K-blocks
+Kernel structure. The classic (``bh``) tier is standard TPU flash
+attention: grid = (batch·heads, Nq/block_q, Nk/block_k), K-blocks
 innermost so the running max ``m``, denominator ``l`` and output
 accumulator live in VMEM scratch across grid steps; the output block is
 written once on the final K step.
@@ -86,8 +86,8 @@ def _check_blocks(block_q: Optional[int], block_k: Optional[int]) -> None:
 
 def resolve_flash_blocks(block_q: Optional[int] = None,
                          block_k: Optional[int] = None) -> tuple[int, int]:
-    """Checked blocks of the classic and fused tiers: the measured
-    defaults (256/512, r04) where nothing was requested."""
+    """Checked blocks of the classic tier: the measured defaults
+    (256/512, r04) where nothing was requested."""
     _check_blocks(block_q, block_k)
     return (_DEFAULT_BLOCK_Q if block_q is None else block_q,
             _DEFAULT_BLOCK_K if block_k is None else block_k)
@@ -141,53 +141,6 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         l = l_ref[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)            # fully-masked rows → 0
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-
-def _accumulate_packed_heads(q, k, v, j, m_ref, l_ref, acc_ref, *,
-                             kv_len: int, block_k: int, scale: float,
-                             precision, num_heads: int, head_dim: int):
-    """One K-block accumulation over statically-unrolled heads, operands
-    in the full-width [block, H·D] layout the fused tier projects them
-    into. Head h's running max/denominator live in lane h of the
-    [BQ, 128] m/l scratches (hence ``num_heads ≤ 128``). (The packed
-    tier used this loop too until its heads moved onto the grid.)"""
-    col = jax.lax.broadcasted_iota(
-        jnp.int32, (q.shape[0], block_k), 1) if kv_len % block_k else None
-
-    for h in range(num_heads):
-        sl = slice(h * head_dim, (h + 1) * head_dim)
-        s = jax.lax.dot_general(
-            q[:, sl], k[:, sl], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision,
-        ) * scale                                   # [BQ, BK]
-
-        if col is not None:                        # mask the K padding tail
-            s = jnp.where(j * block_k + col < kv_len, s, NEG_INF)
-
-        m_prev = m_ref[:, h:h + 1]                 # [BQ, 1] (lane h)
-        l_prev = l_ref[:, h:h + 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v[:, sl], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision,
-        )                                          # [BQ, D]
-        acc_ref[:, sl] = acc_ref[:, sl] * corr + pv
-        m_ref[:, h:h + 1] = m_new
-        l_ref[:, h:h + 1] = l_new
-
-
-def _finalize_packed_heads(o_ref, m_ref, l_ref, acc_ref, *,
-                           num_heads: int, head_dim: int):
-    """Write the normalized output block once, on the final K step."""
-    for h in range(num_heads):
-        sl = slice(h * head_dim, (h + 1) * head_dim)
-        l = l_ref[:, h:h + 1]
-        l = jnp.where(l == 0.0, 1.0, l)            # fully-masked rows → 0
-        o_ref[0, :, sl] = (acc_ref[:, sl] / l).astype(o_ref.dtype)
 
 
 def _stack_group_heads(q, head_dim: int):
@@ -364,61 +317,6 @@ def _packed_scratch(block_q: int, head_dim: int) -> list:
     return [pltpu.VMEM((rows, _LANES), jnp.float32),
             pltpu.VMEM((rows, _LANES), jnp.float32),
             pltpu.VMEM((rows, width), jnp.float32)]
-
-
-def _flash_kernel_fused(xq_ref, xkv_ref, wq_ref, wk_ref, wv_ref, o_ref,
-                        q_ref, m_ref, l_ref, acc_ref, *,
-                        kv_len: int, block_k: int, num_k_blocks: int,
-                        scale: float, precision, num_heads: int,
-                        head_dim: int):
-    """Fused QKV-projection + attention: the kernel's inputs are the
-    attention block's INPUT activations (x, [1, block, C] row tiles) and
-    the three [C, H·D] projection weights — q/k/v are projected on-chip
-    and never round-trip HBM, so there is no custom-call boundary for
-    XLA to lose fusions at (the ~15 ms/forward relayout + lost-fusion
-    cost `docs/roofline.md` finding 1 measured).
-
-    Schedule: the q row-block is projected ONCE per grid row (j == 0)
-    into VMEM scratch; each K step projects its own [BK, C]·[C, H·D]
-    k/v tiles before the shared packed-heads accumulation. The K/V
-    projection is therefore recomputed once per q block — ``Nq/block_q``
-    times total, an extra ``C/block_q`` of the attention FLOPs — which
-    is why the tier is selected per geometry by the autotune sweep
-    (``ops/autotune.py``) rather than by default: it wins where the
-    boundary cost beats the recompute (narrow C, long N), loses where it
-    doesn't. Projections accumulate in f32 on the MXU and cast back to
-    the operand dtype, matching the out-of-kernel Dense numerics."""
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        q = jax.lax.dot_general(
-            xq_ref[0], wq_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision)
-        q_ref[:] = q.astype(q_ref.dtype)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    xkv = xkv_ref[0]                               # [BK, C]
-    k = jax.lax.dot_general(
-        xkv, wk_ref[:], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=precision,
-    ).astype(q_ref.dtype)                          # [BK, H·D]
-    v = jax.lax.dot_general(
-        xkv, wv_ref[:], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=precision,
-    ).astype(q_ref.dtype)
-
-    _accumulate_packed_heads(
-        q_ref[:], k, v, j, m_ref, l_ref, acc_ref,
-        kv_len=kv_len, block_k=block_k, scale=scale, precision=precision,
-        num_heads=num_heads, head_dim=head_dim)
-
-    @pl.when(j == num_k_blocks - 1)
-    def _finalize():
-        _finalize_packed_heads(o_ref, m_ref, l_ref, acc_ref,
-                               num_heads=num_heads, head_dim=head_dim)
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
@@ -608,25 +506,6 @@ def _flash_mha_packed(q, k, v, num_heads: int, block_q: int, block_k: int,
     return out[:, :Nq]
 
 
-# scoped-VMEM limit of one fused or classic kernel on the chip (the
-# compiler's default), and what `_fused_vmem_bytes` is checked against.
-# Calibrated against the v5e compiler itself (docs/kernels.md, "VMEM
-# model"; tests/test_chip_compile.py asks it again on every run): the
-# compiler's own count has two parts. The tiles and scratch the call
-# declares, which the first terms of each model reproduce to the byte;
-# and scratch for the values the kernel BODY holds, which the declaration
-# does not show and which the models estimate from the body's largest
-# live values.
-_VMEM_BUDGET_BYTES = 16 * 1024 * 1024
-_MIN_BLOCK_Q = 64     # shrink floors: below these tiles the grid is all
-_MIN_BLOCK_K = 128    # overhead (one lane tile / 8 sublane tiles)
-# fused K tiles wider than this are not offered: the fused tier's tiles
-# are full-width ([block, C] and [block, H·D]), and at 1024 rows the
-# compiler's body scratch alone ran from 2 MB (H·D=640) to 19 MB
-# (H·D=3072) — on no probed width did a 1024-row K tile fit where a
-# 512-row one did not.
-_MAX_BLOCK_K = 512
-
 # The packed call asks the compiler for its own scoped limit
 # (`vmem_limit_bytes`; a v5e core has 128 MiB of VMEM) and plans its
 # blocks inside a budget below it: the margin is for what the model
@@ -646,13 +525,6 @@ _PACKED_BLOCK_Q = 512
 
 def _round_up(n: int, multiple: int) -> int:
     return -(-n // multiple) * multiple
-
-
-def _logits_bytes(block_q: int, block_k: int) -> int:
-    """Per-head values of the shared accumulation step: f32 logits, f32
-    probabilities and their operand-dtype cast for the PV matmul, reused
-    from head to head."""
-    return block_q * block_k * (4 + 4 + 2)
 
 
 def _packed_group(head_dim: int) -> tuple[int, int]:
@@ -739,49 +611,6 @@ def _packed_blocks(q_len: int, kv_len: int, head_dim: int, itemsize: int = 2,
         f"{_PACKED_VMEM_BUDGET_BYTES >> 20} MB VMEM budget")
 
 
-def _fused_vmem_bytes(c: int, hd: int, block_q: int, block_k: int,
-                      itemsize: int) -> int:
-    """Scoped VMEM of one fused-kernel grid step: double-buffered x
-    row-tiles ([block, C]) and the out tile, the three resident [C, H·D]
-    projection weights (constant index map — the compiler does keep one
-    buffer of each), the projected-q scratch (operand dtype) and the f32
-    accumulator + m/l scratches; plus what the body holds — the f32
-    results of the three in-kernel projections ([BQ, H·D] once per grid
-    row, two [BK, H·D] per step), the k/v casts back to the operand
-    dtype, and the logits. The body term is what the model used to leave
-    out: at C=1280 it is 5.8 MB at 256/256 blocks, and the compiler
-    refused that call (20.26 MB against the 16 MB limit) while the model
-    said 15.25."""
-    io = 2 * (block_q * c + block_k * c + block_q * hd) * itemsize
-    weights = 3 * c * hd * itemsize
-    scratch = (block_q * hd * itemsize          # projected q
-               + block_q * hd * 4               # f32 accumulator
-               + 2 * block_q * _LANES * 4)      # m / l
-    body = (block_q * hd * 4                    # q projection, f32
-            + 2 * block_k * hd * 4              # k, v projections, f32
-            + 2 * block_k * hd * itemsize       # k, v casts
-            + _logits_bytes(block_q, block_k))
-    return io + weights + scratch + body
-
-
-def _shrink_blocks_for_vmem(bytes_fn, block_q: int, block_k: int
-                            ) -> Optional[tuple[int, int]]:
-    """Fused tier: halve block_k (first — K tiles dominate the working
-    set), then block_q, until ``bytes_fn(bq, bk)`` fits
-    ``_VMEM_BUDGET_BYTES`` with a K tile no wider than ``_MAX_BLOCK_K``;
-    None when even the floor tiles blow the budget. Deterministic: the
-    same request always shrinks to the same blocks."""
-    bq, bk = block_q, block_k
-    while bk > _MAX_BLOCK_K or bytes_fn(bq, bk) > _VMEM_BUDGET_BYTES:
-        if bk > _MIN_BLOCK_K:
-            bk //= 2
-        elif bq > _MIN_BLOCK_Q:
-            bq //= 2
-        else:
-            return None
-    return bq, bk
-
-
 def _packed_legal(H: int, D: int) -> bool:
     """Pure geometric legality of the packed-heads layout: whole heads
     fill whole 128-lane groups. D % 64 confines the layout to the tested
@@ -851,164 +680,3 @@ def flash_attention(
                          block_q=block_q, block_k=block_k,
                          interpret=interpret)
     return out.reshape(B, H, Nq, D).transpose(0, 2, 1, 3)
-
-
-# --- fused QKV-projection + attention tier ----------------------------------
-
-
-def _fused_feasible(C: int, H: int, D: int,
-                    block_q: int = _DEFAULT_BLOCK_Q,
-                    block_k: int = _DEFAULT_BLOCK_K,
-                    itemsize: int = 2) -> Optional[tuple[int, int]]:
-    """Hardware legality of the fused tier: packed-heads geometric
-    constraints plus a lane-aligned model width (C on the x-tile minor
-    axis) plus a feasible block pair under the fused VMEM model — the
-    three resident [C, H·D] weights dominate it, so wide models (WAN
-    1536, FLUX 3072) are fused-infeasible on chip and take the packed
-    tier from the tuning table instead. Returns the (possibly shrunken)
-    blocks, or None."""
-    HD = H * D
-    if not (HD % _LANES == 0 and H <= _LANES and D % 64 == 0
-            and C % _LANES == 0):
-        return None
-    return _shrink_blocks_for_vmem(
-        functools.partial(_fused_vmem_bytes, C, HD, itemsize=itemsize),
-        block_q, block_k)
-
-
-def split_qkv_weight(w_qkv: jax.Array) -> tuple[jax.Array, jax.Array,
-                                                jax.Array]:
-    """[C, 3·H·D] fused-projection weight → (wq, wk, wv) static slices
-    (the layout ``models/dit.py``'s ``qkv`` Dense emits)."""
-    hd = w_qkv.shape[-1] // 3
-    return w_qkv[:, :hd], w_qkv[:, hd:2 * hd], w_qkv[:, 2 * hd:]
-
-
-def _fused_emulated(x, wq, wk, wv, num_heads: int, block_q: int,
-                    block_k: int):
-    """Fused tier in plain JAX ops: projection (f32 MXU accumulation,
-    cast back to the operand dtype — exactly the kernel's epilogue) then
-    the shared `_flash_emulated` block schedule. The CPU/shard_map
-    stand-in that keeps the fused tier testable everywhere the pallas
-    interpreter can't run; the block schedule and masking are identical,
-    so parity tests of this path cover the kernel's math."""
-    B, N, C = x.shape
-    HD = wq.shape[-1]
-    D = HD // num_heads
-
-    def proj(w):
-        y = jax.lax.dot_general(x, w, (((2,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        return y.astype(x.dtype)
-
-    def to_bh(t):
-        return (t.reshape(B, N, num_heads, D)
-                .transpose(0, 2, 1, 3).reshape(B * num_heads, N, D))
-
-    out = _flash_emulated(to_bh(proj(wq)), to_bh(proj(wk)), to_bh(proj(wv)),
-                          block_q=block_q, block_k=block_k)
-    return (out.reshape(B, num_heads, N, D).transpose(0, 2, 1, 3))
-
-
-@functools.partial(jax.jit, static_argnames=("num_heads", "block_q",
-                                             "block_k", "interpret"))
-def _flash_mha_fused(x, wq, wk, wv, num_heads: int, block_q: int,
-                     block_k: int, interpret: bool):
-    B, N, C = x.shape
-    HD = wq.shape[-1]
-    D = HD // num_heads
-    scale = 1.0 / (D ** 0.5)
-    precision = (jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
-                 else jax.lax.Precision.DEFAULT)
-
-    # x is streamed twice under different paddings: q row-tiles walk
-    # block_q-grained rows, k/v row-tiles walk block_k-grained rows
-    xq = _pad_to(x, 1, block_q)
-    xkv = _pad_to(x, 1, block_k)
-    nqb = xq.shape[1] // block_q
-    nkb = xkv.shape[1] // block_k
-
-    out_sds = jax.ShapeDtypeStruct((B, xq.shape[1], HD), x.dtype,
-                                   vma=jax.typeof(xq).vma)
-
-    kernel = functools.partial(
-        _flash_kernel_fused, kv_len=N, block_k=block_k, num_k_blocks=nkb,
-        scale=scale, precision=precision, num_heads=num_heads, head_dim=D)
-
-    w_spec = pl.BlockSpec((C, HD), lambda b, i, j: (0, 0),
-                          memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        kernel,
-        grid=(B, nqb, nkb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, C), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, C), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            w_spec, w_spec, w_spec,
-        ],
-        out_specs=pl.BlockSpec((1, block_q, HD), lambda b, i, j: (b, i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=out_sds,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, HD), x.dtype),           # projected q
-            pltpu.VMEM((block_q, _LANES), jnp.float32),   # per-head max
-            pltpu.VMEM((block_q, _LANES), jnp.float32),   # per-head sum
-            pltpu.VMEM((block_q, HD), jnp.float32),       # output acc
-        ],
-        interpret=interpret,
-    )(xq, xkv, wq, wk, wv)
-    return out[:, :N]
-
-
-def fused_qkv_attention(
-    x: jax.Array, wq: jax.Array, wk: jax.Array, wv: jax.Array,
-    num_heads: int,
-    block_q: Optional[int] = None, block_k: Optional[int] = None,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    """Self-attention computed straight from the block's input
-    activations: ``x`` [B, N, C] and the three bias-free projection
-    weights [C, H·D] (``split_qkv_weight`` splits a packed [C, 3·H·D]).
-    Returns [B, N, H, D] — the same contract as ``full_attention`` on
-    the projected operands, without q/k/v ever materializing in HBM.
-
-    Serves projection→attention sites with nothing in between (SDXL
-    UNet self-attention); sites that qk-norm/RoPE between projection and
-    attention (FLUX, WAN) cannot fuse and take the packed tier instead.
-    ``interpret=None`` auto-selects like ``flash_attention``; blocks are
-    checked the same way and default to 256/512. On hardware, infeasible
-    geometries (the VMEM model — weights resident) raise; in interpret
-    mode the requested blocks run regardless, keeping every geometry
-    CPU-testable."""
-    if interpret is None:
-        interpret = _platform() == "cpu"
-    block_q, block_k = resolve_flash_blocks(block_q, block_k)
-    B, N, C = x.shape
-    HD = wq.shape[-1]
-    if wq.shape != (C, HD) or wk.shape != (C, HD) or wv.shape != (C, HD):
-        raise ValueError(
-            f"fused qkv attention needs three [C, H·D] weights; got "
-            f"wq={wq.shape}, wk={wk.shape}, wv={wv.shape} for C={C}")
-    if HD % num_heads:
-        raise ValueError(
-            f"projection width {HD} not divisible by num_heads={num_heads}")
-    D = HD // num_heads
-    if interpret and _in_manual_trace(x):
-        return _fused_emulated(x, wq, wk, wv, num_heads,
-                               block_q=block_q, block_k=block_k)
-    itemsize = jnp.dtype(x.dtype).itemsize
-    blocks = _fused_feasible(C, num_heads, D, block_q, block_k, itemsize)
-    if blocks is None:
-        if not interpret:
-            raise ValueError(
-                f"fused qkv attention infeasible at C={C}, H·D={HD} "
-                f"({x.dtype}): the resident projection weights exceed the "
-                f"{_VMEM_BUDGET_BYTES >> 20} MB VMEM budget at any block "
-                "size — use the packed tier (ops/autotune.py picks this "
-                "per geometry)")
-        blocks = (block_q, block_k)
-    bq, bk = blocks
-    out = _flash_mha_fused(x, wq, wk, wv, num_heads=num_heads,
-                           block_q=bq, block_k=bk, interpret=interpret)
-    return out.reshape(B, N, num_heads, D)

@@ -1,17 +1,17 @@
 """Pallas causal attention for a prefill walked in chunks through a cache.
 
-Three kernels on one schedule. ``latent_causal_mha`` is the prefill side of
+Two kernels on one schedule. ``latent_causal_mha`` is the prefill side of
 multi-head latent attention (``ops/latent_attention.py``): a chunk of
 queries at positions ``start .. start+C−1`` against the rows ``j ≤`` each
-query's position of a decompressed workspace. ``shared_kv_causal_mha`` is
-the same for many query heads over ONE key/value head
-(``ops/shared_kv_attention.py``). The third (at the end of the file, jitted
-as ``gqa_causal_mha`` and ``gqa_window_mha``: ``ops/gqa_attention.py``)
-serves GROUPS of query heads over a key/value head each and may see a BAND
-only, the last ``window`` keys of each query. The schedule's pieces —
-``_last_block``, ``_on_visible_blocks``, ``_mask_above_diagonal``,
-``_accumulate`` — are shared, the logits and the K/V tiles are each
-kernel's own. What the bidirectional kernels of ``flash_attention.py`` lack:
+query's position of a decompressed workspace. The other
+(``ops/gqa_attention.py``) serves GROUPS of query heads over a key/value
+head each and may see a BAND only, the last ``window`` keys of each query;
+it is jitted under three names, so that a device trace tells its callers
+apart: ``gqa_causal_mha``, ``gqa_window_mha`` and — one group, every query
+head over ONE key/value head — ``shared_kv_causal_mha``. The schedule's
+pieces — ``_last_block``, ``_on_visible_blocks``, ``_accumulate`` — are
+shared, the logits, the mask and the K/V tiles are each kernel's own. What
+the bidirectional kernels of ``flash_attention.py`` lack:
 
 - a **causal mask** whose diagonal moves with ``start`` (a scalar the
   kernel prefetches: one compiled kernel serves every chunk of a scan).
@@ -193,74 +193,6 @@ def latent_causal_mha(q_nope, q_rope, kv, k_rope, start, num_heads: int,
       kv)
 
 
-# --- one key/value head shared by every query head --------------------------
-
-
-def _shared_kv_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                      acc_ref, *, block_q: int, block_k: int,
-                      num_k_blocks: int, precision):
-    i, j = pl.program_id(1), pl.program_id(2)
-    first_row = start_ref[0] + i * block_q
-    last = _last_block(start_ref[0], i, block_q, block_k, num_k_blocks)
-    _init_running(j, m_ref, l_ref, acc_ref)
-
-    def step(masked: bool):
-        s = jax.lax.dot_general(q_ref[...], k_ref[...],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32,
-                                precision=precision)
-        if masked:
-            s = _mask_above_diagonal(s, first_row, j * block_k)
-        _accumulate(s, v_ref[...], m_ref, l_ref, acc_ref, precision)
-
-    _on_visible_blocks(step, j, last, first_row, block_k)
-
-    @pl.when(j == num_k_blocks - 1)
-    def _finalize():
-        o_ref[...] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("num_heads", "block_q",
-                                             "block_k", "interpret"))
-def shared_kv_causal_mha(q, k, v, start, num_heads: int, block_q: int,
-                         block_k: int, interpret: bool):
-    """Causal attention of ``num_heads`` query heads over ONE key/value
-    head (multi-query attention, no positional encoding): the schedule
-    above — the prefetched ``start``, the clamped last block, the masked
-    diagonal step — with K and V tiles that ignore the head index, so no
-    per-head copy of the cache exists. ``q`` [C, H·d] times the softmax
-    scale, ``k``, ``v`` [S, d] (the cache itself), ``start`` the first
-    query's position (traced). ``C % block_q == 0``, ``S % block_k == 0``.
-    Answers [C, H·d]."""
-    C, S = q.shape[0], k.shape[0]
-    d = k.shape[1]
-    nq, nk = C // block_q, S // block_k
-    kernel = functools.partial(_shared_kv_kernel, block_q=block_q,
-                               block_k=block_k, num_k_blocks=nk,
-                               precision=_precision_of(q.dtype))
-
-    def kv_block(h, i, j, start_ref):
-        return (jnp.minimum(j, _last_block(start_ref[0], i, block_q,
-                                           block_k, nk)), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(num_heads, nq, nk),
-        in_specs=[pl.BlockSpec((block_q, d), lambda h, i, j, s: (i, h)),
-                  pl.BlockSpec((block_k, d), kv_block),
-                  pl.BlockSpec((block_k, d), kv_block)],
-        out_specs=pl.BlockSpec((block_q, d), lambda h, i, j, s: (i, h)),
-        scratch_shapes=_running_scratch(block_q, d))
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((C, num_heads * d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-        interpret=interpret,
-    )(jnp.reshape(start, (1,)).astype(jnp.int32), q, k, v)
-
-
 # --- groups of query heads over a key/value head each, whole or a band -----
 
 
@@ -356,8 +288,8 @@ def _gqa_mha(q, k, v, start, lowest, num_heads: int, window: "int | None",
     )(bounds, q, k, v)
 
 
-# one body under two names, so that a device trace tells the full layers'
-# kernel from the window layers'
+# one body under three names, so that a device trace tells the full layers'
+# kernel from the window layers' and both from a shared-K/V model's
 
 
 @functools.partial(jax.jit, static_argnames=("num_heads", "block_q",
@@ -376,4 +308,14 @@ def gqa_window_mha(q, k, v, start, lowest, num_heads: int, window: int,
                    block_q: int, block_k: int, interpret: bool):
     """:func:`_gqa_mha` over the last ``window`` keys of each query."""
     return _gqa_mha(q, k, v, start, lowest, num_heads, window, block_q,
+                    block_k, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("num_heads", "block_q",
+                                             "block_k", "interpret"))
+def shared_kv_causal_mha(q, k, v, start, num_heads: int, block_q: int,
+                         block_k: int, interpret: bool):
+    """:func:`gqa_causal_mha` where every query head reads ONE key/value
+    head (multi-query attention): ``k``, ``v`` [S, d], the cache itself."""
+    return _gqa_mha(q, k[None], v[None], start, 0, num_heads, None, block_q,
                     block_k, interpret)

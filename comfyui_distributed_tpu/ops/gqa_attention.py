@@ -2,8 +2,10 @@
 (grouped-query attention), through a cache, whole or over a window.
 
 ``q`` has ``H`` heads of ``d``; the cache holds ``G`` key and ``G`` value
-heads a token, ``[G, S, d]``; query head ``h`` reads head ``h // (H / G)``.
-Positional encoding is the caller's (the rows come roped, or not at all).
+heads a token, ``[G, S, d]``; query head ``h`` reads head ``h // (H / G)``
+(``G`` = 1: every head over ONE shared key/value head, a state-space
+hybrid's attention layers). Positional encoding is the caller's (the rows
+come roped, or not at all).
 
 - :func:`causal_chunk` — a prefill chunk of ``C`` queries at positions
   ``start .. start+C−1`` against rows that already hold the chunk's own
@@ -13,10 +15,12 @@ Positional encoding is the caller's (the rows come roped, or not at all).
   the chunk's own]``, ``start`` the first query's row among THEM, and
   ``lowest`` the first row that holds a key at all (the ring is empty
   before the first chunk). On a TPU the blocked kernel of
-  ``ops/flash_latent.py`` (``gqa_causal_mha`` / ``gqa_window_mha``: K and V
-  tiles indexed by the group, blocks outside the band skipped): nothing ``C
-  × S`` exists. Elsewhere the masked softmax over the rows, plainly
-  (``lax``): what the kernel is held to.
+  ``ops/flash_latent.py`` (K and V tiles indexed by the group, blocks
+  outside the band skipped), under the name of what it serves —
+  ``gqa_window_mha`` a band, ``shared_kv_causal_mha`` one shared head,
+  ``gqa_causal_mha`` the rest: nothing ``C × S`` exists. Elsewhere the
+  masked softmax over the rows, plainly (``lax``): what the kernel is held
+  to.
 - :func:`step` — one decoded token: one XLA step over a ring or a buffer,
   the rows that hold no key yet masked.
 """
@@ -75,20 +79,22 @@ def causal_chunk(q, k, v, start, scale: float, dtype, block_q: int,
     pad = -S % bk       # none where the caller sized its rows to the block
     if pad:
         k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (k, v))
+    tier = ("gqa_window" if window is not None
+            else "shared_kv_causal" if k.shape[0] == 1 else "gqa_causal")
     if kernel == "pallas":
-        from .attention import note_gqa
+        from .attention import note_causal
 
-        note_gqa(window is not None, H, d, C, S + pad, dtype, bq, bk)
-    interpret = kernel == "interpret"
+        note_causal(tier, H, d, C, S + pad, dtype, bq, bk)
+    blocks = dict(num_heads=H, block_q=bq, block_k=bk,
+                  interpret=kernel == "interpret")
     q, k, v = q.reshape(C, H * d), k.astype(dtype), v.astype(dtype)
-    if window is None:
-        o = flash_latent.gqa_causal_mha(q, k, v, start, num_heads=H,
-                                        block_q=bq, block_k=bk,
-                                        interpret=interpret)
+    if tier == "gqa_window":
+        o = flash_latent.gqa_window_mha(q, k, v, start, lowest,
+                                        window=window, **blocks)
+    elif tier == "shared_kv_causal":
+        o = flash_latent.shared_kv_causal_mha(q, k[0], v[0], start, **blocks)
     else:
-        o = flash_latent.gqa_window_mha(q, k, v, start, lowest, num_heads=H,
-                                        window=window, block_q=bq,
-                                        block_k=bk, interpret=interpret)
+        o = flash_latent.gqa_causal_mha(q, k, v, start, **blocks)
     return o.reshape(C, H, d)
 
 

@@ -299,10 +299,10 @@ def mla_chunk_attention(q_nope, q_rope, c_cache, kr_cache, start, w_b,
                                   kr_pad, start, nope, dtype, bq,
                                   block_k).astype(dtype)
     if kernel == "pallas":
-        from .attention import note_latent_causal
+        from .attention import note_causal
 
-        note_latent_causal(H, nope + q_rope.shape[-1], C, S_pad, dtype, bq,
-                           block_k)
+        note_causal("latent_causal", H, nope + q_rope.shape[-1], C, S_pad,
+                    dtype, bq, block_k)
     o = flash_latent.latent_causal_mha(
         q_nope.reshape(C, H * nope), jnp.swapaxes(q_rope, 0, 1), kv,
         kr_pad, start, num_heads=H, block_q=bq, block_k=block_k,

@@ -174,7 +174,7 @@ LLM_ATTN_KEYS = REGISTRY.counter(
 ATTN_KERNEL_SELECTED = REGISTRY.counter(
     "cdt_attn_kernel_selected",
     "Attention kernel-tier selections at trace time, by tier "
-    "(fused/packed/bh/xla; latent_causal, shared_kv_causal, gqa_causal, "
+    "(packed/bh/xla; latent_causal, shared_kv_causal, gqa_causal, "
     "gqa_window: a chunked prefill's own kernel over a latent cache / one "
     "shared key/value head / grouped key/value heads, whole or a window's "
     "band), geometry (hH.dD.qN.kvN.dtype — bucketed, "

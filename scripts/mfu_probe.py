@@ -170,15 +170,13 @@ def exp_batch() -> None:
 
 
 def exp_attn() -> None:
-    """Attention microbench: flash (auto layout) vs XLA, plus the fused
-    QKV+attention tier at self-attention shapes where C == H·D. Shapes
-    cover SDXL, the FLUX H·D=3072 width (packed: 24 head groups) and
-    WAN's ~14k-token geometry."""
+    """Attention microbench: flash (auto layout) vs XLA. Shapes cover
+    SDXL, the FLUX H·D=3072 width (packed: 24 head groups) and WAN's
+    ~14k-token geometry."""
     import jax
     import jax.numpy as jnp
 
-    from comfyui_distributed_tpu.ops.flash_attention import (
-        flash_attention, fused_qkv_attention)
+    from comfyui_distributed_tpu.ops.flash_attention import flash_attention
 
     shapes = [
         ("self64", 2, 4096, 10, 64, 4096),
@@ -205,19 +203,6 @@ def exp_attn() -> None:
 
         return run
 
-    def timed_fused(h, ws):
-        @jax.jit
-        def run(seed, x):
-            def body(carry, _):
-                out = fused_qkv_attention(carry, *ws, h, interpret=False)
-                out = out.reshape(carry.shape)
-                return (x + out * (seed * 1e-6).astype(x.dtype)), None
-
-            final, _ = jax.lax.scan(body, x, None, length=ATTN_SCAN)
-            return jnp.sum(final.astype(jnp.float32))
-
-        return run
-
     for name, b, nq, h, d, nk in shapes:
         # works for nq != nk too: attention output is q-shaped, so the
         # scan carry stays [B, Nq, H, D] while k/v stay fixed
@@ -237,21 +222,6 @@ def exp_attn() -> None:
             "flash_tflops": round(flops / t_flash / 1e12, 1),
             "xla_tflops": round(flops / t_xla / 1e12, 1),
         }
-        if nq == nk:   # self-attention: fused tier (C == H·D) if feasible
-            from comfyui_distributed_tpu.ops.flash_attention import (
-                _fused_feasible)
-
-            C = h * d
-            if _fused_feasible(C, h, d) is not None:
-                x = jax.random.normal(jax.random.key(3), (b, nq, C),
-                                      jnp.bfloat16)
-                ws = [jax.random.normal(jax.random.key(4 + i), (C, C),
-                                        jnp.bfloat16) / (C ** 0.5)
-                      for i in range(3)]
-                t_fused = _median_time(timed_fused(h, ws), x) / ATTN_SCAN
-                # the fused op also does the QKV projection; its FLOPs
-                # column includes that so tiers stay comparable per op
-                rec["fused_us"] = round(t_fused * 1e6, 1)
         print(json.dumps(rec), flush=True)
 
 

@@ -60,7 +60,7 @@ class TestTableRoundTrip:
         path = tmp_path / "table.json"
         a = TuningTable(path=path, shipped=False)
         b = TuningTable(path=path, shipped=False)
-        a.record(geom(h=10), KernelChoice("fused", 256, 512, source="sweep"))
+        a.record(geom(h=10), KernelChoice("packed", 256, 512, source="sweep"))
         b.record(geom(h=20, q=1024, kv=1024),
                  KernelChoice("xla", source="sweep"))
         merged = TuningTable(path=path, shipped=False)
@@ -86,6 +86,11 @@ class TestTableRoundTrip:
                                               "block_k": 512},
                 "garbage": {"tier": "packed"},
                 "h2.d64.q128.kv128.bf16": {"tier": "warp-drive"},
+                # a row a local overlay kept from before the fused QKV
+                # tier went (PR 42): skipped, not crashed on
+                "h10.d64.q1024.kv1024.bf16": {"tier": "fused",
+                                              "block_q": 256,
+                                              "block_k": 512},
             }}))
         t = TuningTable(path=path, shipped=False)
         assert len(t) == 1
@@ -119,11 +124,10 @@ class TestShippedTable:
             assert not errors, f"{key.key_str()}: {errors}"
 
     def test_flux_geometry_does_not_fall_back_to_classic(self):
-        """Acceptance: H·D=3072 runs packed (or fused), not the classic
-        bh call."""
+        """Acceptance: H·D=3072 runs packed, not the classic bh call."""
         t = TuningTable(shipped=True, path="/nonexistent/none.json")
         choice = t.get(GeometryKey.from_shape(24, 128, 4608, 4608))
-        assert choice.tier in ("packed", "fused")
+        assert choice.tier == "packed"
 
     def test_validate_entry_catches_vmem_blowout(self):
         # a packed K tile is one head group wide: 1024 rows fit where the
@@ -232,23 +236,6 @@ class TestDispatcherPrecedence:
         assert a == b
         assert (a.tier, a.block_q, a.block_k) == ("packed", 256, 512)
 
-    def test_fused_downgrades_at_non_fusable_site(self, on_tpu):
-        key = GeometryKey.from_shape(10, 64, 4096, 4096)
-        self.table_with(key, KernelChoice("fused", 256, 512,
-                                          source="sweep"))
-        fus = on_tpu.select_kernel(4096, 4096, 10, 64, fusable_width=640)
-        assert (fus.tier, fus.block_q, fus.block_k) == ("fused", 256, 512)
-        non = on_tpu.select_kernel(4096, 4096, 10, 64)
-        assert non.tier == "packed"
-        assert (non.block_q, non.block_k) == (256, 512)
-        # a width the fused VMEM model refuses, or one that is not
-        # lane-aligned, gets the projected site's answer
-        for width in (4096, 96):
-            wide = on_tpu.select_kernel(4096, 4096, 10, 64,
-                                        fusable_width=width)
-            assert (wide.tier, wide.block_q, wide.block_k) == \
-                ("packed", 256, 512)
-
     def test_explicit_force_beats_table_xla(self, on_tpu, monkeypatch):
         """CDT_FLASH_ATTENTION=1 promises flash; a table 'xla' entry
         must yield to it (review finding: precedence says explicit env
@@ -265,19 +252,6 @@ class TestDispatcherPrecedence:
         assert autotune.itemsize_of(jnp.bfloat16) == 2
         assert autotune.itemsize_of("f32") == 4
         assert autotune.itemsize_of("bfloat16") == 2
-
-    def test_policy_never_answers_fused(self, on_tpu):
-        """The fused tier is a table row's to give: the policy answers
-        packed even where the tier is feasible (SDXL's 64² sites), and a
-        dry bake therefore writes packed there."""
-        from comfyui_distributed_tpu.ops import flash_attention as fa
-
-        assert fa._fused_feasible(640, 10, 64) == (256, 512)
-        assert on_tpu.policy_choice(4096, 4096, 10, 64).tier == "packed"
-        # SDXL's 32² level: the three resident C=1280 weights leave room
-        # for 128/128 only; WAN's C=1536 for no fused tile at all
-        assert fa._fused_feasible(1280, 20, 64) == (128, 128)
-        assert fa._fused_feasible(1536, 12, 128) is None
 
     def test_prefer_flash_ignores_table_xla(self, on_tpu):
         """The memory-constrained caller's guarantee survives a
@@ -313,103 +287,86 @@ class TestDispatcherPrecedence:
         assert series.get(lbl, 0) - before.get(lbl, 0) == 1
         assert key.key_str() in on_tpu.selection_summary()
 
-# (site, heads, head_dim, q_len, kv_len, dtype, fusable, prefer_flash, tp)
+# (site, heads, head_dim, q_len, kv_len, dtype, prefer_flash, tp)
 # → (tier, block_q, block_k), recorded from PR 27's tree (the parent of the
 # PR that merged the two rule sets into one policy) with the platform
 # reading ``tpu``: every site the benchmark's cells trace, a tp=2 shard of
-# each, every geometry of the model zoo at a fusable and at a projected
-# site, the memory-constrained callers, and untabled geometries on both
-# sides of each floor. A row changes only with the table row or the
-# policy line that a PR means to change.
+# each, every geometry of the model zoo, the memory-constrained callers,
+# and untabled geometries on both sides of each floor. A row changes only
+# with the table row or the policy line that a PR means to change.
 PINNED_SELECTIONS = [
-    ("solo30.self64", 10, 64, 4096, 4096, "bf16", True, False, 1,
+    ("solo30.self64", 10, 64, 4096, 4096, "bf16", False, 1,
      ("packed", 512, 4096)),
-    ("solo30.self32", 20, 64, 1024, 1024, "bf16", True, False, 1,
+    ("solo30.self32", 20, 64, 1024, 1024, "bf16", False, 1,
      ("packed", 512, 1024)),
-    ("solo30.cross64", 10, 64, 4096, 77, "bf16", False, False, 1,
+    ("solo30.cross64", 10, 64, 4096, 77, "bf16", False, 1,
      ("xla", None, None)),
-    ("solo30.cross32", 20, 64, 1024, 77, "bf16", False, False, 1,
+    ("solo30.cross32", 20, 64, 1024, 77, "bf16", False, 1,
      ("xla", None, None)),
-    ("cells.text_encoder", 12, 64, 77, 77, "bf16", True, False, 1,
+    ("cells.text_encoder", 12, 64, 77, 77, "bf16", False, 1,
      ("xla", None, None)),
-    ("solo28.joint", 24, 64, 4173, 4173, "bf16", False, False, 1,
+    ("solo28.joint", 24, 64, 4173, 4173, "bf16", False, 1,
      ("packed", 464, 4224)),
-    ("solo28.joint.tp2", 24, 64, 4173, 4173, "bf16", False, False, 2,
+    ("solo28.joint.tp2", 24, 64, 4173, 4173, "bf16", False, 2,
      ("packed", 464, 4224)),
-    ("solo30.self64.tp2", 10, 64, 4096, 4096, "bf16", True, False, 2,
+    ("solo30.self64.tp2", 10, 64, 4096, 4096, "bf16", False, 2,
      ("xla", None, None)),
-    ("solo30.self32.tp2", 20, 64, 1024, 1024, "bf16", True, False, 2,
+    ("solo30.self32.tp2", 20, 64, 1024, 1024, "bf16", False, 2,
      ("packed", 512, 1024)),
-    ("zoo.sdxl_self64.fusable", 10, 64, 4096, 4096, "bf16", True, False, 1,
+    ("zoo.sdxl_self64", 10, 64, 4096, 4096, "bf16", False, 1,
      ("packed", 512, 4096)),
-    ("zoo.sdxl_self64.projected", 10, 64, 4096, 4096, "bf16", False, False, 1,
-     ("packed", 512, 4096)),
-    ("zoo.sdxl_self32.fusable", 20, 64, 1024, 1024, "bf16", True, False, 1,
+    ("zoo.sdxl_self32", 20, 64, 1024, 1024, "bf16", False, 1,
      ("packed", 512, 1024)),
-    ("zoo.sdxl_self32.projected", 20, 64, 1024, 1024, "bf16", False, False, 1,
-     ("packed", 512, 1024)),
-    ("zoo.sdxl_cross64.fusable", 10, 64, 4096, 77, "bf16", True, False, 1,
+    ("zoo.sdxl_cross64", 10, 64, 4096, 77, "bf16", False, 1,
      ("xla", None, None)),
-    ("zoo.sdxl_cross64.projected", 10, 64, 4096, 77, "bf16", False, False, 1,
+    ("zoo.sdxl_cross32", 20, 64, 1024, 77, "bf16", False, 1,
      ("xla", None, None)),
-    ("zoo.sdxl_cross32.fusable", 20, 64, 1024, 77, "bf16", True, False, 1,
-     ("xla", None, None)),
-    ("zoo.sdxl_cross32.projected", 20, 64, 1024, 77, "bf16", False, False, 1,
-     ("xla", None, None)),
-    ("zoo.flux_joint.fusable", 24, 128, 4608, 4608, "bf16", True, False, 1,
+    ("zoo.flux_joint", 24, 128, 4608, 4608, "bf16", False, 1,
      ("packed", 512, 4608)),
-    ("zoo.flux_joint.projected", 24, 128, 4608, 4608, "bf16", False, False, 1,
-     ("packed", 512, 4608)),
-    ("zoo.wan_self.fusable", 12, 128, 14040, 14040, "bf16", True, False, 1,
+    ("zoo.wan_self", 12, 128, 14040, 14040, "bf16", False, 1,
      ("packed", 512, 14080)),
-    ("zoo.wan_self.projected", 12, 128, 14040, 14040, "bf16", False, False, 1,
-     ("packed", 512, 14080)),
-    ("zoo.wan_cross.fusable", 12, 128, 14040, 512, "bf16", True, False, 1,
+    ("zoo.wan_cross", 12, 128, 14040, 512, "bf16", False, 1,
      ("packed", 512, 512)),
-    ("zoo.wan_cross.projected", 12, 128, 14040, 512, "bf16", False, False, 1,
-     ("packed", 512, 512)),
-    ("prefer.flux_joint", 24, 128, 4608, 4608, "bf16", False, True, 1,
+    ("prefer.flux_joint", 24, 128, 4608, 4608, "bf16", True, 1,
      ("packed", 512, 4608)),
-    ("prefer.over_xla_row", 10, 64, 4096, 77, "bf16", False, True, 1,
+    ("prefer.over_xla_row", 10, 64, 4096, 77, "bf16", True, 1,
      ("bh", None, None)),
-    ("prefer.short_untabled", 8, 64, 512, 512, "bf16", False, True, 1,
+    ("prefer.short_untabled", 8, 64, 512, 512, "bf16", True, 1,
      ("bh", None, None)),
-    ("prefer.packed_illegal", 5, 64, 4608, 4608, "bf16", False, True, 1,
+    ("prefer.packed_illegal", 5, 64, 4608, 4608, "bf16", True, 1,
      ("bh", None, None)),
-    ("policy.exact_length_below_floor", 16, 64, 1000, 1000, "bf16", False, False, 1,
+    ("policy.exact_length_below_floor", 16, 64, 1000, 1000, "bf16", False, 1,
      ("xla", None, None)),
-    ("policy.at_floor", 16, 64, 1024, 256, "bf16", False, False, 1,
+    ("policy.at_floor", 16, 64, 1024, 256, "bf16", False, 1,
      ("packed", 512, 256)),
-    ("policy.short_kv", 16, 64, 2048, 255, "bf16", False, False, 1,
+    ("policy.short_kv", 16, 64, 2048, 255, "bf16", False, 1,
      ("xla", None, None)),
-    ("policy.packed_illegal_mid", 5, 64, 4608, 4608, "bf16", False, False, 1,
+    ("policy.packed_illegal_mid", 5, 64, 4608, 4608, "bf16", False, 1,
      ("xla", None, None)),
-    ("policy.packed_illegal_long", 5, 64, 9000, 9000, "bf16", False, False, 1,
+    ("policy.packed_illegal_long", 5, 64, 9000, 9000, "bf16", False, 1,
      ("bh", None, None)),
-    ("policy.short_kv_long_q", 10, 64, 16384, 77, "bf16", False, False, 1,
+    ("policy.short_kv_long_q", 10, 64, 16384, 77, "bf16", False, 1,
      ("bh", None, None)),
-    ("policy.f32_joint", 24, 64, 4173, 4173, "f32", False, False, 1,
+    ("policy.f32_joint", 24, 64, 4173, 4173, "f32", False, 1,
      ("packed", 464, 4224)),
-    ("policy.d128_streams", 16, 128, 40000, 40000, "bf16", False, False, 1,
+    ("policy.d128_streams", 16, 128, 40000, 40000, "bf16", False, 1,
      ("packed", 512, 20096)),
 ]
 
 
 @pytest.mark.parametrize("case", PINNED_SELECTIONS, ids=lambda c: c[0])
 def test_selection_pinned(on_tpu, case):
-    _, heads, head_dim, q_len, kv_len, dtype, fusable, prefer, tp, want = case
+    _, heads, head_dim, q_len, kv_len, dtype, prefer, tp, want = case
     with on_tpu.tp_shard_scope(tp):
-        choice = on_tpu.select_kernel(
-            q_len, kv_len, heads, head_dim, dtype=dtype,
-            fusable_width=heads * head_dim if fusable else None,
-            prefer_flash=prefer)
+        choice = on_tpu.select_kernel(q_len, kv_len, heads, head_dim,
+                                      dtype=dtype, prefer_flash=prefer)
     assert (choice.tier, choice.block_q, choice.block_k) == want
 
 
 @pytest.mark.parametrize("cross", [False, True], ids=["self", "cross"])
 def test_attention_site_asks_once(monkeypatch, cross):
-    """The dense branch of ``models/layers.Attention`` runs the choice the
-    site asked for: ``full_attention`` does not look it up again."""
+    """``models/layers.Attention`` runs the choice the site asked for:
+    ``full_attention`` does not look it up again."""
     import jax
     import jax.numpy as jnp
 
@@ -431,7 +388,8 @@ def test_attention_site_asks_once(monkeypatch, cross):
         lambda: module.init_with_output(jax.random.key(0), x, *ctx)[0])
     assert out.shape == x.shape
     assert len(calls) == 1
-    assert calls[0][1]["fusable_width"] == (None if cross else 128)
+    assert calls[0][0] == (16, 7 if cross else 16, 2, 64)
+    assert set(calls[0][1]) == {"dtype"}
 
 
 # the shipped rows the policy does not answer the same, with the policy's

@@ -8,8 +8,6 @@ repo's own model counted. These cases compile, at real widths:
 
 - every Pallas row of ``ops/attn_table_default.json`` alone, with its
   operands as program arguments (the strictest setting the compiler has);
-- the fused tier at SDXL's 64² geometry, which no row selects: compiled
-  while its code lives (ROADMAP D12);
 - the classic ``bh`` call at FLUX's geometry, which no row selects but the
   floors of ``select_kernel`` still reach;
 - SDXL's two self-attention sites inside the transformer block that calls
@@ -18,10 +16,9 @@ repo's own model counted. These cases compile, at real widths:
   in a product's epilogue, not on ``proj_out``'s operand path (PR 35);
 - SD3's joint attention (B=2, N=4173, H=24, D=64), which no row names:
   the packed tier's default path, at the blocks its shape derives;
-- and, for every row and for SD3, the largest blocks ``_fused_feasible``
-  and the packed VMEM model (``_packed_blocks``) approve: a model that
-  says yes where the compiler says no is the bug this file exists to
-  catch.
+- and, for every row and for SD3, the largest blocks the packed VMEM
+  model (``_packed_blocks``) approves: a model that says yes where the
+  compiler says no is the bug this file exists to catch.
 
 Nothing runs, so nothing here is a result or a time. The persistent
 compilation cache is off around the compiles: an entry written for a
@@ -83,13 +80,7 @@ def _compile_kernel(chip, tier, H, D, nq, nk, bq, bk, batch=1):
     def arg(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
 
-    if tier == "fused":
-        C = H * D
-        x, w = arg(batch, nq, C), arg(C, C)
-        lowered = fa._flash_mha_fused.lower(
-            x, w, w, w, num_heads=H, block_q=bq, block_k=bk,
-            interpret=False)
-    elif tier == "packed":
+    if tier == "packed":
         q, kv = arg(batch, nq, H * D), arg(batch, nk, H * D)
         lowered = fa._flash_mha_packed.lower(
             q, kv, kv, num_heads=H, block_q=bq, block_k=bk,
@@ -108,7 +99,7 @@ def _compile_kernel(chip, tier, H, D, nq, nk, bq, bk, batch=1):
 # tokens (not a block multiple: the call pads) with the shape's blocks and
 # with requested 256/512 streaming K, SD3's joint attention at the CFG
 # batch, the classic call the table never picks, and SDXL's 64² site at the
-# CFG batch by the fused tier (no row's) and by the packed one (as it runs)
+# CFG batch (as it runs)
 KERNEL_CASES = [
     (ks, c.tier, k.num_heads, k.head_dim, k.q_bucket, k.kv_bucket,
      c.block_q, c.block_k, 1)
@@ -120,7 +111,6 @@ KERNEL_CASES = [
     ("sd3_joint_4173", "packed", 24, 64, 4173, 4173, None, None, 2),
     ("sd3_joint_4173_streamed", "packed", 24, 64, 4173, 4173, 256, 512, 2),
     ("flux_bh_h24.d128.q8192", "bh", 24, 128, 8192, 8192, 256, 512, 1),
-    ("sdxl_self64_fused", "fused", 10, 64, 4096, 4096, 256, 512, 2),
     ("sdxl_self64_cfg", "packed", 10, 64, 4096, 4096, None, None, 2),
 ]
 
@@ -220,18 +210,6 @@ def test_sdxl_self_attention_inside_its_block(chip, level, monkeypatch):
     assert selected[cross.key_str()] == "xla", selected
 
 
-def _approved_frontier(feasible):
-    """For each candidate block_q, the widest candidate block_k the
-    feasibility function approves unchanged."""
-    out = []
-    for bq in autotune.BLOCK_Q_CANDIDATES:
-        ok = [bk for bk in autotune.BLOCK_K_CANDIDATES
-              if feasible(bq, bk) == (bq, bk)]
-        if ok:
-            out.append((bq, max(ok)))
-    return out
-
-
 def _packed_frontier(nq, nk, D):
     """The packed VMEM model's largest approvals at one geometry: the
     tallest doubling q block it still holds the whole sequence against
@@ -262,25 +240,18 @@ PACKED_GEOMETRIES = {
 
 @pytest.mark.parametrize("row", sorted(PACKED_GEOMETRIES), ids=str)
 def test_feasibility_never_approves_what_the_compiler_refuses(chip, row):
-    """The VMEM models against the compiler, on the rows the table ships
-    and on SD3's default-path geometry: whatever ``_fused_feasible`` and
-    the packed model approve for the geometry — not only the pair the
-    table or the shape chose — must compile. On PR 21's parent the fused
-    model approved 256/256 at C=1280 (15.25 MB by its count) and the
-    compiler wanted 20.26 MB of a 16 MB limit."""
+    """The VMEM model against the compiler, on the rows the table ships
+    and on SD3's default-path geometry: whatever the packed model approves
+    for the geometry — not only the pair the table or the shape chose —
+    must compile."""
     H, D, nq, nk = PACKED_GEOMETRIES[row]
-    approved = [("packed", bq, bk) for bq, bk in _packed_frontier(nq, nk, D)]
-    if nq == nk and row in PALLAS_ROWS:
-        # self-attention: the fused tier is a candidate too
-        approved += [("fused", bq, bk) for bq, bk in _approved_frontier(
-            lambda bq, bk: fa._fused_feasible(H * D, H, D, bq, bk))]
-    assert any(t == "packed" for t, _, _ in approved), \
-        f"nothing approved for {row}"
-    for tier, bq, bk in approved:
+    approved = _packed_frontier(nq, nk, D)
+    assert approved, f"nothing approved for {row}"
+    for bq, bk in approved:
         try:
-            _compile_kernel(chip, tier, H, D, nq, nk, bq, bk)
+            _compile_kernel(chip, "packed", H, D, nq, nk, bq, bk)
         except Exception as e:  # noqa: BLE001 — the compiler's refusal
-            pytest.fail(f"{tier} {bq}/{bk} approved for {row} but "
+            pytest.fail(f"packed {bq}/{bk} approved for {row} but "
                         f"refused by the compiler: {str(e)[:400]}")
 
 
@@ -327,10 +298,12 @@ def test_the_selective_scan_kernel_compiles_at_the_served_geometry(chip):
 
 
 def test_the_shared_kv_causal_kernel_compiles_at_the_served_geometry(chip):
-    """``flash_latent.shared_kv_causal_mha`` as the same prefill calls it: 20
-    query heads of 128 over one key/value head, a 4096-token chunk over the
-    64 k cache padded to the K block, the tile the config ships, a traced
-    start."""
+    """``flash_latent.shared_kv_causal_mha`` — the grouped-query body under
+    its third name — as the same prefill calls it: 20 query heads of 128
+    over one key/value head, a 4096-token chunk over the 64 k cache padded
+    to the K block (67 584 rows), the tile the config ships (2048 × 2048), a
+    traced start. The compiled kernel carries the NAME: what the cell's
+    trace readers match (``cdtbench/kinds/jamba.py``)."""
     from comfyui_distributed_tpu.models.llm_jamba import JambaConfig
     from comfyui_distributed_tpu.ops import flash_latent
 
@@ -341,11 +314,14 @@ def test_the_shared_kv_causal_kernel_compiles_at_the_served_geometry(chip):
     def arg(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
 
+    assert (H, d, S) == (20, 128, 67584)
+    assert (cfg.attn_block_q, cfg.attn_block_k) == (2048, 2048)
     lowered = flash_latent.shared_kv_causal_mha.lower(
         arg(C, H * d), arg(S, d), arg(S, d),
         jax.ShapeDtypeStruct((), jnp.int32, sharding=chip), num_heads=H,
         block_q=cfg.attn_block_q, block_k=cfg.attn_block_k, interpret=False)
-    assert "tpu_custom_call" in lowered.compile().as_text()
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text and "shared_kv_causal_mha" in text
 
 
 def test_the_state_space_rewriters_programs_fit_beside_sdxl(chip,

@@ -291,7 +291,7 @@ class TestShapeGate:
                 flash_attention(q, k, v, block_k=200, interpret=True,
                                 layout=layout)
         key = autotune.GeometryKey.from_shape(2, 64, 256, 512)
-        for tier in ("packed", "bh", "fused"):
+        for tier in ("packed", "bh"):
             errors = autotune.validate_entry(
                 key, autotune.KernelChoice(tier, 256, 200))
             assert errors and "multiple of 128" in errors[0]
@@ -494,214 +494,6 @@ class TestPackedParity:
                                block_q=128, block_k=256)
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
-
-
-def fused_reference(x, wq, wk, wv, num_heads):
-    B, N, C = x.shape
-    D = wq.shape[-1] // num_heads
-    q = (x @ wq).reshape(B, N, num_heads, D)
-    k = (x @ wk).reshape(B, N, num_heads, D)
-    v = (x @ wv).reshape(B, N, num_heads, D)
-    return dense_reference(q, k, v)
-
-
-def rand_fused(seed, B, N, C, HD=None):
-    HD = C if HD is None else HD
-    ks = jax.random.split(jax.random.key(seed), 4)
-    x = jax.random.normal(ks[0], (B, N, C))
-    scale = 1.0 / (C ** 0.5)
-    return (x,) + tuple(jax.random.normal(k, (C, HD)) * scale
-                        for k in ks[1:])
-
-
-class TestFusedKernel:
-    """Fused QKV-projection + attention tier: q/k/v are projected inside
-    the flash grid from the block's input activations — parity against
-    projection + dense attention across the geometry matrix
-    (interpret mode, CPU)."""
-
-    @pytest.mark.parametrize("name,B,N,C,H", [
-        ("sdxl_self64", 2, 300, 640, 10),     # ragged N (padding edges)
-        ("sdxl_self32", 1, 1024, 1280, 20),   # block-aligned
-        ("flux_3072", 1, 600, 3072, 24),      # H·D=3072, ragged N
-        ("tiny_ragged", 1, 77, 128, 2),       # N smaller than one block
-    ])
-    def test_matches_reference(self, name, B, N, C, H):
-        from comfyui_distributed_tpu.ops.flash_attention import (
-            fused_qkv_attention)
-
-        x, wq, wk, wv = rand_fused(3, B, N, C)
-        out = fused_qkv_attention(x, wq, wk, wv, H, interpret=True)
-        ref = fused_reference(x, wq, wk, wv, H)
-        assert out.shape == (B, N, H, C // H)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-4, rtol=2e-4)
-
-    def test_wan_14k_token_shape(self):
-        """≥14k tokens at WAN's head_dim=128 — the long-N regime the
-        roofline names. Runs the emulated fused path (the same block
-        schedule/masking as the kernel, XLA-compiled — the pallas
-        interpreter's per-grid-step overhead is prohibitive at a
-        57×29 grid); head count reduced to 2: the kernel unrolls heads
-        identically regardless of H."""
-        from comfyui_distributed_tpu.ops.flash_attention import (
-            _fused_emulated)
-
-        B, N, C, H = 1, 14464, 256, 2
-        x, wq, wk, wv = rand_fused(5, B, N, C)
-        out = _fused_emulated(x, wq, wk, wv, H, block_q=256, block_k=512)
-        ref = fused_reference(x, wq, wk, wv, H)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-4, rtol=2e-4)
-
-    def test_kernel_matches_emulated(self):
-        """The pallas kernel and the plain-JAX emulation are the same
-        block schedule — near-bitwise agreement, which is what makes
-        emulated coverage of big shapes meaningful."""
-        from comfyui_distributed_tpu.ops.flash_attention import (
-            _fused_emulated, fused_qkv_attention)
-
-        x, wq, wk, wv = rand_fused(7, 2, 300, 640)
-        a = fused_qkv_attention(x, wq, wk, wv, 10, block_q=128,
-                                block_k=128, interpret=True)
-        b = _fused_emulated(x, wq, wk, wv, 10, block_q=128, block_k=128)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-6, rtol=1e-6)
-
-    def test_bf16_operands(self):
-        from comfyui_distributed_tpu.ops.flash_attention import (
-            fused_qkv_attention)
-
-        x, wq, wk, wv = (t.astype(jnp.bfloat16)
-                         for t in rand_fused(9, 1, 256, 640))
-        out = fused_qkv_attention(x, wq, wk, wv, 10, interpret=True)
-        assert out.dtype == jnp.bfloat16
-        ref = fused_reference(x.astype(jnp.float32),
-                              wq.astype(jnp.float32),
-                              wk.astype(jnp.float32),
-                              wv.astype(jnp.float32), 10)
-        np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
-                                   np.asarray(ref), atol=5e-2, rtol=5e-2)
-
-    def test_inside_shard_map(self):
-        """Inside a dp shard_map trace the emulated path serves the
-        fused tier (the pallas interpreter can't — same check_vma
-        constraint as the plain kernel)."""
-        from jax.sharding import PartitionSpec as P
-
-        from comfyui_distributed_tpu.ops.flash_attention import (
-            fused_qkv_attention)
-        from comfyui_distributed_tpu.parallel.mesh import build_mesh
-
-        mesh = build_mesh({"dp": 8})
-        x, wq, wk, wv = rand_fused(11, 8, 64, 128)
-
-        def per_shard(x, wq, wk, wv):
-            return fused_qkv_attention(x, wq, wk, wv, 2, interpret=True)
-
-        f = jax.jit(shard_map(
-            per_shard, mesh=mesh,
-            in_specs=(P("dp"), P(), P(), P()),
-            out_specs=P("dp")))
-        out = f(x, wq, wk, wv)
-        ref = fused_reference(x, wq, wk, wv, 2)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-4, rtol=2e-4)
-
-    def test_split_qkv_weight(self):
-        from comfyui_distributed_tpu.ops.flash_attention import (
-            fused_qkv_attention, split_qkv_weight)
-
-        C = 128
-        w = jax.random.normal(jax.random.key(13), (C, 3 * C)) / C ** 0.5
-        wq, wk, wv = split_qkv_weight(w)
-        assert wq.shape == wk.shape == wv.shape == (C, C)
-        x = jax.random.normal(jax.random.key(14), (1, 200, C))
-        out = fused_qkv_attention(x, wq, wk, wv, 2, interpret=True)
-        ref = fused_reference(x, wq, wk, wv, 2)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-4, rtol=2e-4)
-
-    def test_shape_validation(self):
-        from comfyui_distributed_tpu.ops.flash_attention import (
-            fused_qkv_attention)
-
-        x, wq, wk, wv = rand_fused(15, 1, 64, 128)
-        with pytest.raises(ValueError, match="num_heads"):
-            fused_qkv_attention(x, wq, wk, wv, 3, interpret=True)
-        with pytest.raises(ValueError, match=r"\[C, H·D\]"):
-            fused_qkv_attention(x, wq[:64], wk, wv, 2, interpret=True)
-
-
-class TestFusedModelSite:
-    """The SDXL UNet self-attention site (models/layers.py Attention)
-    takes the fused path when the dispatcher picks it, with the same
-    params either way — checkpoints can't tell the branches apart."""
-
-    def _table_with_fused(self, h, d, q, kv):
-        from comfyui_distributed_tpu.ops import autotune
-
-        autotune.reset_default_table()
-        t = autotune.default_table()
-        # dtype must match the module's (f32 here) — the table keys on it
-        t.record(autotune.GeometryKey.from_shape(h, d, q, kv, "float32"),
-                 autotune.KernelChoice("fused", 128, 128, source="sweep"),
-                 save=False)
-        return t
-
-    def test_fused_branch_matches_dense_branch(self, monkeypatch):
-        import flax.linen as nn  # noqa: F401
-
-        from comfyui_distributed_tpu.models.layers import Attention
-        from comfyui_distributed_tpu.ops import attention as attn
-
-        H, D, N, C = 2, 64, 256, 128
-        x = jax.random.normal(jax.random.key(16), (1, N, C))
-        module = Attention(num_heads=H, head_dim=D, dtype=jnp.float32)
-        monkeypatch.delenv("CDT_FLASH_ATTENTION", raising=False)
-        params = module.init(jax.random.key(17), x)
-        dense_out = module.apply(params, x)
-        # force the fused tier (table entry + forced flash so the CPU
-        # platform gate doesn't veto it)
-        self._table_with_fused(H, D, N, N)
-        monkeypatch.setenv("CDT_FLASH_ATTENTION", "1")
-        attn.reset_selections()
-        fused_out = module.apply(params, x)
-        assert "to_q" in params["params"]
-        np.testing.assert_allclose(np.asarray(fused_out),
-                                   np.asarray(dense_out),
-                                   atol=2e-4, rtol=2e-4)
-        assert any(d.startswith("fused")
-                   for d in attn.selection_summary().split(",")
-                   for g, _, d in [d.partition("=")])
-
-    def test_infeasible_real_width_degrades_to_dense(self, monkeypatch):
-        """The table validates fused feasibility assuming C == H·D; a
-        site whose REAL channel width is lane-misaligned must degrade to
-        the dense path instead of raising mid-forward (review finding)."""
-        from comfyui_distributed_tpu.models.layers import Attention
-
-        H, D, N, C = 2, 64, 256, 96          # C % 128 != 0 → fused illegal
-        x = jax.random.normal(jax.random.key(21), (1, N, C))
-        self._table_with_fused(H, D, N, N)
-        monkeypatch.setenv("CDT_FLASH_ATTENTION", "1")
-        module = Attention(num_heads=H, head_dim=D, dtype=jnp.float32)
-        params = module.init(jax.random.key(22), x)
-        out = module.apply(params, x)
-        assert out.shape == (1, N, C)
-
-    def test_cross_attention_never_fuses(self, monkeypatch):
-        from comfyui_distributed_tpu.models.layers import Attention
-
-        H, D, N, C, M = 2, 64, 256, 128, 77
-        x = jax.random.normal(jax.random.key(18), (1, N, C))
-        ctx = jax.random.normal(jax.random.key(19), (1, M, C))
-        self._table_with_fused(H, D, N, M)
-        monkeypatch.setenv("CDT_FLASH_ATTENTION", "1")
-        module = Attention(num_heads=H, head_dim=D, dtype=jnp.float32)
-        params = module.init(jax.random.key(20), x, ctx)
-        out = module.apply(params, x, ctx)   # downgrades, must not crash
-        assert out.shape == (1, N, C)
 
 
 # --- the two-segment packed call (MMDiT joint attention, PR 41) -------------

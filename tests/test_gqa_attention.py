@@ -1,7 +1,8 @@
-"""``ops/gqa_attention.py`` and the third kernel of ``ops/flash_latent.py``
-(groups of query heads over a key/value head each, whole or a band), in the
-Pallas interpreter and as the ``lax`` statement, against a naive float64
-masked softmax: over starts, band edges inside, at and across blocks,
+"""``ops/gqa_attention.py`` and the grouped-query kernel of
+``ops/flash_latent.py`` (groups of query heads over a key/value head each,
+whole or a band; ONE group is the shared-K/V case), in the Pallas
+interpreter and as the ``lax`` statement, against a naive float64 masked
+softmax: over starts, band edges inside, at and across blocks,
 ``window=None``, a ring that is still empty, 6 heads a group, and tiles
 whose sides differ (each kernel is served with a pair of its own)."""
 
@@ -62,6 +63,20 @@ def test_the_full_kernel_is_naive_attention_with_six_heads_a_group(
                                      kernel=kernel)
     assert got.shape == (16, 12, 16)
     assert close(got, naive(q, k, v, start, 0.25))
+
+
+@pytest.mark.parametrize("kernel,block_q,block_k", KERNEL_TILES)
+def test_one_shared_head_is_a_case_of_the_grouped_kernel(kernel, block_q,
+                                                         block_k):
+    """20 heads over 1 in miniature — 6 heads of 16 over ONE key/value
+    head, the chunk at start 16 of 56 rows: the kernel that runs under the
+    name ``shared_kv_causal_mha`` is the grouped one with a single group."""
+    q, k, v = case(jax.random.key(7), 16, 56, 6, 1, 16)
+    got = gqa_attention.causal_chunk(q, k, v, jnp.int32(16), 0.25,
+                                     jnp.float32, block_q, block_k,
+                                     kernel=kernel)
+    assert got.shape == (16, 6, 16)
+    assert close(got, naive(q, k, v, 16, 0.25))
 
 
 # (window, block_q, block_k): the band's lower edge inside a block, at a
@@ -141,18 +156,44 @@ def test_a_model_serves_each_kernel_with_a_pair_of_its_own():
     assert tiny.attn_full_block_k > tiny.prefill_chunk_tokens
 
 
-def test_the_two_names_are_one_body():
-    q, k, v = case(jax.random.key(5), 8, 16, 4, 2, 8)
-    flat = q.reshape(8, 32)
-    whole = flash_latent.gqa_causal_mha(flat, k, v, jnp.int32(8),
-                                        num_heads=4, block_q=8, block_k=8,
-                                        interpret=True)
-    wide = flash_latent.gqa_window_mha(flat, k, v, jnp.int32(8), jnp.int32(0),
-                                       num_heads=4, window=64, block_q=8,
-                                       block_k=8, interpret=True)
-    assert np.array_equal(np.asarray(whole), np.asarray(wide))
-    assert flash_latent.gqa_causal_mha.__name__ == "gqa_causal_mha"
-    assert flash_latent.gqa_window_mha.__name__ == "gqa_window_mha"
+def _call_by_name(name, q, k, v, start):
+    """The jitted ``name`` of ``flash_latent`` on ``q`` [C,H·d] and ``k``,
+    ``v`` [G,S,d] (G = 1 for the shared name, which takes the cache's own
+    [S,d]); the window name under a band wider than every row."""
+    fn = getattr(flash_latent, name)
+    blocks = dict(num_heads=4, block_q=8, block_k=8, interpret=True)
+    if name == "gqa_window_mha":
+        return fn, (q, k, v, start, jnp.int32(0)), dict(blocks, window=64)
+    if name == "shared_kv_causal_mha":
+        return fn, (q, k[0], v[0], start), blocks
+    return fn, (q, k, v, start), blocks
+
+
+@pytest.mark.parametrize("name", ["gqa_causal_mha", "gqa_window_mha",
+                                  "shared_kv_causal_mha"])
+def test_each_name_is_its_own_in_a_program_and_all_are_one_body(
+        name, monkeypatch):
+    """What a device trace reads (``cdtbench/kinds/jamba.py``,
+    ``kinds/trinity.py`` match ``^<name>``) is the jitted function's name in
+    the lowered program; what runs under it is ``_gqa_mha``, whatever the
+    name: the same answer bit for bit, and no other body is traced."""
+    q, k, v = case(jax.random.key(5), 8, 16, 4, 1, 8)
+    flat, start = q.reshape(8, 32), jnp.int32(8)
+    fn, args, kw = _call_by_name(name, flat, k, v, start)
+    assert fn.__name__ == name
+    assert f"jit_{name}" in fn.lower(*args, **kw).as_text()
+    ref, ref_args, ref_kw = _call_by_name("gqa_causal_mha", flat, k, v, start)
+    assert np.array_equal(np.asarray(fn(*args, **kw)),
+                          np.asarray(ref(*ref_args, **ref_kw)))
+    traced = []
+    body = flash_latent._gqa_mha
+    monkeypatch.setattr(flash_latent, "_gqa_mha",
+                        lambda *a: traced.append(a[5:7]) or body(*a))
+    # shapes no other test hands this name: a trace, not a cache hit
+    q, k, v = case(jax.random.key(5), 24, 24, 4, 1, 8)
+    fn, args, kw = _call_by_name(name, q.reshape(24, 32), k, v, start)
+    fn(*args, **kw)
+    assert traced == [(4, 64 if name == "gqa_window_mha" else None)]
 
 
 def test_the_decode_step_reads_the_rows_it_is_told_are_valid():
@@ -170,9 +211,13 @@ def test_both_kernels_report_a_tier_of_their_own():
 
     for tier in ("gqa_window", "gqa_causal"):
         assert tier in autotune.REPORTED_TIERS and tier not in autotune.TIERS
+    assert set(attention.CAUSAL_TIER_REASONS) \
+        == set(autotune.REPORTED_TIERS) - set(autotune.TIERS)
     attention.reset_selections()
-    attention.note_gqa(True, 48, 128, 4096, 8192, jnp.bfloat16, 1024, 1024)
-    attention.note_gqa(False, 48, 128, 4096, 133120, jnp.bfloat16, 2048, 2048)
+    attention.note_causal("gqa_window", 48, 128, 4096, 8192, jnp.bfloat16,
+                          1024, 1024)
+    attention.note_causal("gqa_causal", 48, 128, 4096, 133120, jnp.bfloat16,
+                          2048, 2048)
     summary = attention.selection_summary()
     assert "gqa_window:1024/1024" in summary
     assert "gqa_causal:2048/2048" in summary

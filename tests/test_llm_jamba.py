@@ -22,7 +22,7 @@ from comfyui_distributed_tpu.diffusion import pipeline_llm
 from comfyui_distributed_tpu.models import llm_jamba as J
 from comfyui_distributed_tpu.models import llm_jamba_reference as R
 from comfyui_distributed_tpu.models import llm_model
-from comfyui_distributed_tpu.ops import shared_kv_attention
+from comfyui_distributed_tpu.ops import gqa_attention
 
 ROOT = Path(__file__).resolve().parent.parent
 F32_TOL = 2e-4          # float32 program against the float32 reference
@@ -172,15 +172,15 @@ def _naive(q, k, v, start, scale):
     ("lax", 8, 8), ("interpret", 8, 8), ("interpret", 4, 16),
     ("interpret", 16, 4), ("interpret", 8, 32)])
 @pytest.mark.parametrize("start", [0, 16, 40])
-def test_the_shared_kv_kernel_is_naive_attention_at_jambas_widths(
+def test_the_shared_kv_case_is_naive_attention_at_jambas_widths(
         kernel, block_q, block_k, start):
     """20 heads of 128 over one key/value head, a chunk of 16 at three
     starts over a cache of 56 rows (padded to the K block inside): the
     moving diagonal, the clamped last block and the masked step."""
     q, k, v = _attention_case(jax.random.key(5), 16, 56, 20, 128)
-    got = shared_kv_attention.causal_chunk(
-        q, k, v, jnp.int32(start), 128 ** -0.5, jnp.float32, block_q,
-        block_k, kernel)
+    got = gqa_attention.causal_chunk(
+        q, k[None], v[None], jnp.int32(start), 128 ** -0.5, jnp.float32,
+        block_q, block_k, kernel=kernel)
     assert got.shape == (16, 20, 128)
     assert close(got, _naive(q, k, v, start, 128 ** -0.5), 1e-5)
 
@@ -189,17 +189,17 @@ def test_rows_above_the_chunk_are_never_read():
     q, k, v = _attention_case(jax.random.key(6), 8, 32, 4, 8)
     poisoned_k = k.at[16:].set(jnp.nan)
     poisoned_v = v.at[16:].set(jnp.nan)
-    got = shared_kv_attention.causal_chunk(
-        q, poisoned_k, poisoned_v, jnp.int32(8), 1.0, jnp.float32, 8, 8,
-        "interpret")
+    got = gqa_attention.causal_chunk(
+        q, poisoned_k[None], poisoned_v[None], jnp.int32(8), 1.0,
+        jnp.float32, 8, 8, kernel="interpret")
     assert np.isfinite(np.asarray(got)).all()
     assert close(got, _naive(q, k, v, 8, 1.0), 1e-5)
 
 
 def test_the_decode_step_is_the_naive_attentions_last_row():
     q, k, v = _attention_case(jax.random.key(7), 1, 24, 4, 8)
-    got = shared_kv_attention.step(q[0], k, v, jnp.int32(17), 0.3,
-                                   jnp.float32)
+    got = gqa_attention.step(q[0], k[None], v[None], jnp.arange(24) <= 17,
+                             0.3, jnp.float32)
     assert close(got, _naive(q, k, v, 17, 0.3)[0], 1e-5)
 
 
@@ -209,8 +209,8 @@ def test_the_blocked_kernel_reports_a_tier_of_its_own(monkeypatch):
     assert "shared_kv_causal" in autotune.REPORTED_TIERS
     assert "shared_kv_causal" not in autotune.TIERS
     attention.reset_selections()
-    attention.note_shared_kv_causal(20, 128, 4096, 66560, jnp.bfloat16, 1024,
-                                    1024)
+    attention.note_causal("shared_kv_causal", 20, 128, 4096, 66560,
+                          jnp.bfloat16, 1024, 1024)
     assert "shared_kv_causal:1024/1024" in attention.selection_summary()
     attention.reset_selections()
 
