@@ -23,6 +23,14 @@ from .samplers import run_segment, token_program
 TAP_EVERY = 128     # llm_decode returns every 128th step's logits
 
 
+def _slot_counts(cfg) -> int:
+    """How many counts both programs hand back: the held slots of every
+    expert layer and, where the router has identity experts, the slots on
+    those (``[held … | zero …]``)."""
+    layers = len(cfg.moe_layers)
+    return layers * 2 if layers and cfg.routing.zero_experts else layers
+
+
 class LLMPipeline:
     """Any language model that gives ``models/llm_model.LLMModel``'s
     functions: ``config.model`` is that value, ``config`` its sizes."""
@@ -85,7 +93,7 @@ class LLMPipeline:
         ``new_tokens`` steps of the token program in one scan.
         ``tap_every`` is the served program's unless a parity tool builds
         a decode of its own to compare more rows."""
-        n_counts = len(self.config.moe_layers)
+        n_counts = _slot_counts(self.config)
 
         def llm_decode(weights, logits, cache, key, temperature):
             def forward(state, token, i):
@@ -113,23 +121,29 @@ class LLMPipeline:
         (the tap logits stay on the device)."""
         prefill, decode = self.programs(len(ids), int(new_tokens))
         _, chunks, form = self.prefill_plan(len(ids))
-        logits, cache, held_prefill, *rows = prefill(
+        layers = len(self.config.moe_layers)
+        logits, cache, slots_prefill, *rows = prefill(
             jnp.asarray(ids, jnp.int32))
         # a chunked prefill counts the rows its experts multiplied (none
         # without an expert layer); the whole-prompt form multiplies every
         # held expert by every token
         rows = int(np.asarray(rows[0]).sum()) if rows else (
             len(ids) * self.config.num_experts * len(self.config.moe_layers))
-        out, taps, held_decode, finite = decode(
+        out, taps, slots_decode, finite = decode(
             logits, cache, jax.random.key(int(seed)),
             jnp.asarray(temperature, jnp.float32))
+        slots_prefill, slots_decode = (np.asarray(slots_prefill),
+                                       np.asarray(slots_decode))
         # ``finite`` covers prefill's logits too: step 0 draws from them
         return {"ids": np.asarray(out), "prefill_logits": logits,
                 # shapes only (eval_shape): nothing is allocated or run
                 "cache_bytes": cache_bytes(self.model, self.config,
                                            len(ids) + int(new_tokens)),
                 "tap_logits": taps, "finite": bool(finite),
-                "held_prefill": np.asarray(held_prefill),
-                "held_decode": np.asarray(held_decode),
+                # per expert layer; ``zero_*`` empty without identity experts
+                "held_prefill": slots_prefill[:layers],
+                "held_decode": slots_decode[:layers],
+                "zero_prefill": slots_prefill[layers:],
+                "zero_decode": slots_decode[layers:],
                 "prefill_chunks": chunks, "prefill_form": form,
                 "rows_prefill": rows}

@@ -1024,11 +1024,17 @@ class TPUPromptRewrite(NodeDef):
                     int(out["held_decode"].sum()))
                 for phase, tokens in phases:
                     held = int(out[f"held_{phase}"].sum())
+                    # on an identity expert: neither held nor absent
+                    zero = int(out[f"zero_{phase}"].sum())
                     _tm.LLM_EXPERT_SLOTS.labels(where="held",
                                                 phase=phase).inc(held)
+                    if cfg.routing.zero_experts:
+                        _tm.LLM_EXPERT_SLOTS.labels(where="zero",
+                                                    phase=phase).inc(zero)
                     _tm.LLM_EXPERT_SLOTS.labels(
                         where="absent", phase=phase).inc(
-                            tokens * cfg.routed_slots_per_token - held)
+                            tokens * cfg.routed_slots_per_token - held
+                            - zero)
         new_ids = out["ids"]
         if not out["finite"]:
             raise RuntimeError("the language model produced a non-finite "

@@ -19,7 +19,10 @@ class LLMModel(NamedTuple):
     #                         (logits [V], cache, held [expert layers])
     # ``held`` has one count an expert layer — ``cfg.moe_layers`` — and is
     # an empty int32 vector for a model that has none (its config then
-    # needs no ``routing``, ``num_experts`` or ``expert_tile``)
+    # needs no ``routing``, ``num_experts`` or ``expert_tile``); a model
+    # whose router has identity experts (``routing.zero_experts``) appends
+    # the slots that fell on those, again one count an expert layer:
+    # ``[held … | zero …]``
     empty_cache: Callable   # (cfg, max_len) -> the decode carry's state
     cache_kinds: Callable   # (cfg, cache) -> {kind of layer: its leaves}
     # the prefill's continuation, for a model whose prompt is walked in

@@ -46,7 +46,7 @@ class ModelPreset:
     # test presets stay float32: their parity tolerances are float32's.
     param_dtype: "str | None" = None
     # config of a language model (models/llm_hybrid.py, llm_motif.py,
-    # llm_kimi.py, llm_jamba.py, llm_trinity.py; its
+    # llm_kimi.py, llm_jamba.py, llm_trinity.py, llm_longcat.py; its
     # ``.model`` gives the functions): such a preset has no denoiser, VAE
     # or text tower, and is loaded by LLMLoader
     llm: "object | None" = None
@@ -211,7 +211,11 @@ def _llm_preset(name: str, family: str, tiny: bool = False):
     """A language model of ``family``: at its published widths (this
     chip's share, or the whole model where it fits), or its tiny float32
     form for the CPU."""
-    if family == "trinity":
+    if family == "longcat":
+        from .llm_longcat import LongcatConfig
+
+        share = LongcatConfig.tiny if tiny else LongcatConfig.longcat_share
+    elif family == "trinity":
         from .llm_trinity import TrinityConfig
 
         share = TrinityConfig.tiny if tiny else TrinityConfig.trinity_share
@@ -267,6 +271,8 @@ PRESETS: dict[str, ModelPreset] = {
     "jamba-tiny": _llm_preset("jamba-tiny", "jamba", tiny=True),
     "trinity-large-preview": _llm_preset("trinity-large-preview", "trinity"),
     "trinity-tiny": _llm_preset("trinity-tiny", "trinity", tiny=True),
+    "longcat-flash-omni": _llm_preset("longcat-flash-omni", "longcat"),
+    "longcat-tiny": _llm_preset("longcat-tiny", "longcat", tiny=True),
 }
 
 

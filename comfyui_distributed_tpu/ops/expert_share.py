@@ -8,12 +8,20 @@ that would add the other chips' parts is not here, and nothing stands in
 for it: on one chip the partial result is what goes on
 (``tests/test_llm_hybrid.py`` ties the shares to the uncut layer).
 
-Routing (sigmoid scores, top-k, group-limited where the model groups its
-experts): the selection runs on ``score + bias``, the combine weights on
-the bare scores of the selected experts, normalised and scaled. Scores are
-float32. The experts are gated MLPs ``(act(x W_g, x W_u)) W_down``; the
-model passes its activation (``silu_gate`` here; a model may bring its own,
-with per-expert parameters).
+Routing (sigmoid scores, or a softmax over all the router's outputs;
+top-k, group-limited where the model groups its experts): the selection
+runs on ``score + bias``, the combine weights on the bare scores of the
+selected experts, normalised where the model normalises them, and scaled.
+Scores are float32. The experts are gated MLPs ``(act(x W_g, x W_u))
+W_down``; the model passes its activation (``silu_gate`` here; a model may
+bring its own, with per-expert parameters).
+
+A router may have a THIRD place a slot can fall: ``zero_experts`` identity
+experts, its outputs ``[experts, experts + zero_experts)``, which have no
+weights and cost no product — ``w_e · x``. They are neither held nor
+absent: every chip computes them for the tokens whose stream it has
+(:func:`zero_part`), and no form of the held part ever sees one (an index
+``≥ experts`` lies outside every share).
 
 Three forms of the experts' part. :func:`held_part_dense` applies every
 held expert to every token and masks (a prefill of a few hundred tokens: one
@@ -47,21 +55,30 @@ class Routing:
     groups_kept: int
     scaling: float
     group_top: int = 2    # a group's score is the sum of its best two
+    # values of the MODEL (a request has no say in them):
+    score: str = "sigmoid"     # or "softmax", over all the outputs
+    normalised: bool = True    # the chosen weights sum to ``scaling``
+    zero_experts: int = 0      # identity experts, after the real ones
+
+    @property
+    def outputs(self) -> int:
+        """The router's whole width."""
+        return self.experts + self.zero_experts
 
 
 @device_scoped("llm_router")
 def route(x, w_router, bias, r: Routing):
     """``x`` [T,D] → ``(idx [T,k] int32, weights [T,k] f32)`` over all
-    ``r.experts``. ``bias`` (None: the router has none) moves the
-    selection only."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                               w_router.astype(jnp.float32),
-                               precision=jax.lax.Precision.HIGHEST))
+    ``r.outputs`` (an index ``≥ r.experts`` is an identity expert).
+    ``bias`` (None: the router has none) moves the selection only."""
+    score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[r.score]
+    s = score(jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST))
     sel = s if bias is None else s + bias.astype(jnp.float32)
     if r.groups == 1:
         return _combine(s, jax.lax.top_k(sel, r.per_token)[1], r)
     T = x.shape[0]
-    per_group = r.experts // r.groups
+    per_group = r.outputs // r.groups
     grouped = sel.reshape(T, r.groups, per_group)
     group_score = jax.lax.top_k(grouped, r.group_top)[0].sum(-1)
     kept = jax.lax.top_k(group_score, r.groups_kept)[1]           # [T,gk]
@@ -74,8 +91,9 @@ def route(x, w_router, bias, r: Routing):
 
 def _combine(s, idx, r: Routing):
     w = jnp.take_along_axis(s, idx, axis=1)
-    w = w / w.sum(-1, keepdims=True) * r.scaling
-    return idx.astype(jnp.int32), w
+    if r.normalised:
+        w = w / w.sum(-1, keepdims=True)
+    return idx.astype(jnp.int32), w * r.scaling
 
 
 def silu_gate(g, u, _=None):
@@ -99,8 +117,25 @@ def swiglu(x, w_gu, w_down, dtype):
 
 
 def held_slots(idx, first: int, held: int):
-    """Which routed slots fell on the experts ``[first, first+held)``."""
+    """Which routed slots fell on the experts ``[first, first+held)`` (a
+    share lies below the router's ``experts``: never an identity one)."""
     return (idx >= first) & (idx < first + held)
+
+
+def zero_part(x, idx, w, r: Routing, valid=None):
+    """The identity experts' part, ``(Σ_{chosen e ≥ r.experts} w_e) · x``
+    [T,D] f32 — no weight is read and nothing is multiplied but a row by a
+    scalar — and how many slots (of rows ``valid`` says are real; None:
+    all) fell on them. Computed where the token's stream is: by every
+    chip alike, once."""
+    zero = idx >= r.experts
+    with device_scope("llm_experts"):
+        mix = jnp.where(zero, w, 0.0).sum(-1, keepdims=True) \
+            * x.astype(jnp.float32)
+    with device_scope("llm_router"):
+        if valid is not None:
+            zero &= valid[:, None]
+        return mix, zero.sum().astype(jnp.int32)
 
 
 @device_scoped("llm_experts")
@@ -149,7 +184,8 @@ GROUP_TILE = 128      # rows of one grouped product: the MXU's height
 
 def prefill_form(rows: int, r: Routing, tile: int = GROUP_TILE) -> str:
     """``grouped`` where a held expert expects (``rows · per_token /
-    experts``, routing even) at least half a tile of rows, ``dense``
+    outputs``, routing even over the router's whole width: a slot on an
+    identity expert is no held expert's) at least half a tile of rows, ``dense``
     below that: there most of every tile would be padding, and each of
     them still reads its expert's weights. EXACTLY at half a tile (4096
     rows × top 4 ÷ 256 experts = 64: the fifth rewriter's chunk) even
@@ -159,7 +195,7 @@ def prefill_form(rows: int, r: Routing, tile: int = GROUP_TILE) -> str:
     chunk and only each one's last tile is padded), where the dense form
     multiplies ``held experts × rows`` whatever is routed: 64 a routed
     row there."""
-    return "grouped" if 2 * rows * r.per_token >= tile * r.experts \
+    return "grouped" if 2 * rows * r.per_token >= tile * r.outputs \
         else "dense"
 
 

@@ -59,7 +59,8 @@ DEVICE_LAYERS: tuple[tuple[str, str], ...] = (
                 "selective scan, the gate, out_proj and its residual add"),
     ("llm_router", "router logits, selection, weights, the slot counters"),
     ("llm_experts", "the held routed experts, all three forms (dense-masked, "
-                    "grouped, per token) and their combine"),
+                    "grouped, per token) and their combine; the identity "
+                    "experts' mix and an expert branch's join"),
     ("llm_shared_ffn", "the shared expert and the dense layers' FFN, with "
                        "the sum that joins them to the residual stream"),
     ("llm_mix", "the multi-stream residual: Sinkhorn rounds, gates, the mix "

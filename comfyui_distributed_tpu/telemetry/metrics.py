@@ -109,11 +109,13 @@ LLM_TOKENS = REGISTRY.counter(
 LLM_EXPERT_SLOTS = REGISTRY.counter(
     "cdt_llm_expert_slots_total",
     "Routed expert slots (tokens x experts per token x expert layers), by "
-    "where the selected expert lives: held (on this chip, computed) or "
-    "absent (another chip of the expert group, left out), and by the "
-    "phase that routed them (prefill, decode). Counted inside the "
+    "where the selected expert lives: held (on this chip, computed), "
+    "absent (another chip of the expert group, left out) or zero (an "
+    "identity expert of the router: no weights, computed here for every "
+    "token; only a model whose router has such outputs moves it), and by "
+    "the phase that routed them (prefill, decode). Counted inside the "
     "programs and fed from their outputs; a model with no expert layer "
-    "moves neither.",
+    "moves none.",
     ("where", "phase"))
 
 LLM_CACHE_BYTES = REGISTRY.gauge(

@@ -452,6 +452,60 @@ def test_the_window_and_full_rewriters_programs_fit_beside_sdxl(chip,
     assert 5.2 < decode_gib < 6.8 and decode_gib + sdxl < 15.75 - 2.0
 
 
+def test_the_double_layer_rewriters_programs_fit_beside_sdxl(chip,
+                                                             monkeypatch):
+    """Both language programs of ``longcat-flash-omni.brief16k-sdxl8`` at
+    the cell's sizes (16 384 + 256 tokens, the published widths, 8 experts
+    held): they compile for the chip, their arguments + temporaries leave
+    room for SDXL's segment program's weights (4.79 GiB, docs/weights.md)
+    in 15.75 GiB, ``llm_prefill`` holds one call site of the causal latent
+    kernel an attention SUBLAYER — eight, what Kimi's form needs a layer
+    and no more — and writes the sixteen latent leaves where they lie: no
+    copy of one inside the scan of chunks."""
+    from comfyui_distributed_tpu.diffusion.pipeline_llm import LLMPipeline
+    from comfyui_distributed_tpu.models.llm_longcat import LongcatConfig
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    cfg = LongcatConfig.longcat_share()
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+            tree)
+
+    weights = place(cfg.model.init(cfg, None, abstract=True))
+    prefill, decode = LLMPipeline(cfg, weights).programs(16384, 256)
+    ids = jax.ShapeDtypeStruct((16384,), jnp.int32, sharding=chip)
+    logits, cache, counts, rows = jax.eval_shape(prefill.jitted, weights,
+                                                 ids)
+    assert counts.shape == (2 * cfg.num_layers,)      # [held … | zero …]
+    assert rows.shape == (cfg.num_layers,)
+    assert len(cache["c"]) == len(cache["kr"]) == 2 * cfg.num_layers == 8
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    gib, sdxl = 2.0 ** 30, 4.79
+    compiled = prefill.jitted.lower(weights, ids).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") \
+        == 2 * cfg.num_layers == 8
+    assert "latent_causal_mha" in text
+    for leaf in (cache["c"][0], cache["kr"][0]):
+        buffer = "bf16[{},{}]".format(*leaf.shape)
+        assert leaf.shape[0] == 16640
+        assert not [line for line in text.splitlines()
+                    if buffer in line.split("=")[0] and " copy(" in line]
+    mem = compiled.memory_analysis()
+    prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                   + mem.output_size_in_bytes) / gib
+    assert 8.5 < prefill_gib < 9.6 and prefill_gib + sdxl < 15.75 - 1.0
+    compiled = decode.jitted.lower(
+        weights, place(logits), place(cache), place(key),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=chip)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()     # decode is XLA
+    mem = compiled.memory_analysis()
+    decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
+    assert 7.5 < decode_gib < 8.4 and decode_gib + sdxl + 0.56 < 15.75 - 1.0
+
+
 # (id, config, text rows, batch, label): a joint block as its model's cell
 # or card runs it, 1024² (4096 image rows)
 JOINT_BLOCKS = [

@@ -214,7 +214,8 @@ LLMS = {"ling-tiny": ("llm_hybrid", "LLMConfig", 24),
         "motif-tiny": ("llm_motif", "MotifConfig", 24),
         "kimi-tiny": ("llm_kimi", "KimiConfig", 37),     # three chunks of 16
         "jamba-tiny": ("llm_jamba", "JambaConfig", 37),
-        "trinity-tiny": ("llm_trinity", "TrinityConfig", 21)}  # 2 chunks + 5
+        "trinity-tiny": ("llm_trinity", "TrinityConfig", 21),  # 2 chunks + 5
+        "longcat-tiny": ("llm_longcat", "LongcatConfig", 37)}  # three chunks
 
 PROGRAMS = {"txt2img_seg": _txt2img_seg, "flow_seg": _flow_seg, "fin": _fin}
 for _name, _how in LLMS.items():
@@ -250,6 +251,12 @@ EXPECTED = {
     "llm_prefill:trinity-tiny": {"llm_attn", "llm_router", "llm_experts",
                                  "llm_shared_ffn", "llm_head"},
     "llm_decode:trinity-tiny": {"llm_attn", "llm_router", "llm_experts",
+                                "llm_shared_ffn", "llm_head"},
+    # no shared expert: llm_shared_ffn is the two dense FFNs of a double
+    # layer; the identity experts' mix and the branch's join are llm_experts'
+    "llm_prefill:longcat-tiny": {"llm_attn", "llm_router", "llm_experts",
+                                 "llm_shared_ffn", "llm_head"},
+    "llm_decode:longcat-tiny": {"llm_attn", "llm_router", "llm_experts",
                                 "llm_shared_ffn", "llm_head"},
 }
 
