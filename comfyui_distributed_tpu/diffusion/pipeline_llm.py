@@ -90,12 +90,16 @@ class LLMPipeline:
         """``(logits, cache, key, temperature) -> (ids [new_tokens], tap
         logits [new_tokens // tap_every, V], held slots per expert layer
         (an empty vector where the model has none), finite)``:
-        ``new_tokens`` steps of the token program in one scan.
+        ``new_tokens`` steps of the token program in one scan, over the
+        model's ``decode_weights`` where it gives that form.
         ``tap_every`` is the served program's unless a parity tool builds
         a decode of its own to compare more rows."""
         n_counts = _slot_counts(self.config)
 
         def llm_decode(weights, logits, cache, key, temperature):
+            if self.model.decode_weights is not None:
+                weights = self.model.decode_weights(self.config, weights)
+
             def forward(state, token, i):
                 return self.step(weights, state, token, prompt_tokens + i)
 

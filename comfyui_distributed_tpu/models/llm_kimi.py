@@ -361,5 +361,17 @@ def decode_step(cfg: KimiConfig, params, cache: dict, token, pos):
     return logits_of(cfg, params, h), cache, _stack_counts(held)
 
 
+def decode_weights(cfg: KimiConfig, params):
+    """``params`` as a token loop hands them to every :func:`decode_step`:
+    each layer's ``w_b`` in the absorbed step's form, made once ahead of
+    the loop (``latent_attention.absorbed_form``)."""
+    def formed(p):
+        return {**p, "w_b": mla_ops.absorbed_form(p["w_b"],
+                                                  cfg.num_attention_heads)}
+
+    return {**params, "layers": [{**layer, "attn": formed(layer["attn"])}
+                                 for layer in params["layers"]]}
+
+
 MODEL = LLMModel(init_kimi, prefill, decode_step, empty_cache, cache_kinds,
-                 prefill_chunk)
+                 prefill_chunk, decode_weights)

@@ -408,5 +408,19 @@ def decode_step(cfg: LongcatConfig, params, cache: dict, token, pos):
     return logits_of(cfg, params, h), cache, _stack_counts(held + zero)
 
 
+def decode_weights(cfg: LongcatConfig, params):
+    """``params`` as a token loop hands them to every :func:`decode_step`:
+    each attention sublayer's ``w_b`` in the absorbed step's form, made
+    once ahead of the loop (``latent_attention.absorbed_form``)."""
+    def formed(p):
+        return {**p, "w_b": mla_ops.absorbed_form(p["w_b"],
+                                                  cfg.num_attention_heads)}
+
+    return {**params, "layers": [
+        {**layer, "sub": [{**sub, "attn": formed(sub["attn"])}
+                          for sub in layer["sub"]]}
+        for layer in params["layers"]]}
+
+
 MODEL = LLMModel(init_longcat, prefill, decode_step, empty_cache, cache_kinds,
-                 prefill_chunk)
+                 prefill_chunk, decode_weights)

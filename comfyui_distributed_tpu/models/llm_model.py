@@ -28,13 +28,13 @@ class LLMModel(NamedTuple):
     # the prefill's continuation, for a model whose prompt is walked in
     # chunks through the cache (None: ``prefill`` takes it whole):
     # (cfg, weights, cache, ids [C], start, n_valid, all_logits=False) ->
-    # (logits, cache, held [expert layers], rows [expert layers]); the
-    # chunk's length is ``cfg.prefill_chunk_tokens`` (a model whose cache
-    # holds a RING ties it to the ring's length), ``start`` a multiple of
-    # it, and the cache may hold three kinds of leaf: full-length rows,
-    # rings and recurrent states (what a padded chunk owes each:
-    # ``chunked_prefill``)
+    # (logits, cache, held [expert layers], rows [expert layers]); a chunk
+    # is ``cfg.prefill_chunk_tokens`` long (a RING in the cache ties it to
+    # its length), ``start`` a multiple (a padded one: ``chunked_prefill``)
     prefill_chunk: "Callable | None" = None
+    # (cfg, weights) -> weights in the form ``decode_step`` reads fastest,
+    # made ONCE ahead of a token loop (None: the loop reads them as stored)
+    decode_weights: "Callable | None" = None
 
 
 def cache_bytes(model: LLMModel, cfg, max_len: int) -> dict:

@@ -100,10 +100,10 @@ def mla_naive(q_nope, q_rope, c, k_rope, w_b, scale: float, dtype):
 
 def mla_absorbed_step(q_nope, q_rope, c_cache, kr_cache, pos, w_b,
                       scale: float, dtype):
-    """One token against the latent cache, ``W_b`` absorbed into the
-    query and the output. ``q_nope`` [H,nope], ``q_rope`` [H,r] (roped),
-    ``c_cache`` [Tmax,rank], ``kr_cache`` [Tmax,r], both already holding
-    position ``pos``; rows past ``pos`` are masked. Answers [H,v]."""
+    """One token against the latent cache, ``W_b`` (2-D as stored, or in
+    :func:`absorbed_form`) absorbed into query and output. ``q_nope``
+    [H,nope], ``q_rope`` [H,r] (roped), ``c_cache`` [Tmax,rank], ``kr_cache``
+    [Tmax,r] already hold ``pos``; rows past it are masked. Answers [H,v]."""
     H, nope = q_nope.shape
     w = w_b.reshape(w_b.shape[0], H, -1).astype(dtype)   # [rank,H,nope+v]
     q_c = jnp.einsum("hd,chd->hc", q_nope.astype(dtype), w[..., :nope],
@@ -308,3 +308,16 @@ def mla_chunk_attention(q_nope, q_rope, c_cache, kr_cache, start, w_b,
         kr_pad, start, num_heads=H, block_q=bq, block_k=block_k,
         interpret=kernel == "interpret")
     return o.reshape(C, H, v)
+
+
+def absorbed_form(w_b, num_heads: int):
+    """``W_b`` [rank, H·(nope+v)] as :func:`mla_absorbed_step` reads it:
+    ``[rank, H, nope+v]``. The stored leaf is tiled eight RANK rows by 128
+    columns and the step's two products want eight HEADS by 128, so the
+    reshape moves every byte. Inside a token loop the compiler re-tiles
+    the matrix at every token (16.8 MB read and written a sublayer at 64
+    heads: PERF.md §6, PR 45); a program that loops over steps calls this
+    ONCE ahead of its loop — the barrier keeps the reshape there — and the
+    loop's body reads the copy. Prefill reads the leaf as it lies."""
+    return jax.lax.optimization_barrier(
+        w_b.reshape(w_b.shape[0], num_heads, -1))

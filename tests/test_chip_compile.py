@@ -25,8 +25,10 @@ compilation cache is off around the compiles: an entry written for a
 described device cannot be read back without one, and only warns.
 """
 
+import importlib.util
 import os
 import re
+from pathlib import Path
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 # describing a topology loads libtpu, which one process at a time may do
@@ -42,6 +44,12 @@ from jax.sharding import SingleDeviceSharding
 from comfyui_distributed_tpu.ops import attention as attn
 from comfyui_distributed_tpu.ops import autotune
 from comfyui_distributed_tpu.ops import flash_attention as fa
+
+_spec = importlib.util.spec_from_file_location(
+    "loop_copies",
+    Path(__file__).resolve().parent.parent / "scripts" / "loop_copies.py")
+loop_copies = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(loop_copies)
 
 TABLE = autotune.TuningTable(shipped=True, path="/nonexistent/none.json",
                              autoload=True).entries()
@@ -132,23 +140,16 @@ def test_table_has_the_rows_the_main_paths_select():
     assert {c.tier for _, c in PALLAS_ROWS.values()} == {"packed"}
 
 
-_COMPUTATION = re.compile(r"^(?:ENTRY )?(%?[\w.\-]+) \(.*\) -> .* \{$")
 _FF_ERFC = re.compile(r'op_name="[^"]*/ff/[^"]*erfc')
+_computations = loop_copies.computations
 
 
-def _computations(text):
-    """``{name: [instruction lines]}`` of a compiled module's text."""
-    out, name = {}, None
-    for line in map(str.strip, text.splitlines()):
-        head = _COMPUTATION.match(line)
-        if name is None and head:
-            name = head.group(1)
-            out[name] = []
-        elif line == "}":
-            name = None
-        elif name is not None:
-            out[name].append(line)
-    return out
+def _copy_sizes(text, looped):
+    """Bytes of each copy of 1 MiB or more a compiled module holds inside
+    its while bodies — paid at every trip — or (``looped`` false) outside
+    them, as ``scripts/loop_copies.py`` lists them."""
+    return [size for _, where, size, _ in loop_copies.large_copies(text)
+            if where == looped]
 
 
 def _assert_gelu_is_a_products_epilogue(text):
@@ -461,7 +462,9 @@ def test_the_double_layer_rewriters_programs_fit_beside_sdxl(chip,
     in 15.75 GiB, ``llm_prefill`` holds one call site of the causal latent
     kernel an attention SUBLAYER — eight, what Kimi's form needs a layer
     and no more — and writes the sixteen latent leaves where they lie: no
-    copy of one inside the scan of chunks."""
+    copy of one inside the scan of chunks. ``W_b`` (16.8 MB a sublayer) is
+    copied nowhere in ``llm_prefill`` and once a sublayer AHEAD of
+    ``llm_decode``'s token loop, whose body copies nothing of 1 MiB."""
     from comfyui_distributed_tpu.diffusion.pipeline_llm import LLMPipeline
     from comfyui_distributed_tpu.models.llm_longcat import LongcatConfig
 
@@ -497,13 +500,57 @@ def test_the_double_layer_rewriters_programs_fit_beside_sdxl(chip,
     prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                    + mem.output_size_in_bytes) / gib
     assert 8.5 < prefill_gib < 9.6 and prefill_gib + sdxl < 15.75 - 1.0
+    w_b = weights["layers"][0]["sub"][0]["attn"]["w_b"]
+    w_b_bytes = w_b.size * w_b.dtype.itemsize
+    assert w_b_bytes == 512 * 16384 * 2
+    assert w_b_bytes not in _copy_sizes(text, True) + _copy_sizes(text,
+                                                                  False)
     compiled = decode.jitted.lower(
         weights, place(logits), place(cache), place(key),
         jax.ShapeDtypeStruct((), jnp.float32, sharding=chip)).compile()
-    assert "tpu_custom_call" not in compiled.as_text()     # decode is XLA
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text                   # decode is XLA
+    assert not _copy_sizes(text, True)
+    assert _copy_sizes(text, False).count(w_b_bytes) == 2 * cfg.num_layers
     mem = compiled.memory_analysis()
     decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
     assert 7.5 < decode_gib < 8.4 and decode_gib + sdxl + 0.56 < 15.75 - 1.0
+
+
+# (preset, prompt + new tokens of its cell, copies of W_b ahead of the token
+# loop, copies of it inside)
+LATENT_DECODERS = [("kimi-k2.6", 32768, 128, 5, 0),
+                   ("ling-3.0-flash-vl", 512, 1024, 0, 1)]
+
+
+@pytest.mark.parametrize("case", LATENT_DECODERS, ids=lambda c: c[0])
+def test_a_latent_decoders_token_loop_copies_no_matrix_through_hbm(
+        chip, case, monkeypatch):
+    """``llm_decode`` of the other two rewriters that decode through
+    ``mla_absorbed_step``, alone, at their cells' sizes. Kimi's model gives
+    ``decode_weights``: the re-tiling of its five ``W_b`` stands in the
+    entry computation, once a request, and the while body copies nothing
+    of 1 MiB (until PR 45: five 16.8 MB copies a token). Ling's does not:
+    its one 8.4 MB copy stays in the loop because its RESULT lies in VMEM
+    (``S(1)``) — the copy is the product's operand fetch, the matrix is
+    read from HBM once a token — and the form made ahead of the loop read
+    1.8345 ms a token against 1.8127 on the chip (PERF.md §6, PR 45). If
+    that copy ever lands in HBM, measure again."""
+    from comfyui_distributed_tpu.models.registry import PRESETS
+
+    preset, prompt_tokens, new_tokens, ahead, inside = case
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    cfg = PRESETS[preset].llm
+    compiled = loop_copies.compiled_programs(
+        cfg, prompt_tokens, new_tokens, chip, ["llm_decode"])["llm_decode"]
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    w_b_bytes = 2 * cfg.kv_lora_rank * cfg.num_attention_heads \
+        * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+    assert _copy_sizes(text, True) == [w_b_bytes] * inside
+    assert all("S(1)" in shape for _, looped, _, shape
+               in loop_copies.large_copies(text) if looped)
+    assert _copy_sizes(text, False).count(w_b_bytes) == ahead
 
 
 # (id, config, text rows, batch, label): a joint block as its model's cell

@@ -101,7 +101,8 @@ def test_chunked_delta_rule_survives_the_gates_lower_bound():
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(S).all())
 
 
-def test_absorbed_mla_is_the_naive_one():
+@pytest.mark.parametrize("form", ["stored", "absorbed"])
+def test_absorbed_mla_is_the_naive_one(form):
     T, H, nope, r, rank, dv = 12, 2, 8, 4, 16, 8
     ks = jax.random.split(jax.random.key(3), 5)
     q_nope = jax.random.normal(ks[0], (T, H, nope))
@@ -112,6 +113,8 @@ def test_absorbed_mla_is_the_naive_one():
     naive = latent_attention.mla_naive(q_nope, q_rope, c, kr, w_b, 0.3,
                                        jnp.float32)
     pad = lambda x: jnp.concatenate([x, jnp.full((5, x.shape[1]), 9.0)])
+    if form == "absorbed":          # what a token loop makes ahead of its steps
+        w_b = latent_attention.absorbed_form(w_b, H)
     for t in (0, 5, T - 1):        # rows past t are masked, whatever they hold
         step = latent_attention.mla_absorbed_step(
             q_nope[t], q_rope[t], pad(c), pad(kr), t, w_b, 0.3, jnp.float32)

@@ -185,17 +185,64 @@ def test_rows_above_the_chunk_are_never_read():
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_the_absorbed_step_is_the_naive_attention_rows_last():
-    H, nope, rope, v, rank, n = 4, 8, 8, 8, 16, 11
+# (H, nope, v, rank): the tiny preset's; Kimi's and LongCat's heads at
+# their published widths; Ling's 32; a value narrower than the key (the
+# Pallas prefill kernel needs nope == v, the decode step never did)
+STEP_GEOMETRIES = [(4, 8, 8, 16), (64, 128, 128, 64), (32, 128, 128, 64),
+                   (4, 16, 8, 16)]
+
+
+@pytest.mark.parametrize("form", ["stored", "absorbed"])
+@pytest.mark.parametrize("geometry", STEP_GEOMETRIES,
+                         ids=lambda g: "h{}.nope{}.v{}.rank{}".format(*g))
+def test_the_absorbed_step_is_the_naive_attention_rows_last(geometry, form):
+    """``W_b`` as the weight tree stores it, and in the form a token loop
+    makes of it once ahead of its steps (``absorbed_form``): the same
+    answer, float32 to 1e-5."""
+    H, nope, v, rank = geometry
+    rope, n = 8, 11
     q_nope, q_rope, c, k_rope, w_b = _attention_case(
         jax.random.key(5), n, H, nope, rope, v, rank)
     want = latent_attention.mla_naive(q_nope, q_rope, c, k_rope, w_b, 0.3,
                                       jnp.float32)[-1]
+    assert want.shape == (H, v)
     cache = jnp.zeros((16, rank)).at[:n].set(c)
     kr = jnp.zeros((16, rope)).at[:n].set(k_rope)
+    if form == "absorbed":
+        w_b = latent_attention.absorbed_form(w_b, H)
+        assert w_b.shape == (rank, H, nope + v)
     got = latent_attention.mla_absorbed_step(
         q_nope[-1], q_rope[-1], cache, kr, n - 1, w_b, 0.3, jnp.float32)
     assert close(got, want, 1e-5)
+
+
+@pytest.mark.parametrize("preset", ["kimi-tiny", "longcat-tiny"])
+def test_decode_over_the_form_made_ahead_of_the_loop_is_bit_equal(preset):
+    """``llm_decode`` of a model that gives ``decode_weights`` — bfloat16,
+    as the cells run it — against the same program reading the stored
+    leaves inside the loop (what it was until PR 45): the same ids and the
+    same logits, bit for bit, and the form is made once a latent sublayer
+    AHEAD of the scan (no ``w_b``-sized reshape inside it)."""
+    from comfyui_distributed_tpu.models.registry import PRESETS
+
+    cfg = dataclasses.replace(PRESETS[preset].llm, dtype="bfloat16")
+    assert cfg.model.decode_weights is not None
+    params = cfg.model.init(cfg, jax.random.key(0))
+    ids = jax.random.randint(jax.random.key(1), (20,), 0, cfg.vocab_size)
+    pipe = pipeline_llm.LLMPipeline(cfg, params)
+    logits, cache, *_ = pipe.prefill_fn(20, 12)(ids)
+    stored = pipeline_llm.LLMPipeline(cfg, params)
+    stored.model = cfg.model._replace(decode_weights=None)
+    args = (logits, cache, jax.random.key(3), jnp.asarray(0.7, jnp.float32))
+    got = pipe.decode_fn(20, 12, tap_every=3)(*args)
+    want = stored.decode_fn(20, 12, tap_every=3)(*args)
+    assert got[1].shape == (4, cfg.vocab_size)
+    for a, b in zip(got, want):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    text = str(jax.make_jaxpr(pipe.decode_fn(20, 12).jitted)(params, *args))
+    ahead, loop = text.split("scan[", 1)
+    assert ahead.count("optimization_barrier") == len(cache["c"]) >= 4
+    assert "optimization_barrier" not in loop
 
 
 def test_yarns_table_is_the_closed_form_at_hand_checked_indices():
