@@ -12,7 +12,9 @@ Mix(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``), a final RMS norm, logits
 W_in``; ``u ← silu(causal depthwise conv(u) + b_conv)``; ``[δ | B | C] = u
 W_x``; ``δ``, ``B``, ``C`` each RMS-normed with a weight of its own; ``Δ =
 softplus(δ W_dt + b_dt)``; ``A = −exp(A_log)``; the recurrence and the gate
-are ``ops/selective_scan.py``'s; out ``= y W_out``. **Attention**:
+are ``ops/selective_scan.py``'s, and so is a prefill chunk's convolution
+(both read their half of ``[u | z]`` where ``W_in``'s product wrote it);
+out ``= y W_out``. **Attention**:
 ``num_attention_heads`` query heads over ``num_key_value_heads`` = 1 key
 and value head (``ops/gqa_attention.py``: one group), scale ``d^−½``, no
 bias.
@@ -291,15 +293,22 @@ def _mamba_chunk(cfg: JambaConfig, layer, h, tail, state, n_valid, kernel):
     C, p = h.shape[0], layer["ssm"]
     x = _pre_norm(h, layer["norm1"], cfg.rms_norm_eps)
     with device_scope("llm_ssm"):
+        # uz whole to both kernels: each reads its half where the product
+        # wrote it (a sliced operand of a Pallas call is a copy)
         uz = _dot(x, p["w_in"], dtype)
-        padded = jnp.concatenate([tail, uz[:, :Di]], 0)
-        u = jax.nn.silu(sum(padded[j:j + C] * p["conv_w"][j]
-                            for j in range(K)) + p["conv_b"])
-        # the inputs of tokens n_valid − (K−1) .. n_valid − 1
-        tail = jax.lax.dynamic_slice_in_dim(padded, n_valid, K - 1, 0)
+        u = selective_scan.conv_chunk(tail, uz, p["conv_w"], p["conv_b"],
+                                      kernel)
+        # the inputs of tokens n_valid − (K−1) .. n_valid − 1: of the old
+        # tail and the K−1 rows of uz that end at n_valid (not of the whole
+        # [tail; u half], which the compiler would fetch to take three rows)
+        last = min(K - 1, C)
+        lo = jnp.maximum(n_valid - last, 0)
+        tail = jax.lax.dynamic_slice_in_dim(jnp.concatenate(
+            [tail, jax.lax.dynamic_slice(uz, (lo, 0), (last, Di))], 0),
+            n_valid - lo, K - 1, 0)
         dt, B, Cm, A = _scan_inputs(cfg, p, u)
-        y, state = selective_scan.scan_chunk(state, u, dt, uz[:, Di:], B, Cm,
-                                             A, p["d"], n_valid, kernel)
+        y, state = selective_scan.scan_chunk(state, u, dt, uz, B, Cm, A,
+                                             p["d"], n_valid, kernel)
         h = h + _dot(y, p["w_out"], dtype)
     return _ffn(cfg, layer, h), tail, state
 

@@ -144,6 +144,15 @@ _FF_ERFC = re.compile(r'op_name="[^"]*/ff/[^"]*erfc')
 _computations = loop_copies.computations
 
 
+def _pallas_calls(text, jitted=None):
+    """The Pallas call lines of a compiled module's text (those traced
+    under ``jit(<jitted>)`` where a name is given): each carries its
+    operands' shapes as ``operand_layout_constraints``."""
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and (jitted is None or f"jit({jitted})/pallas_call" in line)]
+
+
 def _copy_sizes(text, looped):
     """Bytes of each copy of 1 MiB or more a compiled module holds inside
     its while bodies — paid at every trip — or (``looped`` false) outside
@@ -283,7 +292,8 @@ def test_the_latent_causal_kernel_compiles_at_the_served_geometry(chip):
 def test_the_selective_scan_kernel_compiles_at_the_served_geometry(chip):
     """``ops/selective_scan.py`` as the state-space rewriter's prefill calls
     it: a 4096-token chunk of 5120 channels and 16 states, the blocks the
-    module ships."""
+    module ships, the gate handed over as the ``[u | z]`` product whole
+    (``f32[4096, 10240]``) — and the Pallas call takes it as it is (PR 46)."""
     from comfyui_distributed_tpu.ops import selective_scan as ss
 
     T, d, N = 4096, 5120, 16
@@ -292,10 +302,34 @@ def test_the_selective_scan_kernel_compiles_at_the_served_geometry(chip):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
 
     lowered = ss.selective_scan.lower(
-        arg(d, N), arg(T, d), arg(T, d), arg(T, d), arg(T, N), arg(T, N),
+        arg(d, N), arg(T, d), arg(T, d), arg(T, 2 * d), arg(T, N), arg(T, N),
         arg(d, N), arg(d), block_t=ss.BLOCK_T, block_d=ss.BLOCK_D,
         unroll=ss.UNROLL)
-    assert "tpu_custom_call" in lowered.compile().as_text()
+    text = lowered.compile().as_text()
+    call, = _pallas_calls(text, "selective_scan")
+    assert "f32[4096,10240]{1,0}" in call
+    assert not [move for move in loop_copies.large_moves(text)
+                if move[0] == "slice"]
+
+
+def test_the_convolution_kernel_compiles_at_the_served_geometry(chip):
+    """``selective_scan.causal_conv_silu`` as the same prefill calls it: the
+    four taps and the silu over the left half of the ``[u | z]`` product,
+    handed over whole, in the blocks the module ships."""
+    from comfyui_distributed_tpu.ops import selective_scan as ss
+
+    T, d, K = 4096, 5120, 4
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+
+    text = ss.causal_conv_silu.lower(
+        arg(K - 1, d), arg(T, 2 * d), arg(K, d), arg(d),
+        block_t=ss.CONV_BLOCK_T, block_d=ss.CONV_BLOCK_D).compile().as_text()
+    call, = _pallas_calls(text, "causal_conv_silu")
+    assert "f32[4096,10240]{1,0}" in call
+    assert not [move for move in loop_copies.large_moves(text)
+                if move[0] == "slice"]
 
 
 def test_the_shared_kv_causal_kernel_compiles_at_the_served_geometry(chip):
@@ -331,9 +365,9 @@ def test_the_state_space_rewriters_programs_fit_beside_sdxl(chip,
     cell's sizes (65 536 + 128 tokens, the WHOLE model): they compile for
     the chip, their arguments + temporaries leave room for SDXL's segment
     program (4.79 + 0.56 GiB, docs/weights.md) in 15.75 GiB, and the
-    program holds one Pallas call site a run of Mamba layers and one an
-    attention layer — five — not one a layer (28+), so a warm set-up
-    re-lowers five kernels (PERF.md §2)."""
+    program holds two Pallas call sites a run of Mamba layers (the
+    convolution and the scan) and one an attention layer — eight — not two
+    a layer (54+), so a warm set-up re-lowers eight kernels (PERF.md §2)."""
     from comfyui_distributed_tpu.diffusion.pipeline_llm import LLMPipeline
     from comfyui_distributed_tpu.models.llm_jamba import JambaConfig
 
@@ -352,8 +386,8 @@ def test_the_state_space_rewriters_programs_fit_beside_sdxl(chip,
     key = jax.eval_shape(lambda: jax.random.key(0))
     gib, sdxl = 2.0 ** 30, 4.79 + 0.56
     compiled = prefill.jitted.lower(weights, ids).compile()
-    assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") \
-        == len(cfg.mamba_runs) + len(cfg.attention_layers) == 5
+    assert len(_pallas_calls(compiled.as_text())) \
+        == 2 * len(cfg.mamba_runs) + len(cfg.attention_layers) == 8
     mem = compiled.memory_analysis()
     prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
     assert 5.6 < prefill_gib < 7.0 and prefill_gib + sdxl < 15.75 - 2.0
@@ -364,6 +398,38 @@ def test_the_state_space_rewriters_programs_fit_beside_sdxl(chip,
     mem = compiled.memory_analysis()
     decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
     assert 5.6 < decode_gib < 6.5 and decode_gib + sdxl < 15.75 - 2.0
+
+
+def test_a_mamba_layer_hands_both_kernels_uz_where_w_in_wrote_it(
+        chip, monkeypatch):
+    """``llm_prefill`` of the state-space rewriter at its cell's 65 536
+    tokens: in every run of Mamba layers the ``[u | z]`` product's
+    ``f32[4096,10240]`` output reaches BOTH Pallas calls whole — the
+    convolution reads its left half, the selective scan its right — and
+    nothing, on the core or as an asynchronous ``slice-start``, alone or in
+    a fusion of nothing but slices, copies either half. Until PR 46 a
+    two-way slice fusion read the product's 168 MB and wrote both halves a
+    layer a chunk, 416 times a request (0.153 s), because the kernel was
+    handed ``uz[:, Di:]`` and an operand of a Pallas call is an array of its
+    own. Nor does XLA broadcast ``B`` / ``C`` to ``[4096,16,128]`` in HBM
+    any more (two 33.6 MB arrays a layer a chunk): the scan kernel spreads
+    them along the lanes in VMEM (PERF.md §6, PR 46)."""
+    from comfyui_distributed_tpu.models.registry import PRESETS
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    cfg = PRESETS["ai21-jamba2-3b"].llm
+    text = loop_copies.compiled_programs(
+        cfg, 65536, 128, chip, ["llm_prefill"])["llm_prefill"].as_text()
+    runs = sum(1 for n in cfg.mamba_runs if n)
+    for name in ("causal_conv_silu", "selective_scan"):
+        calls = _pallas_calls(text, name)
+        assert len(calls) == runs == 3
+        assert all("f32[4096,10240]{1,0}" in call for call in calls)
+    moves = loop_copies.large_moves(text, 32 * 2 ** 20)
+    assert not [source for kind, *_, source in moves
+                if kind == "slice" and source.startswith("f32[4096,10240]")]
+    assert not [shape for kind, _, looped, _, shape, _ in moves
+                if kind == "broadcast" and looped]
 
 
 @pytest.mark.parametrize("window", [None, 4096])

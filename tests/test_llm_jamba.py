@@ -107,6 +107,25 @@ def test_padded_rows_leave_state_and_conv_tails_untouched(params, ids,
     assert not close(ran_on["ssm"], exact["ssm"], 1e-3)
 
 
+@pytest.mark.parametrize("kernel", ["lax", "interpret"])
+@pytest.mark.parametrize("last", [1, 2, 3, 16])
+def test_a_last_chunk_shorter_than_the_convolution_keeps_older_tail_rows(
+        params, ids, kernel, last):
+    """The tail after a chunk is read from the old tail and the K−1 rows
+    of ``[u | z]`` that end at ``n_valid`` (PR 46: not from the whole u
+    half): with one or two real rows in the last chunk it still holds rows
+    the chunk BEFORE left: what unpadded chunks leave."""
+    n = 16 + last
+    _, padded, _, _ = llm_model.chunked_prefill(
+        J.MODEL, CFG, params, ids[:n], n, False, 16, kernel=kernel)
+    cache = J.prefill_chunk(CFG, params, J.empty_cache(CFG, n), ids[:16], 0,
+                            16, kernel=kernel)[1]
+    exact = J.prefill_chunk(CFG, params, cache, ids[16:n], 16, last,
+                            kernel="lax")[1]
+    assert close(padded["conv"], exact["conv"], 1e-5)
+    assert close(padded["ssm"], exact["ssm"], 1e-5)
+
+
 def test_a_chunk_continues_from_the_cache_the_chunks_before_it_left(
         params, ids, full_logits):
     cache = J.empty_cache(CFG, 48)
