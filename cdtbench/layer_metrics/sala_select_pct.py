@@ -1,0 +1,8 @@
+"""The scope is in ``sala_select_pct.json``; the reader is
+``cdtbench/kinds/sala.py: scope_pct``."""
+
+from cdtbench.kinds.sala import scope_pct
+
+
+def read(ctx):
+    return scope_pct(ctx, "select")

@@ -1017,6 +1017,10 @@ class TPUPromptRewrite(NodeDef):
                 for (kind, phase), n in pairs(prompt_tokens,
                                               new_tokens).items():
                     _tm.LLM_ATTN_KEYS.labels(layers=kind, phase=phase).inc(n)
+            blocks = getattr(cfg, "selected_blocks", None)
+            if blocks is not None:    # attention over a selection of blocks
+                for kind, n in blocks(prompt_tokens, new_tokens).items():
+                    _tm.LLM_SELECT_BLOCKS.labels(kind=kind).inc(n)
             if cfg.moe_layers:
                 _tm.LLM_EXPERT_ROWS.labels(form=out["prefill_form"]).inc(
                     out["rows_prefill"])

@@ -211,7 +211,11 @@ def _llm_preset(name: str, family: str, tiny: bool = False):
     """A language model of ``family``: at its published widths (this
     chip's share, or the whole model where it fits), or its tiny float32
     form for the CPU."""
-    if family == "longcat":
+    if family == "sala":
+        from .llm_sala import SalaConfig
+
+        share = SalaConfig.tiny if tiny else SalaConfig.sala_cut
+    elif family == "longcat":
         from .llm_longcat import LongcatConfig
 
         share = LongcatConfig.tiny if tiny else LongcatConfig.longcat_share
@@ -273,6 +277,8 @@ PRESETS: dict[str, ModelPreset] = {
     "trinity-tiny": _llm_preset("trinity-tiny", "trinity", tiny=True),
     "longcat-flash-omni": _llm_preset("longcat-flash-omni", "longcat"),
     "longcat-tiny": _llm_preset("longcat-tiny", "longcat", tiny=True),
+    "minicpm-sala": _llm_preset("minicpm-sala", "sala"),
+    "sala-tiny": _llm_preset("sala-tiny", "sala", tiny=True),
 }
 
 

@@ -652,3 +652,10 @@ def joint_attention(txt, img, num_heads: int, prefer_flash: bool = False,
         B = out.shape[0]
         return (out[:, :T].reshape(B, T, num_heads * D),
                 out[:, T:].reshape(B, N, num_heads * D))
+
+
+# a later kernel's tier, added where it moves no line of the code above
+# (ops/block_select_attention.py: the K/V tile index comes from a
+# prefetched table of each query tile's selected blocks)
+CAUSAL_TIER_REASONS["block_select"] = (
+    "chunked prefill over the key blocks each query selects")

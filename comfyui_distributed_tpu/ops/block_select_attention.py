@@ -1,0 +1,379 @@
+"""Causal attention over a SELECTION of key blocks (InfLLM-v2's rule):
+every query chooses, per key/value group, which ``block_size``-row blocks
+of the cache it reads, by scoring COMPRESSED keys.
+
+For the query at position ``t`` and key/value group ``g`` (``J`` heads):
+
+1. compressed key ``K̄_j = mean(K[stride·j : stride·j + kernel])`` for every
+   ``j`` whose ``kernel`` rows all lie at or below ``t`` (``kernel = 2 ·
+   stride``: a window every ``stride`` rows, each row in two);
+2. ``p_h = softmax_j(q_h · K̄_j · scale)`` a head, ``s_g[j] = Σ_{h∈g} p_h[j]``;
+3. block score ``B_g[b] = max`` of ``s_g`` over the windows that touch rows
+   ``block·b … block·b + block − 1`` (``block / stride + 1`` of them);
+4. block 0 (``init_blocks``) and the ``window / block`` blocks that end at
+   the query's own are FORCED (``+∞``), blocks past its own excluded
+   (``−∞``); the table is the top ``topk + window / block`` of ``B_g``;
+5. softmax attention of each head over the rows ``≤ t`` of those blocks.
+
+**The compressed-key cache** (:func:`compress_chunk`, :func:`compress_step`)
+holds ``K̄_j`` at SLOT ``j + 1``: slot ``s`` is complete once row ``stride ·
+(s + 1) − 1`` is written, a chunk that starts at ``start`` writes exactly
+the slots ``start / stride …`` (slot 0 never holds a window), and block
+``b`` is touched by the slots ``per·b … per·b + per`` (``per = block /
+stride``). A padded chunk writes only the slots its ``n_valid`` rows
+complete — the buffer advances at a ``stride``-th of the token rate, and a
+window that spans two chunks is completed by the second.
+
+**Scores and the selection are float32** (:func:`block_scores`,
+:func:`select`); the selection is the ``table`` best blocks, ties to the
+lower index (two neighbouring blocks share the window that straddles them,
+so equal scores are the rule's own, not an accident of rounding).
+
+**The chunk form** (:func:`sparse_chunk`) serves ``block_q`` NEIGHBOURING
+queries' ``J · block_q`` head rows a tile: what neighbours share is the
+tile's UNION of blocks — an ascending list a tile, handed to the Pallas
+kernel (:func:`block_select_mha`) as a scalar-prefetched table that drives
+the K and V tile index (``blocks_per_step`` tiles a grid step, each a
+BlockSpec of the same cache); a per-(query, union entry) mask says which
+entries are each query's own, and entries past a tile's count are skipped
+(their index repeats the last, so nothing is fetched). Neighbours that
+choose alike cost what they selected; neighbours that choose apart cost
+their union, never more than the causal prefix. ``lax``: the masked
+softmax over all rows, plainly — what the kernel is held to.
+:func:`sparse_step` is one decoded token: it GATHERS its table's blocks.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import flash_attention
+from .flash_attention import NEG_INF
+from .flash_latent import (_VMEM_LIMIT_BYTES, _accumulate, _init_running,
+                           _precision_of, _running_scratch)
+
+
+class Selection(NamedTuple):
+    """The sizes of the rule (a model config's fields)."""
+    kernel_size: int = 32
+    kernel_stride: int = 16
+    block_size: int = 64
+    init_blocks: int = 1
+    window_size: int = 2048
+    topk: int = 64
+
+    @property
+    def per(self) -> int:
+        """Compressed slots a block."""
+        return self.block_size // self.kernel_stride
+
+    @property
+    def local_blocks(self) -> int:
+        return self.window_size // self.block_size
+
+    @property
+    def table(self) -> int:
+        """Blocks a query reads at most: ``topk`` with the initial ones
+        among them, the local ones on top."""
+        return self.topk + self.local_blocks
+
+    def check(self) -> None:
+        if self.kernel_size != 2 * self.kernel_stride \
+                or self.block_size % self.kernel_stride \
+                or self.window_size % self.block_size:
+            raise ValueError(
+                "the compressed cache is written for windows of two "
+                "strides and blocks of whole strides")
+
+
+# --- the compressed-key cache ------------------------------------------------
+
+
+def compress_chunk(kc, k_cache, k_chunk, start, n_valid, sel: Selection):
+    """``kc`` [G, Sc, d] with the slots a chunk completes written:
+    ``k_chunk`` [G, C, d] are rows ``start …`` (``start`` a multiple of the
+    stride), ``k_cache`` [G, S, d] holds the rows below them, the first
+    ``n_valid`` rows of the chunk are real."""
+    G, C, d = k_chunk.shape
+    st = sel.kernel_stride
+    n = -(-C // st)
+    prev = jax.lax.dynamic_slice(
+        k_cache, (0, jnp.maximum(start - st, 0), 0), (G, st, d))
+    rows = jnp.concatenate([prev, k_chunk], axis=1).astype(jnp.float32)
+    rows = jnp.pad(rows, ((0, 0), (0, n * st - C), (0, 0)))
+    halves = rows.reshape(G, n + 1, st, d).sum(axis=2)
+    mean = (halves[:, :-1] + halves[:, 1:]) / sel.kernel_size
+    slot = start // st + jnp.arange(n)
+    done = (slot >= 1) & (st * (slot + 1) <= start + n_valid)
+    at = (0, start // st, 0)
+    old = jax.lax.dynamic_slice(kc, at, (G, n, d))
+    return jax.lax.dynamic_update_slice(
+        kc, jnp.where(done[None, :, None], mean.astype(kc.dtype), old), at)
+
+
+def compress_step(kc, k_cache, pos, sel: Selection):
+    """``kc`` after the token at ``pos`` wrote its key row into ``k_cache``
+    [G, S, d]: one more slot where that row completes a window."""
+    G, _, d = k_cache.shape
+    st, ks = sel.kernel_stride, sel.kernel_size
+    done = ((pos + 1) % st == 0) & (pos + 1 >= ks)
+    rows = jax.lax.dynamic_slice(
+        k_cache, (0, jnp.maximum(pos + 1 - ks, 0), 0), (G, ks, d))
+    mean = rows.astype(jnp.float32).mean(axis=1, keepdims=True)
+    at = (0, jnp.maximum((pos + 1) // st - 1, 0), 0)
+    old = jax.lax.dynamic_slice(kc, at, (G, 1, d))
+    return jax.lax.dynamic_update_slice(
+        kc, jnp.where(done, mean.astype(kc.dtype), old), at)
+
+
+# --- scores and the selection ------------------------------------------------
+
+
+def block_scores(q, kc, pos, scale: float, dtype, sel: Selection):
+    """``q`` [Q, H, d] (normed, unscaled) at positions ``pos`` [Q] against
+    the compressed cache ``kc`` [G, Sc, d] (``Sc`` a multiple of ``per``):
+    float32 ``[G, Q, Sc / per]`` block scores, forced blocks ``+∞``, blocks
+    past a query's own ``−∞``."""
+    Q, H, d = q.shape
+    G, Sc, _ = kc.shape
+    per, st = sel.per, sel.kernel_stride
+    nb = Sc // per
+    J = H // G
+    # a group's head rows stacked [G, Q·J, d]: one plain product a group
+    # whose minor axis is the slots (a [G,Q,J,Sc] einsum is laid out with
+    # the queries minor on the chip, and its softmax crawls)
+    qg = jnp.swapaxes(q.reshape(Q, G, J, d), 0, 1).reshape(G, Q * J, d)
+    logits = jnp.einsum("gmd,gsd->gms", (qg * scale).astype(dtype),
+                        kc.astype(dtype),
+                        preferred_element_type=jnp.float32)
+    slot = jnp.arange(Sc)
+    whole = (slot[None, :] >= 1) \
+        & (st * (slot[None, :] + 1) <= pos[:, None] + 1)      # [Q,Sc]
+    whole = jnp.repeat(whole, J, axis=0)[None]                # [1,Q·J,Sc]
+    # the softmax written out, its two row reductions behind a barrier:
+    # left to itself the chip's compiler turns ``x − max(x)`` and ``e /
+    # sum(e)`` into reduce-windows as wide as two rows (1.9 s a layer a
+    # prefill: PERF.md §6, PR 47). A row with no whole window reads 0.
+    logits = jnp.where(whole, logits, NEG_INF)
+    top = jax.lax.optimization_barrier(logits.max(axis=-1, keepdims=True))
+    e = jnp.where(whole, jnp.exp(logits - top), 0.0)
+    norm = jax.lax.optimization_barrier(e.sum(axis=-1, keepdims=True))
+    s = (e / jnp.maximum(norm, 1e-30)).reshape(G, Q, J, Sc).sum(axis=2)
+    a = s.reshape(G, Q, nb, per)
+    after = jnp.concatenate(
+        [a[:, :, 1:, 0], jnp.zeros((G, Q, 1), jnp.float32)], axis=2)
+    score = jnp.maximum(a.max(axis=-1), after)
+    own = (pos // sel.block_size)[None, :, None]
+    b = jnp.arange(nb)[None, None, :]
+    forced = (b < sel.init_blocks) | (b > own - sel.local_blocks)
+    return jnp.where(b > own, -jnp.inf, jnp.where(forced, jnp.inf, score))
+
+
+def select(score, sel: Selection):
+    """The blocks each (group, query) reads: ``[G, Q, nb]`` bool — the
+    ``table`` best of the scores, never an excluded one. Neighbouring
+    blocks SHARE the window that straddles them, so equal scores are
+    common: among blocks tied at the last place the lower index wins, as
+    ``lax.top_k`` and a stable ``argsort`` have it."""
+    table = min(sel.table, score.shape[-1])
+    kth = jax.lax.top_k(score, table)[0][..., -1:]
+    above, tied = score > kth, score == kth
+    room = table - above.sum(axis=-1, keepdims=True)
+    first = jnp.cumsum(tied, axis=-1) <= room
+    return (above | (tied & first)) & (score > -jnp.inf)
+
+
+# --- the chunk form ------------------------------------------------------------
+
+
+def _block_select_kernel(union_ref, count_ref, pos_ref, q_ref, *refs,
+                         block_q: int, block: int, per_step: int,
+                         heads: int, tiles: int, union_len: int, precision):
+    k_refs, v_refs = refs[:per_step], refs[per_step:2 * per_step]
+    mask_ref, o_ref, m_ref, l_ref, acc_ref = refs[2 * per_step:]
+    g, i, e = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    tile = g * tiles + i
+    _init_running(e, m_ref, l_ref, acc_ref)
+
+    @pl.when(e * per_step < count_ref[tile])
+    def _step():
+        width = per_step * block
+        k = jnp.concatenate([r[0] for r in k_refs], axis=0)
+        v = jnp.concatenate([r[0] for r in v_refs], axis=0)
+        s = jax.lax.dot_general(q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                                precision=precision)
+        # which columns each QUERY of the tile may read: its own entries
+        # of the union, and in them the rows at or below its position
+        own = mask_ref[0, 0, 0]                               # [block_q, R]
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+        mine = jnp.zeros((block_q, width), jnp.float32)
+        col_pos = jnp.zeros((1, width), jnp.int32)
+        for r in range(per_step):
+            here = (col // block) == r
+            mine = jnp.where(here, own[:, r:r + 1], mine)
+            first = union_ref[tile * union_len + e * per_step + r] * block
+            col_pos = jnp.where(here, first + col % block, col_pos)
+        row_pos = pos_ref[0] + i * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0)
+        seen = jnp.where(col_pos <= row_pos, mine, 0.0)
+        seen = jnp.concatenate([seen] * heads, axis=0)        # head-major
+        _accumulate(jnp.where(seen > 0.5, s, NEG_INF), v, m_ref, l_ref,
+                    acc_ref, precision)
+
+    @pl.when(e == pl.num_programs(2) - 1)
+    def _finalize():
+        o_ref[0, 0] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def block_select_mha(q, k, v, union, count, mask, start, block: int,
+                     interpret: bool):
+    """``q`` [G, tiles, J·block_q, d] (a tile's head rows, head-major,
+    times the softmax scale); ``k``, ``v`` [G, S, d] with ``S`` a multiple
+    of ``block``; ``union`` [G, tiles, U] int32 — each tile's blocks,
+    ascending, entries past ``count`` [G, tiles] repeating the last;
+    ``mask`` [G, tiles, U / R, block_q, R] float32, 1 where the query reads
+    the entry; ``start`` the first query's position. The K and V tile of
+    grid step ``(g, i, e)`` slot ``r`` is block ``union[g, i, e·R + r]``:
+    the table is prefetched, the tiles follow it. Answers as ``q``."""
+    G, tiles, rows, d = q.shape
+    _, _, steps, block_q, per_step = mask.shape
+    U = union.shape[-1]
+    heads = rows // block_q
+    kernel = functools.partial(
+        _block_select_kernel, block_q=block_q, block=block,
+        per_step=per_step, heads=heads, tiles=tiles, union_len=U,
+        precision=_precision_of(q.dtype))
+
+    def kv_spec(r):
+        return pl.BlockSpec(
+            (1, block, d),
+            lambda g, i, e, union_ref, *_: (
+                g, union_ref[(g * tiles + i) * U + e * per_step + r], 0))
+
+    tile_spec = pl.BlockSpec((1, 1, rows, d),
+                             lambda g, i, e, *_: (g, i, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(G, tiles, steps),
+        in_specs=[tile_spec]
+        + [kv_spec(r) for r in range(per_step)] * 2
+        + [pl.BlockSpec((1, 1, 1, block_q, per_step),
+                        lambda g, i, e, *_: (g, i, e, 0, 0))],
+        out_specs=tile_spec,
+        scratch_shapes=_running_scratch(rows, d))
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(union.reshape(-1).astype(jnp.int32),
+      count.reshape(-1).astype(jnp.int32),
+      jnp.reshape(start, (1,)).astype(jnp.int32), q,
+      *([k] * per_step), *([v] * per_step), mask)
+
+
+def tile_unions(chosen, block_q: int, per_step: int):
+    """From ``chosen`` [G, Q, nb]: each tile of ``block_q`` queries' union
+    of blocks ``[G, tiles, U]`` (ascending; ``U`` = ``nb`` rounded up to
+    the step; entries past the count repeat the last), the counts ``[G,
+    tiles]`` and the per-query mask ``[G, tiles, U / R, block_q, R]``."""
+    G, Q, nb = chosen.shape
+    tiles = Q // block_q
+    U = -(-nb // per_step) * per_step
+    own = chosen.reshape(G, tiles, block_q, nb)
+    any_ = own.any(axis=2)
+    count = any_.sum(axis=-1).astype(jnp.int32)
+    order = jnp.argsort(~any_, axis=-1, stable=True).astype(jnp.int32)
+    entry = jnp.minimum(jnp.arange(U), count[..., None] - 1)
+    union = jnp.take_along_axis(order, jnp.maximum(entry, 0), axis=-1)
+    mask = jnp.take_along_axis(own, union[:, :, None, :], axis=-1) \
+        & (jnp.arange(U) < count[..., None])[:, :, None, :]
+    mask = mask.reshape(G, tiles, block_q, U // per_step, per_step)
+    return union, count, jnp.swapaxes(mask, 2, 3).astype(jnp.float32)
+
+
+def sparse_chunk(q, k, v, chosen, start, scale: float, dtype,
+                 sel: Selection, block_q: int = 64, per_step: int = 16,
+                 kernel: str | None = None):
+    """``q`` [Q, H, d] at rows ``start …`` of ``k``, ``v`` [G, S, d] (their
+    own rows written), each over the blocks ``chosen`` [G, Q, nb] gives it
+    and in them the rows at or below its own. ``kernel``: ``pallas`` (the
+    default on a TPU), ``interpret`` or ``lax`` (the default elsewhere).
+    Answers [Q, H, d] in ``dtype``."""
+    Q, H, d = q.shape
+    G, S, _ = k.shape
+    J, bs = H // G, sel.block_size
+    if kernel is None:
+        kernel = "pallas" if flash_attention._platform() == "tpu" else "lax"
+    q = (q * scale).astype(dtype)
+    if kernel == "lax":
+        from .gqa_attention import _masked_softmax_rows
+
+        row = (start + jnp.arange(Q))[:, None]
+        col = jnp.arange(S)[None, :]
+        seen = jnp.repeat(chosen, bs, axis=-1)[:, :, :S] & (col <= row)[None]
+        outs = [_masked_softmax_rows(q[:, g * J:(g + 1) * J], k[g:g + 1],
+                                     v[g:g + 1], seen[g], dtype)
+                for g in range(G)]
+        return jnp.concatenate(outs, axis=1).astype(dtype)
+    bq = math.gcd(Q, block_q)
+    tiles = Q // bq
+    if kernel == "pallas":
+        from .attention import note_causal
+
+        note_causal("block_select", H, d, Q, S, dtype, bq, per_step * bs)
+    union, count, mask = tile_unions(chosen, bq, per_step)
+    # a tile's rows head-major: row j·bq + t is head j of the tile's query t
+    qt = q.reshape(tiles, bq, G, J, d).transpose(2, 0, 3, 1, 4) \
+        .reshape(G, tiles, J * bq, d)
+    o = block_select_mha(qt, k.astype(dtype), v.astype(dtype), union, count,
+                         mask, start, block=bs,
+                         interpret=kernel == "interpret")
+    return o.reshape(G, tiles, J, bq, d).transpose(1, 3, 0, 2, 4) \
+        .reshape(Q, H, d)
+
+
+# --- one decoded token ---------------------------------------------------------
+
+
+def sparse_step(q, k, v, kc, pos, scale: float, dtype, sel: Selection):
+    """One token's ``q`` [H, d] (normed, unscaled) at ``pos`` over the
+    blocks it selects of ``k``, ``v`` [G, S, d] (its own row written, ``kc``
+    up to date): the table's blocks are GATHERED — ``table · block`` rows a
+    group, whatever the cache holds (the two halves under the named scopes
+    ``select`` and ``sparse_core``). Float32 [H, d], and the selection
+    ``[G, blocks]`` bool (a parity tool's; nobody else computes it)."""
+    H, d = q.shape
+    G, S, _ = k.shape
+    J, bs = H // G, sel.block_size
+    with jax.named_scope("select"):
+        score = block_scores(q[None], kc, jnp.reshape(pos, (1,)), scale,
+                             dtype, sel)[:, 0]                   # [G, nb]
+        top, table = jax.lax.top_k(score, min(sel.table, score.shape[-1]))
+        held = top > -jnp.inf
+    with jax.named_scope("sparse_core"):
+        rows = (table[:, :, None] * bs + jnp.arange(bs)).reshape(G, -1)
+        seen = jnp.repeat(held, bs, axis=-1) & (rows <= pos)
+        kb, vb = (jax.vmap(lambda blocks, t: blocks[t])(
+            a.reshape(G, S // bs, bs, d), table).reshape(G, -1, d)
+            for a in (k, v))
+        qg = (q * scale).reshape(G, J, d).astype(dtype)
+        s = jnp.einsum("gjd,gsd->gjs", qg, kb.astype(dtype),
+                       preferred_element_type=jnp.float32)
+        p = jax.nn.softmax(jnp.where(seen[:, None], s, NEG_INF), axis=-1)
+        o = jnp.einsum("gjs,gsd->gjd", p.astype(dtype), vb.astype(dtype),
+                       preferred_element_type=jnp.float32)
+    chosen = ((table[:, :, None] == jnp.arange(score.shape[-1]))
+              & held[:, :, None]).any(axis=1)
+    return o.reshape(H, d), chosen

@@ -573,3 +573,16 @@ WORKER_MONITOR_CHECKS = REGISTRY.counter(
     "cdt_worker_monitor_checks_total",
     "Watchdog verdicts (master_died / worker_exit / signal).",
     ("outcome",))
+
+# --- a later family, added where it moves no line of the code above ---------
+
+LLM_SELECT_BLOCKS = REGISTRY.counter(
+    "cdt_llm_select_blocks_total",
+    "Key blocks the queries of a language model's block-selecting attention "
+    "layers read (ops/block_select_attention.py), over every (layer, "
+    "key/value group, query) of a request, by kind: forced (the initial "
+    "block and the local ones that end at the query's own) or chosen (by "
+    "compressed-key score). From the config's sizes and the request's token "
+    "counts; a request within dense_len reads every block and counts them "
+    "forced; a model without such layers never moves it.",
+    ("kind",))

@@ -1,0 +1,260 @@
+"""Attention over a selection of key blocks
+(``ops/block_select_attention.py``): the compressed-key cache as chunks
+and tokens write it, the block scores and tables against a brute-force
+``argsort``, the forced blocks, the causal cut inside the own block, the
+chunk form against the step form through the cache, and the Pallas kernel
+(in the interpreter here) against the masked softmax — also for a table
+whose blocks are not contiguous."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from comfyui_distributed_tpu.ops import block_select_attention as bsa
+
+SEL = bsa.Selection(kernel_size=4, kernel_stride=2, block_size=8,
+                    init_blocks=1, window_size=16, topk=3)
+G, J, D = 2, 3, 8
+H = G * J
+SCALE = D ** -0.5
+# float32 sums of a few hundred terms: 1e-6; an unmasked row, a wrong
+# block or a missing causal cut moves an output by 1e-1
+TOL = 5e-6
+
+
+def rows(S, Q, seed=1):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(ks[0], (G, S, D)),
+            jax.random.normal(ks[1], (G, S, D)),
+            jax.random.normal(ks[2], (Q, H, D)))
+
+
+def compressed(k, upto, chunk=16):
+    """The compressed cache after chunks of ``chunk`` rows wrote ``k`` up
+    to row ``upto``."""
+    S = k.shape[1]
+    kc, cache = jnp.zeros((G, S // 2, D)), jnp.zeros_like(k)
+    for st in range(0, upto, chunk):
+        n = min(chunk, upto - st)
+        cache = cache.at[:, st:st + chunk].set(k[:, st:st + chunk])
+        kc = bsa.compress_chunk(kc, cache, k[:, st:st + chunk], st, n, SEL)
+    return kc, cache
+
+
+def brute_tables(q, k, pos):
+    """Tables by the rule, a query and a group at a time, in numpy."""
+    q, k = np.asarray(q, np.float64), np.asarray(k, np.float64)
+    S, nb = k.shape[1], k.shape[1] // SEL.block_size
+    out = np.zeros((G, len(pos), nb), bool)
+    for g in range(G):
+        for n, t in enumerate(pos):
+            starts = [j * 2 for j in range((S - 4) // 2 + 1)
+                      if j * 2 + 4 <= t + 1]
+            score = np.zeros(nb)
+            if starts:
+                means = np.stack([k[g, s:s + 4].mean(0) for s in starts])
+                logits = q[n, g * J:(g + 1) * J] @ means.T * SCALE
+                p = np.exp(logits - logits.max(-1, keepdims=True))
+                p = (p / p.sum(-1, keepdims=True)).sum(0)
+                for b in range(nb):
+                    near = [p[i] for i, s in enumerate(starts)
+                            if s < 8 * b + 8 and s + 4 > 8 * b]
+                    score[b] = max(near, default=0.0)
+            own = t // 8
+            for b in range(nb):
+                if b > own:
+                    score[b] = -np.inf
+                elif b == 0 or b > own - 2:
+                    score[b] = np.inf
+            order = np.argsort(-score, kind="stable")[:SEL.table]
+            out[g, n, [b for b in order if score[b] > -np.inf]] = True
+    return out
+
+
+def test_the_rules_sizes():
+    assert (SEL.per, SEL.local_blocks, SEL.table) == (4, 2, 5)
+    full = bsa.Selection()
+    assert (full.per, full.local_blocks, full.table) == (4, 32, 96)
+    with pytest.raises(ValueError):
+        bsa.Selection(kernel_size=3, kernel_stride=2).check()
+
+
+def test_chunks_write_the_means_of_their_windows_across_a_chunk_edge():
+    k, _, _ = rows(96, 1)
+    kc, _ = compressed(k, 96)
+    for j in range((96 - 4) // 2 + 1):           # window j sits at slot j + 1
+        assert np.allclose(kc[:, j + 1], k[:, 2 * j:2 * j + 4].mean(1),
+                           atol=1e-6), j
+    assert not np.asarray(kc[:, 0]).any()        # slot 0 holds no window
+
+
+@pytest.mark.parametrize("n_valid", [1, 2, 3, 9, 16])
+def test_a_padded_chunk_writes_only_the_slots_its_real_rows_complete(n_valid):
+    k, _, _ = rows(96, 1)
+    kc, cache = compressed(k, 32)
+    before = np.asarray(kc).copy()
+    cache = cache.at[:, 32:48].set(k[:, 32:48])
+    after = np.asarray(bsa.compress_chunk(kc, cache, k[:, 32:48], 32,
+                                          jnp.asarray(n_valid), SEL))
+    done = (32 + n_valid) // 2 - 1               # windows whole by then
+    assert np.allclose(after[:, 1:done + 1], [
+        [np.asarray(k[g, 2 * j:2 * j + 4]).mean(0) for j in range(done)]
+        for g in range(G)], atol=1e-6)
+    assert (after[:, done + 1:] == before[:, done + 1:]).all()
+
+
+def test_tokens_write_a_slot_every_stride_rows_as_the_chunks_did():
+    k, _, _ = rows(96, 1)
+    kc, cache = compressed(k, 32)
+    want, _ = compressed(k, 48)
+    for pos in range(32, 48):
+        cache = cache.at[:, pos].set(k[:, pos])
+        grown = bsa.compress_step(kc, cache, pos, SEL)
+        changed = int((np.asarray(grown) != np.asarray(kc)).any((0, 2)).sum())
+        assert changed == (1 if (pos + 1) % 2 == 0 else 0)
+        kc = grown
+    assert np.allclose(kc, want, atol=1e-6)
+
+
+@pytest.mark.parametrize("start", [0, 8, 40, 64])
+def test_the_tables_are_the_rules_by_a_stable_argsort(start):
+    k, _, q = rows(96, 32, seed=start + 2)
+    kc, _ = compressed(k, start + 32)
+    pos = start + jnp.arange(32)
+    score = bsa.block_scores(q, kc, pos, SCALE, jnp.float32, SEL)
+    chosen = np.asarray(bsa.select(score, SEL))
+    assert (chosen == brute_tables(q, k, np.asarray(pos))).all()
+    own = np.asarray(pos) // 8
+    assert (chosen.sum(-1) == np.minimum(own + 1, SEL.table)).all()
+    for n, b in enumerate(own):                  # the forced blocks
+        assert chosen[:, n, 0].all() and chosen[:, n, b].all()
+        assert chosen[:, n, max(b - 1, 0)].all()
+        assert not chosen[:, n, b + 1:].any()
+
+
+def test_neighbouring_blocks_tie_and_the_lower_index_wins():
+    score = jnp.asarray([[[jnp.inf, 0.5, 0.7, 0.7, 0.7, 0.2, jnp.inf,
+                           -jnp.inf]]])
+    sel = SEL._replace(topk=2, window_size=8)      # a table of 3
+    assert np.asarray(bsa.select(score, sel))[0, 0].tolist() == [
+        True, False, True, False, False, False, True, False]
+
+
+def test_a_tiles_union_is_ascending_and_repeats_its_last_entry():
+    chosen = np.zeros((1, 4, 7), bool)
+    chosen[0, 0, [0, 2]] = chosen[0, 1, [0, 5]] = True
+    chosen[0, 2, [0, 1]] = chosen[0, 3, [0, 1, 6]] = True
+    union, count, mask = bsa.tile_unions(jnp.asarray(chosen), 2, 4)
+    assert count.tolist() == [[3, 3]]
+    assert union[0, 0].tolist() == [0, 2, 5, 5, 5, 5, 5, 5]
+    assert union[0, 1].tolist() == [0, 1, 6, 6, 6, 6, 6, 6]
+    assert mask.shape == (1, 2, 2, 2, 4)
+    # tile 0, step 0: query 0 owns entries 0 and 1 (blocks 0, 2), query 1
+    # entries 0 and 2 (blocks 0, 5); nothing past the count
+    assert mask[0, 0, 0].tolist() == [[1, 1, 0, 0], [1, 0, 1, 0]]
+    assert not np.asarray(mask[0, :, 1]).any()
+
+
+@pytest.mark.parametrize("kernel,block_q,per_step", [
+    ("lax", 8, 2), ("interpret", 8, 2), ("interpret", 16, 4),
+    ("interpret", 4, 1)])
+def test_the_chunk_form_is_the_masked_softmax_over_the_selected_rows(
+        kernel, block_q, per_step):
+    k, v, q = rows(96, 32)
+    kc, cache = compressed(k, 80)
+    chosen = bsa.select(bsa.block_scores(q, kc, 48 + jnp.arange(32), SCALE,
+                                         jnp.float32, SEL), SEL)
+    got = bsa.sparse_chunk(q, cache, v, chosen, 48, SCALE, jnp.float32, SEL,
+                           block_q, per_step, kernel)
+    s = np.einsum("qgjd,gsd->qgjs",
+                  np.asarray(q, np.float64).reshape(32, G, J, D),
+                  np.asarray(cache, np.float64)) * SCALE
+    seen = np.repeat(np.asarray(chosen), 8, -1) \
+        & (np.arange(96)[None, None] <= (48 + np.arange(32))[None, :, None])
+    s = np.where(np.swapaxes(seen, 0, 1)[:, :, None], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("qgjs,gsd->qgjd", p / p.sum(-1, keepdims=True),
+                     np.asarray(v, np.float64)).reshape(32, H, D)
+    assert np.abs(np.asarray(got) - want).max() < TOL
+
+
+@pytest.mark.parametrize("kernel", ["lax", "interpret"])
+def test_a_table_whose_blocks_are_not_contiguous(kernel):
+    """Hand-made tables with gaps, different for every query and group:
+    only their rows are read."""
+    k, v, q = rows(96, 16, seed=7)
+    rng = np.random.default_rng(0)
+    chosen = np.zeros((G, 16, 12), bool)
+    for g in range(G):
+        for n in range(16):
+            own = (64 + n) // 8
+            chosen[g, n, rng.choice(own, 3, replace=False)] = True
+            chosen[g, n, own] = True
+    got = bsa.sparse_chunk(q, k, v, jnp.asarray(chosen), 64, SCALE,
+                           jnp.float32, SEL, 8, 2, kernel)
+    # rows outside the tables may hold anything
+    outside = ~np.repeat(chosen.any(1), 8, -1)               # [G, S]
+    loud_k = jnp.where(outside[:, :, None], 1e4, k)
+    loud_v = jnp.where(outside[:, :, None], -1e4, v)
+    again = bsa.sparse_chunk(q, loud_k, loud_v, jnp.asarray(chosen), 64,
+                             SCALE, jnp.float32, SEL, 8, 2, kernel)
+    assert np.abs(np.asarray(got) - np.asarray(again)).max() < TOL
+    lax = bsa.sparse_chunk(q, k, v, jnp.asarray(chosen), 64, SCALE,
+                           jnp.float32, SEL, kernel="lax")
+    assert np.abs(np.asarray(got) - np.asarray(lax)).max() < TOL
+
+
+@pytest.mark.parametrize("kernel", ["lax", "interpret"])
+def test_the_causal_cut_inside_the_own_block(kernel):
+    """Rows of a query's own block past its position are never read."""
+    k, v, q = rows(96, 8, seed=3)
+    kc, cache = compressed(k, 48)
+    pos = 40 + jnp.arange(8)                      # the whole of block 5
+    chosen = bsa.select(bsa.block_scores(q, kc, pos, SCALE, jnp.float32,
+                                         SEL), SEL)
+    got = bsa.sparse_chunk(q, cache, v, chosen, 40, SCALE, jnp.float32, SEL,
+                           8, 2, kernel)
+    for n in (0, 3, 6):
+        loud_k = cache.at[:, 40 + n + 1:48].set(1e4)
+        loud_v = v.at[:, 40 + n + 1:48].set(-1e4)
+        again = bsa.sparse_chunk(q, loud_k, loud_v, chosen, 40, SCALE,
+                                 jnp.float32, SEL, 8, 2, kernel)
+        assert np.abs(np.asarray(got[n]) - np.asarray(again[n])).max() < TOL
+        assert np.abs(np.asarray(got[7]) - np.asarray(again[7])).max() > 1.0
+
+
+@pytest.mark.parametrize("start", [0, 48])
+def test_the_chunk_form_is_the_step_form_through_the_cache(start):
+    k, v, q = rows(96, 32, seed=5)
+    kc, cache = compressed(k, start + 32)
+    chosen = bsa.select(bsa.block_scores(q, kc, start + jnp.arange(32),
+                                         SCALE, jnp.float32, SEL), SEL)
+    whole = bsa.sparse_chunk(q, cache, v, chosen, start, SCALE, jnp.float32,
+                             SEL, 8, 2, "interpret")
+    kc_t, cache_t = compressed(k, start) if start else (
+        jnp.zeros((G, 48, D)), jnp.zeros_like(k))
+    for n in range(32):
+        pos = start + n
+        cache_t = cache_t.at[:, pos].set(k[:, pos])
+        kc_t = bsa.compress_step(kc_t, cache_t, pos, SEL)
+        o, table = bsa.sparse_step(q[n], cache_t, v, kc_t, pos, SCALE,
+                                   jnp.float32, SEL)
+        assert np.abs(np.asarray(o) - np.asarray(whole[n])).max() < TOL, n
+        assert (np.asarray(table) == np.asarray(chosen[:, n])).all(), n
+
+
+def test_the_kernel_reports_a_tier_of_its_own(monkeypatch):
+    from comfyui_distributed_tpu.ops import attention, autotune
+
+    assert "block_select" in autotune.REPORTED_TIERS
+    assert "block_select" in attention.CAUSAL_TIER_REASONS
+    said = []
+    monkeypatch.setattr(attention, "_note_selection",
+                        lambda geometry, choice: said.append(
+                            (geometry, choice.tier, choice.block_q,
+                             choice.block_k)))
+    attention.note_causal("block_select", 32, 128, 512, 65664,
+                          jnp.bfloat16, 64, 1024)
+    assert said == [("h32.d128.q512.kv131072.bf16", "block_select", 64,
+                     1024)]

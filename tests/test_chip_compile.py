@@ -26,6 +26,7 @@ described device cannot be read back without one, and only warns.
 """
 
 import importlib.util
+import math
 import os
 import re
 from pathlib import Path
@@ -687,3 +688,71 @@ def test_a_joint_block_hands_the_kernel_its_products_outputs(chip, case,
         assert operands[0] == operands[2] == operands[4], operands
     key = autotune.GeometryKey.from_shape(cfg.heads, hd, T + N, T + N)
     assert attn.selection_summary() == f"{key.key_str()}={label}"
+
+
+def test_the_table_driven_kernel_compiles_at_the_served_geometry(chip):
+    """``block_select_attention.block_select_mha`` as the sparse layers'
+    prefill calls it: 512 queries a call in tiles of 64 neighbours × 16
+    heads of a K/V group (1024 rows), 16 K and 16 V tiles of one 64-row
+    block a grid step — each a BlockSpec whose index is read from the
+    PREFETCHED union table — over the 65 664-row buffers. The compiled
+    kernel carries the NAME the cell's trace readers match
+    (``cdtbench/kinds/sala.py``)."""
+    from comfyui_distributed_tpu.models.llm_sala import SalaConfig
+    from comfyui_distributed_tpu.ops import block_select_attention as bsa
+
+    cfg = SalaConfig.sala_cut()
+    G, d = cfg.num_key_value_heads, cfg.head_dim
+    J = cfg.num_attention_heads // G
+    bq, R, bs = cfg.sparse_block_q, cfg.sparse_blocks_per_step, cfg.block_size
+    S = cfg.cache_rows(65536 + 128)
+    tiles, U = cfg.select_rows // bq, -(-(S // bs) // R) * R
+    assert (S, tiles, U, J * bq) == (65664, 8, 1040, 1024)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    lowered = bsa.block_select_mha.lower(
+        arg((G, tiles, J * bq, d)), arg((G, S, d)), arg((G, S, d)),
+        arg((G, tiles, U), jnp.int32), arg((G, tiles), jnp.int32),
+        arg((G, tiles, U // R, bq, R), jnp.float32), arg((), jnp.int32),
+        block=bs, interpret=False)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text and "block_select_mha" in text
+
+
+def test_the_selecting_rewriters_programs_fit_beside_sdxl(chip, monkeypatch):
+    """Both language programs of ``minicpm-sala.brief64k-sdxl8`` at the
+    cell's sizes (65 536 + 128 tokens, published widths, 12 layers): they
+    compile for the chip and leave room for SDXL's segment program (4.79 +
+    0.56 GiB) in 15.75 GiB; the prefill holds ONE Pallas call site a sparse
+    layer; no float32 buffer the size of a whole chunk's scores —
+    ``[4096, 32 heads, 4224 slots]``, 2.1 GiB — exists (the queries score
+    512 at a time, the slots in whole lanes: ``cache_slots``); and
+    ``llm_decode``'s token loop copies nothing of 1 MiB (the states are a
+    leaf a layer: stacked, all nine were copied a token)."""
+    from comfyui_distributed_tpu.models.llm_sala import SalaConfig
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    cfg = SalaConfig.sala_cut()
+    compiled = loop_copies.compiled_programs(cfg, 65536, 128, chip)
+    gib, sdxl = 2.0 ** 30, 4.79 + 0.56
+    text = compiled["llm_prefill"].as_text()
+    assert len(_pallas_calls(text)) == len(cfg.sparse_layers) == 3
+    slots = cfg.cache_slots(cfg.cache_rows(65536 + 128))
+    scores = [math.prod(int(n) for n in shape.split(","))
+              for shape in re.findall(r"f32\[([\d,]+)\]", text)
+              if str(slots) in shape.split(",")]
+    assert slots == 4224 and scores
+    assert max(scores) == 2 * cfg.select_rows * 16 * slots \
+        < 4096 * 32 * slots // 4
+    mem = compiled["llm_prefill"].memory_analysis()
+    prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                   + mem.output_size_in_bytes) / gib
+    assert 8.0 < prefill_gib < 9.0 and prefill_gib + sdxl < 15.75 - 1.0
+    text = compiled["llm_decode"].as_text()
+    assert "tpu_custom_call" not in text                   # decode is XLA
+    assert not _copy_sizes(text, True)
+    mem = compiled["llm_decode"].memory_analysis()
+    decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
+    assert 7.5 < decode_gib < 8.4 and decode_gib + sdxl < 15.75 - 1.0

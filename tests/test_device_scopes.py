@@ -215,7 +215,8 @@ LLMS = {"ling-tiny": ("llm_hybrid", "LLMConfig", 24),
         "kimi-tiny": ("llm_kimi", "KimiConfig", 37),     # three chunks of 16
         "jamba-tiny": ("llm_jamba", "JambaConfig", 37),
         "trinity-tiny": ("llm_trinity", "TrinityConfig", 21),  # 2 chunks + 5
-        "longcat-tiny": ("llm_longcat", "LongcatConfig", 37)}  # three chunks
+        "longcat-tiny": ("llm_longcat", "LongcatConfig", 37),  # three chunks
+        "sala-tiny": ("llm_sala", "SalaConfig", 40)}   # past its dense_len
 
 PROGRAMS = {"txt2img_seg": _txt2img_seg, "flow_seg": _flow_seg, "fin": _fin}
 for _name, _how in LLMS.items():
@@ -258,6 +259,10 @@ EXPECTED = {
                                  "llm_shared_ffn", "llm_head"},
     "llm_decode:longcat-tiny": {"llm_attn", "llm_router", "llm_experts",
                                 "llm_shared_ffn", "llm_head"},
+    # no expert layer; selection, sparse core and the linear recurrence are
+    # plain named scopes BELOW cdt.llm_attn (there is no seventeenth layer)
+    "llm_prefill:sala-tiny": {"llm_attn", "llm_shared_ffn", "llm_head"},
+    "llm_decode:sala-tiny": {"llm_attn", "llm_shared_ffn", "llm_head"},
 }
 
 
@@ -281,3 +286,24 @@ def test_every_product_of_a_served_program_is_under_exactly_one_layer(
     assert EXPECTED[program] <= layers, (
         f"{program} opened {sorted(layers)}, not "
         f"{sorted(EXPECTED[program] - layers)}")
+
+
+def test_the_selecting_rewriters_work_is_named_below_its_layer():
+    """``select``, ``sparse_core`` and ``lightning`` are plain named scopes
+    under ``cdt.llm_attn`` (what ``cdtbench/kinds/sala.py: scope_seconds``
+    reads from a trace): every product of the three is under exactly one of
+    them, and under the one registered layer."""
+    plain = re.compile(r"/(select|sparse_core|lightning)(?:/|$)")
+    for program in ("llm_prefill:sala-tiny", "llm_decode:sala-tiny"):
+        fn, args = PROGRAMS[program]()
+        seen = list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+        below = {}
+        for primitive, stack, ops in seen:
+            found = plain.findall(stack)
+            if found:
+                assert len(found) == 1 and LAYER.findall(stack) \
+                    == ["llm_attn"], stack
+                below[found[0]] = below.get(found[0], 0) + ops
+        want = {"select", "sparse_core", "lightning"} \
+            if program.startswith("llm_prefill") else {"select", "lightning"}
+        assert set(below) >= want and all(below.values()), below
