@@ -1021,6 +1021,9 @@ class TPUPromptRewrite(NodeDef):
             if blocks is not None:    # attention over a selection of blocks
                 for kind, n in blocks(prompt_tokens, new_tokens).items():
                     _tm.LLM_SELECT_BLOCKS.labels(kind=kind).inc(n)
+                for kind, n in cfg.scored_slot_tiles(prompt_tokens,
+                                                     new_tokens).items():
+                    _tm.LLM_SELECT_SLOT_TILES.labels(kind=kind).inc(n)
             if cfg.moe_layers:
                 _tm.LLM_EXPERT_ROWS.labels(form=out["prefill_form"]).inc(
                     out["rows_prefill"])

@@ -100,9 +100,11 @@ class SalaConfig:
     rope_positions: int = 131072
     # the schedule of the chunked prefill; sizes of the program, not options
     # of a request: the chunk; the dense branch's tile; how many queries
-    # score and select at once (what bounds the float32 [rows, heads,
-    # slots] scores); a sparse tile's neighbouring queries and the K blocks
-    # a grid step reads; the chunk form's block of the linear recurrence
+    # score and select at once (what bounds the float32 [groups, rows,
+    # slots] sums, the tables' sort and their masks); a sparse tile's
+    # neighbouring queries and the K blocks a grid step reads (the scoring
+    # kernel's tile is the op's own constant); the chunk form's block of
+    # the linear recurrence
     prefill_chunk_tokens: int = 4096
     attn_block_q: int = 2048
     attn_block_k: int = 2048
@@ -252,6 +254,30 @@ class SalaConfig:
         return {"forced": n * forced,
                 "chosen": n * (sum(int(r[0]) for r in read.values())
                                - forced)}
+
+    def scored_slot_tiles(self, prompt_tokens: int, new_tokens: int) -> dict:
+        """(query tile, slot tile) pairs the prefill's scoring kernel meets
+        in a request, over every (sparse layer, key/value group): ``scored``
+        (some query of the tile sees a window of the slot tile whole) and
+        ``skipped`` (none does: neither fetched nor computed). By the
+        kernel's own rule at its own tiles, for every chunk the prefill
+        walks (a padded chunk's rows score too); none for a request within
+        ``dense_len``, and decode scores in the plain form."""
+        chunk = min(self.prefill_chunk_tokens, prompt_tokens)
+        walked = -(-prompt_tokens // chunk) * chunk
+        rows = self.cache_rows(max(prompt_tokens + new_tokens, walked))
+        if not self.reads_selection(rows):
+            return {"scored": 0, "skipped": 0}
+        slots = self.cache_slots(rows)
+        block_q, block_slots = select_ops.score_tiles(
+            math.gcd(chunk, self.select_rows), slots)
+        first = np.arange(0, walked, block_q, dtype=np.int64)
+        scored = int((select_ops.last_slot_tile(
+            first, block_q, block_slots, self.kernel_stride,
+            slots // block_slots, np.clip) + 1).sum())
+        n = len(self.sparse_layers) * self.num_key_value_heads
+        return {"scored": n * scored,
+                "skipped": n * (len(first) * (slots // block_slots) - scored)}
 
 
 # --- weights ---------------------------------------------------------------
@@ -408,8 +434,10 @@ def cache_kinds(cfg: SalaConfig, cache: dict) -> dict:
 def _selected_rows(cfg: SalaConfig, q, k, v, kc, start, kernel, keep):
     """The rule for a chunk's queries ``q`` [C,H,d] at rows ``start …``:
     ``select_rows`` of them score, select and attend at a time (the
-    float32 scores of more would not fit beside the model). Answers the
-    attention [C,H,d] and, where ``keep``, the tables [G,C,blocks]."""
+    float32 sums, the sort and the masks of more would not fit beside the
+    model); ``kernel`` names the form of BOTH kernels — the scores' and
+    the table-driven one. Answers the attention [C,H,d] and, where
+    ``keep``, the tables [G,C,blocks]."""
     dtype, sel = jnp.dtype(cfg.dtype), cfg.selection
     C, H, d = q.shape
     scale = cfg.head_dim ** -0.5
@@ -419,7 +447,8 @@ def _selected_rows(cfg: SalaConfig, q, k, v, kc, start, kernel, keep):
         q_n, first = xs
         with jax.named_scope("select"):
             chosen = select_ops.select(select_ops.block_scores(
-                q_n, kc, first + jnp.arange(n), scale, dtype, sel), sel)
+                q_n, kc, first + jnp.arange(n), scale, dtype, sel,
+                kernel), sel)
         with jax.named_scope("sparse_core"):
             o = select_ops.sparse_chunk(
                 q_n, k, v, chosen, first, scale, dtype, sel,

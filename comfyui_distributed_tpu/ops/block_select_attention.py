@@ -27,7 +27,14 @@ window that spans two chunks is completed by the second.
 **Scores and the selection are float32** (:func:`block_scores`,
 :func:`select`); the selection is the ``table`` best blocks, ties to the
 lower index (two neighbouring blocks share the window that straddles them,
-so equal scores are the rule's own, not an accident of rounding).
+so equal scores are the rule's own, not an accident of rounding). A
+chunk's NEIGHBOURING queries have their group sums ``s_g`` made by a
+two-pass Pallas kernel (:func:`block_score_sums`: a head row's maximum and
+sum of exponentials over the slot tiles it can see, then the normalised
+tiles summed over a group's heads — no per-head logit reaches HBM, and a
+slot tile no query of the tile sees whole is neither fetched nor
+computed); ``lax`` writes the softmax out plainly — the CPU's default, one
+decoded token's form, and what the kernel is held to.
 
 **The chunk form** (:func:`sparse_chunk`) serves ``block_q`` NEIGHBOURING
 queries' ``J · block_q`` head rows a tile: what neighbours share is the
@@ -55,7 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import flash_attention
-from .flash_attention import NEG_INF
+from .flash_attention import _LANES, NEG_INF
 from .flash_latent import (_VMEM_LIMIT_BYTES, _accumulate, _init_running,
                            _precision_of, _running_scratch)
 
@@ -136,27 +143,22 @@ def compress_step(kc, k_cache, pos, sel: Selection):
 # --- scores and the selection ------------------------------------------------
 
 
-def block_scores(q, kc, pos, scale: float, dtype, sel: Selection):
-    """``q`` [Q, H, d] (normed, unscaled) at positions ``pos`` [Q] against
-    the compressed cache ``kc`` [G, Sc, d] (``Sc`` a multiple of ``per``):
-    float32 ``[G, Q, Sc / per]`` block scores, forced blocks ``+∞``, blocks
-    past a query's own ``−∞``."""
+def _group_sums(q, kc, pos, stride: int):
+    """``s_g`` written out: ``q`` [Q, H, d] (times the scale) at ``pos``
+    against ``kc`` [G, Sc, d], float32 [G, Q, Sc]."""
     Q, H, d = q.shape
     G, Sc, _ = kc.shape
-    per, st = sel.per, sel.kernel_stride
-    nb = Sc // per
-    J = H // G
+    heads = H // G
     # a group's head rows stacked [G, Q·J, d]: one plain product a group
     # whose minor axis is the slots (a [G,Q,J,Sc] einsum is laid out with
     # the queries minor on the chip, and its softmax crawls)
-    qg = jnp.swapaxes(q.reshape(Q, G, J, d), 0, 1).reshape(G, Q * J, d)
-    logits = jnp.einsum("gmd,gsd->gms", (qg * scale).astype(dtype),
-                        kc.astype(dtype),
+    qg = jnp.swapaxes(q.reshape(Q, G, heads, d), 0, 1).reshape(G, Q * heads, d)
+    logits = jnp.einsum("gmd,gsd->gms", qg, kc,
                         preferred_element_type=jnp.float32)
     slot = jnp.arange(Sc)
     whole = (slot[None, :] >= 1) \
-        & (st * (slot[None, :] + 1) <= pos[:, None] + 1)      # [Q,Sc]
-    whole = jnp.repeat(whole, J, axis=0)[None]                # [1,Q·J,Sc]
+        & (stride * (slot[None, :] + 1) <= pos[:, None] + 1)  # [Q,Sc]
+    whole = jnp.repeat(whole, heads, axis=0)[None]            # [1,Q·J,Sc]
     # the softmax written out, its two row reductions behind a barrier:
     # left to itself the chip's compiler turns ``x − max(x)`` and ``e /
     # sum(e)`` into reduce-windows as wide as two rows (1.9 s a layer a
@@ -165,7 +167,183 @@ def block_scores(q, kc, pos, scale: float, dtype, sel: Selection):
     top = jax.lax.optimization_barrier(logits.max(axis=-1, keepdims=True))
     e = jnp.where(whole, jnp.exp(logits - top), 0.0)
     norm = jax.lax.optimization_barrier(e.sum(axis=-1, keepdims=True))
-    s = (e / jnp.maximum(norm, 1e-30)).reshape(G, Q, J, Sc).sum(axis=2)
+    return (e / jnp.maximum(norm, 1e-30)).reshape(G, Q, heads, Sc).sum(axis=2)
+
+
+# the scoring kernel's tile: neighbouring queries (× a group's heads) and
+# compressed slots, fixed by scripts/score_tile_sweep.py on the chip
+SCORE_BLOCK_Q, SCORE_BLOCK_SLOTS = 128, 1408
+
+
+def score_tiles(Q: int, Sc: int, block_q: int = SCORE_BLOCK_Q,
+                block_slots: int = SCORE_BLOCK_SLOTS):
+    """The scoring kernel's tile of ``Q`` neighbouring queries and ``Sc``
+    slots: the asked sizes where they divide, else what does."""
+    return math.gcd(Q, block_q), math.gcd(Sc, block_slots)
+
+
+def last_slot_tile(first, block_q: int, block_slots: int, stride: int,
+                   slot_tiles: int, clip=jnp.clip):
+    """The last slot tile that holds a window WHOLE for any query of the
+    tile whose first position is ``first`` (its last query sees the slots
+    ``1 … (first + block_q) / stride − 1``); tile 0 where none does. The
+    kernel's index map and body share it with the counter's rule (numpy:
+    ``clip=np.clip``)."""
+    return clip(((first + block_q) // stride - 1) // block_slots, 0,
+                slot_tiles - 1)
+
+
+def _score_sums_kernel(pos_ref, q_ref, k_ref, o_ref, m_ref, l_ref, *,
+                       block_q: int, block_slots: int, heads: int,
+                       stride: int, slot_tiles: int, precision):
+    i, half, t = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    rows = heads * block_q
+    first = pos_ref[0] + i * block_q
+    seen = t <= last_slot_tile(first, block_q, block_slots, stride,
+                               slot_tiles)
+    # every slot of the tile whole for every query of it: slot 0 is not in
+    # it and its last slot lies at or below the FIRST query's last
+    clear = (t >= 1) & ((t + 1) * block_slots <= (first + 1) // stride)
+
+    @pl.when((half == 0) & (t == 0))
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def logits():
+        return jax.lax.dot_general(q_ref[0, 0], k_ref[0],
+                                   (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32,
+                                   precision=precision)
+
+    def whole():
+        slot = t * block_slots + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_slots), 1)
+        # head-major rows: row j·block_q + n is head j of the tile's query n
+        at = first + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0) % block_q
+        return (slot >= 1) & (stride * (slot + 1) <= at + 1)
+
+    def statistics(masked: bool):
+        s = logits()
+        if masked:
+            own = whole()
+            s = jnp.where(own, s, NEG_INF)
+        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        e = jnp.exp(s - m_new)
+        if masked:
+            e = jnp.where(own, e, 0.0)
+        l_new = l_prev * jnp.exp(m_prev - m_new) \
+            + jnp.sum(e, axis=-1, keepdims=True)
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    def sums(masked: bool):
+        p = jnp.exp(logits() - m_ref[:, :1]) \
+            * (1.0 / jnp.maximum(l_ref[:, :1], 1e-30))
+        if masked:
+            p = jnp.where(whole(), p, 0.0)
+        o_ref[0] = p.reshape(heads, block_q, block_slots).sum(axis=0)
+
+    for n, body in enumerate((statistics, sums)):
+        pl.when((half == n) & seen & clear)(
+            functools.partial(body, False))
+        pl.when((half == n) & seen & jnp.logical_not(clear))(
+            functools.partial(body, True))
+
+    @pl.when((half == 1) & jnp.logical_not(seen))
+    def _unseen():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_slots",
+                                             "stride", "interpret"))
+def block_score_sums(q, kc, start, block_q: int, block_slots: int,
+                     stride: int, interpret: bool):
+    """``q`` [G, tiles, J·block_q, d] (a tile's head rows, head-major,
+    times the softmax scale) at positions ``start …`` against ``kc`` [G,
+    Sc, d] (``Sc`` whole tiles of ``block_slots``): float32 ``[G, Q, Sc]``,
+    each head row's softmax over the slots whose window is whole for its
+    query, summed over the tile's ``J`` heads. grid = (G, tiles, 2, slot
+    tiles): the first half walks the slot tiles for each row's running
+    maximum and sum (VMEM scratch), the second walks them again and writes.
+    A tile past the last one any query of the tile sees is skipped in both
+    (its ``kc`` index repeats the last, so nothing is fetched) and written
+    0."""
+    G, tiles, rows, d = q.shape
+    Sc = kc.shape[1]
+    slot_tiles = Sc // block_slots
+    kernel = functools.partial(
+        _score_sums_kernel, block_q=block_q, block_slots=block_slots,
+        heads=rows // block_q, stride=stride, slot_tiles=slot_tiles,
+        precision=_precision_of(q.dtype))
+
+    def last(i, pos_ref):
+        return last_slot_tile(pos_ref[0] + i * block_q, block_q,
+                              block_slots, stride, slot_tiles)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(G, tiles, 2, slot_tiles),
+        in_specs=[
+            pl.BlockSpec((1, 1, rows, d),
+                         lambda g, i, half, t, pos_ref: (g, i, 0, 0)),
+            pl.BlockSpec((1, block_slots, d),
+                         lambda g, i, half, t, pos_ref: (
+                             g, jnp.minimum(t, last(i, pos_ref)), 0))],
+        # the first half writes nothing: it stays on the tile the second
+        # half writes first, so no block leaves VMEM before it is written
+        out_specs=pl.BlockSpec(
+            (1, block_q, block_slots),
+            lambda g, i, half, t, pos_ref: (g, i, half * t)),
+        scratch_shapes=[pltpu.VMEM((rows, _LANES), jnp.float32),   # max
+                        pltpu.VMEM((rows, _LANES), jnp.float32)])  # sum
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((G, tiles * block_q, Sc),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(jnp.reshape(start, (1,)).astype(jnp.int32), q, kc)
+
+
+def _head_major_tiles(q, G: int, block_q: int):
+    """``q`` [Q, H, d] as ``[G, tiles, J·block_q, d]``: row ``j·block_q +
+    n`` of a tile is head ``j`` (of the group's ``J``) of its query ``n``."""
+    Q, H, d = q.shape
+    J, tiles = H // G, Q // block_q
+    return q.reshape(tiles, block_q, G, J, d).transpose(2, 0, 3, 1, 4) \
+        .reshape(G, tiles, J * block_q, d)
+
+
+def block_scores(q, kc, pos, scale: float, dtype, sel: Selection,
+                 kernel: str | None = None, block_q: int = SCORE_BLOCK_Q,
+                 block_slots: int = SCORE_BLOCK_SLOTS):
+    """``q`` [Q, H, d] (normed, unscaled) at positions ``pos`` [Q] against
+    the compressed cache ``kc`` [G, Sc, d] (``Sc`` a multiple of ``per``):
+    float32 ``[G, Q, Sc / per]`` block scores, forced blocks ``+∞``, blocks
+    past a query's own ``−∞``. ``kernel``: ``pallas`` (the default on a
+    TPU) or ``interpret`` — the two-pass kernel, whose queries are
+    NEIGHBOURS (``pos`` is ``pos[0] …``) — or ``lax`` (the default
+    elsewhere)."""
+    Q, H, d = q.shape
+    G, Sc, _ = kc.shape
+    per, st = sel.per, sel.kernel_stride
+    nb = Sc // per
+    if kernel is None:
+        kernel = "pallas" if flash_attention._platform() == "tpu" else "lax"
+    q = (q * scale).astype(dtype)
+    if kernel == "lax":
+        s = _group_sums(q, kc.astype(dtype), pos, st)
+    else:
+        bq, slots = score_tiles(Q, Sc, block_q, block_slots)
+        s = block_score_sums(_head_major_tiles(q, G, bq), kc.astype(dtype),
+                             pos[0], block_q=bq, block_slots=slots,
+                             stride=st, interpret=kernel == "interpret")
     a = s.reshape(G, Q, nb, per)
     after = jnp.concatenate(
         [a[:, :, 1:, 0], jnp.zeros((G, Q, 1), jnp.float32)], axis=2)
@@ -334,12 +512,9 @@ def sparse_chunk(q, k, v, chosen, start, scale: float, dtype,
 
         note_causal("block_select", H, d, Q, S, dtype, bq, per_step * bs)
     union, count, mask = tile_unions(chosen, bq, per_step)
-    # a tile's rows head-major: row j·bq + t is head j of the tile's query t
-    qt = q.reshape(tiles, bq, G, J, d).transpose(2, 0, 3, 1, 4) \
-        .reshape(G, tiles, J * bq, d)
-    o = block_select_mha(qt, k.astype(dtype), v.astype(dtype), union, count,
-                         mask, start, block=bs,
-                         interpret=kernel == "interpret")
+    o = block_select_mha(_head_major_tiles(q, G, bq), k.astype(dtype),
+                         v.astype(dtype), union, count, mask, start,
+                         block=bs, interpret=kernel == "interpret")
     return o.reshape(G, tiles, J, bq, d).transpose(1, 3, 0, 2, 4) \
         .reshape(Q, H, d)
 
@@ -358,8 +533,10 @@ def sparse_step(q, k, v, kc, pos, scale: float, dtype, sel: Selection):
     G, S, _ = k.shape
     J, bs = H // G, sel.block_size
     with jax.named_scope("select"):
+        # one query's 16 rows × the slots: the plain form (no kernel in
+        # the token loop)
         score = block_scores(q[None], kc, jnp.reshape(pos, (1,)), scale,
-                             dtype, sel)[:, 0]                   # [G, nb]
+                             dtype, sel, kernel="lax")[:, 0]     # [G, nb]
         top, table = jax.lax.top_k(score, min(sel.table, score.shape[-1]))
         held = top > -jnp.inf
     with jax.named_scope("sparse_core"):

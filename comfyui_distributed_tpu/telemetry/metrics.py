@@ -586,3 +586,14 @@ LLM_SELECT_BLOCKS = REGISTRY.counter(
     "counts; a request within dense_len reads every block and counts them "
     "forced; a model without such layers never moves it.",
     ("kind",))
+
+LLM_SELECT_SLOT_TILES = REGISTRY.counter(
+    "cdt_llm_select_slot_tiles_total",
+    "(query tile, compressed-slot tile) pairs a block-selecting language "
+    "model's prefill scoring kernel met (ops/block_select_attention.py: "
+    "block_score_sums), over every (layer, key/value group) of a request, "
+    "by kind: scored (a query of the tile sees a window of the slot tile "
+    "whole) or skipped (none does: neither fetched nor computed, read as "
+    "0). From the config's sizes, the kernel's tiles and the request's "
+    "token counts; a request within dense_len scores nothing.",
+    ("kind",))

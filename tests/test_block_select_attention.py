@@ -258,3 +258,95 @@ def test_the_kernel_reports_a_tier_of_its_own(monkeypatch):
                           jnp.bfloat16, 64, 1024)
     assert said == [("h32.d128.q512.kv131072.bf16", "block_select", 64,
                      1024)]
+
+
+# --- the two-pass scoring kernel (in the interpreter) ------------------------
+
+
+def _scored(S, Q, start, seed, block_q, block_slots, kernel):
+    """Block scores of ``Q`` neighbours at ``start`` over a cache of ``S``
+    rows whose EVERY slot holds something loud (the rule alone decides what
+    a query sees, not what a chunk has written)."""
+    k, _, q = rows(S, Q, seed=seed)
+    kc = 3.0 * k[:, :S // 2]
+    return q, bsa.block_scores(q, kc, start + jnp.arange(Q), SCALE,
+                               jnp.float32, SEL, kernel, block_q,
+                               block_slots)
+
+
+# (cache rows, queries, start, query tile, slot tile): Sc = rows / 2 slots
+SCORE_CASES = {
+    "start 0: the first tiles see no whole window": (512, 32, 0, 8, 128),
+    "a chunk edge": (512, 32, 256, 8, 128),
+    "mid-cache, one query tile": (512, 32, 300, 32, 128),
+    # queries 250..281 see slots ..124 … ..139: the tile of 16 at 250 sees
+    # slot tile 1 (slots 128 …) only through its later queries
+    "a query tile straddles a slot tile's edge": (1024, 32, 250, 16, 128),
+    "later slot tiles all skipped": (2048, 16, 40, 8, 128),
+    "one slot tile": (512, 32, 96, 8, 256),
+    "a query tile that does not divide": (512, 24, 64, 16, 128),
+}
+
+
+@pytest.mark.parametrize("case", SCORE_CASES.values(), ids=SCORE_CASES.keys())
+def test_the_scoring_kernel_is_the_plain_softmax_summed_over_a_group(case):
+    S, Q, start, block_q, block_slots = case
+    q, want = _scored(S, Q, start, 11, block_q, block_slots, "lax")
+    _, got = _scored(S, Q, start, 11, block_q, block_slots, "interpret")
+    want, got = np.asarray(want), np.asarray(got)
+    forced = ~np.isfinite(want)
+    assert (got[forced] == want[forced]).all()
+    assert np.abs(got[~forced] - want[~forced]).max() < 1e-6
+    # tie-free inputs: the TABLES are equal
+    assert (np.asarray(bsa.select(jnp.asarray(got), SEL))
+            == np.asarray(bsa.select(jnp.asarray(want), SEL))).all()
+
+
+def _sums_both_ways(S, Q, start, block_q, block_slots, seed=13):
+    k, _, q = rows(S, Q, seed=seed)
+    kc = 3.0 * k[:, :S // 2]
+    bq, slots = bsa.score_tiles(Q, S // 2, block_q, block_slots)
+    got = bsa.block_score_sums(
+        bsa._head_major_tiles(q * SCALE, G, bq), kc, jnp.int32(start),
+        block_q=bq, block_slots=slots, stride=SEL.kernel_stride,
+        interpret=True)
+    want = bsa._group_sums(q * SCALE, kc, start + jnp.arange(Q),
+                           SEL.kernel_stride)
+    return np.asarray(got), np.asarray(want)
+
+
+def test_a_tile_with_no_whole_window_reads_zero():
+    """Positions 0 … 2 complete no window of four rows: their rows are 0
+    everywhere (never a softmax over nothing), their neighbours' are not."""
+    got, want = _sums_both_ways(512, 8, 0, 8, 128)
+    assert np.abs(got - want).max() < 1e-6
+    assert not got[:, :3].any() and got[:, 3:, 1].all()
+    assert np.allclose(got[:, 3:].sum(-1), J, atol=1e-5)
+
+
+@pytest.mark.parametrize("start,block_q,scored", [
+    (40, 8, 1), (250, 16, 2), (250, 32, 2), (600, 8, 3), (2040, 8, 8)])
+def test_skipped_slot_tiles_are_written_as_zeros(start, block_q, scored):
+    """A slot tile past the last one a query tile sees is never computed —
+    and reads exactly 0, never what the buffer held: the sums over a cache
+    of 1024 slots in tiles of 128, the skipped tiles counted by the rule
+    the counter uses."""
+    got, want = _sums_both_ways(2048, 32, start, block_q, 128)
+    assert np.abs(got - want).max() < 1e-6
+    last = bsa.last_slot_tile(start + np.arange(0, 32, block_q), block_q,
+                              128, SEL.kernel_stride, 8, np.clip)
+    assert last.max() + 1 == scored
+    for i, t in enumerate(last):
+        tile = got[:, i * block_q:(i + 1) * block_q]
+        assert not tile[:, :, (t + 1) * 128:].any()
+        assert tile[:, :, t * 128:(t + 1) * 128].any()
+
+
+def test_one_token_scores_in_the_plain_form(monkeypatch):
+    """``sparse_step`` holds no Pallas call whatever the platform says."""
+    monkeypatch.setattr(bsa.flash_attention, "_platform", lambda: "tpu")
+    k, v, q = rows(96, 1)
+    kc, cache = compressed(k, 64)
+    text = jax.jit(lambda: bsa.sparse_step(
+        q[0], cache, v, kc, 63, SCALE, jnp.float32, SEL)).lower().as_text()
+    assert "pallas" not in text and "custom_call" not in text

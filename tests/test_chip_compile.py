@@ -721,16 +721,51 @@ def test_the_table_driven_kernel_compiles_at_the_served_geometry(chip):
     assert "tpu_custom_call" in text and "block_select_mha" in text
 
 
+def test_the_scoring_kernel_compiles_at_the_served_geometry(chip):
+    """``block_select_attention.block_score_sums`` as the sparse layers'
+    prefill calls it (PR 48): 512 neighbouring queries a call in tiles of
+    128 × 16 heads of a K/V group (2048 rows), the 4224 compressed slots in
+    three tiles of 1408 whole lanes, two halves — a float32 ``[2048,
+    1408]`` logit tile and its temporaries in VMEM. Its name is NOT the
+    sparse kernel's (``cdtbench/kinds/sala.py: SPARSE_KERNEL`` reads that
+    one alone)."""
+    from comfyui_distributed_tpu.models.llm_sala import SalaConfig
+    from comfyui_distributed_tpu.ops import block_select_attention as bsa
+
+    cfg = SalaConfig.sala_cut()
+    G, d = cfg.num_key_value_heads, cfg.head_dim
+    J = cfg.num_attention_heads // G
+    Sc = cfg.cache_slots(cfg.cache_rows(65536 + 128))
+    bq, slots = bsa.score_tiles(cfg.select_rows, Sc)
+    assert (Sc, bq, slots, J * bq) == (4224, 128, 1408, 2048)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    lowered = bsa.block_score_sums.lower(
+        arg((G, cfg.select_rows // bq, J * bq, d)), arg((G, Sc, d)),
+        arg((), jnp.int32), block_q=bq, block_slots=slots,
+        stride=cfg.kernel_stride, interpret=False)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text and "block_score_sums" in text
+    assert not re.search(r"(?m)^\s*%?block_select_mha", text)
+    assert f"f32[{G},{cfg.select_rows},{Sc}]" in text       # the group sums
+
+
 def test_the_selecting_rewriters_programs_fit_beside_sdxl(chip, monkeypatch):
     """Both language programs of ``minicpm-sala.brief64k-sdxl8`` at the
     cell's sizes (65 536 + 128 tokens, published widths, 12 layers): they
     compile for the chip and leave room for SDXL's segment program (4.79 +
-    0.56 GiB) in 15.75 GiB; the prefill holds ONE Pallas call site a sparse
-    layer; no float32 buffer the size of a whole chunk's scores —
-    ``[4096, 32 heads, 4224 slots]``, 2.1 GiB — exists (the queries score
-    512 at a time, the slots in whole lanes: ``cache_slots``); and
-    ``llm_decode``'s token loop copies nothing of 1 MiB (the states are a
-    leaf a layer: stacked, all nine were copied a token)."""
+    0.56 GiB) in 15.75 GiB; the prefill holds TWO Pallas call sites a
+    sparse layer — the scoring kernel, then the table-driven one —; the
+    largest float32 buffer with a ``slots`` axis is the GROUP sums ``[2,
+    512, 4224]`` (PR 48: no per-head ``[2, 8192, 4224]`` scores are
+    written, and never a whole chunk's ``[4096, 32 heads, 4224 slots]``,
+    2.1 GiB: the queries score 512 at a time, the slots in whole lanes:
+    ``cache_slots``); and ``llm_decode`` holds no Pallas call — one token
+    scores in the plain form — and its token loop copies nothing of 1 MiB
+    (the states are a leaf a layer: stacked, all nine were copied a
+    token)."""
     from comfyui_distributed_tpu.models.llm_sala import SalaConfig
 
     monkeypatch.setattr(fa, "_platform", lambda: "tpu")
@@ -738,18 +773,20 @@ def test_the_selecting_rewriters_programs_fit_beside_sdxl(chip, monkeypatch):
     compiled = loop_copies.compiled_programs(cfg, 65536, 128, chip)
     gib, sdxl = 2.0 ** 30, 4.79 + 0.56
     text = compiled["llm_prefill"].as_text()
-    assert len(_pallas_calls(text)) == len(cfg.sparse_layers) == 3
+    calls = _pallas_calls(text)
+    assert len(calls) == 2 * len(cfg.sparse_layers) == 6
+    assert sum("block_score_sums" in c for c in calls) \
+        == sum("block_select_mha" in c for c in calls) == 3
     slots = cfg.cache_slots(cfg.cache_rows(65536 + 128))
     scores = [math.prod(int(n) for n in shape.split(","))
               for shape in re.findall(r"f32\[([\d,]+)\]", text)
               if str(slots) in shape.split(",")]
     assert slots == 4224 and scores
-    assert max(scores) == 2 * cfg.select_rows * 16 * slots \
-        < 4096 * 32 * slots // 4
+    assert max(scores) == 2 * cfg.select_rows * slots
     mem = compiled["llm_prefill"].memory_analysis()
     prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                    + mem.output_size_in_bytes) / gib
-    assert 8.0 < prefill_gib < 9.0 and prefill_gib + sdxl < 15.75 - 1.0
+    assert 8.0 < prefill_gib < 8.4 and prefill_gib + sdxl < 15.75 - 1.0
     text = compiled["llm_decode"].as_text()
     assert "tpu_custom_call" not in text                   # decode is XLA
     assert not _copy_sizes(text, True)

@@ -152,7 +152,10 @@ def test_the_reference_given_tables_holds_the_selection_fixed(
             == np.asarray(keep["chosen"][1])[:, ::2]).all()
 
 
-def test_the_models_tables_are_the_references(params, sparse_ids):
+@pytest.mark.parametrize("kernel", [None, "interpret"])
+def test_the_models_tables_are_the_references(params, sparse_ids, kernel):
+    """``interpret``: the prefill's scoring and sparse kernels, as on the
+    chip; the decode steps score in the plain form either way."""
     keep = {}
     R.forward(CFG, params, sparse_ids, keep=keep)
     cache = S.empty_cache(CFG, 80)
@@ -161,7 +164,8 @@ def test_the_models_tables_are_the_references(params, sparse_ids):
         valid = min(16, T_SPARSE - 16 * i)
         chunk = jnp.pad(sparse_ids[16 * i:16 * i + valid], (0, 16 - valid))
         *_, cache, _, _, kept = (None,) + S.prefill_chunk(
-            CFG, params, cache, chunk, 16 * i, valid, keep_tables=True)
+            CFG, params, cache, chunk, 16 * i, valid, kernel=kernel,
+            keep_tables=True)
         kept = [np.asarray(t)[:, :valid] for t in kept]
         tables = kept if tables is None else [
             np.concatenate([a, t], 1) for a, t in zip(tables, kept)]
@@ -299,6 +303,31 @@ def _shipped_graph(tmp_path, seed):
     return graph
 
 
+def _brute_slot_tiles(cfg, prompt_tokens, new_tokens):
+    """The scoring kernel's (query tile, slot tile) pairs of a request, a
+    pair at a time: scored where the slot tile is the first or holds a
+    window that is whole for a query of the tile."""
+    from comfyui_distributed_tpu.ops import block_select_attention as bsa
+
+    chunk = min(cfg.prefill_chunk_tokens, prompt_tokens)
+    walked = -(-prompt_tokens // chunk) * chunk
+    rows = cfg.cache_rows(max(prompt_tokens + new_tokens, walked))
+    if rows <= cfg.dense_len:
+        return {"scored": 0, "skipped": 0}
+    Sc, st = cfg.cache_slots(rows), cfg.kernel_stride
+    bq, slots = bsa.score_tiles(math.gcd(chunk, cfg.select_rows), Sc)
+    scored = skipped = 0
+    for first in range(0, walked, bq):
+        last_pos = first + bq - 1
+        for t in range(Sc // slots):
+            seen = t == 0 or any(
+                slot >= 1 and st * (slot + 1) <= last_pos + 1
+                for slot in range(t * slots, (t + 1) * slots))
+            scored, skipped = scored + seen, skipped + (not seen)
+    n = len(cfg.sparse_layers) * cfg.num_key_value_heads
+    return {"scored": n * scored, "skipped": n * skipped}
+
+
 def test_the_shipped_graph_runs_and_the_counters_move_as_stated(tmp_path):
     from comfyui_distributed_tpu import telemetry
     from comfyui_distributed_tpu.graph.executor import (GraphExecutor,
@@ -312,6 +341,8 @@ def test_the_shipped_graph_runs_and_the_counters_move_as_stated(tmp_path):
                      for k in CFG.attended_keys(40, 8)},
             "blocks": {k: tm.LLM_SELECT_BLOCKS.labels(kind=k).value
                        for k in ("forced", "chosen")},
+            "tiles": {k: tm.LLM_SELECT_SLOT_TILES.labels(kind=k).value
+                      for k in ("scored", "skipped")},
             "slots": sum(tm.LLM_EXPERT_SLOTS.labels(where=w, phase=p).value
                          for w in ("held", "absent")
                          for p in ("prefill", "decode")),
@@ -332,6 +363,11 @@ def test_the_shipped_graph_runs_and_the_counters_move_as_stated(tmp_path):
             assert after["keys"][key] - before["keys"][key] == 3 * n, key
         for kind, n in CFG.selected_blocks(40, 8).items():
             assert after["blocks"][kind] - before["blocks"][kind] == 3 * n
+        # three chunks of 16 in query tiles of 8, 128 slots in one tile
+        assert _brute_slot_tiles(CFG, 40, 8) == {"scored": 2 * 2 * 6,
+                                                 "skipped": 0}
+        for kind, n in _brute_slot_tiles(CFG, 40, 8).items():
+            assert after["tiles"][kind] - before["tiles"][kind] == 3 * n
         rows = CFG.cache_rows(48)
         assert tm.LLM_CACHE_BYTES.labels(layers="sparse_index").value \
             == 2 * 2 * 128 * 8 * 4
@@ -358,12 +394,27 @@ def test_the_rules_counts_are_a_brute_force_count():
     assert keys[("lightning", "prefill")] == 3 * T * (T + 1) // 2
     assert CFG.selected_blocks(T, new) == {
         "forced": 2 * 2 * forced, "chosen": 2 * 2 * (read - forced)}
+    # the scoring kernel's slot tiles: one tile of 128 slots, then two of
+    # which the early chunks skip the second; none within dense_len
+    assert CFG.scored_slot_tiles(T, new) == _brute_slot_tiles(CFG, T, new) \
+        == {"scored": 2 * 2 * 10, "skipped": 0}
+    tiles = CFG.scored_slot_tiles(440, 8)
+    assert tiles == _brute_slot_tiles(CFG, 440, 8)
+    assert tiles["scored"] + tiles["skipped"] == 2 * 2 * 56 * 2
+    assert tiles["skipped"] == 2 * 2 * 32      # tiles whose last row < 257
+    assert CFG.scored_slot_tiles(20, 6) == {"scored": 0, "skipped": 0}
     # a request within dense_len reads every row
     assert CFG.attended_keys(20, 6)[("sparse", "prefill")] == 2 * 210
     full = S.SalaConfig.sala_cut()
     keys = full.attended_keys(65536, 128)
     share = keys[("sparse", "prefill")] / (3 * 65536 * 65537 / 2)
     assert share == pytest.approx(0.1778, abs=1e-4)
+    # the cell's request: 512 query tiles of 128 × 3 slot tiles of 1408,
+    # three layers, two groups — the causal skip takes a third
+    tiles = full.scored_slot_tiles(65536, 128)
+    assert tiles == _brute_slot_tiles(full, 65536, 128)
+    assert tiles["scored"] + tiles["skipped"] == 3 * 2 * 512 * 3
+    assert tiles["skipped"] == 3 * 2 * (176 + 352)    # below slot 1408, 2816
 
 
 # --- the benchmark's files ----------------------------------------------------
