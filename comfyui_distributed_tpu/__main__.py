@@ -6,15 +6,24 @@ The reference's entry is ComfyUI's ``main.py`` with plugin loading
 
 from __future__ import annotations
 
-import argparse
-import asyncio
-import json
-import sys
+import time
+
+_T0 = time.perf_counter()      # where cdt_boot_seconds{phase=import} begins
+
+import argparse  # noqa: E402
+import asyncio  # noqa: E402
+import json  # noqa: E402
+import sys  # noqa: E402
 
 
 def cmd_serve(args: argparse.Namespace) -> None:
+    """Boot a host controller. Every second up to the listening HTTP server
+    is put down to a phase (``telemetry/build.py``): ``import``,
+    ``backend`` or ``controller`` — spans ``boot.*`` of the one trace
+    ``boot`` (``GET /distributed/trace/boot``), ``cdt_boot_seconds``."""
     from .parallel.bootstrap import (ensure_virtual_devices,
                                      init_multihost)
+    from .telemetry.build import boot_elapsed, boot_phase
     from .utils.compile_cache import enable_compile_cache
 
     # CDT_VIRTUAL_DEVICES: stand up the virtual CPU mesh BEFORE anything
@@ -22,46 +31,57 @@ def cmd_serve(args: argparse.Namespace) -> None:
     # then serveable on a chipless host (docs/parallelism.md)
     ensure_virtual_devices()
 
-    # persistent XLA compile cache BEFORE the first trace: full-scale
-    # sampler/ladder programs take minutes to compile (the offload
-    # ladders recompile per sigma-ladder length) — a server restart or
-    # step-count change must not re-pay compiles it has already done
-    enable_compile_cache()
+    import jax  # noqa: F401 — timed as an import, not as the backend's start
 
-    # must precede any jax device query (backend freezes on first touch);
-    # no-op without a coordinator (single host)
-    init_multihost(
-        coordinator_address=getattr(args, "coordinator", None),
-        num_processes=getattr(args, "num_hosts", None),
-        process_id=getattr(args, "host_index", None),
-    )
+    boot_elapsed("import", _T0)
+    with boot_phase("backend"):
+        # persistent XLA compile cache BEFORE the first trace: full-scale
+        # sampler/ladder programs take minutes to compile (the offload
+        # ladders recompile per sigma-ladder length) — a server restart or
+        # step-count change must not re-pay compiles it has already done
+        enable_compile_cache()
 
-    from .api.app import run_app
-    from .cluster.controller import Controller
-    from .parallel.mesh import device_census
-    from .utils.config import update_config
-    from .utils.logging import log
-    from .workers.detection import auto_populate_hosts
-    from .workers.process_manager import delayed_auto_launch, get_worker_manager
+        # must precede any jax device query (backend freezes on first
+        # touch); no-op without a coordinator (single host)
+        init_multihost(
+            coordinator_address=getattr(args, "coordinator", None),
+            num_processes=getattr(args, "num_hosts", None),
+            process_id=getattr(args, "host_index", None),
+        )
+
+    with boot_phase("import"):
+        from .api.app import run_app
+        from .cluster.controller import Controller
+        from .parallel.mesh import device_census
+        from .utils.config import update_config
+        from .utils.logging import log
+        from .workers.detection import auto_populate_hosts
+        from .workers.process_manager import (delayed_auto_launch,
+                                              get_worker_manager)
 
     # claim the devices NOW: this process owns every chip of the host for
     # its lifetime (docs/deployment.md, "One process per chip"), and a
     # backend that cannot start is a failed boot — not a server that finds
     # out on its first request, and never a server on another platform
-    census = device_census()
+    with boot_phase("backend"):
+        census = device_census()
     log(f"devices: {len(census)} x {census[0]['platform']} "
         f"({census[0]['kind']})")
 
-    controller = Controller()
-    if not controller.is_worker and not controller.load_config().get(
-            "settings", {}).get("has_auto_populated_workers"):
-        # first-launch auto-configuration (reference auto-populates one
-        # worker per CUDA device, web/masterDetection.js:36-100; here: one
-        # controller per TPU slice host advertised by the runtime)
-        update_config(auto_populate_hosts, controller.config_path)
+    with boot_phase("controller"):
+        controller = Controller()
+        if not controller.is_worker and not controller.load_config().get(
+                "settings", {}).get("has_auto_populated_workers"):
+            # first-launch auto-configuration (reference auto-populates
+            # one worker per CUDA device, web/masterDetection.js:36-100;
+            # here: one controller per TPU slice host advertised by the
+            # runtime)
+            update_config(auto_populate_hosts, controller.config_path)
 
     async def main() -> None:
-        runner = await run_app(controller, host=args.host, port=args.port)
+        with boot_phase("controller"):
+            runner = await run_app(controller, host=args.host,
+                                   port=args.port)
         if not controller.is_worker:
             manager = get_worker_manager()
             asyncio.ensure_future(delayed_auto_launch(manager))

@@ -99,11 +99,11 @@ def bind_weights(jitted, weights, label: "str | None" = None,
     is waited for (``block_until_ready`` under a ``program.wait`` span —
     callers materialize the output immediately anyway) and recorded as
     ``cdt_pipeline_compile_seconds{pipeline=label}`` on the first call
-    (which pays trace + XLA compile) vs ``cdt_pipeline_execute_seconds``
+    (trace + build + first run: ``_first_call``, at the file's end, also
+    records it net of the build) vs ``cdt_pipeline_execute_seconds``
     after; with ``steps`` the per-step quotient also lands in
-    ``cdt_sampler_step_seconds``. ``span_attrs`` are further attributes of
-    the ``pipeline_call`` span (a chunked prefill's ``chunk``). With
-    telemetry disabled the call path is exactly the old one-liner."""
+    ``cdt_sampler_step_seconds``. ``span_attrs``: more attributes of the
+    ``pipeline_call`` span (a ``chunk``). Disabled: the old one-liner."""
     from ..telemetry import enabled as _tm_enabled
 
     if mesh is not None:
@@ -134,7 +134,7 @@ def bind_weights(jitted, weights, label: "str | None" = None,
         dt = time.perf_counter() - t0  # cdtlint: disable=D001
         if state["first"]:
             state["first"] = False
-            _tm.PIPELINE_COMPILE_SECONDS.labels(pipeline=label).observe(dt)
+            _first_call(label, t0, dt)
         else:
             _tm.PIPELINE_EXECUTE_SECONDS.labels(pipeline=label).observe(dt)
         if steps:
@@ -1447,3 +1447,15 @@ def demux_microbatch(out: jax.Array, mesh: Mesh, n_requests: int,
                   for i in range(n_dp)]
         per_request.append(jnp.concatenate(blocks, axis=0))
     return per_request
+
+
+def _first_call(label: str, t0: float, dt: float) -> None:
+    """A labelled program's first call (``bind_weights``): its whole wall
+    time as ``cdt_pipeline_compile_seconds``, and what is left of it once
+    the build seconds JAX reported meanwhile are taken out, as
+    ``cdt_program_build_seconds{phase=first_run}``. Down here, and not
+    beside its one caller, because the compile cache's key holds the line
+    every operation above is traced at (``utils/compile_cache.py``)."""
+    from ..telemetry.build import first_call
+
+    first_call(label, t0, dt)

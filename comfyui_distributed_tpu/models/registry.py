@@ -827,9 +827,10 @@ class LLMBundle:
             cache.clear()
 
 
-def _note_weights(name: str, bundle: "ModelBundle") -> None:
-    """Say what was just put on the device: bytes, the dtype the
-    denoiser is held in, and which text tower will serve prompts."""
+def _note_weights(name: str, bundle: "ModelBundle") -> int:
+    """Say what was just put on the device: bytes (answered too), the
+    dtype the denoiser is held in, and which text tower will serve
+    prompts."""
     from ..cluster.residency import bundle_bytes
     from ..telemetry import enabled as _tm_enabled
     from ..telemetry import metrics as _tm
@@ -842,6 +843,7 @@ def _note_weights(name: str, bundle: "ModelBundle") -> None:
         _tm.MODEL_WEIGHT_BYTES.labels(
             model=name, dtype=dtype,
             text_tower=bundle.text_tower()).set(float(nbytes))
+    return nbytes
 
 
 class ModelRegistry:
@@ -881,9 +883,7 @@ class ModelRegistry:
                 if preset is None:
                     raise ValidationError(f"unknown model {name!r}; have {self.available()}")
                 ckpt = self.checkpoint_root / name if self.checkpoint_root else None
-                build = LLMBundle if preset.kind == "llm" else ModelBundle
-                self._cache[name] = bundle = build(preset, ckpt)
-                _note_weights(name, bundle)
+                self._cache[name] = _build_bundle(name, preset, ckpt)
             bundle = self._cache[name]
             if self.residency is not None:
                 try:
@@ -900,3 +900,19 @@ class ModelRegistry:
                 # the registry (cluster/residency.pinned_bundle)
                 bundle._residency = self.residency
             return bundle
+
+
+def _build_bundle(name: str, preset, ckpt) -> ModelBundle:
+    """Construct a preset's bundle under a ``weights.init`` span (attrs
+    ``model``, ``bytes``) whose seconds, net of the programs built inside,
+    are ``cdt_weights_seconds{model, phase=init}``. HOST seconds: an
+    initialiser still running on the device when the constructor returns
+    overlaps what the host does next, and is not waited for here — what a
+    first program still waits for of it is that program's ``first_run``."""
+    from ..telemetry.build import weights_span
+
+    build = LLMBundle if preset.kind == "llm" else ModelBundle
+    with weights_span("init", name) as attrs:
+        bundle = build(preset, ckpt)
+        attrs(bytes=_note_weights(name, bundle))
+    return bundle

@@ -91,7 +91,7 @@ def replicate(mesh: Mesh, tree: Any) -> Any:
     if not mesh.devices.flat[0].client.local_devices():
         return tree
     from .. import telemetry
-    from ..telemetry.spans import span
+    from ..telemetry.build import weights_span
 
     sh = replicated_sharding(mesh)
     mesh_key = mesh_cache_key(mesh)
@@ -111,8 +111,8 @@ def replicate(mesh: Mesh, tree: Any) -> Any:
             else:
                 leaves[i], found = placed, found + 1
         if move:
-            with span("weights.place", leaves=len(move),
-                      devices=mesh.devices.size):
+            with weights_span("place", "", leaves=len(move),
+                              devices=mesh.devices.size):
                 moved = jax.block_until_ready(
                     jax.device_put([leaves[i] for i in move], sh))
             for i, placed in zip(move, moved):

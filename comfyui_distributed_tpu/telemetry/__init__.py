@@ -6,7 +6,7 @@ communication is what lets a distributed stack find overlap opportunities
 and diagnose concurrency ceilings (PAPERS.md: T3, arxiv 2401.16677;
 TPU-concurrency limits, arxiv 2011.03641).
 
-Four modules, stdlib-only by contract (``device_scopes`` imports JAX
+Five modules, stdlib-only by contract (``device_scopes`` imports JAX
 inside its one function, when a program is traced):
 
 - ``registry``  — process-global, thread/async-safe Counter / Gauge /
@@ -17,7 +17,10 @@ inside its one function, when a program is traced):
   rendered from one ``snapshot()``;
 - ``device_scopes`` — the registry of device layers and
   ``device_scope(layer)``, the ``cdt.<layer>`` name a traced operation
-  carries into the profiler's trace (the device-side twin of ``span``).
+  carries into the profiler's trace (the device-side twin of ``span``);
+- ``build``     — the set-up ledger: boot phases, a model's weights, and
+  each program's trace / lower / cache read / compile / first run, fed by
+  the two ``jax.monitoring`` listeners it holds (imported where used).
 
 ``metrics`` declares the framework's standard families; instrumentation
 sites import those objects and guard every record with ``enabled()`` —

@@ -597,3 +597,47 @@ LLM_SELECT_SLOT_TILES = REGISTRY.counter(
     "0). From the config's sizes, the kernel's tiles and the request's "
     "token counts; a request within dense_len scores nothing.",
     ("kind",))
+
+# --- the set-up ledger (telemetry/build.py): exclusive SELF seconds -----------
+# A program's name comes from the code base, not from traffic, so these two
+# families may hold more series than MAX_SERIES: past it the overflow series
+# would lose the phase with the name, and the phases must add up.
+
+PROGRAM_BUILD_SECONDS = REGISTRY.histogram(
+    "cdt_program_build_seconds",
+    "Seconds JAX spent building one program, by the program's name (jit_ "
+    "stripped) and phase: trace, lower (Mosaic lowering of Pallas call sites "
+    "is in here), cache_key + cache_read (a persistent-cache hit: the read, "
+    "decompression, deserialisation and load of the executable, and the "
+    "rest of the lookup) or compile (anything else: compiled), and "
+    "first_run (a labelled program's first call, under its label, net of "
+    "the other phases). SELF seconds: an inner program's build inside a "
+    "trace is the inner program's, so the series add up to wall time. Its "
+    "count is how often.",
+    ("program", "phase"), buckets=(0.01, 0.1, 1.0, 10.0, 100.0),
+    max_series=2048)
+
+PROGRAM_CACHE = REGISTRY.counter(
+    "cdt_program_cache_total",
+    "Executables JAX got, by the program's name and what the persistent "
+    "cache did: hit (read), miss (compiled and written: on a warm start a "
+    "program of >= 1 s compiled AGAIN) or uncached (compiled and not "
+    "written — under the time or size threshold — or never looked up).",
+    ("program", "outcome"), max_series=1024)
+
+WEIGHTS_SECONDS = REGISTRY.histogram(
+    "cdt_weights_seconds",
+    "Seconds of making a model's weights, net of program builds inside: "
+    "init (a bundle's construction in the registry, host seconds: model "
+    "is the preset) or place (a tree's transfer onto a mesh, "
+    "parallel/sharding.replicate: model is empty, a tree has no name).",
+    ("model", "phase"), buckets=COMPILE_BUCKETS)
+
+BOOT_SECONDS = REGISTRY.gauge(
+    "cdt_boot_seconds",
+    "Seconds of the serve process's boot, by phase: import (the first line "
+    "of __main__.py to the end of cmd_serve's imports, jax among them), "
+    "backend (compile cache on, multi-host init, the device census: the "
+    "first touch of the chip), controller (Controller() to the HTTP "
+    "server listening). Net of program builds inside; set once.",
+    ("phase",))

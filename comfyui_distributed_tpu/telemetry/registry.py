@@ -14,10 +14,11 @@ no clock reads, no label lookups, no lock traffic.
 
 Label sets are frozen at declaration (``labelnames``); per-series children
 are keyed by the tuple of label *values* in declaration order. Cardinality
-is capped per metric (``MAX_SERIES``): past the cap, new label sets
-collapse into one ``~overflow~`` series and the drop is counted — a
-runaway label (e.g. a per-request id) can degrade resolution but can
-never leak memory without bound.
+is capped per metric (``MAX_SERIES``, or the family's own ``max_series``
+where its labels are bounded by the code base, not by traffic): past the
+cap, new label sets collapse into one ``~overflow~`` series and the drop
+is counted — a runaway label (e.g. a per-request id) can degrade
+resolution but can never leak memory without bound.
 """
 
 from __future__ import annotations
@@ -137,10 +138,12 @@ class _HistogramValue:
 class _Metric:
     kind = "untyped"
 
-    def __init__(self, name: str, help: str, labelnames: Sequence[str] = ()):
+    def __init__(self, name: str, help: str, labelnames: Sequence[str] = (),
+                 max_series: int = MAX_SERIES):
         self.name = name
         self.help = help
         self.labelnames = tuple(labelnames)
+        self.max_series = max_series
         self._lock = tracked_lock("telemetry.family")
         self._children: dict[tuple, object] = {}
         self._dropped = 0
@@ -159,7 +162,7 @@ class _Metric:
         with self._lock:
             child = self._children.get(key)
             if child is None:
-                if len(self._children) >= MAX_SERIES:
+                if len(self._children) >= self.max_series:
                     self._dropped += 1
                     key = (_OVERFLOW,) * len(self.labelnames)
                     child = self._children.get(key)
@@ -222,11 +225,12 @@ class Histogram(_Metric):
     kind = "histogram"
 
     def __init__(self, name, help, labelnames=(),
-                 buckets: Optional[Sequence[float]] = None):
+                 buckets: Optional[Sequence[float]] = None,
+                 max_series: int = MAX_SERIES):
         self.buckets = tuple(sorted(buckets or DURATION_BUCKETS))
         if not self.buckets:
             raise ValueError(f"{name}: histogram needs at least one bucket")
-        super().__init__(name, help, labelnames)
+        super().__init__(name, help, labelnames, max_series)
 
     def _make_value(self):
         return _HistogramValue(self._lock, self.buckets)
@@ -260,8 +264,10 @@ class MetricRegistry:
             return m
 
     def counter(self, name: str, help: str = "",
-                labelnames: Sequence[str] = ()) -> Counter:
-        return self._get_or_create(Counter, name, help, labelnames)
+                labelnames: Sequence[str] = (),
+                max_series: int = MAX_SERIES) -> Counter:
+        return self._get_or_create(Counter, name, help, labelnames,
+                                   max_series=max_series)
 
     def gauge(self, name: str, help: str = "",
               labelnames: Sequence[str] = ()) -> Gauge:
@@ -269,9 +275,10 @@ class MetricRegistry:
 
     def histogram(self, name: str, help: str = "",
                   labelnames: Sequence[str] = (),
-                  buckets: Optional[Sequence[float]] = None) -> Histogram:
+                  buckets: Optional[Sequence[float]] = None,
+                  max_series: int = MAX_SERIES) -> Histogram:
         return self._get_or_create(Histogram, name, help, labelnames,
-                                   buckets=buckets)
+                                   buckets=buckets, max_series=max_series)
 
     def snapshot(self) -> dict:
         """Structured export form — the single source both renderers

@@ -45,9 +45,10 @@ from .registry import REGISTRY, enabled
 
 TRACE_HEADER = "X-CDT-Trace"
 
-# (trace_id, span_id) of the innermost active span; span_id may be "" when
-# only a remote parent context was adopted (use_trace without a local span)
-_CTX: "contextvars.ContextVar[Optional[tuple[str, str]]]" = \
+# (trace_id, span_id, attrs) of the innermost active span; span_id may be ""
+# and attrs None when only a remote parent context was adopted (use_trace
+# without a local span)
+_CTX: "contextvars.ContextVar[Optional[tuple[str, str, Optional[dict]]]]" = \
     contextvars.ContextVar("cdt_trace", default=None)
 
 _SPAN_SECONDS = REGISTRY.histogram(
@@ -66,9 +67,10 @@ def new_trace_id() -> str:
 class SpanStore:
     """Bounded in-memory ring of finished spans, grouped by trace.
 
-    Oldest traces are evicted first; a single trace is capped so a runaway
-    loop cannot grow one entry without bound. ``resolve`` maps a job or
-    prompt id (seen as a span attribute) back to its trace."""
+    Oldest traces are evicted first, a pinned one (``pin``: the process's
+    ``boot`` trace) never; a single trace is capped so a runaway loop
+    cannot grow one entry without bound. ``resolve`` maps a job or prompt
+    id (seen as a span attribute) back to its trace."""
 
     def __init__(self, max_traces: int = 256, max_spans: int = 512):
         self.max_traces = max_traces
@@ -76,6 +78,11 @@ class SpanStore:
         self._lock = threading.Lock()
         self._traces: "OrderedDict[str, list[dict]]" = OrderedDict()
         self._by_key: dict[str, str] = {}
+        self._pinned: set[str] = set()
+
+    def pin(self, trace_id: str) -> None:
+        """Keep that trace out of the eviction order."""
+        self._pinned.add(trace_id)
 
     def record(self, span: dict) -> None:
         tid = span["trace_id"]
@@ -84,7 +91,9 @@ class SpanStore:
             if spans is None:
                 spans = self._traces[tid] = []
                 while len(self._traces) > self.max_traces:
-                    old_tid, _ = self._traces.popitem(last=False)
+                    old_tid = next(t for t in self._traces
+                                   if t not in self._pinned)
+                    del self._traces[old_tid]
                     for k in [k for k, v in self._by_key.items()
                               if v == old_tid]:
                         del self._by_key[k]
@@ -168,7 +177,7 @@ def span(name: str, trace_id: Optional[str] = None,
     if parent_id is None and cur and cur[0] == trace_id:
         parent_id = cur[1] or None
     span_id = secrets.token_hex(4)
-    token = _CTX.set((trace_id, span_id))
+    token = _CTX.set((trace_id, span_id, attrs))
     annotation = None
     if _ANNOTATOR is not None and not _in_asyncio_task():
         annotation = _ANNOTATOR(ANNOTATION_PREFIX + name)
@@ -205,6 +214,14 @@ def _finish(name, trace_id, span_id, parent_id, start, duration, attrs,
         rec["error"] = error
     STORE.record(rec)
     _SPAN_SECONDS.labels(name=name).observe(duration)
+
+
+def set_span_attrs(**attrs) -> None:
+    """Add attributes to the innermost active span: what is known only
+    once its work is done (the bytes of what it built)."""
+    cur = _CTX.get()
+    if cur and cur[2] is not None:
+        cur[2].update(attrs)
 
 
 @contextmanager
@@ -246,7 +263,7 @@ def use_trace(trace_id: str, parent_span_id: Optional[str] = None):
     """Adopt a remote trace context (parsed from ``X-CDT-Trace``) for the
     duration of the block: spans opened inside join ``trace_id`` with
     ``parent_span_id`` as their parent."""
-    token = _CTX.set((trace_id, parent_span_id or ""))
+    token = _CTX.set((trace_id, parent_span_id or "", None))
     try:
         yield
     finally:
@@ -271,7 +288,7 @@ def trace_headers() -> dict:
     cur = _CTX.get()
     if not cur:
         return {}
-    tid, sid = cur
+    tid, sid = cur[:2]
     return {TRACE_HEADER: f"{tid}:{sid}" if sid else tid}
 
 

@@ -113,31 +113,24 @@ def enable_compile_cache(path: Optional[str] = None,
 
     if _tm_enabled():
         _tm.COMPILE_CACHE_ENABLED.set(1.0)
-        _count_compiles(_tm)
+        _count_compiles()
     return d
 
 
-def _count_compiles(_tm) -> None:
+def _count_compiles() -> None:
     """Feed jax's own compile and cache events into the metrics registry
     (once per process): whether a restart found its programs on disk is
     then a number in ``/distributed/metrics.json``, not a guess from
-    directory listings."""
+    directory listings — and so is WHICH program it did not find, and
+    what each program's tracing, lowering, cache read or compile took
+    (``telemetry/build.py``: the set-up ledger, and the two listeners)."""
     global _listening
     if _listening:
         return
     _listening = True
     import jax.monitoring
 
-    outcomes = {"/jax/compilation_cache/cache_hits": "hit",
-                "/jax/compilation_cache/cache_misses": "miss"}
+    from ..telemetry import build
 
-    def on_event(event: str, **_):
-        if event in outcomes:
-            _tm.COMPILE_CACHE_REQUESTS.labels(outcome=outcomes[event]).inc()
-
-    def on_duration(event: str, seconds: float, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            _tm.XLA_COMPILE_SECONDS.observe(seconds)
-
-    jax.monitoring.register_event_listener(on_event)
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(build.on_event)
+    jax.monitoring.register_event_duration_secs_listener(build.on_duration)

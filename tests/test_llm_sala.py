@@ -531,7 +531,8 @@ def test_the_cell_assembles_with_the_briefs_sizes_and_the_units_step():
     assert [m["name"] for m in ours] == SALA_METRICS
     assert all(m["workloads"] == [CELL] and m["moves"] == "request_p50_s"
                for m in ours)
-    assert bench["per_layer"][-len(ours):] == ours      # appended, last
+    first = bench["per_layer"].index(ours[0])           # appended as one run
+    assert bench["per_layer"][first:first + len(ours)] == ours
     assert bench["workloads"][-1]["name"] == CELL
     assert bench["configs"][-1]["name"] == "minicpm-sala"
     assert all(len(e["why"]) <= 200 for e in (bench["workloads"][-1],
