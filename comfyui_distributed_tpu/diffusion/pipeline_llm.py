@@ -129,10 +129,17 @@ class LLMPipeline:
         logits, cache, slots_prefill, *rows = prefill(
             jnp.asarray(ids, jnp.int32))
         # a chunked prefill counts the rows its experts multiplied (none
-        # without an expert layer); the whole-prompt form multiplies every
-        # held expert by every token
-        rows = int(np.asarray(rows[0]).sum()) if rows else (
-            len(ids) * self.config.num_experts * len(self.config.moe_layers))
+        # without an expert layer: a block-selecting model's chunks count
+        # their sparse kernel's grid steps by fetch in that place); the
+        # whole-prompt form multiplies every held expert by every token
+        counted = np.asarray(rows[0]) if rows else np.zeros((0,), np.int64)
+        if not layers:
+            rows, steps = 0, counted
+        elif rows:
+            rows, steps = int(counted.sum()), counted[:0]
+        else:
+            rows = len(ids) * self.config.num_experts * layers
+            steps = counted
         out, taps, slots_decode, finite = decode(
             logits, cache, jax.random.key(int(seed)),
             jnp.asarray(temperature, jnp.float32))
@@ -150,4 +157,4 @@ class LLMPipeline:
                 "zero_prefill": slots_prefill[layers:],
                 "zero_decode": slots_decode[layers:],
                 "prefill_chunks": chunks, "prefill_form": form,
-                "rows_prefill": rows}
+                "rows_prefill": rows, "sparse_steps": steps}

@@ -1024,6 +1024,9 @@ class TPUPromptRewrite(NodeDef):
                 for kind, n in cfg.scored_slot_tiles(prompt_tokens,
                                                      new_tokens).items():
                     _tm.LLM_SELECT_SLOT_TILES.labels(kind=kind).inc(n)
+                from ..ops.block_select_attention import STEP_FETCHES
+                for fetch, n in zip(STEP_FETCHES, out["sparse_steps"]):
+                    _tm.LLM_SPARSE_STEPS.labels(fetch=fetch).inc(int(n))
             if cfg.moe_layers:
                 _tm.LLM_EXPERT_ROWS.labels(form=out["prefill_form"]).inc(
                     out["rows_prefill"])

@@ -436,8 +436,9 @@ def _selected_rows(cfg: SalaConfig, q, k, v, kc, start, kernel, keep):
     ``select_rows`` of them score, select and attend at a time (the
     float32 sums, the sort and the masks of more would not fit beside the
     model); ``kernel`` names the form of BOTH kernels — the scores' and
-    the table-driven one. Answers the attention [C,H,d] and, where
-    ``keep``, the tables [G,C,blocks]."""
+    the table-driven one. Answers the attention [C,H,d], the sparse
+    kernel's grid steps by ``STEP_FETCHES`` [3] and, where ``keep``, the
+    tables [G,C,blocks]."""
     dtype, sel = jnp.dtype(cfg.dtype), cfg.selection
     C, H, d = q.shape
     scale = cfg.head_dim ** -0.5
@@ -450,17 +451,17 @@ def _selected_rows(cfg: SalaConfig, q, k, v, kc, start, kernel, keep):
                 q_n, kc, first + jnp.arange(n), scale, dtype, sel,
                 kernel), sel)
         with jax.named_scope("sparse_core"):
-            o = select_ops.sparse_chunk(
+            o, fetches = select_ops.sparse_chunk(
                 q_n, k, v, chosen, first, scale, dtype, sel,
                 cfg.sparse_block_q, cfg.sparse_blocks_per_step, kernel)
-        return (o, chosen) if keep else (o, None)
+        return o, fetches, (chosen if keep else None)
 
-    o, chosen = jax.lax.map(some, (q.reshape(C // n, n, H, d),
-                                   start + jnp.arange(C // n) * n))
+    o, fetches, chosen = jax.lax.map(some, (q.reshape(C // n, n, H, d),
+                                            start + jnp.arange(C // n) * n))
     if keep:
         chosen = jnp.swapaxes(chosen, 0, 1)
         chosen = chosen.reshape(chosen.shape[0], C, chosen.shape[-1])
-    return o.reshape(C, H, d), chosen
+    return o.reshape(C, H, d), fetches.sum(axis=0), chosen
 
 
 def prefill_chunk(cfg: SalaConfig, params, cache: dict, ids, start, n_valid,
@@ -471,8 +472,10 @@ def prefill_chunk(cfg: SalaConfig, params, cache: dict, ids, start, n_valid,
     and the compressed buffers stand where token ``n_valid − 1`` left
     them, and nothing reads the K/V rows they write). Continues from
     ``cache``. Answers ``(logits, cache, held, rows)`` as
-    ``llm_kimi.prefill_chunk``: ``held`` and ``rows`` are empty (no expert
-    layer). ``kernel`` names the form of the attention kernels
+    ``llm_kimi.prefill_chunk``: ``held`` is empty (no expert layer) and in
+    the ``rows`` place stand the sparse kernel's grid steps by
+    ``STEP_FETCHES``, summed over the sparse layers (empty for a dense
+    request). ``kernel`` names the form of the attention kernels
     (``pallas``, ``interpret``, ``lax``; None: the platform's).
     ``keep_tables`` (a parity tool's) appends the sparse layers'
     selections ``[G, C, blocks]``, None for a dense request."""
@@ -485,7 +488,7 @@ def prefill_chunk(cfg: SalaConfig, params, cache: dict, ids, start, n_valid,
     with device_scope("llm_attn"):
         rope = _rope_rows(params, start, C)
     cache = {k: list(v) for k, v in cache.items()}
-    tables = []
+    tables, steps = [], []
     h = _embed(cfg, params, ids)
     at_sparse = at_lightning = 0
     for i, layer in enumerate(params["layers"]):
@@ -504,9 +507,10 @@ def prefill_chunk(cfg: SalaConfig, params, cache: dict, ids, start, n_valid,
                         kc = select_ops.compress_chunk(
                             cache["kc"][j], cache["k"][j], k, start,
                             n_valid, cfg.selection)
-                    o, chosen = _selected_rows(cfg, q, k_all, v_all, kc,
-                                               start, kernel, keep_tables)
+                    o, fetches, chosen = _selected_rows(
+                        cfg, q, k_all, v_all, kc, start, kernel, keep_tables)
                     cache["kc"][j] = kc
+                    steps.append(fetches)
                     tables.append(chosen)
                 else:
                     o = gqa_attention.causal_chunk(
@@ -528,7 +532,10 @@ def prefill_chunk(cfg: SalaConfig, params, cache: dict, ids, start, n_valid,
         h = _ffn(cfg, layer, h)
     with device_scope("llm_head"):
         last = h if all_logits else h[n_valid - 1]
-    out = (logits_of(cfg, params, last), cache, _no_held(), _no_held())
+    with device_scope("llm_sample"):
+        # in the place of the rows an expert layer multiplied (none here)
+        steps = sum(steps) if steps else _no_held()
+    out = (logits_of(cfg, params, last), cache, _no_held(), steps)
     return out + ((tables if selects else None),) if keep_tables else out
 
 

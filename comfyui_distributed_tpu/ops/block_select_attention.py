@@ -39,11 +39,11 @@ decoded token's form, and what the kernel is held to.
 **The chunk form** (:func:`sparse_chunk`) serves ``block_q`` NEIGHBOURING
 queries' ``J · block_q`` head rows a tile: what neighbours share is the
 tile's UNION of blocks — an ascending list a tile, handed to the Pallas
-kernel (:func:`block_select_mha`) as a scalar-prefetched table that drives
-the K and V tile index (``blocks_per_step`` tiles a grid step, each a
-BlockSpec of the same cache); a per-(query, union entry) mask says which
-entries are each query's own, and entries past a tile's count are skipped
-(their index repeats the last, so nothing is fetched). Neighbours that
+kernel (:func:`block_select_mha`) as a scalar-prefetched table that its
+own copies of K and V rows follow (``blocks_per_step`` blocks a grid step:
+ONE stretch of the cache where the entries are consecutive, else a copy a
+block); a per-(query, union entry) mask says which entries are each query's
+own, and steps past a tile's count fetch nothing. Neighbours that
 choose alike cost what they selected; neighbours that choose apart cost
 their union, never more than the causal prefix. ``lax``: the masked
 softmax over all rows, plainly — what the kernel is held to.
@@ -371,40 +371,124 @@ def select(score, sel: Selection):
 # --- the chunk form ------------------------------------------------------------
 
 
-def _block_select_kernel(union_ref, count_ref, pos_ref, q_ref, *refs,
-                         block_q: int, block: int, per_step: int,
-                         heads: int, tiles: int, union_len: int, precision):
-    k_refs, v_refs = refs[:per_step], refs[per_step:2 * per_step]
-    mask_ref, o_ref, m_ref, l_ref, acc_ref = refs[2 * per_step:]
+# a grid step of the table-driven kernel by how its K and V rows arrive
+# (``cdt_llm_sparse_steps_total``'s labels, in the counts' order)
+STEP_FETCHES = ("run", "blocks", "skipped")
+
+
+def _visible(e, count, per_step: int):
+    """Does step ``e`` of a tile whose union holds ``count`` blocks hold
+    one? (Step 0 always: every query reads its own block.)"""
+    return (e == 0) | (e * per_step < count)
+
+
+def _is_run(first, last, per_step: int):
+    """Are a step's ``per_step`` ascending entries CONSECUTIVE blocks?
+    (Entries past a tile's count repeat the last, so a step the count cuts
+    is never one.)"""
+    return last - first == per_step - 1
+
+
+def _kv_copies(act, union_ref, k_hbm, v_hbm, k_buf, v_buf, sem, tile, e,
+               slot, *, block: int, per_step: int, tiles: int,
+               union_len: int):
+    """``act`` (start, or wait for) the copies that bring the K and V rows
+    of step ``e`` of ``tile`` into buffer ``slot``: ONE copy each of
+    ``per_step · block`` rows of the cache where the step's entries are a
+    run, else a copy a block — either way the rows land where the step
+    reads them, side by side."""
+    base = tile * union_len + e * per_step
+    g, first = tile // tiles, union_ref[base]
+
+    def both(at, rows: int, to: int):
+        at = pl.multiple_of(at * block, block)
+        for n, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+            act(pltpu.make_async_copy(hbm.at[g, pl.ds(at, rows)],
+                                      buf.at[slot, pl.ds(to, rows)],
+                                      sem.at[n, slot]))
+
+    run = _is_run(first, union_ref[base + per_step - 1], per_step)
+    pl.when(run)(lambda: both(first, per_step * block, 0))
+
+    @pl.when(jnp.logical_not(run))
+    def _pieces():
+        for r in range(per_step):
+            both(union_ref[base + r], block, r * block)
+
+
+def _spread_own(own, union_ref, base, *, block: int):
+    """A step's per-query mask ``own`` [block_q, R] (1 where the query
+    reads the union entry) spread over its blocks' columns ``[block_q, R ·
+    block]``, and each column's position in the cache ``[1, R · block]``
+    (``base``: the step's first entry in the flat table)."""
+    block_q, per_step = own.shape
+    width = per_step * block
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    mine = jnp.zeros((block_q, width), jnp.float32)
+    col_pos = jnp.zeros((1, width), jnp.int32)
+    for r in range(per_step):
+        here = (col // block) == r
+        mine = jnp.where(here, own[:, r:r + 1], mine)
+        col_pos = jnp.where(here, union_ref[base + r] * block + col % block,
+                            col_pos)
+    return mine, col_pos
+
+
+def _own_logits(s, own, union_ref, base, first_row, *, block: int,
+                heads: int):
+    """The logits ``s`` [heads · block_q, R · block] (head-major) of a
+    step with every column a row's QUERY may not read at ``NEG_INF``: it
+    reads its own entries of the union and in them the rows at or below
+    its position (``first_row`` the tile's first)."""
+    mine, col_pos = _spread_own(own, union_ref, base, block=block)
+    row_pos = first_row + jax.lax.broadcasted_iota(
+        jnp.int32, (own.shape[0], 1), 0)
+    seen = jnp.where(col_pos <= row_pos, mine, 0.0)
+    seen = jnp.concatenate([seen] * heads, axis=0)            # head-major
+    return jnp.where(seen > 0.5, s, NEG_INF)
+
+
+def _block_select_kernel(union_ref, count_ref, pos_ref, q_ref, mask_ref,
+                         k_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref, k_buf,
+                         v_buf, sem, slot_ref, *, block_q: int, block: int,
+                         per_step: int, heads: int, tiles: int,
+                         all_tiles: int, union_len: int, precision):
     g, i, e = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     tile = g * tiles + i
+    count = count_ref[tile]
+    copies = functools.partial(
+        _kv_copies, union_ref=union_ref, k_hbm=k_hbm, v_hbm=v_hbm,
+        k_buf=k_buf, v_buf=v_buf, sem=sem, block=block, per_step=per_step,
+        tiles=tiles, union_len=union_len)
     _init_running(e, m_ref, l_ref, acc_ref)
 
-    @pl.when(e * per_step < count_ref[tile])
+    @pl.when((tile == 0) & (e == 0))
+    def _first():
+        slot_ref[0] = 0
+        copies(lambda c: c.start(), tile=tile, e=e, slot=0)
+
+    @pl.when(_visible(e, count, per_step))
     def _step():
-        width = per_step * block
-        k = jnp.concatenate([r[0] for r in k_refs], axis=0)
-        v = jnp.concatenate([r[0] for r in v_refs], axis=0)
-        s = jax.lax.dot_general(q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+        # the NEXT visible step's rows set out before this one's are
+        # waited for: the tile's next step, else the next tile's first
+        slot = slot_ref[0]
+        slot_ref[0] = 1 - slot
+        more = (e + 1) * per_step < count
+
+        @pl.when(more | (tile + 1 < all_tiles))
+        def _next():
+            copies(lambda c: c.start(), tile=jnp.where(more, tile, tile + 1),
+                   e=jnp.where(more, e + 1, 0), slot=1 - slot)
+
+        copies(lambda c: c.wait(), tile=tile, e=e, slot=slot)
+        s = jax.lax.dot_general(q_ref[0, 0], k_buf[slot],
+                                (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32,
                                 precision=precision)
-        # which columns each QUERY of the tile may read: its own entries
-        # of the union, and in them the rows at or below its position
-        own = mask_ref[0, 0, 0]                               # [block_q, R]
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
-        mine = jnp.zeros((block_q, width), jnp.float32)
-        col_pos = jnp.zeros((1, width), jnp.int32)
-        for r in range(per_step):
-            here = (col // block) == r
-            mine = jnp.where(here, own[:, r:r + 1], mine)
-            first = union_ref[tile * union_len + e * per_step + r] * block
-            col_pos = jnp.where(here, first + col % block, col_pos)
-        row_pos = pos_ref[0] + i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        seen = jnp.where(col_pos <= row_pos, mine, 0.0)
-        seen = jnp.concatenate([seen] * heads, axis=0)        # head-major
-        _accumulate(jnp.where(seen > 0.5, s, NEG_INF), v, m_ref, l_ref,
-                    acc_ref, precision)
+        s = _own_logits(s, mask_ref[0, 0, 0], union_ref,
+                        tile * union_len + e * per_step,
+                        pos_ref[0] + i * block_q, block=block, heads=heads)
+        _accumulate(s, v_buf[slot], m_ref, l_ref, acc_ref, precision)
 
     @pl.when(e == pl.num_programs(2) - 1)
     def _finalize():
@@ -419,53 +503,62 @@ def block_select_mha(q, k, v, union, count, mask, start, block: int,
     of ``block``; ``union`` [G, tiles, U] int32 — each tile's blocks,
     ascending, entries past ``count`` [G, tiles] repeating the last;
     ``mask`` [G, tiles, U / R, block_q, R] float32, 1 where the query reads
-    the entry; ``start`` the first query's position. The K and V tile of
-    grid step ``(g, i, e)`` slot ``r`` is block ``union[g, i, e·R + r]``:
-    the table is prefetched, the tiles follow it. Answers as ``q``."""
+    the entry; ``start`` the first query's position. The table is
+    prefetched and the K and V rows of grid step ``(g, i, e)`` follow it
+    by the kernel's own copies (``k`` and ``v`` stay in HBM), twice
+    buffered: the blocks ``union[g, i, e·R …]`` side by side, fetched as
+    ONE stretch of ``R · block`` rows where they are consecutive and a
+    block at a time where they are not; a step past the count fetches
+    nothing, not even its mask. The steps run in the grid's order on one
+    core (a step sets out the next one's copies). Answers as ``q``."""
     G, tiles, rows, d = q.shape
     _, _, steps, block_q, per_step = mask.shape
     U = union.shape[-1]
-    heads = rows // block_q
     kernel = functools.partial(
         _block_select_kernel, block_q=block_q, block=block,
-        per_step=per_step, heads=heads, tiles=tiles, union_len=U,
-        precision=_precision_of(q.dtype))
+        per_step=per_step, heads=rows // block_q, tiles=tiles,
+        all_tiles=G * tiles, union_len=U, precision=_precision_of(q.dtype))
 
-    def kv_spec(r):
-        return pl.BlockSpec(
-            (1, block, d),
-            lambda g, i, e, union_ref, *_: (
-                g, union_ref[(g * tiles + i) * U + e * per_step + r], 0))
+    def last_step(g, i, count_ref):
+        return jnp.maximum(-(-count_ref[g * tiles + i] // per_step) - 1, 0)
 
     tile_spec = pl.BlockSpec((1, 1, rows, d),
                              lambda g, i, e, *_: (g, i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(G, tiles, steps),
-        in_specs=[tile_spec]
-        + [kv_spec(r) for r in range(per_step)] * 2
-        + [pl.BlockSpec((1, 1, 1, block_q, per_step),
-                        lambda g, i, e, *_: (g, i, e, 0, 0))],
+        in_specs=[tile_spec,
+                  pl.BlockSpec((1, 1, 1, block_q, per_step),
+                               lambda g, i, e, union_ref, count_ref, *_: (
+                                   g, i, jnp.minimum(
+                                       e, last_step(g, i, count_ref)), 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=tile_spec,
-        scratch_shapes=_running_scratch(rows, d))
+        scratch_shapes=_running_scratch(rows, d) + [
+            pltpu.VMEM((2, per_step * block, d), k.dtype),
+            pltpu.VMEM((2, per_step * block, d), v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),                  # (K|V, slot)
+            pltpu.SMEM((1,), jnp.int32)])           # the slot to fill next
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(union.reshape(-1).astype(jnp.int32),
       count.reshape(-1).astype(jnp.int32),
-      jnp.reshape(start, (1,)).astype(jnp.int32), q,
-      *([k] * per_step), *([v] * per_step), mask)
+      jnp.reshape(start, (1,)).astype(jnp.int32), q, mask, k, v)
 
 
 def tile_unions(chosen, block_q: int, per_step: int):
     """From ``chosen`` [G, Q, nb]: each tile of ``block_q`` queries' union
     of blocks ``[G, tiles, U]`` (ascending; ``U`` = ``nb`` rounded up to
     the step; entries past the count repeat the last), the counts ``[G,
-    tiles]`` and the per-query mask ``[G, tiles, U / R, block_q, R]``."""
+    tiles]``, the per-query mask ``[G, tiles, U / R, block_q, R]`` and the
+    grid's steps by how their rows arrive, int32 ``[3]`` in
+    ``STEP_FETCHES``' order (the kernel's own two rules over the table)."""
     G, Q, nb = chosen.shape
     tiles = Q // block_q
     U = -(-nb // per_step) * per_step
@@ -478,7 +571,14 @@ def tile_unions(chosen, block_q: int, per_step: int):
     mask = jnp.take_along_axis(own, union[:, :, None, :], axis=-1) \
         & (jnp.arange(U) < count[..., None])[:, :, None, :]
     mask = mask.reshape(G, tiles, block_q, U // per_step, per_step)
-    return union, count, jnp.swapaxes(mask, 2, 3).astype(jnp.float32)
+    by_step = union.reshape(G, tiles, U // per_step, per_step)
+    visible = _visible(jnp.arange(U // per_step), count[..., None], per_step)
+    runs = (visible & _is_run(by_step[..., 0], by_step[..., -1],
+                              per_step)).sum()
+    seen = visible.sum()
+    fetches = jnp.stack([runs, seen - runs,
+                         visible.size - seen]).astype(jnp.int32)
+    return union, count, jnp.swapaxes(mask, 2, 3).astype(jnp.float32), fetches
 
 
 def sparse_chunk(q, k, v, chosen, start, scale: float, dtype,
@@ -488,7 +588,8 @@ def sparse_chunk(q, k, v, chosen, start, scale: float, dtype,
     own rows written), each over the blocks ``chosen`` [G, Q, nb] gives it
     and in them the rows at or below its own. ``kernel``: ``pallas`` (the
     default on a TPU), ``interpret`` or ``lax`` (the default elsewhere).
-    Answers [Q, H, d] in ``dtype``."""
+    Answers [Q, H, d] in ``dtype`` and the kernel's grid steps by
+    ``STEP_FETCHES`` (int32 ``[3]``; ``lax`` has no grid: zeros)."""
     Q, H, d = q.shape
     G, S, _ = k.shape
     J, bs = H // G, sel.block_size
@@ -504,19 +605,20 @@ def sparse_chunk(q, k, v, chosen, start, scale: float, dtype,
         outs = [_masked_softmax_rows(q[:, g * J:(g + 1) * J], k[g:g + 1],
                                      v[g:g + 1], seen[g], dtype)
                 for g in range(G)]
-        return jnp.concatenate(outs, axis=1).astype(dtype)
+        return (jnp.concatenate(outs, axis=1).astype(dtype),
+                jnp.zeros((len(STEP_FETCHES),), jnp.int32))
     bq = math.gcd(Q, block_q)
     tiles = Q // bq
     if kernel == "pallas":
         from .attention import note_causal
 
         note_causal("block_select", H, d, Q, S, dtype, bq, per_step * bs)
-    union, count, mask = tile_unions(chosen, bq, per_step)
+    union, count, mask, fetches = tile_unions(chosen, bq, per_step)
     o = block_select_mha(_head_major_tiles(q, G, bq), k.astype(dtype),
                          v.astype(dtype), union, count, mask, start,
                          block=bs, interpret=kernel == "interpret")
     return o.reshape(G, tiles, J, bq, d).transpose(1, 3, 0, 2, 4) \
-        .reshape(Q, H, d)
+        .reshape(Q, H, d), fetches
 
 
 # --- one decoded token ---------------------------------------------------------

@@ -641,3 +641,15 @@ BOOT_SECONDS = REGISTRY.gauge(
     "first touch of the chip), controller (Controller() to the HTTP "
     "server listening). Net of program builds inside; set once.",
     ("phase",))
+
+LLM_SPARSE_STEPS = REGISTRY.counter(
+    "cdt_llm_sparse_steps_total",
+    "Grid steps of a block-selecting language model's table-driven sparse "
+    "prefill kernel (ops/block_select_attention.py: block_select_mha), over "
+    "every (layer, key/value group, query tile) of a request, by how the "
+    "step's K and V rows arrive: run (its blocks are consecutive: ONE copy "
+    "each of blocks-a-step x block rows), blocks (a copy a block) or "
+    "skipped (past the tile's union: nothing fetched or computed). Counted "
+    "on the device from each tile's prefetched table by the kernel's own "
+    "two rules; a request within dense_len, and the lax form, count none.",
+    ("fetch",))

@@ -6,6 +6,9 @@ chunk form against the step form through the cache, and the Pallas kernel
 (in the interpreter here) against the masked softmax — also for a table
 whose blocks are not contiguous."""
 
+import importlib.util
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,6 +31,11 @@ def rows(S, Q, seed=1):
     return (jax.random.normal(ks[0], (G, S, D)),
             jax.random.normal(ks[1], (G, S, D)),
             jax.random.normal(ks[2], (Q, H, D)))
+
+
+def sparse_chunk(*args, **kw):
+    """The chunk form's answers (its step counts have tests of their own)."""
+    return bsa.sparse_chunk(*args, **kw)[0]
 
 
 def compressed(k, upto, chunk=16):
@@ -145,7 +153,7 @@ def test_a_tiles_union_is_ascending_and_repeats_its_last_entry():
     chosen = np.zeros((1, 4, 7), bool)
     chosen[0, 0, [0, 2]] = chosen[0, 1, [0, 5]] = True
     chosen[0, 2, [0, 1]] = chosen[0, 3, [0, 1, 6]] = True
-    union, count, mask = bsa.tile_unions(jnp.asarray(chosen), 2, 4)
+    union, count, mask, _ = bsa.tile_unions(jnp.asarray(chosen), 2, 4)
     assert count.tolist() == [[3, 3]]
     assert union[0, 0].tolist() == [0, 2, 5, 5, 5, 5, 5, 5]
     assert union[0, 1].tolist() == [0, 1, 6, 6, 6, 6, 6, 6]
@@ -165,7 +173,7 @@ def test_the_chunk_form_is_the_masked_softmax_over_the_selected_rows(
     kc, cache = compressed(k, 80)
     chosen = bsa.select(bsa.block_scores(q, kc, 48 + jnp.arange(32), SCALE,
                                          jnp.float32, SEL), SEL)
-    got = bsa.sparse_chunk(q, cache, v, chosen, 48, SCALE, jnp.float32, SEL,
+    got = sparse_chunk(q, cache, v, chosen, 48, SCALE, jnp.float32, SEL,
                            block_q, per_step, kernel)
     s = np.einsum("qgjd,gsd->qgjs",
                   np.asarray(q, np.float64).reshape(32, G, J, D),
@@ -191,16 +199,16 @@ def test_a_table_whose_blocks_are_not_contiguous(kernel):
             own = (64 + n) // 8
             chosen[g, n, rng.choice(own, 3, replace=False)] = True
             chosen[g, n, own] = True
-    got = bsa.sparse_chunk(q, k, v, jnp.asarray(chosen), 64, SCALE,
+    got = sparse_chunk(q, k, v, jnp.asarray(chosen), 64, SCALE,
                            jnp.float32, SEL, 8, 2, kernel)
     # rows outside the tables may hold anything
     outside = ~np.repeat(chosen.any(1), 8, -1)               # [G, S]
     loud_k = jnp.where(outside[:, :, None], 1e4, k)
     loud_v = jnp.where(outside[:, :, None], -1e4, v)
-    again = bsa.sparse_chunk(q, loud_k, loud_v, jnp.asarray(chosen), 64,
+    again = sparse_chunk(q, loud_k, loud_v, jnp.asarray(chosen), 64,
                              SCALE, jnp.float32, SEL, 8, 2, kernel)
     assert np.abs(np.asarray(got) - np.asarray(again)).max() < TOL
-    lax = bsa.sparse_chunk(q, k, v, jnp.asarray(chosen), 64, SCALE,
+    lax = sparse_chunk(q, k, v, jnp.asarray(chosen), 64, SCALE,
                            jnp.float32, SEL, kernel="lax")
     assert np.abs(np.asarray(got) - np.asarray(lax)).max() < TOL
 
@@ -213,12 +221,12 @@ def test_the_causal_cut_inside_the_own_block(kernel):
     pos = 40 + jnp.arange(8)                      # the whole of block 5
     chosen = bsa.select(bsa.block_scores(q, kc, pos, SCALE, jnp.float32,
                                          SEL), SEL)
-    got = bsa.sparse_chunk(q, cache, v, chosen, 40, SCALE, jnp.float32, SEL,
+    got = sparse_chunk(q, cache, v, chosen, 40, SCALE, jnp.float32, SEL,
                            8, 2, kernel)
     for n in (0, 3, 6):
         loud_k = cache.at[:, 40 + n + 1:48].set(1e4)
         loud_v = v.at[:, 40 + n + 1:48].set(-1e4)
-        again = bsa.sparse_chunk(q, loud_k, loud_v, chosen, 40, SCALE,
+        again = sparse_chunk(q, loud_k, loud_v, chosen, 40, SCALE,
                                  jnp.float32, SEL, 8, 2, kernel)
         assert np.abs(np.asarray(got[n]) - np.asarray(again[n])).max() < TOL
         assert np.abs(np.asarray(got[7]) - np.asarray(again[7])).max() > 1.0
@@ -230,7 +238,7 @@ def test_the_chunk_form_is_the_step_form_through_the_cache(start):
     kc, cache = compressed(k, start + 32)
     chosen = bsa.select(bsa.block_scores(q, kc, start + jnp.arange(32),
                                          SCALE, jnp.float32, SEL), SEL)
-    whole = bsa.sparse_chunk(q, cache, v, chosen, start, SCALE, jnp.float32,
+    whole = sparse_chunk(q, cache, v, chosen, start, SCALE, jnp.float32,
                              SEL, 8, 2, "interpret")
     kc_t, cache_t = compressed(k, start) if start else (
         jnp.zeros((G, 48, D)), jnp.zeros_like(k))
@@ -258,6 +266,147 @@ def test_the_kernel_reports_a_tier_of_its_own(monkeypatch):
                           jnp.bfloat16, 64, 1024)
     assert said == [("h32.d128.q512.kv131072.bf16", "block_select", 64,
                      1024)]
+
+
+# --- a step's two fetches ---------------------------------------------------------
+
+_spec = importlib.util.spec_from_file_location(
+    "sparse_step_sweep",
+    Path(__file__).resolve().parent.parent / "scripts" / "sparse_step_sweep.py")
+sweep = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(sweep)
+
+
+def _table(start, Q, nb, blocks, thin=True):
+    """``chosen`` [G, Q, nb]: every query at ``start …`` reads the blocks
+    ``blocks(own)`` gives for its own block — less, where ``thin``, a third
+    of them (which third turns with the query and the group, so the mask
+    is every query's own and the union still the whole list), never block
+    0 nor its own."""
+    chosen = np.zeros((G, Q, nb), bool)
+    for g in range(G):
+        for n in range(Q):
+            own = (start + n) // 8
+            for b in blocks(own):
+                if not thin or b in (0, own) or (b + n + g) % 3:
+                    chosen[g, n, b] = True
+    return chosen
+
+
+# (start, queries, blocks, the table's blocks by a query's own block,
+#  queries a tile, blocks a step) -> grid steps (run, blocks, skipped)
+STEP_CASES = {
+    "every step a run, the own block inside the last": (
+        (56, 8, 12, lambda own: range(own + 1), 8, 2), (8, 0, 4)),
+    "no step a run": (
+        (64, 8, 12, lambda own: range(own % 2, own + 1, 2), 8, 2),
+        (0, 6, 6)),
+    "a run ends at the tile's count inside a step": (
+        (48, 8, 12, lambda own: range(own + 1), 8, 4), (2, 2, 2)),
+    "a count below one step": (
+        (8, 8, 12, lambda own: range(own + 1), 8, 4), (0, 2, 4)),
+    "two queries a block: the cut in both of a tile's last steps": (
+        (40, 16, 12, lambda own: range(own + 1), 4, 2), (24, 4, 20)),
+    "three tiles, runs then scattered blocks": (
+        (64, 24, 16, lambda own: [*range(4), *range(5, own - 1, 2), own],
+         8, 2), (12, 10, 26)),
+    "16 blocks a step": (
+        (248, 16, 48, lambda own: range(own + 1), 8, 16), (8, 2, 2)),
+}
+
+
+@pytest.mark.parametrize("case,steps", STEP_CASES.values(),
+                         ids=STEP_CASES.keys())
+def test_a_step_fetches_by_its_table_and_answers_as_the_parent_did(case,
+                                                                   steps):
+    """The kernel (in the interpreter) over hand-made tables: the masked
+    softmax, PR 49's kernel TO THE BIT (the same mask, products and
+    reductions in the same order: only how the rows arrive differs), and
+    its steps by fetch counted from the table."""
+    start, Q, nb, blocks, block_q, per_step = case
+    k, v, q = rows(8 * nb, Q, seed=17)
+    chosen = jnp.asarray(_table(start, Q, nb, blocks))
+    got, fetches = bsa.sparse_chunk(q, k, v, chosen, start, SCALE,
+                                    jnp.float32, SEL, block_q, per_step,
+                                    "interpret")
+    lax = sparse_chunk(q, k, v, chosen, start, SCALE, jnp.float32, SEL,
+                       kernel="lax")
+    assert np.abs(np.asarray(got) - np.asarray(lax)).max() < TOL
+    assert tuple(fetches.tolist()) == steps
+    union, count, mask, _ = bsa.tile_unions(chosen, block_q, per_step)
+    assert sum(steps) == union.size // per_step
+    tiles = bsa._head_major_tiles(q * SCALE, G, block_q)
+    parent = sweep.parent_mha(tiles, k, v, union, count, mask, start,
+                              block=8, interpret=True)
+    mine = bsa.block_select_mha(tiles, k, v, union, count, mask, start,
+                                block=8, interpret=True)
+    assert (np.asarray(mine) == np.asarray(parent)).all()
+
+
+@pytest.mark.parametrize("fetch,mask_form", [
+    ("specs", "bias"), ("copies", "bias"), ("copies", "bias_selects")])
+def test_the_forms_that_lost_the_sweep_keep_the_parents_bits(fetch,
+                                                             mask_form):
+    """The sweep's other forms of the step — the mask as an ADDED bias over
+    PR 49's fetch and over the shipped one — answer as both ends do: what
+    the sweep times are the same answers."""
+    start, Q, nb, blocks, block_q, per_step = STEP_CASES[
+        "three tiles, runs then scattered blocks"][0]
+    k, v, q = rows(8 * nb, Q, seed=19)
+    chosen = jnp.asarray(_table(start, Q, nb, blocks))
+    union, count, mask, _ = bsa.tile_unions(chosen, block_q, per_step)
+    tiles = bsa._head_major_tiles(q * SCALE, G, block_q)
+    args = (tiles, k, v, union, count, mask, start)
+    parent = sweep.parent_mha(*args, block=8, interpret=True)
+    half = sweep.step_form_mha(*args, block=8, fetch=fetch,
+                               mask_form=mask_form, interpret=True)
+    assert (np.asarray(half) == np.asarray(parent)).all()
+
+
+def test_a_query_with_nothing_in_a_tiles_first_step_is_still_right():
+    """A query whose first entry comes in a LATER step of its tile: its
+    heads' rows are all ``NEG_INF`` in step 0 (running maximum and all),
+    and what they gathered there is scaled away by the first real
+    logit."""
+    k, v, q = rows(96, 8, seed=23)
+    chosen = np.zeros((G, 8, 12), bool)
+    chosen[:, :4, [0, 1]] = True                 # step 0 of the union
+    chosen[:, 4:, [5, 6]] = True                 # step 1 alone
+    chosen = jnp.asarray(chosen)
+    got = sparse_chunk(q, k, v, chosen, 56, SCALE, jnp.float32, SEL, 8, 2,
+                       "interpret")
+    lax = sparse_chunk(q, k, v, chosen, 56, SCALE, jnp.float32, SEL,
+                       kernel="lax")
+    assert np.abs(np.asarray(got) - np.asarray(lax)).max() < TOL
+
+
+def test_the_sweeps_tables_are_the_three_kinds():
+    """``scripts/sparse_step_sweep.py``'s tables at the test's sizes:
+    clustered tiles choose alike (a union is one query's table), scattered
+    unions hold no two adjacent blocks (so no step is a run), and every
+    table holds the query's own block."""
+    sel = bsa.Selection(kernel_size=4, kernel_stride=2, block_size=8,
+                        init_blocks=1, window_size=16, topk=6)
+    _, _, q = rows(8, 16)
+    kc = jnp.zeros((G, 4 * 32, D))
+    for kind in ("clustered", "scattered", "lone"):
+        chosen = sweep.chosen_of(kind, jax.random.key(3), q, kc, 192, SCALE,
+                                 jnp.float32, sel, 8)
+        own = (192 + np.arange(16)) // 8
+        assert np.asarray(chosen)[:, np.arange(16), own].all(), kind
+        union, count, _, fetches = bsa.tile_unions(chosen, 8, 2)
+        per_query = np.asarray(chosen).sum(-1)
+        if kind == "clustered":
+            assert (per_query == sel.table).all()
+            assert (np.asarray(count) == sel.table).all()
+        elif kind == "scattered":
+            assert (per_query == sel.table).all()
+            blocks = np.asarray(chosen).reshape(G, 2, 8, -1).any(2)
+            assert not (blocks[..., 1:] & blocks[..., :-1]).any()
+            assert fetches[0] == 0 and fetches[1] > 2 * G
+        else:
+            assert (np.asarray(count) == 1).all()
+            assert fetches.tolist() == [0, 2 * G, 2 * G * 15]
 
 
 # --- the two-pass scoring kernel (in the interpreter) ------------------------

@@ -693,11 +693,14 @@ def test_a_joint_block_hands_the_kernel_its_products_outputs(chip, case,
 def test_the_table_driven_kernel_compiles_at_the_served_geometry(chip):
     """``block_select_attention.block_select_mha`` as the sparse layers'
     prefill calls it: 512 queries a call in tiles of 64 neighbours × 16
-    heads of a K/V group (1024 rows), 16 K and 16 V tiles of one 64-row
-    block a grid step — each a BlockSpec whose index is read from the
-    PREFETCHED union table — over the 65 664-row buffers. The compiled
-    kernel carries the NAME the cell's trace readers match
-    (``cdtbench/kinds/sala.py``)."""
+    heads of a K/V group (1024 rows), the K and V rows of a grid step — 16
+    blocks of 64 — brought from the 65 664-row buffers (left in HBM) by
+    the kernel's OWN copies into two 1024-row buffers each, ONE stretch
+    where the PREFETCHED union table's entries are consecutive and a copy
+    a block where not (PR 50: no 32 BlockSpecs a step); the table has an
+    entry for every block the compressed cache has slots for (1056: 66
+    steps a tile). The compiled kernel carries the NAME the cell's trace
+    readers match (``cdtbench/kinds/sala.py``)."""
     from comfyui_distributed_tpu.models.llm_sala import SalaConfig
     from comfyui_distributed_tpu.ops import block_select_attention as bsa
 
@@ -706,8 +709,9 @@ def test_the_table_driven_kernel_compiles_at_the_served_geometry(chip):
     J = cfg.num_attention_heads // G
     bq, R, bs = cfg.sparse_block_q, cfg.sparse_blocks_per_step, cfg.block_size
     S = cfg.cache_rows(65536 + 128)
-    tiles, U = cfg.select_rows // bq, -(-(S // bs) // R) * R
-    assert (S, tiles, U, J * bq) == (65664, 8, 1040, 1024)
+    tiles = cfg.select_rows // bq
+    U = -(-(cfg.cache_slots(S) // cfg.selection.per) // R) * R
+    assert (S, tiles, U, J * bq) == (65664, 8, 1056, 1024)
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -719,6 +723,13 @@ def test_the_table_driven_kernel_compiles_at_the_served_geometry(chip):
         block=bs, interpret=False)
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text and "block_select_mha" in text
+    # one call, five operands past the three prefetched tables: the
+    # queries, the mask and K and V WHOLE (no block of them a BlockSpec)
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line and " custom-call(" in line)
+    assert call.count(f"bf16[{G},{S},{d}]") == 2
+    assert len(re.findall(r"%\S+", call.split(" custom-call(")[1]
+                          .split(")")[0])) == 7
 
 
 def test_the_scoring_kernel_compiles_at_the_served_geometry(chip):
@@ -783,6 +794,15 @@ def test_the_selecting_rewriters_programs_fit_beside_sdxl(chip, monkeypatch):
               if str(slots) in shape.split(",")]
     assert slots == 4224 and scores
     assert max(scores) == 2 * cfg.select_rows * slots
+    # the kernel takes the K and V buffers WHOLE and where they lie (PR
+    # 50): nothing of their size is copied at a trip of the chunks' scan,
+    # and the loops copy what they did before it (92 copies, 2737 MB: the
+    # lightning layers' blocks, the tables, the masks)
+    looped = [(size, shape) for _, where, size, shape
+              in loop_copies.large_copies(text) if where]
+    rows = cfg.cache_rows(65536 + 128)
+    assert not [shape for _, shape in looped if str(rows) in shape]
+    assert sum(size for size, _ in looped) <= 2737e6
     mem = compiled["llm_prefill"].memory_analysis()
     prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                    + mem.output_size_in_bytes) / gib

@@ -91,8 +91,9 @@ def test_chunked_prefill_is_the_reference_at_every_position(params, T, chunk,
         S.MODEL, CFG, params, ids[:T], T + NEW, all_logits=True,
         chunk=chunk, kernel=kernel)
     assert close(logits, want[:T])
-    assert held.shape == rows.shape == (0,)
     sparse = T == T_SPARSE
+    # no expert layer; the rows' place holds the sparse kernel's steps
+    assert held.shape == (0,) and rows.shape == ((3,) if sparse else (0,))
     assert cache["kc"][0].shape[1] == (128 if sparse else 0)
 
 
@@ -266,9 +267,13 @@ def test_the_pipeline_serves_it_with_no_branch_on_its_name(params,
         "sparse_index": 2 * 2 * CFG.cache_slots(rows) * 8 * 4,
         "linear": 3 * 4 * 8 * 8 * 4}
     assert CFG.cache_slots(rows) == 128
+    # the rows' place holds the sparse kernel's steps: the CPU's lax form
+    # has no grid, a dense request no sparse kernel
+    assert out["rows_prefill"] == 0 and out["sparse_steps"].tolist() == [0] * 3
     dense = pipe.generate(np.asarray(ids[:T_DENSE]).tolist(), NEW, seed=3,
                           temperature=0.7)
     assert dense["cache_bytes"]["sparse_index"] == 0
+    assert dense["sparse_steps"].shape == (0,)
 
 
 def test_registry_kind_and_loaders():
@@ -373,6 +378,75 @@ def test_the_shipped_graph_runs_and_the_counters_move_as_stated(tmp_path):
             == 2 * 2 * 128 * 8 * 4
         assert tm.LLM_CACHE_BYTES.labels(layers="linear").value \
             == 3 * 4 * 8 * 8 * 4
+
+
+def _grid_steps(cfg, prompt_tokens, new_tokens):
+    """Grid steps of the table-driven kernel in a request: every (chunk,
+    ``select_rows`` queries, sparse layer, group, query tile) walks the
+    blocks the compressed cache has slots for (whole lanes of slots: more
+    than the K/V rows' blocks), rounded up to whole steps."""
+    chunk = min(cfg.prefill_chunk_tokens, prompt_tokens)
+    chunks = -(-prompt_tokens // chunk)
+    rows = cfg.cache_rows(max(prompt_tokens + new_tokens, chunks * chunk))
+    blocks = cfg.cache_slots(rows) // cfg.selection.per
+    steps = -(-blocks // cfg.sparse_blocks_per_step)
+    return (chunks * chunk // cfg.sparse_block_q * len(cfg.sparse_layers)
+            * cfg.num_key_value_heads * steps)
+
+
+@pytest.mark.parametrize("kernel,T", [("interpret", T_SPARSE), ("lax", T_SPARSE),
+                                      ("interpret", T_DENSE)])
+def test_a_prefill_hands_back_its_sparse_kernels_steps_by_fetch(params,
+                                                                kernel, T):
+    """In the place of the rows an expert layer multiplied: the grid steps
+    of every sparse layer's kernel over the chunks, by how their K/V rows
+    arrive — they sum to the grid (the lax form has none, a dense request
+    no sparse kernel)."""
+    _, _, held, steps = llm_model.chunked_prefill(
+        S.MODEL, CFG, params, _ids(T), T + NEW, kernel=kernel)
+    assert held.shape == (0,)
+    if T == T_DENSE:
+        assert steps.shape == (0,)
+        return
+    run, blocks, skipped = steps.tolist()
+    if kernel == "lax":
+        assert (run, blocks, skipped) == (0, 0, 0)
+        return
+    assert run + blocks + skipped == _grid_steps(CFG, T, NEW) == 5 * 2 * 2 * 2 * 16
+    # every tile holds its own block: a step at least; late tiles' forced
+    # blocks (the last three) are a run of two and a lone block
+    assert run + blocks >= 5 * 2 * 2 * 2 and run > 0 and blocks > 0
+
+
+def test_the_node_counts_the_sparse_steps_a_request(tmp_path, monkeypatch):
+    """``cdt_llm_sparse_steps_total``'s three labels move by the grid's
+    steps a request (the kernel in the interpreter here: on a TPU it is the
+    served form)."""
+    from comfyui_distributed_tpu import telemetry
+    from comfyui_distributed_tpu.graph.executor import GraphExecutor
+    from comfyui_distributed_tpu.ops import block_select_attention as bsa
+    from comfyui_distributed_tpu.telemetry import metrics as tm
+
+    if not telemetry.enabled():
+        pytest.skip("telemetry is off")
+    chunk = bsa.sparse_chunk
+    monkeypatch.setattr(
+        bsa, "sparse_chunk", lambda *a: chunk(*a[:-1], "interpret"))
+
+    def counted():
+        return [tm.LLM_SPARSE_STEPS.labels(fetch=f).value
+                for f in bsa.STEP_FETCHES]
+
+    graph = _shipped_graph(tmp_path, 21)
+    graph["9"]["inputs"].update(prompt_tokens=41)    # a program of its own
+    before = counted()
+    executor = GraphExecutor()
+    for seed in (21, 22):
+        graph["3"]["inputs"]["seed"] = seed
+        executor.execute(graph)
+    moved = [a - b for a, b in zip(counted(), before)]
+    assert sum(moved) == 2 * _grid_steps(CFG, 41, 8) == 2 * 3 * 2 * 2 * 2 * 16
+    assert moved[0] + moved[1] >= 2 * 3 * 2 * 2 * 2 and moved[2] > 0
 
 
 def test_the_rules_counts_are_a_brute_force_count():
