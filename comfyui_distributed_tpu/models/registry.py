@@ -916,3 +916,17 @@ def _build_bundle(name: str, preset, ckpt) -> ModelBundle:
         bundle = build(preset, ckpt)
         attrs(bytes=_note_weights(name, bundle))
     return bundle
+
+
+def _glm_preset(name: str, tiny: bool = False):
+    """The ``glm`` family (models/llm_glm.py), registered at the END of
+    this file: a program's cache key holds the lines its operations were
+    traced at (ROADMAP D17), and a preset added above would move them."""
+    from .llm_glm import GlmConfig
+
+    share = GlmConfig.tiny if tiny else GlmConfig.glm_share
+    return ModelPreset(name, unet=None, vae=None, text=None, llm=share())
+
+
+PRESETS["glm-5"] = _glm_preset("glm-5")
+PRESETS["glm-tiny"] = _glm_preset("glm-tiny", tiny=True)

@@ -61,7 +61,7 @@ TIERS = ("packed", "bh", "xla")
 # cache, over one shared key/value head, and over grouped key/value heads
 # (whole, or the band of a window layer)
 REPORTED_TIERS = TIERS + ("latent_causal", "shared_kv_causal", "gqa_causal",
-                          "gqa_window", "block_select")
+                          "gqa_window", "block_select", "index_select")
 
 # the in-repo resolved table for the known model zoo
 _SHIPPED_PATH = Path(__file__).resolve().parent / "attn_table_default.json"

@@ -123,8 +123,9 @@ LLM_CACHE_BYTES = REGISTRY.gauge(
     "Bytes of one rewrite request's decode cache, by the kind of layer "
     "that holds them: window (a ring of sliding_window rows a layer), "
     "full (prompt + new rows a layer), recurrent (linear-attention or "
-    "state-space states and convolution tails). Set when a request's "
-    "cache is made.",
+    "state-space states and convolution tails), or a model's own kinds "
+    "(latent and index: a latent cache and the index keys that choose its "
+    "rows). Set when a request's cache is made.",
     ("layers",))
 
 LLM_PREFILL_CHUNKS = REGISTRY.counter(

@@ -813,3 +813,88 @@ def test_the_selecting_rewriters_programs_fit_beside_sdxl(chip, monkeypatch):
     mem = compiled["llm_decode"].memory_analysis()
     decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
     assert 7.5 < decode_gib < 8.4 and decode_gib + sdxl < 15.75 - 1.0
+
+
+GLM_KERNELS = ("index_score_sums", "index_select_keep", "index_masked_mha")
+
+
+@pytest.mark.parametrize("kernel", GLM_KERNELS)
+def test_the_index_selecting_kernels_compile_at_the_served_geometry(chip,
+                                                                   kernel):
+    """``ops/index_select_attention``'s three kernels as ``glm-5``'s prefill
+    calls them (PR 51), alone: the scores of 1024 queries × 32 index heads
+    of 128 against 69 632 index keys; the exact top 2048 of 1024 rows, 32
+    rows' float32 scores (8.5 MiB) in VMEM for all the passes; 4096 queries
+    of 8 heads × 256/256 over a decompressed workspace under the byte
+    mask."""
+    from comfyui_distributed_tpu.models.llm_glm import GlmConfig
+    from comfyui_distributed_tpu.ops import index_select_attention as ops
+
+    cfg = GlmConfig.glm_share()
+    C, S = cfg.prefill_chunk_tokens, 17 * cfg.prefill_chunk_tokens
+    n, J, di = cfg.select_rows, cfg.index_n_heads, cfg.index_head_dim
+    g, d = ops.HEADS_PER_PASS, cfg.v_head_dim
+    assert cfg.qk_nope_head_dim + cfg.qk_rope_head_dim == d == 256
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    start = arg((), jnp.int32)
+    if kernel == "index_score_sums":
+        lowered = ops.index_score_sums.lower(
+            arg((J, n, di)), arg((n, J), jnp.float32), arg((S, di)), start,
+            block_q=ops.INDEX_TILE[0], block_k=ops.INDEX_TILE[1],
+            interpret=False)
+        out = f"f32[{n},{S}]"
+    elif kernel == "index_select_keep":
+        lowered = ops.index_select_keep.lower(
+            arg((n, S), jnp.float32), start, topk=cfg.index_topk,
+            rows=ops.SELECT_ROWS, interpret=False)
+        out = f"s8[{n},{S}]"
+    else:
+        lowered = ops.index_masked_mha.lower(
+            arg((C, g * d)), arg((S, g * d)), arg((S, g * d)),
+            arg((C, S), jnp.int8), start, num_heads=g,
+            block_q=ops.CORE_TILE[0], block_k=ops.CORE_TILE[1],
+            interpret=False)
+        out = f"bf16[{C},{g * d}]"
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text and kernel in text and out in text
+
+
+def test_the_index_selecting_rewriters_programs_fit_beside_sdxl(chip,
+                                                               monkeypatch):
+    """Both language programs of ``glm-5.brief64k-sdxl8`` at the cell's
+    sizes (65 536 + 128 tokens, published widths, 5 layers): they compile
+    for the chip and leave room for SDXL's segment program (4.79 + 0.56
+    GiB) in 15.75 GiB; the prefill holds THREE Pallas call sites a layer —
+    scores, selection, attention under the mask —; nothing ``[heads, chunk,
+    rows]`` exists in float32 (the scores are ``[1024, 69 632]``, a
+    quarter of a chunk's queries at a time, the mask a byte a pair); and
+    ``llm_decode`` holds no Pallas call — one token scores, selects
+    (``top_k``) and gathers in XLA."""
+    from comfyui_distributed_tpu.models.llm_glm import GlmConfig
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    cfg = GlmConfig.glm_share()
+    compiled = loop_copies.compiled_programs(cfg, 65536, 128, chip)
+    gib, sdxl = 2.0 ** 30, 4.79 + 0.56
+    text = compiled["llm_prefill"].as_text()
+    calls = _pallas_calls(text)
+    assert len(calls) == 3 * cfg.num_hidden_layers == 15
+    for name in GLM_KERNELS:
+        assert len(_pallas_calls(text, name)) == cfg.num_hidden_layers
+    rows = 17 * cfg.prefill_chunk_tokens
+    wide = [math.prod(int(n) for n in shape.split(","))
+            for shape in re.findall(r"f32\[([\d,]+)\]", text)
+            if str(rows) in shape.split(",")]
+    assert wide and max(wide) == cfg.select_rows * rows
+    mem = compiled["llm_prefill"].memory_analysis()
+    prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                   + mem.output_size_in_bytes) / gib
+    assert 7.0 < prefill_gib < 7.6 and prefill_gib + sdxl < 15.75 - 1.0
+    text = compiled["llm_decode"].as_text()
+    assert "tpu_custom_call" not in text                   # decode is XLA
+    mem = compiled["llm_decode"].memory_analysis()
+    decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
+    assert 5.8 < decode_gib < 6.5 and decode_gib + sdxl < 15.75 - 1.0

@@ -216,7 +216,8 @@ LLMS = {"ling-tiny": ("llm_hybrid", "LLMConfig", 24),
         "jamba-tiny": ("llm_jamba", "JambaConfig", 37),
         "trinity-tiny": ("llm_trinity", "TrinityConfig", 21),  # 2 chunks + 5
         "longcat-tiny": ("llm_longcat", "LongcatConfig", 37),  # three chunks
-        "sala-tiny": ("llm_sala", "SalaConfig", 40)}   # past its dense_len
+        "sala-tiny": ("llm_sala", "SalaConfig", 40),   # past its dense_len
+        "glm-tiny": ("llm_glm", "GlmConfig", 40)}      # past its index_topk
 
 PROGRAMS = {"txt2img_seg": _txt2img_seg, "flow_seg": _flow_seg, "fin": _fin}
 for _name, _how in LLMS.items():
@@ -263,6 +264,12 @@ EXPECTED = {
     # plain named scopes BELOW cdt.llm_attn (there is no seventeenth layer)
     "llm_prefill:sala-tiny": {"llm_attn", "llm_shared_ffn", "llm_head"},
     "llm_decode:sala-tiny": {"llm_attn", "llm_shared_ffn", "llm_head"},
+    # the indexer's scores, the selection and the attention under it are
+    # plain named scopes BELOW cdt.llm_attn too (sixteen layers there are)
+    "llm_prefill:glm-tiny": {"llm_attn", "llm_router", "llm_experts",
+                             "llm_shared_ffn", "llm_head"},
+    "llm_decode:glm-tiny": {"llm_attn", "llm_router", "llm_experts",
+                            "llm_shared_ffn", "llm_head"},
 }
 
 
@@ -307,3 +314,24 @@ def test_the_selecting_rewriters_work_is_named_below_its_layer():
         want = {"select", "sparse_core", "lightning"} \
             if program.startswith("llm_prefill") else {"select", "lightning"}
         assert set(below) >= want and all(below.values()), below
+
+
+def test_the_index_selecting_rewriters_work_is_named_below_its_layer():
+    """``llm_index`` and ``llm_sparse_attn`` are plain named scopes under
+    ``cdt.llm_attn`` (what ``cdtbench/kinds/glm.py: scope_seconds`` reads
+    from a trace): every product of the scores and of the attention over
+    the kept keys is under exactly one of them, and under the one
+    registered layer (the selection multiplies nothing)."""
+    plain = re.compile(r"/(llm_index|llm_select|llm_sparse_attn)(?:/|$)")
+    for program in ("llm_prefill:glm-tiny", "llm_decode:glm-tiny"):
+        fn, args = PROGRAMS[program]()
+        seen = list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+        below = {}
+        for primitive, stack, ops in seen:
+            found = plain.findall(stack)
+            if found:
+                assert len(found) == 1 and LAYER.findall(stack) \
+                    == ["llm_attn"], stack
+                below[found[0]] = below.get(found[0], 0) + ops
+        assert set(below) == {"llm_index", "llm_sparse_attn"} \
+            and all(below.values()), below

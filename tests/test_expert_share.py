@@ -184,6 +184,9 @@ OLDER = {
     "motif-tiny": (Routing(16, 4, 1, 1, 2.0), 24, (24, 1, "dense")),
     "kimi-tiny": (Routing(16, 4, 1, 1, 2.827), 37, (16, 3, "grouped")),
     "trinity-tiny": (Routing(16, 2, 1, 1, 2.448), 21, (8, 3, "grouped")),
+    # the sixth expert rewriter (PR 51) builds the same positional Routing
+    "glm-5": (Routing(256, 8, 1, 1, 2.5), 65536, (4096, 16, "grouped")),
+    "glm-tiny": (Routing(16, 4, 1, 1, 2.5), 40, (16, 3, "grouped")),
 }
 
 
