@@ -25,7 +25,11 @@ and the tests' yardstick) and the form a TPU runs:
   the scores once and writes a byte a pair).
 * **the attention** over the kept keys. Prefill
   (:func:`masked_chunk_attention`) decompresses a GROUP of heads' keys and
-  values at a time into a workspace and runs a blocked softmax kernel under
+  values at a time into a workspace — ONE kernel, ``index_fill_kv`` (PR 52):
+  a tile of latent rows in, the group's keys ``[c W_k | k_rope]`` and values
+  ``c W_v`` out in the layout the next kernel reads, float32 in VMEM and
+  rounded once; rows past the chunk's end are never written, so nothing
+  initialises the workspace — and runs a blocked softmax kernel under
   the mask (``index_masked_mha``: the schedule of ``flash_latent.py`` with
   the byte mask in place of the diagonal, a key ``[k_nope | k_rope]`` and a
   value of their own widths). A query's keys are its own — with seeded
@@ -62,13 +66,15 @@ CAUSAL_TIER_REASONS.setdefault(
 
 _VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 _INT_MIN = -2 ** 31
-# the tiles of the three kernels at the served sizes (a chunk of 4096
+# the tiles of the four kernels at the served sizes (a chunk of 4096
 # queries, 128-wide index heads, 256-wide keys and values), fixed by
-# measurement (PERF.md §6, PR 51); a smaller call takes what divides it
+# measurement (PERF.md §6, PRs 51 and 52); a smaller call takes what divides
+# it
 INDEX_TILE = (256, 1024)      # (queries, keys) of a score step
 SELECT_ROWS = 32              # rows whose scores stay in VMEM together
 CORE_TILE = (1024, 1024)      # (queries, keys) of an attention step
 HEADS_PER_PASS = 8            # heads decompressed into the workspace at once
+FILL_ROWS = 1024              # workspace rows a fill step decompresses
 
 
 # --- the scores --------------------------------------------------------------
@@ -355,6 +361,81 @@ def index_masked_mha(q, k, v, keep, start, num_heads: int, block_q: int,
     )(jnp.reshape(start, (1,)).astype(jnp.int32), q, k, v, keep)
 
 
+def _fill_kernel(n_ref, c_ref, kr_ref, wk_ref, wv_ref, k_ref, v_ref, *,
+                 heads: int, nope: int, precision):
+    @pl.when(pl.program_id(0) < n_ref[0])
+    def _step():
+        c = c_ref[...]
+        dk, dv = k_ref.shape[1] // heads, v_ref.shape[1] // heads
+        kr = kr_ref[...].astype(jnp.float32)
+        is_rope = jax.lax.broadcasted_iota(jnp.int32, kr.shape, 1) >= nope
+        for h in range(heads):
+            k = jnp.dot(c, wk_ref[:, h * dk:(h + 1) * dk],
+                        preferred_element_type=jnp.float32,
+                        precision=precision)
+            k_ref[:, h * dk:(h + 1) * dk] = jnp.where(
+                is_rope, kr, k).astype(k_ref.dtype)
+            v_ref[:, h * dv:(h + 1) * dv] = jnp.dot(
+                c, wv_ref[:, h * dv:(h + 1) * dv],
+                preferred_element_type=jnp.float32,
+                precision=precision).astype(v_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("num_heads", "nope", "block_rows",
+                                             "interpret"))
+def index_fill_kv(c, kr_wide, w_k, w_v, n_rows, num_heads: int, nope: int,
+                  block_rows: int, interpret: bool):
+    """The workspace :func:`index_masked_mha` reads, of ``num_heads`` heads:
+    ``c`` [S,rank] latent rows, ``kr_wide`` [S,dk] the rope key in a key's
+    last columns (anything in its first ``nope``), ``w_k`` [rank, H·dk] a
+    head's ``nope`` key columns (anything in the rope's place), ``w_v``
+    [rank, H·dv], ``n_rows`` (traced, whole ``block_rows``, at least one)
+    the rows to decompress. ``S % block_rows == 0``. Answers ``(k [S, H·dk],
+    v [S, H·dv])``: a head's columns together, a key ``[c W_k | k_rope]``,
+    each product accumulated in float32 and rounded once; rows at or past
+    ``n_rows`` are never written and hold what they were allocated with."""
+    S = c.shape[0]
+    kernel = functools.partial(_fill_kernel, heads=num_heads, nope=nope,
+                               precision=_precision_of(c.dtype))
+
+    def seen(i, n_ref):
+        # a tile past the filled rows stays on the last filled one: nothing
+        # is fetched for it and its output block is not written back
+        return (jnp.minimum(i, n_ref[0] - 1), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(S // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, c.shape[1]), seen),
+                  pl.BlockSpec((block_rows, kr_wide.shape[1]), seen),
+                  pl.BlockSpec(w_k.shape, lambda i, n: (0, 0)),
+                  pl.BlockSpec(w_v.shape, lambda i, n: (0, 0))],
+        out_specs=[pl.BlockSpec((block_rows, w_k.shape[1]), seen),
+                   pl.BlockSpec((block_rows, w_v.shape[1]), seen)])
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((S, w_k.shape[1]), c.dtype),
+                   jax.ShapeDtypeStruct((S, w_v.shape[1]), c.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(jnp.reshape(n_rows // block_rows, (1,)).astype(jnp.int32), c, kr_wide,
+      w_k, w_v)
+
+
+def fill_operands(kr_cache, w_b, num_heads: int, nope: int, g: int, dtype):
+    """What :func:`index_fill_kv` reads beside the latent rows, made once
+    for all the groups of ``g`` heads: ``(kr_wide [S,dk], w_k [H/g, rank,
+    g·dk], w_v [H/g, rank, g·v])`` in ``dtype`` — the rope key in a key's
+    last columns, and a head's key columns with that place left empty."""
+    rank, rope, groups = w_b.shape[0], kr_cache.shape[1], num_heads // g
+    w = w_b.astype(dtype).reshape(rank, groups, g, -1)       # [.., nope+v]
+    w_k = jnp.pad(w[..., :nope], ((0, 0),) * 3 + ((0, rope),))
+    return (jnp.pad(kr_cache.astype(dtype), ((0, 0), (nope, 0))),
+            jnp.moveaxis(w_k, 1, 0).reshape(groups, rank, -1),
+            jnp.moveaxis(w[..., nope:], 1, 0).reshape(groups, rank, -1))
+
+
 def masked_chunk_attention(q_nope, q_rope, c_cache, kr_cache, keep, start,
                            w_b, scale: float, dtype,
                            kernel: str | None = None,
@@ -364,15 +445,18 @@ def masked_chunk_attention(q_nope, q_rope, c_cache, kr_cache, keep, start,
     chunk's own rows. ``q_nope`` [C,H,nope], ``q_rope`` [C,H,r] (roped),
     ``c_cache`` [S,rank], ``kr_cache`` [S,r], ``w_b`` [rank, H·(nope+v)].
 
-    ``heads_per_pass`` heads at a time: their rows ``< start + C`` are
-    decompressed (``c W_b``) into a workspace — a key ``[k_nope | k_rope]``,
-    a value — ``C`` rows a step, and the chunk runs the blocked softmax
-    under the mask over it: nothing ``C × S`` exists but the mask, and the
-    workspace is a group's, not the layer's (4.3 GB at 64 heads × 65 536
-    rows). Answers [C,H,v] in ``dtype``."""
+    ``heads_per_pass`` heads at a time: ONE kernel (:func:`index_fill_kv`)
+    decompresses their rows below the chunk's end into a workspace — a key
+    ``[c W_k | k_rope]``, a value ``c W_v``, in the layout the attention
+    kernel reads — and writes nothing past them (the attention kernel
+    neither fetches nor computes a key tile past a query tile's last row);
+    then the chunk runs the blocked softmax under the mask over it. Nothing
+    ``C × S`` exists but the mask, and the workspace is a group's, not the
+    layer's (4.3 GB at 64 heads × 65 536 rows). Answers [C,H,v] in
+    ``dtype``."""
     kernel = _kernel_of(kernel)
     C, H, nope = q_nope.shape
-    S, rank = c_cache.shape
+    S = c_cache.shape[0]
     v = w_b.shape[1] // H - nope
     if kernel == "lax":
         kv = jnp.dot(c_cache.astype(dtype), w_b.astype(dtype),
@@ -387,44 +471,30 @@ def masked_chunk_attention(q_nope, q_rope, c_cache, kr_cache, keep, start,
                                     dtype).astype(dtype)
     g = math.gcd(H, heads_per_pass)
     rope = q_rope.shape[-1]
-    bq, bk = math.gcd(C, CORE_TILE[0]), math.gcd(S, CORE_TILE[1])
     if S % C:
         raise ValueError(f"a cache of {S} rows is not whole chunks of {C}")
+    # a key tile divides the chunk: no tile the attention kernel fetches
+    # holds a row the fill has not written
+    bq, bk = math.gcd(C, CORE_TILE[0]), math.gcd(C, CORE_TILE[1])
     if kernel == "pallas":
         note_causal("index_select", H, nope + rope, C, S, dtype, bq, bk)
     q = (jnp.concatenate([q_nope, q_rope], -1) * scale).astype(dtype)
     q = jnp.swapaxes(q.reshape(C, H // g, g * (nope + rope)), 0, 1)
-    w_g = jnp.swapaxes(w_b.reshape(rank, H // g, g * (nope + v)), 0, 1)
-    n_fill = jnp.minimum((start + 2 * C - 1) // C, S // C)
-    precision = _precision_of(jnp.dtype(dtype))
+    c_cache = c_cache.astype(dtype)
+    kr_wide, w_k, w_v = fill_operands(kr_cache, w_b, H, nope, g, dtype)
+    n_rows = jnp.minimum((start + 2 * C - 1) // C * C, S)
 
     def one_group(xs):
-        q_g, w = xs
-
-        def fill(j, ws):
-            k_ws, v_ws = ws
-            rows = jax.lax.dynamic_slice_in_dim(c_cache, j * C, C, 0)
-            kr = jax.lax.dynamic_slice_in_dim(kr_cache, j * C, C, 0)
-            kv = jnp.dot(rows.astype(dtype), w.astype(dtype),
-                         preferred_element_type=jnp.float32,
-                         precision=precision).reshape(C, g, nope + v)
-            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
-                kr[:, None].astype(jnp.float32), (C, g, rope))], -1)
-            return (jax.lax.dynamic_update_slice_in_dim(
-                        k_ws, k.reshape(C, -1).astype(dtype), j * C, 0),
-                    jax.lax.dynamic_update_slice_in_dim(
-                        v_ws, kv[..., nope:].reshape(C, -1).astype(dtype),
-                        j * C, 0))
-
-        k_ws, v_ws = jax.lax.fori_loop(
-            0, n_fill, fill,
-            (jnp.zeros((S, g * (nope + rope)), dtype),
-             jnp.zeros((S, g * v), dtype)))
+        q_g, wk_g, wv_g = xs
+        k_ws, v_ws = index_fill_kv(
+            c_cache, kr_wide, wk_g, wv_g, n_rows, num_heads=g, nope=nope,
+            block_rows=math.gcd(C, FILL_ROWS),
+            interpret=kernel == "interpret")
         return index_masked_mha(q_g, k_ws, v_ws, keep, start, num_heads=g,
                                 block_q=bq, block_k=bk,
                                 interpret=kernel == "interpret")
 
-    o = jax.lax.map(one_group, (q, w_g))                   # [H/g, C, g·v]
+    o = jax.lax.map(one_group, (q, w_k, w_v))              # [H/g, C, g·v]
     return jnp.swapaxes(o, 0, 1).reshape(C, H, v)
 
 

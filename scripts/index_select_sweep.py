@@ -11,6 +11,13 @@ cache of 69 632 rows, bfloat16):
   ``--index-tiles``;
 - ``select``: ``index_select_keep`` (top 2048) at ``--select-rows``, and the
   same rule as XLA passes (``select_keep_lax``) on a slice of the rows;
+- ``fill``: ``index_fill_kv`` (a group of 8 heads' keys and values out of
+  the latent cache, PR 52) over the row tiles of ``--fill-rows``, beside the
+  two forms that did not ship — PR 51's (two buffers of zeros a group, a
+  float32 product a 4096-row step, slices, a concatenation, two updates) and
+  the same arithmetic left to XLA without them (the buffers carried, a
+  bfloat16 product with the rope key added in its fusion) — and beside the
+  product's floor (16.35 TFLOP a layer at the chip's 197 TFLOP/s);
 - ``core``: ``index_masked_mha`` over ``--heads`` heads of 256/256 under a
   mask of 2048 random kept keys a row, over ``--core-tiles``, with the
   workspace fill it needs (``masked_chunk_attention`` whole, 64 heads);
@@ -23,7 +30,8 @@ It prints seconds a call and, summed over a 16-chunk prefill (a sampled
 position stands for the chunks nearest it), seconds a layer.
 
     python scripts/index_select_sweep.py [--positions 0,7,15] [--reps 3]
-        [--parts index,select,core,gather] [--out chiprun_out/tile_sweep]
+        [--parts index,select,fill,core,gather]
+        [--out chiprun_out/tile_sweep]
 
 Run on the chip, as the one process that owns it. It fails without a TPU: a
 kernel's time on the CPU says nothing. No program reads this script's
@@ -42,6 +50,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 C, S, TOPK, CHUNKS = 4096, 69632, 2048, 16
 J, DI, H, DK, DV, RANK, ROPE = 32, 128, 64, 256, 256, 512, 64
+# c W_b over the rows each of the 16 chunks sees, a layer, and the chip's peak
+FILL_FLOP = sum(C * n for n in range(1, CHUNKS + 1)) * RANK \
+    * H * (DK - ROPE + DV) * 2
+PEAK_FLOPS = 197e12
 
 
 def timed(fn, *args, reps: int):
@@ -56,6 +68,70 @@ def timed(fn, *args, reps: int):
     return best
 
 
+def timed_in_place(fn, buffers: list, *args, reps: int):
+    """:func:`timed` of a function that is GIVEN its ``len(buffers)`` output
+    buffers (they are donated) and answers them first: ``buffers`` holds the
+    newest."""
+    import jax
+
+    n, best = len(buffers), float("inf")
+    buffers[:] = jax.block_until_ready(fn(*buffers, *args))[:n]
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        buffers[:] = jax.block_until_ready(fn(*buffers, *args))[:n]
+        best = min(best, time.perf_counter() - t0)
+    return best
+
+
+def fill_as_pr51(c, kr, w, n_fill):
+    """A group's workspace as ``masked_chunk_attention`` filled it before PR
+    52. ``w`` [rank, g·(nope+v)]."""
+    import jax
+    import jax.numpy as jnp
+
+    g, nope, bf = w.shape[1] // (DK - ROPE + DV), DK - ROPE, c.dtype
+
+    def fill(j, ws):
+        k_ws, v_ws = ws
+        rows = jax.lax.dynamic_slice_in_dim(c, j * C, C, 0)
+        kr_j = jax.lax.dynamic_slice_in_dim(kr, j * C, C, 0)
+        kv = jnp.dot(rows, w, preferred_element_type=jnp.float32
+                     ).reshape(C, g, nope + DV)
+        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+            kr_j[:, None].astype(jnp.float32), (C, g, ROPE))], -1)
+        return (jax.lax.dynamic_update_slice_in_dim(
+                    k_ws, k.reshape(C, -1).astype(bf), j * C, 0),
+                jax.lax.dynamic_update_slice_in_dim(
+                    v_ws, kv[..., nope:].reshape(C, -1).astype(bf), j * C, 0))
+
+    return jax.lax.fori_loop(0, n_fill, fill,
+                             (jnp.zeros((S, g * DK), bf),
+                              jnp.zeros((S, g * DV), bf)))
+
+
+def fill_as_xla(k_ws, v_ws, c, kr_wide, w_k, w_v, n_fill):
+    """The kernel's arithmetic as XLA products: the buffers given (no
+    zeros), bfloat16 out of each product's fusion, the rope key added there
+    (``w_k``'s rope columns are zero, ``kr_wide``'s others)."""
+    import jax
+    import jax.numpy as jnp
+
+    g = w_k.shape[1] // DK
+
+    def fill(j, ws):
+        k_ws, v_ws = ws
+        rows = jax.lax.dynamic_slice_in_dim(c, j * C, C, 0)
+        kr_j = jax.lax.dynamic_slice_in_dim(kr_wide, j * C, C, 0)
+        k = jnp.dot(rows, w_k, preferred_element_type=jnp.float32) \
+            + jnp.tile(kr_j, (1, g)).astype(jnp.float32)
+        v = jnp.dot(rows, w_v, preferred_element_type=c.dtype)
+        return (jax.lax.dynamic_update_slice_in_dim(
+                    k_ws, k.astype(c.dtype), j * C, 0),
+                jax.lax.dynamic_update_slice_in_dim(v_ws, v, j * C, 0))
+
+    return jax.lax.fori_loop(0, n_fill, fill, (k_ws, v_ws))
+
+
 def over_prefill(by_position: dict) -> float:
     """Seconds a layer: every chunk takes its nearest sampled position's."""
     at = sorted(by_position)
@@ -67,7 +143,8 @@ def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--positions", default="0,7,15")
     parser.add_argument("--reps", type=int, default=3)
-    parser.add_argument("--parts", default="index,select,core,gather")
+    parser.add_argument("--parts", default="index,select,fill,core,gather")
+    parser.add_argument("--fill-rows", default="512,1024,2048")
     parser.add_argument("--index-tiles", default="256x1024,512x1024,256x2048")
     parser.add_argument("--select-rows", default="32")
     parser.add_argument("--core-tiles", default="1024x1024,2048x1024,1024x2048")
@@ -136,6 +213,66 @@ def main(argv=None) -> int:
         report["select.kernel_equals_lax"] = same
         print(f"[sweep] the kernel's mask equals the XLA form's: {same}",
               flush=True)
+
+    if "fill" in parts:
+        g, nope = ops.HEADS_PER_PASS, DK - ROPE
+        c = jax.random.normal(keys[9], (S, RANK), bf)
+        kr = jax.random.normal(keys[10], (S, ROPE), bf)
+        w_b = jax.random.normal(keys[11], (RANK, H * (nope + DV)), bf) / 22.0
+        kr_wide, w_k, w_v = ops.fill_operands(kr, w_b, H, nope, g, bf)
+        w_g = jnp.moveaxis(w_b.reshape(RANK, H // g, -1), 1, 0)
+        floor = FILL_FLOP / PEAK_FLOPS
+        report["fill.floor_layer_s"] = floor
+
+        # an arm fills the workspace of every group of a layer's chunk in
+        # ONE program, as the model's loop over the groups does, and hands
+        # back a row of each (the buffers of eight separate calls cost more
+        # to allocate than a short fill takes)
+        def last_row(ws, n_fill):
+            return tuple(jax.lax.dynamic_slice_in_dim(a, n_fill * C - 1, 1, 0)
+                         for a in ws)
+
+        def line(name, by):
+            layer = over_prefill(by)
+            report[f"fill.{name}"] = {"by_position": by, "layer_s": layer}
+            print(f"[sweep] fill {name} (64 heads by {g}): {by} layer "
+                  f"{layer:.3f} s; the product's floor {floor:.3f} s",
+                  flush=True)
+
+        for rows in (int(r) for r in args.fill_rows.split(",")):
+            line(f"kernel.rows{rows}", {p: timed(jax.jit(
+                lambda n: jax.lax.map(lambda w: last_row(ops.index_fill_kv(
+                    c, kr_wide, *w, n * C, num_heads=g, nope=nope,
+                    block_rows=rows, interpret=False), n), (w_k, w_v))),
+                jnp.int32(p + 1), reps=args.reps) for p in positions})
+        line("pr51", {p: timed(jax.jit(
+            lambda n: jax.lax.map(lambda w: last_row(
+                fill_as_pr51(c, kr, w, n), n), w_g)),
+            jnp.int32(p + 1), reps=args.reps) for p in positions})
+
+        def carried(k_ws, v_ws, n):
+            def one(ws, w):
+                ws = fill_as_xla(*ws, c, kr_wide, *w, n)
+                return ws, last_row(ws, n)
+
+            (k_ws, v_ws), rows = jax.lax.scan(one, (k_ws, v_ws), (w_k, w_v))
+            return k_ws, v_ws, rows
+
+        ws = [jnp.zeros((S, g * DK), bf), jnp.zeros((S, g * DV), bf)]
+        xla = jax.jit(carried, donate_argnums=(0, 1))
+        line("xla", {p: timed_in_place(xla, ws, jnp.int32(p + 1),
+                                       reps=args.reps) for p in positions})
+        w_g = w_g[0]
+        k_ws, v_ws = ops.index_fill_kv(
+            c, kr_wide, w_k[0], w_v[0], jnp.int32(16 * C), num_heads=g,
+            nope=nope, block_rows=ops.FILL_ROWS, interpret=False)
+        k_51, v_51 = jax.jit(fill_as_pr51)(c, kr, w_g, jnp.int32(16))
+        same = bool(jnp.array_equal(k_ws[:16 * C], k_51[:16 * C])
+                    & jnp.array_equal(v_ws[:16 * C], v_51[:16 * C]))
+        report["fill.kernel_equals_pr51"] = same
+        print(f"[sweep] the kernel's 65 536 rows equal PR 51's bit for bit: "
+              f"{same}", flush=True)
+        del ws, k_ws, v_ws, k_51, v_51
 
     if "core" in parts:
         g = args.heads

@@ -815,18 +815,20 @@ def test_the_selecting_rewriters_programs_fit_beside_sdxl(chip, monkeypatch):
     assert 7.5 < decode_gib < 8.4 and decode_gib + sdxl < 15.75 - 1.0
 
 
-GLM_KERNELS = ("index_score_sums", "index_select_keep", "index_masked_mha")
+GLM_KERNELS = ("index_score_sums", "index_select_keep", "index_masked_mha",
+               "index_fill_kv")
 
 
 @pytest.mark.parametrize("kernel", GLM_KERNELS)
 def test_the_index_selecting_kernels_compile_at_the_served_geometry(chip,
                                                                    kernel):
-    """``ops/index_select_attention``'s three kernels as ``glm-5``'s prefill
+    """``ops/index_select_attention``'s kernels as ``glm-5``'s prefill
     calls them (PR 51), alone: the scores of 1024 queries × 32 index heads
     of 128 against 69 632 index keys; the exact top 2048 of 1024 rows, 32
     rows' float32 scores (8.5 MiB) in VMEM for all the passes; 4096 queries
     of 8 heads × 256/256 over a decompressed workspace under the byte
-    mask."""
+    mask; and (PR 52) that workspace's fill: 8 heads' keys and values of
+    69 632 latent rows, 1024 rows a step."""
     from comfyui_distributed_tpu.models.llm_glm import GlmConfig
     from comfyui_distributed_tpu.ops import index_select_attention as ops
 
@@ -851,13 +853,20 @@ def test_the_index_selecting_kernels_compile_at_the_served_geometry(chip,
             arg((n, S), jnp.float32), start, topk=cfg.index_topk,
             rows=ops.SELECT_ROWS, interpret=False)
         out = f"s8[{n},{S}]"
-    else:
+    elif kernel == "index_masked_mha":
         lowered = ops.index_masked_mha.lower(
             arg((C, g * d)), arg((S, g * d)), arg((S, g * d)),
             arg((C, S), jnp.int8), start, num_heads=g,
             block_q=ops.CORE_TILE[0], block_k=ops.CORE_TILE[1],
             interpret=False)
         out = f"bf16[{C},{g * d}]"
+    else:
+        lowered = ops.index_fill_kv.lower(
+            arg((S, cfg.kv_lora_rank)), arg((S, d)),
+            arg((cfg.kv_lora_rank, g * d)), arg((cfg.kv_lora_rank, g * d)),
+            start, num_heads=g, nope=cfg.qk_nope_head_dim,
+            block_rows=ops.FILL_ROWS, interpret=False)
+        out = f"bf16[{S},{g * d}]"
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text and kernel in text and out in text
 
@@ -867,13 +876,17 @@ def test_the_index_selecting_rewriters_programs_fit_beside_sdxl(chip,
     """Both language programs of ``glm-5.brief64k-sdxl8`` at the cell's
     sizes (65 536 + 128 tokens, published widths, 5 layers): they compile
     for the chip and leave room for SDXL's segment program (4.79 + 0.56
-    GiB) in 15.75 GiB; the prefill holds THREE Pallas call sites a layer —
-    scores, selection, attention under the mask —; nothing ``[heads, chunk,
-    rows]`` exists in float32 (the scores are ``[1024, 69 632]``, a
-    quarter of a chunk's queries at a time, the mask a byte a pair); and
+    GiB) in 15.75 GiB; the prefill holds FOUR Pallas call sites a layer —
+    scores, selection, the workspace's fill (PR 52), attention under the
+    mask —; nothing ``[heads, chunk, rows]`` exists in float32 (the scores
+    are ``[1024, 69 632]``, a quarter of a chunk's queries at a time, the
+    mask a byte a pair) and no float32 product of the fill's either (a
+    group's ``[4096, 8, 448]`` before PR 52); no buffer of the workspace's
+    size is written as a constant; and
     ``llm_decode`` holds no Pallas call — one token scores, selects
     (``top_k``) and gathers in XLA."""
     from comfyui_distributed_tpu.models.llm_glm import GlmConfig
+    from comfyui_distributed_tpu.ops import index_select_attention as ops
 
     monkeypatch.setattr(fa, "_platform", lambda: "tpu")
     cfg = GlmConfig.glm_share()
@@ -881,7 +894,7 @@ def test_the_index_selecting_rewriters_programs_fit_beside_sdxl(chip,
     gib, sdxl = 2.0 ** 30, 4.79 + 0.56
     text = compiled["llm_prefill"].as_text()
     calls = _pallas_calls(text)
-    assert len(calls) == 3 * cfg.num_hidden_layers == 15
+    assert len(calls) == 4 * cfg.num_hidden_layers == 20
     for name in GLM_KERNELS:
         assert len(_pallas_calls(text, name)) == cfg.num_hidden_layers
     rows = 17 * cfg.prefill_chunk_tokens
@@ -889,6 +902,11 @@ def test_the_index_selecting_rewriters_programs_fit_beside_sdxl(chip,
             for shape in re.findall(r"f32\[([\d,]+)\]", text)
             if str(rows) in shape.split(",")]
     assert wide and max(wide) == cfg.select_rows * rows
+    C, g = cfg.prefill_chunk_tokens, ops.HEADS_PER_PASS
+    kv = cfg.qk_nope_head_dim + cfg.v_head_dim
+    assert not re.findall(rf"f32\[{C},(?:{g},{kv}|{g * kv})\]", text)
+    assert not re.findall(
+        rf"bf16\[{rows},{g * cfg.v_head_dim}\]\S* broadcast\(", text)
     mem = compiled["llm_prefill"].memory_analysis()
     prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                    + mem.output_size_in_bytes) / gib

@@ -255,10 +255,56 @@ def test_the_masked_kernel_is_a_softmax_over_the_kept_keys(start):
                        atol=1e-5)
 
 
-@pytest.mark.parametrize("heads_per_pass", [1, 2, 4])
-def test_the_chunks_attention_by_groups_of_heads_is_the_whole(heads_per_pass):
+@pytest.mark.parametrize("heads_per_pass", [2, 3])
+@pytest.mark.parametrize("start", [0, 16, 32])
+def test_the_fill_kernel_is_the_plain_decompression_of_the_rows_a_chunk_sees(
+        start, heads_per_pass):
+    """``index_fill_kv`` at the tiny preset's geometry, for the first, a
+    middle and the last chunk of a cache of three, by groups of two heads
+    and (3 does not divide 4) of one: a key ``[c W_k | k_rope]`` and a value
+    ``c W_v``, a head's columns together, on every row below the chunk's
+    end."""
+    keys = jax.random.split(jax.random.key(8), 3)
+    C, H, nope, rope, v, rank = (
+        CFG.prefill_chunk_tokens, CFG.num_attention_heads,
+        CFG.qk_nope_head_dim, CFG.qk_rope_head_dim, CFG.v_head_dim,
+        CFG.kv_lora_rank)
+    S, n = 3 * C, start + C
+    c = jax.random.normal(keys[0], (S, rank))
+    kr = jax.random.normal(keys[1], (S, rope))
+    w_b = jax.random.normal(keys[2], (rank, H * (nope + v))) / 4
+    kv = jnp.dot(c, w_b, precision="highest").reshape(S, H, nope + v)
+    want_k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(kr[:, None], (S, H, rope))], -1)
+    g = math.gcd(H, heads_per_pass)
+    assert g == (2 if heads_per_pass == 2 else 1)
+    kr_wide, w_k, w_v = ops.fill_operands(kr, w_b, H, nope, g, jnp.float32)
+    assert w_k.shape == (H // g, rank, g * (nope + rope))
+    assert w_v.shape == (H // g, rank, g * v)
+    for i in range(H // g):
+        k_ws, v_ws = ops.index_fill_kv(
+            c, kr_wide, w_k[i], w_v[i], n, num_heads=g, nope=nope,
+            block_rows=8, interpret=True)
+        assert k_ws.shape == (S, g * (nope + rope))
+        assert v_ws.shape == (S, g * v)
+        heads = slice(i * g, (i + 1) * g)
+        assert np.allclose(np.asarray(k_ws[:n]).reshape(n, g, -1),
+                           np.asarray(want_k[:n, heads]), atol=1e-5)
+        assert np.allclose(np.asarray(v_ws[:n]).reshape(n, g, -1),
+                           np.asarray(kv[:n, heads, nope:]), atol=1e-5)
+        # the rope key passes through untouched
+        assert np.array_equal(
+            np.asarray(k_ws[:n]).reshape(n, g, -1)[..., nope:],
+            np.asarray(want_k[:n, heads, nope:]))
+
+
+@pytest.mark.parametrize("heads_per_pass", [1, 2, 4, 3])
+def test_the_chunks_attention_by_groups_of_heads_is_the_whole(heads_per_pass,
+                                                              monkeypatch):
     """The workspace holds ``heads_per_pass`` heads' keys and values of the
-    rows the chunk sees; rows above it are never read."""
+    rows the chunk sees; rows above it are never read — neither the caches'
+    by the fill nor the workspace's, which the fill leaves unwritten, by the
+    attention: both may hold NaN."""
     keys = jax.random.split(jax.random.key(6), 6)
     C, H, nope, rope, v, rank, S, start = 16, 4, 8, 8, 16, 16, 48, 16
     q_nope = jax.random.normal(keys[0], (C, H, nope))
@@ -271,10 +317,20 @@ def test_the_chunks_attention_by_groups_of_heads_is_the_whole(heads_per_pass):
             ).at[:, 0].set(True).astype(jnp.int8)
     want = ops.masked_chunk_attention(q_nope, q_rope, c, kr, keep, start,
                                       w_b, 0.25, jnp.float32, "lax")
-    poisoned = c.at[start + C:].set(jnp.nan)
-    got = ops.masked_chunk_attention(q_nope, q_rope, poisoned, kr, keep,
-                                     start, w_b, 0.25, jnp.float32,
-                                     "interpret", heads_per_pass)
+    fill, groups = ops.index_fill_kv, []
+
+    def poisoning_fill(c, kr_wide, w_k, w_v, n_rows, **kw):
+        groups.append(kw["num_heads"])
+        past = (jnp.arange(c.shape[0]) >= n_rows)[:, None]
+        return tuple(jnp.where(past, jnp.nan, ws)
+                     for ws in fill(c, kr_wide, w_k, w_v, n_rows, **kw))
+
+    monkeypatch.setattr(ops, "index_fill_kv", poisoning_fill)
+    got = ops.masked_chunk_attention(
+        q_nope, q_rope, c.at[start + C:].set(jnp.nan),
+        kr.at[start + C:].set(jnp.nan), keep, start, w_b, 0.25, jnp.float32,
+        "interpret", heads_per_pass)
+    assert groups == [math.gcd(H, heads_per_pass)]     # traced once, mapped
     assert got.shape == (C, H, v)
     assert np.allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
