@@ -930,3 +930,16 @@ def _glm_preset(name: str, tiny: bool = False):
 
 PRESETS["glm-5"] = _glm_preset("glm-5")
 PRESETS["glm-tiny"] = _glm_preset("glm-tiny", tiny=True)
+
+
+def _keye_preset(name: str, tiny: bool = False):
+    """The ``keye`` family (models/llm_keye.py), registered at the END of
+    this file for ``_glm_preset``'s reason."""
+    from .llm_keye import KeyeConfig
+
+    share = KeyeConfig.tiny if tiny else KeyeConfig.keye_share
+    return ModelPreset(name, unet=None, vae=None, text=None, llm=share())
+
+
+PRESETS["keye-vl-2.0-30b-a3b"] = _keye_preset("keye-vl-2.0-30b-a3b")
+PRESETS["keye-tiny"] = _keye_preset("keye-tiny", tiny=True)

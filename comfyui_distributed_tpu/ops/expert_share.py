@@ -284,3 +284,94 @@ def held_part_token(x, idx, w, e_gu, e_down, first: int, dtype,
 
     return jax.lax.fori_loop(0, held.sum(), body,
                              jnp.zeros(x.shape, jnp.float32))
+
+
+# --- the grouped form as one streaming kernel (PR 53) --------------------------
+
+
+def streamed_form(rows: int, held: int, r: Routing, tile: int) -> bool:
+    """Whether a prefill of ``rows`` rows over ``held`` held experts takes
+    :func:`held_part_streamed` in place of :func:`held_part_grouped`: where
+    the share is the router's WHOLE width (every routed slot is held, so the
+    rows in expert order are ``rows · per_token`` whatever the routing and
+    the static tile bound wastes at most one tile an expert) and every
+    expert expects at least a whole tile of rows (below that each tile would
+    be mostly padding AND meet a new expert: the loop's dense cousin is the
+    better form there). One rule from the shapes, no flag; a share of a
+    wider router keeps the loop (its five modules call :func:`held_part`)."""
+    return held == r.outputs and rows * r.per_token >= tile * r.outputs
+
+
+def held_part_streamed(x, idx, w, e_gu, e_down, first: int, dtype,
+                       act=silu_gate, valid=None, tile: int = GROUP_TILE,
+                       kernel: "str | None" = None):
+    """What :func:`held_part_grouped` computes, as ONE kernel over all the
+    tiles (``ops/expert_stream.py``): the held slots are put in expert order,
+    each expert's rows padded to whole tiles, the rows of ``x`` gathered
+    ONCE into that order; the kernel walks the tiles with the experts'
+    matrices streamed in behind a prefetched tile → expert table; each slot
+    then reads its row of the result back (a gather, no scatter-add) and the
+    ``per_token`` rows of a token are added times their routing weights. No
+    slot is dropped, whatever the skew: the tile bound ``T · k / tile + E``
+    holds for any routing. ``kernel``: ``pallas`` (the default on a TPU),
+    ``interpret``, or ``lax`` (elsewhere: the loop, which this is held to).
+    Answers ``([T,D] f32, rows multiplied = tiles · tile)``."""
+    from . import flash_attention
+    from .expert_stream import expert_tiles_mlp
+
+    kernel = kernel or ("pallas" if flash_attention._platform() == "tpu"
+                        else "lax")
+    if kernel == "lax":
+        return held_part_grouped(x, idx, w, e_gu, e_down, first, dtype, act,
+                                 None, valid, tile)
+    T, k = idx.shape
+    E = e_gu.shape[0]
+    with device_scope("llm_experts"):
+        held = held_slots(idx, first, E)
+        if valid is not None:
+            held &= valid[:, None]
+        local = jnp.where(held, idx - first, E).reshape(-1)   # E: not ours
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        counts = (local[:, None] == jnp.arange(E)).sum(0).astype(jnp.int32)
+        tiles = (counts + tile - 1) // tile
+        # an expert's first row among the sorted slots, and in the buffer
+        tile_end = jnp.cumsum(tiles)
+        row_start = jnp.cumsum(counts) - counts
+        buf_start = (tile_end - tiles) * tile
+        n_tiles = tile_end[-1]
+        n_max = T * k // tile + E                    # tiles, at the most
+        # where each slot's row sits in the buffer (a slot not held: nowhere)
+        e_of = jnp.minimum(local, E - 1)
+        rank = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32)) - row_start[e_of]
+        place = jnp.where(local < E, buf_start[e_of] + rank, n_max * tile)
+        token = jnp.arange(T * k, dtype=jnp.int32) // k
+        source = jnp.zeros((n_max * tile,), jnp.int32).at[place].set(
+            token, mode="drop")
+        tile_expert = jnp.minimum(
+            (jnp.arange(n_max)[:, None] >= tile_end).sum(1), E - 1)
+        y = expert_tiles_mlp(x.astype(dtype)[source], tile_expert, n_tiles,
+                             e_gu.astype(dtype), e_down.astype(dtype),
+                             tile=tile, act=act,
+                             interpret=kernel == "interpret")
+        # a slot not held reads a row no tile wrote: dropped, not weighted
+        mine = jnp.where((local < E)[:, None],
+                         y[jnp.minimum(place, n_max * tile - 1)]
+                         * w.reshape(-1, 1), 0.0)
+        return mine.reshape(T, k, -1).sum(1), n_tiles * tile
+
+
+def held_part_by_shape(x, idx, w, e_gu, e_down, first: int, dtype,
+                       r: Routing, act=silu_gate, valid=None,
+                       tile: int = GROUP_TILE, kernel: "str | None" = None):
+    """:func:`held_part` for a module whose share may be the whole router:
+    the streamed kernel where :func:`streamed_form` says so, else what
+    :func:`held_part` takes (``prefill_form`` names both ``grouped``: rows
+    in expert order, in tiles)."""
+    if prefill_form(x.shape[0], r, tile) == "grouped" \
+            and streamed_form(x.shape[0], e_gu.shape[0], r, tile):
+        return held_part_streamed(x, idx, w, e_gu, e_down, first, dtype, act,
+                                  valid, tile, kernel)
+    return held_part(x, idx, w, e_gu, e_down, first, dtype, r, act,
+                     valid=valid, tile=tile)
+

@@ -125,7 +125,8 @@ LLM_CACHE_BYTES = REGISTRY.gauge(
     "full (prompt + new rows a layer), recurrent (linear-attention or "
     "state-space states and convolution tails), or a model's own kinds "
     "(latent and index: a latent cache and the index keys that choose its "
-    "rows). Set when a request's cache is made.",
+    "rows; kv and index: K/V rows and the index keys that choose them). "
+    "Set when a request's cache is made.",
     ("layers",))
 
 LLM_PREFILL_CHUNKS = REGISTRY.counter(

@@ -916,3 +916,104 @@ def test_the_index_selecting_rewriters_programs_fit_beside_sdxl(chip,
     mem = compiled["llm_decode"].memory_analysis()
     decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
     assert 5.8 < decode_gib < 6.5 and decode_gib + sdxl < 15.75 - 1.0
+
+
+KEYE_KERNELS = ("index_score_sums", "index_select_keep", "index_masked_gqa",
+                "expert_tiles_mlp")
+
+
+@pytest.mark.parametrize("kernel", KEYE_KERNELS)
+def test_the_index_selecting_gqa_kernels_compile_at_the_served_geometry(
+        chip, kernel):
+    """The three kernels as ``keye-vl-2.0-30b-a3b``'s prefill calls them
+    (PR 53), alone: the scores of 1024 queries × 16 index heads of 64 — half
+    the matrix unit's depth — against 69 632 index keys at THIS module's
+    tile; GLM's selection kernel as it is; and 4096 queries of 32 heads over
+    4 K/V heads of 128 under the byte mask, K and V tiles read out of the
+    ``[69 632, 1024]`` row buffer where they lie."""
+    from comfyui_distributed_tpu.models.llm_keye import KeyeConfig
+    from comfyui_distributed_tpu.ops import index_gqa_attention as gqa_ops
+    from comfyui_distributed_tpu.ops import index_select_attention as ops
+
+    cfg = KeyeConfig.keye_share()
+    C, S = cfg.prefill_chunk_tokens, 17 * cfg.prefill_chunk_tokens
+    n, J, di = cfg.select_rows, cfg.indexer_num_heads, cfg.indexer_head_dim
+    H, G, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    start = arg((), jnp.int32)
+    if kernel == "index_score_sums":
+        lowered = ops.index_score_sums.lower(
+            arg((J, n, di)), arg((n, J), jnp.float32), arg((S, di)), start,
+            block_q=gqa_ops.INDEX_TILE[0], block_k=gqa_ops.INDEX_TILE[1],
+            interpret=False)
+        out = f"f32[{n},{S}]"
+    elif kernel == "index_select_keep":
+        lowered = ops.index_select_keep.lower(
+            arg((n, S), jnp.float32), start, topk=cfg.topk,
+            rows=ops.SELECT_ROWS, interpret=False)
+        out = f"s8[{n},{S}]"
+    elif kernel == "index_masked_gqa":
+        lowered = gqa_ops.index_masked_gqa.lower(
+            arg((C, H * d)), arg((S, 2 * G * d)), arg((C, S), jnp.int8),
+            start, num_heads=H, num_kv_heads=G,
+            block_q=gqa_ops.CORE_TILE[0], block_k=gqa_ops.CORE_TILE[1],
+            interpret=False)
+        out = f"bf16[{C},{H * d}]"
+    else:
+        # the expert layer's tiles: 128 experts' [2048, 1536] and [768, 2048]
+        # streamed behind the tile -> expert table, 256 tiles at the most
+        from comfyui_distributed_tpu.ops import expert_share, expert_stream
+
+        E, D, F, tile = (cfg.num_experts, cfg.hidden_size,
+                         cfg.moe_intermediate_size, cfg.expert_tile)
+        n = C * cfg.num_experts_per_tok // tile + E
+        lowered = expert_stream.expert_tiles_mlp.lower(
+            arg((n * tile, D)), arg((n,), jnp.int32), start,
+            arg((E, D, 2 * F)), arg((E, F, D)), tile=tile,
+            act=expert_share.silu_gate, interpret=False)
+        out = f"f32[{n * tile},{D}]"
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text and kernel in text and out in text
+
+
+def test_the_all_held_rewriters_programs_fit_beside_sdxl(chip, monkeypatch):
+    """Both language programs of ``keye-vl-2.0-30b-a3b.brief64k-sdxl8`` at
+    the cell's sizes (65 536 + 128 tokens, published widths, 4 layers, all
+    128 experts of each, the whole vocabulary): they compile for the chip
+    and leave room for SDXL's segment program (4.79 + 0.56 GiB) in 15.75
+    GiB; the prefill holds FOUR Pallas call sites a layer — scores,
+    selection, attention under the mask (no workspace, no fill) and the
+    expert layer's tiles —; the
+    ``[69 632, 1024]`` K/V rows are never copied into a per-head layout
+    (nothing ``[4, 69 632, 128]`` exists); nothing ``[heads, chunk, rows]``
+    exists in float32; and ``llm_decode`` holds no Pallas call — one token
+    scores, selects (``top_k``), gathers and reads its 8 experts in XLA."""
+    from comfyui_distributed_tpu.models.llm_keye import KeyeConfig
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    cfg = KeyeConfig.keye_share()
+    compiled = loop_copies.compiled_programs(cfg, 65536, 128, chip)
+    gib, sdxl = 2.0 ** 30, 4.79 + 0.56
+    text = compiled["llm_prefill"].as_text()
+    assert len(_pallas_calls(text)) == 4 * cfg.num_hidden_layers == 16
+    for name in KEYE_KERNELS:
+        assert len(_pallas_calls(text, name)) == cfg.num_hidden_layers
+    rows = 17 * cfg.prefill_chunk_tokens
+    wide = [math.prod(int(n) for n in shape.split(","))
+            for shape in re.findall(r"f32\[([\d,]+)\]", text)
+            if str(rows) in shape.split(",")]
+    assert wide and max(wide) == cfg.select_rows * rows
+    G, d = cfg.num_key_value_heads, cfg.head_dim
+    assert not re.findall(rf"bf16\[{G},{rows},{d}\]", text)
+    mem = compiled["llm_prefill"].memory_analysis()
+    prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                   + mem.output_size_in_bytes) / gib
+    assert 7.2 < prefill_gib < 8.0 and prefill_gib + sdxl < 15.75 - 1.0
+    text = compiled["llm_decode"].as_text()
+    assert "tpu_custom_call" not in text                   # decode is XLA
+    mem = compiled["llm_decode"].memory_analysis()
+    decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
+    assert 6.4 < decode_gib < 7.8 and decode_gib + sdxl < 15.75 - 1.0

@@ -217,7 +217,8 @@ LLMS = {"ling-tiny": ("llm_hybrid", "LLMConfig", 24),
         "trinity-tiny": ("llm_trinity", "TrinityConfig", 21),  # 2 chunks + 5
         "longcat-tiny": ("llm_longcat", "LongcatConfig", 37),  # three chunks
         "sala-tiny": ("llm_sala", "SalaConfig", 40),   # past its dense_len
-        "glm-tiny": ("llm_glm", "GlmConfig", 40)}      # past its index_topk
+        "glm-tiny": ("llm_glm", "GlmConfig", 40),      # past its index_topk
+        "keye-tiny": ("llm_keye", "KeyeConfig", 40)}   # past its topk
 
 PROGRAMS = {"txt2img_seg": _txt2img_seg, "flow_seg": _flow_seg, "fin": _fin}
 for _name, _how in LLMS.items():
@@ -270,6 +271,11 @@ EXPECTED = {
                              "llm_shared_ffn", "llm_head"},
     "llm_decode:glm-tiny": {"llm_attn", "llm_router", "llm_experts",
                             "llm_shared_ffn", "llm_head"},
+    # no shared expert and no dense layer: nothing opens llm_shared_ffn
+    "llm_prefill:keye-tiny": {"llm_attn", "llm_router", "llm_experts",
+                              "llm_head"},
+    "llm_decode:keye-tiny": {"llm_attn", "llm_router", "llm_experts",
+                             "llm_head"},
 }
 
 
@@ -323,7 +329,8 @@ def test_the_index_selecting_rewriters_work_is_named_below_its_layer():
     the kept keys is under exactly one of them, and under the one
     registered layer (the selection multiplies nothing)."""
     plain = re.compile(r"/(llm_index|llm_select|llm_sparse_attn)(?:/|$)")
-    for program in ("llm_prefill:glm-tiny", "llm_decode:glm-tiny"):
+    for program in ("llm_prefill:glm-tiny", "llm_decode:glm-tiny",
+                    "llm_prefill:keye-tiny", "llm_decode:keye-tiny"):
         fn, args = PROGRAMS[program]()
         seen = list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
         below = {}
