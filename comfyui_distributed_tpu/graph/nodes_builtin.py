@@ -1017,6 +1017,10 @@ class TPUPromptRewrite(NodeDef):
                 for (kind, phase), n in pairs(prompt_tokens,
                                               new_tokens).items():
                     _tm.LLM_ATTN_KEYS.labels(layers=kind, phase=phase).inc(n)
+            columns = getattr(cfg, "select_columns", None)
+            if columns is not None:   # an exact selection a query
+                for kind, n in columns(prompt_tokens, new_tokens).items():
+                    _tm.LLM_SELECT_COLUMNS.labels(kind=kind).inc(n)
             blocks = getattr(cfg, "selected_blocks", None)
             if blocks is not None:    # attention over a selection of blocks
                 for kind, n in blocks(prompt_tokens, new_tokens).items():

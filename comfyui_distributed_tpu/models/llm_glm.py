@@ -166,6 +166,15 @@ class GlmConfig:
         return {("sparse", "prefill"): n * int(read[:prompt_tokens].sum()),
                 ("sparse", "decode"): n * int(read[prompt_tokens:].sum())}
 
+    def select_columns(self, prompt_tokens: int, new_tokens: int) -> dict:
+        """Columns the prefill's selection steps visit in a request and
+        the columns whole cache rows would be (``searched``, ``cache``),
+        summed over the layers: ``index_select_attention.select_columns``."""
+        one = index_ops.select_columns(prompt_tokens, new_tokens,
+                                       self.prefill_chunk_tokens,
+                                       self.select_rows)
+        return {kind: self.num_hidden_layers * n for kind, n in one.items()}
+
 
 # --- weights ---------------------------------------------------------------
 
@@ -301,7 +310,9 @@ def _selected_attention(cfg: GlmConfig, p, q_nope, q_rope, q_i, w, c, kr, ki,
                                             kernel)
         with jax.named_scope("llm_select"):
             # the barrier keeps the kernel a call of its own: fused into the
-            # write of its rows it loses its VMEM limit (16 MiB, 21 needed)
+            # write of its rows it loses its VMEM limit (16 MiB, ~60 needed).
+            # ``first`` is all the kernel needs to stop at the columns these
+            # rows can see (PR 54)
             return jax.lax.optimization_barrier(index_ops.select_keep(
                 scores, first, cfg.index_topk, kernel))
 

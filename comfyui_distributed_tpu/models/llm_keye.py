@@ -155,6 +155,15 @@ class KeyeConfig:
         return {("sparse", "prefill"): n * int(read[:prompt_tokens].sum()),
                 ("sparse", "decode"): n * int(read[prompt_tokens:].sum())}
 
+    def select_columns(self, prompt_tokens: int, new_tokens: int) -> dict:
+        """Columns the prefill's selection steps visit in a request and
+        the columns whole cache rows would be (``searched``, ``cache``),
+        summed over the layers: ``index_select_attention.select_columns``."""
+        one = index_ops.select_columns(prompt_tokens, new_tokens,
+                                       self.prefill_chunk_tokens,
+                                       self.select_rows)
+        return {kind: self.num_hidden_layers * n for kind, n in one.items()}
+
 
 # --- weights ---------------------------------------------------------------
 

@@ -22,7 +22,13 @@ and the tests' yardstick) and the form a TPU runs:
   value than places are left — the last tied POSITION taken by a second
   search over the positions. The answer is a mask ``[C, S]`` int8, not a
   list: a row's scores stay in VMEM for all the passes (the kernel reads
-  the scores once and writes a byte a pair).
+  the scores once and writes a byte a pair). A pass walks the row block in
+  column tiles and stops at the last one a row of the block can SEE (PR
+  54): the order image, every count and the mask's work are for the
+  columns below ``first + rows``, read off the prefetched position — chunk
+  0 of a 64k brief visits one tile of seventeen, the prefill half the
+  cache's columns — and the mask past them is written as zeros
+  (:func:`select_columns` is the same rule on the host, for a counter).
 * **the attention** over the kept keys. Prefill
   (:func:`masked_chunk_attention`) decompresses a GROUP of heads' keys and
   values at a time into a workspace — ONE kernel, ``index_fill_kv`` (PR 52):
@@ -68,10 +74,11 @@ _VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 _INT_MIN = -2 ** 31
 # the tiles of the four kernels at the served sizes (a chunk of 4096
 # queries, 128-wide index heads, 256-wide keys and values), fixed by
-# measurement (PERF.md §6, PRs 51 and 52); a smaller call takes what divides
-# it
+# measurement (PERF.md §6, PRs 51, 52 and 54); a smaller call takes what
+# divides it
 INDEX_TILE = (256, 1024)      # (queries, keys) of a score step
-SELECT_ROWS = 32              # rows whose scores stay in VMEM together
+SELECT_ROWS = 64              # rows whose scores stay in VMEM together
+SELECT_TILE = 4096            # columns of them a turn of a pass's loop counts
 CORE_TILE = (1024, 1024)      # (queries, keys) of an attention step
 HEADS_PER_PASS = 8            # heads decompressed into the workspace at once
 FILL_ROWS = 1024              # workspace rows a fill step decompresses
@@ -188,24 +195,21 @@ def _largest(ok, bits: int, init):
     return jax.lax.fori_loop(0, bits, body, init)
 
 
-def keep_of(key, col, topk: int, position_bits: int):
-    """``key`` [n,S] int32 (a row's order images, ``_INT_MIN`` where the
-    row may not look), ``col`` [n,S] or [1,S] positions. Answers the bool
-    mask of each row's ``topk`` largest keys, ties to the lower position —
-    every admissible key where a row has at most ``topk`` of them (the
-    caller ands the admissible ones)."""
-    def count(pred):
-        return jnp.sum(pred.astype(jnp.int32), axis=1, keepdims=True)
-
+def _search(count, n: int, topk: int, position_bits: int):
+    """What decides the selection of ``n`` rows, each [n,1] int32: the
+    ``topk``-th largest key of a row (``_INT_MIN`` where it has fewer
+    admissible ones) and the last tied POSITION it takes. ``count(pred)``
+    answers [n,1] int32: of each row, the columns where ``pred(key, col)``
+    holds — over whatever columns the caller holds the row's admissible
+    keys among."""
     def reaches(cand):
-        return count(key >= cand) >= topk
+        return count(lambda key, col: key >= cand) >= topk
 
-    zero = jnp.zeros((key.shape[0], 1), jnp.int32)
+    zero = jnp.zeros((n, 1), jnp.int32)
     kth = _largest(reaches, 31,
                    jnp.where(reaches(zero), zero, jnp.int32(_INT_MIN)))
-    above = key > kth
-    tied = key == kth
-    left = topk - count(above)                  # ≥ 1 places for the ties
+    # ≥ 1 places for the ties
+    left = topk - count(lambda key, col: key > kth)
 
     def all_tied():
         return jnp.full_like(zero, 2 ** position_bits - 1)
@@ -213,11 +217,30 @@ def keep_of(key, col, topk: int, position_bits: int):
     def some_tied():
         # the last position taken: the largest p with fewer than ``left``
         # ties below it
-        return _largest(lambda p: count(tied & (col < p)) < left,
-                        position_bits, zero)
+        return _largest(
+            lambda p: count(lambda key, col: (key == kth) & (col < p)) < left,
+            position_bits, zero)
 
-    last = jax.lax.cond(jnp.any(count(tied) > left), some_tied, all_tied)
-    return above | (tied & (col <= last))
+    tied = count(lambda key, col: key == kth)
+    return kth, jax.lax.cond(jnp.any(tied > left), some_tied, all_tied)
+
+
+def _kept(key, col, kth, last):
+    return (key > kth) | ((key == kth) & (col <= last))
+
+
+def keep_of(key, col, topk: int, position_bits: int):
+    """``key`` [n,S] int32 (a row's order images, ``_INT_MIN`` where the
+    row may not look), ``col`` [n,S] or [1,S] positions. Answers the bool
+    mask of each row's ``topk`` largest keys, ties to the lower position —
+    every admissible key where a row has at most ``topk`` of them (the
+    caller ands the admissible ones)."""
+    def count(pred):
+        return jnp.sum(pred(key, col).astype(jnp.int32), axis=1,
+                       keepdims=True)
+
+    return _kept(key, col, *_search(count, key.shape[0], topk,
+                                    position_bits))
 
 
 def select_keep_lax(scores, first_row, topk: int):
@@ -232,28 +255,82 @@ def select_keep_lax(scores, first_row, topk: int):
     return (keep & seen).astype(jnp.int8)
 
 
-def _select_kernel(start_ref, s_ref, o_ref, *, rows: int, topk: int,
-                   position_bits: int):
-    row = start_ref[0] + pl.program_id(0) * rows \
-        + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape, 1)
-    seen = col <= row
-    key = jnp.where(seen, order_key(s_ref[...]), jnp.int32(_INT_MIN))
-    keep = keep_of(key, col, topk, position_bits)
-    o_ref[...] = (keep & seen).astype(jnp.int8)
+def _select_visible(first, scores_of, o_ref, key_ref, *, topk: int,
+                    position_bits: int, tile: int):
+    """:func:`select_keep_lax` of the rows at positions ``first …`` over
+    the column tiles they can SEE: tile ``t`` holds the positions ``t·tile
+    …``, and no row looks past the last one's own — the tiles after that
+    are never read, counted or compared, and their mask is zeros.
+    ``scores_of(t, at)`` answers tile ``t``'s float32 scores (``at`` its
+    columns in a row block); ``key_ref`` [rows, S] int32 is scratch."""
+    rows, tiles = o_ref.shape[0], o_ref.shape[1] // tile
+    row = first + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    visible = jnp.minimum((first + rows + tile - 1) // tile, tiles)
+    lane = math.gcd(tile, 128)
+
+    def columns(t, offset: int = 0, width: int = tile):
+        """``width`` columns of tile ``t`` from its ``offset``-th: where
+        they lie in the row block, and their positions."""
+        at = pl.multiple_of(t * tile, tile) + offset
+        return pl.ds(at, width), at + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, width), 1)
+
+    @pl.loop(0, visible)
+    def _image(t):
+        at, col = columns(t)
+        key_ref[:, at] = jnp.where(col <= row, order_key(scores_of(t, at)),
+                                   jnp.int32(_INT_MIN))
+
+    def count(pred):
+        # a lane keeps its own running count through a row's tiles (plain
+        # register adds); the lanes are summed once a pass
+        def one(t, lanes):
+            for g in range(0, tile, lane):
+                at, col = columns(t, g, lane)
+                lanes = lanes + pred(key_ref[:, at], col).astype(jnp.int32)
+            return lanes
+
+        return jnp.sum(jax.lax.fori_loop(
+            0, visible, one, jnp.zeros((rows, lane), jnp.int32)),
+            axis=1, keepdims=True)
+
+    kth, last = _search(count, rows, topk, position_bits)
+
+    @pl.loop(0, visible)
+    def _mask(t):
+        at, col = columns(t)
+        keep = _kept(key_ref[:, at], col, kth, last) & (col <= row)
+        o_ref[:, at] = keep.astype(jnp.int8)
+
+    @pl.loop(visible, tiles)
+    def _unseen(t):
+        o_ref[:, columns(t)[0]] = jnp.zeros((rows, tile), jnp.int8)
 
 
-@functools.partial(jax.jit, static_argnames=("topk", "rows", "interpret"))
-def index_select_keep(scores, start, topk: int, rows: int, interpret: bool):
+def _select_kernel(start_ref, s_ref, o_ref, key_ref, *, rows: int, **rule):
+    _select_visible(start_ref[0] + pl.program_id(0) * rows,
+                    lambda t, at: s_ref[:, at], o_ref, key_ref, **rule)
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "rows", "tile",
+                                             "interpret"))
+def index_select_keep(scores, start, topk: int, rows: int, tile: int,
+                      interpret: bool):
     """:func:`select_keep_lax` as a kernel: ``rows`` queries' scores in
-    VMEM at a time. ``scores`` [C,S] float32, ``C % rows == 0``."""
+    VMEM at a time, searched ``tile`` columns at a time as far as the
+    step's last row sees (``start`` decides: chunk 0 of a long cache
+    visits one tile in seventeen). ``scores`` [C,S] float32, ``C % rows ==
+    0``, ``S % tile == 0``; the mask is whole: 0 in every column past a
+    row's position."""
     C, S = scores.shape
     kernel = functools.partial(_select_kernel, rows=rows, topk=topk,
-                               position_bits=max(S.bit_length(), 1))
+                               position_bits=max(S.bit_length(), 1),
+                               tile=tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(C // rows,),
         in_specs=[pl.BlockSpec((rows, S), lambda i, s: (i, 0))],
-        out_specs=pl.BlockSpec((rows, S), lambda i, s: (i, 0)))
+        out_specs=pl.BlockSpec((rows, S), lambda i, s: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((rows, S), jnp.int32)])
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, S), jnp.int8),
@@ -264,15 +341,41 @@ def index_select_keep(scores, start, topk: int, rows: int, interpret: bool):
     )(jnp.reshape(start, (1,)).astype(jnp.int32), scores)
 
 
+def select_tile(S: int) -> int:
+    """The columns a pass of the selection visits at a time over a cache of
+    ``S`` rows: ``SELECT_TILE`` where it divides them, else all of them."""
+    return S if S % SELECT_TILE else SELECT_TILE
+
+
+def select_columns(prompt_tokens: int, new_tokens: int, chunk: int,
+                   call_rows: int) -> dict:
+    """Columns the selection kernel's grid steps visit over one layer of a
+    chunked prefill — ``searched`` — and what whole rows would be —
+    ``cache`` —, by :func:`_select_visible`'s rule at :func:`select_keep`'s
+    tiles: ``chunk`` tokens a chunk (a padded last chunk selects too),
+    ``call_rows`` of them a call, a cache of whole chunks."""
+    chunk = min(chunk, prompt_tokens)
+    walked = -(-prompt_tokens // chunk) * chunk
+    S = -(-max(prompt_tokens + new_tokens, walked) // chunk) * chunk
+    rows, tile = math.gcd(chunk, call_rows), S    # the plain form's one step
+    if rows % SELECT_ROWS == 0:
+        rows, tile = SELECT_ROWS, select_tile(S)
+    first = range(0, walked, rows)
+    return {"searched": tile * sum(min(-(-(f + rows) // tile), S // tile)
+                                   for f in first),
+            "cache": S * len(first)}
+
+
 def select_keep(scores, start, topk: int, kernel: str | None = None):
     """The mask of the queries at positions ``start …``: int8 [C,S], 1 on
     the ``min(topk, t + 1)`` positions ``s ≤ t`` of largest score, ties to
     the lower ``s``. ``kernel`` as :func:`index_scores`."""
     kernel = _kernel_of(kernel)
-    C = scores.shape[0]
+    C, S = scores.shape
     if kernel == "lax" or C % SELECT_ROWS:
         return select_keep_lax(scores, start, topk)
     return index_select_keep(scores, start, topk=topk, rows=SELECT_ROWS,
+                             tile=select_tile(S),
                              interpret=kernel == "interpret")
 
 

@@ -600,6 +600,20 @@ LLM_SELECT_SLOT_TILES = REGISTRY.counter(
     "token counts; a request within dense_len scores nothing.",
     ("kind",))
 
+LLM_SELECT_COLUMNS = REGISTRY.counter(
+    "cdt_llm_select_columns_total",
+    "Cache columns the grid steps of an index-selecting language model's "
+    "exact selection kernel met in a prefill (ops/index_select_attention.py: "
+    "index_select_keep), over every (layer, step) of a request, by kind: "
+    "searched (the column tiles a step's rows can see: the order image, "
+    "every counting pass and the mask's work are theirs alone) and cache "
+    "(whole rows: what every step visited before the kernel read its "
+    "position). By the kernel's own rule at its own tiles, from the "
+    "config's sizes and the request's token counts; searched / cache is "
+    "how far the causal cut engages (0.5 over a prefill of many chunks, 1 "
+    "where one tile is the whole cache). Decode selects in the plain form.",
+    ("kind",))
+
 # --- the set-up ledger (telemetry/build.py): exclusive SELF seconds -----------
 # A program's name comes from the code base, not from traffic, so these two
 # families may hold more series than MAX_SERIES: past it the overflow series

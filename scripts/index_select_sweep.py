@@ -9,8 +9,15 @@ cache of 69 632 rows, bfloat16):
 
 - ``index``: ``index_score_sums`` (32 index heads of 128) over the tiles of
   ``--index-tiles``;
-- ``select``: ``index_select_keep`` (top 2048) at ``--select-rows``, and the
-  same rule as XLA passes (``select_keep_lax``) on a slice of the rows;
+- ``select``: ``index_select_keep`` (top 2048) at ``--select-rows`` over the
+  column tiles of ``--select-tiles`` — the shipped form (PR 54: a step
+  searches the column tiles its rows can see, the row block auto-pipelined
+  whole) — beside the other candidate (the scores left in HBM, a step
+  copying in its visible tiles itself) and the whole-width kernel both
+  replace (PR 51's: every pass over all 69 632 columns), in seconds a chunk
+  and microseconds a grid step by position, each held to
+  ``select_keep_lax``'s bits at every position; and the same rule as XLA
+  passes on a slice of the rows;
 - ``fill``: ``index_fill_kv`` (a group of 8 heads' keys and values out of
   the latent cache, PR 52) over the row tiles of ``--fill-rows``, beside the
   two forms that did not ship — PR 51's (two buffers of zeros a group, a
@@ -83,6 +90,98 @@ def timed_in_place(fn, buffers: list, *args, reps: int):
     return best
 
 
+def select_whole_rows(scores, start, topk: int, rows: int,
+                      interpret: bool = False):
+    """The selection as PR 51 shipped it: ``rows`` whole rows of the cache
+    in VMEM, every pass of the search over all their columns whatever the
+    rows can see."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from comfyui_distributed_tpu.ops import index_select_attention as ops
+
+    n, width = scores.shape
+    bits = max(width.bit_length(), 1)
+
+    def kernel(start_ref, s_ref, o_ref):
+        row = start_ref[0] + pl.program_id(0) * rows \
+            + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape, 1)
+        seen = col <= row
+        key = jnp.where(seen, ops.order_key(s_ref[...]),
+                        jnp.int32(ops._INT_MIN))
+        o_ref[...] = (ops.keep_of(key, col, topk, bits)
+                      & seen).astype(jnp.int8)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(n // rows,),
+        in_specs=[pl.BlockSpec((rows, width), lambda i, s: (i, 0))],
+        out_specs=pl.BlockSpec((rows, width), lambda i, s: (i, 0)))
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, width), jnp.int8),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=ops._VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(jnp.reshape(start, (1,)).astype(jnp.int32), scores)
+
+
+def select_copied_tiles(scores, start, topk: int, rows: int, tile: int,
+                        interpret: bool = False):
+    """The shipped search with the scores left in HBM: a step sets out a
+    copy of each column tile its rows can see and makes a tile's order image
+    as the tile arrives; nothing past them is fetched."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from comfyui_distributed_tpu.ops import index_select_attention as ops
+
+    n, width = scores.shape
+    tiles, bits = width // tile, max(width.bit_length(), 1)
+
+    def kernel(start_ref, s_hbm, o_ref, s_buf, key_ref, sems):
+        i = pl.program_id(0)
+        first = start_ref[0] + i * rows
+
+        def copy(t):
+            at = pl.ds(pl.multiple_of(t * tile, tile), tile)
+            return pltpu.make_async_copy(
+                s_hbm.at[pl.ds(pl.multiple_of(i * rows, rows), rows), at],
+                s_buf.at[:, at], sems.at[t])
+
+        @pl.loop(0, jnp.minimum((first + rows + tile - 1) // tile, tiles))
+        def _fetch(t):
+            copy(t).start()
+
+        def arrived(t, at):
+            copy(t).wait()
+            return s_buf[:, at]
+
+        ops._select_visible(first, arrived, o_ref, key_ref, topk=topk,
+                            position_bits=bits, tile=tile)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(n // rows,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((rows, width), lambda i, s: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((rows, width), jnp.float32),
+                        pltpu.VMEM((rows, width), jnp.int32),
+                        pltpu.SemaphoreType.DMA((tiles,))])
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, width), jnp.int8),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=ops._VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(jnp.reshape(start, (1,)).astype(jnp.int32), scores)
+
+
 def fill_as_pr51(c, kr, w, n_fill):
     """A group's workspace as ``masked_chunk_attention`` filled it before PR
     52. ``w`` [rank, g·(nope+v)]."""
@@ -146,7 +245,8 @@ def main(argv=None) -> int:
     parser.add_argument("--parts", default="index,select,fill,core,gather")
     parser.add_argument("--fill-rows", default="512,1024,2048")
     parser.add_argument("--index-tiles", default="256x1024,512x1024,256x2048")
-    parser.add_argument("--select-rows", default="32")
+    parser.add_argument("--select-rows", default="32,64")
+    parser.add_argument("--select-tiles", default="2048,4096")
     parser.add_argument("--core-tiles", default="1024x1024,2048x1024,1024x2048")
     parser.add_argument("--heads", type=int, default=8)
     parser.add_argument("--gather-rows", type=int, default=256)
@@ -187,28 +287,58 @@ def main(argv=None) -> int:
 
     scores = None
     if "select" in parts or "gather" in parts:
+        # of the cache's LAST 1024 positions: every column is a real score
         scores = ops.index_score_sums(
-            q_i[:, :1024], w[:1024], k_i, jnp.int32(15 * C), block_q=256,
+            q_i[:, :1024], w[:1024], k_i, jnp.int32(S - 1024), block_q=256,
             block_k=1024, interpret=False)
     if "select" in parts:
+        calls = 32           # of 1024 rows in one program: 8 chunks' worth
+        # a chunk's first and last 1024 rows at each position, as XLA passes
+        plain = jax.jit(lambda sc, s: ops.select_keep_lax(sc, s, TOPK))
+        wants = {s: plain(scores, jnp.int32(s))
+                 for p in positions for s in (p * C, p * C + 3072)}
+
+        def line(name, form, rows):
+            """Seconds a chunk of 4096 queries by position — four calls of
+            1024 rows, as the model makes them, ``calls`` of them behind
+            ONE dispatch — and whether the form's mask is the XLA form's
+            in a chunk's first and last 1024 rows."""
+            walk = jax.jit(lambda sc, s: jax.lax.map(
+                lambda j: form(sc, s + j * 1024)[:8, :128],
+                jnp.arange(calls) % 4))
+            by = {p: 4 * timed(walk, scores, jnp.int32(p * C),
+                               reps=args.reps) / calls for p in positions}
+            mask = jax.jit(form)
+            same = all(bool(jnp.array_equal(mask(scores, jnp.int32(s)), want))
+                       for s, want in wants.items())
+            step = {p: round(1e6 * t * rows / C, 2) for p, t in by.items()}
+            report[f"select.{name}"] = {
+                "by_position": by, "layer_s": over_prefill(by),
+                "step_us": step, "equals_lax": same}
+            print(f"[sweep] select {name}: a chunk {by}, a {rows}-row step "
+                  f"{step} us, layer {over_prefill(by):.4f} s; the XLA "
+                  f"form's mask at every position: {same}", flush=True)
+
         for rows in (int(r) for r in args.select_rows.split(",")):
-            by = {p: 4 * timed(lambda s: ops.index_select_keep(
-                scores, s, topk=TOPK, rows=rows, interpret=False),
-                jnp.int32(p * C), reps=args.reps) for p in positions}
-            report[f"select.rows{rows}"] = {"by_position": by,
-                                            "layer_s": over_prefill(by)}
-            print(f"[sweep] select rows {rows} (x4: a chunk): {by} layer "
-                  f"{over_prefill(by):.3f} s", flush=True)
+            for tile in (int(t) for t in args.select_tiles.split(",")):
+                line(f"visible.rows{rows}.tile{tile}",
+                     lambda sc, s: ops.index_select_keep(
+                         sc, s, topk=TOPK, rows=rows, tile=tile,
+                         interpret=False), rows)
+                line(f"copied.rows{rows}.tile{tile}",
+                     lambda sc, s: select_copied_tiles(sc, s, TOPK, rows,
+                                                       tile), rows)
+            line(f"whole.rows{rows}",
+                 lambda sc, s: select_whole_rows(sc, s, TOPK, rows), rows)
         lax_rows = 128
-        t = timed(jax.jit(lambda sc, s: ops.select_keep_lax(sc, s, TOPK)),
-                  scores[:lax_rows], jnp.int32(15 * C), reps=args.reps)
+        t = timed(plain, scores[:lax_rows], jnp.int32(15 * C),
+                  reps=args.reps)
         report["select.lax"] = {"rows": lax_rows, "seconds": t,
                                 "chunk_s": t * C / lax_rows}
         print(f"[sweep] select as XLA passes: {t:.4f} s for {lax_rows} rows "
               f"= {t * C / lax_rows:.3f} s a chunk", flush=True)
         same = bool(jnp.array_equal(
-            ops.index_select_keep(scores[:lax_rows], jnp.int32(15 * C),
-                                  topk=TOPK, rows=32, interpret=False),
+            ops.select_keep(scores[:lax_rows], jnp.int32(15 * C), TOPK),
             ops.select_keep_lax(scores[:lax_rows], jnp.int32(15 * C), TOPK)))
         report["select.kernel_equals_lax"] = same
         print(f"[sweep] the kernel's mask equals the XLA form's: {same}",

@@ -174,7 +174,8 @@ def main(argv=None) -> int:
     scores = jax.random.normal(ks[5], (1024, S), jnp.float32)
     if "select" in parts:
         by = {p: 4 * timed(lambda s: ops.index_select_keep(
-            scores, s, topk=TOPK, rows=ops.SELECT_ROWS, interpret=False),
+            scores, s, topk=TOPK, rows=ops.SELECT_ROWS,
+            tile=ops.select_tile(S), interpret=False),
             jnp.int32(p * C), reps=args.reps) for p in positions}
         say("select.kernel", {"s_a_chunk": by, "s_a_layer": a_layer(by)})
 

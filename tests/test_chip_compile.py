@@ -824,8 +824,11 @@ def test_the_index_selecting_kernels_compile_at_the_served_geometry(chip,
                                                                    kernel):
     """``ops/index_select_attention``'s kernels as ``glm-5``'s prefill
     calls them (PR 51), alone: the scores of 1024 queries × 32 index heads
-    of 128 against 69 632 index keys; the exact top 2048 of 1024 rows, 32
-    rows' float32 scores (8.5 MiB) in VMEM for all the passes; 4096 queries
+    of 128 against 69 632 index keys; the exact top 2048 of 1024 rows, 64
+    rows' float32 scores (17 MiB, twice buffered) and their int32 order
+    image in VMEM for all the passes, searched in the 4096-column tiles a
+    step's rows see (PR 54: a loop whose length the position decides, the
+    tiles at lane offsets the compiler must take as aligned); 4096 queries
     of 8 heads × 256/256 over a decompressed workspace under the byte
     mask; and (PR 52) that workspace's fill: 8 heads' keys and values of
     69 632 latent rows, 1024 rows a step."""
@@ -849,9 +852,11 @@ def test_the_index_selecting_kernels_compile_at_the_served_geometry(chip,
             interpret=False)
         out = f"f32[{n},{S}]"
     elif kernel == "index_select_keep":
+        assert (n, S, ops.SELECT_ROWS, ops.select_tile(S)) \
+            == (1024, 69632, 64, 4096)
         lowered = ops.index_select_keep.lower(
             arg((n, S), jnp.float32), start, topk=cfg.index_topk,
-            rows=ops.SELECT_ROWS, interpret=False)
+            rows=ops.SELECT_ROWS, tile=ops.select_tile(S), interpret=False)
         out = f"s8[{n},{S}]"
     elif kernel == "index_masked_mha":
         lowered = ops.index_masked_mha.lower(
@@ -953,7 +958,7 @@ def test_the_index_selecting_gqa_kernels_compile_at_the_served_geometry(
     elif kernel == "index_select_keep":
         lowered = ops.index_select_keep.lower(
             arg((n, S), jnp.float32), start, topk=cfg.topk,
-            rows=ops.SELECT_ROWS, interpret=False)
+            rows=ops.SELECT_ROWS, tile=ops.select_tile(S), interpret=False)
         out = f"s8[{n},{S}]"
     elif kernel == "index_masked_gqa":
         lowered = gqa_ops.index_masked_gqa.lower(

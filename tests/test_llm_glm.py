@@ -9,6 +9,7 @@ pipeline, the nodes, the shipped graph, and the benchmark's files, counts
 and readers of the cell."""
 
 import dataclasses
+import importlib.util
 import json
 import math
 from pathlib import Path
@@ -143,7 +144,18 @@ def test_a_bfloat16_run_fails_the_float32_tolerance(params, ids, full_logits):
 # --- the selection -------------------------------------------------------------
 
 
+# where a chunk of 16 queries starts in a cache of 512 columns searched in
+# tiles of 128: a step's rows see one tile, three (its rows 250 … 257 cross
+# into the third) or all four
+_CHUNKS = {"first chunk": 0, "middle chunk": 250, "last chunk": 496}
+
+
 def _scores(case: str, n: int = 16, S: int = 48, first: int = 20):
+    """``(scores, the first row's position, the kernel's column tile)``: 48
+    columns are searched whole (no tile divides them)."""
+    tile = S
+    if case in _CHUNKS:
+        S, first, tile = 512, _CHUNKS[case], 128
     s = jax.random.normal(jax.random.key(3), (n, S), jnp.float32)
     if case == "ties":
         s = jnp.round(s * 2) / 2              # a dozen values: many ties
@@ -151,42 +163,135 @@ def _scores(case: str, n: int = 16, S: int = 48, first: int = 20):
         s = jnp.zeros((n, S), jnp.float32)
     elif case == "negative":
         s = -jnp.abs(s) - 1.0
-    return s, first
+    elif case == "short prefix":
+        first = 0                       # rows 0 … 15 see 1 … 16 keys
+    return s, first, tile
 
 
 @pytest.mark.parametrize("kernel", ["lax", "interpret"])
 @pytest.mark.parametrize("topk", [1, 7, 12, 64])
 @pytest.mark.parametrize("case", ["random", "ties", "all equal", "negative",
-                                  "short prefix"])
+                                  "short prefix", *_CHUNKS])
 def test_the_selection_is_lax_top_ks_position_for_position(case, topk,
                                                           kernel):
     """Exactly ``min(topk, t + 1)`` keys a row, ties to the lower position,
-    the whole prefix where it is short — ``lax.top_k``'s set."""
-    scores, first = _scores(case)
-    if case == "short prefix":
-        first = 0                       # rows 0 … 15 see 1 … 16 keys
+    the whole prefix where it is short — ``lax.top_k``'s set, and nothing
+    in ANY column past a row's position: the kernel's 8-row steps search
+    the column tiles their rows see (at the first chunk ``t + 1 = topk``
+    falls inside a step for ``topk`` 7 and 12) and write the rest."""
+    scores, first, tile = _scores(case)
     n, S = scores.shape
     if kernel == "lax":
         keep = ops.select_keep_lax(scores, first, topk)
     else:
         keep = ops.index_select_keep(scores, first, topk=topk, rows=8,
-                                     interpret=True)
+                                     tile=tile, interpret=True)
+        assert np.array_equal(np.asarray(keep), np.asarray(
+            ops.select_keep_lax(scores, first, topk)))
     seen = first + jnp.arange(n)[:, None] >= jnp.arange(S)[None, :]
     want = R.select(jnp.where(seen, scores, -jnp.inf), topk)
+    assert keep.shape == (n, S) and not np.asarray(keep)[~np.asarray(seen)].any()
     assert np.array_equal(np.asarray(keep) != 0, np.asarray(want))
     assert np.array_equal(np.asarray(keep).sum(1),
                           np.minimum(topk, first + np.arange(n) + 1))
 
 
-def test_a_forced_tie_goes_to_the_lower_position():
+@pytest.mark.parametrize("case", ["in one tile", "across a tile boundary",
+                                  "no tile divides the columns"])
+def test_a_forced_tie_goes_to_the_lower_position(case):
     scores = jnp.asarray([[0.5, 2.0, 1.0, 1.0, 1.0, 0.1, 1.0, 3.0]])
-    for kernel in ("lax", "interpret"):
-        keep = ops.select_keep(scores, 7, 4, kernel)
-        assert np.asarray(keep)[0].tolist() == [0, 1, 1, 1, 0, 0, 0, 1]
-    rows, valid = ops.index_step(
-        jnp.ones((1, 1)), jnp.ones((1,)), scores[0][:, None], 7, 4,
-        jnp.float32)
-    assert sorted(np.asarray(rows).tolist()) == [1, 2, 3, 7] and valid.all()
+    if case == "in one tile":
+        for kernel in ("lax", "interpret"):
+            keep = ops.select_keep(scores, 7, 4, kernel)
+            assert np.asarray(keep)[0].tolist() == [0, 1, 1, 1, 0, 0, 0, 1]
+        rows, valid = ops.index_step(
+            jnp.ones((1, 1)), jnp.ones((1,)), scores[0][:, None], 7, 4,
+            jnp.float32)
+        assert sorted(np.asarray(rows).tolist()) == [1, 2, 3, 7] \
+            and valid.all()
+        return
+    if case == "across a tile boundary":
+        # eight rows at 300 …: six keys tie at 1.0 around column 128, the
+        # end of the first tile of three the step sees; two lie above them
+        S, tile, first, tied = 512, 128, 300, [125, 127, 128, 129, 140, 290]
+        scores = jnp.zeros((8, S)).at[:, jnp.asarray(tied)].set(1.0) \
+            .at[:, jnp.asarray([5, 260])].set(2.0)
+        for topk, kept in ((4, [5, 125, 127, 260]),
+                           (5, [5, 125, 127, 128, 260]),
+                           (8, sorted([5, 260, *tied]))):
+            keep = np.asarray(ops.index_select_keep(
+                scores, first, topk=topk, rows=8, tile=tile, interpret=True))
+            assert all(np.flatnonzero(row).tolist() == kept for row in keep)
+            assert np.array_equal(keep, np.asarray(
+                ops.select_keep_lax(scores, first, topk)))
+        return
+    # the served tile where it divides the cache, else the whole width —
+    # through the caller's rule, as the model reaches the kernel
+    assert ops.select_tile(17 * 4096) == ops.SELECT_TILE == 4096
+    assert ops.select_tile(17 * 4096 + 128) == 17 * 4096 + 128
+    scores = jnp.tile(scores, (ops.SELECT_ROWS, 6))              # [32, 48]
+    assert ops.select_tile(scores.shape[1]) == 48
+    keep = ops.select_keep(scores, 9, 4, "interpret")
+    assert np.array_equal(np.asarray(keep),
+                          np.asarray(ops.select_keep_lax(scores, 9, 4)))
+    assert np.asarray(keep)[0].tolist() == [0, 1, 1, 0, 0, 0, 0, 1, 0, 1] \
+        + [0] * 38
+
+
+@pytest.mark.parametrize("model", ["glm-5", "keye-vl-2.0-30b-a3b"])
+def test_over_a_64k_brief_the_selection_searches_half_the_caches_columns(
+        model):
+    """``cdt_llm_select_columns_total``'s arithmetic, by the kernel's own
+    rule: 16 chunks of 4096 over a cache of 17, a 64-row step of chunk
+    ``i`` visits ``i + 1`` tiles of 4096 — 557 056 of 1 114 112 columns a
+    row's walk — and a cache one tile holds is searched whole."""
+    from comfyui_distributed_tpu.models.llm_keye import KeyeConfig
+
+    cfg = G.GlmConfig.glm_share() if model == "glm-5" \
+        else KeyeConfig.keye_share()
+    assert (cfg.prefill_chunk_tokens, cfg.select_rows) == (4096, 1024)
+    steps = 4096 // ops.SELECT_ROWS * cfg.num_hidden_layers
+    assert cfg.select_columns(65536, 128) == {
+        "searched": 557_056 * steps, "cache": 1_114_112 * steps}
+    one = ops.select_columns(65536, 128, 4096, 1024)
+    # a padded last chunk selects too, over a cache of the 16 chunks walked
+    assert ops.select_columns(65536 - 4000, 128, 4096, 1024) == {
+        "searched": one["searched"], "cache": 16 * 4096 * 1024}
+    assert one["searched"] / one["cache"] == 0.5
+    # the visible tiles of every step, one by one, as the kernel counts them
+    tile, S = ops.select_tile(17 * 4096), 17 * 4096
+    assert one["searched"] == sum(
+        -(-(first + ops.SELECT_ROWS) // tile) * tile
+        for first in range(0, 65536, ops.SELECT_ROWS))
+    # 4096 + 128 tokens: one chunk over a cache of two
+    assert ops.select_columns(4096, 128, 4096, 1024) == {
+        "searched": 4096 * 64, "cache": 2 * 4096 * 64}
+    tiny = type(cfg).tiny()
+    assert len(set(tiny.select_columns(40, 8).values())) == 1
+
+
+@pytest.mark.parametrize("form", ["copied tiles", "whole rows"])
+@pytest.mark.parametrize("case", list(_CHUNKS))
+def test_the_sweeps_forms_that_did_not_ship_select_the_same_keys(case, form):
+    """``scripts/index_select_sweep.py`` times the shipped kernel against
+    the scores left in HBM (a step copies in the tiles its rows see) and
+    against PR 51's whole rows: both are the plain form's mask bit for bit,
+    or the comparison is of different work."""
+    spec = importlib.util.spec_from_file_location(
+        "index_select_sweep", Path(__file__).resolve().parent.parent
+        / "scripts" / "index_select_sweep.py")
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    scores, first, tile = _scores(case)
+    for topk in (7, 64):
+        if form == "copied tiles":
+            keep = sweep.select_copied_tiles(scores, first, topk, 8, tile,
+                                             interpret=True)
+        else:
+            keep = sweep.select_whole_rows(scores, first, topk, 8,
+                                           interpret=True)
+        assert np.array_equal(np.asarray(keep), np.asarray(
+            ops.select_keep_lax(scores, first, topk)))
 
 
 def test_order_key_orders_as_the_floats_do():
@@ -496,6 +601,8 @@ def test_the_shipped_graph_runs_and_the_counters_move_as_stated(tmp_path):
             "keys": {p: tm.LLM_ATTN_KEYS.labels(layers="sparse",
                                                 phase=p).value
                      for p in ("prefill", "decode")},
+            "columns": {k: tm.LLM_SELECT_COLUMNS.labels(kind=k).value
+                        for k in ("searched", "cache")},
             "chunks": tm.LLM_PREFILL_CHUNKS.labels().value}
 
     before = read()
@@ -515,6 +622,11 @@ def test_the_shipped_graph_runs_and_the_counters_move_as_stated(tmp_path):
         for phase in ("prefill", "decode"):
             assert after["keys"][phase] - before["keys"][phase] \
                 == 3 * want[("sparse", phase)]
+        # three chunks of 16 in calls of 8 rows over 48 columns, 5 layers:
+        # the tiny preset's one tile is its whole cache
+        for kind in ("searched", "cache"):
+            assert after["columns"][kind] - before["columns"][kind] \
+                == 3 * 5 * 6 * 48 == 3 * CFG.select_columns(40, 8)[kind]
         assert tm.LLM_CACHE_POSITIONS.labels().value == 48
         assert tm.LLM_CACHE_BYTES.labels(layers="latent").value \
             == 5 * 48 * 24 * 4
