@@ -1056,88 +1056,6 @@ def run_wan22_benchmark(steps: int, runs: int | None) -> dict:
     return _run_wan_like(steps, runs, moe=True)
 
 
-def run_attn_benchmark(steps: int, runs: int | None) -> dict:
-    """Per-geometry attention A/B from the tuning table (ISSUE 8): for every
-    entry in the effective table (shipped model-zoo layer + any local
-    sweeps) time each legal (tier, blocks) candidate on the live
-    accelerator and report the table's choice against the measured best
-    — the evidence that the shipped bake still matches this hardware
-    generation.
-
-    On CPU (no accelerator) timing is meaningless; instead the run
-    verifies the decision chain end to end — every table entry passes
-    the legality validator and the dry-policy sweep reproduces the shipped
-    choice — and says so explicitly (``platform: cpu``, ``ab_mode: decisions``)
-    so a toy line can't be mistaken for hardware numbers."""
-    import jax
-
-    from comfyui_distributed_tpu.ops import autotune
-
-    platform = jax.devices()[0].platform
-    on_tpu = platform == "tpu"
-    # shipped model-zoo layer + any local sweeps (reads never raise —
-    # a missing/corrupt local file degrades to the shipped layer)
-    table = autotune.default_table()
-    geometries = table.entries()
-    per_geometry = []
-    agreements = 0
-    for key, choice in geometries.items():
-        rec: dict = {"geometry": key.key_str(),
-                     "table": choice.to_dict()}
-        errors = autotune.validate_entry(key, choice)
-        if errors:
-            rec["legality_errors"] = errors
-        if on_tpu:
-            timings = []
-            for cand in autotune.candidates_for(key):
-                try:
-                    us = autotune._time_candidate(
-                        key, cand, runs=int(runs or 3)) * 1e6
-                    timings.append(
-                        {"tier": cand.tier, "block_q": cand.block_q,
-                         "block_k": cand.block_k, "us": round(us, 1)})
-                except Exception as e:  # noqa: BLE001 — candidate isolation
-                    timings.append({"tier": cand.tier,
-                                    "block_q": cand.block_q,
-                                    "block_k": cand.block_k,
-                                    "error": str(e)[:200]})
-            ok = [t for t in timings if "us" in t]
-            if ok:
-                best = min(ok, key=lambda t: t["us"])
-                rec["measured_best"] = best
-                rec["table_matches_best"] = (
-                    best["tier"] == choice.tier
-                    and best.get("block_q") == choice.block_q
-                    and best.get("block_k") == choice.block_k)
-                agreements += bool(rec["table_matches_best"])
-            rec["candidates"] = timings
-        else:
-            dry = autotune.sweep_geometry(key, mode="dry")
-            rec["dry_policy"] = (dry.choice.to_dict()
-                                 if dry.choice else None)
-            rec["table_matches_policy"] = (
-                dry.choice is not None
-                and dry.choice.tier == choice.tier
-                and dry.choice.block_q == choice.block_q
-                and dry.choice.block_k == choice.block_k)
-            agreements += bool(rec["table_matches_policy"])
-        per_geometry.append(rec)
-
-    return {
-        "metric": ("attn_ab_table_agreement" if on_tpu
-                   else "attn_ab_decisions_cpu"),
-        "value": round(agreements / max(len(per_geometry), 1), 4),
-        "unit": "fraction",
-        "vs_baseline": 1.0,
-        "vs_baseline_note": "no published attention A/B baseline",
-        "platform": platform,
-        "device_kind": getattr(jax.devices()[0], "device_kind", platform),
-        "ab_mode": "timed" if on_tpu else "decisions",
-        "geometries": len(per_geometry),
-        "per_geometry": per_geometry,
-    }
-
-
 def run_serving_benchmark(steps: int, runs: int | None) -> dict:
     """Serving front door A/B (ISSUE 9, docs/serving.md): the same R
     requests executed (a) sequentially as R solo programs and (b) as one
@@ -2252,7 +2170,6 @@ _WORKLOADS = {
     "wan": run_wan_benchmark,
     "wan14b": run_wan14b_benchmark,
     "wan22": run_wan22_benchmark,
-    "attn": run_attn_benchmark,
     "serving": run_serving_benchmark,
     "elastic": run_elastic_benchmark,
     "caching": run_caching_benchmark,
@@ -2280,7 +2197,7 @@ def main() -> None:
     parser.add_argument("--runs", type=int, default=None)
     parser.add_argument("--workload",
                         choices=["txt2img", "usdu", "flux", "wan",
-                                 "wan14b", "wan22", "attn", "serving",
+                                 "wan14b", "wan22", "serving",
                                  "elastic", "caching", "stages"],
                         default="txt2img",
                         help="txt2img (SDXL images/sec), usdu (4K upscale "
@@ -2288,8 +2205,7 @@ def main() -> None:
                              "(t2v wall-clock), wan14b (14B t2v via the "
                              "quantized offload executor), wan22 "
                              "(dual-expert MoE t2v, same geometry as "
-                             "wan), attn (per-geometry attention A/B "
-                             "from the tuning table), serving (front-door "
+                             "wan), serving (front-door "
                              "microbatch vs sequential + offered-load "
                              "latency, docs/serving.md), elastic "
                              "(scale-event overhead + steal pickup "

@@ -278,7 +278,7 @@ def series(metrics: dict, name: str) -> list[dict]:
     return metrics.get(name, {}).get("series", [])
 
 
-def report(server: Server, device: dict, table: dict) -> None:
+def report(server: Server, device: dict) -> None:
     """Print the server's own account of the run from
     ``/distributed/metrics.json`` and ``/distributed/memory_stats``, and
     fail where it contradicts what the run was meant to prove."""
@@ -301,14 +301,14 @@ def report(server: Server, device: dict, table: dict) -> None:
     say("attention kernels: " + (", ".join(
         f"{g}={t}" for g, t in sorted(selected.items())) or "none recorded"))
     if device["platform"] == "tpu":
-        # served by the tier the table names; `xla` (or nothing) there
+        # both of SDXL's self-attention sites stand at or past the packed
+        # floors (ops/attention.policy_choice); `xla` (or nothing) there
         # means the dispatcher fell back
         for geometry in SDXL_SELF_ATTENTION:
-            tier = table[geometry]["tier"]
-            if tier == "xla" or selected.get(geometry) != tier:
+            if selected.get(geometry) != "packed":
                 raise SmokeFailure(
-                    f"attention site {geometry}: the table says {tier}, the "
-                    f"server served it with {selected.get(geometry)!r}")
+                    f"attention site {geometry}: the policy answers packed, "
+                    f"the server served it with {selected.get(geometry)!r}")
 
     first = {s["labels"]["pipeline"]: s for s in
              series(metrics, "cdt_pipeline_compile_seconds")}
@@ -331,11 +331,6 @@ def report(server: Server, device: dict, table: dict) -> None:
     if "Traceback (most recent call last)" in log_text:
         raise SmokeFailure("an exception in the server log:\n"
                            + server.log_tail(40))
-
-
-def shipped_table() -> dict:
-    return json.loads((ROOT / "comfyui_distributed_tpu" / "ops"
-                       / "attn_table_default.json").read_text())["entries"]
 
 
 def verdict(device: dict | None, failure: str | None) -> tuple[str, int]:
@@ -364,7 +359,7 @@ def smoke_one_chip(workflow: dict | None = None, cpu_rehearsal: bool = False,
                                  out_dir=out_dir)
             say("request wall times (s): "
                 + ", ".join(f"{t:.2f}" for t in times))
-            report(server, device, shipped_table())
+            report(server, device)
     except SmokeFailure as e:
         failure = str(e)
         print(f"[chip_smoke] FAILED: {failure}", file=sys.stderr, flush=True)

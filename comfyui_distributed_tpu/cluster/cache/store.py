@@ -5,8 +5,8 @@ policy mirrors ``cluster/residency.ResidencyPlanner`` — least-recently-
 used eviction under a byte budget, pinned entries untouchable — applied
 to named numpy-array bundles instead of model bundles.
 
-Persistence follows the ``utils/jsonio`` contract the shape catalog and
-autotune table established, extended with a binary sidecar per entry:
+Persistence follows the ``utils/jsonio`` contract the shape catalog
+established, extended with a binary sidecar per entry:
 
 - the **index** (``<tier>_index.json``) is read-merge-atomic-written, so
   concurrent writers (serving master, bench, a second controller against

@@ -1098,7 +1098,7 @@ class Txt2ImgPipeline:
 
         def run(weights, seeds, contexts, uncond_contexts, ys, uys):
             # traced inside the tp scope so every attention site resolves
-            # its PER-SHARD (H/tp) kernel choice from the tuning table
+            # its PER-SHARD (H/tp) kernel choice (ops/attention.py)
             with tp_shard_scope(tp):
                 def per_dp(i):
                     outs = []

@@ -46,15 +46,10 @@ class WarmupEntry:
     outcome: str          # cache_hit | compiled | error | skipped
     seconds: float = 0.0
     detail: str = ""
-    # attention geometries this program serves (ops/autotune.py keys) —
-    # fed to the autotune stage so the worker stays `warming` until its
-    # catalog geometries are tuned
-    geometries: list = dataclasses.field(default_factory=list)
 
     def to_dict(self) -> dict:
         return {"program": self.key.to_dict(), "outcome": self.outcome,
-                "seconds": round(self.seconds, 3), "detail": self.detail,
-                "geometries": [g.key_str() for g in self.geometries]}
+                "seconds": round(self.seconds, 3), "detail": self.detail}
 
 
 def _cache_artifacts(cache_dir: Optional[str]) -> set:
@@ -283,9 +278,8 @@ def mesh_tier_keys(keys: Iterable[ProgramKey], mesh) -> list[ProgramKey]:
 
 def run_warmup(registry, mesh, keys: Iterable[ProgramKey],
                models: Optional[Iterable[str]] = None,
-               on_entry: Optional[Callable[[WarmupEntry], None]] = None,
-               tune: bool = True,
-               tune_report: Optional[list] = None) -> list[WarmupEntry]:
+               on_entry: Optional[Callable[[WarmupEntry], None]] = None
+               ) -> list[WarmupEntry]:
     """Warm every catalog program buildable on this host.
 
     ``models`` (or ``CDT_WARMUP_MODELS``) filters which model bundles are
@@ -299,16 +293,10 @@ def run_warmup(registry, mesh, keys: Iterable[ProgramKey],
     Per-entry failures are recorded, never raised: one bad catalog row
     must not leave the worker cold for the rest.
 
-    Two phases with the attention autotune stage BETWEEN them: phase A
-    builds the bundles and derives each program's attention geometries;
-    ``autotune.ensure_tuned`` then sweeps any untuned geometry (appended
-    to ``tune_report``); phase B AOT-compiles. The order matters — the
-    kernel choice is baked into the traced HLO at lower time, so tuning
-    after compilation would warm programs carrying pre-sweep kernel
-    choices and invalidate the cache on the next trace. ``tune=False``
-    (or ``CDT_ATTN_TUNE=0``) skips the stage.
+    One pass, a program at a time (:func:`_warm_one`): nothing stands
+    between building a bundle and compiling its program since the
+    attention tuning stage went (PR 55).
     """
-    from ..ops import autotune
     from ..telemetry import enabled as _tm_enabled
     from ..telemetry import metrics as _tm
     from ..utils.compile_cache import active_cache_dir
@@ -330,66 +318,9 @@ def run_warmup(registry, mesh, keys: Iterable[ProgramKey],
             "everything in the catalog")
     cache_dir = active_cache_dir()
 
-    # --- phase A: build bundles, derive geometries ------------------------
-    plan: list = []   # (key, bundle | None, pre-resolved entry | None)
-    geometries: set = set()
-    for key in keys:
-        if (allowed is not None and key.model not in allowed) \
-                or not _mesh_matches(key, mesh):
-            plan.append((key, None,
-                         WarmupEntry(key, "skipped",
-                                     detail="model filtered or mesh "
-                                            "mismatch")))
-            continue
-        t0 = time.perf_counter()
-        try:
-            # bundle build happens OUTSIDE the classification window:
-            # its own init compiles (VAE/text) would otherwise write
-            # cache artifacts and mislabel a disk-served target program
-            # "compiled"
-            bundle = registry.get(key.model)
-        except Exception as e:  # noqa: BLE001 — per-entry isolation
-            plan.append((key, None,
-                         WarmupEntry(key, "error",
-                                     time.perf_counter() - t0,
-                                     detail=str(e))))
-            debug_log(f"warmup: {key} failed: {e}")
-            continue
-        entry = WarmupEntry(key, "pending")
-        try:
-            entry.geometries = autotune.geometries_for_program(bundle, key)
-            geometries.update(entry.geometries)
-        except Exception as e:  # noqa: BLE001 — advisory
-            debug_log(f"warmup: geometry derivation for {key} failed: {e}")
-        plan.append((key, bundle, entry))
-
-    # --- autotune stage: BEFORE compilation, so the kernel choices the
-    # traces bake in are the tuned ones ------------------------------------
-    if tune and geometries and autotune.tuning_enabled():
-        swept = autotune.ensure_tuned(sorted(geometries))
-        if tune_report is not None:
-            tune_report.extend(swept)
-
-    # --- phase B: AOT lower + compile -------------------------------------
     report: list[WarmupEntry] = []
-    for key, bundle, entry in plan:
-        if bundle is not None:
-            try:
-                before = _cache_artifacts(cache_dir)
-                t0 = time.perf_counter()
-                lower_program(bundle, key, mesh)
-                entry.seconds = time.perf_counter() - t0
-                wrote = bool(_cache_artifacts(cache_dir) - before)
-                # new cache artifacts ⇒ XLA actually compiled; none (with
-                # a cache active) ⇒ the executable was deserialized from
-                # disk — the warm-restart fast path this pass exists for
-                entry.outcome = ("compiled" if wrote or not cache_dir
-                                 else "cache_hit")
-            except Exception as e:  # noqa: BLE001 — per-entry isolation
-                entry.outcome = "error"
-                entry.seconds = time.perf_counter() - t0
-                entry.detail = str(e)
-                debug_log(f"warmup: {key} failed: {e}")
+    for key in keys:
+        entry = _warm_one(registry, mesh, key, allowed, cache_dir)
         report.append(entry)
         if _tm_enabled():
             _tm.WARMUP_PROGRAMS.labels(outcome=entry.outcome).inc()
@@ -398,6 +329,37 @@ def run_warmup(registry, mesh, keys: Iterable[ProgramKey],
         if on_entry is not None:
             on_entry(entry)
     return report
+
+
+def _warm_one(registry, mesh, key: ProgramKey, allowed: Optional[set],
+              cache_dir: Optional[str]) -> WarmupEntry:
+    """Build one catalog program's bundle, AOT-lower and compile it, and
+    classify the outcome; a failure is the entry's, never raised."""
+    if (allowed is not None and key.model not in allowed) \
+            or not _mesh_matches(key, mesh):
+        return WarmupEntry(key, "skipped",
+                           detail="model filtered or mesh mismatch")
+    t0 = time.perf_counter()
+    try:
+        # bundle build happens OUTSIDE the classification window: its own
+        # init compiles (VAE/text) would otherwise write cache artifacts
+        # and mislabel a disk-served target program "compiled"
+        bundle = registry.get(key.model)
+        before = _cache_artifacts(cache_dir)
+        t0 = time.perf_counter()
+        lower_program(bundle, key, mesh)
+        seconds = time.perf_counter() - t0
+        wrote = bool(_cache_artifacts(cache_dir) - before)
+        # new cache artifacts ⇒ XLA actually compiled; none (with a cache
+        # active) ⇒ the executable was deserialized from disk — the
+        # warm-restart fast path this pass exists for
+        return WarmupEntry(
+            key, "compiled" if wrote or not cache_dir else "cache_hit",
+            seconds)
+    except Exception as e:  # noqa: BLE001 — per-entry isolation
+        debug_log(f"warmup: {key} failed: {e}")
+        return WarmupEntry(key, "error", time.perf_counter() - t0,
+                           detail=str(e))
 
 
 class WarmupManager:
@@ -418,7 +380,6 @@ class WarmupManager:
         self._state = COLD
         self._lock = threading.Lock()
         self._report: list[WarmupEntry] = []
-        self._autotune_report: list = []
         self._started_at: Optional[float] = None
         self._finished_at: Optional[float] = None
 
@@ -479,29 +440,18 @@ class WarmupManager:
             keys += tier
             log(f"warmup: starting pass over {len(keys)} catalog "
                 f"program(s) ({len(tier)} mesh-tier)")
-            # the autotune stage runs INSIDE run_warmup, between bundle
-            # build and AOT compile — the worker stays `warming` until
-            # every attention geometry its catalog programs serve has a
-            # tuned kernel config, and the compiled programs bake those
-            # tuned choices into their traces
-            self._autotune_report = []
             self._report = run_warmup(self._registry_fn(), self._mesh_fn(),
-                                      keys, models=models,
-                                      tune_report=self._autotune_report)
+                                      keys, models=models)
             cat.save()
             self._finished_at = time.monotonic()
             self._set_state(READY)
             hits = sum(e.outcome == "cache_hit" for e in self._report)
             comp = sum(e.outcome == "compiled" for e in self._report)
             errs = sum(e.outcome == "error" for e in self._report)
-            swept = sum(e.outcome in ("swept", "dry")
-                        for e in self._autotune_report)
             log(f"warmup: ready — {hits} cache hit(s), {comp} compiled, "
                 f"{errs} error(s), "
                 f"{sum(e.outcome == 'skipped' for e in self._report)} "
-                f"skipped; autotune: {swept} swept, "
-                f"{sum(e.outcome == 'cached' for e in self._autotune_report)}"
-                f" cached in "
+                f"skipped in "
                 f"{self._finished_at - self._started_at:.1f}s")
         except Exception as e:  # noqa: BLE001 — boot must survive warmup
             self._finished_at = time.monotonic()
@@ -518,9 +468,6 @@ class WarmupManager:
         counts: dict[str, int] = {}
         for e in self._report:
             counts[e.outcome] = counts.get(e.outcome, 0) + 1
-        tune_counts: dict[str, int] = {}
-        for e in self._autotune_report:
-            tune_counts[e.outcome] = tune_counts.get(e.outcome, 0) + 1
         return {
             "state": self._state,
             "catalog_size": (len(self._catalog)
@@ -528,8 +475,4 @@ class WarmupManager:
             "outcomes": counts,
             "seconds": None if took is None else round(took, 3),
             "report": [e.to_dict() for e in self._report],
-            "autotune": {
-                "outcomes": tune_counts,
-                "report": [e.to_dict() for e in self._autotune_report],
-            },
         }

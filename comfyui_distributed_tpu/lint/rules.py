@@ -3,7 +3,7 @@
 =====  =====================================================================
 L001   lock-discipline: mutation of a lock-guarded shared-registry attribute
        outside a ``with self._lock`` block (BREAKERS, DRAIN, CacheTier,
-       TuningTable, ShapeCatalog, ResidencyPlanner, telemetry registry, ...).
+       ShapeCatalog, ResidencyPlanner, telemetry registry, ...).
 A001   async-hygiene: blocking calls (``time.sleep``, sync file I/O,
        ``subprocess``, ``fcntl``, ``Future.result()``) directly in an
        ``async def`` body without executor offload.

@@ -105,8 +105,8 @@ class ResBlock(nn.Module):
 class Attention(nn.Module):
     """Multi-head attention over [B, N, C] with optional cross context.
 
-    The kernel dispatcher (``ops/attention.select_kernel`` — a
-    tuning-table row, else the one policy) is asked once a site; then
+    The kernel dispatcher (``ops/attention.select_kernel`` — the one
+    policy, nothing ahead of it) is asked once a site; then
     plain ``nn.Dense`` projections and ``full_attention`` with the choice
     (the packed Pallas kernel at SDXL's 64² and 32² self-attention, XLA
     at its cross-attention)."""

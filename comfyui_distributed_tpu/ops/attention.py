@@ -28,6 +28,8 @@ from jax.lax import axis_size as _axis_size
 
 from ..telemetry.device_scopes import device_scope, device_scoped
 from ..utils import constants
+from .flash_attention import _on_tpu, _packed_blocks, _packed_legal
+from .kernel_choice import GeometryKey, KernelChoice, itemsize_of
 
 # the attention core's device scope round a whole dispatcher: the kernel
 # call with its pads, transposes and casts is cdt.attn_core wherever it is
@@ -63,8 +65,8 @@ _TP_SHARDS: _contextvars.ContextVar = _contextvars.ContextVar(
 @_contextlib.contextmanager
 def tp_shard_scope(tp: int):
     """Trace-scope marker: attention sites traced inside this scope
-    resolve their tuning-table entry by PER-SHARD geometry (heads/tp).
-    No-op for tp <= 1."""
+    resolve their kernel by PER-SHARD geometry (heads/tp). No-op for
+    tp <= 1."""
     token = _TP_SHARDS.set(max(int(tp), 1))
     try:
         yield
@@ -135,8 +137,6 @@ def note_causal(tier: str, num_heads: int, head_dim: int, q_len: int,
     (``latent_attention.mla_chunk_attention``,
     ``gqa_attention.causal_chunk``) names the tier it ran
     (:data:`CAUSAL_TIER_REASONS`) with the blocks it was served."""
-    from .autotune import GeometryKey, KernelChoice
-
     _note_selection(
         GeometryKey.from_shape(num_heads, head_dim, q_len, kv_len,
                                dtype).key_str(),
@@ -146,19 +146,13 @@ def note_causal(tier: str, num_heads: int, head_dim: int, q_len: int,
 
 def _with_packed_blocks(choice, q_len: int, kv_len: int, head_dim: int,
                         dtype):
-    """A packed choice with the blocks its call will run: what the table
-    row requested, the rest derived from the shape
-    (``flash_attention._packed_blocks``) — resolved here so that the
-    selection log and counter show them, and handed to the call so that
-    it cannot resolve others."""
+    """A packed choice with the blocks its call will run, derived from
+    the shape (``flash_attention._packed_blocks``) — resolved here so that
+    the selection log and counter show them, and handed to the call so
+    that it cannot resolve others."""
     if choice.tier != "packed":
         return choice
-    from .autotune import itemsize_of
-    from .flash_attention import _check_blocks, _packed_blocks
-
-    _check_blocks(choice.block_q, choice.block_k)
-    bq, bk = _packed_blocks(q_len, kv_len, head_dim, itemsize_of(dtype),
-                            choice.block_q, choice.block_k)
+    bq, bk = _packed_blocks(q_len, kv_len, head_dim, itemsize_of(dtype))
     return _dataclasses.replace(choice, block_q=bq, block_k=bk)
 
 
@@ -174,36 +168,41 @@ def reset_selections() -> None:
         _SELECTIONS.clear()
 
 
-# Engagement floors of the pallas tiers, measured r04 on the v5e
-# (`scripts/mfu_probe.py`, docs/roofline.md finding 1a). The packed
-# layout beats XLA's fused attention from SDXL's self-attention lengths
-# up (q >= 1024) but not with a tiny K: at SDXL cross-attention (K = 77
-# text tokens in one mostly-padding tile) it measured behind XLA (1.20 vs
-# 1.04 ms/64-op chain). The classic pre-transposed ([B·H,N,D]) call LOSES
+# Engagement floors of the pallas tiers, measured on the v5e. The packed
+# layout beats XLA's fused attention from q = 832 up but not below: at
+# SDXL's 32² level (B 2, H 20, D 64, bf16; PR 55's chip reading, PERF.md
+# §6) XLA's lowering falls off a cliff between 800 and 832 tokens — the
+# core alone 130 µs at 800, 368 µs at 832, 517 µs at 1024, against packed
+# 157 / 163 / 188 µs; the whole site (projections + core) 1.9–2.1× behind
+# packed at every length measured from 832 to 1024 (832, 864, 896, 936,
+# 960, 988, 1008, 1024: SDXL's non-square ~1 MP sizes stand at 988–1008).
+# Below the cliff XLA wins the core (576: 74 vs 99 µs, 768: 100 vs 126 µs)
+# and ties the site; at 800 the two readings disagree (core 1.21× behind,
+# site 0.86×), so the floor is the first length where both agree. Nor does
+# packed win with a tiny K (r04, `scripts/mfu_probe.py`, docs/roofline.md
+# finding 1a): at SDXL cross-attention (K = 77 text tokens in one
+# mostly-padding tile) it measured behind XLA (1.20 vs 1.04 ms/64-op
+# chain). The classic pre-transposed ([B·H,N,D]) call LOSES
 # to XLA at SDXL lengths (flash-bh 0.1763 s/fwd vs XLA 0.1677 at 1024²;
 # the trace shows the boundary relayout, not the kernel body, as the
 # cost): at N <= a few K the O(N²) score matrix fits HBM comfortably and
 # XLA fuses softmax into the matmuls, so its win is memory at long N
 # (ring/SP sequences, video token counts).
-PACKED_MIN_Q = 1024
+PACKED_MIN_Q = 832
 PACKED_MIN_KV = 256
 BH_MIN_Q = 8192
 
 
 def policy_choice(q_len: int, kv_len: int, num_heads: int, head_dim: int,
                   flash_only: bool = False):
-    """The one rule for a geometry no table row decides — what
-    ``select_kernel`` falls back to and what a dry bake of the tuning
-    table writes (``autotune.sweep_geometry``): packed where the layout
-    is legal and both of its floors hold; else the classic ``bh`` call
+    """The one rule, for every geometry, asked with the EXACT lengths
+    the site runs: packed where the layout is legal and both of its
+    floors hold; else the classic ``bh`` call
     from ``BH_MIN_Q`` up, where the streamed softmax's memory win still
     applies (packed-illegal widths, or a long q over a tiny K); else XLA.
     ``flash_only`` takes the XLA outcome away (a caller that was
     promised flash): ``bh`` at any length. Blocks are left to the shape
     (``_with_packed_blocks``) or the classic 256/512."""
-    from .autotune import KernelChoice
-    from .flash_attention import _packed_legal
-
     if (_packed_legal(num_heads, head_dim) and q_len >= PACKED_MIN_Q
             and kv_len >= PACKED_MIN_KV):
         return KernelChoice(
@@ -224,32 +223,29 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
                   dtype="bfloat16", prefer_flash: bool = False,
                   segments=None):
     """Resolve the kernel tier + block config for one attention site. The
-    ONLY code that chooses; nothing downstream decides again. In order:
+    ONLY code that chooses; nothing downstream decides again, and nothing
+    — no file, no table, no other variable — can outrank it. In order:
 
     1. ``CDT_FLASH_ATTENTION=0`` → ``xla`` (the operator's way out);
     2. not on a TPU and not forced (``=1``) → ``xla``;
-    3. the tuning table's row for the geometry (``ops/autotune.py``),
-       else the one policy (:func:`policy_choice`);
+    3. the one policy (:func:`policy_choice`) at the site's exact lengths;
     4. an ``xla`` answer under ``prefer_flash`` or ``=1`` becomes the
        policy's flash answer.
 
-    Packed blocks are resolved here, once, from the row and the shape, so
-    the log, the counter and the call agree (``segments``: a joint site's
-    text and image rows, for its label). Same site + table ⇒ same choice.
+    Packed blocks are resolved here, once, from the shape, so the log,
+    the counter and the call agree (``segments``: a joint site's text and
+    image rows, for its label). Same site ⇒ same choice.
 
     ``prefer_flash`` (memory-constrained callers, see ``full_attention``)
-    outranks a table ``xla`` row: the sweep optimized for time while the
+    outranks the policy's ``xla``: the floors optimize for time while the
     caller needs the streamed softmax to fit HBM.
 
     Mesh-aware: inside a :func:`tp_shard_scope` the head count is
-    divided by the tp degree BEFORE key derivation — the per-shard
-    geometry (H/tp heads) is what actually executes, and a full-H table
-    entry can carry blocks that are illegal (or slow) at H/tp."""
-    from .autotune import GeometryKey, KernelChoice, lookup
-    from .flash_attention import _on_tpu
-
-    # ONE definition of the per-shard rule (GeometryKey.shard): sweeps,
-    # table keys and this dispatch must never disagree about it
+    divided by the tp degree BEFORE the policy is asked — the per-shard
+    geometry (H/tp heads) is what actually executes, and packed legality
+    at the full H says nothing of H/tp."""
+    # ONE definition of the per-shard rule (GeometryKey.shard): the label
+    # and the policy's head count must never disagree about it
     gkey = GeometryKey.from_shape(num_heads, head_dim, q_len, kv_len,
                                   dtype).shard(current_tp_shards())
     num_heads = gkey.num_heads
@@ -267,8 +263,7 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
         # hosts would flood the selection log with xla lines
         return KernelChoice("xla", reason="not on TPU")
 
-    choice = (lookup(num_heads, head_dim, q_len, kv_len, dtype)
-              or policy_choice(q_len, kv_len, num_heads, head_dim))
+    choice = policy_choice(q_len, kv_len, num_heads, head_dim)
     if choice.tier == "xla" and (forced or prefer_flash):
         choice = _dataclasses.replace(
             policy_choice(q_len, kv_len, num_heads, head_dim,
@@ -285,12 +280,12 @@ def select_kernel(q_len: int, kv_len: int, num_heads: int, head_dim: int,
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    prefer_flash: bool = False, choice=None) -> jax.Array:
     """Dense [B,N,H,D] attention by the kernel ``select_kernel`` picks
-    for the geometry (a tuning-table row, else the one policy; XLA
-    off-TPU). A caller that has already asked (``models/layers.py``)
-    hands its ``choice`` in and is not asked about again.
+    for the geometry (the one policy; XLA off-TPU). A caller that has
+    already asked (``models/layers.py``) hands its ``choice`` in and is
+    not asked about again.
 
-    ``prefer_flash=True`` outranks the policy's floors AND table ``xla``
-    rows (still TPU-only, still overridable by an explicit
+    ``prefer_flash=True`` outranks the policy's floors (still TPU-only,
+    still overridable by an explicit
     ``CDT_FLASH_ATTENTION``): set by memory-constrained callers — the
     fp8-resident offload executor's block programs OOM'd at compile with
     XLA attention (measured r04: 16.89 GB needed vs 15.75 HBM at FLUX's
@@ -593,7 +588,6 @@ def _joint_plan(choice, segments, head_dim: int, dtype):
     geometry is one ``flash_joint.joint_plan`` serves. Else None."""
     if segments is None or choice.tier != "packed":
         return None
-    from .autotune import itemsize_of
     from .flash_joint import joint_plan
 
     txt_len, img_len = segments

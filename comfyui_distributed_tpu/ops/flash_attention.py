@@ -74,7 +74,7 @@ def _check_block(name: str, value: int, multiple: int) -> None:
 
 
 def _check_blocks(block_q: Optional[int], block_k: Optional[int]) -> None:
-    """Validate requested blocks (an argument, a tuning-table row; None
+    """Validate requested blocks (a caller's arguments; None
     = not requested, the tier applies its own): a non-positive or
     non-(8,128)-divisible value raises a descriptive ``ValueError``
     instead of letting pallas fail deep in lowering."""
@@ -567,8 +567,8 @@ def _packed_vmem_bytes(head_dim: int, block_q: int, block_k: int,
 def _packed_blocks(q_len: int, kv_len: int, head_dim: int, itemsize: int = 2,
                    block_q: Optional[int] = None,
                    block_k: Optional[int] = None) -> tuple[int, int]:
-    """(block_q, block_k) of the packed call. Requested blocks (an
-    argument, a tuning-table row) keep their meaning — rows of one q
+    """(block_q, block_k) of the packed call. Requested blocks (a
+    caller's arguments) keep their meaning — rows of one q
     tile and of one K/V tile — and win; what is not requested comes from
     the shape:
 

@@ -173,7 +173,7 @@ LLM_ATTN_KEYS = REGISTRY.counter(
     "kinds never moves it.",
     ("layers", "phase"))
 
-# --- attention kernel dispatch / autotune (ops/attention.py, ops/autotune.py)
+# --- attention kernel dispatch (ops/attention.py, ops/kernel_choice.py)
 
 ATTN_KERNEL_SELECTED = REGISTRY.counter(
     "cdt_attn_kernel_selected",
@@ -188,13 +188,6 @@ ATTN_KERNEL_SELECTED = REGISTRY.counter(
     "traced program per geometry; the dispatch decision is observable "
     "without a profiler.",
     ("tier", "geometry", "blocks"))
-
-AUTOTUNE_SWEEP_SECONDS = REGISTRY.histogram(
-    "cdt_autotune_sweep_seconds",
-    "Wall-clock of one attention autotune sweep (all candidates for one "
-    "geometry). Runs off the request path — during warmup or the "
-    "autotune_sweep.py CLI.",
-    buckets=COMPILE_BUCKETS)
 
 # --- tile farm --------------------------------------------------------------
 

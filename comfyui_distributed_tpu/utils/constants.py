@@ -817,11 +817,11 @@ WARMUP_MODELS = knob_str(
     "Comma list of models to warm ('all'/'*' = the full workflow "
     "catalog; default: loaded + tiny presets).", doc="docs/deployment.md")
 
-# --- attention kernels / autotuner (PR 5, docs/kernels.md) ------------------
+# --- attention kernels (PR 5, docs/kernels.md) ------------------------------
 FLASH_ATTENTION = knob_optbool(
     "CDT_FLASH_ATTENTION", "kernels",
-    "Force the flash path on (1) or off (0); unset = table row, else the "
-    "one policy.",
+    "Force the flash path on (1) or off (0); unset = the one policy "
+    "(packed from its floors up on a TPU, XLA elsewhere).",
     doc="docs/kernels.md", on_garbage="default")
 # Hot-loop gate knob: warn-and-default on garbage is a TESTED contract
 # (an env typo must not crash the attention dispatch mid-job).
@@ -829,14 +829,6 @@ RING_BLOCK = knob_int(
     "CDT_RING_BLOCK", 1024, "kernels",
     "Ring-attention block size for the sp axis.",
     doc="docs/kernels.md", on_garbage="default")
-ATTN_TABLE = knob_str(
-    "CDT_ATTN_TABLE", None, "kernels",
-    "Local tuning-table overlay path (default: next to the XLA cache).",
-    doc="docs/kernels.md")
-ATTN_TUNE = knob_bool(
-    "CDT_ATTN_TUNE", True, "kernels",
-    "Sweep untuned geometries inside the warmup window.",
-    doc="docs/kernels.md")
 
 # --- HBM residency / offload (cluster/residency.py, diffusion/offload.py) ---
 HBM_BUDGET_GB = knob_float(

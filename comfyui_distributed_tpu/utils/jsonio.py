@@ -1,9 +1,9 @@
 """Atomic JSON persistence shared by the merge-on-save registries.
 
-Two artifacts persist next to the XLA compilation cache and are written
-by multiple processes (serving master, warmup CLI, autotune sweeps): the
-shape catalog (``cluster/shape_catalog.py``) and the attention tuning
-table (``ops/autotune.py``). Both follow the same contract:
+Artifacts that persist next to the XLA compilation cache and are written
+by multiple processes (serving master, warmup CLI, a second controller):
+the shape catalog (``cluster/shape_catalog.py``) and the content cache's
+index (``cluster/cache/store.py``). Both follow the same contract:
 
 - **reads never crash**: a missing, unreadable, or garbled file degrades
   to "no data" (the caller logs at debug level and starts empty);

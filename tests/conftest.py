@@ -92,27 +92,6 @@ def _reset_resilience_state():
 
 
 @pytest.fixture(autouse=True)
-def _isolate_attn_table(tmp_path_factory, monkeypatch):
-    """The attention tuning table (ops/autotune.py) persists next to the
-    XLA cache by default; point every test at a throwaway path and drop
-    the cached instance so no test reads another's sweeps (or a real
-    /tmp leftover). The shipped in-repo layer still loads — that IS
-    production behavior."""
-    import sys
-
-    monkeypatch.setenv(
-        "CDT_ATTN_TABLE",
-        str(tmp_path_factory.mktemp("attn") / "attn_tuning.json"))
-    mod = sys.modules.get("comfyui_distributed_tpu.ops.autotune")
-    if mod is not None:
-        mod.reset_default_table()
-    yield
-    mod = sys.modules.get("comfyui_distributed_tpu.ops.autotune")
-    if mod is not None:
-        mod.reset_default_table()
-
-
-@pytest.fixture(autouse=True)
 def _isolate_content_cache(tmp_path_factory, monkeypatch):
     """The content cache (cluster/cache) persists next to the XLA cache
     by default; point every test at a throwaway directory so no test

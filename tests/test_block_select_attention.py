@@ -253,9 +253,9 @@ def test_the_chunk_form_is_the_step_form_through_the_cache(start):
 
 
 def test_the_kernel_reports_a_tier_of_its_own(monkeypatch):
-    from comfyui_distributed_tpu.ops import attention, autotune
+    from comfyui_distributed_tpu.ops import attention, kernel_choice
 
-    assert "block_select" in autotune.REPORTED_TIERS
+    assert "block_select" in kernel_choice.REPORTED_TIERS
     assert "block_select" in attention.CAUSAL_TIER_REASONS
     said = []
     monkeypatch.setattr(attention, "_note_selection",

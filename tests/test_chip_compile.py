@@ -6,17 +6,18 @@ mode cannot show what it shows: a kernel that passed every interpret-mode
 test was refused on the chip's terms for 4 MB more scoped VMEM than the
 repo's own model counted. These cases compile, at real widths:
 
-- every Pallas row of ``ops/attn_table_default.json`` alone, with its
-  operands as program arguments (the strictest setting the compiler has);
-- the classic ``bh`` call at FLUX's geometry, which no row selects but the
-  floors of ``select_kernel`` still reach;
+- every geometry of the model zoo the policy answers ``packed``, alone,
+  with its operands as program arguments (the strictest setting the
+  compiler has);
+- the classic ``bh`` call at FLUX's geometry, which the policy does not
+  answer there but the floors of ``select_kernel`` still reach;
 - SDXL's two self-attention sites inside the transformer block that calls
   them, with the dispatcher choosing the tier as it does on the chip —
   and, in the same text, where the compiler put the GEGLU's exact gelu:
   in a product's epilogue, not on ``proj_out``'s operand path (PR 35);
-- SD3's joint attention (B=2, N=4173, H=24, D=64), which no row names:
-  the packed tier's default path, at the blocks its shape derives;
-- and, for every row and for SD3, the largest blocks the packed VMEM
+- SD3's joint attention (B=2, N=4173, H=24, D=64), inside a label's
+  bucket: the packed tier at the blocks its exact shape derives;
+- and, for every such geometry and for SD3, the largest blocks the packed VMEM
   model (``_packed_blocks``) approves: a model that says yes where the
   compiler says no is the bug this file exists to catch.
 
@@ -43,8 +44,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from comfyui_distributed_tpu.ops import attention as attn
-from comfyui_distributed_tpu.ops import autotune
 from comfyui_distributed_tpu.ops import flash_attention as fa
+from comfyui_distributed_tpu.ops.kernel_choice import GeometryKey
 
 _spec = importlib.util.spec_from_file_location(
     "loop_copies",
@@ -52,10 +53,29 @@ _spec = importlib.util.spec_from_file_location(
 loop_copies = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(loop_copies)
 
-TABLE = autotune.TuningTable(shipped=True, path="/nonexistent/none.json",
-                             autoload=True).entries()
-PALLAS_ROWS = {k.key_str(): (k, c) for k, c in TABLE.items()
-               if c.tier != "xla"}
+# the model zoo's serving geometries (docs/roofline.md, r05's table):
+# (heads, head_dim, q_len, kv_len)
+ZOO = {
+    # SDXL UNet at 1024²: 64² = 4096 tokens at 10 heads × 64, 32² = 1024
+    # tokens at 20 × 64, and their 77-token cross-attention contexts
+    "sdxl_self64": (10, 64, 4096, 4096),
+    "sdxl_self32": (20, 64, 1024, 1024),
+    "sdxl_cross64": (10, 64, 4096, 77),
+    "sdxl_cross32": (20, 64, 1024, 77),
+    # FLUX-12B at 1024²: 4096 image + 512 text joint tokens, 24 × 128
+    "flux_joint": (24, 128, 4608, 4608),
+    # WAN-1.3B t2v 33 frames at 480p: 14 040 tokens, 12 × 128, and the
+    # 512-token text cross-attention
+    "wan_self": (12, 128, 14040, 14040),
+    "wan_cross": (12, 128, 14040, 512),
+}
+# those the one policy answers with a Pallas tier, by geometry label
+PALLAS_ROWS = {
+    key.key_str(): (key, choice)
+    for key, choice in ((GeometryKey.from_shape(H, D, nq, nk),
+                         attn.policy_choice(nq, nk, H, D))
+                        for H, D, nq, nk in ZOO.values())
+    if choice.tier != "xla"}
 
 
 @pytest.fixture(scope="module")
@@ -102,12 +122,12 @@ def _compile_kernel(chip, tier, H, D, nq, nk, bq, bk, batch=1):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# (id, tier, H, D, Nq, Nk, block_q, block_k, batch): the table's Pallas
-# rows at their bucket lengths (a packed row without blocks takes the
-# shape's, as the dispatcher resolves it), WAN also at its real 14 040
+# (id, tier, H, D, Nq, Nk, block_q, block_k, batch): the zoo's Pallas
+# geometries at their label's bucket lengths (a packed choice takes the
+# shape's blocks, as the dispatcher resolves it), WAN also at its real 14 040
 # tokens (not a block multiple: the call pads) with the shape's blocks and
 # with requested 256/512 streaming K, SD3's joint attention at the CFG
-# batch, the classic call the table never picks, and SDXL's 64² site at the
+# batch, the classic call the policy never picks there, and SDXL's 64² site at the
 # CFG batch (as it runs)
 KERNEL_CASES = [
     (ks, c.tier, k.num_heads, k.head_dim, k.q_bucket, k.kv_bucket,
@@ -133,11 +153,12 @@ def test_table_row_compiles_alone(chip, case):
 
 
 def test_table_has_the_rows_the_main_paths_select():
-    """The cases above are read from the shipped table; an emptied table
-    must not pass by compiling nothing."""
-    zoo = {k.key_str() for k in autotune.model_zoo_geometries().values()}
-    assert zoo == {k.key_str() for k in TABLE}
-    assert len(PALLAS_ROWS) >= 5
+    """The cases above are what the policy answers over the zoo; a policy
+    that stopped answering ``packed`` must not pass by compiling nothing."""
+    assert sorted(PALLAS_ROWS) == [
+        "h10.d64.q4096.kv4096.bf16", "h12.d128.q16384.kv16384.bf16",
+        "h12.d128.q16384.kv512.bf16", "h20.d64.q1024.kv1024.bf16",
+        "h24.d128.q8192.kv8192.bf16"]
     assert {c.tier for _, c in PALLAS_ROWS.values()} == {"packed"}
 
 
@@ -188,12 +209,11 @@ def test_sdxl_self_attention_inside_its_block(chip, level, monkeypatch):
     text tokens, GEGLU) at the width of the 64² and the 32² level, CFG
     batch 2, with the dispatcher choosing as it does on the chip: the
     Pallas call compiles where other ops of the same program produce its
-    operands, and the tier is the table's."""
+    operands, and the tier is the policy's."""
     from comfyui_distributed_tpu.models.layers import TransformerBlock
 
     C, heads, n = level
-    for var in ("CDT_FLASH_ATTENTION", "CDT_ATTN_TUNE"):
-        monkeypatch.delenv(var, raising=False)
+    monkeypatch.delenv("CDT_FLASH_ATTENTION", raising=False)
     # the one place the kernels ask where they are (ops/flash_attention):
     # steered here, in the test, since jax.devices() still says cpu
     monkeypatch.setattr(fa, "_platform", lambda: "tpu")
@@ -215,9 +235,9 @@ def test_sdxl_self_attention_inside_its_block(chip, level, monkeypatch):
     _assert_gelu_is_a_products_epilogue(text)
     selected = dict(item.split("=") for item in
                     attn.selection_summary().split(","))
-    key = autotune.GeometryKey.from_shape(heads, C // heads, n, n).key_str()
+    key = GeometryKey.from_shape(heads, C // heads, n, n).key_str()
     assert selected[key].startswith("packed"), selected
-    cross = autotune.GeometryKey.from_shape(heads, C // heads, n, 77)
+    cross = GeometryKey.from_shape(heads, C // heads, n, 77)
     assert selected[cross.key_str()] == "xla", selected
 
 
@@ -251,10 +271,9 @@ PACKED_GEOMETRIES = {
 
 @pytest.mark.parametrize("row", sorted(PACKED_GEOMETRIES), ids=str)
 def test_feasibility_never_approves_what_the_compiler_refuses(chip, row):
-    """The VMEM model against the compiler, on the rows the table ships
-    and on SD3's default-path geometry: whatever the packed model approves
-    for the geometry — not only the pair the table or the shape chose —
-    must compile."""
+    """The VMEM model against the compiler, on the zoo's packed geometries
+    and on SD3's joint site: whatever the packed model approves for the
+    geometry — not only the pair the shape chose — must compile."""
     H, D, nq, nk = PACKED_GEOMETRIES[row]
     approved = _packed_frontier(nq, nk, D)
     assert approved, f"nothing approved for {row}"
@@ -642,8 +661,7 @@ def test_a_joint_block_hands_the_kernel_its_products_outputs(chip, case,
     from comfyui_distributed_tpu.models import dit
 
     _, preset, T, B, label = case
-    for var in ("CDT_FLASH_ATTENTION", "CDT_ATTN_TUNE"):
-        monkeypatch.delenv(var, raising=False)
+    monkeypatch.delenv("CDT_FLASH_ATTENTION", raising=False)
     monkeypatch.setattr(fa, "_platform", lambda: "tpu")
     attn.reset_selections()
     cfg = getattr(dit.DiTConfig, preset)()
@@ -686,7 +704,7 @@ def test_a_joint_block_hands_the_kernel_its_products_outputs(chip, case,
     if not (cfg.qk_norm or cfg.pos_embed == "rope"):
         assert operands[1] == operands[3] == operands[5], operands
         assert operands[0] == operands[2] == operands[4], operands
-    key = autotune.GeometryKey.from_shape(cfg.heads, hd, T + N, T + N)
+    key = GeometryKey.from_shape(cfg.heads, hd, T + N, T + N)
     assert attn.selection_summary() == f"{key.key_str()}={label}"
 
 

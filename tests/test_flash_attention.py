@@ -198,7 +198,7 @@ class TestShapeGate:
     the one policy picks per shape — packed-legal layouts engage at
     q ≥ 1024 with K ≥ 256 (measured crossover, docs/roofline.md finding
     1a), packed-illegal layouts keep the classic 8192 gate. Geometries
-    with no table row, so the policy alone answers."""
+    inside and at the edges of a label's bucket: the policy alone answers."""
 
     @pytest.fixture()
     def tier(self, monkeypatch):
@@ -207,7 +207,6 @@ class TestShapeGate:
         from comfyui_distributed_tpu.ops import attention as attn
 
         monkeypatch.delenv("CDT_FLASH_ATTENTION", raising=False)
-        monkeypatch.setenv("CDT_ATTN_TUNE", "0")
         fake = types.SimpleNamespace(platform="tpu")
         monkeypatch.setattr(attn.jax, "devices", lambda *a: [fake])
 
@@ -238,9 +237,9 @@ class TestShapeGate:
         assert tier(4608, 4608, 24, 128) == "packed"
 
     def test_floors_hold_exact_lengths_not_buckets(self, tier):
-        # 1000 tokens share the 1024 bucket of the table's keys; the
-        # policy reads the length itself
-        assert tier(1000, 1000, 16, 64) == "xla"
+        # 830 tokens share the 1024 bucket of the label; the policy
+        # reads the length itself (PACKED_MIN_Q = 832, PR 55's reading)
+        assert tier(830, 830, 16, 64) == "xla"
         assert tier(1024, 255, 16, 64) == "xla"
         assert tier(1024, 256, 16, 64) == "packed"
 
@@ -273,8 +272,8 @@ class TestShapeGate:
         """Non-positive or non-(8,128)-divisible blocks raise a
         descriptive error at the call instead of letting pallas fail
         deep in Mosaic lowering (ISSUE 8 satellite) — in either layout,
-        and in a tuning-table row."""
-        from comfyui_distributed_tpu.ops import autotune
+        and in the checks the dispatcher resolves a choice's blocks with."""
+        from comfyui_distributed_tpu.ops import flash_attention as fa
 
         q, k, v = rand_qkv(jax.random.key(12), Nq=256, Nk=512)
         for layout in ("packed", "bh"):
@@ -290,11 +289,10 @@ class TestShapeGate:
             with pytest.raises(ValueError, match="multiple of 128"):
                 flash_attention(q, k, v, block_k=200, interpret=True,
                                 layout=layout)
-        key = autotune.GeometryKey.from_shape(2, 64, 256, 512)
-        for tier in ("packed", "bh"):
-            errors = autotune.validate_entry(
-                key, autotune.KernelChoice(tier, 256, 200))
-            assert errors and "multiple of 128" in errors[0]
+        assert fa._packed_legal(2, 64)
+        for check in (fa._check_blocks, fa.resolve_flash_blocks):
+            with pytest.raises(ValueError, match="multiple of 128"):
+                check(256, 200)
 
 
 def _packed_call_spy(monkeypatch):
@@ -373,16 +371,11 @@ class TestPackedBlocks:
             < _PACKED_VMEM_BUDGET_BYTES
 
     def test_requested_blocks_past_the_budget_raise(self):
-        """In the function, in the call, and in a table row's dispatch."""
-        from comfyui_distributed_tpu.ops import attention as attn
-        from comfyui_distributed_tpu.ops import autotune
+        """In the function and in the call."""
         from comfyui_distributed_tpu.ops.flash_attention import _packed_blocks
 
         with pytest.raises(ValueError, match="VMEM"):
             _packed_blocks(16384, 16384, 128, 2, 4096, 16384)
-        choice = autotune.KernelChoice("packed", 4096, 16384)
-        with pytest.raises(ValueError, match="VMEM"):
-            attn._with_packed_blocks(choice, 16384, 16384, 128, "bf16")
         q, k, v = (jax.ShapeDtypeStruct((1, 16384, 1, 128), jnp.bfloat16),) * 3
         with pytest.raises(ValueError, match="VMEM"):
             jax.eval_shape(
@@ -559,7 +552,7 @@ class TestJointSegments:
 
     @staticmethod
     def _packed_choice(case):
-        from comfyui_distributed_tpu.ops.autotune import KernelChoice
+        from comfyui_distributed_tpu.ops.kernel_choice import KernelChoice
 
         _, _, T, N, _, _, _ = case
         return KernelChoice("packed", 512, -(-(T + N) // 128) * 128)
@@ -634,7 +627,7 @@ class TestJointSegments:
         same attention."""
         from comfyui_distributed_tpu.ops import attention as attn
         from comfyui_distributed_tpu.ops import flash_joint as fj
-        from comfyui_distributed_tpu.ops.autotune import KernelChoice
+        from comfyui_distributed_tpu.ops.kernel_choice import KernelChoice
 
         monkeypatch.setattr(
             fj, "flash_joint_attention",

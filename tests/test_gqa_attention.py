@@ -207,12 +207,13 @@ def test_the_decode_step_reads_the_rows_it_is_told_are_valid():
 
 
 def test_both_kernels_report_a_tier_of_their_own():
-    from comfyui_distributed_tpu.ops import attention, autotune
+    from comfyui_distributed_tpu.ops import attention, kernel_choice
 
     for tier in ("gqa_window", "gqa_causal"):
-        assert tier in autotune.REPORTED_TIERS and tier not in autotune.TIERS
+        assert tier in kernel_choice.REPORTED_TIERS
+        assert tier not in kernel_choice.TIERS
     assert set(attention.CAUSAL_TIER_REASONS) \
-        == set(autotune.REPORTED_TIERS) - set(autotune.TIERS)
+        == set(kernel_choice.REPORTED_TIERS) - set(kernel_choice.TIERS)
     attention.reset_selections()
     attention.note_causal("gqa_window", 48, 128, 4096, 8192, jnp.bfloat16,
                           1024, 1024)

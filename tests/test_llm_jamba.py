@@ -223,10 +223,10 @@ def test_the_decode_step_is_the_naive_attentions_last_row():
 
 
 def test_the_blocked_kernel_reports_a_tier_of_its_own(monkeypatch):
-    from comfyui_distributed_tpu.ops import attention, autotune
+    from comfyui_distributed_tpu.ops import attention, kernel_choice
 
-    assert "shared_kv_causal" in autotune.REPORTED_TIERS
-    assert "shared_kv_causal" not in autotune.TIERS
+    assert "shared_kv_causal" in kernel_choice.REPORTED_TIERS
+    assert "shared_kv_causal" not in kernel_choice.TIERS
     attention.reset_selections()
     attention.note_causal("shared_kv_causal", 20, 128, 4096, 66560,
                           jnp.bfloat16, 1024, 1024)

@@ -11,7 +11,7 @@ Tier-1 evidence that the multi-chip strategies EXECUTE on the virtual
 - sp and dp×tp execute against a single-device reference of the same
   seed fold-in (f32 stacks, the repo's 2e-4 sharding tolerance; the
   txt2img dp fan-out and kill-switch paths are asserted bit-identical);
-- the mesh-aware autotuner resolves PER-SHARD geometries under
+- the kernel selection resolves PER-SHARD geometries under
   ``tp_shard_scope``;
 - the chaos-marked mesh-drain event: a worker drains mid mesh-tier
   batched job with bit-identical completion, zero dead-letters, and no
@@ -288,13 +288,13 @@ class TestMeshTierMicrobatch:
 
 
 # ---------------------------------------------------------------------------
-# mesh-aware autotune
+# mesh-aware kernel selection
 # ---------------------------------------------------------------------------
 
 
-class TestMeshAwareAutotune:
+class TestMeshAwareKernelSelection:
     def test_geometry_shard(self):
-        from comfyui_distributed_tpu.ops.autotune import GeometryKey
+        from comfyui_distributed_tpu.ops.kernel_choice import GeometryKey
 
         g = GeometryKey.from_shape(12, 128, 14040, 14040)
         assert g.shard(2).num_heads == 6
@@ -303,62 +303,27 @@ class TestMeshAwareAutotune:
         assert g.shard(5) is g
         assert g.shard(1) is g
 
-    def test_parse_mesh_spec(self):
-        from comfyui_distributed_tpu.ops.autotune import parse_mesh_spec
+    def test_select_kernel_resolves_per_shard_geometry(self, monkeypatch):
+        import types
 
-        assert parse_mesh_spec("dp4xtp2") == {"dp": 4, "tp": 2}
-        assert parse_mesh_spec("tp=2") == {"tp": 2}
-        assert parse_mesh_spec("dp=2,tp=4") == {"dp": 2, "tp": 4}
-        with pytest.raises(ValueError):
-            parse_mesh_spec("nonsense!")
+        from comfyui_distributed_tpu.ops import attention
 
-    def test_select_kernel_resolves_per_shard_geometry(self, tmp_path,
-                                                       monkeypatch):
-        from comfyui_distributed_tpu.ops import attention, autotune
-
-        # local overlay holding ONLY the per-shard (h6) entry
-        table = autotune.TuningTable(path=tmp_path / "t.json",
-                                     shipped=False, autoload=False)
-        key = autotune.GeometryKey.from_shape(6, 128, 14040, 14040)
-        table.record(key, autotune.KernelChoice("bh", 256, 512,
-                                                source="sweep",
-                                                reason="per-shard"))
-        monkeypatch.setenv("CDT_ATTN_TABLE", str(tmp_path / "t.json"))
-        monkeypatch.setenv("CDT_FLASH_ATTENTION", "1")  # skip the
-        # off-TPU early return so the table lookup is reachable on CPU
-        autotune.reset_default_table()
-        try:
-            with attention.tp_shard_scope(2):
-                choice = attention.select_kernel(14040, 14040, 12, 128)
-            assert (choice.tier, choice.block_q) == ("bh", 256)
-            assert choice.source == "table"
-            # without the scope the same site resolves the FULL-H entry
-            # (the shipped wan_self bake) — the pre-fix behavior a
-            # tp-sharded site must no longer see
-            full = attention.select_kernel(14040, 14040, 12, 128)
-            assert (full.tier, full.block_q) != (choice.tier,
-                                                 choice.block_q)
-        finally:
-            autotune.reset_default_table()
-
-    def test_program_geometries_shard_over_tp_mesh(self):
-        from comfyui_distributed_tpu.cluster.shape_catalog import \
-            ProgramKey
-        from comfyui_distributed_tpu.models.registry import ModelRegistry
-        from comfyui_distributed_tpu.ops import autotune
-
-        bundle = ModelRegistry().get("flux-tiny")
-        flat = autotune.geometries_for_program(
-            bundle, ProgramKey("flow_dp", "flux-tiny", 32, 32, 2))
-        tp = autotune.geometries_for_program(
-            bundle, ProgramKey("flow_tp", "flux-tiny", 32, 32, 2,
-                               mesh=(("dp", 4), ("tp", 2))))
-        assert {g.num_heads for g in flat} == {4}
-        assert {g.num_heads for g in tp} == {2}
-        # sp programs dispatch ring attention, not the table
-        assert autotune.geometries_for_program(
-            bundle, ProgramKey("flow_sp", "flux-tiny", 32, 32, 2,
-                               mesh=(("sp", 8),))) == []
+        monkeypatch.delenv("CDT_FLASH_ATTENTION", raising=False)
+        monkeypatch.setattr(attention.jax, "devices",
+                            lambda *a: [types.SimpleNamespace(platform="tpu")])
+        attention.reset_selections()
+        # SDXL's 64² site: 10 heads × 64 are five 128-lane groups (packed);
+        # a tp=2 shard runs 5 heads, which are not — the policy is asked
+        # with the shard's own head count and answers for IT
+        with attention.tp_shard_scope(2):
+            shard = attention.select_kernel(4096, 4096, 10, 64)
+        assert shard.tier == "xla"
+        assert "h5.d64.q4096.kv4096.bf16=xla" in attention.selection_summary()
+        # without the scope the same site resolves the FULL-H geometry —
+        # what a tp-sharded site must not see
+        full = attention.select_kernel(4096, 4096, 10, 64)
+        assert (full.tier, full.block_q, full.block_k) == \
+            ("packed", 512, 4096)
 
 
 # ---------------------------------------------------------------------------
@@ -470,7 +435,7 @@ def test_warmup_compiles_mesh_tier_programs(monkeypatch, tmp_path):
     keys = [ProgramKey("flow_dp", "flux-tiny", 32, 32, 2)]
     keys += mesh_tier_keys(keys, mesh)
     report = run_warmup(ModelRegistry(), mesh, keys,
-                        models=["flux-tiny"], tune=False)
+                        models=["flux-tiny"])
     outcomes = {e.key.pipeline: e.outcome for e in report}
     assert outcomes["flow_sp"] in ("compiled", "cache_hit"), report
     assert outcomes["flow_tp"] in ("compiled", "cache_hit"), report

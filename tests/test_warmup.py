@@ -219,59 +219,21 @@ class TestWarmupManager:
         assert (tmp_path / "cat.json").exists()
 
 
-    def test_autotune_stage_gates_ready(self, tmp_path, monkeypatch,
-                                        restore_cache_config):
-        """ISSUE 8: a worker reports ready only AFTER its catalog
-        geometries are tuned — the autotune stage runs inside the
-        warming window, derives geometries from the warmed programs,
-        and persists the table."""
-        from comfyui_distributed_tpu.ops import autotune
-
+    def test_status_is_state_outcomes_report(self, tmp_path, monkeypatch,
+                                             restore_cache_config):
+        """Warm-up is one pass over its programs: it reaches ``ready`` on
+        them alone, and its status names no tuning stage."""
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", _WARM_CACHE)
-        autotune.reset_default_table()
-        mgr = WarmupManager(lambda: ModelRegistry(),
-                            lambda: build_mesh({"dp": 1},
-                                               jax.devices()[:1]),
-                            catalog=_tiny_catalog(tmp_path))
-        seen = {}
-        orig = autotune.ensure_tuned
-
-        def spy(geometries, **kw):
-            seen["state_during_tuning"] = mgr.state
-            seen["geometries"] = list(geometries)
-            return orig(geometries, **kw)
-
-        monkeypatch.setattr(autotune, "ensure_tuned", spy)
-        status = mgr.run(models=["tiny"], seed_workflows=False)
-        assert status["state"] == "ready"
-        # sweeps happened while the worker still reported warming
-        assert seen["state_during_tuning"] == "warming"
-        assert seen["geometries"], "no geometries derived from catalog"
-        # off-TPU the sweep resolves the deterministic dry policy
-        assert set(status["autotune"]["outcomes"]) <= {"dry", "cached"}
-        # persisted: every derived geometry now resolves from the table
-        table = autotune.default_table()
-        for g in seen["geometries"]:
-            assert table.get(g) is not None
-        # warmup report names the geometries per program
-        report_geoms = [g for e in status["report"]
-                        for g in e["geometries"]]
-        assert report_geoms
-
-    def test_autotune_kill_switch(self, tmp_path, monkeypatch,
-                                  restore_cache_config):
-        from comfyui_distributed_tpu.ops import autotune
-
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", _WARM_CACHE)
-        monkeypatch.setenv("CDT_ATTN_TUNE", "0")
-        autotune.reset_default_table()
         mgr = WarmupManager(lambda: ModelRegistry(),
                             lambda: build_mesh({"dp": 1},
                                                jax.devices()[:1]),
                             catalog=_tiny_catalog(tmp_path))
         status = mgr.run(models=["tiny"], seed_workflows=False)
         assert status["state"] == "ready"
-        assert status["autotune"]["report"] == []
+        assert set(status) == {"state", "catalog_size", "outcomes",
+                               "seconds", "report"}
+        assert set(status["report"][0]) == {"program", "outcome", "seconds",
+                                            "detail"}
 
 
 class TestHealthAndRoute:
