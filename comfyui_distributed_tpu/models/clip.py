@@ -151,8 +151,10 @@ class CLIPTextModel:
         self.module = CLIPTextTransformer(self.config)
 
     def init(self, rng: jax.Array) -> "CLIPTextModel":
+        from .draw import draw_params
+
         toks = jnp.zeros((1, self.config.max_len), jnp.int32)
-        self.params = jax.jit(self.module.init)(rng, toks)
+        self.params = draw_params(self.module, rng, toks)
         return self
 
     def __call__(self, tokens: jax.Array) -> dict[str, jax.Array]:

@@ -152,6 +152,8 @@ def init_controlnet(
     context_len: int = 77,
     hint_channels: int = 3,
 ) -> ControlNetBundle:
+    from .draw import draw_params
+
     model = ControlNet(config, hint_channels=hint_channels)
     H, W, C = sample_shape
     down = 8  # hint stem downscale (three stride-2 convs)
@@ -161,5 +163,5 @@ def init_controlnet(
     y = (jnp.zeros((1, config.adm_in_channels), jnp.float32)
          if config.adm_in_channels else None)
     hint = jnp.zeros((1, H * down, W * down, hint_channels), jnp.float32)
-    params = jax.jit(model.init)(rng, x, t, ctx, y, hint)
+    params = draw_params(model, rng, x, t, ctx, y, hint)
     return ControlNetBundle(model, params)

@@ -503,10 +503,10 @@ def init_dit(config: DiTConfig, rng: jax.Array,
     """``abstract=True`` returns a ShapeDtypeStruct tree instead of
     materialized random params — the shape template weight conversion
     needs without paying a 12B-param random init (FLUX-size presets).
-    ``param_dtype`` casts float params inside the fused init program
-    (see ``models/unet.init_unet``) — bf16 residency is what lets a
+    ``param_dtype`` casts float params inside each leaf's draw
+    (``models/draw.py``) — bf16 residency is what lets a
     FLUX-class model fit accelerator HBM at all."""
-    from .unet import casting_init
+    from .draw import draw_params
 
     model = DiT(config)
     h, w = sample_hw
@@ -514,9 +514,6 @@ def init_dit(config: DiTConfig, rng: jax.Array,
     t = jnp.zeros((1,))
     ctx = jnp.zeros((1, context_len, config.context_dim))
     pooled = jnp.zeros((1, config.pooled_dim))
-    init_fn = casting_init(model.init, param_dtype)
-    if abstract:
-        params = jax.eval_shape(init_fn, rng, x, t, ctx, pooled)
-    else:
-        params = jax.jit(init_fn)(rng, x, t, ctx, pooled)
+    params = draw_params(model, rng, x, t, ctx, pooled,
+                         param_dtype=param_dtype, abstract=abstract)
     return model, params

@@ -651,9 +651,9 @@ class ModelBundle:
             # the converter fills float32 on the host; hold what the
             # preset says (an orbax restore already lands in the dtype of
             # the tree it restores over)
-            from .unet import _cast_float_params
+            from .draw import cast_float
 
-            self._set_core_params(_cast_float_params(
+            self._set_core_params(cast_float(
                 self._core_params(), self.preset.param_dtype))
         self._stamp_text_encoder()
 

@@ -178,13 +178,12 @@ class T5Model:
         self.module = T5Encoder(self.config)
 
     def init(self, rng: jax.Array, abstract: bool = False) -> "T5Model":
+        from .draw import draw_params
+
         toks = jnp.zeros((1, self.config.max_len), jnp.int32)
-        if abstract:
-            # shape template only (conversion about to replace every leaf
-            # — a T5-XXL random init alone is ~19 GB)
-            self.params = jax.eval_shape(self.module.init, rng, toks)
-        else:
-            self.params = jax.jit(self.module.init)(rng, toks)
+        # abstract: a shape template only (conversion about to replace
+        # every leaf — a T5-XXL random init alone is ~19 GB)
+        self.params = draw_params(self.module, rng, toks, abstract=abstract)
         return self
 
     def __call__(self, tokens: jax.Array, attn_mask=None) -> jax.Array:

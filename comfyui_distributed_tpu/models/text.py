@@ -107,8 +107,10 @@ class TextEncoder:
         )
 
     def init(self, rng: jax.Array) -> "TextEncoder":
+        from .draw import draw_params
+
         tokens = jnp.zeros((1, self.config.max_len), jnp.int32)
-        self.params = jax.jit(self.module.init)(rng, tokens)
+        self.params = draw_params(self.module, rng, tokens)
         return self
 
     def tokenize(self, texts: Sequence[str]) -> jax.Array:

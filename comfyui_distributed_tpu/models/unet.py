@@ -213,40 +213,24 @@ def init_unet(
 
     ``abstract=True`` returns a ShapeDtypeStruct tree (conversion template
     — no multi-GB random init when every leaf is about to be replaced).
-    ``param_dtype`` (e.g. ``jnp.bfloat16``) casts float params INSIDE the
-    init program: XLA fuses the cast per buffer, so peak device memory is
-    the cast tree plus one layer — never the full fp32 tree (an SDXL fp32
-    init plus a post-hoc cast transiently needs 15.6 GB; fused it's
-    ~5.5 GB, and inference weights want bf16 residency anyway)."""
+    ``param_dtype`` (e.g. ``jnp.bfloat16``) casts float params INSIDE each
+    leaf's draw, so peak device memory is the cast tree plus one leaf —
+    never the full fp32 tree (an SDXL fp32 init plus a post-hoc cast
+    transiently needs 15.6 GB; cast leaf by leaf it's ~5.5 GB, and
+    inference weights want bf16 residency anyway)."""
+    from .draw import draw_params
+
     model = UNet2D(config)
     H, W, C = sample_shape
     x = jnp.zeros((1, H, W, C), jnp.float32)
     t = jnp.zeros((1,), jnp.float32)
     ctx = jnp.zeros((1, context_len, config.context_dim), jnp.float32)
     y = jnp.zeros((1, config.adm_in_channels), jnp.float32) if config.adm_in_channels else None
-    # jit the init: eager tracing dispatches each initializer op through a
-    # separate tiny XLA executable (~tens of seconds for a full UNet even
-    # at toy sizes); one compiled program is an order of magnitude faster
-    init_fn = casting_init(model.init, param_dtype)
-    if abstract:
-        params = jax.eval_shape(init_fn, rng, x, t, ctx, y)
-    else:
-        params = jax.jit(init_fn)(rng, x, t, ctx, y)
+    # neither eager flax init (each initialiser op its own tiny executable:
+    # tens of seconds even at toy sizes) nor jit of the whole init (ONE
+    # program holding 1 752 draws and the forward: 299 s to compile, 67 s
+    # to read back): one small jitted draw a distinct (initialiser, shape,
+    # dtype, cast), every leaf under the key flax gives it (models/draw.py)
+    params = draw_params(model, rng, x, t, ctx, y, param_dtype=param_dtype,
+                         abstract=abstract)
     return model, params
-
-
-def _cast_float_params(params, dtype):
-    """Cast float leaves to ``dtype`` (shared by the init helpers)."""
-    return jax.tree_util.tree_map(
-        lambda p: p.astype(dtype)
-        if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
-
-
-def casting_init(init_fn, param_dtype):
-    """Wrap a flax ``init`` so float params are cast to ``param_dtype``
-    inside the same compiled program (fused, per-buffer — the full-size
-    fp32 tree never materializes). No-op when ``param_dtype`` is None.
-    Shared by init_unet / init_dit / init_wan."""
-    if param_dtype is None:
-        return init_fn
-    return lambda *a: _cast_float_params(init_fn(*a), param_dtype)

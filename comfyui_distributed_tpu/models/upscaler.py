@@ -156,7 +156,9 @@ class UpscalerBundle:
 
 def init_upscaler(config: UpscalerConfig, rng: jax.Array,
                   sample_hw: tuple[int, int] = (32, 32)) -> UpscalerBundle:
+    from .draw import draw_params
+
     model = RRDBNet(config)
     x = jnp.zeros((1, *sample_hw, config.in_channels), jnp.float32)
-    params = jax.jit(model.init)(rng, x)
+    params = draw_params(model, rng, x)
     return UpscalerBundle(model, params)

@@ -179,9 +179,9 @@ class AutoencoderKL:
         k1, k2 = jax.random.split(rng)
         img = jnp.zeros((1, H, W, cfg.in_channels))
         lat = jnp.zeros((1, H // cfg.downscale, W // cfg.downscale, cfg.latent_channels))
-        # jitted: one compiled init program instead of per-op eager dispatch
-        self.enc_params = jax.jit(self.encoder.init)(k1, img)
-        self.dec_params = jax.jit(self.decoder.init)(k2, lat)
+        from .draw import draw_params   # on a comment's old line (D17)
+        self.enc_params = draw_params(self.encoder, k1, img)
+        self.dec_params = draw_params(self.decoder, k2, lat)
         return self
 
     def encode(self, images: jax.Array, params=None) -> jax.Array:

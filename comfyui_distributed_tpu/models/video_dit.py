@@ -175,12 +175,12 @@ class VideoDiT(nn.Module):
 def init_video_dit(config: VideoDiTConfig, rng: jax.Array,
                    sample_fhw: tuple[int, int, int] = (5, 8, 8),
                    context_len: int = 16, abstract: bool = False):
+    from .draw import draw_params
+
     model = VideoDiT(config)
     f, h, w = sample_fhw
     x = jnp.zeros((1, f, h, w, config.in_channels))
     args = (rng, x, jnp.zeros((1,)),
             jnp.zeros((1, context_len, config.context_dim)),
             jnp.zeros((1, config.pooled_dim)))
-    if abstract:
-        return model, jax.eval_shape(model.init, *args)
-    return model, jax.jit(model.init)(*args)
+    return model, draw_params(model, *args, abstract=abstract)

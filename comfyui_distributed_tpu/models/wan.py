@@ -268,9 +268,9 @@ def init_wan(config: WanConfig, rng: jax.Array,
              sample_fhw: tuple[int, int, int] = (5, 8, 8),
              context_len: int = 16, abstract: bool = False,
              param_dtype=None):
-    """``param_dtype`` casts float params inside the fused init program
-    (see ``models/unet.init_unet``) — a 14B WAN never fits as fp32."""
-    from .unet import casting_init
+    """``param_dtype`` casts float params inside each leaf's draw
+    (``models/draw.py``) — a 14B WAN never fits as fp32."""
+    from .draw import draw_params
 
     model = WanModel(config)
     f, h, w = sample_fhw
@@ -278,10 +278,8 @@ def init_wan(config: WanConfig, rng: jax.Array,
             jnp.zeros((1,)),
             jnp.zeros((1, context_len, config.text_dim)),
             jnp.zeros((1, 16)))
-    init_fn = casting_init(model.init, param_dtype)
-    if abstract:
-        return model, jax.eval_shape(init_fn, *args)
-    return model, jax.jit(init_fn)(*args)
+    return model, draw_params(model, *args, param_dtype=param_dtype,
+                              abstract=abstract)
 
 
 # ---------------------------------------------------------------------------

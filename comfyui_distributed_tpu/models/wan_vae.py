@@ -330,14 +330,16 @@ class WanVAE3D:
 
     def init(self, rng: jax.Array, frames: int = 5,
              image_hw: tuple[int, int] = (32, 32)) -> "WanVAE3D":
+        from .draw import draw_params
+
         cfg = self.config
         H, W = image_hw
         k1, k2 = jax.random.split(rng)
         vid = jnp.zeros((1, frames, H, W, cfg.in_channels))
         lat = jnp.zeros((1, cfg.latent_frames(frames), H // cfg.downscale,
                          W // cfg.downscale, cfg.latent_channels))
-        self.enc_params = jax.jit(self.encoder.init)(k1, vid)
-        self.dec_params = jax.jit(self.decoder.init)(k2, lat)
+        self.enc_params = draw_params(self.encoder, k1, vid)
+        self.dec_params = draw_params(self.decoder, k2, lat)
         return self
 
     def encode(self, video: jax.Array, params=None) -> jax.Array:

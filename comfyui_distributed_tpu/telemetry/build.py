@@ -9,7 +9,9 @@ model is built or a program is built — never on a warm request:
   (a hit of the persistent cache) or ``compile`` (anything else), and
   ``first_run`` (a labelled program's first call, net of the phases);
 - ``cdt_weights_seconds{model, phase}``: ``init`` (a bundle's
-  construction) and ``place`` (a tree's transfer onto a mesh);
+  construction) and ``place`` (a tree's transfer onto a mesh) — and, counts
+  beside them, ``cdt_weights_drawn_leaves_total{model}`` and
+  ``cdt_weights_draw_programs_total{model}`` (``models/draw.py``);
 - ``cdt_boot_seconds{phase}``: ``import``, ``backend``, ``controller``.
 
 The ledger is EXCLUSIVE: building nests (an inner ``jit`` is traced inside
@@ -58,6 +60,8 @@ class _Thread(threading.local):
         self.total = array("d", [0.0])
         self.outcome = None         # hit | miss, from the cache's events
         self.retrieval = 0.0        # seconds of the read, on a hit
+        self.model = ""             # the bundle whose weights.init is open
+        self.pooled = False         # a thread of pooled_builds' pool
 
 
 _mine = _Thread()
@@ -123,13 +127,16 @@ def _phase_done(fun_name: str, phase: str, seconds: float) -> None:
 def _backend_done(fun_name: str, seconds: float) -> None:
     """The backend event of ``seconds`` closed: what the cache's events
     said inside it (they carry no name) now has one. A hit splits into the
-    read and the rest (the cache key, mostly); anything else compiled."""
+    read and the rest (the cache key, mostly); anything else compiled. On
+    a pool's thread only the outcome counts: the seconds are the pool's."""
     program = program_of(fun_name)
     outcome, read = _mine.outcome, min(_mine.retrieval, seconds)
     _mine.outcome, _mine.retrieval = None, 0.0
-    _tm.XLA_COMPILE_SECONDS.observe(seconds)
     _tm.PROGRAM_CACHE.labels(program=program,
                              outcome=outcome or "uncached").inc()
+    if _mine.pooled:
+        return
+    _tm.XLA_COMPILE_SECONDS.observe(seconds)
     if outcome == "hit":
         _phase(program, "cache_read")(read)
         _phase(program, "cache_key")(seconds - read)
@@ -162,7 +169,7 @@ def on_event(event: str, **_) -> None:
 def on_duration(event: str, seconds: float, fun_name: str = "", **_) -> None:
     if event == "/jax/core/compile/backend_compile_duration":
         _backend_done(fun_name, seconds)
-    elif event in _PHASES:
+    elif event in _PHASES and not _mine.pooled:
         _phase_done(fun_name, _PHASES[event], seconds)
     elif event == "/jax/compilation_cache/cache_retrieval_time_sec":
         _mine.retrieval = seconds
@@ -190,13 +197,55 @@ def _ledger_span(name: str, record, **span_args):
         _settle(t0, time.perf_counter() - t0, record)
 
 
+@contextmanager
 def weights_span(phase: str, model: str, **attrs):
     """A ``weights.<phase>`` span whose SELF seconds land in
-    ``cdt_weights_seconds{model, phase}``."""
+    ``cdt_weights_seconds{model, phase}``; what is drawn on this thread
+    while it is open counts under ``model`` (:func:`weights_drawn`)."""
     def record(seconds: float) -> None:
         _tm.WEIGHTS_SECONDS.labels(model=model, phase=phase).observe(seconds)
 
-    return _ledger_span(f"weights.{phase}", record, model=model, **attrs)
+    outer, _mine.model = _mine.model, model
+    try:
+        with _ledger_span(f"weights.{phase}", record, model=model,
+                          **attrs) as set_attrs:
+            yield set_attrs
+    finally:
+        _mine.model = outer
+
+
+def in_pool() -> None:
+    """This thread builds for :func:`pooled_builds` (a pool's
+    ``initializer``): its builds' seconds are not its own."""
+    _mine.pooled = True
+
+
+@contextmanager
+def pooled_builds(program: str):
+    """Programs built SIDE BY SIDE on :func:`in_pool` threads while this is
+    open are one entry of the ledger: the wall seconds the opening thread
+    waited, under ``program``'s ``compile`` (their traces, lowerings and
+    cache reads too: seconds summed over threads would be counted twice)."""
+    if not enabled():
+        yield
+        return
+
+    def record(seconds: float) -> None:
+        _tm.XLA_COMPILE_SECONDS.observe(seconds)    # as a backend event's
+        _phase(program, "compile")(seconds)
+
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        _settle(t0, time.perf_counter() - t0, record)
+
+
+def weights_drawn(leaves: int, programs: int) -> None:
+    """``models/draw.py`` drew ``leaves`` random-weight leaves through
+    ``programs`` distinct programs, for the bundle being built here."""
+    _tm.WEIGHTS_DRAWN_LEAVES.labels(model=_mine.model).inc(leaves)
+    _tm.WEIGHTS_DRAW_PROGRAMS.labels(model=_mine.model).inc(programs)
 
 
 def _boot_seconds(phase: str):

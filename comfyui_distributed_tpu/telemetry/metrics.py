@@ -642,6 +642,20 @@ WEIGHTS_SECONDS = REGISTRY.histogram(
     "parallel/sharding.replicate: model is empty, a tree has no name).",
     ("model", "phase"), buckets=COMPILE_BUCKETS)
 
+WEIGHTS_DRAWN_LEAVES = REGISTRY.counter(
+    "cdt_weights_drawn_leaves_total",
+    "Random-weight leaves drawn (models/draw.py: draw_params), by the "
+    "bundle whose weights.init span was open (empty outside one).",
+    ("model",))
+
+WEIGHTS_DRAW_PROGRAMS = REGISTRY.counter(
+    "cdt_weights_draw_programs_total",
+    "Distinct draw_leaf programs those leaves went through — one a distinct "
+    "(initialiser, shape, dtype, cast) — summed over a bundle's modules. "
+    "Tens for 1 700 leaves; a count near the leaves' says every leaf "
+    "compiled a program of its own.",
+    ("model",))
+
 BOOT_SECONDS = REGISTRY.gauge(
     "cdt_boot_seconds",
     "Seconds of the serve process's boot, by phase: import (the first line "

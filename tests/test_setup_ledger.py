@@ -259,6 +259,59 @@ def test_a_weights_span_records_self_seconds(ledger):
     assert build.self_seconds(t0, 0.5) == 0.0
 
 
+def test_a_bundles_weights_are_drawn_leaf_by_leaf_under_its_name(ledger):
+    """No program holds a whole model's initialisation (it was ``<lambda>``,
+    ``jax.jit`` of flax's ``init``): the abstract pass is ``init_shapes``,
+    a draw is ``draw_leaf``, and the bundle's line counts both."""
+    from comfyui_distributed_tpu.models import registry
+
+    bundle = registry._build_bundle("tiny", registry.PRESETS["tiny"], None)
+    drawn = sum(len(jax.tree_util.tree_leaves(tree)) for tree in (
+        bundle.pipeline.unet_params, bundle.pipeline.vae.enc_params,
+        bundle.pipeline.vae.dec_params, bundle.text_encoder.params))
+    (labels, leaves), = tm.WEIGHTS_DRAWN_LEAVES.series()
+    (also, programs), = tm.WEIGHTS_DRAW_PROGRAMS.series()
+    assert labels == also == {"model": "tiny"}
+    assert leaves["value"] == drawn > 300
+    assert 4 <= programs["value"] < drawn / 3
+    built = {program for program, _ in _series(tm.PROGRAM_BUILD_SECONDS)}
+    assert "init_shapes" in built and "<lambda>" not in built
+    (labels, _), = tm.WEIGHTS_SECONDS.series()
+    assert labels == {"model": "tiny", "phase": "init"}
+
+
+def test_a_pools_builds_are_one_entry_the_wall_its_opener_waited(ledger):
+    """Programs built side by side: seconds summed over the pool's threads
+    would be counted twice, so they are not counted there at all — the
+    cache's outcomes still are, by name."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    X.block_until_ready()
+    programs = [_tiny_program(_salt()) for _ in range(4)]
+    before = _build()
+    t0 = time.perf_counter()
+    with build.weights_span("init", "pooled-model"):
+        with build.pooled_builds("ledger_pool"), \
+                ThreadPoolExecutor(4, initializer=build.in_pool) as pool:
+            for out in pool.map(lambda program: program(X), programs):
+                out.block_until_ready()
+    wall = time.perf_counter() - t0
+    assert _build("ledger_pool", "compile", "count") == 1
+    assert 0 < _build("ledger_pool", "compile") <= wall
+    assert _build("ledger_tiny") == 0
+    assert _build() - before == pytest.approx(_build("ledger_pool"))
+    # compile_s stays cache_read_s + miss_compile_s: the backend seconds too
+    assert tm.XLA_COMPILE_SECONDS.series()[0][1]["sum"] == pytest.approx(
+        _build(phase=("cache_key", "cache_read", "compile")), rel=1e-9)
+    assert _cache("ledger_tiny") == {"miss": 4.0}
+    # ... and the span around the pool is net of it, as of any build
+    (_, snap), = tm.WEIGHTS_SECONDS.series()
+    assert snap["sum"] + _build("ledger_pool") <= wall
+    # the opener's own thread was never the pool's
+    _tiny_program(_salt())(X).block_until_ready()
+    assert _build("ledger_tiny", "compile", "count") == 1
+
+
 def test_bind_weights_first_run_is_the_first_call_net_of_its_build(ledger):
     from comfyui_distributed_tpu.diffusion.pipeline import bind_weights
 
