@@ -943,3 +943,16 @@ def _keye_preset(name: str, tiny: bool = False):
 
 PRESETS["keye-vl-2.0-30b-a3b"] = _keye_preset("keye-vl-2.0-30b-a3b")
 PRESETS["keye-tiny"] = _keye_preset("keye-tiny", tiny=True)
+
+
+def _zaya_preset(name: str, tiny: bool = False):
+    """The ``zaya`` family (models/llm_zaya.py), registered at the END of
+    this file for ``_glm_preset``'s reason."""
+    from .llm_zaya import ZayaConfig
+
+    share = ZayaConfig.tiny if tiny else ZayaConfig.zaya_share
+    return ModelPreset(name, unet=None, vae=None, text=None, llm=share())
+
+
+PRESETS["zaya1-8b"] = _zaya_preset("zaya1-8b")
+PRESETS["zaya-tiny"] = _zaya_preset("zaya-tiny", tiny=True)

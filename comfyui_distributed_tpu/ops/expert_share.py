@@ -69,15 +69,31 @@ class Routing:
 @device_scoped("llm_router")
 def route(x, w_router, bias, r: Routing):
     """``x`` [T,D] → ``(idx [T,k] int32, weights [T,k] f32)`` over all
-    ``r.outputs`` (an index ``≥ r.experts`` is an identity expert).
+    ``r.outputs`` (an index ``≥ r.experts`` is an identity expert): the
+    LINEAR router, ``logits = x · w_router``, of :func:`route_logits`.
     ``bias`` (None: the router has none) moves the selection only."""
+    return _select(
+        jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST), bias, r)
+
+
+@device_scoped("llm_router")
+def route_logits(logits, bias, r: Routing):
+    """:func:`route` for a model that made its router's ``logits`` [T,
+    ``r.outputs``] float32 itself (an MLP, a state carried between layers):
+    the scores, the selection on ``score + bias``, the combine weights and
+    what the slot counters read stay in this one place."""
+    return _select(logits, bias, r)
+
+
+def _select(logits, bias, r: Routing):
+    """Scores, selection and combine weights from a router's logits."""
     score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[r.score]
-    s = score(jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
-                      precision=jax.lax.Precision.HIGHEST))
+    s = score(logits)
     sel = s if bias is None else s + bias.astype(jnp.float32)
     if r.groups == 1:
         return _combine(s, jax.lax.top_k(sel, r.per_token)[1], r)
-    T = x.shape[0]
+    T = logits.shape[0]
     per_group = r.outputs // r.groups
     grouped = sel.reshape(T, r.groups, per_group)
     group_score = jax.lax.top_k(grouped, r.group_top)[0].sum(-1)
@@ -298,7 +314,17 @@ def streamed_form(rows: int, held: int, r: Routing, tile: int) -> bool:
     expert expects at least a whole tile of rows (below that each tile would
     be mostly padding AND meet a new expert: the loop's dense cousin is the
     better form there). One rule from the shapes, no flag; a share of a
-    wider router keeps the loop (its five modules call :func:`held_part`)."""
+    wider router keeps the loop (its five modules call :func:`held_part`).
+    Served at two geometries, both with 4096-row chunks and tiles of 256
+    rows: 128 experts of 2048 × 768 at top 8 (32 768 slots a chunk, 256 an
+    expert: the kernel 3.07 ms a chunk a layer as served, where the loop's
+    tile read 63 µs, ~12 ms a chunk — PERF.md §6, PR 53) and 16 experts of 2048 × 2048 at top 1, EXACTLY at the edge
+    (4096 slots = 256 · 16; an expert's matrices are 24 MiB, twice buffered
+    48 of the kernel's 64: they still fit whole beside a 256-row tile): the
+    kernel 1.74 / 2.08 / 1.91 ms a chunk at even routing / a cycled brief's
+    / one expert taking all, the loop 2.40 / 3.16 / 2.43; at tiles of 128
+    rows 2.03 / 2.11 / 1.86 against 3.04 / 3.48 / 3.24 (PERF.md §6, PR 57;
+    the floors there are 0.52 ms of products and 0.49 ms of bytes)."""
     return held == r.outputs and rows * r.per_token >= tile * r.outputs
 
 

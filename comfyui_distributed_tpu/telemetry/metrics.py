@@ -125,7 +125,8 @@ LLM_CACHE_BYTES = REGISTRY.gauge(
     "full (prompt + new rows a layer), recurrent (linear-attention or "
     "state-space states and convolution tails), or a model's own kinds "
     "(latent and index: a latent cache and the index keys that choose its "
-    "rows; kv and index: K/V rows and the index keys that choose them). "
+    "rows; kv and index: K/V rows and the index keys that choose them; kv "
+    "and tails: K/V rows and an attention layer's convolution tails). "
     "Set when a request's cache is made.",
     ("layers",))
 

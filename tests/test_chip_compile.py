@@ -1040,3 +1040,89 @@ def test_the_all_held_rewriters_programs_fit_beside_sdxl(chip, monkeypatch):
     mem = compiled["llm_decode"].memory_analysis()
     decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
     assert 6.4 < decode_gib < 7.8 and decode_gib + sdxl < 15.75 - 1.0
+
+
+@pytest.mark.parametrize("kernel", ["gqa_causal_mha", "expert_tiles_mlp"])
+def test_the_latent_rewriters_kernels_compile_at_the_served_geometry(chip,
+                                                                     kernel):
+    """The two kernels as ``zaya1-8b``'s prefill calls them (PR 57), alone:
+    the causal kernel at a THIRD geometry — 8 query heads over 2 K/V heads of
+    128, a 4096-token chunk over the 131 072 rows of a context filled to its
+    end, at the tile the preset ships — and the expert layer's tiles at 16
+    experts of ``[2048, 4096]`` + ``[2048, 2048]`` WHOLE (24 MiB an expert,
+    48 twice buffered of the kernel's 64 MiB: a tile of 256 rows still fits —
+    one of 512 does not — and an axis over the inner width read slower on the
+    chip: PERF.md §6, PR 57)."""
+    from comfyui_distributed_tpu.models import llm_zaya
+    from comfyui_distributed_tpu.ops import (expert_share, expert_stream,
+                                             flash_latent)
+
+    cfg = llm_zaya.ZayaConfig.zaya_share()
+    H, G, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    C = cfg.prefill_chunk_tokens
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    if kernel == "gqa_causal_mha":
+        S = jax.eval_shape(lambda: llm_zaya.empty_cache(
+            cfg, 130944 + 128))["k"][0].shape[1]
+        assert S == 131072 and S % cfg.attn_block_k == 0 \
+            and C % cfg.attn_block_q == 0
+        lowered = flash_latent.gqa_causal_mha.lower(
+            arg((C, H * d)), arg((G, S, d)), arg((G, S, d)), scalar,
+            num_heads=H, block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
+            interpret=False)
+        out = f"bf16[{C},{H * d}]"
+    else:
+        E, D, F, tile = (cfg.num_experts, cfg.hidden_size,
+                         cfg.moe_intermediate_size, cfg.expert_tile)
+        n = C * cfg.num_experts_per_tok // tile + E
+        assert n == 32
+        lowered = expert_stream.expert_tiles_mlp.lower(
+            arg((n * tile, D)), arg((n,), jnp.int32), scalar,
+            arg((E, D, 2 * F)), arg((E, F, D)), tile=tile,
+            act=expert_share.silu_gate, interpret=False)
+        out = f"f32[{n * tile},{D}]"
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text and kernel in text and out in text
+
+
+def test_the_latent_rewriters_programs_fit_beside_sdxl(chip, monkeypatch):
+    """Both language programs of ``zaya1-8b.ctx128k-sdxl8`` at the cell's
+    sizes (130 944 + 128 tokens = the published context, published widths,
+    10 layers, all 16 experts of each, the whole vocabulary): they compile
+    for the chip and leave room for SDXL's segment program (4.79 + 0.56 GiB)
+    in 15.75 GiB; the prefill holds TWO Pallas call sites a layer — one
+    ``gqa_causal_mha`` and the expert layer's tiles —; the K and V buffers
+    are written where they lie; nothing ``[chunk, rows]`` exists in float32;
+    and ``llm_decode`` holds no Pallas call. The peak is printed."""
+    from comfyui_distributed_tpu.models.llm_zaya import ZayaConfig
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    cfg = ZayaConfig.zaya_share()
+    compiled = loop_copies.compiled_programs(cfg, 130944, 128, chip)
+    gib, sdxl = 2.0 ** 30, 4.79 + 0.56
+    text = compiled["llm_prefill"].as_text()
+    layers = cfg.num_hidden_layers
+    assert len(_pallas_calls(text)) == 2 * layers == 20
+    for name in ("gqa_causal_mha", "expert_tiles_mlp"):
+        assert len(_pallas_calls(text, name)) == layers
+    rows, C = 131072, cfg.prefill_chunk_tokens
+    assert not re.findall(rf"f32\[(\d+,)?{C},{rows}\]", text)
+    buffer = f"bf16[{cfg.num_key_value_heads},{rows},{cfg.head_dim}]"
+    assert buffer in text
+    assert not [line for line in text.splitlines()
+                if buffer in line.split("=")[0] and " copy(" in line]
+    mem = compiled["llm_prefill"].memory_analysis()
+    prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                   + mem.output_size_in_bytes) / gib
+    text = compiled["llm_decode"].as_text()
+    assert "tpu_custom_call" not in text                   # decode is XLA
+    mem = compiled["llm_decode"].memory_analysis()
+    decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
+    print(f"zaya1-8b at 130944 + 128: llm_prefill {prefill_gib:.2f} GiB, "
+          f"llm_decode {decode_gib:.2f} GiB beside SDXL's {sdxl:.2f}")
+    assert 6.0 < prefill_gib < 6.8 and prefill_gib + sdxl < 15.75 - 2.0
+    assert 7.2 < decode_gib < 8.0 and decode_gib + sdxl < 15.75 - 2.0

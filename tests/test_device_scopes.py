@@ -218,7 +218,8 @@ LLMS = {"ling-tiny": ("llm_hybrid", "LLMConfig", 24),
         "longcat-tiny": ("llm_longcat", "LongcatConfig", 37),  # three chunks
         "sala-tiny": ("llm_sala", "SalaConfig", 40),   # past its dense_len
         "glm-tiny": ("llm_glm", "GlmConfig", 40),      # past its index_topk
-        "keye-tiny": ("llm_keye", "KeyeConfig", 40)}   # past its topk
+        "keye-tiny": ("llm_keye", "KeyeConfig", 40),   # past its topk
+        "zaya-tiny": ("llm_zaya", "ZayaConfig", 37)}   # a padded last chunk
 
 PROGRAMS = {"txt2img_seg": _txt2img_seg, "flow_seg": _flow_seg, "fin": _fin}
 for _name, _how in LLMS.items():
@@ -275,6 +276,11 @@ EXPECTED = {
     "llm_prefill:keye-tiny": {"llm_attn", "llm_router", "llm_experts",
                               "llm_head"},
     "llm_decode:keye-tiny": {"llm_attn", "llm_router", "llm_experts",
+                             "llm_head"},
+    # the router is an MLP: its products are llm_router's; no shared expert
+    "llm_prefill:zaya-tiny": {"llm_attn", "llm_router", "llm_experts",
+                              "llm_head"},
+    "llm_decode:zaya-tiny": {"llm_attn", "llm_router", "llm_experts",
                              "llm_head"},
 }
 
@@ -342,3 +348,28 @@ def test_the_index_selecting_rewriters_work_is_named_below_its_layer():
                 below[found[0]] = below.get(found[0], 0) + ops
         assert set(below) == {"llm_index", "llm_sparse_attn"} \
             and all(below.values()), below
+
+
+def test_the_latent_rewriters_work_is_named_below_its_layer():
+    """``llm_cca_mix`` (the grouped convolution's mixes: the only products
+    between the latent projections and the core) and ``llm_cca_core`` are
+    plain named scopes under ``cdt.llm_attn``: every product of the two is
+    under exactly one of them and under the one registered layer; the latent
+    and output projections are under the layer alone; the router's MLP is
+    ``cdt.llm_router``'s."""
+    plain = re.compile(r"/(llm_cca_mix|llm_cca_core)(?:/|$)")
+    for program in ("llm_prefill:zaya-tiny", "llm_decode:zaya-tiny"):
+        fn, args = PROGRAMS[program]()
+        seen = list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+        below, layers = {}, {}
+        for primitive, stack, ops in seen:
+            (layer,) = LAYER.findall(stack)
+            layers[layer] = layers.get(layer, 0) + 1
+            found = plain.findall(stack)
+            if found:
+                assert len(found) == 1 and layer == "llm_attn", stack
+                below[found[0]] = below.get(found[0], 0) + ops
+        assert set(below) == {"llm_cca_mix", "llm_cca_core"} \
+            and all(below.values()), below
+        # a layer's router: the down-projection, two hidden layers, the output
+        assert layers["llm_router"] >= 4 * 3

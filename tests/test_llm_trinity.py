@@ -347,8 +347,11 @@ def test_the_nodes_load_it_and_count_its_attended_keys():
     (same,) = TPUPromptRewrite().execute(llm, "a lighthouse at dusk", 11,
                                          prompt_tokens=21, new_tokens=6)
     assert words == same and len(words.split()) == 6
-    moved = {k: v - before.get(k, 0.0) for k, v in keys().items()}
     want = llm.pipeline.config.attended_keys(21, 6)
+    # another model's kinds of layer may stand in the registry (a test file
+    # that ran earlier in this process): they did not move
+    moved = {k: v - before.get(k, 0.0) for k, v in keys().items()}
+    moved = {k: d for k, d in moved.items() if d or k in want}
     assert moved == {k: 2.0 * v for k, v in want.items()}
     assert moved["window", "prefill"] < 4 * moved["full", "prefill"]
 
