@@ -120,14 +120,15 @@ class LLMPipeline:
 
     def generate(self, ids, new_tokens: int, seed: int,
                  temperature: float) -> dict:
-        """``ids`` (the prompt, host ints) → exactly ``new_tokens`` drawn
-        ids and what the programs say of themselves, fetched to the host
-        (the tap logits stay on the device)."""
+        """``ids`` (the prompt: any sequence of host ints, copied to the
+        device as it is where it is an int32 array) → exactly
+        ``new_tokens`` drawn ids and what the programs say of themselves,
+        fetched to the host (the tap logits stay on the device)."""
         prefill, decode = self.programs(len(ids), int(new_tokens))
         _, chunks, form = self.prefill_plan(len(ids))
         layers = len(self.config.moe_layers)
         logits, cache, slots_prefill, *rows = prefill(
-            jnp.asarray(ids, jnp.int32))
+            jnp.asarray(np.asarray(ids, np.int32)))
         # a chunked prefill counts the rows its experts multiplied (none
         # without an expert layer: a block-selecting model's chunks count
         # their sparse kernel's grid steps by fetch in that place); the

@@ -943,27 +943,36 @@ REWRITE_PREAMBLE = (
     "visual detail . do not add text , logos or watermarks . user prompt :")
 
 
-def rewrite_prompt_ids(text: str, prompt_tokens: int, vocab: int) -> list:
-    """Exactly ``prompt_tokens`` ids over ``[0, vocab)``: the fixed
-    preamble (cycled) and then the user's words, at most an eighth of the
-    prompt. A stand-in hash tokenizer, as ``models/text.py``'s: the
-    model's own tokenizer is not here."""
+def rewrite_prompt_array(text: str, prompt_tokens: int,
+                         vocab: int) -> np.ndarray:
+    """Exactly ``prompt_tokens`` ids over ``[0, vocab)``, an int32 array
+    (what the prefill program takes: no list of the prompt's length is
+    ever walked): the fixed preamble (cycled) and then the user's words,
+    at most an eighth of the prompt. A stand-in hash tokenizer, as
+    ``models/text.py``'s: the model's own tokenizer is not here."""
     from ..models.text import _stable_hash_token
 
     user = [_stable_hash_token(w, vocab)
             for w in str(text).lower().split()[:max(1, prompt_tokens // 8)]]
-    lead = _preamble_ids(vocab)
-    n = prompt_tokens - len(user)
-    return (lead * (n // len(lead) + 1))[:n] + user
+    lead = np.resize(_preamble_ids(vocab), prompt_tokens - len(user))
+    return np.concatenate([lead, np.asarray(user, np.int32)])
+
+
+def rewrite_prompt_ids(text: str, prompt_tokens: int, vocab: int) -> list:
+    """``rewrite_prompt_array``'s ids as a list, for the parity tools."""
+    return rewrite_prompt_array(text, prompt_tokens, vocab).tolist()
 
 
 @functools.lru_cache(maxsize=8)
-def _preamble_ids(vocab: int) -> list:
+def _preamble_ids(vocab: int) -> np.ndarray:
     """The preamble's words hashed once a vocabulary: a 32 k-token prompt
     cycles these ids, it does not hash 32 k words a request."""
     from ..models.text import _stable_hash_token
 
-    return [_stable_hash_token(w, vocab) for w in REWRITE_PREAMBLE.split()]
+    ids = np.array([_stable_hash_token(w, vocab)
+                    for w in REWRITE_PREAMBLE.split()], np.int32)
+    ids.setflags(write=False)       # cached: every request reads this one
+    return ids
 
 
 @register_node("TPUPromptRewrite")
@@ -994,7 +1003,7 @@ class TPUPromptRewrite(NodeDef):
                 f"prompt_tokens {prompt_tokens} / new_tokens {new_tokens}: "
                 "too few", field="prompt_tokens")
         with span("llm.tokenize", tokens=prompt_tokens):
-            ids = rewrite_prompt_ids(text, prompt_tokens, cfg.vocab_size)
+            ids = rewrite_prompt_array(text, prompt_tokens, cfg.vocab_size)
         with _pinned(llm):
             out = llm.pipeline.generate(ids, new_tokens, int(seed),
                                         float(temperature))

@@ -354,6 +354,34 @@ def test_the_pipeline_binds_two_labelled_programs_without_callbacks(params):
     assert out["held_prefill"].shape == out["held_decode"].shape == (6,)
 
 
+def test_generate_copies_any_sequence_of_ids_in_as_one_int32_array(params):
+    """A list, an int64 array and an int32 array of one prompt are one
+    request: the prefill program is handed an int32 array each time (no
+    ``convert_element_type`` runs ahead of it) and answers the same."""
+    pipe = pipeline_llm.LLMPipeline(CFG, params)
+    prefill, decode = pipe.programs(16, 8)
+    seen = []
+
+    def spy(ids):
+        seen.append(ids)
+        return prefill(ids)
+
+    pipe.programs = lambda n_prompt, n_new: (spy, decode)
+    prompt = [(7 * i + 3) % CFG.vocab_size for i in range(16)]
+    outs = [pipe.generate(form, 8, seed=5, temperature=0.7)
+            for form in (prompt, np.array(prompt, np.int64),
+                         np.array(prompt, np.int32))]
+    assert len(seen) == 3
+    for ids in seen:
+        assert isinstance(ids, jax.Array) and ids.dtype == jnp.int32
+        assert ids.shape == (16,) and ids.tolist() == prompt
+    for out in outs[1:]:
+        assert out["rows_prefill"] == outs[0]["rows_prefill"] > 0
+        for key in ("ids", "held_prefill", "held_decode", "prefill_logits"):
+            assert np.array_equal(np.asarray(out[key]),
+                                  np.asarray(outs[0][key])), key
+
+
 # --- registry, nodes, the shipped graph ---------------------------------------
 
 
@@ -389,6 +417,27 @@ def test_rewrite_prompt_ids_pads_the_preamble_to_the_exact_length():
     assert short[:448] == long[:448]                  # the fixed preamble
     assert short[-3:] != long[-3:]
     assert all(2 <= t < 19648 for t in short + long)
+
+
+def test_the_node_hands_generate_an_array_not_a_list(monkeypatch):
+    """A long brief's ids reach ``generate`` as the int32 array they were
+    built as: no list of the prompt's length stands between them."""
+    from comfyui_distributed_tpu.graph.nodes_builtin import (
+        LLMLoader, TPUPromptRewrite, rewrite_prompt_ids)
+
+    (llm,) = LLMLoader().execute("ling-tiny")
+    seen = []
+    real = llm.pipeline.generate
+    monkeypatch.setattr(
+        llm.pipeline, "generate",
+        lambda ids, *args: seen.append(ids) or real(ids, *args))
+    (text,) = TPUPromptRewrite().execute(llm, "a red fox", 3,
+                                         prompt_tokens=16, new_tokens=8)
+    (ids,) = seen
+    assert isinstance(ids, np.ndarray) and ids.dtype == np.int32
+    assert ids.tolist() == rewrite_prompt_ids("a red fox", 16,
+                                              CFG.vocab_size)
+    assert len(text.split()) == 8
 
 
 def _shipped_graph(tmp_path, seed):
@@ -622,3 +671,38 @@ def test_rewrite_prompt_ids_cycles_the_preamble_hashed_once():
     finally:
         nodes_builtin._preamble_ids = real
     assert calls == [20480]
+
+
+@pytest.mark.parametrize("text,n,vocab", [
+    ("a red fox jumps", 512, 19648), ("x " * 9000, 32768, 20480),
+    ("one", 5, 64), ("", 40, 64), ("x", 131072, 151936)])
+def test_rewrite_prompt_array_is_the_list_as_one_int32_array(text, n, vocab):
+    from comfyui_distributed_tpu.graph import nodes_builtin
+    from comfyui_distributed_tpu.models.text import _stable_hash_token
+
+    ids = nodes_builtin.rewrite_prompt_array(text, n, vocab)
+    assert isinstance(ids, np.ndarray) and ids.dtype == np.int32
+    assert ids.shape == (n,) and ids.flags.c_contiguous
+    assert ids.flags.writeable                 # the caller's own, not the cache
+    # the list form's ids, and the ids every word hashed by itself gives
+    lead = [_stable_hash_token(w, vocab)
+            for w in nodes_builtin.REWRITE_PREAMBLE.split()]
+    user = [_stable_hash_token(w, vocab)
+            for w in text.lower().split()][:max(1, n // 8)]
+    want = [lead[i % len(lead)] for i in range(n - len(user))] + user
+    assert ids.tolist() == want \
+        == nodes_builtin.rewrite_prompt_ids(text, n, vocab)
+
+
+def test_the_preamble_is_hashed_once_a_vocabulary_and_kept_as_an_array():
+    from comfyui_distributed_tpu.graph import nodes_builtin
+
+    nodes_builtin._preamble_ids.cache_clear()
+    for _ in range(3):
+        nodes_builtin.rewrite_prompt_array("a b c", 4096, 20480)
+    nodes_builtin.rewrite_prompt_ids("a b c", 4096, 19648)
+    info = nodes_builtin._preamble_ids.cache_info()
+    assert (info.misses, info.hits) == (2, 2)
+    lead = nodes_builtin._preamble_ids(20480)
+    assert lead.dtype == np.int32 and not lead.flags.writeable
+    assert len(lead) == len(nodes_builtin.REWRITE_PREAMBLE.split())
