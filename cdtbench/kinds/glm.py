@@ -12,8 +12,12 @@ the roofline shares divide by live here, with the benchmark —
 (``glm_sparse_core_mxu_pct``) and ``decode_bytes_per_token``
 (``glm_decode_hbm_pct``), each what the program MUST do by the model's
 rule, whatever implements it — and so do the cell's readers that are not
-plain data (``layer_metrics/glm_*.py`` only name one of them).
-``cdtbench/GLM.md`` derives the counts."""
+plain data (``layer_metrics/glm_*.py`` only name one of them). The prefill's
+counts are taken over the tokens the program says it ran in the TRACED
+request (``traced_moved``, ``prefilled_from``): a request whose prefix was
+kept from an earlier ask is counted from where its ``llm_prefill`` started,
+never over the whole prompt, and never as the mean of a window that also
+held a first ask. ``cdtbench/GLM.md`` derives the counts."""
 
 from __future__ import annotations
 
@@ -36,6 +40,7 @@ CORE_KERNEL = r"^index_masked_mha"
 SCOPES = ("llm_index", "llm_select", "llm_sparse_attn")
 KEYS = "cdt_llm_attn_keys_total"
 SLOTS = "cdt_llm_expert_slots_total"
+TOKENS = "cdt_llm_tokens_total"
 SECONDS = "cdt_pipeline_execute_seconds"
 
 
@@ -103,13 +108,22 @@ def selected_pairs(config: dict, first: int, last: int) -> float:
     return float(np.minimum(t + 1, config["index_topk"]).sum())
 
 
-def index_score_flops(config: dict, prompt_tokens: int) -> float:
-    """The indexer's scores in ONE prefill: every (query, key) pair with
-    ``key ≤ query`` once — ``T(T+1)/2`` a layer — times ``index_n_heads ·
+def causal_pairs(first: int, last: int) -> float:
+    """(query, key) pairs with ``key ≤ query`` for the queries at positions
+    ``first … last − 1``: ``(last(last+1) − first(first+1))/2``."""
+    return (last * (last + 1) - first * (first + 1)) / 2.0
+
+
+def index_score_flops(config: dict, prompt_tokens: int,
+                      first: int = 0) -> float:
+    """The indexer's scores in ONE prefill that runs the queries at
+    positions ``first … T − 1`` (``first`` > 0: a kept prefix, whose keys
+    are still scored against): every (query, key) pair with ``key ≤ query``
+    once — ``T(T+1)/2`` a layer from 0 — times ``index_n_heads ·
     index_head_dim · 2`` for the heads' products. The ReLU, the weights and
     the sum over heads are vector work; a masked half of a diagonal tile or
     a skipped tile's grid step is the kernel's cost, not its work."""
-    pairs = prompt_tokens * (prompt_tokens + 1) / 2.0
+    pairs = causal_pairs(first, prompt_tokens)
     return float(config["num_hidden_layers"] * pairs * 2
                  * config["index_n_heads"] * config["index_head_dim"])
 
@@ -132,8 +146,12 @@ def selected_pair_flops(config: dict, pairs: float,
 
 
 def prefill_flops(config: dict, prompt_tokens: int, pairs: float,
-                  held_slots: float) -> float:
-    """The algorithmic operations of ONE ``llm_prefill``: per layer the
+                  held_slots: float, first: int = 0) -> float:
+    """The algorithmic operations of ONE ``llm_prefill`` that runs the
+    tokens at positions ``first … T − 1`` (everything linear in the tokens
+    over ``T − first`` of them, the index scores over the causal pairs of
+    those queries; ``pairs`` and ``held_slots`` are the program's own
+    counts of what it ran): per layer the
     attention's and the indexer's projections (the latent decompressed ONCE
     a token), the index scores over the causal pairs, the SELECTED pairs
     ``pairs`` (a head, all layers together, as the program counted them) in
@@ -141,17 +159,17 @@ def prefill_flops(config: dict, prompt_tokens: int, pairs: float,
     shared expert and ``held_slots`` (one request's routed slots that fell
     on held experts, all expert layers together, as the program counted
     them) rows of one expert; the head on the last position."""
-    T, D = prompt_tokens, config["hidden_size"]
+    ran, D = prompt_tokens - first, config["hidden_size"]
     layers, dense = config["num_hidden_layers"], \
         config["first_k_dense_replace"]
     expert = 3 * D * config["moe_intermediate_size"]
-    total = layers * 2.0 * T * (attention_params(config)
-                                + indexer_params(config))
-    total += index_score_flops(config, T)
+    total = layers * 2.0 * ran * (attention_params(config)
+                                  + indexer_params(config))
+    total += index_score_flops(config, prompt_tokens, first)
     total += selected_pair_flops(config, pairs, absorbed=True)
-    total += dense * 2.0 * T * 3 * D * config["intermediate_size"]
-    total += (layers - dense) * 2.0 * T * (D * config["router_experts"]
-                                           + expert)
+    total += dense * 2.0 * ran * 3 * D * config["intermediate_size"]
+    total += (layers - dense) * 2.0 * ran * (D * config["router_experts"]
+                                             + expert)
     total += 2.0 * held_slots * expert
     total += 2.0 * config["vocab_size"] * D
     return float(total)
@@ -206,6 +224,53 @@ def _traced_program(ctx: dict, phase: str):
     return program if program and program["count"] else None
 
 
+def traced_moved(ctx: dict, series: str, match: dict):
+    """How far a counter moved A REQUEST over the traced request(s):
+    between the two snapshots ``run.py`` takes as the profiler starts and
+    as it stops (``ctx["traced"]``) — the counts that belong to the device
+    time the trace gives, whatever else the window held: a first ask beside
+    repeats of a kept brief is read as what it was, never as the window's
+    mean. None where the profile never closed. A ctx made by hand without
+    the key (``tests/test_llm_glm.py``) is read over its window, whose
+    requests are alike."""
+    from cdtbench.readers import total
+
+    span = ctx["traced"] if "traced" in ctx else {
+        k: ctx[k] for k in ("opened", "closed", "requests")}
+    if not span or not span["requests"]:
+        return None
+    cell = ctx["cell"]
+    return (total(span["closed"], series, match, "value", cell)
+            - total(span["opened"], series, match, "value", cell)) \
+        / span["requests"]
+
+
+def prefilled_from(ctx: dict):
+    """The position the traced request's ``llm_prefill`` started at: the
+    prompt's length less the tokens the program says it prefilled
+    (``traced_moved`` of ``cdt_llm_tokens_total{phase=prefill}``). None —
+    and every count over the range with it, said in the run's log — where
+    that is not a whole number of tokens within the prompt: never a guess.
+    Only a program that has no such series at all (every program before
+    the series; the hand-made snapshots of ``tests/test_llm_glm.py``) is
+    counted over its whole prompt, which is also said."""
+    from cdtbench.server import say, series
+
+    prompt_tokens = request_sizes(ctx["cell"])[0]
+    ran = traced_moved(ctx, TOKENS, {"phase": "^prefill$"})
+    if ran is None:
+        return None
+    if not series((ctx.get("traced") or ctx)["closed"], TOKENS):
+        say(f"no {TOKENS} series: the whole prompt is counted")
+        return 0
+    if ran != int(ran) or not 0 < ran <= prompt_tokens:
+        say(f"{TOKENS}{{phase=prefill}} moved {ran:g} a traced request of "
+            f"{prompt_tokens} prompt tokens: no count of what llm_prefill "
+            "ran, and none of its shares of a peak")
+        return None
+    return prompt_tokens - int(ran)
+
+
 def _kernel_seconds(ctx: dict, kernel: str) -> float:
     return sum(s for op, s in ctx["trace"]["op_seconds"].items()
                if re.search(kernel, op))
@@ -236,21 +301,24 @@ def decode_ms_per_token(ctx: dict):
 
 
 def prefill_mfu_pct(ctx: dict):
-    """``glm_prefill_mfu_pct``: ``prefill_flops`` (the selected pairs and
-    the held slots as the program counted them in the window, a request)
-    over the compute peak and the traced ``jit_llm_prefill``'s DEVICE
-    time: the whole program's share."""
+    """``glm_prefill_mfu_pct``: ``prefill_flops`` (the range, the selected
+    pairs and the held slots as the program counted them for the TRACED
+    request) over the compute peak and the traced ``jit_llm_prefill``'s
+    DEVICE time: the whole program's share."""
     from cdtbench.flops import peak_flops
 
     program = _traced_program(ctx, "llm_prefill")
-    if program is None or not ctx["requests"]:
+    if program is None:
         return None
-    pairs = moved(ctx, KEYS, {"layers": "^sparse$", "phase": "^prefill$"})
-    held = moved(ctx, SLOTS, {"phase": "^prefill$", "where": "^held$"})
-    if not pairs:
+    pairs = traced_moved(ctx, KEYS, {"layers": "^sparse$",
+                                     "phase": "^prefill$"})
+    held = traced_moved(ctx, SLOTS, {"phase": "^prefill$",
+                                     "where": "^held$"})
+    first = pairs and prefilled_from(ctx)
+    if not pairs or first is None:
         return None
     need = prefill_flops(ctx["cell"].config, request_sizes(ctx["cell"])[0],
-                         pairs / ctx["requests"], held / ctx["requests"])
+                         pairs, held, first)
     seconds = program["seconds"] / program["count"]
     return 100.0 * need / peak_flops(ctx["device"]["kind"]) / seconds
 
@@ -279,10 +347,11 @@ def index_mxu_pct(ctx: dict):
 
     program = _traced_program(ctx, "llm_prefill")
     seconds = program and _kernel_seconds(ctx, SCORE_KERNEL)
-    if not seconds:
+    first = seconds and prefilled_from(ctx)
+    if not seconds or first is None:
         return None
     need = program["count"] * index_score_flops(
-        ctx["cell"].config, request_sizes(ctx["cell"])[0])
+        ctx["cell"].config, request_sizes(ctx["cell"])[0], first)
     return 100.0 * need / peak_flops(ctx["device"]["kind"]) / seconds
 
 
@@ -295,28 +364,30 @@ def sparse_core_mxu_pct(ctx: dict):
 
     program = _traced_program(ctx, "llm_prefill")
     seconds = program and _kernel_seconds(ctx, CORE_KERNEL)
-    if not seconds:
+    first = seconds and prefilled_from(ctx)
+    if not seconds or first is None:
         return None
     config = ctx["cell"].config
     pairs = config["num_hidden_layers"] * selected_pairs(
-        config, 0, request_sizes(ctx["cell"])[0])
+        config, first, request_sizes(ctx["cell"])[0])
     need = program["count"] * selected_pair_flops(config, pairs)
     return 100.0 * need / peak_flops(ctx["device"]["kind"]) / seconds
 
 
 def selected_keys_pct(ctx: dict):
     """``glm_selected_keys_pct``: the (query, key) pairs the layers' heads
-    attended in the window (the program's counter) over the causal pairs of
-    the same queries: 100 the day the layer is served dense."""
+    attended in the traced request (the program's counter) over the causal
+    pairs of the same queries: 100 the day the layer is served dense."""
     cell = ctx["cell"]
-    if cell.config.get("kind") != KIND or not ctx["requests"]:
+    if cell.config.get("kind") != KIND:
         return None
-    seen = moved(ctx, KEYS, {"layers": "^sparse$"})
-    if not seen:
+    seen = traced_moved(ctx, KEYS, {"layers": "^sparse$"})
+    first = seen and prefilled_from(ctx)
+    if not seen or first is None:
         return None
-    total = sum(request_sizes(cell))
-    causal = cell.config["num_hidden_layers"] * total * (total + 1) / 2.0
-    return 100.0 * seen / ctx["requests"] / causal
+    causal = cell.config["num_hidden_layers"] * causal_pairs(
+        first, sum(request_sizes(cell)))
+    return 100.0 * seen / causal
 
 
 _scope_seconds: dict = {}

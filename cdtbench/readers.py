@@ -8,7 +8,11 @@ returns None and the metric is left out of the line.
 snapshots ``opened`` (when the window opens) and ``closed``, ``memory``
 (``/distributed/memory_stats`` after the window), the per-request
 ``records``, ``requests``, ``steps`` and ``images`` completed in the
-window, ``trace`` (the reduction, or None), ``step_flops`` and ``device``.
+window, ``trace`` (the reduction, or None), ``step_flops`` and ``device``;
+in a traced run also ``traced``: the two snapshots taken as the profiler
+started and stopped (``opened``, ``closed``) and the ``requests`` between
+them, or None where the profile never closed — the counts that belong to the
+traced programs' device time.
 """
 
 from __future__ import annotations

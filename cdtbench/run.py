@@ -40,6 +40,7 @@ from cdtbench.server import (BenchFailure, ROOT, census, read_image,  # noqa: E4
                              run_request, say, serve)
 
 EXIT_INCORRECT, EXIT_FAILED, EXIT_NO_DEVICE = 1, 2, 3
+METRICS = "/distributed/metrics.json"
 
 
 class NoDevice(Exception):
@@ -110,17 +111,20 @@ class Run:
         first = int(plan.get("skip_requests", 0))
         last = first + int(plan.get("requests", 1))
         if self.tracing is None and index == first:
+            opened = self.server.request(METRICS)["metrics"]
             t0 = time.time()
             self.server.request("/distributed/profile/start",
                                 {"out": "trace"})
-            self.tracing = {"start_wall": (t0, time.time()), "first": index}
+            self.tracing = {"start_wall": (t0, time.time()), "first": index,
+                            "opened": opened}
         elif self.tracing is not None and "stop_wall" not in self.tracing \
                 and index >= last:
             t0 = time.time()
             self.server.request("/distributed/profile/stop", {},
                                 timeout=300.0)
             self.tracing.update(stop_wall=(t0, time.time()),
-                                requests=index - self.tracing["first"])
+                                requests=index - self.tracing["first"],
+                                closed=self.server.request(METRICS)["metrics"])
 
     def window(self) -> list[dict]:
         traffic = self.cell.traffic
@@ -257,11 +261,11 @@ def main(argv=None) -> int:
             run = Run(cell, server, out_dir, args.seed, seconds,
                       bool(args.trace))
             run.warm_up()
-            opened = server.request("/distributed/metrics.json")["metrics"]
+            opened = server.request(METRICS)["metrics"]
             setup_s = time.monotonic() - T_START
             say(f"set-up done in {setup_s:.1f} s; the window opens")
             records = run.window()
-            closed = server.request("/distributed/metrics.json")["metrics"]
+            closed = server.request(METRICS)["metrics"]
             memory = server.request("/distributed/memory_stats")
             (out_dir / "metrics_close.json").write_text(json.dumps(closed))
             (out_dir / "memory_stats.json").write_text(json.dumps(memory))
@@ -315,6 +319,11 @@ def main(argv=None) -> int:
             traced = run.tracing or {}
             window_s = (traced["stop_wall"][0] - traced["start_wall"][1]
                         if "stop_wall" in traced else None)
+            # the counters as they stood around the TRACED request(s): what
+            # a reader divides the traced programs' device time into
+            ctx["traced"] = {k: traced[k] for k in
+                             ("opened", "closed", "requests")} \
+                if traced.get("requests") else None
             post = reduce_trace(cell, out_dir, window_s)
             for note in post["notes"]:
                 say(f"post: {note}")
