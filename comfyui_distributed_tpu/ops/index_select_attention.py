@@ -36,14 +36,14 @@ and the tests' yardstick) and the form a TPU runs:
   ``c W_v`` out in the layout the next kernel reads, float32 in VMEM and
   rounded once; rows past the chunk's end are never written, so nothing
   initialises the workspace — and runs a blocked softmax kernel under
-  the mask (``index_masked_mha``: the schedule of ``flash_latent.py`` with
-  the byte mask in place of the diagonal, a key ``[k_nope | k_rope]`` and a
-  value of their own widths). A query's keys are its own — with seeded
-  weights the union over a tile of neighbours is the whole prefix — so
-  there is no tile to skip below the diagonal and a list of rows to GATHER
-  is 1152 bytes a row, 132 M rows a layer at 65 536 tokens: the mask form
-  is what a v5e can run (PERF.md §6, PR 51). Decode (:func:`index_step`,
-  :func:`absorbed_rows_step`) is one row: ``lax.top_k`` of its scores, the
+  the mask (``index_masked_mha``: ``flash_latent.py``'s schedule, the byte
+  mask for the diagonal, keys and values of their own widths; PR 60: a K
+  tile by parts, a grid as far as the chunk sees). A query's keys are its
+  own — with seeded weights the union over a tile of neighbours is the
+  whole prefix — so there is no tile to skip below the diagonal and a list
+  of rows to GATHER is 1152 bytes a row, 132 M rows a layer at 65 536
+  tokens: the mask form is what a v5e can run (PERF.md §6, PR 51). Decode
+  (:func:`index_step`, :func:`absorbed_rows_step`) is one row: ``top_k``, the
   ``topk`` latent rows gathered, ``W_b`` absorbed into query and output as
   ``latent_attention.mla_absorbed_step`` does over the whole cache.
 
@@ -72,14 +72,14 @@ CAUSAL_TIER_REASONS.setdefault(
 
 _VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 _INT_MIN = -2 ** 31
-# the tiles of the four kernels at the served sizes (a chunk of 4096
-# queries, 128-wide index heads, 256-wide keys and values), fixed by
-# measurement (PERF.md §6, PRs 51, 52 and 54); a smaller call takes what
-# divides it
+# the tiles of the four kernels at the served sizes (a chunk of 4096 queries,
+# 128-wide index heads, 256-wide keys and values), fixed by measurement
+# (PERF.md §6, PRs 51, 52, 54 and 60); a smaller call takes what divides it
 INDEX_TILE = (256, 1024)      # (queries, keys) of a score step
 SELECT_ROWS = 64              # rows whose scores stay in VMEM together
 SELECT_TILE = 4096            # columns of them a turn of a pass's loop counts
-CORE_TILE = (1024, 1024)      # (queries, keys) of an attention step
+CORE_TILE = (2048, 2048)      # (queries, keys) of an attention step
+CORE_PART = 512               # keys of it multiplied ahead of their softmax
 HEADS_PER_PASS = 8            # heads decompressed into the workspace at once
 FILL_ROWS = 1024              # workspace rows a fill step decompresses
 
@@ -395,45 +395,60 @@ def masked_attention_lax(q, k, v, keep, dtype):
 
 
 def _masked_kernel(start_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, m_ref,
-                   l_ref, acc_ref, *, block_q: int, block_k: int,
+                   l_ref, acc_ref, *, block_q: int, block_k: int, part: int,
                    num_k_blocks: int, precision):
     i, j = pl.program_id(1), pl.program_id(2)
     last = _last_block(start_ref[0], i, block_q, block_k, num_k_blocks)
     _init_running(j, m_ref, l_ref, acc_ref)
 
-    @pl.when(j <= last)
-    def _step():
-        s = jax.lax.dot_general(q_ref[...], k_ref[...],
+    def logits(n: int):
+        at = slice(n * part, (n + 1) * part)
+        s = jax.lax.dot_general(q_ref[...], k_ref[at],
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32,
                                 precision=precision)
         # a row that has kept nothing yet carries exp(0) sums of its
         # masked logits; the first kept key's rescale wipes them (every
         # row keeps at least one key)
-        s = jnp.where(keep_ref[...].astype(jnp.int32) != 0, s, NEG_INF)
-        _accumulate(s, v_ref[...], m_ref, l_ref, acc_ref, precision)
+        return jnp.where(keep_ref[:, at].astype(jnp.int32) != 0, s, NEG_INF)
 
-    @pl.when(j == num_k_blocks - 1)
+    @pl.when(j <= last)
+    def _step():
+        # the K tile ``part`` keys at a time, in their order, the next
+        # part's logit product set out before this part's softmax: the
+        # vector work of one lies under the matrix products of the other
+        s = logits(0)
+        for n in range(block_k // part):
+            ahead = logits(n + 1) if (n + 1) * part < block_k else None
+            _accumulate(s, v_ref[n * part:(n + 1) * part], m_ref, l_ref,
+                        acc_ref, precision)
+            s = ahead
+
+    # the grid's K axis reaches at least this far and may end here
+    @pl.when(j == last)
     def _finalize():
         o_ref[...] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("num_heads", "block_q",
-                                             "block_k", "interpret"))
-def index_masked_mha(q, k, v, keep, start, num_heads: int, block_q: int,
-                     block_k: int, interpret: bool):
-    """``q`` [C, H·dk] times the softmax scale, ``k`` [S, H·dk], ``v``
-    [S, H·dv], ``keep`` [C,S] int8 (it holds the causal rule: nothing past
-    a query's position is kept), ``start`` the first query's position
-    (traced: key tiles wholly past a query tile are neither fetched nor
-    computed). ``C % block_q == 0``, ``S % block_k == 0``. Answers
-    [C, H·dv]."""
+def core_k_steps(start, chunk: int, block_k: int, num_k_blocks: int):
+    """K blocks the attention kernel's grid walks for a chunk of ``chunk``
+    queries at positions ``start …``: as far as the chunk's LAST row sees —
+    a query tile's steps past its own last block are the diagonal's few,
+    never the rest of a padded cache."""
+    return jnp.minimum((start + chunk + block_k - 1) // block_k,
+                       num_k_blocks)
+
+
+def masked_mha_call(q, k, v, keep, start, k_steps, num_heads: int,
+                    block_q: int, block_k: int, part: int, interpret: bool):
+    """:func:`index_masked_mha` over a grid of ``k_steps`` K blocks: an int
+    or a traced scalar that covers every query tile's last visible block."""
     C, S = q.shape[0], k.shape[0]
     H = num_heads
     dk, dv = q.shape[1] // H, v.shape[1] // H
     nq, nk = C // block_q, S // block_k
     kernel = functools.partial(_masked_kernel, block_q=block_q,
-                               block_k=block_k, num_k_blocks=nk,
+                               block_k=block_k, part=part, num_k_blocks=nk,
                                precision=_precision_of(q.dtype))
 
     def seen(i, j, start_ref):
@@ -442,7 +457,7 @@ def index_masked_mha(q, k, v, keep, start, num_heads: int, block_q: int,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(H, nq, nk),
+        grid=(H, nq, k_steps),
         in_specs=[
             pl.BlockSpec((block_q, dk), lambda h, i, j, s: (i, h)),
             pl.BlockSpec((block_k, dk),
@@ -462,6 +477,23 @@ def index_masked_mha(q, k, v, keep, start, num_heads: int, block_q: int,
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(jnp.reshape(start, (1,)).astype(jnp.int32), q, k, v, keep)
+
+
+@functools.partial(jax.jit, static_argnames=("num_heads", "block_q",
+                                             "block_k", "part", "interpret"))
+def index_masked_mha(q, k, v, keep, start, num_heads: int, block_q: int,
+                     block_k: int, part: int, interpret: bool):
+    """``q`` [C, H·dk] times the softmax scale, ``k`` [S, H·dk], ``v``
+    [S, H·dv], ``keep`` [C,S] int8 (it holds the causal rule: nothing past
+    a query's position is kept), ``start`` the first query's position
+    (traced: key tiles wholly past a query tile are neither fetched nor
+    computed, and the grid's K axis ends where the chunk's last row sees:
+    :func:`core_k_steps`). ``C % block_q == 0``, ``S % block_k == 0``,
+    ``block_k % part == 0``. Answers [C, H·dv]."""
+    steps = core_k_steps(jnp.asarray(start, jnp.int32), q.shape[0], block_k,
+                         k.shape[0] // block_k)
+    return masked_mha_call(q, k, v, keep, start, steps, num_heads, block_q,
+                           block_k, part, interpret)
 
 
 def _fill_kernel(n_ref, c_ref, kr_ref, wk_ref, wv_ref, k_ref, v_ref, *,
@@ -579,6 +611,7 @@ def masked_chunk_attention(q_nope, q_rope, c_cache, kr_cache, keep, start,
     # a key tile divides the chunk: no tile the attention kernel fetches
     # holds a row the fill has not written
     bq, bk = math.gcd(C, CORE_TILE[0]), math.gcd(C, CORE_TILE[1])
+    part = math.gcd(bk, CORE_PART)
     if kernel == "pallas":
         note_causal("index_select", H, nope + rope, C, S, dtype, bq, bk)
     q = (jnp.concatenate([q_nope, q_rope], -1) * scale).astype(dtype)
@@ -593,9 +626,11 @@ def masked_chunk_attention(q_nope, q_rope, c_cache, kr_cache, keep, start,
             c_cache, kr_wide, wk_g, wv_g, n_rows, num_heads=g, nope=nope,
             block_rows=math.gcd(C, FILL_ROWS),
             interpret=kernel == "interpret")
-        return index_masked_mha(q_g, k_ws, v_ws, keep, start, num_heads=g,
-                                block_q=bq, block_k=bk,
-                                interpret=kernel == "interpret")
+        # the barrier keeps the kernel a call of its own: fused into the
+        # write of the group's rows it loses its VMEM limit (16 MiB, 20 needed)
+        return jax.lax.optimization_barrier(index_masked_mha(
+            q_g, k_ws, v_ws, keep, start, num_heads=g, block_q=bq,
+            block_k=bk, part=part, interpret=kernel == "interpret"))
 
     o = jax.lax.map(one_group, (q, w_k, w_v))              # [H/g, C, g·v]
     return jnp.swapaxes(o, 0, 1).reshape(C, H, v)

@@ -26,7 +26,16 @@ cache of 69 632 rows, bfloat16):
   bfloat16 product with the rope key added in its fusion) — and beside the
   product's floor (16.35 TFLOP a layer at the chip's 197 TFLOP/s);
 - ``core``: ``index_masked_mha`` over ``--heads`` heads of 256/256 under a
-  mask of 2048 random kept keys a row, over ``--core-tiles``, with the
+  mask of 2048 random kept keys a row, over ``--core-tiles`` (``BQxBK``, or
+  ``BQxBK/PART``: the K tile's logits in products of ``PART`` keys, the
+  next one set out ahead of a part's softmax) and, a tile, over the K
+  extents of ``--core-extents`` — the grid's K axis ending where
+  the chunk's last row sees (``chunk``: the shipped form, PR 60), at the
+  nearest of four static lengths picked from the position by ``lax.switch``
+  (``quarters``), or walking the whole padded cache at every chunk
+  (``whole``: what shipped up to PR 59) — each with the grid steps that
+  compute and the ones that do nothing (:func:`core_grid_steps`), and from
+  two extents of one tile what ONE step of each kind costs; then the
   workspace fill it needs (``masked_chunk_attention`` whole, 64 heads);
 - ``gather``: the form that reads a LIST of rows instead of a mask, for
   ``--gather-rows`` queries: ``lax.top_k`` of their scores (the sort),
@@ -38,6 +47,7 @@ position stands for the chunks nearest it), seconds a layer.
 
     python scripts/index_select_sweep.py [--positions 0,7,15] [--reps 3]
         [--parts index,select,fill,core,gather]
+        [--core-positions all]
         [--out chiprun_out/tile_sweep]
 
 Run on the chip, as the one process that owns it. It fails without a TPU: a
@@ -86,6 +96,20 @@ def timed_in_place(fn, buffers: list, *args, reps: int):
     for _ in range(reps):
         t0 = time.perf_counter()
         buffers[:] = jax.block_until_ready(fn(*buffers, *args))[:n]
+        best = min(best, time.perf_counter() - t0)
+    return best
+
+
+def timed_queue(fn, *args, calls: int, reps: int):
+    """Seconds ``calls`` calls of ``fn`` take set out one behind the other
+    (the chip is waited for once, so a short call is not its dispatch)."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready([fn(*args) for _ in range(calls)])
         best = min(best, time.perf_counter() - t0)
     return best
 
@@ -231,6 +255,72 @@ def fill_as_xla(k_ws, v_ws, c, kr_wide, w_k, w_v, n_fill):
     return jax.lax.fori_loop(0, n_fill, fill, (k_ws, v_ws))
 
 
+CORE_EXTENTS = ("whole", "quarters", "chunk")
+
+
+def quarter_lengths(num_k_blocks: int) -> list:
+    """The four static K extents of the ``quarters`` form."""
+    return [-(-num_k_blocks * n // 4) for n in (1, 2, 3, 4)]
+
+
+def core_grid_steps(chunk: int, cache_rows: int, chunks: int, block_q: int,
+                    block_k: int, extent: str) -> tuple:
+    """``(visible, skipped)``: the grid steps ONE head's attention kernel
+    takes over a prefill of ``chunks`` chunks of ``chunk`` queries against a
+    cache of ``cache_rows`` rows at a ``block_q`` × ``block_k`` tile — those
+    that multiply a K block (a query tile's blocks up to its last row's) and
+    those past them that do nothing — by how far the grid's K axis goes."""
+    nk = cache_rows // block_k
+    visible = skipped = 0
+    for start in range(0, chunks * chunk, chunk):
+        reach = min(-(-(start + chunk) // block_k), nk)
+        steps = {"whole": nk, "chunk": reach,
+                 "quarters": min(n for n in quarter_lengths(nk)
+                                 if n >= reach)}[extent]
+        for first in range(start, start + chunk, block_q):
+            seen = min((first + block_q - 1) // block_k, nk - 1) + 1
+            visible, skipped = visible + seen, skipped + steps - seen
+    return visible, skipped
+
+
+def core_tile(text: str) -> tuple:
+    """``BQxBK`` or ``BQxBK/PART`` → ``(block_q, block_k, part)``; no part:
+    the whole K tile's logits in one product (the plain step)."""
+    tile, _, part = text.partition("/")
+    block_q, block_k = (int(x) for x in tile.split("x"))
+    return block_q, block_k, int(part or block_k)
+
+
+def core_form(extent: str, num_heads: int, block_q: int, block_k: int,
+              part: int, interpret: bool = False):
+    """``f(q, k, v, keep, start)``: the attention kernel with its grid's K
+    axis as ``extent`` says (``chunk`` is ``ops.index_masked_mha`` itself)."""
+    import jax
+    import jax.numpy as jnp
+
+    from comfyui_distributed_tpu.ops import index_select_attention as ops
+
+    tile = dict(num_heads=num_heads, block_q=block_q, block_k=block_k,
+                part=part, interpret=interpret)
+    if extent == "chunk":
+        return lambda *a: ops.index_masked_mha(*a, **tile)
+
+    def over(steps: int):
+        return lambda *a: ops.masked_mha_call(*a, steps, **tile)
+
+    def form(q, k, v, keep, start):
+        nk = k.shape[0] // block_k
+        if extent == "whole":
+            return over(nk)(q, k, v, keep, start)
+        lengths = quarter_lengths(nk)
+        reach = ops.core_k_steps(start, q.shape[0], block_k, nk)
+        return jax.lax.switch(
+            jnp.searchsorted(jnp.asarray(lengths), reach),
+            [over(n) for n in lengths], q, k, v, keep, start)
+
+    return jax.jit(form)
+
+
 def over_prefill(by_position: dict) -> float:
     """Seconds a layer: every chunk takes its nearest sampled position's."""
     at = sorted(by_position)
@@ -247,7 +337,16 @@ def main(argv=None) -> int:
     parser.add_argument("--index-tiles", default="256x1024,512x1024,256x2048")
     parser.add_argument("--select-rows", default="32,64")
     parser.add_argument("--select-tiles", default="2048,4096")
-    parser.add_argument("--core-tiles", default="1024x1024,2048x1024,1024x2048")
+    parser.add_argument("--core-tiles", default="1024x1024,2048x1024,"
+                        "4096x1024,2048x2048,4096x512,1024x2048,"
+                        "1024x1024/512,1024x2048/1024,1024x2048/512,"
+                        "2048x2048/1024,2048x2048/512,1024x4096/1024",
+                        help="BQxBK[/PART]: a query tile, a K tile and the "
+                             "keys of it whose logits are one product")
+    parser.add_argument("--core-extents", default=",".join(CORE_EXTENTS))
+    parser.add_argument("--core-positions", default="all",
+                        help="'all' 16 chunks (the skipped steps differ at "
+                             "every one) or a list as --positions")
     parser.add_argument("--heads", type=int, default=8)
     parser.add_argument("--gather-rows", type=int, default=256)
     parser.add_argument("--out", default="chiprun_out/tile_sweep")
@@ -409,22 +508,57 @@ def main(argv=None) -> int:
         q = jax.random.normal(keys[3], (C, g * DK), bf) / 16.0
         k = jax.random.normal(keys[4], (S, g * DK), bf)
         v = jax.random.normal(keys[5], (S, g * DV), bf)
-        for bq, bk in tiles(args.core_tiles):
-            by = {}
-            for p in positions:
-                seen = (p + 1) * C
-                keep = (jax.random.uniform(keys[6], (C, S))
-                        < TOPK / seen).astype(jnp.int8)
-                keep = keep * (jnp.arange(S)[None, :]
-                               <= p * C + jnp.arange(C)[:, None])
-                keep = keep.at[:, 0].set(1).astype(jnp.int8)
-                by[p] = (H // g) * timed(lambda s: ops.index_masked_mha(
-                    q, k, v, keep, s, num_heads=g, block_q=bq, block_k=bk,
-                    interpret=False), jnp.int32(p * C), reps=args.reps)
-            report[f"core.{bq}x{bk}"] = {"by_position": by,
-                                         "layer_s": over_prefill(by)}
-            print(f"[sweep] core {bq}x{bk} (x{H // g}: 64 heads): {by} "
-                  f"layer {over_prefill(by):.3f} s", flush=True)
+        at = list(range(CHUNKS)) if args.core_positions == "all" \
+            else [int(p) for p in args.core_positions.split(",")]
+        core_tiles = [core_tile(t) for t in args.core_tiles.split(",")]
+        forms = {(tile, extent): core_form(extent, g, *tile)
+                 for tile in core_tiles
+                 for extent in args.core_extents.split(",")}
+        by = {form: {} for form in forms}
+        for p in at:             # a position's mask once for all the forms
+            keep = (jax.random.uniform(keys[6], (C, S))
+                    < TOPK / ((p + 1) * C)).astype(jnp.int8)
+            keep = keep * (jnp.arange(S)[None, :]
+                           <= p * C + jnp.arange(C)[:, None])
+            keep = keep.at[:, 0].set(1).astype(jnp.int8)
+            for form, call in forms.items():
+                by[form][p] = timed_queue(
+                    call, q, k, v, keep, jnp.int32(p * C), calls=H // g,
+                    reps=args.reps)
+            del keep
+        layer = {}
+        for (tile, extent), by_p in by.items():
+            bq, bk, part = tile
+            name = f"{bq}x{bk}/{part}"
+            visible, skipped = (H * n for n in core_grid_steps(
+                C, S, CHUNKS, bq, bk, extent))
+            layer[tile, extent] = (over_prefill(by_p), visible, skipped)
+            report[f"core.{name}.{extent}"] = {
+                "by_position": by_p, "layer_s": layer[tile, extent][0],
+                "visible_steps": visible, "skipped_steps": skipped}
+            print(f"[sweep] core {name} {extent} (x{H // g}: 64 heads): "
+                  f"layer {layer[tile, extent][0]:.4f} s, {visible} "
+                  f"visible + {skipped} skipped steps; by position "
+                  f"{ {p: round(t, 4) for p, t in by_p.items()} }",
+                  flush=True)
+        if args.core_positions == "all":
+            # two extents of one tile differ in skipped steps alone
+            for tile in core_tiles:
+                if not {(tile, "whole"), (tile, "chunk")} <= set(layer):
+                    continue
+                (t_w, visible, s_w), (t_c, _, s_c) = (
+                    layer[tile, "whole"], layer[tile, "chunk"])
+                bq, bk, part = tile
+                skip_us = 1e6 * (t_w - t_c) / (s_w - s_c)
+                step_us = (1e6 * t_c - skip_us * s_c) / visible
+                peak_us = 1e6 * bq * bk * (DK + DV) * 2 / PEAK_FLOPS
+                report[f"core.{bq}x{bk}/{part}.step_us"] = {
+                    "skipped": skip_us, "visible": step_us,
+                    "visible_at_peak": peak_us}
+                print(f"[sweep] core {bq}x{bk}/{part}: a skipped step "
+                      f"{skip_us:.3f} us, a visible one {step_us:.3f} us "
+                      f"({100 * peak_us / step_us:.1f}% of the matrix "
+                      f"units' peak)", flush=True)
         # whole, with the workspace fill: the op the model calls
         q_nope = jax.random.normal(keys[7], (C, H, DK - ROPE), bf)
         q_rope = jax.random.normal(keys[8], (C, H, ROPE), bf)

@@ -881,7 +881,7 @@ def test_the_index_selecting_kernels_compile_at_the_served_geometry(chip,
             arg((C, g * d)), arg((S, g * d)), arg((S, g * d)),
             arg((C, S), jnp.int8), start, num_heads=g,
             block_q=ops.CORE_TILE[0], block_k=ops.CORE_TILE[1],
-            interpret=False)
+            part=ops.CORE_PART, interpret=False)
         out = f"bf16[{C},{g * d}]"
     else:
         lowered = ops.index_fill_kv.lower(

@@ -270,6 +270,15 @@ def test_over_a_64k_brief_the_selection_searches_half_the_caches_columns(
     assert len(set(tiny.select_columns(40, 8).values())) == 1
 
 
+def _sweep():
+    spec = importlib.util.spec_from_file_location(
+        "index_select_sweep", Path(__file__).resolve().parent.parent
+        / "scripts" / "index_select_sweep.py")
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    return sweep
+
+
 @pytest.mark.parametrize("form", ["copied tiles", "whole rows"])
 @pytest.mark.parametrize("case", list(_CHUNKS))
 def test_the_sweeps_forms_that_did_not_ship_select_the_same_keys(case, form):
@@ -277,11 +286,7 @@ def test_the_sweeps_forms_that_did_not_ship_select_the_same_keys(case, form):
     the scores left in HBM (a step copies in the tiles its rows see) and
     against PR 51's whole rows: both are the plain form's mask bit for bit,
     or the comparison is of different work."""
-    spec = importlib.util.spec_from_file_location(
-        "index_select_sweep", Path(__file__).resolve().parent.parent
-        / "scripts" / "index_select_sweep.py")
-    sweep = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sweep)
+    sweep = _sweep()
     scores, first, tile = _scores(case)
     for topk in (7, 64):
         if form == "copied tiles":
@@ -355,9 +360,112 @@ def test_the_masked_kernel_is_a_softmax_over_the_kept_keys(start):
     want = ops.masked_attention_lax(q, k, v, keep, jnp.float32)
     got = ops.index_masked_mha(
         q.reshape(C, -1), k.reshape(S, -1), v.reshape(S, -1), keep, start,
-        num_heads=H, block_q=8, block_k=16, interpret=True)
+        num_heads=H, block_q=8, block_k=16, part=16, interpret=True)
     assert np.allclose(np.asarray(got).reshape(C, H, dv), np.asarray(want),
                        atol=1e-5)
+
+
+_CORE_CHUNKS = {"first chunk": 0, "middle chunk": 32, "padded last chunk": 96}
+
+
+def _core_case(start: int, C: int = 32, H: int = 2, dk: int = 16,
+               dv: int = 24, S: int = 128, topk: int = 24):
+    """A chunk of 32 queries at ``start`` over a cache of four chunks, its
+    mask the selection's own (the first chunk's rows have fewer keys than
+    ``topk``: they keep every one): ``(q, k, v, keep, want [C,H,dv])``."""
+    keys = jax.random.split(jax.random.key(9), 4)
+    q = jax.random.normal(keys[0], (C, H, dk)) / 4
+    k = jax.random.normal(keys[1], (S, H, dk))
+    v = jax.random.normal(keys[2], (S, H, dv))
+    keep = ops.select_keep_lax(jax.random.normal(keys[3], (C, S)), start,
+                               topk)
+    kept = np.asarray(keep).sum(1)
+    assert (kept == np.minimum(start + np.arange(C) + 1, topk)).all()
+    want = ops.masked_attention_lax(q, k, v, keep, jnp.float32)
+    return (q.reshape(C, -1), k.reshape(S, -1), v.reshape(S, -1), keep,
+            np.asarray(want))
+
+
+@pytest.mark.parametrize("tile", [(16, 16, 8), (32, 32, 8), (32, 16, 16)],
+                         ids=["below the chunk", "the chunk",
+                              "a whole K tile a product"])
+@pytest.mark.parametrize("chunk", list(_CORE_CHUNKS))
+def test_the_masked_kernel_by_parts_of_a_k_tile_under_a_grid_that_follows_the_chunk(
+        chunk, tile):
+    """``index_masked_mha`` at a query tile below the chunk and at the
+    chunk, a K tile's logits in products of ``part`` keys (PR 60), for the
+    first chunk, a middle one and the last of a cache of whole chunks: the
+    plain softmax over the kept keys — and the rows past the chunk's end,
+    which the fill never writes, are never read: they hold NaN."""
+    start = _CORE_CHUNKS[chunk]
+    q, k, v, keep, want = _core_case(start)
+    C, (bq, bk, part) = q.shape[0], tile
+    got = ops.index_masked_mha(
+        q, k.at[start + C:].set(jnp.nan), v.at[start + C:].set(jnp.nan),
+        keep, start, num_heads=2, block_q=bq, block_k=bk, part=part,
+        interpret=True)
+    assert np.allclose(np.asarray(got).reshape(want.shape), want, atol=1e-5)
+
+
+@pytest.mark.parametrize("C,S,tile", [
+    (4096, 69632, ops.CORE_TILE), (4096, 69632, (1024, 1024)),
+    (4096, 69632, (4096, 512)), (32, 128, (16, 16)), (32, 160, (8, 32)),
+    (16, 48, (16, 16))])
+def test_the_cores_grid_ends_where_the_chunks_last_row_sees(C, S, tile):
+    """``core_k_steps``: every chunk's K extent covers each of its query
+    tiles' last visible block, ends AT the last tile's, and the last chunk
+    of a cache of whole chunks takes the whole grid."""
+    from comfyui_distributed_tpu.ops.flash_latent import _last_block
+
+    bq, bk = tile
+    nk = S // bk
+    for start in range(0, S, C):
+        steps = int(ops.core_k_steps(start, C, bk, nk))
+        last = [int(_last_block(start, i, bq, bk, nk))
+                for i in range(C // bq)]
+        assert max(last) == steps - 1 and steps <= nk, start
+    assert int(ops.core_k_steps(S - C, C, bk, nk)) == nk
+    assert int(ops.core_k_steps(0, C, bk, nk)) == -(-C // bk)
+
+
+@pytest.mark.parametrize("tile,extent,visible,skipped", [
+    ((1024, 1024), "whole", 665_600, 727_040),
+    ((2048, 1024), "whole", 337_920, 358_400),
+    ((4096, 1024), "whole", 174_080, 174_080),
+    ((1024, 1024), "quarters", 665_600, 204_800),
+    ((2048, 1024), "quarters", 337_920, 97_280),
+    ((1024, 1024), "chunk", 665_600, 30_720),
+    ((2048, 1024), "chunk", 337_920, 10_240),
+    ((4096, 1024), "chunk", 174_080, 0),
+    ((2048, 2048), "whole", 168_960, 179_200),
+    ((2048, 2048), "chunk", 168_960, 5_120)])
+def test_the_sweep_counts_the_cores_grid_steps_as_issue_60_did(
+        tile, extent, visible, skipped):
+    """``scripts/index_select_sweep.core_grid_steps`` at the cell's geometry
+    (16 chunks of 4096 against 69 632 rows), times 5 layers × 64 heads: the
+    steps that multiply a K block and the ones that do nothing, by tile and
+    by how far the grid's K axis goes."""
+    sweep = _sweep()
+    assert (sweep.C, sweep.S, sweep.CHUNKS, sweep.H) == (4096, 69632, 16, 64)
+    got = sweep.core_grid_steps(sweep.C, sweep.S, sweep.CHUNKS, *tile, extent)
+    assert tuple(5 * sweep.H * n for n in got) == (visible, skipped)
+    assert sweep.quarter_lengths(68) == [17, 34, 51, 68]
+    assert sweep.core_tile("2048x2048/512") == (2048, 2048, 512)
+    assert sweep.core_tile("2048x1024") == (2048, 1024, 1024)
+
+
+@pytest.mark.parametrize("extent", ["whole", "quarters", "chunk"])
+@pytest.mark.parametrize("chunk", list(_CORE_CHUNKS))
+def test_the_sweeps_k_extents_attend_alike(chunk, extent):
+    """The sweep times the shipped grid (``chunk``) against the whole padded
+    cache at every chunk and against four static lengths picked by
+    ``lax.switch``: the same attention, or the comparison is of different
+    work."""
+    start = _CORE_CHUNKS[chunk]
+    q, k, v, keep, want = _core_case(start)
+    got = _sweep().core_form(extent, 2, 16, 16, 8, interpret=True)(
+        q, k, v, keep, jnp.int32(start))
+    assert np.allclose(np.asarray(got).reshape(want.shape), want, atol=1e-5)
 
 
 @pytest.mark.parametrize("heads_per_pass", [2, 3])
