@@ -980,7 +980,12 @@ class TPUPromptRewrite(NodeDef):
     """Rewrite a prompt with a language model ahead of ``CLIPTextEncode``:
     ``prompt_tokens`` in, exactly ``new_tokens`` sampled (no stop token),
     as words. Two programs a call (``diffusion/pipeline_llm.py``); fails
-    on a non-finite logit or an id outside the model's vocabulary slice."""
+    on a non-finite logit or an id outside the model's vocabulary slice.
+    A model counts what it has through hooks of its config: ``attended_keys``
+    answers (query, key) pairs a head by ``(kind of layer, phase)`` — and a
+    model that attends to NO key (a cache of states alone) the positions
+    FOLDED into its states, tokens × layers, under ``layers="retention"``:
+    linear in the tokens where every other label's count is pairs."""
 
     INPUTS = {"llm": "LLM", "text": "STRING", "seed": "INT"}
     OPTIONAL = {"prompt_tokens": "INT", "new_tokens": "INT",
@@ -1022,7 +1027,7 @@ class TPUPromptRewrite(NodeDef):
                 if scanned:
                     _tm.LLM_SCAN_TOKENS.labels(phase=phase).inc(scanned)
             pairs = getattr(cfg, "attended_keys", None)
-            if pairs is not None:     # window and full layers mixed
+            if pairs is not None:     # pairs by kind — or positions folded
                 for (kind, phase), n in pairs(prompt_tokens,
                                               new_tokens).items():
                     _tm.LLM_ATTN_KEYS.labels(layers=kind, phase=phase).inc(n)

@@ -956,3 +956,16 @@ def _zaya_preset(name: str, tiny: bool = False):
 
 PRESETS["zaya1-8b"] = _zaya_preset("zaya1-8b")
 PRESETS["zaya-tiny"] = _zaya_preset("zaya-tiny", tiny=True)
+
+
+def _brumby_preset(name: str, tiny: bool = False):
+    """The ``brumby`` family (models/llm_brumby.py), registered at the END of
+    this file for ``_glm_preset``'s reason."""
+    from .llm_brumby import BrumbyConfig
+
+    stage = BrumbyConfig.tiny if tiny else BrumbyConfig.brumby_stage
+    return ModelPreset(name, unet=None, vae=None, text=None, llm=stage())
+
+
+PRESETS["brumby-14b-base"] = _brumby_preset("brumby-14b-base")
+PRESETS["brumby-tiny"] = _brumby_preset("brumby-tiny", tiny=True)

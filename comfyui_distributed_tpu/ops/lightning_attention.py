@@ -6,9 +6,11 @@ head ``h`` with a FIXED decay ``λ_h = exp(−s_h)``,
     S_t = λ_h S_{t−1} + k_t v_tᵀ            S ∈ R^{d×d}, float32
     o_t = S_tᵀ q_t · scale
 
-(``ops/delta_rule.py`` is the other linear recurrence of the tree: a delta
-rule with a learned per-channel gate, which needs a triangular solve; this
-one needs none). The chunk form cuts the chunk into blocks of ``block``
+(the tree's other linear recurrences — ``ops/delta_rule.py``: a learned gate a
+channel, no feature map, ``d × d``, no normaliser, a triangular solve;
+``ops/power_retention.py``: a gate from the token, ``φ`` of degree 2, ``d(d+1)/2
+× d``, a normalising sum; here: a fixed decay, none, ``d × d``, none, no solve).
+The chunk form cuts the chunk into blocks of ``block``
 rows and writes, for row ``i`` of a block that starts from ``S_0``,
 
     o_i = scale · ( Σ_{j≤i} λ^{i−j} (q_i·k_j) v_j  +  λ^{i+1} q_iᵀ S_0 )

@@ -126,7 +126,8 @@ LLM_CACHE_BYTES = REGISTRY.gauge(
     "state-space states and convolution tails), or a model's own kinds "
     "(latent and index: a latent cache and the index keys that choose its "
     "rows; kv and index: K/V rows and the index keys that choose them; kv "
-    "and tails: K/V rows and an attention layer's convolution tails). "
+    "and tails: K/V rows and an attention layer's convolution tails; state: "
+    "retention states alone, the same bytes at every position). "
     "Set when a request's cache is made.",
     ("layers",))
 
@@ -171,7 +172,9 @@ LLM_ATTN_KEYS = REGISTRY.counter(
     "T(T+1)/2 a layer a prefill) or window (at most sliding_window keys a "
     "query: ~T x W), by phase (prefill, decode). From the config's sizes "
     "and the request's token counts; a model that does not mix the two "
-    "kinds never moves it.",
+    "kinds never moves it. layers=retention is no pair: a model that attends "
+    "to no key counts the POSITIONS folded into its states there, tokens x "
+    "layers.",
     ("layers", "phase"))
 
 # --- attention kernel dispatch (ops/attention.py, ops/kernel_choice.py)

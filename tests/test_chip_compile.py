@@ -1126,3 +1126,64 @@ def test_the_latent_rewriters_programs_fit_beside_sdxl(chip, monkeypatch):
           f"llm_decode {decode_gib:.2f} GiB beside SDXL's {sdxl:.2f}")
     assert 6.0 < prefill_gib < 6.8 and prefill_gib + sdxl < 15.75 - 2.0
     assert 7.2 < decode_gib < 8.0 and decode_gib + sdxl < 15.75 - 2.0
+
+
+def test_the_retention_kernel_compiles_at_the_served_geometry(chip):
+    """``power_retention`` as ``brumby-14b-base``'s prefill calls it (PR 61),
+    alone: 40 query heads over 8 K/V heads of 128, a chunk of 4096 rows in
+    blocks of 256, the state ``[8, 128, 8320]`` float32 resident a head —
+    ``φ`` of 5 × 256 rows in a 21 MB VMEM scratch beside it."""
+    from comfyui_distributed_tpu.models.llm_brumby import BrumbyConfig
+    from comfyui_distributed_tpu.ops import power_retention
+
+    cfg = BrumbyConfig.brumby_stage()
+    H, G, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    C, D = cfg.prefill_chunk_tokens, cfg.state_width
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+
+    lowered = power_retention.power_retention.lower(
+        arg(G, d, D), arg(G, d, d), arg(C, H * d), arg(C, G * d),
+        arg(C, G * d), arg(C, G), heads=H // G, dtype="bfloat16",
+        block=cfg.retention_block, interpret=False)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text and f"f32[{C},{H * d}]" in text
+    assert f"f32[{G},{d},{D}]" in text
+
+
+def test_the_retention_rewriters_programs_fit_beside_sdxl(chip, monkeypatch):
+    """Both language programs of ``brumby-14b-base.ctx32k-sdxl8`` at the
+    cell's sizes (32 640 + 128 tokens = the published context, published
+    widths, 6 layers, the whole vocabulary at both ends): they compile for
+    the chip and leave room for SDXL's segment program (4.79 + 0.56 GiB) in
+    15.75 GiB; the prefill holds ONE Pallas call site a layer, the retention
+    walk; nothing holds ``φ`` of a chunk's rows (``[4096, 40, D]``) or a
+    state a block (``[16, 8, D, ·]``) — ``D`` appears in the states'
+    ``[8, 128, 8320]`` alone; ``llm_decode`` holds ONE Pallas call a layer too,
+    the step's pass over the state. The peak
+    is printed."""
+    from comfyui_distributed_tpu.models.llm_brumby import BrumbyConfig
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    cfg = BrumbyConfig.brumby_stage()
+    compiled = loop_copies.compiled_programs(cfg, 32640, 128, chip)
+    gib, sdxl = 2.0 ** 30, 4.79 + 0.56
+    text = compiled["llm_prefill"].as_text()
+    layers, D = cfg.num_hidden_layers, cfg.state_width
+    assert len(_pallas_calls(text)) == layers == 6
+    assert len(_pallas_calls(text, "power_retention")) == layers
+    state = f"[{cfg.num_key_value_heads},{cfg.head_dim},{D}]"
+    assert set(re.findall(rf"\[[\d,]*\b(?:{D}|8256)\b[\d,]*\]", text)) \
+        == {state}
+    mem = compiled["llm_prefill"].memory_analysis()
+    prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                   + mem.output_size_in_bytes) / gib
+    text = compiled["llm_decode"].as_text()
+    assert len(_pallas_calls(text, "retention_read_update")) == layers
+    mem = compiled["llm_decode"].memory_analysis()
+    decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
+    print(f"brumby-14b-base at 32640 + 128: llm_prefill {prefill_gib:.2f} "
+          f"GiB, llm_decode {decode_gib:.2f} GiB beside SDXL's {sdxl:.2f}")
+    assert 7.0 < prefill_gib < 8.0 and prefill_gib + sdxl < 15.75 - 2.0
+    assert 6.8 < decode_gib < 7.6 and decode_gib + sdxl < 15.75 - 2.0

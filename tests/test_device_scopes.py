@@ -219,7 +219,8 @@ LLMS = {"ling-tiny": ("llm_hybrid", "LLMConfig", 24),
         "sala-tiny": ("llm_sala", "SalaConfig", 40),   # past its dense_len
         "glm-tiny": ("llm_glm", "GlmConfig", 40),      # past its index_topk
         "keye-tiny": ("llm_keye", "KeyeConfig", 40),   # past its topk
-        "zaya-tiny": ("llm_zaya", "ZayaConfig", 37)}   # a padded last chunk
+        "zaya-tiny": ("llm_zaya", "ZayaConfig", 37),   # a padded last chunk
+        "brumby-tiny": ("llm_brumby", "BrumbyConfig", 37)}   # and under a gate
 
 PROGRAMS = {"txt2img_seg": _txt2img_seg, "flow_seg": _flow_seg, "fin": _fin}
 for _name, _how in LLMS.items():
@@ -282,6 +283,10 @@ EXPECTED = {
                               "llm_head"},
     "llm_decode:zaya-tiny": {"llm_attn", "llm_router", "llm_experts",
                              "llm_head"},
+    # no expert layer and no K/V row: retention is a plain named scope BELOW
+    # cdt.llm_attn (the sixteen stay sixteen)
+    "llm_prefill:brumby-tiny": {"llm_attn", "llm_shared_ffn", "llm_head"},
+    "llm_decode:brumby-tiny": {"llm_attn", "llm_shared_ffn", "llm_head"},
 }
 
 
@@ -373,3 +378,24 @@ def test_the_latent_rewriters_work_is_named_below_its_layer():
             and all(below.values()), below
         # a layer's router: the down-projection, two hidden layers, the output
         assert layers["llm_router"] >= 4 * 3
+
+
+def test_the_retention_rewriters_work_is_named_below_its_layer():
+    """``llm_retention`` (the gate's product, the products inside a block,
+    the state's read and update) is a plain named scope under
+    ``cdt.llm_attn`` (what ``cdtbench/kinds/brumby.py: retention_seconds``
+    reads from a trace): its products are under the one registered layer;
+    the q/k/v and output projections are under the layer alone."""
+    plain = re.compile(r"/(llm_retention)(?:/|$)")
+    for program in ("llm_prefill:brumby-tiny", "llm_decode:brumby-tiny"):
+        fn, args = PROGRAMS[program]()
+        seen = list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+        below = above = 0.0
+        for primitive, stack, ops in seen:
+            (layer,) = LAYER.findall(stack)
+            if plain.findall(stack):
+                assert layer == "llm_attn", stack
+                below += ops
+            elif layer == "llm_attn":
+                above += ops
+        assert below > 0 and above > 0, (program, below, above)

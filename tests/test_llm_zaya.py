@@ -667,9 +667,10 @@ def test_the_cell_assembles_with_a_brief_that_fills_the_context():
                for m in ours)
     assert bench["per_layer"][-len(ours):] == ours    # appended as one run
     assert len(bench["per_layer"]) <= 128             # the contract's room
-    assert bench["workloads"][-1] == {
+    (entry,) = [w for w in bench["workloads"] if w["name"] == CELL]
+    assert entry == {
         "name": CELL, "config": "zaya1-8b", "traffic": "ctx128k-sdxl8",
-        "chips": 1, "why": bench["workloads"][-1]["why"]}
+        "chips": 1, "why": entry["why"]}
     assert all(len(e["why"]) <= 200
                for e in bench["workloads"] + bench["configs"])
     layers = {m["layer"] for m in bench["per_layer"]
