@@ -129,19 +129,19 @@ CAUSAL_TIER_REASONS = {
 
 
 def note_causal(tier: str, num_heads: int, head_dim: int, q_len: int,
-                kv_len: int, dtype, block_q: int, block_k: int) -> None:
-    """A blocked causal prefill kernel reports itself as the tiers do —
-    the server log's ``attention:`` line and ``cdt_attn_kernel_selected``
-    — though nothing here chooses it: a chunked prefill through a cache
-    has one kernel on a TPU, and its caller
-    (``latent_attention.mla_chunk_attention``,
-    ``gqa_attention.causal_chunk``) names the tier it ran
-    (:data:`CAUSAL_TIER_REASONS`) with the blocks it was served."""
-    _note_selection(
-        GeometryKey.from_shape(num_heads, head_dim, q_len, kv_len,
-                               dtype).key_str(),
-        KernelChoice(tier, block_q, block_k,
-                     reason=CAUSAL_TIER_REASONS[tier]))
+                kv_len: int, dtype, block_q: int, block_k: int,
+                part: int = 0) -> None:
+    """A blocked causal prefill kernel reports itself as the tiers do (the
+    ``attention:`` line, ``cdt_attn_kernel_selected``) though nothing here
+    chooses it: a chunked prefill through a cache has one kernel on a TPU,
+    and its caller names the tier it ran with the blocks it was served and
+    — ``/part`` — the rows of a query tile its step takes at a time."""
+    choice = KernelChoice(tier, block_q, block_k,
+                          reason=CAUSAL_TIER_REASONS[tier])
+    rows = f"/{part}" if 0 < part < block_q else ""
+    _note_selection(GeometryKey.from_shape(
+        num_heads, head_dim, q_len, kv_len, dtype).key_str(), choice,
+        blocks=_blocks_label(choice) + rows)
 
 
 def _with_packed_blocks(choice, q_len: int, kv_len: int, head_dim: int,

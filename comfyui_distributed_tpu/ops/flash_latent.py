@@ -26,15 +26,15 @@ the bidirectional kernels of ``flash_attention.py`` lack:
   ``nope == v`` the K tile of head ``h`` is column block ``2h`` and the V
   tile ``2h+1`` of the same array.
 
-grid = (heads, C/block_q, S/block_k), K innermost; running max, sum and the
-float32 accumulator live in VMEM scratch across K steps. Queries come
-pre-multiplied by the softmax scale. ``S`` is the WHOLE buffer: a block a q
-block does not see is skipped but still a grid step (~0.3 µs), and every
-visible step rewrites the running tiles whole, so a kernel over a long
-prefix wants large tiles and a band of a few K blocks small ones — each
-caller hands the kernel IT runs a ``(block_q, block_k)`` of its own, a
-field of its model's config fixed by ``scripts/causal_tile_sweep.py``
-(docs/kernels.md).
+grid = (heads, C/block_q, K steps), K innermost; running max, sum and the
+float32 accumulator live in VMEM scratch across K steps; queries come times
+the softmax scale. The latent kernel walks ``S/block_k`` steps, the WHOLE
+buffer: a block a q block does not see is skipped but still a grid step
+(0.2–0.4 µs). The grouped-query names walk a TRACED extent (PR 63: from a
+tile's first visible block as far as the chunk's last row sees), the query
+tile ``step_rows`` rows a product. A visible step rewrites the running
+tiles whole: each caller hands its kernel a ``(block_q, block_k)`` of its
+own, a config field fixed by ``scripts/causal_tile_sweep.py`` (kernels.md).
 """
 
 from __future__ import annotations
@@ -214,51 +214,98 @@ def _mask_outside_band(s, first_row, first_col, window: "int | None",
     return jnp.where(seen, s, NEG_INF)
 
 
+# rows of a query tile whose logits are one product (PR 63's sweep, PERF.md
+# §6): the softmax of one part lies under the next part's product — 79 →
+# 91% of the matrix units' peak at 2048 × 2048. 128 is the one length that
+# wins at every tile read (64 reads 1% better at a 2048-key tile and 25%
+# worse at a 1024-key one). Parts of the K tile read SLOWER at every tile
+# (a rescale of the running tiles a part); these rescale nothing more
+STEP_ROWS = 128
+
+
+def step_rows(block_q: int) -> int:
+    """Rows of the query tile the grouped-query kernel's step takes at a
+    time: the tile it is handed is the only thing the rule looks at."""
+    return STEP_ROWS if block_q % STEP_ROWS == 0 else block_q
+
+
+def core_k_steps(start, chunk: int, block_k: int, num_k_blocks: int):
+    """K blocks the attention kernel's grid walks for a chunk of ``chunk``
+    queries at positions ``start …``: as far as the chunk's LAST row sees —
+    a query tile's steps past its own last block are the diagonal's few,
+    never the rest of a padded cache."""
+    return jnp.minimum((start + chunk + block_k - 1) // block_k,
+                       num_k_blocks)
+
+
+def gqa_k_steps(start, lowest, chunk: int, window: "int | None",
+                block_q: int, block_k: int, num_k_blocks: int):
+    """K blocks the grouped-query kernel's grid walks a query tile, counted
+    from the tile's first visible block: :func:`core_k_steps`, or under a
+    band the widest tile's span."""
+    if window is None:
+        return core_k_steps(start, chunk, block_k, num_k_blocks)
+    i = jnp.arange(chunk // block_q)
+    first = _first_column(start + i * block_q, window, lowest) // block_k
+    return jnp.max(_last_block(start, i, block_q, block_k, num_k_blocks)
+                   - first) + 1
+
+
 def _gqa_kernel(bounds_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                acc_ref, *, block_q: int, block_k: int, num_k_blocks: int,
-                window: "int | None", precision):
-    i, j = pl.program_id(1), pl.program_id(2)
+                acc_ref, *, block_q: int, block_k: int, part: int,
+                num_k_blocks: int, window: "int | None", precision):
+    i, walked = pl.program_id(1), pl.program_id(2)
     start, lowest = bounds_ref[0], bounds_ref[1]
     first_row = start + i * block_q
     last = _last_block(start, i, block_q, block_k, num_k_blocks)
     first = _first_column(first_row, window, lowest) // block_k
     edge = _first_column(first_row + block_q - 1, window, lowest)
-    _init_running(j, m_ref, l_ref, acc_ref)
+    j = first + walked              # the walk starts where the tile sees
+    _init_running(walked, m_ref, l_ref, acc_ref)
 
     def step(masked: bool):
-        s = jax.lax.dot_general(q_ref[...], k_ref[0],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32,
-                                precision=precision)
-        if masked:
-            s = _mask_outside_band(s, first_row, j * block_k, window, lowest)
-        _accumulate(s, v_ref[0], m_ref, l_ref, acc_ref, precision)
+        def logits(n: int):
+            s = jax.lax.dot_general(
+                q_ref[pl.ds(n * part, part)], k_ref[0],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=precision)
+            if masked:
+                s = _mask_outside_band(s, first_row + n * part, j * block_k,
+                                       window, lowest)
+            return s
+
+        # the query tile ``part`` rows at a time — each part a softmax of
+        # its own over the whole K tile, nothing rescaled twice — the next
+        # part's logit product set out before this part's softmax: the
+        # vector work of one lies under the matrix products of the other
+        s = logits(0)
+        for n in range(block_q // part):
+            ahead = logits(n + 1) if (n + 1) * part < block_q else None
+            rows = pl.ds(n * part, part)
+            _accumulate(s, v_ref[0], m_ref.at[rows], l_ref.at[rows],
+                        acc_ref.at[rows], precision)
+            s = ahead
 
     _on_visible_blocks(step, j, last, first_row, block_k, first, edge)
 
-    @pl.when(j == num_k_blocks - 1)
+    # the grid's K axis reaches at least this far and may end here
+    @pl.when(j == last)
     def _finalize():
         o_ref[...] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
 
 
-def _gqa_mha(q, k, v, start, lowest, num_heads: int, window: "int | None",
-             block_q: int, block_k: int, interpret: bool):
-    """Causal attention of ``num_heads`` query heads over ``G`` key/value
-    heads, query head ``h`` reading head ``h // (num_heads / G)``: the
-    schedule above with K and V tiles indexed by the GROUP (no per-head
-    copy of the cache exists) and, with ``window``, a band — a query sees
-    the ``window`` keys up to its own, K blocks wholly below the band are
-    neither fetched nor computed, the block its lower edge crosses is
-    masked as the diagonal's is. ``q`` [C, H·d] times the softmax scale;
-    ``k``, ``v`` [G, S, d]; ``start`` the first query's position and
-    ``lowest`` the first valid key row (both traced: rows below ``lowest``
-    hold nothing yet). ``C % block_q == 0``, ``S % block_k == 0``.
-    Answers [C, H·d]."""
+def gqa_call(q, k, v, start, lowest, k_steps, num_heads: int,
+             window: "int | None", block_q: int, block_k: int, part: int,
+             interpret: bool):
+    """:func:`_gqa_mha` over a grid of ``k_steps`` K blocks a query tile —
+    an int or a traced scalar that covers every tile's visible blocks,
+    counted from the tile's first — by ``part`` rows of a query tile a
+    product (``block_q % part == 0``)."""
     C, (G, S, d) = q.shape[0], k.shape
     per_group = num_heads // G
     nq, nk = C // block_q, S // block_k
     kernel = functools.partial(_gqa_kernel, block_q=block_q,
-                               block_k=block_k, num_k_blocks=nk,
+                               block_k=block_k, part=part, num_k_blocks=nk,
                                window=window,
                                precision=_precision_of(q.dtype))
 
@@ -266,11 +313,11 @@ def _gqa_mha(q, k, v, start, lowest, num_heads: int, window: "int | None",
         start, lowest = bounds_ref[0], bounds_ref[1]
         first = _first_column(start + i * block_q, window, lowest) // block_k
         last = _last_block(start, i, block_q, block_k, nk)
-        return (h // per_group, jnp.clip(j, first, last), 0)
+        return (h // per_group, jnp.minimum(first + j, last), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(num_heads, nq, nk),
+        grid=(num_heads, nq, k_steps),
         in_specs=[pl.BlockSpec((block_q, d), lambda h, i, j, b: (i, h)),
                   pl.BlockSpec((1, block_k, d), kv_block),
                   pl.BlockSpec((1, block_k, d), kv_block)],
@@ -286,6 +333,28 @@ def _gqa_mha(q, k, v, start, lowest, num_heads: int, window: "int | None",
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(bounds, q, k, v)
+
+
+def _gqa_mha(q, k, v, start, lowest, num_heads: int, window: "int | None",
+             block_q: int, block_k: int, interpret: bool):
+    """Causal attention of ``num_heads`` query heads over ``G`` key/value
+    heads, query head ``h`` reading head ``h // (num_heads / G)``: the
+    schedule above with K and V tiles indexed by the GROUP (no per-head
+    copy of the cache exists) and, with ``window``, a band — a query sees
+    the ``window`` keys up to its own, K blocks wholly below the band are
+    neither fetched nor computed, the block its lower edge crosses is
+    masked as the diagonal's is. The grid's K axis is a traced bound: a
+    query tile walks from its first visible block as far as the chunk's
+    last row sees (:func:`gqa_k_steps`), :func:`step_rows` rows of it a
+    product. ``q`` [C, H·d] times
+    the softmax scale; ``k``, ``v`` [G, S, d]; ``start`` the first query's
+    position and ``lowest`` the first valid key row (both traced: rows
+    below ``lowest`` hold nothing yet). ``C % block_q == 0``, ``S % block_k
+    == 0``. Answers [C, H·d]."""
+    steps = gqa_k_steps(start, lowest, q.shape[0], window, block_q, block_k,
+                        k.shape[1] // block_k)
+    return gqa_call(q, k, v, start, lowest, steps, num_heads, window,
+                    block_q, block_k, step_rows(block_q), interpret)
 
 
 # one body under three names, so that a device trace tells the full layers'

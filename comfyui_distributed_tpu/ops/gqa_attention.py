@@ -18,9 +18,9 @@ come roped, or not at all).
   ``ops/flash_latent.py`` (K and V tiles indexed by the group, blocks
   outside the band skipped), under the name of what it serves —
   ``gqa_window_mha`` a band, ``shared_kv_causal_mha`` one shared head,
-  ``gqa_causal_mha`` the rest: nothing ``C × S`` exists. Elsewhere the
-  masked softmax over the rows, plainly (``lax``): what the kernel is held
-  to.
+  ``gqa_causal_mha`` the rest (the ``attention:`` line says the tile and
+  the rows of it a step takes at a time: ``2048/2048/128``): nothing ``C
+  × S`` exists. Elsewhere the masked softmax, plainly (``lax``).
 - :func:`step` — one decoded token: one XLA step over a ring or a buffer,
   the rows that hold no key yet masked.
 """
@@ -83,8 +83,8 @@ def causal_chunk(q, k, v, start, scale: float, dtype, block_q: int,
             else "shared_kv_causal" if k.shape[0] == 1 else "gqa_causal")
     if kernel == "pallas":
         from .attention import note_causal
-
-        note_causal(tier, H, d, C, S + pad, dtype, bq, bk)
+        note_causal(tier, H, d, C, S + pad, dtype, bq, bk,
+                    flash_latent.step_rows(bq))
     blocks = dict(num_heads=H, block_q=bq, block_k=bk,
                   interpret=kernel == "interpret")
     q, k, v = q.reshape(C, H * d), k.astype(dtype), v.astype(dtype)

@@ -65,7 +65,7 @@ from .attention import CAUSAL_TIER_REASONS, note_causal
 from . import flash_attention
 from .flash_attention import NEG_INF
 from .flash_latent import (_accumulate, _init_running, _last_block,
-                           _precision_of, _running_scratch)
+                           _precision_of, _running_scratch, core_k_steps)
 
 CAUSAL_TIER_REASONS.setdefault(
     "index_select", "chunked prefill over the keys an indexer kept")
@@ -430,13 +430,13 @@ def _masked_kernel(start_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, m_ref,
         o_ref[...] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
 
 
-def core_k_steps(start, chunk: int, block_k: int, num_k_blocks: int):
-    """K blocks the attention kernel's grid walks for a chunk of ``chunk``
-    queries at positions ``start …``: as far as the chunk's LAST row sees —
-    a query tile's steps past its own last block are the diagonal's few,
-    never the rest of a padded cache."""
-    return jnp.minimum((start + chunk + block_k - 1) // block_k,
-                       num_k_blocks)
+# The K blocks the kernel's grid walks for a chunk of queries at positions
+# ``start …`` — as far as the chunk's LAST row sees: a query tile's steps
+# past its own last block are the diagonal's few, never the rest of a
+# padded cache — are :func:`core_k_steps`' to say: ``flash_latent``'s
+# since PR 63, where the grouped-query kernel walks the same extent, and
+# this module's by import (``scripts/index_select_sweep.py`` and the tests
+# call it here).
 
 
 def masked_mha_call(q, k, v, keep, start, k_steps, num_heads: int,

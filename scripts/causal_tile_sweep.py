@@ -1,21 +1,28 @@
 #!/usr/bin/env python
 """Which tile ``(block_q, block_k)`` a causal prefill kernel of
-``ops/flash_latent.py`` should be served with, read over a WHOLE prefill.
+``ops/flash_latent.py`` should be served with, read over a WHOLE prefill —
+and, for the grouped-query body (PR 63), which STEP and which GRID: the
+query tile ``part`` rows a product (``--parts``; ``none`` is the plain
+step) and the grid's K axis the ``whole`` buffer or the traced bound the
+kernel ships with (``--extent``; ``chunk``: a query tile walks from its
+first visible block as far as the chunk's last row sees).
 
 One run times one kernel alone at its model's served geometry, every
-candidate pair at every chunk position of the cell's prompt (one compiled
-kernel a pair: the position is the prefetched ``start``), and prints, a
-pair: seconds summed over the prefill (what decides), % of the MXU peak on
+candidate form at every chunk position of the cell's prompt (one compiled
+kernel a form: the position is the prefetched ``start``), and prints, a
+form: seconds summed over the prefill (what decides), % of the MXU peak on
 the counted pairs (each visible (query, key) pair once, as
 ``cdtbench/kinds/*.py: attention_core_flops`` counts them), visible and
 skipped grid steps, and the µs a visible and a skipped step fitted on the
 positions (``t = a·visible + b·skipped``, least squares).
 
     python scripts/causal_tile_sweep.py gqa_causal          # Trinity, full layer
+    python scripts/causal_tile_sweep.py gqa_causal_zaya     # ZAYA, 8 q / 2 kv
     python scripts/causal_tile_sweep.py gqa_window          # Trinity, the band
     python scripts/causal_tile_sweep.py latent_causal       # Kimi
     python scripts/causal_tile_sweep.py shared_kv_causal    # Jamba
-        [--pairs 1024x1024,2048x2048] [--positions 0,1,3,7,15,31]
+        [--pairs 1024x1024,2048x2048] [--parts none,512,256,128]
+        [--extent whole,chunk] [--positions 0,1,3,7,15,31]
         [--vmem-mib 100] [--out chiprun_out/tile_sweep]
 
 Run on the chip, as the one process that owns it (through the chip tool
@@ -54,23 +61,32 @@ class Geometry:
     shipped: tuple                     # the pair the model's config holds
     candidates: tuple
     window: int | None = None
+    kv_heads: int = 1
 
 
 def geometries() -> dict:
     from comfyui_distributed_tpu.models.llm_jamba import JambaConfig
     from comfyui_distributed_tpu.models.llm_kimi import KimiConfig
     from comfyui_distributed_tpu.models.llm_trinity import TrinityConfig
+    from comfyui_distributed_tpu.models.llm_zaya import ZayaConfig
 
     up = tuple((q, k) for q in (1024, 2048, 4096) for k in (1024, 2048, 4096))
-    t, k, j = (TrinityConfig.trinity_share(), KimiConfig.kimi_share(),
-               JambaConfig.jamba2_3b())
+    t, k, j, z = (TrinityConfig.trinity_share(), KimiConfig.kimi_share(),
+                  JambaConfig.jamba2_3b(), ZayaConfig.zaya_share())
     n_full = sum(t.is_full(i) for i in range(t.num_hidden_layers))
     return {
         "gqa_causal": Geometry(
             "trinity-large-preview.brief128k-sdxl8", t.num_attention_heads,
             t.prefill_chunk_tokens, 131072 // t.prefill_chunk_tokens,
             131072 + 128, n_full, 4 * t.head_dim,
-            (t.attn_full_block_q, t.attn_full_block_k), up),
+            (t.attn_full_block_q, t.attn_full_block_k), up,
+            kv_heads=t.num_key_value_heads),
+        "gqa_causal_zaya": Geometry(
+            "zaya1-8b.ctx128k-sdxl8", z.num_attention_heads,
+            z.prefill_chunk_tokens, 131072 // z.prefill_chunk_tokens,
+            130944 + 128, z.num_hidden_layers, 4 * z.head_dim,
+            (z.attn_block_q, z.attn_block_k), up,
+            kv_heads=z.num_key_value_heads),
         "gqa_window": Geometry(
             "trinity-large-preview.brief128k-sdxl8", t.num_attention_heads,
             t.prefill_chunk_tokens, 131072 // t.prefill_chunk_tokens,
@@ -78,7 +94,7 @@ def geometries() -> dict:
             4 * t.head_dim, (t.attn_window_block_q, t.attn_window_block_k),
             ((1024, 1024), (1024, 512), (2048, 512), (512, 2048),
              (512, 1024), (2048, 1024), (1024, 2048)),
-            window=t.sliding_window),
+            window=t.sliding_window, kv_heads=t.num_key_value_heads),
         "latent_causal": Geometry(
             "kimi-k2.6.brief32k-sdxl8", k.num_attention_heads,
             k.prefill_chunk_tokens, 32768 // k.prefill_chunk_tokens,
@@ -103,22 +119,25 @@ def _bounds(g: Geometry, position: int) -> tuple:
 
 
 def grid_steps(g: Geometry, position: int, block_q: int, block_k: int,
-               rows: int) -> tuple:
+               rows: int, extent: str = "whole") -> tuple:
     """``(visible, skipped)`` grid steps of one call, by the kernel's own
-    rule: a q block runs K blocks ``first .. last``."""
-    from comfyui_distributed_tpu.ops.flash_latent import (_first_column,
-                                                          _last_block)
+    rule: a q block runs K blocks ``first .. last`` of the ``walked`` its
+    grid gives it (``extent``: the ``whole`` buffer, or the grouped-query
+    kernel's traced bound)."""
+    from comfyui_distributed_tpu.ops import flash_latent as fl
 
     start, lowest = _bounds(g, position)
     nk = rows // block_k
     visible = 0
     for i in range(g.chunk // block_q):
-        first = int(_first_column(start + i * block_q, g.window,
-                                  lowest)) // block_k
-        visible += int(_last_block(start, i, block_q, block_k, nk)) \
+        first = int(fl._first_column(start + i * block_q, g.window,
+                                     lowest)) // block_k
+        visible += int(fl._last_block(start, i, block_q, block_k, nk)) \
             - first + 1
-    visible *= g.heads
-    return visible, g.heads * (g.chunk // block_q) * nk - visible
+    walked = nk if extent == "whole" else int(fl.gqa_k_steps(
+        start, lowest, g.chunk, g.window, block_q, block_k, nk))
+    return (visible * g.heads,
+            g.heads * ((g.chunk // block_q) * walked - visible))
 
 
 def counted_pairs(g: Geometry, position: int) -> int:
@@ -132,9 +151,12 @@ def counted_pairs(g: Geometry, position: int) -> int:
     return pairs
 
 
-def build_call(name: str, g: Geometry, block_q: int, block_k: int):
+def build_call(name: str, g: Geometry, block_q: int, block_k: int,
+               part: int | None = None, extent: str = "chunk"):
     """``call(position) -> array`` of kernel ``name`` at the pair, on
-    random bf16 operands of the served shapes, and the rows it walks."""
+    random bf16 operands of the served shapes, and the rows it walks. The
+    grouped-query names run ``flash_latent.gqa_call`` by ``part`` rows of
+    the query tile a product (None: the plain step) over the K ``extent``."""
     import math
 
     import jax
@@ -147,8 +169,6 @@ def build_call(name: str, g: Geometry, block_q: int, block_k: int):
     rows = -(-g.rows // step) * step
     keys = jax.random.split(jax.random.key(0), 4)
     d = g.flops_per_pair // 4      # the one-product kernels: 2·d + 2·d
-    blocks = dict(num_heads=g.heads, block_q=block_q, block_k=block_k,
-                  interpret=False)
 
     def normal(key, *shape):
         return jax.random.normal(key, shape, jnp.bfloat16)
@@ -161,22 +181,25 @@ def build_call(name: str, g: Geometry, block_q: int, block_k: int):
         kv, kr = normal(keys[2], rows, g.heads * (nope + k.v_head_dim)), \
             normal(keys[3], rows, rope)
         return rows, lambda p: flash_latent.latent_causal_mha(
-            qn, qr, kv, kr, jnp.int32(_bounds(g, p)[0]), **blocks)
+            qn, qr, kv, kr, jnp.int32(_bounds(g, p)[0]), num_heads=g.heads,
+            block_q=block_q, block_k=block_k, interpret=False)
     q = normal(keys[0], g.chunk, g.heads * d)
-    if name == "shared_kv_causal":
-        kk, vv = normal(keys[1], rows, d), normal(keys[2], rows, d)
-        return rows, lambda p: flash_latent.shared_kv_causal_mha(
-            q, kk, vv, jnp.int32(_bounds(g, p)[0]), **blocks)
-    from comfyui_distributed_tpu.models.llm_trinity import TrinityConfig
+    kk, vv = (normal(key, g.kv_heads, rows, d) for key in keys[1:3])
+    nk = rows // block_k
 
-    G = TrinityConfig.trinity_share().num_key_value_heads
-    kk, vv = normal(keys[1], G, rows, d), normal(keys[2], G, rows, d)
-    if g.window is None:
-        return rows, lambda p: flash_latent.gqa_causal_mha(
-            q, kk, vv, jnp.int32(_bounds(g, p)[0]), **blocks)
-    return rows, lambda p: flash_latent.gqa_window_mha(
-        q, kk, vv, *(jnp.int32(b) for b in _bounds(g, p)), window=g.window,
-        **blocks)
+    @jax.jit
+    def form(q, kk, vv, start, lowest):
+        steps = nk if extent == "whole" else flash_latent.gqa_k_steps(
+            start, lowest, g.chunk, g.window, block_q, block_k, nk)
+        return flash_latent.gqa_call(
+            q, kk, vv, start, lowest, steps, g.heads, g.window, block_q,
+            block_k, part or block_q, False)
+
+    # the bounds are on the device ahead of the timed calls: two scalars'
+    # transfers a call outlast a short kernel
+    bounds = {p: tuple(jnp.int32(b) for b in _bounds(g, p))
+              for p in range(g.chunks)}
+    return rows, lambda p: form(q, kk, vv, *bounds[p])
 
 
 def time_call(call, position: int, reps: int, batch: int) -> float:
@@ -230,7 +253,34 @@ def positions_of(g: Geometry, asked: str | None) -> dict:
     return weights
 
 
-def sweep(name: str, pairs, asked: str | None, reps: int, vmem_mib) -> dict:
+def forms_of(name: str, pairs, parts, extents):
+    """``(block_q, block_k, part, extent)`` of every form asked for: a part
+    divides its query tile and is shorter (None: the plain step); the
+    latent kernel has the plain step over the whole buffer only."""
+    if name == "latent_causal":
+        return [(bq, bk, None, "whole") for bq, bk in pairs]
+    return [(bq, bk, part, extent) for bq, bk in pairs for part in parts
+            if part is None or (part < bq and bq % part == 0)
+            for extent in extents]
+
+
+def label(block_q: int, block_k: int, part, extent: str) -> str:
+    return f"{block_q}x{block_k}" + (f"/{part}" if part else "") \
+        + f" {extent}"
+
+
+def shipped_form(name: str, g: Geometry) -> list:
+    """``[block_q, block_k, part, extent]`` the kernel ships with."""
+    from comfyui_distributed_tpu.ops import flash_latent
+
+    if name == "latent_causal":
+        return [*g.shipped, None, "whole"]
+    part = flash_latent.step_rows(g.shipped[0])
+    return [*g.shipped, part if part < g.shipped[0] else None, "chunk"]
+
+
+def sweep(name: str, pairs, parts, extents, asked: str | None, reps: int,
+          vmem_mib) -> dict:
     import jax
 
     from comfyui_distributed_tpu.ops import flash_latent
@@ -241,23 +291,27 @@ def sweep(name: str, pairs, asked: str | None, reps: int, vmem_mib) -> dict:
     if vmem_mib:
         flash_latent._VMEM_LIMIT_BYTES = vmem_mib * 1024 * 1024
     g = geometries()[name]
+    shipped = shipped_form(name, g)
     weights = positions_of(g, asked)
     pairs_counted = {p: counted_pairs(g, p) for p in weights}
     rows_out = []
-    for block_q, block_k in pairs or g.candidates:
-        row = {"block_q": block_q, "block_k": block_k}
+    for block_q, block_k, part, extent in forms_of(
+            name, pairs or g.candidates, parts or [shipped[2]], extents):
+        row = {"block_q": block_q, "block_k": block_k, "part": part,
+               "extent": extent}
+        what = f"{name} {label(block_q, block_k, part, extent)}"
         try:
-            rows, call = build_call(name, g, block_q, block_k)
+            rows, call = build_call(name, g, block_q, block_k, part, extent)
             jax.block_until_ready(call(0))                   # compiles
-            batch = 8 if g.window is not None else 2
+            batch = 8 if g.window is not None or g.heads <= 8 else 2
             secs = {p: time_call(call, p, reps, batch) for p in weights}
         except Exception as e:  # noqa: BLE001 — the compiler's refusal
             row["refused"] = str(e)[:300]
             rows_out.append(row)
-            print(f"{name} {block_q}x{block_k}: refused {row['refused']}",
-                  flush=True)
+            print(f"{what}: refused {row['refused']}", flush=True)
             continue
-        steps = {p: grid_steps(g, p, block_q, block_k, rows) for p in weights}
+        steps = {p: grid_steps(g, p, block_q, block_k, rows, extent)
+                 for p in weights}
         total = sum(weights[p] * secs[p] for p in weights) * g.calls
         flops = sum(weights[p] * pairs_counted[p] for p in weights) \
             * g.calls * g.heads * g.flops_per_pair
@@ -277,9 +331,9 @@ def sweep(name: str, pairs, asked: str | None, reps: int, vmem_mib) -> dict:
                 str(p): 100.0 * pairs_counted[p] * g.heads * g.flops_per_pair
                 / MXU_PEAK / secs[p] for p in weights})
         rows_out.append(row)
-        print(f"{name} {block_q}x{block_k}: {total:.4f} s a prefill, "
+        print(f"{what}: {total:.4f} s a prefill, "
               f"{row['mxu_pct']:.1f}% of the MXU peak", flush=True)
-    return {"kernel": name, "cell": g.cell, "shipped": list(g.shipped),
+    return {"kernel": name, "cell": g.cell, "shipped": shipped,
             "positions": {str(p): w for p, w in weights.items()},
             "calls_per_position": g.calls, "vmem_limit_mib": vmem_mib or
             flash_latent._VMEM_LIMIT_BYTES // 2 ** 20, "reps": reps,
@@ -294,17 +348,20 @@ def table(result: dict) -> str:
     def num(x, fmt):
         return "—" if x is None else format(x, fmt)
 
+    def form(r):
+        return [r["block_q"], r["block_k"], r["part"], r["extent"]]
+
     done = [r for r in result["rows"] if "refused" not in r]
     base = next((r["prefill_s"] for r in done
-                 if [r["block_q"], r["block_k"]] == result["shipped"]), None)
+                 if form(r) == result["shipped"]), None)
     lines = [f"`{result['kernel']}` ({result['cell']}; positions "
              f"{','.join(result['positions'])}; VMEM limit "
              f"{result['vmem_limit_mib']} MiB)",
-             "| tile | s a prefill | vs shipped | % MXU | ms first · last "
-             "position | visible steps | skipped steps | µs visible | "
-             "µs skipped |", "|---|---|---|---|---|---|---|---|---|"]
+             "| tile[/part] extent | s a prefill | vs shipped | % MXU | ms "
+             "first · last position | visible steps | skipped steps | "
+             "µs visible | µs skipped |", "|---|---|---|---|---|---|---|---|---|"]
     for r in result["rows"]:
-        tile = f"{r['block_q']} × {r['block_k']}"
+        tile = label(*form(r))
         if "refused" in r:
             lines.append(f"| {tile} | refused: {r['refused'][:80]} |||||||||")
             continue
@@ -320,10 +377,16 @@ def table(result: dict) -> str:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("kernel", choices=["gqa_causal", "gqa_window",
-                                       "latent_causal", "shared_kv_causal"])
+    ap.add_argument("kernel", choices=["gqa_causal", "gqa_causal_zaya",
+                                       "gqa_window", "latent_causal",
+                                       "shared_kv_causal"])
     ap.add_argument("--pairs", help="block_q x block_k, comma-separated "
                     "(default: the kernel's candidates)")
+    ap.add_argument("--parts", help="rows of a query tile a logit product, "
+                    "comma-separated; none: the plain step (default: the "
+                    "part the kernel ships with)")
+    ap.add_argument("--extent", default="chunk", help="the grid's K axis, "
+                    "comma-separated: whole, chunk (the shipped bound)")
     ap.add_argument("--positions", help="chunk positions, comma-separated "
                     "(default: every chunk of the cell's prompt)")
     ap.add_argument("--reps", type=int, default=3)
@@ -333,8 +396,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     pairs = [tuple(int(n) for n in p.split("x"))
              for p in args.pairs.split(",")] if args.pairs else None
-    result = sweep(args.kernel, pairs, args.positions, args.reps,
-                   args.vmem_mib)
+    parts = [None if p == "none" else int(p)
+             for p in args.parts.split(",")] if args.parts else None
+    result = sweep(args.kernel, pairs, parts, args.extent.split(","),
+                   args.positions, args.reps, args.vmem_mib)
     os.makedirs(args.out, exist_ok=True)
     tag = args.kernel + (f".vmem{args.vmem_mib}" if args.vmem_mib else "")
     with open(os.path.join(args.out, tag + ".json"), "w") as f:

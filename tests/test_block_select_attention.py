@@ -259,13 +259,13 @@ def test_the_kernel_reports_a_tier_of_its_own(monkeypatch):
     assert "block_select" in attention.CAUSAL_TIER_REASONS
     said = []
     monkeypatch.setattr(attention, "_note_selection",
-                        lambda geometry, choice: said.append(
+                        lambda geometry, choice, blocks: said.append(
                             (geometry, choice.tier, choice.block_q,
-                             choice.block_k)))
+                             choice.block_k, blocks)))
     attention.note_causal("block_select", 32, 128, 512, 65664,
                           jnp.bfloat16, 64, 1024)
     assert said == [("h32.d128.q512.kv131072.bf16", "block_select", 64,
-                     1024)]
+                     1024, "64/1024")]
 
 
 # --- a step's two fetches ---------------------------------------------------------

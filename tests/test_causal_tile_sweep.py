@@ -54,6 +54,50 @@ def test_the_issues_counts_at_the_old_tile(sweep):
     assert gs["latent_causal"].calls * sum(v + s for v, s in k) == 368640
 
 
+# (kernel, tile, rows) → (visible, skipped over the whole buffer, skipped
+# over the traced extent) a request: ISSUE 63's counts and the band's
+STEPS_63 = [
+    ("gqa_causal_zaya", (2048, 2048), 131072, (166400, 161280, 2560)),
+    ("gqa_causal", (2048, 2048), 133120, (99840, 99840, 1536)),
+    ("shared_kv_causal", (2048, 2048), 67584, (21120, 21120, 640)),
+    ("gqa_window", (1024, 1024), 8192, (120960, 75648, 1152)),
+]
+
+
+@pytest.mark.parametrize("name,tile,rows,want", STEPS_63)
+def test_the_traced_extent_leaves_the_diagonals_few_skipped_steps(
+        sweep, name, tile, rows, want):
+    """ISSUE 63: ZAYA's ten layers walk 327 680 grid steps a request over
+    the whole buffer, 166 400 of them visible; under the kernel's traced K
+    extent a query tile's only skipped steps are the ones past its own last
+    block — and under a band a tile walks from ITS first visible block."""
+    g = sweep.geometries()[name]
+    weights = sweep.positions_of(g, None)
+    total = {}
+    for extent in ("whole", "chunk"):
+        steps = [sweep.grid_steps(g, p, *tile, rows, extent) for p in weights]
+        total[extent] = tuple(
+            g.calls * sum(w * s[k] for s, w in zip(steps, weights.values()))
+            for k in (0, 1))
+    assert total["whole"] == want[:2]
+    assert total["chunk"] == (want[0], want[2])
+
+
+def test_a_form_is_a_tile_a_part_of_its_rows_and_an_extent(sweep):
+    """A part divides the query tile and is shorter; ``none`` is the plain
+    step; the latent kernel has one form a tile."""
+    forms = sweep.forms_of("gqa_causal", [(2048, 2048), (1024, 2048)],
+                           [None, 2048, 1024, 128, 96], ["whole", "chunk"])
+    assert [f[:3] for f in forms if f[3] == "chunk"] == [
+        (2048, 2048, None), (2048, 2048, 1024), (2048, 2048, 128),
+        (1024, 2048, None), (1024, 2048, 128)]
+    assert len(forms) == 10
+    assert sweep.forms_of("latent_causal", [(2048, 1024)], [None, 128],
+                          ["whole", "chunk"]) == [(2048, 1024, None, "whole")]
+    assert sweep.label(2048, 2048, 128, "chunk") == "2048x2048/128 chunk"
+    assert sweep.label(1024, 1024, None, "whole") == "1024x1024 whole"
+
+
 def test_the_counted_pairs_are_the_models_attended_keys(sweep):
     from comfyui_distributed_tpu.models.llm_trinity import TrinityConfig
 
@@ -97,8 +141,13 @@ def test_the_geometries_are_the_presets_and_name_their_shipped_pair(sweep):
     assert gs["gqa_causal"].shipped != gs["gqa_window"].shipped
     for g in gs.values():
         assert g.shipped in g.candidates and g.chunk == 4096
+    zaya = gs["gqa_causal_zaya"]
+    assert (zaya.heads, zaya.kv_heads, zaya.calls, zaya.chunks) \
+        == (8, 2, 10, 32)
+    assert (gs["gqa_causal"].kv_heads, gs["shared_kv_causal"].kv_heads) \
+        == (8, 1)
 
 
 def test_a_tile_is_never_timed_off_the_chip(sweep):
     with pytest.raises(SystemExit, match="timed on a TPU"):
-        sweep.sweep("gqa_window", None, None, 1, None)
+        sweep.sweep("gqa_window", None, [None], ["chunk"], None, 1, None)

@@ -491,6 +491,47 @@ def test_the_grouped_query_kernel_compiles_at_the_served_geometry(chip,
     assert "tpu_custom_call" in lowered.compile().as_text()
 
 
+@pytest.mark.parametrize("cell", ["zaya1-8b", "trinity-large-preview"])
+def test_the_parted_step_compiles_under_the_kernels_own_vmem_limit(chip,
+                                                                  cell):
+    """PR 63's form of the grouped-query body as the two long-context
+    cells serve it: a 2048 × 2048 tile whose step takes the query tile in
+    the parts ``step_rows`` says (two float32 logit parts of ``[part, 2048]``
+    alive at once, the next one's product ahead of a part's softmax) over a
+    grid whose K extent is TRACED (``core_k_steps``), under the 64 MiB the
+    kernel asks for itself — 8 q / 2 kv heads over 131 072 rows and 48 / 8
+    over 133 120."""
+    from comfyui_distributed_tpu.models import llm_trinity, llm_zaya
+    from comfyui_distributed_tpu.ops import flash_latent
+
+    if cell == "zaya1-8b":
+        cfg = llm_zaya.ZayaConfig.zaya_share()
+        bq, bk = cfg.attn_block_q, cfg.attn_block_k
+        S = jax.eval_shape(lambda: llm_zaya.empty_cache(
+            cfg, 130944 + 128))["k"][0].shape[1]
+    else:
+        cfg = llm_trinity.TrinityConfig.trinity_share()
+        bq, bk = cfg.attn_full_block_q, cfg.attn_full_block_k
+        S = -(-(131072 + 128) // bk) * bk
+    H, G, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    C, part = cfg.prefill_chunk_tokens, flash_latent.step_rows(bq)
+    assert (bq, bk, part) == (2048, 2048, flash_latent.STEP_ROWS)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def served(q, k, v, start):
+        steps = flash_latent.core_k_steps(start, C, bk, S // bk)
+        return flash_latent.gqa_call(q, k, v, start, 0, steps, H, None, bq,
+                                     bk, part, False)
+
+    lowered = jax.jit(served).lower(arg((C, H * d)), arg((G, S, d)),
+                                    arg((G, S, d)), arg((), jnp.int32))
+    assert str(flash_latent._VMEM_LIMIT_BYTES) in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text and f"bf16[{C},{H * d}]" in text
+
+
 def test_the_window_and_full_rewriters_programs_fit_beside_sdxl(chip,
                                                                 monkeypatch):
     """Both language programs of ``trinity-large-preview.brief128k-sdxl8``

@@ -4,7 +4,9 @@ whole or a band; ONE group is the shared-K/V case), in the Pallas
 interpreter and as the ``lax`` statement, against a naive float64 masked
 softmax: over starts, band edges inside, at and across blocks,
 ``window=None``, a ring that is still empty, 6 heads a group, and tiles
-whose sides differ (each kernel is served with a pair of its own)."""
+whose sides differ (each kernel is served with a pair of its own); since
+PR 63 the step by PARTS of a query tile and the grid's traced K extent, at
+every chunk position of a small prefill under all three names."""
 
 import jax
 import jax.numpy as jnp
@@ -196,6 +198,140 @@ def test_each_name_is_its_own_in_a_program_and_all_are_one_body(
     assert traced == [(4, 64 if name == "gqa_window_mha" else None)]
 
 
+def _chunk_rows(k, v, position, C, window):
+    """What a prefill hands the kernel at chunk ``position`` of ``k``, ``v``
+    [G, T, d] (every token's row): a full layer the buffer itself, rows at
+    their positions; a window layer ``[ring ; chunk]`` — the ``window`` rows
+    ahead of the chunk (zeros where the ring is still empty) and its own —
+    with ``start`` the first query's row among them and ``lowest`` the
+    first that holds a key."""
+    if window is None:
+        return k, v, position * C, 0
+    at = position * C
+    lowest = max(window - at, 0)
+    rows = [jnp.pad(a[:, max(at - window, 0):at + C],
+                    ((0, 0), (lowest, 0), (0, 0))) for a in (k, v)]
+    return *rows, window, lowest
+
+
+# (name, window, G): the three jitted names of the one body
+NAMES = [("gqa_causal_mha", None, 2), ("gqa_window_mha", 16, 2),
+         ("shared_kv_causal_mha", None, 1)]
+# (block_q, block_k, part): a part below the query tile, the query tile
+# itself (the plain step), a query tile of eight parts over a K tile longer
+# than the chunk, parts of ONE row
+PARTED = [(8, 16, 4), (8, 16, 8), (16, 32, 2), (4, 8, 1)]
+
+
+@pytest.mark.parametrize("block_q,block_k,part", PARTED)
+@pytest.mark.parametrize("name,window,G", NAMES)
+def test_the_parted_step_is_the_reference_at_every_chunk_position(
+        name, window, G, block_q, block_k, part):
+    """A prefill of 72 tokens in chunks of 16 (the LAST one padded: 8 of
+    its rows hold no token of the brief) through a buffer of 96 rows /
+    ``[ring ; chunk]`` of 16 + 16: at every position the kernel by
+    ``part`` rows a product over its traced extent answers what the naive
+    softmax answers for the chunk's rows."""
+    C, T, H, d = 16, 80, 4, 8
+    q, k, v = case(jax.random.key(11), T, 96, H, G, d)
+    want = naive(q, k, v, 0, 0.5, window)
+    for position in range(T // C):
+        kk, vv, start, lowest = _chunk_rows(k, v, position, C, window)
+        S = kk.shape[1]
+        steps = flash_latent.gqa_k_steps(start, lowest, C, window, block_q,
+                                         block_k, S // block_k)
+        got = flash_latent.gqa_call(
+            (q[position * C:(position + 1) * C] * 0.5).reshape(C, H * d),
+            kk, vv, start, lowest, steps, H, window, block_q, block_k, part,
+            True)
+        assert close(got.reshape(C, H, d),
+                     want[position * C:(position + 1) * C]), position
+
+
+@pytest.mark.parametrize("start", [16, 24, 32])
+@pytest.mark.parametrize("lowest", [0, 9, 14])
+def test_a_bands_edge_and_the_diagonal_cross_every_part_elsewhere(start,
+                                                                  lowest):
+    """A query tile of 16 rows in parts of 4 under a band of 6 over K tiles
+    of 16: the band's lower edge and the diagonal cross each PART's logits
+    at columns of its own (a part's mask starts at ITS first row), in one K
+    tile or in two — and rows below ``lowest`` are poisoned (a masked logit
+    is replaced, not multiplied)."""
+    q, k, v = case(jax.random.key(12), 16, 48, 4, 2, 8)
+    bad_k = k.at[:, :lowest].set(jnp.nan)
+    steps = flash_latent.gqa_k_steps(start, lowest, 16, 6, 16, 16, 3)
+    got = flash_latent.gqa_call(
+        q.reshape(16, 32), bad_k, v, start, lowest, steps, 4, 6, 16, 16, 4,
+        True)
+    assert np.isfinite(np.asarray(got)).all()
+    assert close(got.reshape(16, 4, 8),
+                 naive(q, k, v, start, 1.0, 6, lowest))
+
+
+def test_the_rule_for_the_part_reads_the_tile_alone():
+    """Parts of 128 rows of every served query tile (ZAYA's and Trinity's
+    full layers, Jamba's; the band's), the plain step where 128 does not
+    divide the tile (the tiny presets)."""
+    from comfyui_distributed_tpu.models.llm_jamba import JambaConfig
+    from comfyui_distributed_tpu.models.llm_trinity import TrinityConfig
+    from comfyui_distributed_tpu.models.llm_zaya import ZayaConfig
+
+    t = TrinityConfig.trinity_share()
+    for block_q in (ZayaConfig.zaya_share().attn_block_q, t.attn_full_block_q,
+                    JambaConfig.jamba2_3b().attn_block_q,
+                    t.attn_window_block_q):
+        assert flash_latent.step_rows(block_q) == flash_latent.STEP_ROWS == 128
+    for tiny in (4, 8, 16, 64, 2048 + 64):
+        assert flash_latent.step_rows(tiny) == tiny
+
+
+@pytest.mark.parametrize("C,S,block_q,block_k", [
+    (16, 96, 8, 16), (16, 96, 4, 32), (16, 64, 16, 8),
+    (4096, 131072, 2048, 2048), (4096, 133120, 2048, 2048)])
+def test_the_traced_extent_covers_every_query_tiles_last_block(
+        C, S, block_q, block_k):
+    """``core_k_steps``, the walk with no band, at every chunk position:
+    each query tile's last visible block lies inside the walk, the walk ends
+    at the last tile's, and the last chunk of the buffer takes the whole
+    grid."""
+    nk = S // block_k
+    for start in range(0, S - C + 1, C):
+        steps = int(flash_latent.gqa_k_steps(start, 0, C, None, block_q,
+                                             block_k, nk))
+        lasts = [int(flash_latent._last_block(start, i, block_q, block_k,
+                                              nk)) for i in range(C // block_q)]
+        assert steps == max(lasts) + 1 <= nk
+    assert int(flash_latent.core_k_steps(S - C, C, block_k, nk)) == nk
+    assert int(flash_latent.core_k_steps(0, C, block_k, nk)) \
+        == -(-C // block_k)
+
+
+@pytest.mark.parametrize("C,window,block_q,block_k", [
+    (16, 24, 8, 8), (16, 16, 4, 8), (16, 24, 4, 20),
+    (4096, 4096, 1024, 1024), (4096, 4096, 2048, 1024)])
+def test_a_bands_walk_is_its_widest_query_tiles_span(C, window, block_q,
+                                                     block_k):
+    """``gqa_k_steps`` over ``[ring ; chunk]``: every query tile's visible
+    blocks, counted from ITS first, lie inside the walk; some tile fills it;
+    an empty ring (chunk 0) walks the diagonal's blocks only."""
+    S = window + C
+    nk = S // block_k
+    for lowest in (0, window // 2, window):
+        steps = int(flash_latent.gqa_k_steps(window, lowest, C, window,
+                                             block_q, block_k, nk))
+        spans = []
+        for i in range(C // block_q):
+            first = int(flash_latent._first_column(
+                window + i * block_q, window, lowest)) // block_k
+            spans.append(int(flash_latent._last_block(
+                window, i, block_q, block_k, nk)) - first + 1)
+        assert steps == max(spans) <= nk
+    if (block_q, block_k) == (1024, 1024):      # the served band: 5 of 8
+        assert (steps, nk) == (4, 8)
+        assert int(flash_latent.gqa_k_steps(4096, 0, C, window, block_q,
+                                            block_k, nk)) == 5
+
+
 def test_the_decode_step_reads_the_rows_it_is_told_are_valid():
     q, k, v = case(jax.random.key(6), 1, 24, 6, 2, 8)
     valid = jnp.arange(24) <= 17
@@ -207,7 +343,11 @@ def test_the_decode_step_reads_the_rows_it_is_told_are_valid():
 
 
 def test_both_kernels_report_a_tier_of_their_own():
-    from comfyui_distributed_tpu.ops import attention, kernel_choice
+    # index_select_attention registers the one causal tier that is not a
+    # kernel of flash_latent.py
+    from comfyui_distributed_tpu.ops import (attention,  # noqa: F401
+                                             index_select_attention,
+                                             kernel_choice)
 
     for tier in ("gqa_window", "gqa_causal"):
         assert tier in kernel_choice.REPORTED_TIERS
@@ -222,4 +362,33 @@ def test_both_kernels_report_a_tier_of_their_own():
     summary = attention.selection_summary()
     assert "gqa_window:1024/1024" in summary
     assert "gqa_causal:2048/2048" in summary
+    attention.reset_selections()
+
+
+def test_a_site_reports_the_rows_its_step_takes_at_a_time(monkeypatch):
+    """What ``causal_chunk`` hands ``note_causal`` on a TPU: the tile and
+    ``step_rows`` of it — ``2048/2048/128`` in the ``attention:`` line and
+    the counter's ``blocks`` label; a tile the rule leaves whole (the tiny
+    presets) reads as it always did."""
+    from comfyui_distributed_tpu.ops import attention
+
+    noted = []
+    monkeypatch.setattr(attention, "note_causal",
+                        lambda *a: noted.append(a))
+    monkeypatch.setattr(flash_latent, "gqa_causal_mha",
+                        lambda q, *a, **kw: q)
+    q, k, v = case(jax.random.key(8), 256, 512, 4, 2, 8)
+    gqa_attention.causal_chunk(q, k, v, jnp.int32(0), 1.0, jnp.float32, 256,
+                               256, kernel="pallas")
+    gqa_attention.causal_chunk(q[:16], k, v, jnp.int32(0), 1.0, jnp.float32,
+                               8, 256, kernel="pallas")
+    assert [a[0] for a in noted] == ["gqa_causal"] * 2
+    assert [a[-3:] for a in noted] == [(256, 256, 128), (8, 256, 8)]
+    monkeypatch.undo()
+    attention.reset_selections()
+    for tile in noted:
+        attention.note_causal(*tile)
+    summary = attention.selection_summary()
+    assert "gqa_causal:256/256/128" in summary
+    assert "gqa_causal:8/256" in summary and "8/256/" not in summary
     attention.reset_selections()
