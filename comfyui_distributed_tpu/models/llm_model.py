@@ -52,19 +52,19 @@ def chunked_prefill(model: LLMModel, cfg, weights, ids, max_len: int,
                     all_logits: bool = False, chunk: "int | None" = None,
                     **kw):
     """A prompt ``ids`` [T] through ``model.prefill_chunk``, ``chunk``
-    tokens (``cfg.prefill_chunk_tokens``) at a time: ONE scan whose carry
-    is the cache, so nothing the size of the prompt exists but the cache
-    and the ids. The last chunk is padded (the cache has rows for it):
+    tokens (``cfg.prefill_chunk_tokens``) at a time: ONE scan whose carry is
+    the cache, so nothing the size of the prompt exists but the cache and the
+    ids. The last chunk is padded (the cache has rows for it):
     ``prefill_chunk`` is told how many of its rows are the prompt's
-    (``n_valid``) and owes the carry this — rows of a full-length cache
-    past ``n_valid`` may hold anything (nothing reads them before a decode
-    step rewrites them), but a RECURRENT leaf (a state, a convolution's
-    tail) must come back as token ``n_valid − 1`` left it, for a padded row
-    that advanced it would be part of every token after. The rows of a
-    RING (slot ``position % length``) are a recurrent leaf in this sense: a
-    padded row would overwrite the slot of a row the next token still sees,
-    so a padded chunk writes only its ``n_valid`` rows there. Answers
-    ``(logits, cache, held, rows)``: the last position's logits [V] (every position's [T,V] with ``all_logits``), held slots
+    (``n_valid``) and owes the carry this — rows of a full-length cache past
+    ``n_valid`` may hold anything (nothing reads them before a decode step
+    rewrites them), but a RECURRENT leaf (a state, a convolution's tail) must
+    come back as token ``n_valid − 1`` left it. The rows of a RING (slot
+    ``position % length``) are one: a padded row would overwrite the slot of a
+    row the next token still sees, so a padded chunk writes only its
+    ``n_valid`` rows there — and where the ring is SHORTER than the chunk, the
+    LAST ``length`` of them (the rows before ``n_valid``, not the chunk's tail).
+    Answers ``(logits, cache, held, rows)``: the last position's logits [V] (every position's [T,V] with ``all_logits``), held slots
     and rows multiplied per expert layer summed over the chunks."""
     import jax
     import jax.numpy as jnp

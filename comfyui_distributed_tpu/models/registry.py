@@ -969,3 +969,16 @@ def _brumby_preset(name: str, tiny: bool = False):
 
 PRESETS["brumby-14b-base"] = _brumby_preset("brumby-14b-base")
 PRESETS["brumby-tiny"] = _brumby_preset("brumby-tiny", tiny=True)
+
+
+def _mimo_preset(name: str, tiny: bool = False):
+    """The ``mimo`` family (models/llm_mimo.py), registered at the END of
+    this file for ``_glm_preset``'s reason."""
+    from .llm_mimo import MimoConfig
+
+    stage = MimoConfig.tiny if tiny else MimoConfig.mimo_stage
+    return ModelPreset(name, unet=None, vae=None, text=None, llm=stage())
+
+
+PRESETS["mimo-v2-flash"] = _mimo_preset("mimo-v2-flash")
+PRESETS["mimo-tiny"] = _mimo_preset("mimo-tiny", tiny=True)

@@ -130,17 +130,17 @@ CAUSAL_TIER_REASONS = {
 
 def note_causal(tier: str, num_heads: int, head_dim: int, q_len: int,
                 kv_len: int, dtype, block_q: int, block_k: int,
-                part: int = 0) -> None:
+                part: int = 0, value_dim: int = 0) -> None:
     """A blocked causal prefill kernel reports itself as the tiers do (the
-    ``attention:`` line, ``cdt_attn_kernel_selected``) though nothing here
-    chooses it: a chunked prefill through a cache has one kernel on a TPU,
-    and its caller names the tier it ran with the blocks it was served and
-    — ``/part`` — the rows of a query tile its step takes at a time."""
+    ``attention:`` line, ``cdt_attn_kernel_selected``): its caller names the
+    tier, the blocks, ``/part`` the rows of a query tile a step takes at a
+    time, ``value_dim`` where values are narrower than keys (``d192/128``)."""
     choice = KernelChoice(tier, block_q, block_k,
                           reason=CAUSAL_TIER_REASONS[tier])
     rows = f"/{part}" if 0 < part < block_q else ""
     _note_selection(GeometryKey.from_shape(
-        num_heads, head_dim, q_len, kv_len, dtype).key_str(), choice,
+        num_heads, head_dim, q_len, kv_len, dtype).key_str().replace(
+        ".q", f"/{value_dim}.q" if value_dim else ".q", 1), choice,
         blocks=_blocks_label(choice) + rows)
 
 

@@ -185,7 +185,8 @@ ATTN_KERNEL_SELECTED = REGISTRY.counter(
     "(packed/bh/xla; latent_causal, shared_kv_causal, gqa_causal, "
     "gqa_window: a chunked prefill's own kernel over a latent cache / one "
     "shared key/value head / grouped key/value heads, whole or a window's "
-    "band), geometry (hH.dD.qN.kvN.dtype — bucketed, "
+    "band), geometry (hH.dD.qN.kvN.dtype, dD/V where values are narrower "
+    "than keys — bucketed, "
     "so cardinality is bounded by the model zoo) and resolved blocks "
     "('<block_q>/<block_k>', for packed also ':k-resident' or "
     "':k-streamed'; '' where the tier has none). Increments once per "

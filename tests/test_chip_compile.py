@@ -1228,3 +1228,66 @@ def test_the_retention_rewriters_programs_fit_beside_sdxl(chip, monkeypatch):
           f"GiB, llm_decode {decode_gib:.2f} GiB beside SDXL's {sdxl:.2f}")
     assert 7.0 < prefill_gib < 8.0 and prefill_gib + sdxl < 15.75 - 2.0
     assert 6.8 < decode_gib < 7.6 and decode_gib + sdxl < 15.75 - 2.0
+
+
+def test_the_wide_kernel_compiles_at_the_served_geometry(chip):
+    """``flash_latent._gqa_kernel`` as ``mimo-v2-flash``'s full layers call it
+    (PR 64), alone: 64 head-major query heads of 192 over 4 K/V heads, values
+    128 wide, a 4096-token chunk over the 128 k buffer rounded to the K
+    block, at the 2048 × 2048 tile it ships with and the parts ``step_rows``
+    says — a tile whose last dimension is 192, no multiple of the 128 lanes,
+    is where the compiler would refuse first."""
+    from comfyui_distributed_tpu.models import llm_mimo
+    from comfyui_distributed_tpu.ops import gqa_sink_attention
+
+    cfg = llm_mimo.MimoConfig.mimo_stage()
+    H, G = cfg.num_attention_heads, cfg.num_key_value_heads
+    dk, dv, C = cfg.head_dim, cfg.v_head_dim, cfg.prefill_chunk_tokens
+    cache = jax.eval_shape(lambda: llm_mimo.empty_cache(cfg, 131072 + 128))
+    S = cache["k"][0].shape[1]
+    assert cache["k"][0].shape == (G, 133120, dk) and cache["v"][0].shape \
+        == (G, 133120, dv) and S % cfg.attn_block_k == 0
+    assert cache["k"][1].shape == (8, 128, dk)          # a window layer's ring
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    lowered = gqa_sink_attention.gqa_wide_causal_mha.lower(
+        arg((H, C, dk)), arg((G, S, dk)), arg((G, S, dv)),
+        arg((), jnp.int32), block_q=cfg.attn_block_q,
+        block_k=cfg.attn_block_k, interpret=False)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text and f"bf16[{C},{H * dv}]" in text
+
+
+def test_the_window_and_sink_rewriters_programs_fit_beside_sdxl(chip,
+                                                                monkeypatch):
+    """Both language programs of ``mimo-v2-flash.brief128k-sdxl8`` at the
+    cell's sizes (131 072 + 128 tokens, published widths, 7 layers, 16 experts
+    held, an eighth of the vocabulary): they compile for the chip and leave
+    room for SDXL's segment program (4.79 + 0.56 GiB) in 15.75 GiB;
+    ``llm_prefill`` holds ONE Pallas call site a FULL layer — two: the band of
+    the five window layers is XLA's — and nothing holds a chunk's logits
+    against ``[ring ; chunk]`` whole (``[…, 4096·8, 4224]``); ``llm_decode``
+    holds none. The peak is printed."""
+    from comfyui_distributed_tpu.models.llm_mimo import MimoConfig
+
+    monkeypatch.setattr(fa, "_platform", lambda: "tpu")
+    cfg = MimoConfig.mimo_stage()
+    compiled = loop_copies.compiled_programs(cfg, 131072, 128, chip)
+    gib, sdxl = 2.0 ** 30, 4.79 + 0.56
+    text = compiled["llm_prefill"].as_text()
+    assert len(_pallas_calls(text)) == 2
+    assert len(_pallas_calls(text, "gqa_wide_causal_mha")) == 2
+    assert not re.findall(r"f32\[[\d,]*\b4224\]", text)
+    mem = compiled["llm_prefill"].memory_analysis()
+    prefill_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                   + mem.output_size_in_bytes) / gib
+    text = compiled["llm_decode"].as_text()
+    assert not _pallas_calls(text)
+    mem = compiled["llm_decode"].memory_analysis()
+    decode_gib = (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib
+    print(f"mimo-v2-flash at 131072 + 128: llm_prefill {prefill_gib:.2f} "
+          f"GiB, llm_decode {decode_gib:.2f} GiB beside SDXL's {sdxl:.2f}")
+    assert 7.8 < prefill_gib < 9.0 and prefill_gib + sdxl < 15.75 - 1.0
+    assert 7.6 < decode_gib < 8.6 and decode_gib + sdxl < 15.75 - 1.0

@@ -220,7 +220,8 @@ LLMS = {"ling-tiny": ("llm_hybrid", "LLMConfig", 24),
         "glm-tiny": ("llm_glm", "GlmConfig", 40),      # past its index_topk
         "keye-tiny": ("llm_keye", "KeyeConfig", 40),   # past its topk
         "zaya-tiny": ("llm_zaya", "ZayaConfig", 37),   # a padded last chunk
-        "brumby-tiny": ("llm_brumby", "BrumbyConfig", 37)}   # and under a gate
+        "brumby-tiny": ("llm_brumby", "BrumbyConfig", 37),   # and under a gate
+        "mimo-tiny": ("llm_mimo", "MimoConfig", 37)}   # 2 chunks + 5: a ring
 
 PROGRAMS = {"txt2img_seg": _txt2img_seg, "flow_seg": _flow_seg, "fin": _fin}
 for _name, _how in LLMS.items():
@@ -287,6 +288,12 @@ EXPECTED = {
     # cdt.llm_attn (the sixteen stay sixteen)
     "llm_prefill:brumby-tiny": {"llm_attn", "llm_shared_ffn", "llm_head"},
     "llm_decode:brumby-tiny": {"llm_attn", "llm_shared_ffn", "llm_head"},
+    # no shared expert: llm_shared_ffn is the one dense layer; the two cores
+    # are plain named scopes BELOW cdt.llm_attn (the sixteen stay sixteen)
+    "llm_prefill:mimo-tiny": {"llm_attn", "llm_router", "llm_experts",
+                              "llm_shared_ffn", "llm_head"},
+    "llm_decode:mimo-tiny": {"llm_attn", "llm_router", "llm_experts",
+                             "llm_shared_ffn", "llm_head"},
 }
 
 
@@ -399,3 +406,28 @@ def test_the_retention_rewriters_work_is_named_below_its_layer():
             elif layer == "llm_attn":
                 above += ops
         assert below > 0 and above > 0, (program, below, above)
+
+
+def test_the_window_and_sink_rewriters_cores_are_named_below_their_layer():
+    """``llm_swa_core`` (a window layer's band with its sink, the ring's
+    step) and ``llm_full_core`` (a full layer's causal core, the buffer's
+    step) are plain named scopes under ``cdt.llm_attn`` (what
+    ``cdtbench/kinds/mimo.py: scope_seconds`` reads from a trace): every
+    product of a core is under exactly one of them and under the one
+    registered layer; the q/k/v and output projections are under the layer
+    alone. Five window layers to two full: both carry products."""
+    plain = re.compile(r"/(llm_swa_core|llm_full_core)(?:/|$)")
+    for program in ("llm_prefill:mimo-tiny", "llm_decode:mimo-tiny"):
+        fn, args = PROGRAMS[program]()
+        seen = list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+        below, above = {}, 0.0
+        for primitive, stack, ops in seen:
+            (layer,) = LAYER.findall(stack)
+            found = plain.findall(stack)
+            if found:
+                assert len(found) == 1 and layer == "llm_attn", stack
+                below[found[0]] = below.get(found[0], 0.0) + ops
+            elif layer == "llm_attn":
+                above += ops
+        assert set(below) == {"llm_swa_core", "llm_full_core"} \
+            and all(below.values()) and above > 0, (program, below, above)

@@ -392,3 +392,99 @@ def test_a_site_reports_the_rows_its_step_takes_at_a_time(monkeypatch):
     assert "gqa_causal:256/256/128" in summary
     assert "gqa_causal:8/256" in summary and "8/256/" not in summary
     attention.reset_selections()
+
+
+# --- the same body behind head-major queries, keys wider than values (PR 64) --
+
+
+def wide_case(key, C, S, H, G, dk, dv):
+    q, k, _ = case(key, C, S, H, G, dk)
+    return q, k, jax.random.normal(jax.random.fold_in(key, 9), (G, S, dv))
+
+
+def naive_wide(q, k, v, seen, scale, sink=None):
+    """Float64, a head by itself, the sink one more term of the denominator."""
+    H, G = q.shape[1], k.shape[0]
+    heads = np.repeat(np.arange(G), H // G)
+    s = np.einsum("chd,hsd->chs", np.asarray(q, np.float64),
+                  np.asarray(k, np.float64)[heads]) * scale
+    e = np.where(seen[:, None, :], np.exp(s), 0.0)
+    total = e.sum(-1, keepdims=True)
+    if sink is not None:
+        total = total + np.exp(np.asarray(sink, np.float64))[None, :, None]
+    return np.einsum("chs,hsd->chd", e / total,
+                     np.asarray(v, np.float64)[heads])
+
+
+@pytest.mark.parametrize("kernel,block_q,block_k", KERNEL_TILES)
+@pytest.mark.parametrize("start", [0, 16, 40])
+def test_the_wide_call_is_naive_attention_at_two_widths(kernel, block_q,
+                                                        block_k, start):
+    """``gqa_sink_attention.causal_chunk``: 12 heads over 2 key/value heads,
+    keys 24 wide and values 16, at ``start`` > 0 — ``_gqa_kernel`` behind a
+    head-major query tile whose last dimension is the whole key width."""
+    from comfyui_distributed_tpu.ops import gqa_sink_attention
+
+    q, k, v = wide_case(jax.random.key(64), 16, 56, 12, 2, 24, 16)
+    scale = 24 ** -0.5
+    got = gqa_sink_attention.causal_chunk(q, k, v, start, scale, jnp.float32,
+                                          block_q, block_k, kernel=kernel)
+    seen = np.arange(56)[None, :] <= (start + np.arange(16))[:, None]
+    assert got.shape == (16, 12, 16)
+    assert close(got, naive_wide(q, k, v, seen, scale))
+
+
+@pytest.mark.parametrize("start", [0, 16, 40])
+def test_at_one_width_the_wide_call_is_the_grouped_querys_own(start):
+    """Keys as wide as values: the new call and ``gqa_causal_mha`` run one
+    body and answer alike, bit for bit in the interpreter."""
+    from comfyui_distributed_tpu.ops import gqa_sink_attention
+
+    q, k, v = case(jax.random.key(5), 16, 64, 12, 2, 16)
+    args = (start, 0.25, jnp.float32, 8, 16)
+    ours = gqa_sink_attention.causal_chunk(q, k, v, *args,
+                                           kernel="interpret")
+    theirs = gqa_attention.causal_chunk(q, k, v, *args, kernel="interpret")
+    assert np.array_equal(np.asarray(ours), np.asarray(theirs))
+
+
+@pytest.mark.parametrize("lowest", [0, "window", 3])
+@pytest.mark.parametrize("rows,window", [(32, 8), (24, 8), (13, 4)])
+def test_the_block_local_band_with_a_sink_is_the_naive_band(rows, window,
+                                                            lowest):
+    """``band_chunk`` over ``[ring ; chunk]`` with ``lowest`` > 0 (a ring
+    still empty — ``lowest`` the window: every query has its own key at
+    least — or partly), a chunk that is no multiple of the window, both
+    widths and the sink — and without the sink it is ``gqa_attention``'s band
+    at the same rows."""
+    from comfyui_distributed_tpu.ops import gqa_sink_attention
+
+    q, k, v = wide_case(jax.random.key(7), rows, window + rows, 12, 4, 24, 16)
+    sink = jax.random.normal(jax.random.key(8), (12,))
+    scale = 24 ** -0.5
+    lowest = window if lowest == "window" else lowest
+    row = (window + np.arange(rows))[:, None]
+    col = np.arange(window + rows)[None, :]
+    seen = (col <= row) & (row - col < window) & (col >= lowest)
+    got = gqa_sink_attention.band_chunk(q, k, v, lowest, window, scale,
+                                        jnp.float32, sink)
+    assert close(got, naive_wide(q, k, v, seen, scale, sink))
+    bare = gqa_sink_attention.band_chunk(q, k, v[..., :16], lowest, window,
+                                         scale, jnp.float32)
+    same = gqa_attention.causal_chunk(
+        q, k, jnp.pad(v, ((0, 0), (0, 0), (0, 8))), window, scale,
+        jnp.float32, 8, 8, window=window, lowest=lowest, kernel="lax")
+    assert close(bare, same[..., :16])
+    assert not close(bare, got, 1e-3)
+
+
+def test_the_wide_step_masks_the_rows_that_hold_no_key_and_joins_the_sink():
+    from comfyui_distributed_tpu.ops import gqa_sink_attention
+
+    q, k, v = wide_case(jax.random.key(11), 1, 9, 12, 4, 24, 16)
+    sink = jax.random.normal(jax.random.key(12), (12,))
+    valid = np.arange(9) <= 5
+    for b in (None, sink):
+        got = gqa_sink_attention.step(q[0], k, v, jnp.asarray(valid), 0.2,
+                                      jnp.float32, b)
+        assert close(got, naive_wide(q, k, v, valid[None], 0.2, b)[0])

@@ -600,10 +600,13 @@ def test_the_cell_assembles_with_a_brief_that_fills_the_context():
     chunk = cell.config["prefill_chunk_tokens"]
     assert divmod(32640, chunk) == (7, 3968)         # a padded last chunk
     bench = cell.bench
-    assert bench["workloads"][-1] == {
+    (entry,) = [w for w in bench["workloads"] if w["name"] == CELL]
+    assert entry == {
         "name": CELL, "config": "brumby-14b-base", "traffic": "ctx32k-sdxl8",
-        "chips": 1, "why": bench["workloads"][-1]["why"]}
-    assert bench["configs"][-1]["name"] == "brumby-14b-base"
+        "chips": 1, "why": entry["why"]}
+    (config,) = [c for c in bench["configs"]
+                 if c["name"] == "brumby-14b-base"]
+    assert config["file"] == "cdtbench/configs/brumby-14b-base.json"
     assert all(len(e["why"]) <= 200
                for e in bench["workloads"] + bench["configs"])
     # per_layer was FULL when this cell came (128 of the contract's 128):
