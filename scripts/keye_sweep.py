@@ -11,8 +11,16 @@ positions (a chunk of 4096 queries at ``position × 4096`` against a cache of
 
 - ``index``: ``index_score_sums`` over the tiles of ``--index-tiles``;
 - ``select``: ``index_select_keep`` (top 2048, GLM's kernel as it is);
-- ``core``: ``index_masked_gqa`` under a mask of ``min(2048, t + 1)`` random
-  kept keys a row, over ``--core-tiles``;
+- ``core``: ``index_masked_gqa``'s call under a mask of ``min(2048, t + 1)``
+  random kept keys a row, one line a FORM (PR 65): every tile of
+  ``--core-tile`` by every part of ``--core-part`` (rows of one head a logit
+  product, the next part's product set out ahead of a part's softmax;
+  ``whole`` is a head at a time, the step as it was), over the grid of
+  ``--core-extent`` (``chunk``: the K axis ends where the chunk sees, what
+  ships; ``whole``: every block of the padded cache, as it was), and beside
+  them PR 60's form at the shipped tile — the K TILE in parts of
+  ``--core-kpart`` keys, a rescale of the running tiles a part
+  (:func:`k_parts_kernel`, this script's own: the module ships one form);
 - ``experts``: ``held_part_grouped`` at 128 held experts, 4096 rows × top 8
   = 32 768 slots a chunk, over ``--expert-tiles`` — the rows drawn even over
   the experts (``even``) and as a brief repeats its tokens (``skewed``: 256
@@ -31,6 +39,8 @@ positions (a chunk of 4096 queries at ``position × 4096`` against a cache of
 
     python scripts/keye_sweep.py [--positions 0,7,15] [--reps 3]
         [--parts index,select,core,experts,decode] [--out chiprun_out/pr53]
+        [--core-tile 512x2048,1024x2048] [--core-part whole,256,128,64]
+        [--core-kpart 512,1024] [--core-extent chunk,whole]
 
 Run on the chip, as the one process that owns it. It fails without a TPU: a
 kernel's time on the CPU says nothing. No program reads this script's output.
@@ -74,6 +84,83 @@ def a_layer(by_position: dict) -> float:
 
 def tiles_of(text: str) -> list:
     return [tuple(int(n) for n in t.split("x")) for t in text.split(",")]
+
+
+def visible_steps(position: int, block_q: int, block_k: int) -> int:
+    """(query tile, K tile) pairs of one K/V head the core multiplies for
+    the chunk at ``position``: a query tile's blocks up to its last row's."""
+    first = position * C
+    return sum(min((first + (i + 1) * block_q - 1) // block_k,
+                   S // block_k - 1) + 1 for i in range(C // block_q))
+
+
+def core_forms(tiles, parts, kparts, extents, shipped) -> list:
+    """``(block_q, block_k, part, kpart, extent)`` of every form asked for:
+    rows parts that divide their tile at every tile, ``whole`` the tile
+    itself; K parts at the shipped tile alone."""
+    forms = [(bq, bk, bq if part == "whole" else int(part), None, extent)
+             for bq, bk in tiles for part in parts for extent in extents
+             if part == "whole" or (int(part) < bq and bq % int(part) == 0)]
+    return forms + [(*shipped, shipped[0], kpart, extents[0])
+                    for kpart in kparts if shipped[1] % kpart == 0]
+
+
+def core_label(bq: int, bk: int, part: int, kpart, extent: str) -> str:
+    return f"{bq}x{bk}" + (f"/{part}" if part < bq else "") \
+        + (f"/k{kpart}" if kpart else "") \
+        + ("" if extent == "chunk" else f".{extent}")
+
+
+def k_parts_kernel(k_part: int):
+    """PR 60's form of the step in this kernel's body (GLM's
+    ``_masked_kernel``): the K tile ``k_part`` keys at a time in their
+    order, a head after the other, the next part's logit product set out
+    before a part's softmax — one more rescale of a head's running tiles a
+    part. The sweep's losing arm; ``index_gqa_attention`` ships rows parts."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from comfyui_distributed_tpu.ops.flash_attention import NEG_INF
+    from comfyui_distributed_tpu.ops.flash_latent import (
+        _accumulate, _init_running, _last_block)
+
+    def kernel(start_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, m_ref, l_ref,
+               acc_ref, *, block_q, block_k, part, num_k_blocks, heads,
+               precision):
+        i, j = pl.program_id(1), pl.program_id(2)
+        last = _last_block(start_ref[0], i, block_q, block_k, num_k_blocks)
+        _init_running(j, m_ref, l_ref, acc_ref)
+        d = k_ref.shape[1]
+        parts = [(h, slice(c, c + k_part)) for h in range(heads)
+                 for c in range(0, block_k, k_part)]
+
+        @pl.when(j <= last)
+        def _step():
+            bias = jnp.where(keep_ref[...].astype(jnp.int32) != 0, 0.0,
+                             NEG_INF)
+
+            def logits(h: int, at: slice):
+                return jax.lax.dot_general(
+                    q_ref[:, h * d:(h + 1) * d], k_ref[at],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=precision) + bias[:, at]
+
+            s = logits(*parts[0])
+            for n, (h, at) in enumerate(parts):
+                ahead = logits(*parts[n + 1]) if n + 1 < len(parts) else None
+                _accumulate(s, v_ref[at], m_ref.at[h], l_ref.at[h],
+                            acc_ref.at[h], precision)
+                s = ahead
+
+        @pl.when(j == last)
+        def _finalize():
+            for h in range(heads):
+                o_ref[:, h * d:(h + 1) * d] = (
+                    acc_ref[h] / l_ref[h][:, :1]).astype(o_ref.dtype)
+
+    return kernel
 
 
 def sorted_grouped(x, idx, w, e_gu, e_down, tile: int):
@@ -124,9 +211,16 @@ def main(argv=None) -> int:
     parser.add_argument("--parts", default="index,select,core,experts,decode")
     parser.add_argument("--index-tiles",
                         default="256x1024,512x1024,1024x1024,512x2048")
-    parser.add_argument("--core-tiles",
-                        default="256x1024,512x1024,1024x1024,512x2048,"
-                                "1024x512")
+    parser.add_argument("--core-tile", "--core-tiles", dest="core_tiles",
+                        default="512x2048,1024x2048,256x2048,512x1024")
+    parser.add_argument("--core-part", default="whole,256,128,64",
+                        help="rows of one head a logit product")
+    parser.add_argument("--core-kpart", default="512,1024",
+                        help="keys of the shipped tile a product (PR 60's "
+                             "form; empty: none)")
+    parser.add_argument("--core-extent", default="chunk",
+                        help="chunk (the grid ends where the chunk sees) "
+                             "and/or whole (every block of the cache)")
     parser.add_argument("--expert-tiles", default="128,256,512")
     parser.add_argument("--out", default="chiprun_out/pr53")
     args = parser.parse_args(argv)
@@ -189,20 +283,55 @@ def main(argv=None) -> int:
             share = jnp.minimum(1.0, TOPK / (row + 1.0))
             return ((col <= row) & (u < share)).astype(jnp.int8)
 
-        for bq, bk in tiles_of(args.core_tiles):
-            by = {}
-            for p in positions:
-                keep = mask_at(jnp.int32(p * C), ks[6])
-                by[p] = timed(lambda s, keep=keep, bq=bq, bk=bk:
-                              gqa_ops.index_masked_gqa(
-                                  q, kv, keep, s, num_heads=H,
-                                  num_kv_heads=G, block_q=bq, block_k=bk,
-                                  interpret=False),
-                              jnp.int32(p * C), reps=args.reps)
-            dense = CHUNKS * C * (CHUNKS * C + 1) / 2 * H * 2 * D_HEAD * 2
-            say(f"core.{bq}x{bk}", {
+        masks = {p: mask_at(jnp.int32(p * C), ks[6]) for p in positions}
+        kernel_as_shipped = gqa_ops._masked_gqa_kernel
+        table = ["| tile[/part] | ms at chunk "
+                 + " · ".join(str(p) for p in positions)
+                 + " | s a layer | % MXU, causal pairs | % MXU, its tiles |",
+                 "|---|---|---|---|---|"]
+        for bq, bk, part, kpart, extent in core_forms(
+                tiles_of(args.core_tiles), args.core_part.split(","),
+                [int(k) for k in args.core_kpart.split(",") if k],
+                args.core_extent.split(","), gqa_ops.CORE_TILE):
+            # a jit of its own a form: the call reads the kernel's body and
+            # the grid's extent when it is traced
+            gqa_ops._masked_gqa_kernel = k_parts_kernel(kpart) if kpart \
+                else kernel_as_shipped
+
+            # the operands are ARGUMENTS: closed over, 160 MB of them would
+            # be constants of every form's executable
+            @jax.jit
+            def call(q, kv, keep, start, bq=bq, bk=bk, part=part,
+                     extent=extent):
+                steps = S // bk if extent == "whole" else \
+                    gqa_ops.core_k_steps(start, C, bk, S // bk)
+                return gqa_ops.masked_gqa_call(q, kv, keep, start, steps, H,
+                                               G, bq, bk, part, False)
+
+            name = core_label(bq, bk, part, kpart, extent)
+            try:
+                by = {p: timed(call, q, kv, masks[p], jnp.int32(p * C),
+                               reps=args.reps) for p in positions}
+            except Exception as e:  # noqa: BLE001 — a form the chip refuses
+                say(f"core.{name}", {"refused": repr(e)[:300]})
+                continue
+            finally:
+                gqa_ops._masked_gqa_kernel = kernel_as_shipped
+            pairs = CHUNKS * C * (CHUNKS * C + 1) / 2 * H * 2 * D_HEAD * 2
+            tiles = H * bq * bk * 2 * D_HEAD * 2 * sum(
+                visible_steps(p, bq, bk) for p in range(CHUNKS))
+            say(f"core.{name}", {
                 "s_a_call": by, "s_a_layer": a_layer(by),
-                "dense_mxu_pct": 100 * dense / PEAK_FLOPS / a_layer(by)})
+                "dense_mxu_pct": 100 * pairs / PEAK_FLOPS / a_layer(by),
+                "tile_mxu_pct": 100 * tiles / PEAK_FLOPS / a_layer(by)})
+            row = found[f"core.{name}"]
+            table.append(
+                f"| {name} | "
+                + " · ".join(f"{1e3 * by[p]:.3f}" for p in positions)
+                + f" | {row['s_a_layer']:.4f} | {row['dense_mxu_pct']:.1f} "
+                  f"| {row['tile_mxu_pct']:.1f} |")
+        (out / "keye_core_table.md").write_text("\n".join(table) + "\n")
+        print("\n".join(table), flush=True)
 
     if "experts" in parts or "decode" in parts:
         e_gu = jax.random.normal(ks[7], (EXPERTS, HIDDEN, 2 * WIDTH), bf) \

@@ -994,7 +994,8 @@ def test_the_index_selecting_gqa_kernels_compile_at_the_served_geometry(
     the matrix unit's depth — against 69 632 index keys at THIS module's
     tile; GLM's selection kernel as it is; and 4096 queries of 32 heads over
     4 K/V heads of 128 under the byte mask, K and V tiles read out of the
-    ``[69 632, 1024]`` row buffer where they lie."""
+    ``[69 632, 1024]`` row buffer where they lie, a step's rows in the parts
+    ``core_part`` says over a grid whose K axis is traced (PR 65)."""
     from comfyui_distributed_tpu.models.llm_keye import KeyeConfig
     from comfyui_distributed_tpu.ops import index_gqa_attention as gqa_ops
     from comfyui_distributed_tpu.ops import index_select_attention as ops
@@ -1026,6 +1027,14 @@ def test_the_index_selecting_gqa_kernels_compile_at_the_served_geometry(
             block_q=gqa_ops.CORE_TILE[0], block_k=gqa_ops.CORE_TILE[1],
             interpret=False)
         out = f"bf16[{C},{H * d}]"
+        # PR 65: the shipped tile by the shipped part — 64 parts of 64 rows
+        # of one head a step, two float32 logit parts and the 4 MiB bias
+        # alive at once — over a TRACED K extent (``start`` is an argument:
+        # ``core_k_steps`` of it is the grid's last axis), under the scoped
+        # VMEM the kernel asks for itself
+        assert gqa_ops.core_part(gqa_ops.CORE_TILE[0]) == gqa_ops.CORE_PART \
+            < gqa_ops.CORE_TILE[0]
+        assert str(gqa_ops._VMEM_LIMIT_BYTES) in lowered.as_text()
     else:
         # the expert layer's tiles: 128 experts' [2048, 1536] and [768, 2048]
         # streamed behind the tile -> expert table, 256 tiles at the most

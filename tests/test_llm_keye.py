@@ -270,6 +270,177 @@ def test_the_masked_grouped_kernel_is_a_softmax_over_the_kept_keys(start,
     assert np.allclose(np.asarray(want)[t, h], (p / p.sum()) @ v, atol=1e-5)
 
 
+# --- the step by parts over a traced K extent (PR 65) -------------------------
+
+
+def _parted_case(start, S, C=256, H=8, G=4, d=16):
+    """``C`` queries at ``start …`` of 8 heads over 4 K/V heads of 16 against
+    a cache of ``S`` rows under a random causal mask that keeps about a
+    third — and ONE key only in row 5; rows past ``start + C`` hold NaN (a
+    kernel that multiplied a tile past the chunk would answer it)."""
+    keys = jax.random.split(jax.random.key(65), 3)
+    q = jax.random.normal(keys[0], (C, H, d)) / 4
+    kv = jax.random.normal(keys[1], (S, 2 * G * d))
+    kv = kv.at[-(-(start + C) // 256) * 256:].set(jnp.nan)
+    seen = start + np.arange(C)[:, None] >= np.arange(S)[None, :]
+    keep = (jax.random.uniform(keys[2], (C, S)) < 0.3) & seen
+    keep = keep.at[:, 0].set(True).at[5].set(False).at[5, start + 2].set(True)
+    return q, kv, keep.astype(jnp.int8)
+
+
+def _parted(q, kv, keep, start, tile, part, k_steps=None):
+    C, H, d = q.shape
+    bq, bk = tile
+    if k_steps is None:
+        k_steps = gqa_ops.core_k_steps(jnp.int32(start), C, bk,
+                                       kv.shape[0] // bk)
+    return np.asarray(gqa_ops.masked_gqa_call(
+        q.reshape(C, H * d), kv, keep, start, k_steps, H, 4, bq, bk, part,
+        True)).reshape(C, H, d)
+
+
+# the diagonal inside a query tile's K tile (start 160 + 256 rows end at 415:
+# K tiles of 128 and of 256 are crossed mid-tile)
+@pytest.mark.parametrize("tile", [(256, 128), (256, 256)])
+@pytest.mark.parametrize("part", [256, 128, 64, 32])
+def test_a_steps_rows_in_parts_answer_the_whole_heads_bits(part, tile):
+    """``part`` rows of one head a logit product, heads outer, the next
+    part's product traced ahead of a part's softmax: each row still takes
+    ONE softmax over the whole K tile a step, so the answers are the whole
+    head's to the bit — and the plain masked softmax inside the file's
+    tolerance, in a row that keeps one key too."""
+    start = 160
+    q, kv, keep = _parted_case(start, 512)
+    whole = _parted(q, kv, keep, start, tile, tile[0])
+    got = _parted(q, kv, keep, start, tile, part)
+    assert np.array_equal(got, whole)
+    want = np.asarray(gqa_ops.masked_gqa_lax(
+        q, jnp.nan_to_num(kv), keep, 4, jnp.float32))
+    assert np.allclose(got, want, atol=1e-5)
+    # the row that keeps one key answers that key's value, every head
+    v = np.asarray(kv[start + 2, 4 * 16:]).reshape(4, 16)
+    assert np.allclose(got[5], np.repeat(v, 2, axis=0), atol=1e-6)
+
+
+@pytest.mark.parametrize("start", [0, 768, 1792])
+def test_the_grid_ends_where_the_chunk_sees_whatever_pads_the_cache(
+        start, monkeypatch):
+    """A cache padded far past ``start + C`` (the first, a middle and the
+    last chunk of a prompt of 2048 in a buffer of 2560: 4 to 18 dead K
+    blocks of 128) answers the bits of a cache that ends at the chunk; the
+    jitted call's grid carries ONE traced bound, its K axis, and that bound
+    reads ``core_k_steps(start, C, block_k, nk)``."""
+    C, S, tile = 256, 2560, (128, 128)
+    q, kv, keep = _parted_case(start, S)
+    ends = start + C
+    got = gqa_ops.index_masked_gqa(
+        q.reshape(C, -1), kv, keep, start, num_heads=8, num_kv_heads=4,
+        block_q=tile[0], block_k=tile[1], interpret=True)
+    short = gqa_ops.index_masked_gqa(
+        q.reshape(C, -1), kv[:ends], keep[:, :ends], start, num_heads=8,
+        num_kv_heads=4, block_q=tile[0], block_k=tile[1], interpret=True)
+    assert (S - ends) // tile[1] >= 4
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.array_equal(np.asarray(got), np.asarray(short))
+    # traced: one dynamic grid bound
+    jaxpr = jax.make_jaxpr(lambda *a: gqa_ops.index_masked_gqa.__wrapped__(
+        *a, 8, 4, *tile, False))(q.reshape(C, -1), kv, keep,
+                                 jnp.int32(start))
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert calls[0].params["grid_mapping"].num_dynamic_grid_bounds == 1
+    # concrete: the bound the call was handed, as a number
+    grids = []
+
+    def fake_call(kernel, grid_spec, out_shape, **kw):
+        grids.append(grid_spec.grid)
+        return lambda *a: jnp.zeros(out_shape.shape, out_shape.dtype)
+
+    monkeypatch.setattr(gqa_ops.pl, "pallas_call", fake_call)
+    gqa_ops.index_masked_gqa.__wrapped__(
+        q.reshape(C, -1), kv, keep, jnp.int32(start), 8, 4, *tile, False)
+    G, nq, steps = grids[0]
+    nk = S // tile[1]
+    assert (G, nq) == (4, C // tile[0])
+    assert int(steps) == int(gqa_ops.core_k_steps(start, C, tile[1], nk)) \
+        == -(-ends // tile[1]) <= nk - 4
+
+
+@pytest.mark.parametrize("block_q", [192, 96, 32])
+def test_a_tile_the_part_does_not_divide_is_taken_a_head_at_a_time(
+        block_q, monkeypatch):
+    """``core_part``'s rule (``step_rows``' shape, this tile's length): 64
+    rows or the whole tile, by the tile alone — and the served call hands
+    its kernel what the rule says of ITS tile."""
+    assert gqa_ops.core_part(gqa_ops.CORE_TILE[0]) == gqa_ops.CORE_PART == 64
+    part = gqa_ops.core_part(block_q)
+    assert part == (64 if block_q == 192 else block_q)
+    C = 2 * block_q
+    q, kv, keep = _parted_case(64, 512, C=C)
+    parts = []
+    call = gqa_ops.masked_gqa_call
+    monkeypatch.setattr(gqa_ops, "masked_gqa_call",
+                        lambda *a: parts.append(a[7:10]) or call(*a))
+    got = gqa_ops.index_masked_gqa(
+        q.reshape(C, -1), kv, keep, 64, num_heads=8, num_kv_heads=4,
+        block_q=block_q, block_k=128, interpret=True)
+    assert parts == [(block_q, 128, part)]
+    want = gqa_ops.masked_gqa_lax(q, jnp.nan_to_num(kv), keep, 4,
+                                  jnp.float32)
+    assert np.allclose(np.asarray(got).reshape(want.shape), np.asarray(want),
+                       atol=1e-5)
+
+
+def test_the_site_reports_its_part_in_the_attention_line(monkeypatch):
+    """``masked_chunk_gqa`` on a TPU notes the tile AND ``core_part`` of it:
+    ``512/2048/64`` in the ``attention:`` line and the counter's ``blocks``
+    label at the served sizes, a tile the rule leaves whole as it always
+    read."""
+    from comfyui_distributed_tpu.ops import attention
+
+    monkeypatch.setattr(gqa_ops, "index_masked_gqa", lambda q, *a, **kw: q)
+    attention.reset_selections()
+    for C, S in ((4096, 69632), (16, 64)):
+        gqa_ops.masked_chunk_gqa(
+            jnp.zeros((C, 32, 128), jnp.bfloat16),
+            jnp.zeros((S, 8), jnp.bfloat16), None, 0, 4, 1.0, jnp.bfloat16,
+            "pallas")
+    summary = attention.selection_summary()
+    assert "index_select:512/2048/64" in summary
+    assert "index_select:16/64" in summary and "16/64/" not in summary
+    attention.reset_selections()
+
+
+@pytest.mark.parametrize("k_part", [64, 128])
+def test_the_sweeps_losing_arm_is_the_same_attention(k_part, monkeypatch):
+    """``scripts/keye_sweep.py`` times PR 60's form — the K tile in parts —
+    through this module's call with a kernel body of its own: the same
+    softmax, or the table compares different work."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "keye_sweep", ROOT / "scripts" / "keye_sweep.py")
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    start = 160
+    q, kv, keep = _parted_case(start, 512)
+    want = _parted(q, kv, keep, start, (256, 128), 256)
+    monkeypatch.setattr(gqa_ops, "_masked_gqa_kernel",
+                        sweep.k_parts_kernel(k_part))
+    got = _parted(q, kv, keep, start, (256, 128), 256)
+    assert np.allclose(got, want, atol=1e-5)
+    forms = sweep.core_forms([(512, 2048), (256, 2048)],
+                             ["whole", "256", "128"], [512], ["chunk"],
+                             gqa_ops.CORE_TILE)
+    assert [sweep.core_label(*f) for f in forms] == [
+        "512x2048", "512x2048/256", "512x2048/128", "256x2048",
+        "256x2048/128", "512x2048/k512"]
+    # 16c + 12 visible steps a K/V head a layer at the shipped tile
+    assert [sweep.visible_steps(c, 512, 2048) for c in (0, 7, 15)] \
+        == [12, 124, 252]
+    assert sum(sweep.visible_steps(c, 512, 2048) for c in range(16)) == 2112
+
+
 def test_the_score_kernel_at_this_geometry_is_the_plain_sum():
     kq, kw, kk = jax.random.split(jax.random.key(4), 3)
     C, J, d, S = 16, 4, 8, 64
