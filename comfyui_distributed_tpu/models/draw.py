@@ -157,8 +157,8 @@ def draw_params(module, rng, *example_args, param_dtype=None,
     firsts: dict = {}
     for path in paths:
         firsts.setdefault(program[path], path)
-    with pooled_builds("draw_leaf"), \
-            ThreadPoolExecutor(BUILDERS, initializer=in_pool) as pool:
+    with pooled_builds("draw_leaf") as opener, ThreadPoolExecutor(
+            BUILDERS, initializer=in_pool, initargs=(opener,)) as pool:
         built = dict(zip(firsts.values(), pool.map(draw, firsts.values())))
     drawn = [built[path] if path in built else draw(path) for path in paths]
     weights_drawn(len(drawn), len(firsts))

@@ -19,8 +19,9 @@ inside its one function, when a program is traced):
   ``device_scope(layer)``, the ``cdt.<layer>`` name a traced operation
   carries into the profiler's trace (the device-side twin of ``span``);
 - ``build``     — the set-up ledger: boot phases, a model's weights, and
-  each program's trace / lower / cache read / compile / first run, fed by
-  the two ``jax.monitoring`` listeners it holds (imported where used).
+  each program's trace / lower / cache read / compile / first run — its
+  seconds, who asked for it and when — fed by the three ``jax.monitoring``
+  listeners it holds (imported where used).
 
 ``metrics`` declares the framework's standard families; instrumentation
 sites import those objects and guard every record with ``enabled()`` —

@@ -613,7 +613,7 @@ LLM_SELECT_COLUMNS = REGISTRY.counter(
     ("kind",))
 
 # --- the set-up ledger (telemetry/build.py): exclusive SELF seconds -----------
-# A program's name comes from the code base, not from traffic, so these two
+# A program's name comes from the code base, not from traffic, so these
 # families may hold more series than MAX_SERIES: past it the overflow series
 # would lose the phase with the name, and the phases must add up.
 
@@ -630,6 +630,31 @@ PROGRAM_BUILD_SECONDS = REGISTRY.histogram(
     "count is how often.",
     ("program", "phase"), buckets=(0.01, 0.1, 1.0, 10.0, 100.0),
     max_series=2048)
+
+PROGRAM_BUILD_UNDER_SECONDS = REGISTRY.gauge(
+    "cdt_program_build_under_seconds",
+    "The same build seconds as cdt_program_build_seconds, by WHO asked: "
+    "under is the innermost ledger entry that was open around the build "
+    "(an outer program's name while it was traced or lowered, "
+    "first_run:<label>, weights.<phase>:<model>, boot.<phase>) and phase is "
+    "the build's own (trace, lower, cache_key, cache_read, compile, "
+    "first_run). Exclusive like the other family: summed over under, a "
+    "phase reads what cdt_program_build_seconds reads for it. A build is "
+    "under - from the moment it ends until an entry closes around it, so - "
+    "is what nothing enclosed, the one series here that can fall, and "
+    "summed from the building threads' own lists when the family is read.",
+    ("under", "phase"), max_series=2048)
+
+PROGRAM_COLD_COMPILE_SECONDS = REGISTRY.counter(
+    "cdt_program_cold_compile_seconds",
+    "Seconds of XLA compilation each program stands for, by program: on a "
+    "persistent-cache hit what JAX says the entry saved plus the read (the "
+    "compile that wrote the entry), otherwise the backend event's own "
+    "seconds. Summed over programs: what this process would have compiled "
+    "with an empty cache. Seconds BY PROGRAM, summed over threads: a pool's "
+    "builds count each for itself here (the ledger counts the pool's wall), "
+    "so side by side they add up to more than the clock.",
+    ("program",), max_series=1024)
 
 PROGRAM_CACHE = REGISTRY.counter(
     "cdt_program_cache_total",

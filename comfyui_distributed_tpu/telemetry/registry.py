@@ -147,6 +147,9 @@ class _Metric:
         self._lock = tracked_lock("telemetry.family")
         self._children: dict[tuple, object] = {}
         self._dropped = 0
+        # called before every read: a family whose owner keeps part of
+        # its state elsewhere (per thread, say) brings it up to date then
+        self.before_read = None
         if not self.labelnames:
             self._children[()] = self._make_value()
 
@@ -154,11 +157,20 @@ class _Metric:
         raise NotImplementedError
 
     def labels(self, **labelvalues):
-        if set(labelvalues) != set(self.labelnames):
+        names = self.labelnames
+        try:
+            key = tuple([str(labelvalues[n]) for n in names])
+        except KeyError:
+            key = None
+        if key is None or len(labelvalues) != len(names):
             raise ValueError(
-                f"{self.name}: expected labels {self.labelnames}, "
+                f"{self.name}: expected labels {names}, "
                 f"got {tuple(sorted(labelvalues))}")
-        key = tuple(str(labelvalues[n]) for n in self.labelnames)
+        # a series that exists is read without the lock (a dict read is
+        # atomic, and children only ever come: reset swaps the whole dict)
+        child = self._children.get(key)
+        if child is not None:
+            return child
         with self._lock:
             child = self._children.get(key)
             if child is None:
@@ -182,6 +194,8 @@ class _Metric:
         return self._children[()]
 
     def series(self) -> list[tuple[dict, dict]]:
+        if self.before_read is not None:
+            self.before_read()
         with self._lock:
             items = list(self._children.items())
         return [(dict(zip(self.labelnames, key)), child.snap())
@@ -270,8 +284,10 @@ class MetricRegistry:
                                    max_series=max_series)
 
     def gauge(self, name: str, help: str = "",
-              labelnames: Sequence[str] = ()) -> Gauge:
-        return self._get_or_create(Gauge, name, help, labelnames)
+              labelnames: Sequence[str] = (),
+              max_series: int = MAX_SERIES) -> Gauge:
+        return self._get_or_create(Gauge, name, help, labelnames,
+                                   max_series=max_series)
 
     def histogram(self, name: str, help: str = "",
                   labelnames: Sequence[str] = (),

@@ -122,8 +122,9 @@ def _count_compiles() -> None:
     (once per process): whether a restart found its programs on disk is
     then a number in ``/distributed/metrics.json``, not a guess from
     directory listings — and so is WHICH program it did not find, and
-    what each program's tracing, lowering, cache read or compile took
-    (``telemetry/build.py``: the set-up ledger, and the two listeners)."""
+    what each program's tracing, lowering, cache read or compile took,
+    who asked for it and when (``telemetry/build.py``: the set-up ledger,
+    and the three listeners)."""
     global _listening
     if _listening:
         return
@@ -134,3 +135,4 @@ def _count_compiles() -> None:
 
     jax.monitoring.register_event_listener(build.on_event)
     jax.monitoring.register_event_duration_secs_listener(build.on_duration)
+    jax.monitoring.register_event_time_span_listener(build.on_time_span)
